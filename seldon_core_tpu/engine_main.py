@@ -14,6 +14,7 @@ import asyncio
 import json
 import logging
 import os
+import signal
 
 from .graph.service import EngineApp
 from .graph.spec import PredictorSpec, default_predictor, validate_predictor
@@ -56,6 +57,9 @@ def main(argv=None) -> None:
 
         mesh = make_mesh(spec.tpu_mesh)
     app = EngineApp(spec, request_logger=RequestLogger.from_env(), mesh=mesh)
+    # SIGTERM (the control plane's and Kubernetes' stop signal) ends the
+    # server the way Ctrl-C does: exit code 0, not death by signal
+    signal.signal(signal.SIGTERM, signal.default_int_handler)
     try:
         asyncio.run(app.serve(args.host, args.http_port, None if args.no_grpc else args.grpc_port))
     except KeyboardInterrupt:

@@ -1021,8 +1021,15 @@ class EngineApp:
                     grpc_port: Optional[int] = 5001):
         self.start_readiness_loop()
         servers = [self.rest_app().serve_forever(host, http_port)]
+        gsrv = None
         if grpc_port:
             gsrv = self.grpc_server()
             gsrv.add_insecure_port(f"{host}:{grpc_port}")
             await gsrv.start()
-        await asyncio.gather(*servers)
+        try:
+            await asyncio.gather(*servers)
+        finally:
+            if gsrv is not None:
+                # a server dropped unstopped tries to schedule its own
+                # shutdown on the closed loop from __del__
+                await gsrv.stop(None)

@@ -832,11 +832,14 @@ class DecoderLM(ServedModel):
                 kr = jnp.repeat(k, rep, axis=1)
                 vr = jnp.repeat(v, rep, axis=1)
             # flash (pallas) on TPU for MXU-tileable prompt lengths; XLA
-            # einsum fallback elsewhere. Prefill is inference-only, so the
+            # einsum elsewhere. Prefill is inference-only, so the
             # kernel needs no VJP (training keeps parallel/ring.py paths).
             from ..ops import attention as prefill_attention
 
-            o = prefill_attention(q, kr, vr, causal=True)
+            o = prefill_attention(
+                q, kr, vr, causal=True,
+                mesh=getattr(self, "_serving_mesh", None),
+            )
             o = o.transpose(0, 2, 1, 3).reshape(B, Tp, Hl * cfg.head_dim)
             x = x + o @ layer_p["wo"].astype(dt)
             ffn_out, _ = self._ffn(layer_p, x)

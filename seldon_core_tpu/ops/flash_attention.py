@@ -9,10 +9,10 @@ algorithm is the standard FlashAttention blocking, tiled for the MXU
 (128-row blocks, f32 accumulators, bf16 operands).
 
 ``attention()`` is the public entry: it dispatches to the Pallas kernel
-on TPU for shapes that tile cleanly and falls back to the XLA einsum
-path (parallel/ring.full_attention's math) everywhere else — CPU tests,
+on TPU for shapes that tile cleanly and takes the XLA einsum path
+(parallel/ring.full_attention's math) everywhere else — CPU tests,
 tiny prompts, ragged head dims. ``flash_attention()`` is the kernel
-itself (``interpret=True`` runs it on CPU for equivalence tests).
+itself (its ``interpret`` flag runs it on CPU for equivalence tests).
 
 Used by DecoderLM.prefill (serving prefill is inference-only, so the
 kernel needs no VJP). The BERT encoder keeps its XLA attention: its
@@ -28,6 +28,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.experimental import pallas as pl
+from jax.sharding import PartitionSpec as P
 
 NEG_INF = -1e30
 
@@ -56,8 +58,11 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, block_q, block_k, causal):
 
     def body(i, carry):
         o, m, l = carry
-        kb = k_ref[0, pl.ds(i * block_k, block_k), :].astype(jnp.float32)
-        vb = v_ref[0, pl.ds(i * block_k, block_k), :].astype(jnp.float32)
+        # the traced start is block-aligned: say so, or Mosaic cannot
+        # prove the sublane slice lands on a tile edge
+        start = pl.multiple_of(i * block_k, block_k)
+        kb = k_ref[0, pl.ds(start, block_k), :].astype(jnp.float32)
+        vb = v_ref[0, pl.ds(start, block_k), :].astype(jnp.float32)
         s = jax.lax.dot_general(
             q, kb, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         )  # [block_q, block_k]
@@ -97,12 +102,6 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, block_q, block_k, causal):
     o_ref[0] = (o / l).astype(o_ref.dtype)
 
 
-try:  # pallas is TPU/Triton-only in some builds; the fallback never needs it
-    from jax.experimental import pallas as pl
-except Exception:  # pragma: no cover - exercised only in pallas-less builds
-    pl = None
-
-
 @functools.partial(
     jax.jit, static_argnames=("causal", "block_q", "block_k", "interpret")
 )
@@ -118,8 +117,6 @@ def flash_attention(
     """Pallas blocked attention. q [B,H,Tq,Dh], k/v [B,H,Tk,Dh].
     Tq must divide by block_q and Tk by block_k (use :func:`attention`
     for the dispatching fallback)."""
-    if pl is None:
-        raise RuntimeError("pallas is unavailable in this jax build")
     b, h, t_q, dh = q.shape
     t_k = k.shape[2]
     if t_q % block_q or t_k % block_k:
@@ -137,7 +134,10 @@ def flash_attention(
     )
     out = pl.pallas_call(
         kernel,
-        out_shape=jax.ShapeDtypeStruct(qf.shape, q.dtype),
+        # under shard_map the result varies over the mesh axes q does
+        out_shape=jax.ShapeDtypeStruct(
+            qf.shape, q.dtype, vma=jax.typeof(q).vma
+        ),
         grid=(b * h, t_q // block_q),
         in_specs=[
             pl.BlockSpec((1, block_q, dh), lambda bh, i: (bh, i, 0)),
@@ -150,22 +150,32 @@ def flash_attention(
     return out.reshape(b, h, t_q, dh)
 
 
-def attention(q, k, v, kv_len=None, causal: bool = True):
+def attention(q, k, v, kv_len=None, causal: bool = True, mesh=None):
     """Dispatching attention: Pallas flash kernel on TPU when the shape
     tiles onto the MXU, XLA einsum otherwise (CPU, tiny prompts). Inference
     only — the kernel defines no VJP; training paths keep the XLA/ring
-    implementations (parallel/ring.py)."""
+    implementations (parallel/ring.py).
+
+    ``mesh``: the serving mesh when the caller runs under one. Mosaic
+    kernels cannot be partitioned by GSPMD, so the kernel call is wrapped
+    in a ``shard_map`` with every operand and the result replicated —
+    each chip runs the whole single-device kernel, which is the
+    replicated-compute contract of ``DecoderLM.set_serving_mesh``.
+
+    Compiled by Mosaic on a v5e (libtpu 0.0.34) at head_dim 128, block 128,
+    T in {128, 512, 1024, 1792}, alone and inside prefill, one chip and a
+    four-chip mesh (``chip_smoke.py``). Head dims 64 and 256 and the
+    256/512 blocks (T >= 4096) lower for the TPU platform but have not been
+    compiled on the chip."""
     t_q, t_k = q.shape[2], k.shape[2]
-    # bigger blocks amortise the online-softmax rescale and MXU ramp-up;
-    # measured on v5e: T=8192 runs 2x XLA at block 512, T<=2048 is at the
-    # compute roof either way
+    # bigger blocks amortise the online-softmax rescale and MXU ramp-up
+    # (block-size choice not measured on the current machine)
     block = 128
     while block < 512 and t_q % (block * 2) == 0 and t_k % (block * 2) == 0 \
             and block * 16 < t_q:
         block *= 2
     use_kernel = (
-        pl is not None
-        and kv_len is None
+        kv_len is None
         and jax.default_backend() == "tpu"
         and t_q % block == 0
         and t_k % block == 0
@@ -173,4 +183,11 @@ def attention(q, k, v, kv_len=None, causal: bool = True):
     )
     if not use_kernel:
         return _xla_attention(q, k, v, causal=causal, kv_len=kv_len)
-    return flash_attention(q, k, v, causal=causal, block_q=block, block_k=block)
+    kernel = functools.partial(
+        flash_attention, causal=causal, block_q=block, block_k=block
+    )
+    if mesh is not None:
+        kernel = jax.shard_map(
+            kernel, mesh=mesh, in_specs=(P(), P(), P()), out_specs=P()
+        )
+    return kernel(q, k, v)

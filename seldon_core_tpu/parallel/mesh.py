@@ -192,7 +192,7 @@ def initialize_distributed(
     )
     if coordinator_address is None and not on_tpu_pod:
         return False
-    if getattr(jax.distributed, "is_initialized", lambda: False)():
+    if jax.distributed.is_initialized():
         return False
     try:
         jax.distributed.initialize(

@@ -1,9 +1,9 @@
-"""Varying-manual-axes helpers (JAX >= 0.9 shard_map typing).
+"""Varying-manual-axes helpers (shard_map typing).
 
 Inside shard_map, every value's aval carries the set of mesh axes it
 varies over; scan carries and binary ops must agree on it. These helpers
-smooth over the pvary -> pcast rename and let code promote values to a
-target variance without hand-maintaining axis lists.
+let code promote values to a target variance without hand-maintaining
+axis lists.
 """
 
 from __future__ import annotations
@@ -19,19 +19,13 @@ def pvary(x, axes):
     axes = tuple(a for a in axes if a not in vma_of(x))
     if not axes:
         return x
-    if hasattr(lax, "pcast"):
-        return lax.pcast(x, axes, to="varying")
-    return lax.pvary(x, axes)
+    return lax.pcast(x, axes, to="varying")
 
 
 def vma_of(x) -> frozenset:
     import jax
 
-    if hasattr(jax, "typeof"):
-        aval = jax.typeof(x)
-    else:  # jax < 0.6: no jax.typeof; core.get_aval is the same lookup
-        aval = jax.core.get_aval(x)
-    return getattr(aval, "vma", frozenset())
+    return jax.typeof(x).vma
 
 
 def tree_vma(tree) -> frozenset:

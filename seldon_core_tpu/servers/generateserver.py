@@ -192,6 +192,7 @@ from __future__ import annotations
 
 import dataclasses
 import logging
+import time
 from concurrent.futures import TimeoutError as FuturesTimeout
 from typing import Any, Callable, Dict, Iterable, List, Optional, Sequence
 
@@ -476,6 +477,7 @@ class GenerateServer(SeldonComponent):
     def load(self) -> None:
         from ..serving.continuous import ContinuousBatcher
 
+        t_load = time.monotonic()
         server = JAXServer(self.model_uri)
         apply_fn, params = server.build()
         self._model = server._model
@@ -617,6 +619,10 @@ class GenerateServer(SeldonComponent):
             # weight-keyed, so one warm covers all tenants (the
             # scale-to-zero no-recompile property).
             self._load_tenants(params)
+        # the batcher holds the serving copy; under a mesh this local is
+        # the UNSHARDED tree, whole on the first chip through warm-up
+        del params
+        t_warm = time.monotonic()
         if self._warmup_prompt_lens:
             # compile-before-listen: every prefill/insert/burst variant the
             # declared traffic shape needs is built here, so the first
@@ -648,9 +654,25 @@ class GenerateServer(SeldonComponent):
                 self.tenant_scheduler.start()
         if self._role == "decode" and self._peer is not None:
             self._kv_client = self._build_failover(self._peer)
+        # the ready line names the device it serves on: JAX left alone
+        # carries on on the CPU when the accelerator fails to initialise,
+        # and a captured log must show which one this was
+        import jax
+
+        devices = (
+            list(self._mesh.devices.flat) if self._mesh is not None
+            else jax.devices()[:1]
+        )
         logger.info(
-            "generateserver: %s ready (role=%s, slots=%d, max_seq=%d)",
+            "generateserver: %s ready (role=%s, slots=%d, max_seq=%d) "
+            "platform=%s device_kind=%r visible_devices=%d serving_devices=%d "
+            "bytes_in_use=%s load_s=%.1f warm_s=%.1f",
             self.model_uri, self._role, self._slots, self.batcher.max_seq,
+            devices[0].platform, devices[0].device_kind, jax.device_count(),
+            len(devices),
+            # None where the backend keeps no memory stats (CPU)
+            [(d.memory_stats() or {}).get("bytes_in_use") for d in devices],
+            t_warm - t_load, time.monotonic() - t_warm,
         )
 
     def _load_tenants(self, primary_params) -> None:

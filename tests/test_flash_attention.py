@@ -1,9 +1,9 @@
 """Pallas flash-attention kernel: interpret-mode equivalence on CPU.
 
 Tier-1 strategy (SURVEY §4): the kernel's math is checked against the
-plain XLA einsum reference at f32 precision; the TPU lowering itself is
-exercised by the chip benchmarks (modelbench) and by DecoderLM.prefill
-on hardware.
+plain XLA einsum reference at f32 precision, and the lowering guards at
+the bottom lower prefill for the TPU platform from this CPU host. The
+Mosaic compile itself needs the chip: chip_smoke.py's kernel leg.
 """
 
 import jax
@@ -96,3 +96,48 @@ def test_prefill_unchanged_by_dispatch():
     logits, cache = model.prefill(params, prompt, 32)
     assert logits.shape == (2, 128)
     assert bool(jnp.isfinite(logits).all())
+
+
+def _export_prefill_for_tpu(monkeypatch, mesh):
+    """Lower DecoderLM.prefill for the TPU platform from this CPU host,
+    with attention()'s kernel branch selected, at a 128-token bucket and
+    head_dim 128. Lowering runs Pallas -> Mosaic MLIR and the SPMD
+    partitioner's custom-call rules; only libtpu's Mosaic compile needs
+    the chip (chip_smoke.py's kernel leg)."""
+    import numpy as np
+
+    from seldon_core_tpu.models.llm import DecoderLM
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    model = DecoderLM(
+        vocab_size=256, d_model=256, n_layers=2, n_heads=2, n_kv_heads=2,
+        d_ff=256, max_seq=128,
+    )
+    assert model.cfg.head_dim == 128
+    params = jax.tree_util.tree_map(
+        lambda a: a.astype(jnp.bfloat16), model.init_params(0)
+    )
+    if mesh is not None:
+        model.set_serving_mesh(mesh)
+        params = jax.device_put(params, model.param_sharding(mesh, params))
+    prompt = jnp.asarray(
+        np.random.RandomState(0).randint(0, 256, (1, 128)), jnp.int32
+    )
+    fn = jax.jit(lambda p, t: model.prefill(p, t, 128))
+    return jax.export.export(fn, platforms=["tpu"])(params, prompt)
+
+
+def test_prefill_lowers_to_mosaic_call_for_tpu(monkeypatch):
+    exported = _export_prefill_for_tpu(monkeypatch, mesh=None)
+    assert "tpu_custom_call" in exported.mlir_module()
+
+
+def test_meshed_prefill_lowers_for_tpu(monkeypatch):
+    """Mosaic kernels cannot be partitioned by GSPMD: under the serving
+    mesh the kernel call must sit inside a shard_map, or the first
+    meshed prefill compile on real chips raises NotImplementedError."""
+    from seldon_core_tpu.parallel import make_mesh
+
+    mesh = make_mesh({"data": 1, "model": 2})
+    exported = _export_prefill_for_tpu(monkeypatch, mesh=mesh)
+    assert "tpu_custom_call" in exported.mlir_module()
