@@ -8,10 +8,13 @@ log goes to stderr):
     reference        compare the served model with the plain reference
     snapshot         the batcher's counters, SLO samples since the last
                      snapshot, and the device's memory statistics
-    trace_start DIR  start the JAX profiler (only this process can); the
-                     reply carries a snapshot taken once it runs
-    trace_stop       stop it; the snapshot is taken before the stop, which
-                     can take many seconds to write the trace
+    trace_start DIR  the program's ``tracing.start_capture(DIR)``: the JAX
+                     profiler (only this process can); the reply carries a
+                     snapshot taken once it runs
+    trace_stop       ``tracing.stop_capture()``; its report rides under
+                     ``program``, as the program gave it. The snapshot is
+                     taken before the stop, which can take many seconds to
+                     write the trace
 
 The first line on stdout names the device, before anything is built.
 """
@@ -84,7 +87,7 @@ class Control:
         return out
 
     def serve(self) -> None:
-        import jax
+        from seldon_core_tpu import tracing
 
         for line in sys.stdin:
             words = line.split()
@@ -96,16 +99,13 @@ class Control:
                 elif words[0] == "reference":
                     say(self.reference())
                 elif words[0] == "trace_start":
-                    # device ops and the spans the program names itself;
-                    # the Python tracer's frames only slow the host down
-                    options = jax.profiler.ProfileOptions()
-                    options.python_tracer_level = 0
-                    jax.profiler.start_trace(words[1], profiler_options=options)
+                    tracing.start_capture(words[1])
                     say(self.snapshot())
                 elif words[0] == "trace_stop":
                     snap = self.snapshot()
-                    jax.profiler.stop_trace()
-                    say(dict(snap, stop_s=time.monotonic() - snap["t"]))
+                    program = tracing.stop_capture()
+                    say(dict(snap, stop_s=time.monotonic() - snap["t"],
+                             program=program))
                 else:
                     say({"error": f"unknown command {words[0]!r}"})
             except Exception as e:  # noqa: BLE001 - the parent decides

@@ -22,6 +22,9 @@ UNIT_RE = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 SOURCES = ("device_trace", "program_span", "program_counter", "host_clock")
 TOP_KEYS = {"command", "paths", "run_seconds", "configs", "workloads",
             "end_to_end", "per_layer"}
+# the driver's switch: cells are measured and traced in one process
+# (``--trace 2``); read by nothing here
+OPTIONAL_TOP_KEYS = {"trace_in_run"}
 
 
 class ManifestError(ValueError):
@@ -43,7 +46,7 @@ def validate(manifest: dict) -> None:
     """The rules a typo breaks: key sets, names, units, references. The
     driver checks the whole contract; this catches a bad entry before a
     run is spent on it."""
-    if set(manifest) != TOP_KEYS:
+    if set(manifest) - OPTIONAL_TOP_KEYS != TOP_KEYS:
         raise ManifestError(f"keys {sorted(manifest)} != {sorted(TOP_KEYS)}")
     seen: set = set()
     configs = {c["name"] for c in manifest["configs"]}

@@ -1,7 +1,7 @@
 #!/usr/bin/env python3
 """The benchmark's entry: one run of one cell.
 
-    python3 benchmark/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
+    python3 benchmark/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1|2>
 
 This parent never imports jax. It writes the cell's model directory and
 predictor spec from the configuration file, starts ``benchmark/child.py``
@@ -10,8 +10,11 @@ with the plain reference, offers the cell's traffic over the wire, and
 prints as the last line of stdout one JSON object: ``correct``,
 ``attempted``, ``failed``, ``metrics``, ``device`` and, traced,
 ``breakdown``. ``--trace 0`` reports the cell's end-to-end metrics,
-``--trace 1`` its per-layer metrics. Set-up is everything from process
-start to the window opening. Without an accelerator, or outside the repo,
+``--trace 1`` its per-layer metrics from a capture inside the window.
+``--trace 2`` reports both from one process: it is a ``--trace 0`` run up
+to the moment the window closes, and then captures a few seconds of the
+same traffic. Set-up is everything from process start to the window
+opening. Without an accelerator, or outside the repo,
 it exits non-zero and prints no result.
 
 ``--rehearse-cpu`` is the builder's rehearsal: a tiny model on the CPU, no
@@ -48,8 +51,11 @@ SHUTDOWN_TIMEOUT_S = 60.0
 # before the rest is cut (a full lane plus one queued request: two times 256
 # tokens at under 20 ms)
 FINISH_S = 20.0
-TRACE_AFTER_S = 1.0     # into the window
+TRACE_AFTER_S = 1.0     # into the window (--trace 1)
 TRACE_FOR_S = 4.0
+# --trace 2: how long after the window the capture waits for first tokens
+# still on their way to requests due in the window
+FIRST_TOKEN_WAIT_S = 3.0
 # a compile this long once the load runs means a request waited on an
 # executable the warm-up should have covered
 SLOW_COMPILE_S = 1.0
@@ -328,7 +334,7 @@ def main(argv=None) -> int:
     parser.add_argument("--workload", required=True)
     parser.add_argument("--seed", type=int, required=True)
     parser.add_argument("--seconds", type=float, required=True)
-    parser.add_argument("--trace", type=int, choices=(0, 1), default=0)
+    parser.add_argument("--trace", type=int, choices=(0, 1, 2), default=0)
     parser.add_argument("--rehearse-cpu", action="store_true",
                         help="tiny model on the CPU; never prints a result")
     args = parser.parse_args(argv)
@@ -382,18 +388,51 @@ def main(argv=None) -> int:
         t_close = t_open + args.seconds
         log(f"window open after {setup_s:.1f}s of set-up")
         trace_window, trace_snaps = None, None
-        if args.trace:
-            time.sleep(min(TRACE_AFTER_S, args.seconds / 4))
-            trace_dir = os.path.join(run_dir, "trace")
+        trace_dir = os.path.join(run_dir, "trace")
+
+        def capture(seconds: float) -> tuple:
             s0 = child.ask(f"trace_start {trace_dir}", timeout=300.0)
-            time.sleep(max(0.5, min(TRACE_FOR_S, t_close - time.monotonic() - 1)))
+            time.sleep(seconds)
             s1 = child.ask("trace_stop", timeout=300.0)
             log(f"trace stopped in {s1['stop_s']:.1f}s")
+            # the program's report, kept beside requests.jsonl
+            with open(os.path.join(run_dir, "capture.json"), "w") as f:
+                json.dump(s1.get("program"), f)
+            return s0, s1
+
+        if args.trace == 1:
+            time.sleep(min(TRACE_AFTER_S, args.seconds / 4))
+            s0, s1 = capture(
+                max(0.5, min(TRACE_FOR_S, t_close - time.monotonic() - 1)))
             trace_window, trace_snaps = (s0["t"], s1["t"]), (s0, s1)
             slo += s0["slo"] + s1["slo"]
         time.sleep(max(0.0, t_close - time.monotonic()))
         snap_close = child.ask("snapshot")
         slo += snap_close["slo"]
+        e2e = manifest.metrics_of(man, "end_to_end", cell["name"])
+        if args.trace == 2:
+            # up to here a --trace 0 run, and the load goes on. Of the
+            # window's end-to-end numbers only a first token can still be
+            # on its way, to a request due in the window: wait for those,
+            # so that nothing the capture does can reach them.
+            if any(m["name"].startswith("ttft") for m in e2e):
+                waited = time.monotonic() + FIRST_TOKEN_WAIT_S
+                while (pending := sum(
+                        not r.first and not r.status for r in endtoend.due_in(
+                            list(load.records), t_open, t_close))
+                       ) and time.monotonic() < waited:
+                    time.sleep(0.01)
+                if pending:
+                    # counted when it comes, as under --trace 0
+                    log(f"{pending} first token(s) of the window still on "
+                        f"their way after {FIRST_TOKEN_WAIT_S}s")
+            # the profiler's first start and stop go into a trace that is
+            # thrown away (about 4 s on the chip), so that their cost falls
+            # into no number; then the same traffic is traced
+            capture(0.0)
+            shutil.rmtree(trace_dir, ignore_errors=True)
+            s0, s1 = capture(TRACE_FOR_S)
+            trace_window, trace_snaps = (s0["t"], s1["t"]), (s0, s1)
         time.sleep(float(mix.get("drain_s", 0)))
         load.stop(FINISH_S)
         log("load ended")
@@ -431,6 +470,13 @@ def main(argv=None) -> int:
             wrong.append(f"{failed} of {attempted} requests failed, e.g. "
                          + "; ".join(f"#{r.index} {r.status} {r.error}"
                                      for r in bad[:5]))
+        # --trace 2: the same traffic runs on through the capture and its
+        # stop; attempted and failed stay the window's
+        late = endtoend.failed_in(records, t_close, float("inf"), False)
+        if late and args.trace == 2:
+            wrong.append(f"{len(late)} requests due after the window failed, "
+                         "e.g. " + "; ".join(f"#{r.index} {r.status} {r.error}"
+                                             for r in late[:5]))
         if restarts:
             wrong.append(f"the batcher restarted {restarts} time(s)")
         if slow:
@@ -449,6 +495,14 @@ def main(argv=None) -> int:
             print(f"benchmark: warning: the engine exited {rc} on SIGTERM",
                   file=sys.stderr)
         metrics: dict = {}
+        if args.trace != 1:
+            for m in e2e:
+                value = setup_s if m["name"] == "setup_s" else endtoend.compute(
+                    m["name"], records, t_open, t_close)
+                metrics[m["name"]] = {"value": value, "unit": m["unit"]}
+            log(f"samples: ttft {len(endtoend.ttft_ms(records, t_open, t_close))}, "
+                f"tpot {len(endtoend.tpot_ms(records, t_open, t_close))}, tokens "
+                f"{endtoend.tokens_in(records, t_open, t_close)}")
         if args.trace:
             run = {
                 "cell": cell, "config": cfg, "traffic": mix, "peaks": peaks,
@@ -462,14 +516,6 @@ def main(argv=None) -> int:
                 value = manifest.layer_reader(ROOT, man, m["name"])(run)
                 if value is not None:
                     metrics[m["name"]] = {"value": value, "unit": m["unit"]}
-        else:
-            for m in manifest.metrics_of(man, "end_to_end", cell["name"]):
-                value = setup_s if m["name"] == "setup_s" else endtoend.compute(
-                    m["name"], records, t_open, t_close)
-                metrics[m["name"]] = {"value": value, "unit": m["unit"]}
-            log(f"samples: ttft {len(endtoend.ttft_ms(records, t_open, t_close))}, "
-                f"tpot {len(endtoend.tpot_ms(records, t_open, t_close))}, tokens "
-                f"{endtoend.tokens_in(records, t_open, t_close)}")
         device["memory_peak_bytes"] = snap_end["memory_peak_bytes"]
         result = {"correct": correct, "attempted": attempted, "failed": failed,
                   "metrics": metrics, "device": device}

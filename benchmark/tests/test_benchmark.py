@@ -146,6 +146,20 @@ def test_end_to_end_arithmetic_on_hand_made_records():
     assert endtoend.percentile([5], 95) == 5
 
 
+def test_a_failure_after_the_window_is_found():
+    """``--trace 2``: the same traffic runs on through the capture, and a
+    request due then that failed still makes ``correct`` false; one that
+    the stop cut, or one of the window (counted there), does not."""
+    recs = [
+        _rec(0, 19.0, 19.4, [(19.4, 8)], 19.9),
+        _rec(1, 21.0, 0.0, [], 0.0, status="failed"),
+        _rec(2, 25.0, 0.0, [], 0.0, status="aborted"),
+        _rec(3, 15.0, 0.0, [], 0.0, status="failed"),
+    ]
+    late = endtoend.failed_in(recs, 20.0, float("inf"), False)
+    assert [r.index for r in late] == [1]
+
+
 # -- the end of the load ---------------------------------------------------------
 
 def _sse_server(gap_s):
@@ -366,7 +380,7 @@ def test_a_cell_is_added_by_files_alone(tmp_path):
     env = dict(os.environ, JAX_PLATFORMS="cpu",
                JAX_COMPILATION_CACHE_DIR=str(tmp_path / "cache"))
     lines = {}
-    for flag in ("0", "1"):
+    for flag in ("0", "1", "2"):
         done = subprocess.run(
             [sys.executable, "benchmark/run.py", "--workload", "added.tiny",
              "--seed", str(2**31 + 7), "--seconds", "3", "--trace", flag,
@@ -379,7 +393,7 @@ def test_a_cell_is_added_by_files_alone(tmp_path):
         with pytest.raises(ValueError):
             json.loads(last)
         lines[flag] = json.loads(last.split(": ", 1)[1])
-    e2e, layers = lines["0"], lines["1"]
+    e2e, layers, both = lines["0"], lines["1"], lines["2"]
     assert set(e2e) == RESULT_KEYS and set(layers) == RESULT_KEYS | {"breakdown"}
     assert e2e["correct"] and e2e["failed"] == 0 and e2e["attempted"] > 0
     assert set(e2e["metrics"]) == {"tpot_p50_ms", "setup_s"}
@@ -389,5 +403,15 @@ def test_a_cell_is_added_by_files_alone(tmp_path):
     assert {"decode_step_device_ms", "load_s", "warm_s"} <= set(layers["metrics"])
     assert set(e2e["device"]) == {"platform", "kind", "count", "memory_peak_bytes"}
     assert {"busy_s", "window_s"} <= set(layers["device"])
+    # --trace 2: the window measured as under --trace 0, then captured
+    assert set(both) == set(layers) and both["correct"] and both["failed"] == 0
+    assert set(both["metrics"]) == set(e2e["metrics"]) | set(layers["metrics"])
+    assert set(both["device"]) == set(layers["device"])
+    assert set(both["breakdown"]) == set(layers["breakdown"])
+    # the program's own spans and counters, read from its capture report
+    for flag in ("1", "2"):
+        got = lines[flag]["metrics"]
+        assert 0.0 < got["scheduler_host_share"]["value"] <= 100.0
+        assert 0.0 <= got["prefill_device_share"]["value"] <= 100.0
     runs = bench / "_runs" / "added.tiny"
-    assert len(list(runs.glob("*/requests.jsonl"))) == 2    # written on every run
+    assert len(list(runs.glob("*/requests.jsonl"))) == 3    # written on every run

@@ -607,7 +607,9 @@ class EngineApp:
             graphs (or multi-node ones) 501 — streaming can't flow through
             transformer hops."""
             from ..http_server import StreamingResponse
+            from ..tracing import get_tracer
 
+            received_t = time.monotonic()
             if self.paused:
                 return Response(error_body(503, "paused"), 503)
             target = getattr(self.executor.root.client, "user_object", None)
@@ -626,8 +628,15 @@ class EngineApp:
                 body = body["jsonData"]
             try:
                 # stream() validates AND submits eagerly — malformed bodies
-                # and dead batchers raise here, before any bytes go out
-                handle = target.stream(body)
+                # and dead batchers raise here, before any bytes go out.
+                # The root span gives the request a trace context, like
+                # the unary route's: submit() captures it, and the
+                # scheduler's and the front's timeline spans hang under it
+                with get_tracer().span(
+                    "generate_stream", tags={"deployment": self.spec.name},
+                    headers=req.headers,
+                ):
+                    handle = target.stream(body)
             except ShedError as e:
                 # admit-queue shed: same 429 + Retry-After contract as the
                 # unary path, decided before any stream bytes exist
@@ -666,6 +675,12 @@ class EngineApp:
             # guarantees it runs (it drains/starts the iterator even on
             # abort), so the pair always balances.
             self._inflight_add(1)
+            # the front's stamps on the scheduler's request: received,
+            # and below the moments the first token chunk and the done
+            # event are handed to the connection
+            gen = getattr(handle, "request", None)
+            if gen is not None:
+                gen.front.received_t = received_t
 
             def sse():
                 try:
@@ -674,7 +689,16 @@ class EngineApp:
                         # hit count — feed the same engine roll-up the unary
                         # path uses, or stream-only deployments read 0
                         self._count_stream_cache_hit(chunk)
-                        yield b"data: " + json.dumps(chunk).encode() + b"\n\n"
+                        data = b"data: " + json.dumps(chunk).encode() + b"\n\n"
+                        if gen is not None:
+                            now = time.monotonic()
+                            if chunk.get("done"):
+                                gen.front.done_write_t = now
+                            elif not gen.front.first_write_t:
+                                gen.front.first_write_t = now
+                                gen.emit_span("front.first_write",
+                                              gen.first_tok_t, now)
+                        yield data
                 finally:
                     self._inflight_add(-1)
 

@@ -210,6 +210,10 @@ class StreamHandle:
 
     chunks: Iterable
     cancel: Callable[[], bool]
+    # the scheduler's GenRequest, for the front to stamp (``front``) and
+    # to hang its own timeline span on; None behind a tenant queue that
+    # has not submitted yet
+    request: Any = None
 
 
 class GenerateServer(SeldonComponent):
@@ -593,6 +597,11 @@ class GenerateServer(SeldonComponent):
             swap_resume_policy=self._swap_resume_policy,
             profiler=self.profiler,
         )
+        # tracing.start_capture / stop_capture report this batcher's
+        # counters, loop phases and request timelines
+        from ..tracing import register_capture_source
+
+        register_capture_source(self.batcher)
         # chaos harness (off without SELDON_FAULTS): the scheduler
         # section wires induced poll death onto the batcher's fault
         # hook, the pressure section wires mid-run ledger re-budgeting;
@@ -1344,6 +1353,7 @@ class GenerateServer(SeldonComponent):
 
     @caller_thread
     def predict(self, X, names, meta=None):
+        received_t = time.monotonic()
         if self.batcher is None:
             self.load()
         if self._role == "prefill":
@@ -1432,6 +1442,10 @@ class GenerateServer(SeldonComponent):
             for f in futures:
                 f.cancel()
             raise
+        for f in futures:
+            gr = getattr(f, "gen_request", None)
+            if gr is not None:
+                gr.front.received_t = received_t
         results = self._collect_results(
             futures, token_lists, kw, deadline_s, expires_at
         )
@@ -1556,7 +1570,8 @@ class GenerateServer(SeldonComponent):
                 )
             yield final
 
-        return StreamHandle(chunks=chunks(), cancel=fut.cancel)
+        return StreamHandle(chunks=chunks(), cancel=fut.cancel,
+                            request=getattr(fut, "gen_request", None))
 
     @caller_thread
     def hot_swap(self, model_uri: str, wait_s: float = 30.0) -> Dict[str, Any]:

@@ -68,6 +68,7 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import itertools
 import logging
 import queue
 import threading
@@ -78,7 +79,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from ..analysis.roles import caller_thread, scheduler_only
-from ..tracing import wall_us
+from ..tracing import PhaseClock, get_tracer, wall_us
 
 logger = logging.getLogger(__name__)
 
@@ -130,6 +131,26 @@ class BatcherDead(RuntimeError):
         self.retry_after_s = float(retry_after_s)
 
 
+# the scheduler's poll, phase by phase (PhaseClock adds "other"): each a
+# ``batcher.<phase>`` span in the profiler's host plane and seconds in
+# ``stats["loop_<phase>_s"]``
+LOOP_PHASES = ("admit", "chunks", "dispatch", "read_wait", "credit", "idle")
+
+
+@dataclasses.dataclass
+class FrontStamps:
+    """What the front stamps on a request (monotonic seconds, 0.0 = not
+    reached). Written by the front's thread alone, possibly after the
+    scheduler resolved the request, so the timeline ring keeps this
+    object by reference rather than a copy of its values."""
+
+    # the route was entered, before the body was parsed
+    received_t: float = 0.0
+    # the first token chunk / the done event was handed to the connection
+    first_write_t: float = 0.0
+    done_write_t: float = 0.0
+
+
 @dataclasses.dataclass
 class GenRequest:
     tokens: List[int]
@@ -158,7 +179,15 @@ class GenRequest:
     # cache (post-insert) — for chunked admissions this is many polls
     # after admit_t, so decode residency must anchor here, not at admit
     decode_start_t: float = 0.0
+    # the first burst whose snapshot held this lane with its prefill
+    # token still pending was dispatched: that burst carries the first
+    # token to the host
+    first_dispatch_t: float = 0.0
     first_tok_t: float = 0.0
+    done_t: float = 0.0
+    front: FrontStamps = dataclasses.field(default_factory=FrontStamps)
+    # process-wide sequence number: names the request in the timeline ring
+    rid: int = dataclasses.field(default_factory=itertools.count(1).__next__)
     # wall-clock anchor of submit_t (epoch microseconds) so retroactive
     # spans can place monotonic intervals on the Jaeger timeline
     submit_wall_us: int = 0
@@ -190,6 +219,20 @@ class GenRequest:
     # tenant so the scheduler's starvation score sees per-tenant TTFT
     tenant: Optional[str] = None
     slo: str = "standard"
+
+    def emit_span(self, operation: str, start_t: float, end_t: float,
+                  tags: Optional[Dict[str, Any]] = None) -> None:
+        """Retroactive timeline span, parented under the trace context
+        captured at submit(). No-op (one attribute check) for untraced
+        requests. Monotonic interval endpoints are placed on the wall
+        clock via the request's submit anchor."""
+        if self.trace is None:
+            return
+        start_us = self.submit_wall_us + int((start_t - self.submit_t) * 1e6)
+        get_tracer().record_span(
+            operation, self.trace[0], self.trace[1], start_us,
+            int((end_t - start_t) * 1e6), tags=tags,
+        )
 
 
 @dataclasses.dataclass
@@ -604,6 +647,19 @@ class ContinuousBatcher:
             "slo_samples": 0, "queue_wait_s_sum": 0.0,
             "ttft_s_sum": 0.0, "tpot_s_sum": 0.0,
         })
+        # request timeline: one tuple per completed request beside its
+        # SLO triple — (rid, prompt length, padded bucket, tokens emitted,
+        # cache_hit_tokens, submit_t, admit_t, decode_start_t,
+        # first_dispatch_t, first_tok_t, done_t, FrontStamps). Always on;
+        # nothing per token. :meth:`capture_requests` names the fields.
+        self.timeline_recent: "collections.deque" = collections.deque(maxlen=2048)
+        # the scheduler loop's own time: working polls, bursts read back,
+        # their dispatch-to-host-read seconds summed, and the
+        # loop_<phase>_s seconds the phase clock keeps
+        self.stats.update({
+            "polls": 0, "bursts": 0, "burst_read_lag_s_sum": 0.0,
+        })
+        self._clock = PhaseClock(self.stats, "batcher", "loop", LOOP_PHASES)
         # per-tenant splits of the same samples (multi-tenant serving):
         # keyed lazily by tenant id at _resolve time so the single-tenant
         # path allocates nothing. tenant_slo carries cumulative sums +
@@ -1595,6 +1651,39 @@ class ContinuousBatcher:
             "ttft_ms": pct([s[1] for s in samples]),
             "tpot_ms": pct(tpots) if tpots else None,
         }
+
+    def capture_counters(self) -> Dict[str, Dict[str, float]]:
+        """Running totals for ``tracing.start_capture`` / ``stop_capture``
+        to difference: ``loop`` is the scheduler thread's time by phase
+        (the phase in progress included, so the phases sum to
+        ``wall_s``) with the poll and burst counts; ``counters`` is
+        every numeric entry of ``stats``."""
+        loop = self._clock.read()
+        for k in ("polls", "bursts", "burst_read_lag_s_sum"):
+            loop[k] = self.stats[k]
+        return {"loop": loop, "counters": dict(self.stats)}
+
+    def capture_started(self) -> None:
+        """A profiler records from here on: the loop's phase in progress
+        gets a span in it (``PhaseClock.reenter``)."""
+        self._clock.reenter()
+
+    TIMELINE_FIELDS = (
+        "id", "prompt_len", "bucket", "tokens", "cache_hit_tokens",
+        "submit_t", "admit_t", "decode_start_t", "first_dispatch_t",
+        "first_tok_t", "done_t",
+    )
+
+    def capture_requests(self) -> List[Dict[str, Any]]:
+        """The timeline ring as dicts, oldest first: ``TIMELINE_FIELDS``
+        and the front's stamps as they stand now (absolute monotonic
+        seconds, 0.0 = not reached)."""
+        out = []
+        for entry in list(self.timeline_recent):
+            row = dict(zip(self.TIMELINE_FIELDS, entry))
+            row.update(dataclasses.asdict(entry[-1]))
+            out.append(row)
+        return out
 
     @caller_thread
     def _shed_check(
@@ -3336,20 +3425,8 @@ class ContinuousBatcher:
     @scheduler_only
     def _emit_span(self, req: GenRequest, operation: str, start_t: float,
                    end_t: float, tags: Optional[Dict[str, Any]] = None) -> None:
-        """Retroactive per-request timeline span, parented under the trace
-        context captured at submit(). No-op (one attribute check) for
-        untraced requests, so the scheduler hot path stays clean with
-        tracing off. Monotonic interval endpoints are placed on the wall
-        clock via the request's submit anchor."""
-        if req.trace is None:
-            return
-        from ..tracing import get_tracer
-
-        start_us = req.submit_wall_us + int((start_t - req.submit_t) * 1e6)
-        get_tracer().record_span(
-            operation, req.trace[0], req.trace[1], start_us,
-            int((end_t - start_t) * 1e6), tags=tags,
-        )
+        """:meth:`GenRequest.emit_span` from the scheduler's side."""
+        req.emit_span(operation, start_t, end_t, tags)
 
     @scheduler_only
     def _plan_groups(self, adv: int):
@@ -4221,13 +4298,27 @@ class ContinuousBatcher:
         flushed before any victim is chosen. Preemption is rare; one
         flushed pipeline is its cheapest cost."""
         while pending:
-            mode, payload = pending.popleft()
-            if mode == "spec":
-                self._process_spec_burst(*payload)
-            elif mode == "fused":
-                self._process_fused_burst(*payload)
-            else:
-                self._process_burst(*payload)
+            self._read_burst(pending.popleft())
+
+    @scheduler_only
+    def _read_burst(self, entry) -> None:
+        """Bring one in-flight burst's tokens to the host and credit them.
+        ``entry`` is ``(mode, device arrays, (snapshot, ...), dispatch
+        time)``; the ``np.asarray`` here is the burst's one host sync
+        (phase ``read_wait``), everything after it is ``credit``."""
+        mode, arrays, rest, t_dispatch = entry
+        self._clock.to("read_wait")
+        host = [np.asarray(a) for a in arrays]
+        t_read = self._clock.to("credit")
+        self.stats["bursts"] += 1
+        self.stats["burst_read_lag_s_sum"] += t_read - t_dispatch
+        if mode == "spec":
+            self._process_spec_burst(*host, *rest)
+        elif mode == "fused":
+            self._process_fused_burst(*host, *rest)
+        else:
+            self._process_burst(*host, *rest)
+        self._clock.to("other")
 
     @scheduler_only
     def _pressure_poll(self, pending) -> None:
@@ -5011,7 +5102,7 @@ class ContinuousBatcher:
         # finished + cancelled = all requests ever resolved
         s.credit_done = True
         req = s.request
-        now = time.monotonic()
+        now = req.done_t = time.monotonic()
         if req.future.cancelled():
             self.stats["cancelled"] += 1
             if req.admit_t:
@@ -5047,6 +5138,12 @@ class ContinuousBatcher:
                 self.stats["tpot_s_sum"] += tpot
             self.slo_pending.append((queue_wait, ttft, tpot))
             self.slo_recent.append((queue_wait, ttft, tpot))
+            self.timeline_recent.append((
+                req.rid, len(req.tokens), self._bucket(len(req.tokens)),
+                n_tok, req.cache_hit_tokens, req.submit_t, req.admit_t,
+                req.decode_start_t, req.first_dispatch_t, req.first_tok_t,
+                now, req.front,
+            ))
             if req.tenant is not None:
                 # per-tenant split of the same triple: the TenantScheduler
                 # reads tenant_slo_recent as its TTFT feedback signal and
@@ -5121,6 +5218,13 @@ class ContinuousBatcher:
             # first span of credited tokens = the client-visible TTFT
             # moment (a float store per REQUEST, not per token)
             req.first_tok_t = time.monotonic()
+            if req.trace is not None:
+                held_from = req.decode_start_t or req.admit_t
+                self._emit_span(
+                    req, "gen.first_token_hold", held_from, req.first_tok_t,
+                    tags={"first_dispatch_ms": round(
+                        (req.first_dispatch_t - held_from) * 1e3, 3)},
+                )
         done = False
         for t in tokens:
             s.emitted.append(int(t))
@@ -5138,7 +5242,7 @@ class ContinuousBatcher:
         return done
 
     @scheduler_only
-    def _process_burst(self, toks_dev, snapshot) -> None:
+    def _process_burst(self, host_toks, snapshot) -> None:
         """Credit one burst's tokens to the requests that occupied each lane
         AT DISPATCH TIME. Bursts execute on the device stream in dispatch
         order and any re-admission insert is dispatched after them, so the
@@ -5148,8 +5252,8 @@ class ContinuousBatcher:
         skipped: its remaining rows are overshoot decode, dropped by
         design. ``snapshot[slot] = (s, start_row, col)`` — col is the
         lane's COLUMN in this burst's token matrix (its gathered row for
-        a depth-group sub-burst, the slot id for a whole-batch burst)."""
-        host_toks = np.asarray(toks_dev)  # the burst's one host sync
+        a depth-group sub-burst, the slot id for a whole-batch burst).
+        The arrays are on the host already (:meth:`_read_burst`)."""
         for slot, (s, start, col) in snapshot.items():
             if s.credit_done:
                 continue
@@ -5161,14 +5265,14 @@ class ContinuousBatcher:
         self._check_done()
 
     @scheduler_only
-    def _process_fused_burst(self, toks_dev, counts_dev, done_dev, snapshot,
+    def _process_fused_burst(self, host_toks, counts, done, snapshot,
                              k) -> None:
         """Credit one stop-aware fused burst. Per lane, exactly
         ``counts[col]`` tokens were emitted before its on-device done
         mask froze it (stop token / budget), so — unlike
         :meth:`_process_burst` — no overshoot rows exist to drop; the
         host just credits the counted span (row 0 still carries the
-        deferred prefill first token). ``done_dev`` is the device's own
+        deferred prefill first token). ``done`` is the device's own
         verdict; crediting re-derives it from the tokens (``_credit``
         checks eos/budget per token), so the two can never disagree
         without the identity tests catching it. Like
@@ -5176,8 +5280,6 @@ class ContinuousBatcher:
         from the worst-case k advance to the lane's actual alive steps —
         a lane frozen early must not inflate the pressure ledger or the
         attention-bucket need until the host observes it."""
-        host_toks = np.asarray(toks_dev)  # the burst's one host sync
-        counts = np.asarray(counts_dev)
         for slot, (s, start, col) in snapshot.items():
             if self._active.get(slot) is s and slot in self._pos_host:
                 self._pos_host[slot] -= k - int(counts[col])
@@ -5194,13 +5296,11 @@ class ContinuousBatcher:
         self._check_done()
 
     @scheduler_only
-    def _process_spec_burst(self, start_tok_dev, toks_dev, counts_dev, snapshot, k) -> None:
+    def _process_spec_burst(self, start_tok, host_toks, counts, snapshot, k) -> None:
         """Spec-mode crediting: per round, a lane emitted counts[r, slot]
-        tokens (accepted drafts + the target's correction). Also tightens
-        the host position bound from worst-case (k*(gamma+1)) to actual."""
-        start_tok = np.asarray(start_tok_dev)
-        host_toks = np.asarray(toks_dev)  # [k, S, gamma+1]
-        counts = np.asarray(counts_dev)  # [k, S]
+        tokens (accepted drafts + the target's correction); ``host_toks``
+        is [k, S, gamma+1], ``counts`` [k, S]. Also tightens the host
+        position bound from worst-case (k*(gamma+1)) to actual."""
         worst = k * (self.speculate_tokens + 1)
         # acceptance telemetry over ALL lanes that ran rounds (device-true,
         # independent of host-side crediting cutoffs)
@@ -5229,9 +5329,13 @@ class ContinuousBatcher:
         permitting — rebuilds the device state and resumes, so a
         transient device/driver fault costs seconds, not a pod."""
         self._started.set()
-        while not self._stop.is_set():
-            if not self._loop():
-                return
+        self._clock.start()
+        try:
+            while not self._stop.is_set():
+                if not self._loop():
+                    return
+        finally:
+            self._clock.stop()
 
     @scheduler_only
     def _fail_inflight(self, pending, err: Exception) -> None:
@@ -5246,9 +5350,8 @@ class ContinuousBatcher:
             s = self._active.pop(slot)
             if not s.request.future.done():
                 s.request.future.set_exception(err)
-        for _mode, payload in pending:
-            snap = payload[3] if _mode in ("spec", "fused") else payload[1]
-            for entry in snap.values():
+        for _mode, _arrays, rest, _t in pending:
+            for entry in rest[0].values():
                 s = entry[0]
                 if not s.request.future.done():
                     s.request.future.set_exception(err)
@@ -5334,15 +5437,15 @@ class ContinuousBatcher:
         """One supervised run of the poll loop. Returns False on a clean
         ``close()`` stop, or :meth:`_crash_recover`'s verdict after a
         loop death (True = run again on rebuilt state)."""
-        import collections
-
         import jax.numpy as jnp
 
         from ..tracing import device_trace
 
         temps = np.zeros((self.slots,), np.float32)
-        # in-flight bursts, oldest first: (device tokens, lane snapshot)
+        # in-flight bursts, oldest first: (mode, device arrays to read,
+        # (lane snapshot, ...), dispatch time) — see _read_burst
         pending: "collections.deque" = collections.deque()
+        clock = self._clock
         try:
             while not self._stop.is_set():
                 # chaos hook: an injected poll death here exercises the
@@ -5365,6 +5468,7 @@ class ContinuousBatcher:
                     or self._resume_queue or not self._queue.empty()
                 ):
                     self._work_poll_count += 1
+                    self.stats["polls"] += 1
                 if self.pressure_hook is not None or (
                     self._pressure.budget_bytes > 0
                 ):
@@ -5436,6 +5540,7 @@ class ContinuousBatcher:
                 # admit as many queued requests as there are free slots —
                 # same-bucket admissions are grouped so m lanes share one
                 # batched prefill forward (pow2 chunks bound executables)
+                clock.to("admit")
                 wave: List[GenRequest] = []
                 busy = len(self._active) + len(self._chunked)
                 wave_cost = 0
@@ -5593,17 +5698,22 @@ class ContinuousBatcher:
                     not self._active and not pending and not self._chunked
                     and not (self._resume_queue and not pressure_hold)
                 ):
+                    clock.to("idle")
                     try:
                         req = self._queue.get(timeout=0.05)
                     except queue.Empty:
-                        continue
-                    self._queue.put(req)
+                        req = None
+                    clock.to("other")
+                    if req is not None:
+                        self._queue.put(req)
                     continue
                 if self._chunked:
                     # the interleave: one prefill chunk per pending long
                     # admission, then the decode burst below — decode
                     # lanes keep their cadence while long prompts land
+                    clock.to("chunks")
                     self._advance_chunks()
+                clock.to("dispatch")
                 if self._active:
                     if self._masks_dirty:
                         for i in range(self.slots):
@@ -5686,9 +5796,12 @@ class ContinuousBatcher:
                         # their per-round advance is data-dependent and
                         # the verify pass already amortises param reads.)
                         snapshot = {}
+                        t_dispatch = time.monotonic()
                         for slot, s in self._active.items():
                             first = s.first_pending
                             snapshot[slot] = (s, 0 if first else 1)
+                            if first:
+                                s.request.first_dispatch_t = t_dispatch
                             s.first_pending = False
                             s.dispatched += k + (1 if first else 0)
                             self._pos_host[slot] += adv
@@ -5731,7 +5844,10 @@ class ContinuousBatcher:
                                 t.copy_to_host_async()
                             except AttributeError:
                                 pass
-                        pending.append(("spec", (start_tok, toks, counts, snapshot, k)))
+                        pending.append((
+                            "spec", (start_tok, toks, counts), (snapshot, k),
+                            t_dispatch,
+                        ))
                     else:
                         groups, need = self._plan_groups(adv)
                         if flight is not None:
@@ -5766,10 +5882,13 @@ class ContinuousBatcher:
                         )
                         for lanes, g_bucket in groups:
                             snapshot = {}
+                            t_dispatch = time.monotonic()
                             for col, slot in enumerate(lanes):
                                 s = self._active[slot]
                                 first = s.first_pending
                                 snapshot[slot] = (s, 0 if first else 1, col)
+                                if first:
+                                    s.request.first_dispatch_t = t_dispatch
                                 s.first_pending = False
                                 s.dispatched += k + (1 if first else 0)
                                 self._pos_host[slot] += adv
@@ -5925,15 +6044,17 @@ class ContinuousBatcher:
                                     except AttributeError:
                                         pass
                                 pending.append((
-                                    "fused",
-                                    (toks, counts, done_bits, snapshot, k),
+                                    "fused", (toks, counts, done_bits),
+                                    (snapshot, k), t_dispatch,
                                 ))
                             else:
                                 try:
                                     toks.copy_to_host_async()
                                 except AttributeError:  # non-jax (test doubles)
                                     pass
-                                pending.append(("plain", (toks, snapshot)))
+                                pending.append((
+                                    "plain", (toks,), (snapshot,), t_dispatch,
+                                ))
                         # PREDICTIVE FREE: a lane whose eos-less budget is
                         # now fully covered by dispatched bursts is done —
                         # the host needn't observe the tokens to know it.
@@ -5959,6 +6080,7 @@ class ContinuousBatcher:
                             self._pos_host.pop(slot, None)
                         if freed:
                             self._masks_dirty = True
+                clock.to("other")
                 if flight is not None:
                     admitted = self.stats["admitted"] - f0[0]
                     chunks = self.stats["prefill_chunks"] - f0[1]
@@ -5998,27 +6120,16 @@ class ContinuousBatcher:
                 # without ever stalling dispatch.
                 while pending:
                     if not (len(pending) >= self.pipeline_depth or not self._active):
-                        # last-initiated transfer of the oldest burst: counts
-                        # for spec (start_tok/toks/counts copy in order),
-                        # the done bitmap for fused (toks/counts/done), toks
-                        # for plain — if IT landed, np.asarray of the
-                        # earlier arrays won't block either
-                        head_mode, head_payload = pending[0]
-                        head = head_payload[
-                            2 if head_mode in ("spec", "fused") else 0
-                        ]
+                        # last-initiated transfer of the oldest burst
+                        # (its arrays copy in order): if IT landed,
+                        # np.asarray of the earlier ones won't block either
+                        head = pending[0][1][-1]
                         try:
                             if not head.is_ready():
                                 break
                         except AttributeError:
                             pass  # non-jax array (test doubles): treat as ready
-                    mode, payload = pending.popleft()
-                    if mode == "spec":
-                        self._process_spec_burst(*payload)
-                    elif mode == "fused":
-                        self._process_fused_burst(*payload)
-                    else:
-                        self._process_burst(*payload)
+                    self._read_burst(pending.popleft())
         except Exception:  # noqa: BLE001 - every loop death is supervised
             logger.exception("continuous batcher loop died")
             return self._crash_recover(pending)

@@ -15,22 +15,26 @@ in-process and served in Jaeger HTTP-API JSON shape at the engine's
 so traces stitch across engine → microservice process hops.
 
 TPU deltas: ``device_trace`` wraps ``jax.profiler.TraceAnnotation`` so a
-span's name shows up inside XLA device profiles, and
-``start_device_profile``/``stop_device_profile`` expose the JAX profiler
-(TensorBoard-loadable) for the hot path.
+span's name shows up inside XLA device profiles; :class:`PhaseClock`
+partitions one thread's time into such spans and always-on counters; and
+``start_capture``/``stop_capture`` are the one control that brackets a
+window in the running process — the JAX profiler if asked for, and a
+report of what the registered sources counted and stamped meanwhile.
 """
 
 from __future__ import annotations
 
 import contextlib
 import contextvars
+import functools
 import os
 import random
 import threading
 import time
 from collections import deque
+import weakref
 from dataclasses import dataclass, field
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, Optional, Sequence
 
 TRACE_HEADER = "uber-trace-id"  # trace_id:span_id:parent_span_id:flags
 BAGGAGE_PREFIX = "uberctx-"
@@ -564,28 +568,199 @@ def get_tracer() -> Tracer:
 # -- TPU device tracing -----------------------------------------------------
 
 
-@contextlib.contextmanager
+@functools.cache
+def _annotation_type():
+    """``jax.profiler.TraceAnnotation``, resolved once per process."""
+    try:
+        from jax.profiler import TraceAnnotation
+    except ImportError:  # pragma: no cover
+        return contextlib.nullcontext
+    return TraceAnnotation
+
+
 def device_trace(name: str):
     """Annotate the enclosed device work so it shows up named inside XLA
     profiles (TPU equivalent of the reference's span around the model call)."""
-    try:
-        import jax.profiler
-
-        with jax.profiler.TraceAnnotation(name):
-            yield
-    except ImportError:  # pragma: no cover
-        yield
+    return _annotation_type()(name)
 
 
-def start_device_profile(logdir: str) -> None:
-    """TensorBoard-loadable XLA profile (reference equivalent: JMX :9090 +
-    testing/profiling/engine)."""
-    import jax.profiler
+class PhaseClock:
+    """One thread's wall time, partitioned into named phases.
 
-    jax.profiler.start_trace(logdir)
+    The owning thread calls :meth:`to` at each phase boundary. Every phase
+    is (a) a ``TraceAnnotation`` named ``<prefix>.<phase>``, so it lands in
+    the profiler's host plane on the clock the device ops are on, and (b)
+    seconds added to ``counters["<key>_<phase>_s"]``. Time is never
+    unaccounted: between :meth:`start` and :meth:`stop` the thread is in
+    exactly one phase. One ``time.monotonic()`` read and one annotation
+    per switch; no lock, no allocation beyond the annotation itself.
+    :meth:`read` may be called from any thread."""
+
+    def __init__(self, counters: Dict[str, Any], prefix: str, key: str,
+                 phases: Sequence[str], rest: str = "other"):
+        self._counters = counters
+        self._rest = rest
+        self._key = {p: f"{key}_{p}_s" for p in (*phases, rest)}
+        self._span_name = {p: f"{prefix}.{p}" for p in self._key}
+        for k in self._key.values():
+            counters.setdefault(k, 0.0)
+        self._at: Optional[tuple] = None    # (phase, since) while running
+        self._span = None
+        self._edge = None                   # see reenter()
+        self._seq = 0                       # odd while a switch is half done
+        self._t_start = self._t_stop = 0.0
+
+    def start(self) -> None:
+        now = time.monotonic()
+        # a later start takes up where the accounted time left off
+        self._t_start = now - sum(self._counters[k] for k in self._key.values())
+        self._at = (self._rest, now)
+        self._span = device_trace(self._span_name[self._rest])
+        self._span.__enter__()
+
+    def to(self, phase: str) -> float:
+        """Enter ``phase``; returns the moment of the switch."""
+        now = time.monotonic()
+        if self._at is None:    # not started: nothing to account to
+            return now
+        was, since = self._at
+        self._seq += 1
+        self._counters[self._key[was]] += now - since
+        self._at = (phase, now)
+        self._seq += 1
+        self._span.__exit__(None, None, None)
+        self._span = device_trace(self._span_name[phase])
+        self._span.__enter__()
+        if self._edge is not None:
+            edge, self._edge = self._edge, None
+            edge.__exit__(None, None, None)
+        return now
+
+    def reenter(self) -> None:
+        """Any thread, once a profiler records: the span of the phase in
+        progress was entered before that and is never recorded (a
+        ``read_wait`` can be most of a second), so a second one of its
+        name runs from here to the owner's next switch. Should that switch
+        come first, this span outlives the phase it names; the next
+        phase's own span then lies inside it and is the shorter, so it
+        still names what happens there."""
+        at = self._at
+        if at is not None:
+            edge = device_trace(self._span_name[at[0]])
+            edge.__enter__()
+            self._edge = edge
+
+    def stop(self) -> None:
+        if self._at is None:
+            return
+        self._t_stop = self.to(self._rest)
+        self._span.__exit__(None, None, None)
+        self._span = None
+        self._at = None
+
+    def read(self) -> Dict[str, float]:
+        """``{<phase>_s..., wall_s}`` up to now, the phase in progress
+        included, so the phases sum to ``wall_s``."""
+        while True:
+            seq = self._seq
+            at = self._at
+            now = time.monotonic()
+            out = {f"{p}_s": self._counters[k] for p, k in self._key.items()}
+            if seq % 2 == 0 and self._seq == seq:
+                break
+        if at is not None:
+            out[f"{at[0]}_s"] += now - at[1]
+        out["wall_s"] = (now if at is not None else self._t_stop) - self._t_start
+        return out
 
 
-def stop_device_profile() -> None:
-    import jax.profiler
+class CaptureError(RuntimeError):
+    """``start_capture`` while a capture runs, or ``stop_capture``
+    without one."""
 
-    jax.profiler.stop_trace()
+
+class CaptureControl:
+    """Starts and stops a capture in the running process: the JAX
+    profiler (when a ``logdir`` is given) and the program's own counters
+    and request stamps. The *source* is the object with
+
+    ``capture_counters() -> {group: {name: number}}``  running totals
+    ``capture_requests() -> [dict]``                   recent request timelines
+    ``capture_started()``                              the profiler records now
+
+    held weakly, so a closed server drops out. A capture is a call, not a
+    configuration: nothing here is read from the environment."""
+
+    def __init__(self):
+        self._source = lambda: None
+        self._lock = threading.Lock()
+        self._running: Optional[Dict[str, Any]] = None
+
+    def register(self, source) -> None:
+        self._source = weakref.ref(source)
+
+    def start(self, logdir: Optional[str] = None) -> Dict[str, float]:
+        with self._lock:
+            if self._running is not None:
+                raise CaptureError("a capture is already running")
+            # one pair read back to back: places monotonic stamps on the
+            # profiler's clock (unix nanoseconds)
+            t0 = time.monotonic()
+            wall_unix_ns = time.time_ns()
+            # held strongly until the stop, so both readings are of the
+            # same source
+            source = self._source()
+            before = source.capture_counters() if source is not None else {}
+            if logdir is not None:
+                import jax.profiler
+
+                # device ops and the spans the program names itself; the
+                # Python tracer's frames only slow the host down
+                options = jax.profiler.ProfileOptions()
+                options.python_tracer_level = 0
+                jax.profiler.start_trace(logdir, profiler_options=options)
+                if source is not None:
+                    source.capture_started()
+            self._running = {
+                "t0": t0, "source": source, "before": before,
+                "profiling": logdir is not None,
+                "clock": {"monotonic_s": t0, "unix_ns": wall_unix_ns},
+            }
+            return {"t": t0}
+
+    def stop(self) -> Dict[str, Any]:
+        """Ends the capture and returns its report: ``t0``/``t1``
+        (monotonic), ``clock`` (one monotonic/unix pair), each counter
+        group of the source as differences over the capture (the
+        batcher's ``loop`` and ``counters``), and ``requests`` — every
+        timeline the source still holds, stamps absolute monotonic, so a
+        reader selects by window."""
+        with self._lock:
+            run = self._running
+            if run is None:
+                raise CaptureError("no capture is running")
+            self._running = None
+            source = run["source"]
+            t1 = time.monotonic()
+            try:
+                after = source.capture_counters() if source is not None else {}
+                requests = source.capture_requests() if source is not None else []
+            finally:
+                if run["profiling"]:
+                    import jax.profiler
+
+                    jax.profiler.stop_trace()
+        report: Dict[str, Any] = {"t0": run["t0"], "t1": t1,
+                                  "clock": run["clock"]}
+        for group, values in after.items():
+            before = run["before"].get(group, {})
+            report[group] = {k: v - before.get(k, 0) for k, v in values.items()
+                             if isinstance(v, (int, float))}
+        report["requests"] = requests
+        return report
+
+
+_CAPTURE = CaptureControl()
+register_capture_source = _CAPTURE.register
+start_capture = _CAPTURE.start
+stop_capture = _CAPTURE.stop
