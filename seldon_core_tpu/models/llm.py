@@ -271,21 +271,13 @@ class DecoderLM(ServedModel):
             # batch: every row writes at its own position)
             ck, cv, cache_pos = kv_cache
             if getattr(cache_pos, "ndim", 0):
-                rows = jnp.arange(B)
-                ck = ck.at[rows, :, cache_pos, :].set(k[:, :, 0, :])
-                cv = cv.at[rows, :, cache_pos, :].set(v[:, :, 0, :])
+                ck = self._cache_write(ck, k, cache_pos[:, None])
+                cv = self._cache_write(cv, v, cache_pos[:, None])
             else:
                 ck = lax.dynamic_update_slice(ck, k, (0, 0, cache_pos, 0))
                 cv = lax.dynamic_update_slice(cv, v, (0, 0, cache_pos, 0))
-            k, v = ck, cv
             new_cache = (ck, cv)
-            if attn_len is not None and attn_len < k.shape[2]:
-                # decode is cache-bandwidth-bound: read only the prefix the
-                # scheduler proved can hold keys (a STATIC bucket >= every
-                # lane's position + 1, so one executable per bucket). The
-                # full cache is still written above — only the read narrows.
-                k = lax.slice_in_dim(k, 0, attn_len, axis=2)
-                v = lax.slice_in_dim(v, 0, attn_len, axis=2)
+            k, v = self._cache_read(ck, cv, attn_len)
         if kv_cache is not None:
             # decode attention over the (sliced) cache — see
             # _cache_attention for why the GQA repeat must not happen here
@@ -306,6 +298,60 @@ class DecoderLM(ServedModel):
         if tp_axis is not None:
             o = lax.psum(o, tp_axis)
         return o, new_cache
+
+    @staticmethod
+    def _cache_write(cache, new, positions):
+        """The ONE ragged cache write: ``new`` [B, KV, W, Dh] lands in
+        ``cache`` [B, KV, T, Dh] at ``positions`` [B, W] (row b's column j
+        at position positions[b, j]). A position >= T (or < 0) is DROPPED (JAX
+        scatter semantics) — the stop-aware bursts park finished lanes'
+        writes there.
+
+        Every (lane, KV head, position) is a scatter row of its own, so
+        the window is the ``Dh`` vector alone — already the minor-most
+        dimension of the cache as every other executable holds it. The
+        textbook ``cache.at[rows, :, pos, :].set(...)`` has a [KV, Dh]
+        window instead, and the TPU compiler then carries the cache
+        through the burst's loop with KV minor to T: a cache-sized
+        relayout copy of every layer's K and V on entry to the burst and
+        another on exit, into scratch as large as the cache, whatever
+        ``donate_argnums`` says (ISSUE 26: 27% of device time at 28 lanes
+        x 24 layers). ``tools/burst_hlo_check.py`` compiles the burst and
+        fails on such a copy; PERF.md section 6 has both outputs."""
+        import jax.numpy as jnp
+        from jax import lax
+
+        B, KV, W, _ = new.shape
+        index = jnp.stack(jnp.broadcast_arrays(
+            jnp.arange(B, dtype=jnp.int32)[:, None, None],
+            jnp.arange(KV, dtype=jnp.int32)[None, :, None],
+            positions.astype(jnp.int32)[:, None, :],
+        ), axis=-1)  # [B, KV, W, 3]: (lane, KV head, position)
+        # lax.scatter itself, not cache.at[..].set: jnp's index handling is
+        # traced once per layer for K and for V in every burst variant
+        # that warm() lowers, and cost ~0.2 s a variant there
+        return lax.scatter(
+            cache, index, new.astype(cache.dtype),
+            lax.ScatterDimensionNumbers(
+                update_window_dims=(3,), inserted_window_dims=(0, 1, 2),
+                scatter_dims_to_operand_dims=(0, 1, 2)),
+            mode=lax.GatherScatterMode.FILL_OR_DROP,
+        )
+
+    @staticmethod
+    def _cache_read(ck, cv, attn_len):
+        """The ONE narrowed cache read: the prefix the scheduler proved can
+        hold keys (``attn_len``: a STATIC bucket >= every lane's position
+        + 1, so one executable per bucket; ``None`` reads it all). The
+        write above always addresses the full cache — only the read
+        narrows. The slice is an operand of ``_cache_attention``'s two
+        dots, not an array of its own."""
+        from jax import lax
+
+        if attn_len is None or attn_len >= ck.shape[2]:
+            return ck, cv
+        return (lax.slice_in_dim(ck, 0, attn_len, axis=2),
+                lax.slice_in_dim(cv, 0, attn_len, axis=2))
 
     @staticmethod
     def _cache_attention(q, kc, vc, bound, dt):
@@ -534,9 +580,18 @@ class DecoderLM(ServedModel):
         Why a second layout: the stacked [L, ...] cache flowing through the
         layer scan as xs/ys makes XLA rewrite the whole cache every step —
         decode cost then scales with TOTAL cache bytes, not the attended
-        prefix (measured ~2.5x step-time on a v5e). With per-layer arrays
-        carried through the caller's step loop, the only cache write is the
-        one-position scatter, in place. The continuous batcher
+        prefix (measured ~2.5x step-time on a v5e). The per-layer arrays
+        are carried through the caller's step loop (the batcher's fused
+        burst donates them), and per step and layer the compiled burst
+        does two things to a cache array and no third: ``_cache_write``'s
+        scatter of one ``Dh`` row per (lane, KV head) into the donated
+        buffer itself, and ``_cache_attention``'s two dots reading
+        ``[B, KV, attn_len, Dh]`` of it as a fused operand. No copy on
+        entry or exit, no slice written out: ``tools/burst_hlo_check.py``
+        compiles the burst at the benchmark's shapes and fails on either
+        (``tests/test_burst_hlo.py`` runs it for a described v5e); before
+        ISSUE 26 the same check found 96 cache-sized copies a burst and
+        4.3 GB of scratch beside a 5.6 GB cache. The continuous batcher
         (serving/continuous.py) keeps its persistent cache in this layout.
 
         ``write_pos`` ([B] int32, optional): per-row K/V WRITE position
@@ -586,7 +641,6 @@ class DecoderLM(ServedModel):
         """
         import jax
         import jax.numpy as jnp
-        from jax import lax
 
         cfg = self.cfg
         dt = jnp.dtype(cfg.dtype)
@@ -601,7 +655,6 @@ class DecoderLM(ServedModel):
         blocks = params["blocks"]
         nks: list = []
         nvs: list = []
-        rows = jnp.arange(B)[:, None]
         for l in range(len(ks)):
             p = jax.tree_util.tree_map(lambda a, l=l: a[l], blocks)
             h = _rms_norm(x, p["ln1"].astype(dt), cfg.norm_eps)
@@ -615,15 +668,12 @@ class DecoderLM(ServedModel):
             v = v.reshape(B, W, KVl, cfg.head_dim).transpose(0, 2, 1, 3)
             q = _rope(q, positions, cfg.rope_theta)
             k = _rope(k, positions, cfg.rope_theta)
-            # per-row scatter of the whole window: ck[b,:,pos[b]+j,:] = k[b,:,j,:]
-            ck = ks[l].at[rows, :, positions, :].set(k.transpose(0, 2, 1, 3))
-            cv = vs[l].at[rows, :, positions, :].set(v.transpose(0, 2, 1, 3))
+            # the whole window lands first: ck[b,:,pos[b]+j,:] = k[b,:,j,:]
+            ck = self._cache_write(ks[l], k, positions)
+            cv = self._cache_write(vs[l], v, positions)
             nks.append(self._tp_cache(ck))
             nvs.append(self._tp_cache(cv))
-            kc, vc = ck, cv
-            if attn_len is not None and attn_len < kc.shape[2]:
-                kc = lax.slice_in_dim(kc, 0, attn_len, axis=2)
-                vc = lax.slice_in_dim(vc, 0, attn_len, axis=2)
+            kc, vc = self._cache_read(ck, cv, attn_len)
             # grouped cache read (prefix + in-window causality via the
             # [B, W] bound) — no head-repeated cache copy
             o = self._cache_attention(q, kc, vc, positions, dt)
@@ -692,8 +742,7 @@ class DecoderLM(ServedModel):
             k = _rope(k, positions, cfg.rope_theta)
             ck = lax.dynamic_update_slice(pk, k, (0, 0, start_pos, 0))
             cv = lax.dynamic_update_slice(pv, v, (0, 0, start_pos, 0))
-            gk = lax.slice_in_dim(ck, 0, attn_len, axis=2)
-            gv = lax.slice_in_dim(cv, 0, attn_len, axis=2)
+            gk, gv = self._cache_read(ck, cv, attn_len)
             o = self._cache_attention(q, gk, gv, positions, dt)
             o = o.transpose(0, 2, 1, 3).reshape(B, C, Hl * cfg.head_dim)
             x = x + o @ p["wo"].astype(dt)

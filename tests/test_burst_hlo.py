@@ -1,0 +1,118 @@
+"""The decode burst's compiled program holds no cache-shaped copy or slice
+(ISSUE 26), checked by ``tools/burst_hlo_check.py``: its reading of an HLO
+text on recorded snippets, and the burst itself compiled here, without a
+chip, for a described v5e at the benchmark configurations' widths (two
+layers: the copies are per layer, so two show what twenty-four would).
+
+The topology is described inside a fixture and nowhere else: only one
+process may load the TPU's library, and each xdist worker imports this
+file (see the on-chip-measurement guide, section 2)."""
+
+import functools
+import importlib.util
+import json
+import os
+
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+@functools.lru_cache(maxsize=None)
+def _tool():
+    spec = importlib.util.spec_from_file_location(
+        "burst_hlo_check", os.path.join(ROOT, "tools", "burst_hlo_check.py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+HLO = """HloModule jit_fused_burst, is_scheduled=true, input_output_alias={ {3}: (12, {}, may-alias), {4}: (13, {}, may-alias) }, entry_computation_layout={()}
+
+%fused_computation.1 (param_0.1: bf16[28,8,2048,128]) -> bf16[28,8,640,128] {
+  %param_0.1 = bf16[28,8,2048,128]{3,2,1,0:T(8,128)(2,1)} parameter(0)
+  ROOT %slice.9 = bf16[28,8,640,128]{3,2,1,0:T(8,128)(2,1)} slice(%param_0.1), slice={[0:28], [0:8], [0:640], [0:128]}
+}
+
+%body.2 (p: (bf16[28,8,2048,128])) -> (bf16[28,8,2048,128]) {
+  %p = (bf16[28,8,2048,128]{3,1,2,0:T(8,128)(2,1)}) parameter(0)
+  %gte.1 = bf16[28,8,2048,128]{3,1,2,0:T(8,128)(2,1)} get-tuple-element(%p), index=0
+  %slice.4 = bf16[28,8,640,128]{3,1,2,0:T(8,128)(2,1)S(1)} slice(%gte.1), slice={[0:28], [0:8], [0:640], [0:128]}
+  %fusion.7 = bf16[28,8,640,128]{3,2,1,0:T(8,128)(2,1)} fusion(%gte.1), kind=kLoop, calls=%fused_computation.1
+  ROOT %t = (bf16[28,8,2048,128]{3,1,2,0:T(8,128)(2,1)}) tuple(%gte.1)
+}
+
+%cond.3 (p: (bf16[28,8,2048,128])) -> pred[] {
+  %p = (bf16[28,8,2048,128]{3,1,2,0:T(8,128)(2,1)}) parameter(0)
+  ROOT %c = pred[] constant(true)
+}
+
+ENTRY %main.1 (k0: bf16[28,8,2048,128]) -> bf16[28,8,2048,128] {
+  %k0 = bf16[28,8,2048,128]{3,2,1,0:T(8,128)(2,1)} parameter(0)
+  %slice-start.1 = ((bf16[28,8,2048,128]{3,2,1,0:T(8,128)(2,1)}), bf16[7,8,2048,128]{3,2,1,0:T(8,128)(2,1)S(1)}, s32[]{:S(2)}) slice-start(%k0), slice={[0:7], [0:8], [0:2048], [0:128]}
+  %copy.630 = bf16[28,8,2048,128]{3,1,2,0:T(8,128)(2,1)} copy(%k0)
+  %copy.2 = bf16[28,2048]{1,0:T(8,128)(2,1)} copy(%x)
+  %tup = (bf16[28,8,2048,128]{3,1,2,0:T(8,128)(2,1)}) tuple(%copy.630)
+  %while.1 = (bf16[28,8,2048,128]{3,1,2,0:T(8,128)(2,1)}) while(%tup), condition=%cond.3, body=%body.2
+  %gte.9 = bf16[28,8,2048,128]{3,1,2,0:T(8,128)(2,1)} get-tuple-element(%while.1), index=0
+  ROOT %copy.681 = bf16[28,8,2048,128]{3,2,1,0:T(8,128)(2,1)} copy(%gte.9)
+}
+"""
+
+
+def test_reader_names_cache_shaped_ops_and_where_they_sit():
+    found = _tool().cache_shaped(HLO, 28, 8, (2048, 640), 128)
+    assert sorted((op, res, inside) for op, res, inside, _ in found) == [
+        ("copy", "bf16[28,8,2048,128]", False),
+        ("copy", "bf16[28,8,2048,128]", False),
+        ("slice", "bf16[28,8,640,128]", True),
+        ("slice-start", "bf16[28,8,2048,128]", False),
+    ]
+
+
+def test_reader_leaves_a_fused_slice_and_other_shapes_alone():
+    tool = _tool()
+    clean = "\n".join(
+        line for line in HLO.splitlines()
+        if " copy(%k0)" not in line and "copy(%gte.9)" not in line
+        and "slice-start(" not in line and "%slice.4 =" not in line
+    )
+    # the slice inside fused_computation.1 is an operand of its fusion,
+    # and copy.2 is not the cache's shape
+    assert tool.cache_shaped(clean, 28, 8, (2048, 640), 128) == []
+    # another lane count is another array
+    assert tool.cache_shaped(HLO, 4, 8, (2048, 640), 128) == []
+
+
+def test_reader_counts_the_aliases():
+    assert _tool().alias_count(HLO) == 2
+    assert _tool().alias_count("HloModule m, is_scheduled=true\n") == 0
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler here, or its lock is held
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("config", ["internlm2-1.8b", "mistral-7b-v0.3"])
+def test_burst_compiled_for_v5e_keeps_the_cache_in_place(one_chip, config):
+    with open(os.path.join(ROOT, "benchmark", "configs", config + ".json")) as f:
+        cfg = json.load(f)
+    cfg["name"] = config
+    cfg["num_hidden_layers"] = 2
+    out = _tool().check(cfg, 640, one_chip)
+    assert out["cache_shaped_copies_and_slices"] == {}, out["example"]
+    assert out["input_output_aliases"] >= out["cache_leaves"] == 4
+    assert out["alias_size_in_bytes"] >= out["cache_bytes"]
+    # no scratch of the cache's size: two layers' K and V here
+    assert out["temp_size_in_bytes"] < out["cache_bytes"] / 2
+    assert out["ok"]

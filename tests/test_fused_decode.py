@@ -490,3 +490,156 @@ def test_flight_report_k_collapse_diagnosis():
     # mixed but mostly healthy: below the half-of-polls threshold
     mixed = "\n".join(mod.diagnose(dump([64] * 10 + [8] * 2)))
     assert "DIAGNOSIS: K collapsed" not in mixed
+
+
+# -- the burst executable against the step-at-a-time model --------------------
+# ISSUE 26: one way to write and read the cache (DecoderLM._cache_write /
+# _cache_read). The burst executables over the UNSTACKED per-layer cache
+# must give the greedy tokens and the final cache of eight
+# decode_step_ragged calls over the STACKED cache.
+
+BURST_T = 384  # "full" must lie beyond the 256 bucket
+BURST_K = 8
+
+
+def _burst_case(attn_len):
+    """Four ragged lanes: one whose last write lands at ``attn_len - 1``,
+    one inactive, two mid-cache."""
+    top = attn_len if attn_len is not None else BURST_T
+    pos = np.array([top - BURST_K, 37, 5, top // 2], np.int32)
+    active = np.array([True, False, True, True])
+    cur = np.array([11, 22, 33, 44], np.int32)
+    return pos, active, cur
+
+
+def _burst_state(model, seed=3):
+    """A cache full of seeded noise, stacked and as per-layer lists: a
+    read that strays past a lane's bound, or a write that lands in the
+    wrong place, changes a number."""
+    import jax
+    import jax.numpy as jnp
+
+    stacked = model.init_cache(4, BURST_T)
+    key = jax.random.PRNGKey(seed)
+    stacked = {
+        name: jax.random.normal(k, a.shape, a.dtype) * 0.3
+        for (name, a), k in zip(sorted(stacked.items()),
+                                jax.random.split(key, 2))
+    }
+    n = stacked["k"].shape[0]
+    lists = {name: [jnp.array(stacked[name][l]) for l in range(n)]
+             for name in ("k", "v")}
+    return stacked, lists
+
+
+def _reference_burst(model, params, stacked, cur, pos, active, attn_len,
+                     budgets=None):
+    """BURST_K greedy decode_step_ragged calls. With ``budgets`` (the
+    stop-aware burst) a lane freezes (token, position and cache) once
+    its budget is spent, which is what parking its writes out of bounds
+    must amount to; without, an inactive lane's token reads 0 and its
+    cache is written where it stands, as fused_step does."""
+    import jax
+    import jax.numpy as jnp
+
+    step = jax.jit(model.decode_step_ragged, static_argnums=(4,))
+    cur, pos, active = jnp.asarray(cur), jnp.asarray(pos), jnp.asarray(active)
+    rows = [np.asarray(cur)]
+    freezes = budgets is not None
+    left = jnp.asarray(budgets) if freezes else jnp.full_like(pos, BURST_K)
+    for _ in range(BURST_K):
+        alive = active & (left > 0)
+        logits, new = step(params, stacked, cur[:, None], pos, attn_len)
+        nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        if freezes:
+            keep = alive[None, :, None, None, None]
+            new = {n: jnp.where(keep, new[n], stacked[n]) for n in new}
+        stacked = new
+        cur = jnp.where(alive, nxt, cur if freezes else 0)
+        rows.append(np.asarray(jnp.where(alive, cur, 0)))
+        left = left - alive.astype(jnp.int32)
+        pos = jnp.where(alive, pos + 1, pos)
+    return np.stack(rows), np.asarray(pos), stacked
+
+
+def _run_burst(b, kind, params, lists, cur, pos, active, budgets, attn_len):
+    """``_burst_fn`` or (``stop_burst``) ``_fused_burst_fn`` over the
+    donated per-layer lists, greedy: ``(toks, pos, cache, counts, done)``."""
+    import jax
+    import jax.numpy as jnp
+
+    args = (params, lists, jnp.asarray(cur), jnp.asarray(pos),
+            jnp.asarray(active), jnp.zeros((4,), jnp.float32),
+            jax.vmap(jax.random.PRNGKey)(jnp.arange(4)))
+    if kind == "burst":
+        toks, _, pos, cache, _ = b._burst_fn(*args, BURST_K, attn_len)
+        return toks, pos, cache, None, None
+    toks, counts, done, _, pos, cache, _, _ = b._fused_burst_fn(
+        *args, jnp.full((4,), -1, jnp.int32), jnp.asarray(budgets),
+        BURST_K, attn_len)
+    return toks, pos, cache, counts, done
+
+
+@pytest.mark.parametrize("attn_len", [128, 256, None])
+@pytest.mark.parametrize("kind", ["burst", "stop_burst"])
+def test_burst_executable_matches_stepwise_model(model_and_params, kind,
+                                                 attn_len):
+    model, params = model_and_params
+    b = make_batcher(model_and_params, slots=1, max_seq=16)
+    try:
+        pos0, active, cur0 = _burst_case(attn_len)
+        stacked, lists = _burst_state(model)
+        # a lane of its own runs out of budget after 3 steps: its writes
+        # park out of bounds and must be dropped, not clamped onto T - 1
+        budgets = np.array([BURST_K, BURST_K, 3, BURST_K], np.int32)
+        toks, pos, cache, counts, done = _run_burst(
+            b, kind, params, lists, cur0, pos0, active, budgets, attn_len)
+        want_toks, want_pos, want = _reference_burst(
+            model, params, stacked, cur0, pos0, active, attn_len,
+            budgets=budgets if kind == "stop_burst" else None)
+        if kind == "stop_burst":
+            np.testing.assert_array_equal(np.asarray(counts), [8, 0, 3, 8])
+            assert np.asarray(done).all()
+        np.testing.assert_array_equal(np.asarray(toks), want_toks)
+        np.testing.assert_array_equal(np.asarray(pos), want_pos)
+        for name in ("k", "v"):
+            for l, layer in enumerate(cache[name]):
+                np.testing.assert_array_equal(
+                    np.asarray(layer), np.asarray(want[name][l]),
+                    err_msg=f"{name}[{l}]")
+        # the lane at the bucket's edge did write its last position
+        top = (attn_len or BURST_T) - 1
+        assert not np.array_equal(
+            np.asarray(cache["k"][0][0, :, top]),
+            np.asarray(stacked["k"][0, 0, :, top]))
+    finally:
+        b.close()
+
+
+@pytest.mark.parametrize("kind", ["burst", "stop_burst"])
+def test_burst_consumes_donated_cache(model_and_params, kind):
+    """The cache leaves are donated: the burst consumes them and, where
+    the platform says where a buffer lives, hands the same buffers back."""
+    import jax
+
+    model, params = model_and_params
+    b = make_batcher(model_and_params, slots=1, max_seq=16)
+    try:
+        pos0, active, cur0 = _burst_case(128)
+        _, lists = _burst_state(model)
+        leaves = lists["k"] + lists["v"]
+        jax.block_until_ready(leaves)
+        try:
+            before = [a.unsafe_buffer_pointer() for a in leaves]
+        except Exception:  # a platform that does not say
+            before = None
+        cache = _run_burst(b, kind, params, lists, cur0, pos0, active,
+                           np.full((4,), BURST_K, np.int32), 128)[2]
+        jax.block_until_ready(cache)
+        assert all(a.is_deleted() for a in leaves)
+        if before is not None:
+            after = [a.unsafe_buffer_pointer()
+                     for a in cache["k"] + cache["v"]]
+            assert sorted(after) == sorted(before)
+    finally:
+        b.close()

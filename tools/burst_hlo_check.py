@@ -1,0 +1,224 @@
+#!/usr/bin/env python3
+"""Structural check of the decode burst's compiled program: does the KV
+cache go through ``jit_fused_burst`` in place?
+
+Compiles ``ContinuousBatcher._burst_fn`` (``steps_per_poll`` fused decode
+steps) from shapes alone at a benchmark configuration file's sizes
+(``server.slots`` lanes x ``server.max_seq`` positions, every layer) and
+reads the optimised HLO:
+
+* a ``copy`` / ``copy-start`` / ``slice`` / ``slice-start`` instruction of
+  its own (not inside a fusion) whose result is ``[n <= lanes, KV, T or
+  attn_len, Dh]`` is a cache-shaped array being written out: FAIL;
+* ``temp_size_in_bytes`` beside the cache's bytes (a burst that copies the
+  cache reserves scratch as large as the cache);
+* the input-output aliases (one per donated cache leaf, or donation bought
+  nothing).
+
+    python tools/burst_hlo_check.py                      # on the chip
+    python tools/burst_hlo_check.py --described v5e:2x2  # no chip: the TPU
+        compiler targets a described device (JAX_PLATFORMS=cpu); the HLO
+        is the chip's, a time is not
+
+Exit 0 clean, 1 a cache-shaped copy or slice or a lost alias, 3 skipped
+(no TPU and no ``--described``). A skip is not a pass.
+"""
+
+from __future__ import annotations
+
+import argparse
+import json
+import os
+import re
+import sys
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+
+CONFIGS = ("benchmark/configs/internlm2-1.8b.json",
+           "benchmark/configs/mistral-7b-v0.3.json")
+ATTN_LENS = (640, 1280)
+OFFENDERS = ("copy", "copy-start", "slice", "slice-start")
+_INSTR_RE = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = (.*?) ([\w\-]+)\(")
+_CALLED_RE = re.compile(r"(?:body|condition|to_apply|calls)=%?([\w.\-]+)")
+
+
+def computations(hlo: str) -> dict:
+    """``{name: [instruction lines]}``, and the entry's name under ``""``."""
+    comps: dict = {}
+    cur = None
+    for line in hlo.splitlines():
+        if not line.startswith(" ") and line.rstrip().endswith("{"):
+            m = re.match(r"^(ENTRY )?%?([\w.\-]+)", line)
+            if m:
+                cur = m.group(2)
+                comps[cur] = []
+                if m.group(1):
+                    comps[""] = cur
+            continue
+        if line.startswith("}"):
+            cur = None
+        elif cur is not None:
+            comps[cur].append(line)
+    return comps
+
+
+def scheduled(comps: dict) -> dict:
+    """The computations whose instructions run as ops of their own: the
+    entry and, transitively, loop bodies and conditions and called
+    computations. What a ``fusion`` calls is the inside of one op.
+    ``{name: inside_while}``."""
+    out = {comps[""]: False}
+    todo = [comps[""]]
+    while todo:
+        name = todo.pop()
+        for line in comps[name]:
+            m = _INSTR_RE.match(line)
+            if not m or m.group(3) == "fusion":
+                continue
+            loop = m.group(3) == "while"
+            for callee in _CALLED_RE.findall(line):
+                if callee in comps and callee not in out:
+                    out[callee] = out[name] or loop
+                    todo.append(callee)
+    return out
+
+
+def cache_shaped(hlo: str, lanes: int, kv: int, lengths, dh: int) -> list:
+    """The offending instructions: ``[op, result, inside_while, line]``."""
+    shape = re.compile(
+        r"^\(*bf16\[(\d+),%d,(%s),%d\]" % (kv, "|".join(map(str, lengths)), dh)
+    )
+    comps = computations(hlo)
+    found = []
+    for name, inside in scheduled(comps).items():
+        for line in comps[name]:
+            m = _INSTR_RE.match(line)
+            if not m or m.group(3) not in OFFENDERS:
+                continue
+            res = shape.match(m.group(2))
+            if res and int(res.group(1)) <= lanes:
+                found.append([m.group(3), m.group(2).split("{")[0].lstrip("("),
+                              inside, line.strip()[:160]])
+    return found
+
+
+def alias_count(hlo: str) -> int:
+    """Entries of the module's ``input_output_alias={ {3}: (12, {}, may-alias), ...}``."""
+    header = hlo.split("\n", 1)[0]
+    return len(re.findall(r"\{\d+\}: \(\d+, \{\}", header))
+
+
+def compile_burst(cfg: dict, attn_len: int, device_sharding):
+    """``_burst_fn`` compiled from shapes at the configuration's sizes:
+    nothing is allocated, so this needs no device memory."""
+    import jax
+    import jax.numpy as jnp
+
+    from benchmark.manifest import decoder_kwargs
+    from seldon_core_tpu.models.llm import DecoderLM
+    from seldon_core_tpu.serving.continuous import ContinuousBatcher
+
+    kwargs = decoder_kwargs(cfg, 0)
+    kwargs.pop("seed")
+    model = DecoderLM(**kwargs)
+    mc = model.cfg
+    lanes, T = cfg["server"]["slots"], cfg["server"]["max_seq"]
+    # the executables are closures of the constructor; its own device state
+    # is one lane of 128 positions, and no parameter is touched
+    batcher = ContinuousBatcher(model, {}, slots=1, max_seq=128)
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=device_sharding)
+
+    dt = jnp.dtype(mc.dtype)
+    params = jax.tree_util.tree_map(
+        lambda a: sds(a.shape, dt), jax.eval_shape(model.init_params, 0)
+    )
+    layer = sds((lanes, mc.n_kv_heads, T, mc.head_dim), dt)
+    cache = {"k": [layer] * mc.n_layers, "v": [layer] * mc.n_layers}
+    lane_i32 = sds((lanes,), jnp.int32)
+    compiled = batcher._burst_fn.lower(
+        params, cache, lane_i32, lane_i32, sds((lanes,), jnp.bool_),
+        sds((lanes,), jnp.float32), sds((lanes, 2), jnp.uint32),
+        batcher._k, attn_len,
+    ).compile()
+    cache_bytes = model.kv_bytes_per_token() * lanes * T
+    return compiled, (lanes, mc.n_kv_heads, T, mc.head_dim), cache_bytes
+
+
+def check(cfg: dict, attn_len: int, device_sharding, hlo_dir=None) -> dict:
+    compiled, (lanes, kv, T, dh), cache_bytes = compile_burst(
+        cfg, attn_len, device_sharding)
+    hlo = compiled.as_text()
+    if hlo_dir:
+        os.makedirs(hlo_dir, exist_ok=True)
+        with open(os.path.join(hlo_dir, f"{cfg['name']}.{attn_len}.hlo.txt"), "w") as f:
+            f.write(hlo)
+    mem = compiled.memory_analysis()
+    found = cache_shaped(hlo, lanes, kv, (T, attn_len), dh)
+    kinds: dict = {}
+    for op, result, inside, _line in found:
+        key = f"{op} {result} {'inside' if inside else 'outside'} the while"
+        kinds[key] = kinds.get(key, 0) + 1
+    leaves = 2 * cfg["num_hidden_layers"]
+    aliases = alias_count(hlo)
+    return {
+        "lanes": lanes, "attn_len": attn_len,
+        "cache_shaped_copies_and_slices": kinds,
+        "example": found[0][3] if found else None,
+        "cache_bytes": cache_bytes,
+        "temp_size_in_bytes": mem.temp_size_in_bytes,
+        "alias_size_in_bytes": mem.alias_size_in_bytes,
+        "input_output_aliases": aliases,
+        "cache_leaves": leaves,
+        "ok": not found and aliases >= leaves
+        and mem.alias_size_in_bytes >= cache_bytes,
+    }
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--config", nargs="*", default=list(CONFIGS))
+    ap.add_argument("--attn-len", nargs="*", type=int, default=list(ATTN_LENS))
+    ap.add_argument("--described", metavar="TOPOLOGY",
+                    help="compile for a described TPU (e.g. v5e:2x2), no chip")
+    ap.add_argument("--hlo-dir", help="keep each optimised HLO here")
+    args = ap.parse_args(argv)
+
+    if args.described:
+        os.environ.setdefault("TPU_LOG_DIR", "disabled")
+        os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    import jax
+    from jax.sharding import SingleDeviceSharding
+
+    if args.described:
+        from jax.experimental import topologies
+
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name=args.described)
+        device = topo.devices[0]
+    elif jax.default_backend() == "tpu":
+        device = jax.devices()[0]
+    else:
+        print(f"burst_hlo_check: SKIPPED, backend is {jax.default_backend()!r}"
+              " (run on the chip, or pass --described v5e:2x2)")
+        return 3
+    print(f"burst_hlo_check: compiling for {device.device_kind}"
+          f"{' (described, not attached)' if args.described else ''}")
+    ok = True
+    for path in args.config:
+        with open(os.path.join(ROOT, path)) as f:
+            cfg = json.load(f)
+        cfg["name"] = os.path.basename(path)[:-len(".json")]
+        for attn_len in args.attn_len:
+            out = check(cfg, attn_len, SingleDeviceSharding(device),
+                        args.hlo_dir)
+            print(json.dumps({"config": cfg["name"], **out}))
+            ok = ok and out["ok"]
+    print("burst_hlo_check:", "OK" if ok else "FAIL")
+    return 0 if ok else 1
+
+
+if __name__ == "__main__":
+    sys.exit(main())
