@@ -15,6 +15,14 @@ def loop(run):
     return (report(run) or {}).get("loop")
 
 
+def counters(run):
+    """Every numeric entry of the batcher's ``stats``, differenced over
+    the capture: what an architecture's cost functions are given (the
+    experts a step touched are known only from what was routed). Empty
+    without a report."""
+    return (report(run) or {}).get("counters") or {}
+
+
 def requests(run):
     """The request timelines that belong to the run. Where the capture lies
     after the measured window (``--trace 2``) the ring still holds every

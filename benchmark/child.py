@@ -5,7 +5,8 @@ predictor spec the parent wrote. A second thread answers one-line commands
 from the parent on stdin with one JSON line each on stdout (the engine's
 log goes to stderr):
 
-    reference        compare the served model with the plain reference
+    reference        the architecture module's ``compare_served``: the
+                     served model against its plain reference
     snapshot         the batcher's counters, SLO samples since the last
                      snapshot, and the device's memory statistics
     trace_start DIR  the program's ``tracing.start_capture(DIR)``: the JAX
@@ -23,6 +24,7 @@ from __future__ import annotations
 
 import argparse
 import gc
+import importlib
 import json
 import os
 import sys
@@ -49,8 +51,9 @@ def find_server():
 
 
 class Control:
-    def __init__(self, seed: int):
+    def __init__(self, seed: int, arch):
         self.seed = seed
+        self.arch = arch
         self.slo_seen = 0
 
     def snapshot(self) -> dict:
@@ -76,13 +79,12 @@ class Control:
         }
 
     def reference(self) -> dict:
-        from benchmark.reference.decoder import compare_served
-
         server = find_server()
         if server is None:
             return {"error": "no loaded GenerateServer in this process"}
         t0 = time.monotonic()
-        out = compare_served(server._model, server.batcher.params, self.seed)
+        out = self.arch.compare_served(server._model, server.batcher.params,
+                                       self.seed)
         out["seconds"] = time.monotonic() - t0
         return out
 
@@ -119,6 +121,7 @@ def main(argv=None) -> int:
     parser.add_argument("--chips", type=int, required=True)
     parser.add_argument("--seed", type=int, required=True)
     parser.add_argument("--cache-dir", required=True)
+    parser.add_argument("--architecture", required=True)
     args = parser.parse_args(argv)
 
     import jax
@@ -144,10 +147,13 @@ def main(argv=None) -> int:
         return 3
     say({"device": device})
 
-    from benchmark import weights
-
-    weights.register()
-    control = threading.Thread(target=Control(args.seed).serve, daemon=True)
+    # by the dotted name the parent found it under (``manifest.architecture``),
+    # and nothing else before the engine starts: what this process does here
+    # shows in ``warm_s`` (PERF.md, PR 27)
+    arch = importlib.import_module(args.architecture)
+    arch.register()
+    control = threading.Thread(target=Control(args.seed, arch).serve,
+                               daemon=True)
     control.start()
 
     from seldon_core_tpu import engine_main

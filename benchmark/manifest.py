@@ -5,13 +5,22 @@ configuration, one traffic mix or one per-layer metric is a file of its
 own, found by name, so a later PR adds a cell by adding files and manifest
 entries and edits nothing here:
 
-    <file of the config's entry>              sizes, server settings
+    <file of the config's entry>              sizes, server settings, and
+                                              "architecture": <architecture>
+    <path>/architectures/<architecture>.py    the served family, its kwargs,
+                                              its comparison with the plain
+                                              reference, its cost arithmetic
     <path>/traffic/<traffic>.json             the mix the generator reads
     <path>/layer_metrics/<metric>.py          a reader: read(run) -> value
+
+Nothing outside an architecture's module knows a key of a published
+config but ``vocab_size`` (the traffic draws token ids from it) and the
+``server`` block (slots, max_seq).
 """
 
 from __future__ import annotations
 
+import importlib
 import importlib.util
 import json
 import os
@@ -120,7 +129,14 @@ def _read_json(path: str) -> dict:
 def config(root: str, manifest: dict, name: str) -> dict:
     for c in manifest["configs"]:
         if c["name"] == name:
-            cfg = _read_json(os.path.join(root, c["file"]))
+            path = os.path.join(root, c["file"])
+            cfg = _read_json(path)
+            if not NAME_RE.match(str(cfg.get("architecture", ""))):
+                raise ManifestError(
+                    f'{path}: no "architecture" key: it names the module '
+                    "<path>/architectures/<architecture>.py that holds this "
+                    "configuration's served family, reference and cost "
+                    "arithmetic; no default is taken")
             cfg["name"] = name
             return cfg
     raise ManifestError(f"no config {name!r}")
@@ -161,22 +177,61 @@ def layer_reader(root: str, manifest: dict, metric: str):
     return module.read
 
 
+# what an architecture's module must hold, and run.py, child.py and the
+# roofline readers call
+ARCHITECTURE_API = ("FAMILY", "register", "model_kwargs", "compare_served",
+                    "decode_step_bytes", "prefill_flops", "rehearsal")
+
+
+def architecture(root: str, manifest: dict, name: str):
+    """The module ``architectures/<name>.py`` of a configuration's
+    ``"architecture"``, loaded once a process:
+
+    ``FAMILY``, ``register()``      the served family, through the program's
+                                    own ``models.register``
+    ``model_kwargs(cfg, seed)``     the ``config`` of ``jax_config.json``
+    ``compare_served(model, params, seed)``
+                                    the served model against its plain
+                                    reference; replies ``ok``, ``ratio``,
+                                    ``tolerance``, ``finite``, ...
+    ``decode_step_bytes(cfg, live_positions, counters)``
+    ``prefill_flops(cfg, padded_tokens, sequences, counters)``
+                                    what a step must read and a prefill must
+                                    compute; ``counters`` as
+                                    ``capture.counters`` gives them
+    ``rehearsal(cfg)``              the tiny sizes of ``--rehearse-cpu``
+
+    The parent loads it too and never imports jax: the module imports jax
+    and the program inside its functions.
+
+    It is imported by the dotted name of its path under ``root`` (which is
+    on ``sys.path`` in the parent and in the child), so that the program's
+    ``models.register`` can name the served class. The parent calls this;
+    the child is given ``module.__name__`` and imports that and nothing
+    else: whatever more the child ran before the engine started (this
+    function, ``load``, a few thousand objects made and dropped) made every
+    lowering of the decode burst an eighth slower there (5.4 -> 6.1 s each,
+    ``warm_s`` +9 s; my chip runs, PR 27)."""
+    path = _find(root, manifest, "architectures", name + ".py")
+    dotted = os.path.splitext(os.path.relpath(path, root))[0].replace(os.sep, ".")
+    try:
+        module = importlib.import_module(dotted)
+    except ImportError as e:
+        raise ManifestError(f"{path}: import {dotted}: {e}") from e
+    if not os.path.samefile(module.__file__, path):
+        raise ManifestError(
+            f"{dotted} is {module.__file__}, not {path}: another copy of the "
+            "benchmark comes first on sys.path")
+    lacks = [a for a in ARCHITECTURE_API if not hasattr(module, a)]
+    if lacks:
+        raise ManifestError(f"{path}: lacks {lacks}")
+    return module
+
+
 def decoder_kwargs(cfg: dict, seed: int) -> dict:
-    """The published config's keys as ``DecoderLM`` takes them."""
-    if cfg["hidden_size"] != cfg["num_attention_heads"] * cfg["head_dim"]:
-        raise ManifestError(f"{cfg['name']}: hidden != heads x head_dim")
-    return {
-        "vocab_size": cfg["vocab_size"],
-        "d_model": cfg["hidden_size"],
-        "n_layers": cfg["num_hidden_layers"],
-        "n_heads": cfg["num_attention_heads"],
-        "n_kv_heads": cfg["num_key_value_heads"],
-        "d_ff": cfg["intermediate_size"],
-        "max_seq": cfg["server"]["max_seq"],
-        "rope_theta": float(cfg["rope_theta"]),
-        "norm_eps": float(cfg["rms_norm_eps"]),
-        "dtype": cfg["torch_dtype"],
-        "residual_scale": cfg["weights"]["residual_scale"],
-        # PRNGKey takes 32 bits; the driver's seeds are larger
-        "seed": seed % (2**31 - 1),
-    }
+    """The ``decoder`` module's ``model_kwargs`` under the name that
+    ``tools/burst_hlo_check.py`` imports: the tool lies outside the
+    benchmark's paths, where the PR that moved the function may not edit.
+    Goes once the tool asks ``architecture(...)`` itself (PERF.md, section 7)."""
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    return architecture(root, load(root), "decoder").model_kwargs(cfg, seed)

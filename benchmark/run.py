@@ -4,7 +4,8 @@
     python3 benchmark/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1|2>
 
 This parent never imports jax. It writes the cell's model directory and
-predictor spec from the configuration file, starts ``benchmark/child.py``
+predictor spec from the configuration file and the architecture module
+that file names (``benchmark/architectures/``), starts ``benchmark/child.py``
 (the program's own ``engine_main``, which owns the chip), has it compared
 with the plain reference, offers the cell's traffic over the wire, and
 prints as the last line of stdout one JSON object: ``correct``,
@@ -59,11 +60,6 @@ FIRST_TOKEN_WAIT_S = 3.0
 # a compile this long once the load runs means a request waited on an
 # executable the warm-up should have covered
 SLOW_COMPILE_S = 1.0
-REHEARSAL_SIZES = {
-    "hidden_size": 256, "num_attention_heads": 2, "num_key_value_heads": 1,
-    "head_dim": 128, "intermediate_size": 512, "num_hidden_layers": 2,
-    "vocab_size": 1024,
-}
 READY_RE = re.compile(
     r"generateserver: .* ready \(.*\) platform=(?P<platform>\S+) "
     r"device_kind='(?P<kind>[^']*)' .* load_s=(?P<load>[\d.]+) "
@@ -159,11 +155,12 @@ def http_status(port: int, path: str) -> int:
         return 0
 
 
-def write_spec(run_dir: str, cfg: dict, mix: dict, seed: int) -> str:
-    """Model dir by the normal ``jax_config.json`` route and a
-    GENERATE_SERVER predictor spec: the configuration's server settings,
-    this cell's warm-up shapes, every other knob at the program's default."""
-    from benchmark import manifest, traffic, weights
+def write_spec(run_dir: str, arch, cfg: dict, mix: dict, seed: int) -> str:
+    """Model dir by the normal ``jax_config.json`` route, family and kwargs
+    from the configuration's architecture module, and a GENERATE_SERVER
+    predictor spec: the configuration's server settings, this cell's
+    warm-up shapes, every other knob at the program's default."""
+    from benchmark import traffic
     from seldon_core_tpu.graph.spec import (
         PredictorSpec, default_predictor, validate_predictor,
     )
@@ -171,8 +168,8 @@ def write_spec(run_dir: str, cfg: dict, mix: dict, seed: int) -> str:
     model_dir = os.path.join(run_dir, "model")
     os.makedirs(model_dir, exist_ok=True)
     with open(os.path.join(model_dir, "jax_config.json"), "w") as f:
-        json.dump({"family": weights.FAMILY,
-                   "config": manifest.decoder_kwargs(cfg, seed)}, f)
+        json.dump({"family": arch.FAMILY,
+                   "config": arch.model_kwargs(cfg, seed)}, f)
     settings = dict(cfg["server"])
     settings["warmup_prompt_lens"] = ",".join(
         str(n) for n in traffic.prompt_lens(mix))
@@ -271,9 +268,12 @@ def load_cell(workload: str, rehearse: bool) -> tuple:
     cell = manifest.cell(man, workload)
     cfg = manifest.config(ROOT, man, cell["config"])
     mix = manifest.traffic(ROOT, man, cell["traffic"])
+    # found here, with the other files of the cell: a name with no module
+    # is refused before anything is started
+    arch = manifest.architecture(ROOT, man, cfg["architecture"])
     if rehearse:
         log("REHEARSAL on the CPU at a tiny size: proves nothing about the chip")
-        cfg.update(REHEARSAL_SIZES)
+        cfg.update(arch.rehearsal(cfg))
         cfg["server"] = dict(cfg["server"], slots=min(4, cfg["server"]["slots"]))
     return man, cell, cfg, mix
 
@@ -294,11 +294,13 @@ class Engine:
         self.env.setdefault("TPU_LOG_DIR", "disabled")
         self.env["PYTHONPATH"] = ROOT + os.pathsep + self.env.get("PYTHONPATH", "")
         self.port = free_port()
-        spec_path = write_spec(run_dir, cfg, mix, seed)
+        self.arch = manifest.architecture(ROOT, man, cfg["architecture"])
+        spec_path = write_spec(run_dir, self.arch, cfg, mix, seed)
         self.child = Child(
             [sys.executable, os.path.join(HERE, "child.py"), "--spec", spec_path,
              "--http-port", str(self.port), "--chips", str(cell["chips"]),
-             "--seed", str(seed), "--cache-dir", cache_dir],
+             "--seed", str(seed), "--cache-dir", cache_dir,
+             "--architecture", self.arch.__name__],
             self.env, os.path.join(run_dir, "engine.log"),
         )
         try:
@@ -505,7 +507,8 @@ def main(argv=None) -> int:
                 f"{endtoend.tokens_in(records, t_open, t_close)}")
         if args.trace:
             run = {
-                "cell": cell, "config": cfg, "traffic": mix, "peaks": peaks,
+                "cell": cell, "config": cfg, "architecture": engine.arch,
+                "traffic": mix, "peaks": peaks,
                 "records": records, "window": (t_open, t_close),
                 "ready": ready, "counters": (snap_open, snap_close),
                 "slo": slo, "trace": trace,

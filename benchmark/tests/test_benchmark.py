@@ -22,7 +22,7 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(os.path.dirname(HERE))
 sys.path.insert(0, ROOT)
 
-from benchmark import costs, endtoend, manifest, trace, traffic  # noqa: E402
+from benchmark import endtoend, manifest, trace, traffic  # noqa: E402
 from benchmark.client import ABORTED, OK, Load, Record  # noqa: E402
 
 RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
@@ -33,13 +33,22 @@ def man():
     return manifest.load(ROOT)
 
 
+@pytest.fixture(scope="module")
+def decoder(man):
+    """The ``decoder`` architecture's module, found as a run finds it."""
+    return manifest.architecture(ROOT, man, "decoder")
+
+
 # -- the manifest ------------------------------------------------------------
 
 def test_manifest_is_valid_and_every_file_is_found(man):
     for cell in man["workloads"]:
         cfg = manifest.config(ROOT, man, cell["config"])
         mix = manifest.traffic(ROOT, man, cell["traffic"])
-        assert manifest.decoder_kwargs(cfg, 2**31 + 5)["seed"] < 2**31
+        arch = manifest.architecture(ROOT, man, cfg["architecture"])
+        assert arch is manifest.architecture(ROOT, man, cfg["architecture"])
+        assert arch.model_kwargs(cfg, 2**31 + 5)["seed"] < 2**31
+        assert set(arch.rehearsal(cfg)) <= set(cfg)
         assert traffic.cycle(mix)
         for group in ("end_to_end", "per_layer"):
             assert manifest.metrics_of(man, group, cell["name"])
@@ -47,20 +56,41 @@ def test_manifest_is_valid_and_every_file_is_found(man):
             assert callable(manifest.layer_reader(ROOT, man, m["name"]))
 
 
+def _config_file(tmp_path, **change):
+    """A copy of the first configuration's file with ``change`` applied
+    (``None`` drops the key), for a manifest entry to point at."""
+    with open(os.path.join(ROOT, "benchmark", "configs", "internlm2-1.8b.json")) as f:
+        cfg = json.load(f)
+    for key, value in change.items():
+        cfg.pop(key) if value is None else cfg.update({key: value})
+    path = tmp_path / "changed.json"
+    path.write_text(json.dumps(cfg))
+    return str(path)
+
+
 @pytest.mark.parametrize("mutate, what", [
-    (lambda m: m["workloads"][0].update(name="has space"), "bad name"),
-    (lambda m: m["end_to_end"][0].update(unit="tokens per second"), "bad unit"),
-    (lambda m: m["end_to_end"][0].update(bound=0.2), "bound"),
-    (lambda m: m["per_layer"][0].update(moves="nothing"), "moves"),
-    (lambda m: m["workloads"][0].update(config="absent"), "no config"),
-    (lambda m: m.update(extra=1), "keys"),
-    (lambda m: m["end_to_end"].pop(), "setup_s"),
+    (lambda m, _t: m["workloads"][0].update(name="has space"), "bad name"),
+    (lambda m, _t: m["end_to_end"][0].update(unit="tokens per second"), "bad unit"),
+    (lambda m, _t: m["end_to_end"][0].update(bound=0.2), "bound"),
+    (lambda m, _t: m["per_layer"][0].update(moves="nothing"), "moves"),
+    (lambda m, _t: m["workloads"][0].update(config="absent"), "no config"),
+    (lambda m, _t: m.update(extra=1), "keys"),
+    (lambda m, _t: m["end_to_end"].pop(), "setup_s"),
+    # a configuration names its architecture, and the name has a file: the
+    # file that was looked for is in the message
+    (lambda m, t: m["configs"][0].update(file=_config_file(t, architecture=None)),
+     r'changed\.json: no "architecture" key'),
+    (lambda m, t: m["configs"][0].update(file=_config_file(t, architecture="absent")),
+     r"no architectures/absent\.py under \['benchmark'\]"),
 ])
-def test_manifest_refuses(man, mutate, what):
+def test_manifest_refuses(man, tmp_path, mutate, what):
     bad = copy.deepcopy(man)
-    mutate(bad)
+    mutate(bad, tmp_path)
     with pytest.raises(manifest.ManifestError, match=what):
         manifest.validate(bad)
+        for c in bad["configs"]:
+            cfg = manifest.config(ROOT, bad, c["name"])
+            manifest.architecture(ROOT, bad, cfg["architecture"])
 
 
 def test_unknown_device_has_no_peaks(man):
@@ -282,32 +312,32 @@ def test_reduce_on_the_recorded_chip_trace():
 
 # -- costs -----------------------------------------------------------------------------
 
-def test_costs_against_the_configs_own_arithmetic(man):
+def test_costs_against_the_configs_own_arithmetic(man, decoder):
+    costs = decoder
     mistral = manifest.config(ROOT, man, "mistral-7b-v0.3")
     assert costs.layer_matmul_params(mistral) == 218_103_808     # 218.1 M
     assert costs.kv_bytes_per_position(mistral) == mistral["num_hidden_layers"] * 4096
     intern = manifest.config(ROOT, man, "internlm2-1.8b")
     assert costs.kv_bytes_per_position(intern) == 98_304
-    w0 = costs.decode_step_bytes(intern, 0)
+    w0 = costs.decode_step_bytes(intern, 0, {})
     assert w0 == pytest.approx(2 * (1.889e9 - 92544 * 2048), rel=0.01)
-    assert costs.decode_step_bytes(intern, 1000) - w0 == 1000 * 98_304
+    assert costs.decode_step_bytes(intern, 1000, {}) - w0 == 1000 * 98_304
     # two prompts of one length: the bound on the squares is exact
-    one = costs.prefill_flops(mistral, 1792, 1)
-    assert costs.prefill_flops(mistral, 2 * 1792, 2) == pytest.approx(2 * one)
+    one = costs.prefill_flops(mistral, 1792, 1, {})
+    assert costs.prefill_flops(mistral, 2 * 1792, 2, {}) == pytest.approx(2 * one)
     # unequal lengths are counted low, never high
-    assert costs.prefill_flops(mistral, 1792 + 2048, 2) < one + costs.prefill_flops(
-        mistral, 2048, 1)
+    assert costs.prefill_flops(mistral, 1792 + 2048, 2, {}) < one + costs.prefill_flops(
+        mistral, 2048, 1, {})
 
 
 # -- the plain reference against the served model ----------------------------------------
 
-def test_reference_agrees_with_decoderlm_at_a_tiny_size():
+def test_reference_agrees_with_decoderlm_at_a_tiny_size(decoder):
     import jax
 
-    from benchmark.reference import decoder
-    from benchmark.weights import SeededDecoderLM
+    from benchmark.reference import decoder as reference
 
-    model = SeededDecoderLM(vocab_size=512, d_model=256, n_layers=2, n_heads=2,
+    model = decoder.SeededDecoderLM(vocab_size=512, d_model=256, n_layers=2, n_heads=2,
                             n_kv_heads=1, d_ff=512, max_seq=256, rope_theta=1e6,
                             norm_eps=1e-5, dtype="bfloat16", residual_scale=0.05)
     params = model.init_params(7)
@@ -318,9 +348,9 @@ def test_reference_agrees_with_decoderlm_at_a_tiny_size():
     assert out["ok"] and out["ratio"] < decoder.TOLERANCE, out
     # a model that differs in one norm weight must not pass
     params["ln_f"] = params["ln_f"] * 1.5
-    ref = decoder.logits(params, model.cfg, list(range(8)), [7])
+    ref = reference.logits(params, model.cfg, list(range(8)), [7])
     params["ln_f"] = params["ln_f"] / 1.5
-    good = decoder.logits(params, model.cfg, list(range(8)), [7])
+    good = reference.logits(params, model.cfg, list(range(8)), [7])
     assert abs(ref - good).max() / good.std() > decoder.TOLERANCE
 
 
@@ -329,8 +359,12 @@ def test_reference_agrees_with_decoderlm_at_a_tiny_size():
 def test_the_parent_never_imports_jax():
     code = ("import sys; sys.path.insert(0, %r); import benchmark.run, "
             "benchmark.client, benchmark.traffic, benchmark.endtoend, "
-            "benchmark.manifest, benchmark.costs, benchmark.trace, benchmark.sweep; "
-            "assert 'jax' not in sys.modules, 'jax imported'" % ROOT)
+            "benchmark.manifest, benchmark.capture, benchmark.trace, "
+            "benchmark.sweep; from benchmark import manifest as m; "
+            "man = m.load(%r); [m.architecture(%r, man, m.config(%r, man, "
+            "c['name'])['architecture']) for c in man['configs']]; "
+            "assert 'jax' not in sys.modules, 'jax imported'"
+            % (ROOT, ROOT, ROOT, ROOT))
     done = subprocess.run([sys.executable, "-c", code], capture_output=True,
                           text=True, timeout=60)
     assert done.returncode == 0, done.stderr
@@ -339,7 +373,7 @@ def test_the_parent_never_imports_jax():
 def test_no_result_outside_the_repo(tmp_path):
     shutil.copy(os.path.join(ROOT, "BENCHMARK.json"), tmp_path)
     shutil.copytree(os.path.join(ROOT, "benchmark"), tmp_path / "benchmark",
-                    ignore=shutil.ignore_patterns("_runs", "_cache", "__pycache__"))
+                    ignore=NOT_COMMITTED)
     done = subprocess.run(
         [sys.executable, "benchmark/run.py", "--workload", "internlm2-1.8b.chat",
          "--seed", "1", "--seconds", "1", "--trace", "0"],
@@ -347,22 +381,49 @@ def test_no_result_outside_the_repo(tmp_path):
     assert done.returncode != 0 and done.stdout.strip() == ""
 
 
+NOT_COMMITTED = shutil.ignore_patterns("_runs", "_cache", "__pycache__")
+TINY_MIX = {"loop": "closed", "clients": {"per_slot": 1, "extra": 1}, "ramp_s": 1,
+            "drain_s": 0, "classes": [[20, 8, 1], [40, 16, 1]], "temperature": 0.0}
+
+
+def _copy_of_the_benchmark(tmp_path):
+    """``(benchmark/ of a copy beside the program, the manifest as a dict)``:
+    what a later PR starts from."""
+    shutil.copytree(os.path.join(ROOT, "benchmark"), tmp_path / "benchmark",
+                    ignore=NOT_COMMITTED)
+    os.symlink(os.path.join(ROOT, "seldon_core_tpu"), tmp_path / "seldon_core_tpu")
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        return tmp_path / "benchmark", json.load(f)
+
+
+def _rehearse(tmp_path, cell, flag):
+    """One CPU rehearsal of ``cell`` in the copy: ``(stdout, the line it
+    printed in a result's place)``."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               JAX_COMPILATION_CACHE_DIR=str(tmp_path / "cache"))
+    done = subprocess.run(
+        [sys.executable, "benchmark/run.py", "--workload", cell,
+         "--seed", str(2**31 + 7), "--seconds", "3", "--trace", flag,
+         "--rehearse-cpu"],
+        cwd=tmp_path, env=env, capture_output=True, text=True, timeout=300)
+    assert done.returncode == 0, done.stderr[-3000:]
+    last = done.stdout.strip().splitlines()[-1]
+    # a rehearsal never prints a line that parses as a result
+    assert last.startswith("rehearsal (cpu")
+    with pytest.raises(ValueError):
+        json.loads(last)
+    return done.stdout, json.loads(last.split(": ", 1)[1])
+
+
 def test_a_cell_is_added_by_files_alone(tmp_path):
     """A configuration, a traffic mix, a cell and a per-layer metric added as
     new files and manifest entries in a copy are found and run by the tiny
     CPU rehearsal; nothing that was there is edited."""
-    shutil.copytree(os.path.join(ROOT, "benchmark"), tmp_path / "benchmark",
-                    ignore=shutil.ignore_patterns("_runs", "_cache", "__pycache__"))
-    os.symlink(os.path.join(ROOT, "seldon_core_tpu"), tmp_path / "seldon_core_tpu")
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
-        man = json.load(f)
-    bench = tmp_path / "benchmark"
+    bench, man = _copy_of_the_benchmark(tmp_path)
     cfg = json.load(open(bench / "configs" / "internlm2-1.8b.json"))
     cfg["server"]["slots"] = 2
     (bench / "configs" / "added.json").write_text(json.dumps(cfg))
-    (bench / "traffic" / "tiny.json").write_text(json.dumps({
-        "loop": "closed", "clients": {"per_slot": 1, "extra": 1}, "ramp_s": 1,
-        "drain_s": 0, "classes": [[20, 8, 1], [40, 16, 1]], "temperature": 0.0}))
+    (bench / "traffic" / "tiny.json").write_text(json.dumps(TINY_MIX))
     (bench / "layer_metrics" / "added_requests.py").write_text(
         "def read(run):\n    return float(len(run['records']))\n")
     (bench / "layer_metrics" / "added_nothing.py").write_text(
@@ -377,22 +438,8 @@ def test_a_cell_is_added_by_files_alone(tmp_path):
             "layer": "load generator", "moves": "tpot_p50_ms",
             "workloads": ["added.tiny"]})
     (tmp_path / "BENCHMARK.json").write_text(json.dumps(man))
-    env = dict(os.environ, JAX_PLATFORMS="cpu",
-               JAX_COMPILATION_CACHE_DIR=str(tmp_path / "cache"))
-    lines = {}
-    for flag in ("0", "1", "2"):
-        done = subprocess.run(
-            [sys.executable, "benchmark/run.py", "--workload", "added.tiny",
-             "--seed", str(2**31 + 7), "--seconds", "3", "--trace", flag,
-             "--rehearse-cpu"],
-            cwd=tmp_path, env=env, capture_output=True, text=True, timeout=300)
-        assert done.returncode == 0, done.stderr[-3000:]
-        last = done.stdout.strip().splitlines()[-1]
-        # a rehearsal never prints a line that parses as a result
-        assert last.startswith("rehearsal (cpu")
-        with pytest.raises(ValueError):
-            json.loads(last)
-        lines[flag] = json.loads(last.split(": ", 1)[1])
+    lines = {flag: _rehearse(tmp_path, "added.tiny", flag)[1]
+             for flag in ("0", "1", "2")}
     e2e, layers, both = lines["0"], lines["1"], lines["2"]
     assert set(e2e) == RESULT_KEYS and set(layers) == RESULT_KEYS | {"breakdown"}
     assert e2e["correct"] and e2e["failed"] == 0 and e2e["attempted"] > 0
@@ -415,3 +462,142 @@ def test_a_cell_is_added_by_files_alone(tmp_path):
         assert 0.0 <= got["prefill_device_share"]["value"] <= 100.0
     runs = bench / "_runs" / "added.tiny"
     assert len(list(runs.glob("*/requests.jsonl"))) == 3    # written on every run
+
+
+# the files a PR brings for a configuration of an architecture the benchmark
+# has not seen. The program serves dense decoders only, so this one is a
+# dense decoder too, under names of its own for width and depth: what is
+# shown is who gets called, not new mathematics
+OTHER_ARCHITECTURE = '''
+"""A decoder whose published config says ``width`` and ``depth``."""
+import sys
+
+FAMILY = "other_family"
+STEP_BYTES = 819e3      # with the peak's 819e9 B/s: 1 us a step
+
+
+def __getattr__(name):
+    if name != "OtherLM":
+        raise AttributeError(name)
+    from seldon_core_tpu.models.llm import DecoderLM
+
+    class OtherLM(DecoderLM):
+        def init_params(self, seed=0):
+            print(f"other_family draws its weights, seed {seed}",
+                  file=sys.stderr, flush=True)
+            return super().init_params(seed)
+
+    globals()[name] = OtherLM
+    return OtherLM
+
+
+def register():
+    from seldon_core_tpu import models
+
+    models.register(FAMILY, __name__ + ".OtherLM")
+
+
+def model_kwargs(cfg, seed):
+    return {"vocab_size": cfg["vocab_size"], "d_model": cfg["width"],
+            "n_layers": cfg["depth"], "n_heads": cfg["width"] // 128,
+            "n_kv_heads": 1, "d_ff": 2 * cfg["width"],
+            "max_seq": cfg["server"]["max_seq"], "residual_scale": 0.05,
+            "seed": seed % 1000}
+
+
+def rehearsal(cfg):
+    return {"width": 256, "depth": 1, "vocab_size": 768}
+
+
+def compare_served(model, params, seed):
+    import jax
+    import numpy as np
+
+    from benchmark.reference import other
+
+    tokens = np.random.default_rng(seed).integers(0, model.cfg.vocab_size, 24)
+    served, _cache = jax.jit(lambda p, t: model.prefill(p, t, 128))(
+        params, jax.numpy.asarray(tokens[None], "int32"))
+    ref = other.logits(params, model.cfg, tokens, [23])
+    ratio = float(np.max(np.abs(np.asarray(served[0]) - ref[0])) / ref.std())
+    finite = bool(np.isfinite(np.asarray(served)).all())
+    return {"by": "other_architecture", "ratio": ratio, "tolerance": 0.2,
+            "finite": finite, "ok": finite and ratio <= 0.2}
+
+
+def decode_step_bytes(cfg, live_positions, counters):
+    # a sparse model counts what was routed; without counters, nothing
+    return STEP_BYTES if counters.get("tokens", 0) > 0 else 0.0
+
+
+def prefill_flops(cfg, padded_tokens, sequences, counters):
+    return float(cfg["width"] * padded_tokens)
+'''
+OTHER_REFERENCE = '''
+"""The plain reference of ``other``: the dense decoder's forward."""
+from benchmark.reference.decoder import logits  # noqa: F401
+'''
+OTHER_READER = '''
+from benchmark import capture
+
+
+def read(run):
+    return run["architecture"].prefill_flops(
+        run["config"], 10, 1, capture.counters(run))
+'''
+
+
+def test_a_configuration_of_another_architecture_is_added_by_files_alone(tmp_path):
+    """An architecture module, its reference, a configuration in that
+    architecture's own keys, a cell and a per-layer reader are added to a
+    copy as new files and manifest entries. The rehearsal serves the
+    module's family with the module's kwargs, takes ``correct`` from its
+    ``compare_served`` and the roofline from its bytes, and no file that
+    was there is edited."""
+    bench, man = _copy_of_the_benchmark(tmp_path)
+    (bench / "architectures" / "other.py").write_text(OTHER_ARCHITECTURE)
+    (bench / "reference" / "other.py").write_text(OTHER_REFERENCE)
+    (bench / "layer_metrics" / "other_prefill_flops.py").write_text(OTHER_READER)
+    (bench / "traffic" / "tiny.json").write_text(json.dumps(TINY_MIX))
+    (bench / "configs" / "other.json").write_text(json.dumps({
+        "source": "test", "architecture": "other", "width": 1024, "depth": 6,
+        "vocab_size": 4096, "server": {"slots": 2, "max_seq": 256}}))
+    man["configs"].append({"name": "other", "source": "test", "reduced": [],
+                           "file": "benchmark/configs/other.json", "why": "test"})
+    man["workloads"].append({"name": "other.tiny", "config": "other",
+                             "traffic": "tiny", "chips": 1, "why": "test"})
+    man["per_layer"].append({
+        "name": "other_prefill_flops", "unit": "1", "better": "higher",
+        "source": "program_counter", "layer": "kernels", "moves": "tpot_p50_ms",
+        "workloads": ["other.tiny"]})
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(man))
+    for flag in ("0", "2"):
+        out, line = _rehearse(tmp_path, "other.tiny", flag)
+        assert line["correct"] and line["failed"] == 0 and line["attempted"] > 0
+        # the reply of the module's own comparison, not the decoder's
+        assert "'by': 'other_architecture'" in out and "'tolerance': 0.2" in out
+        run_dir, = (bench / "_runs" / "other.tiny").glob(f"*-trace{flag}-0")
+        # the engine was given, and built, the module's family and kwargs
+        assert json.load(open(run_dir / "model" / "jax_config.json")) == {
+            "family": "other_family",
+            "config": {"vocab_size": 768, "d_model": 256, "n_layers": 1,
+                       "n_heads": 2, "n_kv_heads": 1, "d_ff": 512, "max_seq": 256,
+                       "residual_scale": 0.05, "seed": (2**31 + 7) % 1000}}
+        assert (f"other_family draws its weights, seed {(2**31 + 7) % 1000}"
+                in (run_dir / "engine.log").read_text())
+    got = line["metrics"]
+    assert set(got) >= {"tpot_p50_ms", "setup_s", "decode_step_device_ms"}
+    # 819e3 B at the table's 819e9 B/s is 1 us: the module's bytes, counted
+    # with the capture's counters in hand, over the step's device time
+    assert got["decode_hbm_roofline"]["value"] == pytest.approx(
+        100.0 * 1e-3 / got["decode_step_device_ms"]["value"])
+    assert got["other_prefill_flops"]["value"] == 256 * 10
+    # added, not edited: what the repo's benchmark/ holds is there unchanged
+    for folder, _dirs, files in os.walk(os.path.join(ROOT, "benchmark")):
+        if os.path.basename(folder) in ("_runs", "_cache", "__pycache__"):
+            _dirs[:] = []
+            continue
+        for name in files:
+            theirs = os.path.join(folder, name)
+            mine = os.path.join(bench, os.path.relpath(theirs, os.path.join(ROOT, "benchmark")))
+            assert open(mine, "rb").read() == open(theirs, "rb").read(), mine
