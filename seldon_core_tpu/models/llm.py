@@ -240,7 +240,7 @@ class DecoderLM(ServedModel):
 
     def _attention(
         self, p, x, positions, *, tp_axis=None, sp_axis=None, kv_cache=None,
-        attn_len=None,
+        attn_len=None, lens=None,
     ):
         import jax.numpy as jnp
         from jax import lax
@@ -277,11 +277,21 @@ class DecoderLM(ServedModel):
                 ck = lax.dynamic_update_slice(ck, k, (0, 0, cache_pos, 0))
                 cv = lax.dynamic_update_slice(cv, v, (0, 0, cache_pos, 0))
             new_cache = (ck, cv)
-            k, v = self._cache_read(ck, cv, attn_len)
-        if kv_cache is not None:
-            # decode attention over the (sliced) cache — see
-            # _cache_attention for why the GQA repeat must not happen here
-            o = self._cache_attention(q, k, v, positions, dt)
+            if lens is not None:
+                # ragged single-position decode: each lane reads its own
+                # ``lens[b]`` positions of the unsliced cache (the kernel
+                # on a TPU, the two dots over the bucket elsewhere)
+                from ..ops import decode_attention
+
+                o = decode_attention(
+                    q, ck, cv, positions, lens, attn_len=attn_len,
+                    mesh=getattr(self, "_serving_mesh", None),
+                )
+            else:
+                # decode attention over the (sliced) cache — see
+                # _cache_attention for why the GQA repeat must not happen here
+                k, v = self._cache_read(ck, cv, attn_len)
+                o = self._cache_attention(q, k, v, positions, dt)
         else:
             if KVl < Hl:  # GQA: repeat kv groups (compute-bound prefill
                 # path only; the decode path reads grouped to keep the
@@ -355,55 +365,16 @@ class DecoderLM(ServedModel):
 
     @staticmethod
     def _cache_attention(q, kc, vc, bound, dt):
-        """Attention over the (sliced) KV cache with a key_pos <= bound
-        mask, WITHOUT materialising a head-repeated cache copy.
+        """Attention over the (sliced) KV cache with a ``key_pos <= bound``
+        mask (``bound`` [B] or [B, T]), the GQA group read grouped: the
+        two dots of ``ops.decode_attention.cache_attention``. The chunked
+        and speculative windows, prefix prefill and the uniform-batch
+        ``generate`` read the cache through this; the ragged
+        single-position step goes through ``ops.decode_attention()``,
+        which on a TPU reads only each lane's live positions."""
+        from ..ops.decode_attention import cache_attention
 
-        ``jnp.repeat`` on the cache (the textbook GQA read) writes a
-        rep-times-larger copy to HBM and reads it back — at 16 lanes /
-        256-key windows that tripled the decode step's cache traffic and
-        ran the read path ~7x below the HBM roof (measured on v5e:
-        7.9 -> 5.7 ms/step at 256-key windows, 18.7 -> 9.2 at 1024, for a 1.26B model).
-        Instead q is viewed as [B, KV, rep, T, Dh] and both dots batch
-        over (B, KV), so the MXU consumes the grouped cache directly.
-
-        ``bound``: [B] (single-position decode — every query row masks to
-        its own prefix) or [B, T] (chunked decode — prefix + in-window
-        causality). Scores accumulate in f32 (preferred_element_type);
-        the bf16 cache is never cast or copied.
-        """
-        import jax
-        import jax.numpy as jnp
-        from jax import lax
-
-        B, Hl, T, Dh = q.shape
-        KVl, Ta = kc.shape[1], kc.shape[2]
-        rep = Hl // KVl
-        # NOTE r5: a Pallas flash-decode kernel (Tq=1 online softmax over
-        # contiguous [block_k, Dh] chunks, scalar-prefetched per-lane
-        # bounds, grid (B, chunks)) was built, parity-tested, and A/B'd
-        # IN-SITU inside the fused decode burst on a v5e: 23.7 ms/step vs
-        # this einsum's 6.0 at 16 lanes x 1920-key windows (Dh=64), and
-        # mildly slower at every other shape tried — per-program overhead
-        # x (layers x lanes x chunks) dominates the modest DMA-contiguity
-        # win. (Isolated single-call A/Bs are useless here: ~4 ms of
-        # fixed per-dispatch cost swamps a 100 MB read.) The einsum stays.
-        key_pos = jnp.arange(Ta, dtype=jnp.int32)
-        if getattr(bound, "ndim", 0) == 2:  # [B, T]
-            mask = key_pos[None, None, None, None, :] <= bound[:, None, None, :, None]
-        else:  # [B]
-            mask = key_pos[None, None, None, None, :] <= bound[:, None, None, None, None]
-        qg = q.reshape(B, KVl, rep, T, Dh)
-        s = lax.dot_general(
-            qg, kc, (((4,), (3,)), ((0, 1), (0, 1))),
-            preferred_element_type=jnp.float32,
-        ) / np.sqrt(Dh)  # [B, KV, rep, T, Ta]
-        s = jnp.where(mask, s, -1e30)
-        w = jax.nn.softmax(s, -1).astype(dt)
-        o = lax.dot_general(
-            w, vc, (((4,), (2,)), ((0, 1), (0, 1))),
-            preferred_element_type=jnp.float32,
-        ).astype(dt)  # [B, KV, rep, T, Dh]
-        return o.reshape(B, Hl, T, Dh)
+        return cache_attention(q, kc, vc, bound, dt)
 
     def _ffn(self, p, x, *, tp_axis=None, ep_axes=None):
         import jax
@@ -502,13 +473,15 @@ class DecoderLM(ServedModel):
         dt = jnp.dtype(self.cfg.dtype)
         return params["embed"][tokens.astype(jnp.int32)].astype(dt)
 
-    def _decode_layer(self, layer_p, x, positions, ck, cv, cache_pos, attn_len):
+    def _decode_layer(self, layer_p, x, positions, ck, cv, cache_pos, attn_len,
+                      lens=None):
         """One decoder layer with KV-cache attention: returns the residual
         stream and this layer's updated cache. Shared by the stacked-scan
-        decode (_decode) and the unstacked list decode."""
+        decode (_decode) and the unstacked list decode (which passes each
+        lane's live length ``lens``)."""
         attn_out, (nk, nv) = self._attention(
             layer_p, x, positions, kv_cache=(ck, cv, cache_pos),
-            attn_len=attn_len,
+            attn_len=attn_len, lens=lens,
         )
         x = x + attn_out
         ffn_out, _ = self._ffn(layer_p, x)
@@ -572,7 +545,7 @@ class DecoderLM(ServedModel):
         return self._decode(params, cache, tokens, pos, pos, attn_len=attn_len)
 
     def decode_step_ragged_list(self, params, ks, vs, tokens, pos, attn_len=None,
-                                write_pos=None):
+                                write_pos=None, lens=None):
         """Ragged decode step over an UNSTACKED cache: ``ks``/``vs`` are
         per-layer lists of [B, KV, T, Dh] arrays. Returns
         ``(logits [B, V], new_ks, new_vs)``.
@@ -585,14 +558,21 @@ class DecoderLM(ServedModel):
         burst donates them), and per step and layer the compiled burst
         does two things to a cache array and no third: ``_cache_write``'s
         scatter of one ``Dh`` row per (lane, KV head) into the donated
-        buffer itself, and ``_cache_attention``'s two dots reading
-        ``[B, KV, attn_len, Dh]`` of it as a fused operand. No copy on
-        entry or exit, no slice written out: ``tools/burst_hlo_check.py``
-        compiles the burst at the benchmark's shapes and fails on either
+        buffer itself, and ``ops.decode_attention()``'s read: on a TPU the
+        ragged kernel copying each lane's live blocks out of the buffer
+        where it lies, elsewhere the two dots reading ``[B, KV, attn_len,
+        Dh]`` of it as a fused operand. No copy on entry or exit, no
+        slice written out: ``tools/burst_hlo_check.py`` compiles the
+        burst at the benchmark's shapes and fails on either
         (``tests/test_burst_hlo.py`` runs it for a described v5e); before
         ISSUE 26 the same check found 96 cache-sized copies a burst and
         4.3 GB of scratch beside a 5.6 GB cache. The continuous batcher
         (serving/continuous.py) keeps its persistent cache in this layout.
+
+        ``lens`` ([B] int32, optional): how many positions of its cache
+        each row reads: ``pos + 1``, or 0 for a lane that is idle or done
+        (the kernel then copies nothing for it; the caller drops its
+        logits). Defaults to ``pos + 1`` on every row.
 
         ``write_pos`` ([B] int32, optional): per-row K/V WRITE position
         when it must differ from the attention position — the fused
@@ -606,6 +586,7 @@ class DecoderLM(ServedModel):
 
         pos = pos.astype(jnp.int32)
         wp = pos if write_pos is None else write_pos.astype(jnp.int32)
+        lens = pos + 1 if lens is None else lens.astype(jnp.int32)
         # serving-mesh entry gather / exit reshard (see set_serving_mesh)
         params = self._tp_gather(params)
         ks = self._tp_gather(ks)
@@ -617,7 +598,7 @@ class DecoderLM(ServedModel):
         for l in range(len(ks)):
             layer_p = jax.tree_util.tree_map(lambda a, l=l: a[l], blocks)
             x, nk, nv = self._decode_layer(
-                layer_p, x, pos, ks[l], vs[l], wp, attn_len
+                layer_p, x, pos, ks[l], vs[l], wp, attn_len, lens
             )
             nks.append(self._tp_cache(nk))
             nvs.append(self._tp_cache(nv))

@@ -8,3 +8,4 @@ on CPU and in tests.
 """
 
 from .flash_attention import attention, flash_attention  # noqa: F401
+from .decode_attention import decode_attention, ragged_decode_attention  # noqa: F401

@@ -333,6 +333,15 @@ class _Slot:
     credit_done: bool = False
 
 
+def _positions_streamed(pos: int, k: int, bucket: int, block: int) -> int:
+    """Positions the ragged decode kernel streams for one lane over ``k``
+    steps, the first of which writes at ``pos``: the j-th reads ``pos + j``
+    keys, rounded up to the kernel's ``block`` and never past the bucket."""
+    return sum(
+        min(bucket, -(-(pos + j) // block) * block) for j in range(1, k + 1)
+    )
+
+
 class ContinuousBatcher:
     """Slot-based continuous batching scheduler over a DecoderLM.
 
@@ -587,6 +596,13 @@ class ContinuousBatcher:
             "prefix_tokens_saved": 0, "prefix_cache_bytes": 0,
             "shed": 0,
             "burst_reads": 0, "burst_read_bytes": 0,
+            # how far the ragged decode read engages: positions of K and V
+            # the kernel streams per dispatched (sub)burst (each lane's
+            # length rounded up to the kernel's block, step by step), and
+            # what the bucket's dots read of the same burst (rows x
+            # attn_len x steps). Their ratio is the share of the old read
+            # still made
+            "kv_positions_read": 0, "kv_positions_bucket": 0,
             "group_bursts": 0, "group_lanes": 0, "group_pad_lanes": 0,
             # disaggregated serving: slabs/bytes shipped out (prefill
             # role), slabs/bytes admitted in (decode role), and transfer
@@ -937,7 +953,8 @@ class ContinuousBatcher:
 
         def fused_step(params, ks, vs, cur_tok, pos, active, temps, keys, attn_len):
             logits, ks, vs = model.decode_step_ragged_list(
-                params, ks, vs, cur_tok[:, None], pos, attn_len=attn_len
+                params, ks, vs, cur_tok[:, None], pos, attn_len=attn_len,
+                lens=jnp.where(active, pos + 1, 0),
             )
             keys, nxt = sample_next(keys, logits, temps)
             nxt = jnp.where(active, nxt, 0)
@@ -1055,7 +1072,7 @@ class ContinuousBatcher:
             wpos = jnp.where(alive, pos, park)
             logits, ks, vs = model.decode_step_ragged_list(
                 params, ks, vs, cur_tok[:, None], pos, attn_len=attn_len,
-                write_pos=wpos,
+                write_pos=wpos, lens=jnp.where(alive, pos + 1, 0),
             )
             keys, nxt = sample_next(keys, logits, temps)
             cur_tok = jnp.where(alive, nxt, cur_tok)
@@ -1393,6 +1410,10 @@ class ContinuousBatcher:
             layer.dtype.itemsize * layer.shape[1] * layer.shape[3]
             for layer in self._cache["k"]
         )
+        # the ragged decode read's granule (stats["kv_positions_read"])
+        from ..ops.decode_attention import BLOCK
+
+        self._kv_read_block = BLOCK
         # the draft cache's per-token K/V price (speculation only): the
         # pressure ledger charges live lanes for BOTH caches while the
         # draft is resident, and stops when rung 2 frees it
@@ -6023,6 +6044,15 @@ class ContinuousBatcher:
                             self.stats["burst_read_bytes"] += k * (
                                 self._param_bytes
                                 + rows * g_bucket * self._kv_key_bytes
+                            )
+                            self.stats["kv_positions_read"] += sum(
+                                _positions_streamed(
+                                    self._pos_host[slot] - adv, k, g_bucket,
+                                    self._kv_read_block)
+                                for slot in lanes
+                            )
+                            self.stats["kv_positions_bucket"] += (
+                                k * rows * g_bucket
                             )
                             if use_fused:
                                 self.stats["fused_dispatches"] += 1

@@ -1,8 +1,11 @@
 """The decode burst's compiled program holds no cache-shaped copy or slice
-(ISSUE 26), checked by ``tools/burst_hlo_check.py``: its reading of an HLO
-text on recorded snippets, and the burst itself compiled here, without a
-chip, for a described v5e at the benchmark configurations' widths (two
-layers: the copies are per layer, so two show what twenty-four would).
+(ISSUE 26) and reads the cache through one ragged kernel call per layer,
+with no array of the bucket's shape left (ISSUE 30), checked by
+``tools/burst_hlo_check.py``: its reading of an HLO text on recorded
+snippets, and the burst itself compiled here, without a chip, for a
+described v5e at the benchmark configurations' widths (two layers: the
+copies and the kernel calls are per layer, so two show what twenty-four
+would; and once at the configurations' own depth for the scratch).
 
 The topology is described inside a fixture and nowhere else: only one
 process may load the TPU's library, and each xdist worker imports this
@@ -84,6 +87,22 @@ def test_reader_leaves_a_fused_slice_and_other_shapes_alone():
     assert tool.cache_shaped(HLO, 4, 8, (2048, 640), 128) == []
 
 
+def test_reader_counts_kernel_calls_and_bucket_shaped_arrays():
+    tool = _tool()
+    # the recorded burst is PR 25's: no kernel, and the bucket's slice as
+    # an op of its own, as a fusion's result, and inside that fusion (its
+    # root and its signature)
+    assert tool.kernel_calls(HLO) == {"inside": 0, "outside": 0}
+    assert tool.bucket_shaped(HLO, 28, 8, 640, 128) == 4
+    assert tool.bucket_shaped(HLO, 4, 8, 640, 128) == 0
+    call = (
+        '  %ragged.1 = bf16[28,8,2,128]{3,2,1,0} custom-call(%l, %q, %gte.1,'
+        ' %gte.1), custom_call_target="tpu_custom_call"\n')
+    with_kernel = HLO.replace("  %slice.4 =", call + "  %slice.4 =").replace(
+        "  %copy.2 =", call + "  %copy.2 =")
+    assert tool.kernel_calls(with_kernel) == {"inside": 1, "outside": 1}
+
+
 def test_reader_counts_the_aliases():
     assert _tool().alias_count(HLO) == 2
     assert _tool().alias_count("HloModule m, is_scheduled=true\n") == 0
@@ -115,4 +134,25 @@ def test_burst_compiled_for_v5e_keeps_the_cache_in_place(one_chip, config):
     assert out["alias_size_in_bytes"] >= out["cache_bytes"]
     # no scratch of the cache's size: two layers' K and V here
     assert out["temp_size_in_bytes"] < out["cache_bytes"] / 2
+    # the read: this process's backend is the CPU, the burst is lowered
+    # for the v5e, and it holds the kernel, once a layer, not the dots
+    assert out["kernel_calls"] == {"inside": 2, "outside": 0}
+    assert out["bucket_shaped_arrays"] == 0
     assert out["ok"]
+
+
+@pytest.mark.parametrize("config", ["internlm2-1.8b", "mistral-7b-v0.3"])
+def test_burst_at_full_depth_takes_no_more_scratch_than_before(one_chip, config):
+    """At the configuration's own depth and the deepest warmed bucket but
+    one: a kernel call per layer, the cache aliased through, and
+    ``temp_size_in_bytes`` not above the burst's before the ragged read."""
+    tool = _tool()
+    with open(os.path.join(ROOT, "benchmark", "configs", config + ".json")) as f:
+        cfg = json.load(f)
+    cfg["name"] = config
+    out = tool.check(cfg, 1280, one_chip, temp_limit=tool.TEMP_BEFORE[config])
+    layers = cfg["num_hidden_layers"]
+    assert out["kernel_calls"] == {"inside": layers, "outside": 0}
+    assert out["input_output_aliases"] >= out["cache_leaves"] == 2 * layers
+    assert out["temp_size_in_bytes"] <= out["temp_size_before"]
+    assert out["ok"], out

@@ -11,17 +11,23 @@ reads the optimised HLO:
   its own (not inside a fusion) whose result is ``[n <= lanes, KV, T or
   attn_len, Dh]`` is a cache-shaped array being written out: FAIL;
 * ``temp_size_in_bytes`` beside the cache's bytes (a burst that copies the
-  cache reserves scratch as large as the cache);
+  cache reserves scratch as large as the cache), and at a configuration's
+  own depth not above what the burst took before the ragged read (ISSUE 30);
 * the input-output aliases (one per donated cache leaf, or donation bought
-  nothing).
+  nothing);
+* the decode read (ISSUE 30): the ``while`` body holds one Mosaic kernel
+  call per layer (``ops.decode_attention``, chosen by the platform the
+  burst is lowered for), and nothing anywhere in the module, inside a
+  fusion or out, has the bucket's shape ``[n <= lanes, KV, attn_len, Dh]``:
+  the two dots that read every lane's whole bucket are gone.
 
     python tools/burst_hlo_check.py                      # on the chip
     python tools/burst_hlo_check.py --described v5e:2x2  # no chip: the TPU
         compiler targets a described device (JAX_PLATFORMS=cpu); the HLO
         is the chip's, a time is not
 
-Exit 0 clean, 1 a cache-shaped copy or slice or a lost alias, 3 skipped
-(no TPU and no ``--described``). A skip is not a pass.
+Exit 0 clean, 1 any of the above, 3 skipped (no TPU and no ``--described``).
+A skip is not a pass.
 """
 
 from __future__ import annotations
@@ -39,6 +45,10 @@ CONFIGS = ("benchmark/configs/internlm2-1.8b.json",
            "benchmark/configs/mistral-7b-v0.3.json")
 ATTN_LENS = (640, 1280)
 OFFENDERS = ("copy", "copy-start", "slice", "slice-start")
+# temp_size_in_bytes of the burst at these configurations' own depth before
+# the ragged read, for a described v5e at either ATTN_LENS (PR 26's burst;
+# tools/burst_hlo_check.py at commit 21ef44d)
+TEMP_BEFORE = {"internlm2-1.8b": 689441280, "mistral-7b-v0.3": 1332189696}
 _INSTR_RE = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = (.*?) ([\w\-]+)\(")
 _CALLED_RE = re.compile(r"(?:body|condition|to_apply|calls)=%?([\w.\-]+)")
 
@@ -103,6 +113,30 @@ def cache_shaped(hlo: str, lanes: int, kv: int, lengths, dh: int) -> list:
     return found
 
 
+def kernel_calls(hlo: str) -> dict:
+    """Mosaic kernel calls that run as ops of their own:
+    ``{"inside": n, "outside": m}`` the ``while``."""
+    comps = computations(hlo)
+    out = {"inside": 0, "outside": 0}
+    for name, inside in scheduled(comps).items():
+        for line in comps[name]:
+            m = _INSTR_RE.match(line)
+            if m and m.group(3) == "custom-call" \
+                    and 'custom_call_target="tpu_custom_call"' in line:
+                out["inside" if inside else "outside"] += 1
+    return out
+
+
+def bucket_shaped(hlo: str, lanes: int, kv: int, attn_len: int, dh: int) -> int:
+    """Arrays of the bucket's shape ``[n <= lanes, KV, attn_len, Dh]``
+    anywhere in the module, a fusion's inside included: the slice the
+    bucket's dots took as a fused operand was one."""
+    return sum(
+        int(n) <= lanes
+        for n in re.findall(r"bf16\[(\d+),%d,%d,%d\]" % (kv, attn_len, dh), hlo)
+    )
+
+
 def alias_count(hlo: str) -> int:
     """Entries of the module's ``input_output_alias={ {3}: (12, {}, may-alias), ...}``."""
     header = hlo.split("\n", 1)[0]
@@ -115,11 +149,12 @@ def compile_burst(cfg: dict, attn_len: int, device_sharding):
     import jax
     import jax.numpy as jnp
 
-    from benchmark.manifest import decoder_kwargs
+    from benchmark import manifest
     from seldon_core_tpu.models.llm import DecoderLM
     from seldon_core_tpu.serving.continuous import ContinuousBatcher
 
-    kwargs = decoder_kwargs(cfg, 0)
+    kwargs = manifest.architecture(
+        ROOT, manifest.load(ROOT), cfg["architecture"]).model_kwargs(cfg, 0)
     kwargs.pop("seed")
     model = DecoderLM(**kwargs)
     mc = model.cfg
@@ -147,7 +182,10 @@ def compile_burst(cfg: dict, attn_len: int, device_sharding):
     return compiled, (lanes, mc.n_kv_heads, T, mc.head_dim), cache_bytes
 
 
-def check(cfg: dict, attn_len: int, device_sharding, hlo_dir=None) -> dict:
+def check(cfg: dict, attn_len: int, device_sharding, hlo_dir=None,
+          temp_limit=None) -> dict:
+    """``temp_limit``: ``TEMP_BEFORE``'s entry where ``cfg`` has the
+    configuration's own depth (``main`` passes it), none for a cut one."""
     compiled, (lanes, kv, T, dh), cache_bytes = compile_burst(
         cfg, attn_len, device_sharding)
     hlo = compiled.as_text()
@@ -161,19 +199,27 @@ def check(cfg: dict, attn_len: int, device_sharding, hlo_dir=None) -> dict:
     for op, result, inside, _line in found:
         key = f"{op} {result} {'inside' if inside else 'outside'} the while"
         kinds[key] = kinds.get(key, 0) + 1
-    leaves = 2 * cfg["num_hidden_layers"]
+    layers = cfg["num_hidden_layers"]
+    leaves = 2 * layers
     aliases = alias_count(hlo)
+    kernels = kernel_calls(hlo)
+    bucket = bucket_shaped(hlo, lanes, kv, attn_len, dh) if attn_len < T else 0
     return {
         "lanes": lanes, "attn_len": attn_len,
         "cache_shaped_copies_and_slices": kinds,
         "example": found[0][3] if found else None,
         "cache_bytes": cache_bytes,
         "temp_size_in_bytes": mem.temp_size_in_bytes,
+        "temp_size_before": temp_limit,
         "alias_size_in_bytes": mem.alias_size_in_bytes,
         "input_output_aliases": aliases,
         "cache_leaves": leaves,
+        "kernel_calls": kernels,
+        "bucket_shaped_arrays": bucket,
         "ok": not found and aliases >= leaves
-        and mem.alias_size_in_bytes >= cache_bytes,
+        and mem.alias_size_in_bytes >= cache_bytes
+        and kernels == {"inside": layers, "outside": 0} and not bucket
+        and (temp_limit is None or mem.temp_size_in_bytes <= temp_limit),
     }
 
 
@@ -213,7 +259,7 @@ def main(argv=None) -> int:
         cfg["name"] = os.path.basename(path)[:-len(".json")]
         for attn_len in args.attn_len:
             out = check(cfg, attn_len, SingleDeviceSharding(device),
-                        args.hlo_dir)
+                        args.hlo_dir, TEMP_BEFORE.get(cfg["name"]))
             print(json.dumps({"config": cfg["name"], **out}))
             ok = ok and out["ok"]
     print("burst_hlo_check:", "OK" if ok else "FAIL")
