@@ -26,7 +26,7 @@ unbounded or unhashable domain re-specializes the executable per
 distinct value —
 
 * ``len(...)`` at a static position (unbounded integers; pass a pow2 /
-  bucketized size instead, as ``_group_size_bucket`` does),
+  bucketized size instead, as ``_attn_need`` does),
 * float constants or ``float()`` casts (continuous domain — e.g. a
   temperature must be a traced operand, not a static),
 * dict/list/set literals (unhashable: ``jit`` rejects them at runtime,

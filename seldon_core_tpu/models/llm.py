@@ -175,7 +175,7 @@ class DecoderLM(ServedModel):
             param_bytes = self.n_params() * 2.0
         if kv_row_bytes is None:
             kv_row_bytes = float(self.kv_bytes_per_token())
-        if kind in ("decode_burst", "fused_burst", "group_burst"):
+        if kind in ("decode_burst", "fused_burst"):
             return k * (param_bytes + rows * bucket * kv_row_bytes)
         if kind == "spec_burst":
             # verify chunk: one full forward over gamma+1 positions per

@@ -23,9 +23,11 @@ it can see (``T == 1``, ``head_dim`` a multiple of 128, the cache length
 a multiple of the block, no serving mesh) and by the platform the
 executable is LOWERED for (``lax.platform_dependent``; a process whose
 backend is the CPU can compile for a described TPU and gets the
-kernel), and takes ``cache_attention()``'s dots everywhere else.
-``ragged_decode_attention()`` is the kernel itself (``interpret`` runs
-it on the CPU for the equivalence tests).
+kernel), and takes ``cache_attention()``'s dots everywhere else. That
+rule is ``reads_ragged()``; the scheduler asks it too, because a read
+that bounds itself per lane needs no static bucket and so no executable
+per bucket. ``ragged_decode_attention()`` is the kernel itself
+(``interpret`` runs it on the CPU for the equivalence tests).
 """
 
 from __future__ import annotations
@@ -48,8 +50,28 @@ NEG_INF = -1e30
 # 11.42 all three. A lane's length rounds up to the block, so the smaller
 # block reads less, and its extra iterations cost less than that saves.
 # It is also the scheduler's bucket step (``attn_bucket``), so every
-# bucket and every gathered group slab tiles.
+# bucket tiles.
 BLOCK = 128
+
+
+def reads_ragged(platform, q_shape, cache_shape, dtypes, mesh=None) -> bool:
+    """Whether ``decode_attention()``, lowered for ``platform``, reads
+    each lane's own length (the kernel) and not a static bound of every
+    lane (the dots). ``q_shape`` [B, H, T, Dh]; ``cache_shape`` [B, KV,
+    Tc, Dh] of one layer's K (V's is the same); ``dtypes`` of q, K and V.
+
+    The kernel wants one query position, a head size that fills lanes,
+    whole GQA groups, whole blocks and one dtype; Mosaic kernels cannot
+    be partitioned by GSPMD, so a serving mesh takes the dots."""
+    return (
+        platform == "tpu"
+        and mesh is None
+        and q_shape[2] == 1
+        and q_shape[-1] % 128 == 0
+        and q_shape[1] % cache_shape[1] == 0
+        and cache_shape[2] % BLOCK == 0
+        and len(set(dtypes)) == 1
+    )
 
 
 def cache_attention(q, kc, vc, bound, dt):
@@ -230,7 +252,8 @@ def decode_attention(q, k, v, pos, lens, attn_len=None, mesh=None):
     position and ``lens`` [B] what is read of its cache: ``pos + 1`` for
     a lane whose output anyone reads, 0 for one that is idle or done.
     ``attn_len`` (static) is the scheduler's bucket, an upper bound on
-    every ``lens[b]``.
+    every ``lens[b]``; None where the caller has none, and the bound is
+    then the cache's length.
 
     The kernel streams ``lens[b]`` positions of lane b, none for 0, and
     gives such a lane zeros. The dots cannot skip a lane: they read
@@ -243,9 +266,8 @@ def decode_attention(q, k, v, pos, lens, attn_len=None, mesh=None):
     (24 call sites a step would otherwise trace and lower 24 kernels in
     every variant ``warm()`` builds).
 
-    ``mesh``: the serving mesh when the caller runs under one; Mosaic
-    kernels cannot be partitioned by GSPMD, and the dots can, so a mesh
-    takes the dots.
+    ``mesh``: the serving mesh when the caller runs under one
+    (``reads_ragged()``: it takes the dots).
     """
     t = k.shape[2]
     bound = t if attn_len is None else min(int(attn_len), t)
@@ -259,15 +281,10 @@ def decode_attention(q, k, v, pos, lens, attn_len=None, mesh=None):
         return ragged_decode_attention(
             q, k, v, jnp.minimum(lens, bound), block=BLOCK)
 
-    use_kernel = (
-        mesh is None
-        and q.shape[2] == 1
-        and q.shape[-1] % 128 == 0
-        and q.shape[1] % k.shape[1] == 0
-        and t % BLOCK == 0
-        and q.dtype == k.dtype == v.dtype
-    )
-    if not use_kernel:
+    # the platform is known only when this is lowered: ask whether a
+    # lowering for a TPU takes the kernel, and let that lowering choose
+    if not reads_ragged(
+            "tpu", q.shape, k.shape, (q.dtype, k.dtype, v.dtype), mesh):
         return dots(q, k, v, pos, lens)
     return lax.platform_dependent(
         q, k, v, pos, lens, tpu=kernel, default=dots)

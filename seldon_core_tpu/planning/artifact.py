@@ -43,7 +43,9 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 from ..serving.disagg import ChecksumError, DisaggError, TruncatedStream
 
 MAGIC = b"SPF1"
-PROFILE_VERSION = 1
+# 2: the grid lost its depth-group axes with the mechanism (ISSUE 31); a
+# version-1 artifact is refused, its configs name knobs that are gone
+PROFILE_VERSION = 2
 
 # the knobs a profile grid entry is keyed on — the sweep axes. Order is
 # the canonical config identity (``config_key``); every grid entry must
@@ -52,8 +54,6 @@ CONFIG_KEYS = (
     "slots",
     "prefill_chunk",
     "fused_steps_per_dispatch",
-    "depth_groups",
-    "depth_group_split_bytes",
     "kv_tier_bytes",
 )
 

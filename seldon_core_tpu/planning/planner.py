@@ -59,8 +59,6 @@ logger = logging.getLogger(__name__)
 RETUNABLE_AXES = (
     "fused_steps_per_dispatch",
     "prefill_chunk",
-    "depth_groups",
-    "depth_group_split_bytes",
 )
 
 
@@ -164,14 +162,6 @@ class ServingPlanner:
         if ttft is None and tpot is None:
             return None
         require: Dict[str, Any] = {"slots": current_config.get("slots")}
-        if census:
-            # out-of-census configs would be refused typed by retune();
-            # don't even rank them. depth-group variants and the chunk
-            # executable only exist when the boot census built them.
-            if int(census.get("depth_groups") or 0) <= 1:
-                require["depth_groups"] = int(
-                    current_config.get("depth_groups") or 0
-                )
         try:
             best = self.cost_model.best(
                 ttft_p99_ms=ttft, tpot_p99_ms=tpot, require=require,
@@ -188,8 +178,7 @@ class ServingPlanner:
                 continue
             # an axis the profile never SWEPT carries no evidence: the
             # grid's constant is the driver's choice, not a measured
-            # preference over the member's live value (e.g. the
-            # batcher's own split-bytes heuristic) — never churn it
+            # preference over the member's live value — never churn it
             swept = {
                 int(e["config"].get(axis) or 0)
                 for e in self.cost_model.grid

@@ -2,9 +2,9 @@
 
 InferLine-style (PAPERS.md, arxiv 1812.01776) offline stage: drive a
 REAL generate engine — not a simulator — through a grid of serving
-configs (slots x prefill chunk x fused K x depth-group split x kv-tier
-bytes) under one seeded :class:`~.trafficsim.TrafficSim` trace, and
-price every config from the telemetry PR 18 already exports:
+configs (slots x prefill chunk x fused K x kv-tier bytes) under one
+seeded :class:`~.trafficsim.TrafficSim` trace, and price every config
+from the telemetry PR 18 already exports:
 
 * tokens/s from the replay wall clock,
 * TTFT/TPOT/queue-wait quantiles from the scheduler's SLO reservoir
@@ -41,8 +41,6 @@ def sweep_grid(
     slots: Sequence[int] = (4, 8),
     prefill_chunk: Sequence[int] = (0,),
     fused_steps: Sequence[int] = (0, 4, 8),
-    depth_groups: Sequence[int] = (0,),
-    depth_group_split_bytes: Sequence[int] = (0,),
     kv_tier_bytes: Sequence[int] = (0,),
 ) -> List[Dict[str, int]]:
     """The cartesian config grid, normalized to CONFIG_KEYS. Axes
@@ -51,17 +49,13 @@ def sweep_grid(
     for s in slots:
         for pc in prefill_chunk:
             for fk in fused_steps:
-                for dg in depth_groups:
-                    for sb in depth_group_split_bytes:
-                        for kt in kv_tier_bytes:
-                            out.append(normalize_config({
-                                "slots": s,
-                                "prefill_chunk": pc,
-                                "fused_steps_per_dispatch": fk,
-                                "depth_groups": dg,
-                                "depth_group_split_bytes": sb,
-                                "kv_tier_bytes": kt,
-                            }))
+                for kt in kv_tier_bytes:
+                    out.append(normalize_config({
+                        "slots": s,
+                        "prefill_chunk": pc,
+                        "fused_steps_per_dispatch": fk,
+                        "kv_tier_bytes": kt,
+                    }))
     return out
 
 
@@ -76,13 +70,11 @@ def _quant(slo: Optional[Dict[str, Any]], phase: str, q: str) -> float:
 
 def _compile_variants(census: Optional[Dict[str, Any]]) -> int:
     """Warmed-executable count implied by a boot census — the same
-    vocabulary retune validation speaks (fused K variants x group-burst
-    doubling, plus the chunked-prefill executable when enabled)."""
+    vocabulary retune validation speaks (fused K variants, plus the
+    chunked-prefill executable when enabled)."""
     if not census:
         return 1
     n = max(1, len(census.get("fused_ks") or ()))
-    if int(census.get("depth_groups") or 0) > 1:
-        n *= 2
     if int(census.get("prefill_chunk") or 0) > 0:
         n += 1
     return n

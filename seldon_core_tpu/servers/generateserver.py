@@ -64,15 +64,6 @@ Model URI layout: same ``jax_config.json`` as jaxserver with
                      deadline (meta ``deadlineMs``) are additionally shed
                      when the queue's expected wait exceeds it — see
                      docs/operate.md "Resilience"
-    depth_groups     depth-aware decode: max fused sub-bursts per poll
-                     (0/1 = off). Lanes partition by attention-read
-                     bucket so shallow lanes stop paying the deepest
-                     lane's cache read — see docs/generate.md
-                     "Depth-aware scheduling"
-    depth_group_split_bytes
-                     cost-model override: HBM bytes/step an extra
-                     sub-burst is charged (default: the params' byte
-                     size — one more param read per step)
     prefill_chunk    chunked prefill: split long-prompt prefills into
                      this many tokens per slice, interleaved between
                      decode polls (0 = off) — a 1,792-token admit no
@@ -250,8 +241,6 @@ class GenerateServer(SeldonComponent):
         prefix_cache_hbm_bytes: int = 0,
         prefix_cache_min_tokens: int = 16,
         admit_queue_limit: int = 0,
-        depth_groups: int = 0,
-        depth_group_split_bytes: Optional[int] = None,
         prefill_chunk: int = 0,
         flight_recorder: int = 512,
         role: str = "unified",
@@ -364,11 +353,6 @@ class GenerateServer(SeldonComponent):
         self._prefix_cache_hbm_bytes = int(prefix_cache_hbm_bytes)
         self._prefix_cache_min_tokens = int(prefix_cache_min_tokens)
         self._admit_queue_limit = int(admit_queue_limit)
-        self._depth_groups = int(depth_groups)
-        self._depth_group_split_bytes = (
-            int(depth_group_split_bytes)
-            if depth_group_split_bytes is not None else None
-        )
         self._prefill_chunk = int(prefill_chunk)
         self._flight_recorder = int(flight_recorder)
         # cumulative scheduler stats ship as true counters (deltas)
@@ -581,8 +565,6 @@ class GenerateServer(SeldonComponent):
             prefix_cache_hbm_bytes=self._prefix_cache_hbm_bytes,
             prefix_cache_min_tokens=self._prefix_cache_min_tokens,
             admit_queue_limit=self._admit_queue_limit,
-            depth_groups=self._depth_groups,
-            depth_group_split_bytes=self._depth_group_split_bytes,
             prefill_chunk=self._prefill_chunk,
             flight_recorder_capacity=self._flight_recorder,
             restart_budget=self._restart_budget,
@@ -1761,8 +1743,7 @@ class GenerateServer(SeldonComponent):
             delta("gen_prefill_tokens", s["prefill_tokens"]),
             delta("gen_decode_steps", s["steps"]),
             # per-burst modeled HBM read traffic (params + bucketed KV per
-            # dispatched (sub)burst) — the depth-grouping win shows up as
-            # read bytes per decoded token dropping at mixed depths
+            # dispatched burst)
             delta("gen_burst_reads", s["burst_reads"]),
             delta("gen_burst_read_bytes", s["burst_read_bytes"]),
         ]
@@ -1776,21 +1757,6 @@ class GenerateServer(SeldonComponent):
             out.extend([
                 delta("gen_fused_steps", s["fused_steps"]),
                 delta("gen_fused_dispatches", s["fused_dispatches"]),
-            ])
-        if s.get("group_bursts"):
-            out.extend([
-                delta("gen_group_bursts", s["group_bursts"]),
-                delta("gen_group_lanes", s["group_lanes"]),
-                {
-                    "type": "GAUGE", "key": "gen_group_occupancy",
-                    # real lanes / gathered rows across grouped sub-bursts:
-                    # the pow2 pad overhead the cost model is trading away
-                    "value": round(
-                        s["group_lanes"]
-                        / max(1, s["group_lanes"] + s["group_pad_lanes"]),
-                        4,
-                    ),
-                },
             ])
         if s.get("shed"):
             out.append(delta("gen_shed_total", s["shed"]))

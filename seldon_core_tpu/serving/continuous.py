@@ -31,22 +31,10 @@ calls"). This scheduler closes that gap the TPU way:
 * The KV cache is held as per-layer arrays and updated IN PLACE: only the
   one-position scatter touches HBM per step (a stacked cache threaded
   through the layer scan made XLA rewrite every byte of it every step).
-  The attention READ is bounded by a static bucket covering the deepest
-  lane's position (host-tracked, no sync) — decode cost follows the live
-  prefix, not the allocated cache.
-* **Depth-aware sub-bursts** (``depth_groups``): at mixed prefix depths a
-  single burst bounds EVERY lane's read by the deepest lane's bucket, so
-  shallow lanes stream (and mask away) slab they never attend to. With
-  grouping on, live lanes are partitioned by attention bucket and the
-  poll dispatches one gathered sub-burst per group — each group's cache
-  read narrows to its OWN bucket. A sub-burst gathers its lanes' cache
-  prefixes into a ``[Gb, KV, bucket, Dh]`` slab (Gb = pow2 group-size
-  bucket, so one executable exists per (Gb, bucket) pair), runs the same
-  fused step scan, and scatters state back; a cost model (extra
-  sub-burst ~= one more param read per step vs. the modeled KV-read
-  saving) merges groups that aren't worth splitting. Groups are
-  re-planned every poll, so lanes re-pack automatically as their
-  prefixes deepen across bucket boundaries.
+  The attention READ follows the live prefix, not the allocated cache:
+  where ``ops.decode_attention.reads_ragged`` holds each lane's own
+  length bounds it, elsewhere a static bucket covering the deepest
+  lane's position (host-tracked, no sync).
 * **Chunked prefill interleave** (``prefill_chunk``): a long-prompt
   admission no longer stalls every decode lane for a full prompt-length
   forward. The prompt is split into ``prefill_chunk``-token slices
@@ -350,8 +338,8 @@ class ContinuousBatcher:
     """
 
     # floor for attn_bucket: cache reads must stay MXU/VPU-tileable on
-    # TPU. Tests lower it (via the class attribute) to exercise depth
-    # grouping at tiny cache lengths on CPU.
+    # TPU. Tests lower it (via the class attribute) to cross buckets at
+    # tiny cache lengths on CPU.
     MIN_ATTN_BUCKET = 64
 
     def __init__(
@@ -373,8 +361,6 @@ class ContinuousBatcher:
         prefix_cache_hbm_bytes: int = 0,
         prefix_cache_min_tokens: int = 16,
         admit_queue_limit: int = 0,
-        depth_groups: int = 0,
-        depth_group_split_bytes: Optional[int] = None,
         prefill_chunk: int = 0,
         flight_recorder_capacity: int = 512,
         restart_budget: int = 3,
@@ -417,7 +403,7 @@ class ContinuousBatcher:
         # decode steps with ON-DEVICE stop-token detection and per-lane
         # done masks (0 = off — the step-at-a-time burst path, exactly
         # the pre-fused code). pow2-floored like steps_per_poll so one
-        # executable exists per (K, attn bucket[, group size]).
+        # executable exists per (K, attn bucket).
         self.fused_steps_per_dispatch = max(0, int(fused_steps_per_dispatch))
         fk = self.fused_steps_per_dispatch
         while fk & (fk - 1):
@@ -436,12 +422,8 @@ class ContinuousBatcher:
         # is the practical TPU floor (the read must stay MXU/VPU-
         # tileable), enforced via the MIN_ATTN_BUCKET class attribute so
         # production configs keep the historical clamp while CPU tests
-        # lower it to exercise the depth-grouping machinery at tiny
-        # cache lengths
+        # lower it to cross buckets at tiny cache lengths
         self.attn_bucket = max(type(self).MIN_ATTN_BUCKET, int(attn_bucket))
-        # depth-aware sub-bursts: max sub-bursts per poll (0/1 = off —
-        # the single-burst path is byte-identical to pre-grouping code)
-        self.depth_groups = max(0, int(depth_groups))
         # chunked prefill: prompt tokens per interleaved prefill slice
         # (0 = off; prompts whose bucket fits one chunk never chunk)
         self.prefill_chunk = max(0, int(prefill_chunk))
@@ -576,17 +558,11 @@ class ContinuousBatcher:
         # decode steps (the prefix cache's win shows up as prefill_tokens
         # dropping while prefix_tokens_saved climbs)
         # burst_reads/burst_read_bytes: modeled HBM read traffic of
-        # dispatched decode (sub)bursts — params once per step plus each
+        # dispatched decode bursts — params once per step plus each
         # lane-row's bucketed KV read (spec rounds are excluded: their
         # draft/verify byte model lives in modelbench's round-true MBU).
-        # group_* feed the depth-grouping occupancy gauge: real lanes vs
-        # pow2-bucket pad rows across grouped sub-bursts.
-        # lane_steps = sum over dispatched (sub)bursts of k x rows — the
-        # occupancy denominator. With grouping OFF it equals steps x
-        # slots; with grouping ON a sub-burst contributes only its
-        # gathered rows, so occupancy stays comparable across configs
-        # (steps alone would halve apparent occupancy whenever a poll
-        # splits into two sub-bursts)
+        # lane_steps = sum over dispatched bursts of k x rows (steps x
+        # slots) — the occupancy denominator.
         self.stats = {
             "admitted": 0, "finished": 0, "cancelled": 0, "steps": 0,
             "lane_steps": 0,
@@ -597,13 +573,12 @@ class ContinuousBatcher:
             "shed": 0,
             "burst_reads": 0, "burst_read_bytes": 0,
             # how far the ragged decode read engages: positions of K and V
-            # the kernel streams per dispatched (sub)burst (each lane's
+            # the kernel streams per dispatched burst (each lane's
             # length rounded up to the kernel's block, step by step), and
             # what the bucket's dots read of the same burst (rows x
             # attn_len x steps). Their ratio is the share of the old read
             # still made
             "kv_positions_read": 0, "kv_positions_bucket": 0,
-            "group_bursts": 0, "group_lanes": 0, "group_pad_lanes": 0,
             # disaggregated serving: slabs/bytes shipped out (prefill
             # role), slabs/bytes admitted in (decode role), and transfer
             # bytes the decode-side radix cache deduplicated away
@@ -685,9 +660,8 @@ class ContinuousBatcher:
         self.tenant_slo_pending: Dict[str, "collections.deque"] = {}
         self.tenant_slo_recent: Dict[str, "collections.deque"] = {}
         # scheduler flight recorder: one structured record per poll (batch
-        # composition, depth-group plan + cost-model verdict, chunk
-        # interleave, shed events), bounded + drop-oldest, cheap enough to
-        # leave on (0 = off)
+        # composition, the burst's plan, chunk interleave, shed events),
+        # bounded + drop-oldest, cheap enough to leave on (0 = off)
         from .flightrecorder import FlightRecorder
 
         self.flight: Optional[FlightRecorder] = (
@@ -708,9 +682,8 @@ class ContinuousBatcher:
             profiler if profiler is not None else DeviceTimeLedger()
         )
         # test/debug hook: set to a list and every dispatched decode
-        # (sub)burst appends {"lanes", "attn_len", "need"} — the
-        # scheduler-level proof that no lane's read bound exceeds its
-        # group's bucket
+        # burst appends {"lanes", "attn_len", "need"} — the scheduler-
+        # level record of the bucket each burst and each lane was given
         self.trace_groups: Optional[List[Dict[str, Any]]] = None
         # -- HBM pressure: unified ledger + watermark controller ----------
         # live decode footprint + staging slabs + prefix cache + pending
@@ -796,8 +769,6 @@ class ContinuousBatcher:
         self._retune_census: Dict[str, Any] = {
             # fused Ks warm() compiles: pow2s in [min(k, fused), fused]
             "fused_ks": tuple(sorted(_census_fks)),
-            # group-burst variants exist only when boot depth_groups > 1
-            "depth_groups": self.depth_groups,
             # chunk executables exist only for the boot chunk size
             "prefill_chunk": self.prefill_chunk,
             # warm()'s attention-bucket overhang covered this depth
@@ -1038,7 +1009,9 @@ class ContinuousBatcher:
             tokens so the host syncs once per burst. ``attn_len`` (static)
             bounds the cache read — the scheduler picks a bucket >= every
             lane's end-of-burst position, so one executable exists per
-            (k, bucket) pair and the read narrows to live prefix."""
+            (k, bucket) pair and the read narrows to live prefix; None
+            where each lane's own length bounds it (``_ragged_read``):
+            one executable per k."""
 
             def body(carry, _):
                 ks, vs, cur_tok, pos, keys = carry
@@ -1079,25 +1052,6 @@ class ContinuousBatcher:
             pos = jnp.where(alive, pos + 1, pos)
             return cur_tok, pos, ks, vs, keys
 
-        def fused_scan_body(params, act, temps, stops, attn_len, park):
-            """The ONE fused scan body both burst variants run — a fix to
-            the done condition or the budget decrement lands in the
-            whole-batch AND the gathered depth-group executable by
-            construction, so the grouped-vs-whole-batch byte-identity
-            contract cannot drift one-sided."""
-            def body(carry, _):
-                ks, vs, cur, p, kk, budget, done = carry
-                alive = act & ~done
-                cur, p, ks, vs, kk = fused_masked_step(
-                    params, ks, vs, cur, p, alive, temps, kk, attn_len, park
-                )
-                budget = budget - alive.astype(jnp.int32)
-                done = done | (alive & ((cur == stops) | (budget <= 0)))
-                return (ks, vs, cur, p, kk, budget, done), (
-                    jnp.where(alive, cur, 0), alive,
-                )
-            return body
-
         def fused_stop_burst(params, cache, cur_tok, pos, active, temps,
                              keys, stops, budgets, k, attn_len):
             """k decode steps with ON-DEVICE stop-token detection and
@@ -1115,8 +1069,19 @@ class ContinuousBatcher:
             (decremented on device, re-uploaded only on membership
             changes)."""
             park = cache["k"][0].shape[2]  # static: index >= T is dropped
-            body = fused_scan_body(params, active, temps, stops, attn_len,
-                                   park)
+
+            def body(carry, _):
+                ks, vs, cur, p, kk, budget, done = carry
+                alive = active & ~done
+                cur, p, ks, vs, kk = fused_masked_step(
+                    params, ks, vs, cur, p, alive, temps, kk, attn_len, park
+                )
+                budget = budget - alive.astype(jnp.int32)
+                done = done | (alive & ((cur == stops) | (budget <= 0)))
+                return (ks, vs, cur, p, kk, budget, done), (
+                    jnp.where(alive, cur, 0), alive,
+                )
+
             # a lane can arrive already-done: its stop token was emitted
             # in an earlier burst the host has not read yet (pipeline
             # lag), or its budget was fully covered — either way it runs
@@ -1134,58 +1099,6 @@ class ContinuousBatcher:
             toks = jnp.concatenate([cur_tok[None, :], toks], axis=0)
             return (toks, counts, done, cur, pos, {"k": ks, "v": vs}, keys,
                     budgets)
-
-        def fused_group_stop_burst(params, cache, cur_tok, pos, temps, keys,
-                                   stops, budgets, lane_ix, n_real, k,
-                                   attn_len):
-            """Stop-aware fused burst over a GATHERED depth group: the
-            group_burst gather/scatter discipline (pads parked at
-            ``attn_len``, no pad state leaking back into other groups'
-            lanes) composed with fused_stop_burst's done masks — one
-            executable per (group-size bucket, attn bucket, K) triple,
-            all precompiled by warm()."""
-            act = jnp.arange(lane_ix.shape[0], dtype=jnp.int32) < n_real
-            g_tok = cur_tok[lane_ix]
-            g_pos = jnp.where(act, pos[lane_ix], attn_len)
-            g_temps = temps[lane_ix]
-            g_keys = keys[lane_ix]
-            g_stop = jnp.where(act, stops[lane_ix], -1)
-            g_budget = budgets[lane_ix]
-            g_ks = [layer[lane_ix, :, :attn_len, :] for layer in cache["k"]]
-            g_vs = [layer[lane_ix, :, :attn_len, :] for layer in cache["v"]]
-            # pads park their writes at attn_len (group_burst's
-            # discipline); full-depth sliced views need no mask bound
-            body = fused_scan_body(params, act, g_temps, g_stop, None,
-                                   attn_len)
-            done0 = ~act | (g_budget <= 0) | (g_tok == g_stop)
-            ((g_ks, g_vs, tok_out, g_pos, g_keys, g_budget, done),
-             (toks, alive_rows)) = lax.scan(
-                body, (g_ks, g_vs, g_tok, g_pos, g_keys, g_budget, done0),
-                None, length=k,
-            )
-            counts = alive_rows.astype(jnp.int32).sum(axis=0)
-            toks = jnp.concatenate([g_tok[None, :], toks], axis=0)
-            new = {
-                "k": [
-                    layer.at[lane_ix, :, :attn_len, :].set(g)
-                    for layer, g in zip(cache["k"], g_ks)
-                ],
-                "v": [
-                    layer.at[lane_ix, :, :attn_len, :].set(g)
-                    for layer, g in zip(cache["v"], g_vs)
-                ],
-            }
-            cur_tok = cur_tok.at[lane_ix].set(
-                jnp.where(act, tok_out, cur_tok[lane_ix])
-            )
-            pos = pos.at[lane_ix].set(jnp.where(act, g_pos, pos[lane_ix]))
-            keys = keys.at[lane_ix].set(
-                jnp.where(act[:, None], g_keys, keys[lane_ix])
-            )
-            budgets = budgets.at[lane_ix].set(
-                jnp.where(act, g_budget, budgets[lane_ix])
-            )
-            return toks, counts, done, cur_tok, pos, new, keys, budgets
 
         # -- prefix-cache executables ---------------------------------------
         def prefix_prefill(params, slab, suffix, start_pos, last_index, seed, temp):
@@ -1245,61 +1158,6 @@ class ContinuousBatcher:
                 for name in ("k", "v")
             }
 
-        # -- depth-aware grouped sub-burst -----------------------------------
-        def group_burst(params, cache, cur_tok, pos, temps, keys, lane_ix,
-                        n_real, k, attn_len):
-            """k fused decode steps over a GATHERED lane group: lane_ix
-            ([Gb] int32, DISTINCT lanes; rows >= n_real are pads) selects
-            the group, each lane's cache prefix [0, attn_len) is gathered
-            into a [Gb, KV, attn_len, Dh] slab, the burst scans over the
-            slab, and state scatters back. The read per step is the
-            GROUP's bucket, not the batch max — the whole point. Pads are
-            parked at position attn_len so their K/V writes fall out of
-            bounds and are dropped (jax scatter semantics); their lanes'
-            slabs round-trip bit-identical, so padding with lanes of
-            other (deeper) groups is safe in any dispatch order. One
-            executable per (Gb, attn_len) pair; gather+scatter cost
-            ~4/k of the group's per-burst read, amortised by the scan."""
-            act = jnp.arange(lane_ix.shape[0], dtype=jnp.int32) < n_real
-            g_tok = cur_tok[lane_ix]
-            g_pos = jnp.where(act, pos[lane_ix], attn_len)
-            g_temps = temps[lane_ix]
-            g_keys = keys[lane_ix]
-            g_ks = [layer[lane_ix, :, :attn_len, :] for layer in cache["k"]]
-            g_vs = [layer[lane_ix, :, :attn_len, :] for layer in cache["v"]]
-
-            def body(carry, _):
-                ks, vs, tok, p, kk = carry
-                nxt, p, ks, vs, kk = fused_step(
-                    params, ks, vs, tok, p, act, g_temps, kk, None
-                )
-                return (ks, vs, nxt, p, kk), nxt
-
-            (g_ks, g_vs, tok_out, g_pos, g_keys), toks = lax.scan(
-                body, (g_ks, g_vs, g_tok, g_pos, g_keys), None, length=k
-            )
-            toks = jnp.concatenate([g_tok[None, :], toks], axis=0)
-            new = {
-                "k": [
-                    layer.at[lane_ix, :, :attn_len, :].set(g)
-                    for layer, g in zip(cache["k"], g_ks)
-                ],
-                "v": [
-                    layer.at[lane_ix, :, :attn_len, :].set(g)
-                    for layer, g in zip(cache["v"], g_vs)
-                ],
-            }
-            # pads (inactive rows) must not leak burst-local state back
-            # into lanes that belong to OTHER groups' bursts
-            cur_tok = cur_tok.at[lane_ix].set(
-                jnp.where(act, tok_out, cur_tok[lane_ix])
-            )
-            pos = pos.at[lane_ix].set(jnp.where(act, g_pos, pos[lane_ix]))
-            keys = keys.at[lane_ix].set(
-                jnp.where(act[:, None], g_keys, keys[lane_ix])
-            )
-            return toks, cur_tok, pos, new, keys
-
         # -- preemption recompute-resume: teacher-forced decode replay -------
         def replay_burst(params, cache, lane_ix, toks, act, start_pos,
                          attn_len):
@@ -1312,9 +1170,9 @@ class ContinuousBatcher:
             decode op. ``toks``/``act`` are a fixed-length (k) forced
             chunk (pads inactive: their writes land at the unadvanced
             position the lane's next real step overwrites before any
-            read). One executable per (k, attn_len) pair, same discipline
-            as group_burst; the gathered [1]-lane execution is bitwise
-            equal to the full-batch row (the depth-grouping invariant)."""
+            read). One executable per (k, attn_len) pair; the gathered
+            [1]-lane execution is bitwise equal to the full-batch row
+            (tests/test_pressure.py holds resume to byte identity)."""
             g_ks = [layer[lane_ix, :, :attn_len, :] for layer in cache["k"]]
             g_vs = [layer[lane_ix, :, :attn_len, :] for layer in cache["v"]]
             pos0 = jnp.full((1,), start_pos, jnp.int32)
@@ -1388,32 +1246,39 @@ class ContinuousBatcher:
         self._burst_fn = jax.jit(
             fused_burst, donate_argnums=(1,), static_argnums=(7, 8)
         )
-        self._group_burst_fn = jax.jit(
-            group_burst, donate_argnums=(1,), static_argnums=(8, 9)
-        )
         self._fused_burst_fn = jax.jit(
             fused_stop_burst, donate_argnums=(1,), static_argnums=(9, 10)
-        )
-        self._fused_group_fn = jax.jit(
-            fused_group_stop_burst, donate_argnums=(1,),
-            static_argnums=(10, 11),
         )
         self._chunk_fn = jax.jit(
             chunk_prefill_step, donate_argnums=(1,), static_argnums=(7, 8)
         )
         self._splice_fn = jax.jit(splice_slab, donate_argnums=(0,))
-        # depth-grouping cost model: a separate sub-burst re-reads the
-        # params every step; splitting a shallower group off only pays
-        # when its modeled KV-read saving per step beats that (override
-        # via depth_group_split_bytes — tests force 0 to always split)
+        # K and V bytes per cached position, all layers: the unit of the
+        # modeled burst read and of the pressure ledger
         self._kv_key_bytes = 2 * sum(
             layer.dtype.itemsize * layer.shape[1] * layer.shape[3]
             for layer in self._cache["k"]
         )
         # the ragged decode read's granule (stats["kv_positions_read"])
-        from ..ops.decode_attention import BLOCK
+        from ..ops.decode_attention import BLOCK, reads_ragged
 
         self._kv_read_block = BLOCK
+        # whether the decode step's read takes each lane's own length on
+        # the platform the bursts are lowered for (the cache's devices).
+        # Where it does, the bucket bounds nothing in _burst_fn and
+        # _fused_burst_fn: they are warmed and dispatched with
+        # attn_len=None, one executable per K and none per bucket. The
+        # host's arithmetic (need, the counters, the variant names) keeps
+        # the bucket either way.
+        layer0 = self._cache["k"][0]
+        self._ragged_read = reads_ragged(
+            next(iter(layer0.devices())).platform,
+            (self.slots, model.cfg.n_heads, 1, layer0.shape[3]),
+            layer0.shape,
+            (jnp.dtype(model.cfg.dtype), layer0.dtype,
+             self._cache["v"][0].dtype),
+            mesh,
+        )
         # the draft cache's per-token K/V price (speculation only): the
         # pressure ledger charges live lanes for BOTH caches while the
         # draft is resident, and stops when rung 2 frees it
@@ -1448,11 +1313,6 @@ class ContinuousBatcher:
             _leaf_shard_bytes(leaf)
             for leaf in jax.tree_util.tree_leaves(self.params)
             if hasattr(leaf, "nbytes")
-        )
-        self._group_split_bytes = (
-            int(depth_group_split_bytes)
-            if depth_group_split_bytes is not None
-            else self._param_bytes
         )
         self._insert_fn = jax.jit(insert, donate_argnums=(0,))
         self._prefill_fn = jax.jit(prefill_one)
@@ -2508,17 +2368,16 @@ class ContinuousBatcher:
     # speculate_tokens, cache geometry) would invalidate compiled
     # executables or reallocate device state and is refused typed
     RETUNABLE_KNOBS = (
-        "fused_steps_per_dispatch", "depth_groups",
-        "depth_group_split_bytes", "prefill_chunk", "pipeline_depth",
+        "fused_steps_per_dispatch", "prefill_chunk", "pipeline_depth",
         "admit_queue_limit", "pressure_high", "pressure_low",
     )
 
     def retune_census(self) -> Dict[str, Any]:
         """The boot-time compile census a retune is validated against:
-        which fused Ks warm() compiled, whether group-burst variants
-        exist, the one chunk size with precompiled executables, and the
-        warmed pipeline depth. The planner reads this to prune its
-        search space to configs this member can actually flip to."""
+        which fused Ks warm() compiled, the one chunk size with
+        precompiled executables, and the warmed pipeline depth. The
+        planner reads this to prune its search space to configs this
+        member can actually flip to."""
         return dict(self._retune_census)
 
     def serving_config(self) -> Dict[str, Any]:
@@ -2533,8 +2392,6 @@ class ContinuousBatcher:
             "fused_steps_per_dispatch": int(
                 self.fused_steps_per_dispatch or 0
             ),
-            "depth_groups": int(self.depth_groups or 0),
-            "depth_group_split_bytes": int(self._group_split_bytes or 0),
             "kv_tier_bytes": int(
                 getattr(self._kv_tier, "budget_bytes", 0) or 0
             ),
@@ -2554,14 +2411,13 @@ class ContinuousBatcher:
         and — for a ``prefill_chunk`` change — only once in-flight
         chunked prefills have drained. Byte identity is preserved by
         construction: every retunable knob already carries an
-        on-vs-off/byte-identity contract (fused decode, depth grouping,
-        chunked prefill, pressure, admission caps), so a mid-run retune
+        on-vs-off/byte-identity contract (fused decode, chunked
+        prefill, pressure, admission caps), so a mid-run retune
         produces the same tokens as booting with the new values.
 
         Validation is synchronous and typed (:class:`RetuneError`):
         a value outside the boot compile census — a fused K warm() never
-        compiled, depth grouping on a member booted without group
-        variants, a chunk size with no precompiled chunk executables, a
+        compiled, a chunk size with no precompiled chunk executables, a
         pipeline deepening past the warmed attention overhang — is
         refused HERE, before staging, so the scheduler can never be
         asked to compile mid-traffic.
@@ -2607,21 +2463,6 @@ class ContinuousBatcher:
                     "can be retuned to"
                 )
             target["fused_steps_per_dispatch"] = (raw, fk)
-        if "depth_groups" in knobs:
-            dg = _int("depth_groups")
-            if dg > 1 and census["depth_groups"] <= 1:
-                raise RetuneError(
-                    "depth_groups>1 requires group-burst variants, which "
-                    "warm() only compiles when the member boots with "
-                    "depth_groups>1"
-                )
-            target["depth_groups"] = dg
-        if "depth_group_split_bytes" in knobs:
-            # pure host-side cost-model parameter: no executable depends
-            # on it, any non-negative value is in census
-            target["depth_group_split_bytes"] = _int(
-                "depth_group_split_bytes"
-            )
         if "prefill_chunk" in knobs:
             pc = _int("prefill_chunk")
             if pc not in (0, census["prefill_chunk"]):
@@ -2708,16 +2549,6 @@ class ContinuousBatcher:
                         self._fused_sync = False
                     self.fused_steps_per_dispatch = raw
                     self._fused_k = fk
-                elif name == "depth_groups":
-                    _apply(
-                        name, self.depth_groups, val,
-                        lambda v: setattr(self, "depth_groups", v),
-                    )
-                elif name == "depth_group_split_bytes":
-                    _apply(
-                        name, self._group_split_bytes, val,
-                        lambda v: setattr(self, "_group_split_bytes", v),
-                    )
                 elif name == "prefill_chunk":
                     _apply(
                         name, self.prefill_chunk, val,
@@ -3238,8 +3069,11 @@ class ContinuousBatcher:
             )
         active = jnp.zeros((self.slots,), bool)
         temps = jnp.zeros((self.slots,), jnp.float32)
-        for attn_len in attn_lens:
-            if self._spec_burst_fn is not None:
+        # the plain and the stop-aware burst: one variant per bucket, or
+        # one in all where the read bounds itself (_ragged_read)
+        burst_lens = [None] if self._ragged_read else attn_lens
+        if self._spec_burst_fn is not None:
+            for attn_len in attn_lens:
                 caches = {
                     "k": self._cache["k"], "v": self._cache["v"],
                     "dk": self._draft_cache["k"], "dv": self._draft_cache["v"],
@@ -3257,7 +3091,8 @@ class ContinuousBatcher:
                 self._cache = {"k": nc["k"], "v": nc["v"]}
                 self._draft_cache = {"k": nc["dk"], "v": nc["dv"]}
                 self._cache["k"][0].block_until_ready()  # seldon-lint: disable=host-sync-hot-path (warm precompile: intentional sync while the loop is idle)
-            else:
+        else:
+            for attn_len in burst_lens:
                 toks, self._cur_tok, self._pos, self._cache, self._keys = (
                     self._burst_fn(
                         self.params, self._cache, self._cur_tok, self._pos,
@@ -3265,23 +3100,13 @@ class ContinuousBatcher:
                     )
                 )
                 toks.block_until_ready()  # seldon-lint: disable=host-sync-hot-path (warm precompile: intentional sync while the loop is idle)
-                if self.depth_groups > 1:
-                    # grouped sub-burst variants: every pow2 group-size
-                    # bucket at this attention bucket (mixed-depth polls
-                    # pick any of them; compile-before-listen holds)
-                    for gb in self._warm_group_sizes():
-                        lane_ix = jnp.arange(gb, dtype=jnp.int32)
-                        toks, self._cur_tok, self._pos, self._cache, self._keys = (
-                            self._group_burst_fn(
-                                self.params, self._cache, self._cur_tok,
-                                self._pos, temps, self._keys, lane_ix,
-                                0, k, attn_len,
-                            )
-                        )
-                        toks.block_until_ready()  # seldon-lint: disable=host-sync-hot-path (warm precompile: intentional sync while the loop is idle)
+            logger.info(
+                "warm: decode burst compile census: %d variant(s) "
+                "(k=%d x attn=%s)", len(burst_lens), k, burst_lens,
+            )
         if self._fused_k > 0 and self._spec_burst_fn is None:
-            # stop-aware fused variants: every (K, attn bucket, group
-            # size) the adaptive-K plan can reach — K is a pow2 in
+            # stop-aware fused variants: every (K, attn bucket) the
+            # adaptive-K plan can reach — K is a pow2 in
             # [min(steps_per_poll, fused), fused] (see _fused_plan), so
             # the shrink can never ask for an executable this loop did
             # not build. The one-line census below is the CI-visible
@@ -3294,11 +3119,9 @@ class ContinuousBatcher:
                 fks.append(fk)
                 fk //= 2
             fks = sorted(fks)
-            gbs = self._warm_group_sizes() if self.depth_groups > 1 else []
             stops0 = jnp.full((self.slots,), -1, jnp.int32)
             budget0 = jnp.zeros((self.slots,), jnp.int32)
-            compiled = 0
-            for attn_len in attn_lens:
+            for attn_len in burst_lens:
                 for fk in fks:
                     (
                         toks, _counts, _done, self._cur_tok, self._pos,
@@ -3309,23 +3132,10 @@ class ContinuousBatcher:
                         attn_len,
                     )
                     toks.block_until_ready()  # seldon-lint: disable=host-sync-hot-path (warm precompile: intentional sync while the loop is idle)
-                    compiled += 1
-                    for gb in gbs:
-                        lane_ix = jnp.arange(gb, dtype=jnp.int32)
-                        (
-                            toks, _counts, _done, self._cur_tok, self._pos,
-                            self._cache, self._keys, budget0,
-                        ) = self._fused_group_fn(
-                            self.params, self._cache, self._cur_tok,
-                            self._pos, temps, self._keys, stops0, budget0,
-                            lane_ix, 0, fk, attn_len,
-                        )
-                        toks.block_until_ready()  # seldon-lint: disable=host-sync-hot-path (warm precompile: intentional sync while the loop is idle)
-                        compiled += 1
             logger.info(
                 "warm: fused decode compile census: %d variant(s) "
-                "(k=%s x attn=%s x group_sizes=%s)",
-                compiled, fks, attn_lens, gbs or [self.slots],
+                "(k=%s x attn=%s)",
+                len(burst_lens) * len(fks), fks, burst_lens,
             )
         if self.mesh is not None:
             # sharded-serving census, same PR-13 contract as the fused
@@ -3450,71 +3260,6 @@ class ContinuousBatcher:
         req.emit_span(operation, start_t, end_t, tags)
 
     @scheduler_only
-    def _plan_groups(self, adv: int):
-        """Partition live lanes into <= depth_groups sub-bursts by
-        attention-read bucket. Returns ``([(lanes, bucket)], need)`` with
-        groups shallow-first; ``need[slot]`` is the lane's OWN bucket.
-
-        Packing: one candidate group per distinct bucket, then adjacent
-        groups merge shallow-into-deep while the modeled per-step cost of
-        keeping them split (an extra param read — _group_split_bytes)
-        exceeds the KV-read saving (lanes x bucket gap x _kv_key_bytes),
-        or while the group count exceeds the cap. Merging always prefers
-        filling the cheapest gap first, so a lane spills to a deeper
-        bucket only when the cost model says the split isn't worth it."""
-        need = {
-            slot: self._attn_need(self._pos_host[slot] + adv)
-            for slot in self._active
-        }
-        groups = [
-            ([s for s in sorted(need) if need[s] == b], b)
-            for b in sorted(set(need.values()))
-        ]
-        if self.depth_groups <= 1 or len(groups) == 1:
-            if len(groups) > 1:
-                groups = [(sorted(need), max(need.values()))]
-            return groups, need
-        while len(groups) > 1:
-            best_i, best_delta = None, None
-            for i in range(len(groups) - 1):
-                lanes_s, b_s = groups[i]
-                _, b_d = groups[i + 1]
-                # per-step cost of MERGING group i into its deeper
-                # neighbour, minus the param read the merge saves
-                delta = (
-                    len(lanes_s) * (b_d - b_s) * self._kv_key_bytes
-                    - self._group_split_bytes
-                )
-                if best_delta is None or delta < best_delta:
-                    best_i, best_delta = i, delta
-            if len(groups) > self.depth_groups or best_delta < 0:
-                lanes_s, _ = groups.pop(best_i)
-                lanes_d, b_d = groups[best_i]
-                groups[best_i] = (sorted(lanes_d + lanes_s), b_d)
-            else:
-                break
-        return groups, need
-
-    def _group_size_bucket(self, n: int) -> int:
-        """pow2 group-size bucket (one sub-burst executable per size)."""
-        g = 1
-        while g < n:
-            g <<= 1
-        return min(g, self.slots)
-
-    def _warm_group_sizes(self) -> List[int]:
-        """Every pow2 group-size bucket a mixed-depth poll can dispatch,
-        plus the whole batch — the ONE enumeration both warm()'s grouped
-        sub-burst loop and the fused compile census iterate, so the two
-        can never precompile different variant sets."""
-        gb = 1
-        gbs = [self.slots]
-        while gb < self.slots:
-            gbs.append(gb)
-            gb <<= 1
-        return sorted(set(gbs))
-
-    @scheduler_only
     def _fused_plan(self, k_max=None):
         """Adaptive K for the stop-aware fused burst: ``(k, reason)``.
 
@@ -3535,8 +3280,8 @@ class ContinuousBatcher:
           a K-step burst would stall the flip/checkpoint by K steps.
 
         The result is always a pow2 <= fused_steps_per_dispatch, so one
-        precompiled executable exists per (K, attn bucket[, group size])
-        and the shrink can never trigger an inline XLA compile.
+        precompiled executable exists per (K, attn bucket) and the shrink
+        can never trigger an inline XLA compile.
 
         ``k_max``: the caller's snapshot of ``self._fused_k`` — the loop
         passes the same value that decided ``use_fused`` this poll, so a
@@ -5271,14 +5016,13 @@ class ContinuousBatcher:
         when the lane was pre-freed and re-admitted before this read. A
         request whose output is already complete (``credit_done``) is
         skipped: its remaining rows are overshoot decode, dropped by
-        design. ``snapshot[slot] = (s, start_row, col)`` — col is the
-        lane's COLUMN in this burst's token matrix (its gathered row for
-        a depth-group sub-burst, the slot id for a whole-batch burst).
-        The arrays are on the host already (:meth:`_read_burst`)."""
-        for slot, (s, start, col) in snapshot.items():
+        design. ``snapshot[slot] = (s, start_row)``; the lane's column in
+        the burst's token matrix is its slot. The arrays are on the host
+        already (:meth:`_read_burst`)."""
+        for slot, (s, start) in snapshot.items():
             if s.credit_done:
                 continue
-            if self._credit(s, host_toks[start:, col]):
+            if self._credit(s, host_toks[start:, slot]):
                 if self._active.get(slot) is s:
                     self._finish(slot)
                 else:
@@ -5289,7 +5033,7 @@ class ContinuousBatcher:
     def _process_fused_burst(self, host_toks, counts, done, snapshot,
                              k) -> None:
         """Credit one stop-aware fused burst. Per lane, exactly
-        ``counts[col]`` tokens were emitted before its on-device done
+        ``counts[slot]`` tokens were emitted before its on-device done
         mask froze it (stop token / budget), so — unlike
         :meth:`_process_burst` — no overshoot rows exist to drop; the
         host just credits the counted span (row 0 still carries the
@@ -5301,12 +5045,12 @@ class ContinuousBatcher:
         from the worst-case k advance to the lane's actual alive steps —
         a lane frozen early must not inflate the pressure ledger or the
         attention-bucket need until the host observes it."""
-        for slot, (s, start, col) in snapshot.items():
+        for slot, (s, start) in snapshot.items():
             if self._active.get(slot) is s and slot in self._pos_host:
-                self._pos_host[slot] -= k - int(counts[col])
+                self._pos_host[slot] -= k - int(counts[slot])
             if s.credit_done:
                 continue
-            span = host_toks[start: 1 + int(counts[col]), col]
+            span = host_toks[start: 1 + int(counts[slot]), slot]
             if not len(span):
                 continue
             if self._credit(s, span):
@@ -5805,17 +5549,15 @@ class ContinuousBatcher:
                     # attention-read bucket: the smallest attn_bucket
                     # multiple covering every active lane's end-of-burst
                     # position (host-tracked, no sync). One executable per
-                    # bucket. With depth grouping, each sub-burst narrows
-                    # to ITS lanes' bucket instead (plan below).
+                    # bucket, except where the read bounds itself
+                    # (_ragged_read).
                     attn_len = self._attn_need(
                         max(self._pos_host[i] for i in self._active) + adv
                     )
                     if self._spec_active():
                         # snapshot BEFORE dispatch: tokens of this burst
                         # belong to these occupants, whatever the host
-                        # learns later. (Spec bursts stay whole-batch:
-                        # their per-round advance is data-dependent and
-                        # the verify pass already amortises param reads.)
+                        # learns later.
                         snapshot = {}
                         t_dispatch = time.monotonic()
                         for slot, s in self._active.items():
@@ -5870,221 +5612,121 @@ class ContinuousBatcher:
                             t_dispatch,
                         ))
                     else:
-                        groups, need = self._plan_groups(adv)
+                        # one burst over all live lanes. need[slot] is
+                        # the lane's OWN bucket; the burst's, attn_len,
+                        # is their maximum
+                        need = {
+                            slot: self._attn_need(self._pos_host[slot] + adv)
+                            for slot in self._active
+                        }
+                        lanes = sorted(need)
                         if flight is not None:
-                            # depth-group plan + cost-model verdict: the
-                            # gap between distinct need-buckets and the
-                            # dispatched group count IS how many splits
-                            # the cost model merged away this poll. ONE
-                            # composition record per fused poll (mode
-                            # "fused", the adaptive K and why it shrank)
-                            # — never per fused step, so the recorder's
-                            # host cost stays per-poll as shipped.
+                            # ONE composition record per poll (for a fused
+                            # poll the adaptive K and why it shrank) —
+                            # never per step, so the recorder's host cost
+                            # stays per-poll as shipped.
                             poll_plan = {
                                 "mode": "fused" if use_fused else "decode",
                                 "k": k,
-                                "groups": [
-                                    {"lanes": len(lanes), "bucket": b}
-                                    for lanes, b in groups
-                                ],
+                                "lanes": len(lanes),
+                                "bucket": attn_len,
                                 "distinct_buckets": len(set(need.values())),
-                                "merged": len(set(need.values())) - len(groups),
                             }
                             if use_fused:
                                 poll_plan["k_max"] = fused_k
                                 if fused_reason is not None:
                                     poll_plan["shrunk_by"] = fused_reason
-                        # per-lane bookkeeping happens per SUB-burst: a
-                        # lane's tokens are credited against the column it
-                        # occupied in the burst that decoded it
-                        burst_tenant = (
-                            self._burst_tenant() if self._prof.enabled
-                            else ""
+                        # snapshot BEFORE dispatch, as the spec arm does
+                        snapshot = {}
+                        t_dispatch = time.monotonic()
+                        for slot in lanes:
+                            s = self._active[slot]
+                            first = s.first_pending
+                            snapshot[slot] = (s, 0 if first else 1)
+                            if first:
+                                s.request.first_dispatch_t = t_dispatch
+                            s.first_pending = False
+                            s.dispatched += k + (1 if first else 0)
+                            self._pos_host[slot] += adv
+                        read_bytes = k * (
+                            self._param_bytes
+                            + self.slots * attn_len * self._kv_key_bytes
                         )
-                        for lanes, g_bucket in groups:
-                            snapshot = {}
-                            t_dispatch = time.monotonic()
-                            for col, slot in enumerate(lanes):
-                                s = self._active[slot]
-                                first = s.first_pending
-                                snapshot[slot] = (s, 0 if first else 1, col)
-                                if first:
-                                    s.request.first_dispatch_t = t_dispatch
-                                s.first_pending = False
-                                s.dispatched += k + (1 if first else 0)
-                                self._pos_host[slot] += adv
-                            counts = done_bits = None
-                            if len(groups) == 1:
-                                # single depth group: the exact pre-grouping
-                                # whole-batch path — no gather, columns are
-                                # lane ids
-                                for slot in lanes:
-                                    snapshot[slot] = (
-                                        snapshot[slot][0], snapshot[slot][1],
-                                        slot,
-                                    )
-                                rows = self.slots
-                                if use_fused:
-                                    with self._prof.measure(
-                                        "fused_burst",
-                                        variant=f"k{k}b{g_bucket}",
-                                        tenant=burst_tenant,
-                                        bytes_read=k * (
-                                            self._param_bytes
-                                            + rows * g_bucket
-                                            * self._kv_key_bytes
-                                        ),
-                                        tokens=k * rows,
-                                    ) as _m, device_trace(
-                                        "gen.decode_burst"
-                                    ):
-                                        (
-                                            toks, counts, done_bits,
-                                            self._cur_tok, self._pos,
-                                            self._cache, self._keys,
-                                            self._budget_dev,
-                                        ) = self._fused_burst_fn(
-                                            self.params, self._cache,
-                                            self._cur_tok, self._pos,
-                                            active_dev, temps_dev,
-                                            self._keys, self._stops_dev,
-                                            self._budget_dev, k, g_bucket,
-                                        )
-                                        _m.sync(toks)
-                                else:
-                                    with self._prof.measure(
-                                        "decode_burst",
-                                        variant=f"b{g_bucket}",
-                                        tenant=burst_tenant,
-                                        bytes_read=k * (
-                                            self._param_bytes
-                                            + rows * g_bucket
-                                            * self._kv_key_bytes
-                                        ),
-                                        tokens=k * rows,
-                                    ) as _m, device_trace(
-                                        "gen.decode_burst"
-                                    ):
-                                        toks, self._cur_tok, self._pos, self._cache, self._keys = (
-                                            self._burst_fn(
-                                                self.params, self._cache,
-                                                self._cur_tok, self._pos,
-                                                active_dev, temps_dev, self._keys,
-                                                k, g_bucket,
-                                            )
-                                        )
-                                        _m.sync(toks)
-                            else:
-                                gb = self._group_size_bucket(len(lanes))
-                                pads = [
-                                    i for i in range(self.slots)
-                                    if i not in snapshot
-                                ][: gb - len(lanes)]
-                                lane_ix = jnp.asarray(
-                                    lanes + pads, jnp.int32
+                        # the executable's own bound: none where the read
+                        # takes each lane's length (the host's arithmetic
+                        # above and below keeps the bucket either way)
+                        bound = None if self._ragged_read else attn_len
+                        with self._prof.measure(
+                            "fused_burst" if use_fused else "decode_burst",
+                            variant=f"k{k}b{attn_len}" if use_fused
+                            else f"b{attn_len}",
+                            tenant=self._burst_tenant()
+                            if self._prof.enabled else "",
+                            bytes_read=read_bytes,
+                            tokens=k * self.slots,
+                        ) as _m, device_trace("gen.decode_burst"):
+                            if use_fused:
+                                (
+                                    toks, counts, done_bits,
+                                    self._cur_tok, self._pos, self._cache,
+                                    self._keys, self._budget_dev,
+                                ) = self._fused_burst_fn(
+                                    self.params, self._cache,
+                                    self._cur_tok, self._pos,
+                                    active_dev, temps_dev, self._keys,
+                                    self._stops_dev, self._budget_dev,
+                                    k, bound,
                                 )
-                                rows = gb
-                                if use_fused:
-                                    with self._prof.measure(
-                                        "group_burst",
-                                        variant=f"k{k}r{gb}b{g_bucket}",
-                                        tenant=burst_tenant,
-                                        bytes_read=k * (
-                                            self._param_bytes
-                                            + rows * g_bucket
-                                            * self._kv_key_bytes
-                                        ),
-                                        tokens=k * len(lanes),
-                                    ) as _m, device_trace(
-                                        "gen.decode_burst"
-                                    ):
-                                        (
-                                            toks, counts, done_bits,
-                                            self._cur_tok, self._pos,
-                                            self._cache, self._keys,
-                                            self._budget_dev,
-                                        ) = self._fused_group_fn(
-                                            self.params, self._cache,
-                                            self._cur_tok, self._pos,
-                                            temps_dev, self._keys,
-                                            self._stops_dev,
-                                            self._budget_dev, lane_ix,
-                                            len(lanes), k, g_bucket,
-                                        )
-                                        _m.sync(toks)
-                                else:
-                                    with self._prof.measure(
-                                        "group_burst",
-                                        variant=f"r{gb}b{g_bucket}",
-                                        tenant=burst_tenant,
-                                        bytes_read=k * (
-                                            self._param_bytes
-                                            + rows * g_bucket
-                                            * self._kv_key_bytes
-                                        ),
-                                        tokens=k * len(lanes),
-                                    ) as _m, device_trace(
-                                        "gen.decode_burst"
-                                    ):
-                                        toks, self._cur_tok, self._pos, self._cache, self._keys = (
-                                            self._group_burst_fn(
-                                                self.params, self._cache,
-                                                self._cur_tok, self._pos,
-                                                temps_dev, self._keys, lane_ix,
-                                                len(lanes), k, g_bucket,
-                                            )
-                                        )
-                                        _m.sync(toks)
-                                self.stats["group_bursts"] += 1
-                                self.stats["group_lanes"] += len(lanes)
-                                self.stats["group_pad_lanes"] += gb - len(lanes)
-                            self.stats["steps"] += k
-                            self.stats["lane_steps"] += k * rows
-                            self.stats["burst_reads"] += 1
-                            self.stats["burst_read_bytes"] += k * (
-                                self._param_bytes
-                                + rows * g_bucket * self._kv_key_bytes
-                            )
-                            self.stats["kv_positions_read"] += sum(
-                                _positions_streamed(
-                                    self._pos_host[slot] - adv, k, g_bucket,
-                                    self._kv_read_block)
-                                for slot in lanes
-                            )
-                            self.stats["kv_positions_bucket"] += (
-                                k * rows * g_bucket
-                            )
-                            if use_fused:
-                                self.stats["fused_dispatches"] += 1
-                                self.stats["fused_steps"] += k
-                            if self.trace_groups is not None:
-                                self.trace_groups.append({
-                                    "lanes": tuple(lanes),
-                                    "attn_len": g_bucket,
-                                    "need": {i: need[i] for i in lanes},
-                                    "grouped": len(groups) > 1,
-                                })
-                            # start the device->host token copy NOW; by the
-                            # time the host reads this burst (pipeline_depth
-                            # dispatches later) the transfer has landed
-                            if use_fused:
-                                for t in (toks, counts, done_bits):
-                                    try:
-                                        t.copy_to_host_async()
-                                    except AttributeError:
-                                        pass
-                                pending.append((
+                                burst = (
                                     "fused", (toks, counts, done_bits),
                                     (snapshot, k), t_dispatch,
-                                ))
+                                )
                             else:
-                                try:
-                                    toks.copy_to_host_async()
-                                except AttributeError:  # non-jax (test doubles)
-                                    pass
-                                pending.append((
-                                    "plain", (toks,), (snapshot,), t_dispatch,
-                                ))
+                                (
+                                    toks, self._cur_tok, self._pos,
+                                    self._cache, self._keys,
+                                ) = self._burst_fn(
+                                    self.params, self._cache,
+                                    self._cur_tok, self._pos,
+                                    active_dev, temps_dev, self._keys,
+                                    k, bound,
+                                )
+                                burst = (
+                                    "plain", (toks,), (snapshot,),
+                                    t_dispatch,
+                                )
+                            _m.sync(toks)
+                        self.stats["steps"] += k
+                        self.stats["lane_steps"] += k * self.slots
+                        self.stats["burst_reads"] += 1
+                        self.stats["burst_read_bytes"] += read_bytes
+                        self.stats["kv_positions_read"] += sum(
+                            _positions_streamed(
+                                self._pos_host[slot] - adv, k, attn_len,
+                                self._kv_read_block)
+                            for slot in lanes
+                        )
+                        self.stats["kv_positions_bucket"] += (
+                            k * self.slots * attn_len
+                        )
+                        if use_fused:
+                            self.stats["fused_dispatches"] += 1
+                            self.stats["fused_steps"] += k
+                        if self.trace_groups is not None:
+                            self.trace_groups.append({
+                                "lanes": tuple(lanes),
+                                "attn_len": attn_len,
+                                "need": need,
+                            })
+                        # start the device->host token copy NOW; by the
+                        # time the host reads this burst (pipeline_depth
+                        # dispatches later) the transfer has landed
+                        for t in burst[1]:
+                            try:
+                                t.copy_to_host_async()
+                            except AttributeError:  # non-jax (test doubles)
+                                pass
+                        pending.append(burst)
                         # PREDICTIVE FREE: a lane whose eos-less budget is
                         # now fully covered by dispatched bursts is done —
                         # the host needn't observe the tokens to know it.

@@ -1,14 +1,13 @@
 """Scheduler flight recorder: a bounded ring of per-poll decision records.
 
 The continuous batcher (serving/continuous.py) makes a scheduling
-decision every poll — which requests admit, how live lanes partition
-into depth-grouped sub-bursts, whether the cost model merged groups,
-which long prompts advanced a prefill chunk, what got shed — and none of
-it used to survive the poll. This recorder keeps the last ``capacity``
-decisions as plain dicts in a ``collections.deque`` ring so a
-tail-latency regression can be attributed after the fact (queue wait vs
-prefill interleave vs group re-packing vs eviction) without re-running
-traffic under a profiler.
+decision every poll — which requests admit, the burst it dispatched
+(mode, K, lanes, bucket), which long prompts advanced a prefill chunk,
+what got shed — and none of it used to survive the poll. This recorder
+keeps the last ``capacity`` decisions as plain dicts in a
+``collections.deque`` ring so a tail-latency regression can be
+attributed after the fact (queue wait vs prefill interleave vs
+eviction) without re-running traffic under a profiler.
 
 Cost model: recording must be cheap enough to leave ON in production.
 One small dict is built per *poll* (device-burst cadence, milliseconds),

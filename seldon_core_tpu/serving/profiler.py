@@ -1,9 +1,9 @@
 """Device-time ledger: per-executable attribution for the serving loop.
 
 Every warmed-executable dispatch the continuous batcher makes — prefill,
-chunked prefill slice, decode burst, fused burst, spec burst,
-depth-group variant, prefix splice/insert/extract, swap cast — is timed
-and attributed per ``(kind, variant, tenant)``. The ledger turns the
+chunked prefill slice, decode burst, fused burst, spec burst, prefix
+splice/insert/extract, swap cast — is timed and attributed per
+``(kind, variant, tenant)``. The ledger turns the
 offline modelbench numbers into live gauges: bytes-read per variant are
 known statically (the same cost model ``modelbench.bench_generate``
 prices MBU with — see ``DecoderLM.dispatch_read_bytes``), so live MBU
@@ -55,7 +55,6 @@ KINDS = (
     "chunk_prefill",  # one chunked-prefill slice
     "decode_burst",   # step-at-a-time whole-batch burst
     "fused_burst",    # stop-aware fused multi-step burst (per K)
-    "group_burst",    # depth-group sub-burst variant (plain or fused)
     "spec_burst",     # speculative draft+verify round burst
     "splice",         # prefix/checkpoint donor slab splice into a slab
     "insert",         # prefilled slab insert into a lane of the cache

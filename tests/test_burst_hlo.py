@@ -5,7 +5,8 @@ with no array of the bucket's shape left (ISSUE 30), checked by
 snippets, and the burst itself compiled here, without a chip, for a
 described v5e at the benchmark configurations' widths (two layers: the
 copies and the kernel calls are per layer, so two show what twenty-four
-would; and once at the configurations' own depth for the scratch).
+would; and once at the configurations' own depth for the scratch), with a
+bucket and, as the chip runs it since ISSUE 31, without one.
 
 The topology is described inside a fixture and nowhere else: only one
 process may load the TPU's library, and each xdist worker imports this
@@ -108,6 +109,25 @@ def test_reader_counts_the_aliases():
     assert _tool().alias_count("HloModule m, is_scheduled=true\n") == 0
 
 
+def test_reader_compares_while_bodies_up_to_constants():
+    """Two compilations of one program differ in instruction names and in
+    the values of constants (a burst's bucket is one); an instruction
+    more or fewer is another program."""
+    tool = _tool()
+    body = HLO.replace(
+        "  %slice.4 =", "  %c.1 = s32[] constant(640)\n  %slice.4 =")
+    renamed = body.replace("%slice.4", "%slice.77").replace(
+        "constant(640)", "constant(2048)")
+    assert tool.while_body_diff(body, renamed) == []
+    # outside the while nothing is compared
+    assert tool.while_body_diff(
+        body, body.replace("  %copy.2 = ", "  %copy.3 = ")) == []
+    extra = body.replace(
+        "  %slice.4 =", "  %b.1 = s32[28]{0} broadcast(%c.1), dimensions={}\n  %slice.4 =")
+    assert tool.while_body_diff(body, extra) == [
+        "+ % = s32[28]{0} broadcast(%), dimensions={}"]
+
+
 @pytest.fixture(scope="module")
 def one_chip():
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
@@ -122,13 +142,15 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
+@pytest.mark.parametrize("attn_len", [640, None])
 @pytest.mark.parametrize("config", ["internlm2-1.8b", "mistral-7b-v0.3"])
-def test_burst_compiled_for_v5e_keeps_the_cache_in_place(one_chip, config):
+def test_burst_compiled_for_v5e_keeps_the_cache_in_place(
+        one_chip, config, attn_len):
     with open(os.path.join(ROOT, "benchmark", "configs", config + ".json")) as f:
         cfg = json.load(f)
     cfg["name"] = config
     cfg["num_hidden_layers"] = 2
-    out = _tool().check(cfg, 640, one_chip)
+    out = _tool().check(cfg, attn_len, one_chip)
     assert out["cache_shaped_copies_and_slices"] == {}, out["example"]
     assert out["input_output_aliases"] >= out["cache_leaves"] == 4
     assert out["alias_size_in_bytes"] >= out["cache_bytes"]
@@ -141,16 +163,20 @@ def test_burst_compiled_for_v5e_keeps_the_cache_in_place(one_chip, config):
     assert out["ok"]
 
 
+@pytest.mark.parametrize("attn_len", [1280, None])
 @pytest.mark.parametrize("config", ["internlm2-1.8b", "mistral-7b-v0.3"])
-def test_burst_at_full_depth_takes_no_more_scratch_than_before(one_chip, config):
-    """At the configuration's own depth and the deepest warmed bucket but
-    one: a kernel call per layer, the cache aliased through, and
-    ``temp_size_in_bytes`` not above the burst's before the ragged read."""
+def test_burst_at_full_depth_takes_no_more_scratch_than_before(
+        one_chip, config, attn_len):
+    """At the configuration's own depth, at the deepest warmed bucket but
+    one and without a bucket: a kernel call per layer, the cache aliased
+    through, and ``temp_size_in_bytes`` not above the burst's before the
+    ragged read."""
     tool = _tool()
     with open(os.path.join(ROOT, "benchmark", "configs", config + ".json")) as f:
         cfg = json.load(f)
     cfg["name"] = config
-    out = tool.check(cfg, 1280, one_chip, temp_limit=tool.TEMP_BEFORE[config])
+    out = tool.check(
+        cfg, attn_len, one_chip, temp_limit=tool.TEMP_BEFORE[config])
     layers = cfg["num_hidden_layers"]
     assert out["kernel_calls"] == {"inside": layers, "outside": 0}
     assert out["input_output_aliases"] >= out["cache_leaves"] == 2 * layers

@@ -17,6 +17,7 @@ from seldon_core_tpu.ops.decode_attention import (
     BLOCK,
     decode_attention,
     ragged_decode_attention,
+    reads_ragged,
 )
 
 BLK = 128  # the tests' block: three of them make the cache
@@ -164,6 +165,97 @@ def test_entry_keeps_the_dots_where_the_kernel_does_not_tile(why, kwargs):
     mlir = jax.export.export(
         jax.jit(decode_attention), platforms=["tpu"])(q, *rest).mlir_module()
     assert "tpu_custom_call" not in mlir, why
+
+
+_BF16 = jnp.dtype("bfloat16")
+
+
+@pytest.mark.parametrize("why,change,want", [
+    ("a TPU, heads that tile", {}, True),
+    ("the CPU", dict(platform="cpu"), False),
+    ("a serving mesh", dict(mesh=object()), False),
+    ("a head_dim off the lane width", dict(dh=64), False),
+    ("mixed dtypes", dict(dtypes=(_BF16, _BF16, jnp.dtype("float32"))), False),
+    ("a cache the block does not divide", dict(t=BLOCK + BLOCK // 2), False),
+    ("a window of queries", dict(t_q=2), False),
+    ("heads that are no multiple of the KV heads", dict(heads=3), False),
+])
+def test_the_rule_for_a_ragged_read(why, change, want):
+    """``reads_ragged``: the one place that says where the decode read
+    takes each lane's own length. The scheduler asks it whether a burst
+    needs a bucket; ``decode_attention()`` asks it whether to hand the
+    lowering the kernel."""
+    c = dict(platform="tpu", mesh=None, dh=128, t=2 * BLOCK, t_q=1, heads=4,
+             dtypes=(_BF16,) * 3)
+    c.update(change)
+    got = reads_ragged(
+        c["platform"], (6, c["heads"], c["t_q"], c["dh"]),
+        (6, 2, c["t"], c["dh"]), c["dtypes"], c["mesh"])
+    assert got is want, why
+
+
+@pytest.fixture()
+def on_a_tpu(monkeypatch):
+    """The entry as a lowering for a TPU runs it: ``platform_dependent``
+    takes its ``tpu`` branch and the kernel runs interpreted. Returns the
+    entry's module and the entry, unjitted (the jitted one would answer
+    from a trace made before the patches)."""
+    import importlib
+
+    mod = importlib.import_module("seldon_core_tpu.ops.decode_attention")
+    monkeypatch.setattr(
+        mod.lax, "platform_dependent",
+        lambda *args, tpu, default: tpu(*args))
+    monkeypatch.setattr(
+        mod, "ragged_decode_attention",
+        lambda *a, **kw: ragged_decode_attention(*a, **kw, interpret=True))
+    return mod, mod.decode_attention.__wrapped__
+
+
+def test_entry_takes_its_choice_from_the_rule(on_a_tpu, monkeypatch):
+    """Heads that tile, lowered for a TPU: the kernel. The same call once
+    the rule says no: the dots, bit for bit, and the rule was asked with
+    what the entry can see."""
+    mod, entry = on_a_tpu
+    q, k, v, pos, lens = _entry_args(t=2 * BLOCK)
+    dots = DecoderLM._cache_attention(q, k, v, pos, q.dtype)
+    kernel = entry(q, k, v, pos, lens)
+    assert not np.asarray(kernel[0], np.float32).any()  # lens[0] == 0
+    assert np.asarray(dots[0], np.float32).any()
+    asked = []
+
+    def no(*args):
+        asked.append(args)
+        return False
+
+    monkeypatch.setattr(mod, "reads_ragged", no)
+    got = entry(q, k, v, pos, lens, mesh=None)
+    assert np.array_equal(np.asarray(got, np.float32),
+                          np.asarray(dots, np.float32))
+    assert asked == [("tpu", q.shape, k.shape, (q.dtype,) * 3, None)]
+
+
+@pytest.mark.parametrize("lens", [
+    (0, 1, BLOCK - 1, BLOCK, BLOCK + 1, 2 * BLOCK),
+    (0, 0, 0, 0, 0, 2 * BLOCK),
+    (2 * BLOCK, 0, 2 * BLOCK, 7, 2 * BLOCK, 0),
+], ids=["every_edge", "one_lane_at_the_bucket", "full_and_idle"])
+def test_kernel_without_a_bucket_is_the_bucketed_one_bit_for_bit(on_a_tpu, lens):
+    """What the batcher relies on where the read is ragged: the bucket is
+    a clamp on lengths that never exceed it, so ``attn_len=None`` (the
+    clamp is the cache's length) gives the bucket's outputs exactly, idle
+    lanes and lanes at the bucket itself included."""
+    _, entry = on_a_tpu
+    q, k, v = _inputs(2, jnp.bfloat16, t=3 * BLOCK, seed=3)
+    lens = jnp.asarray(lens, jnp.int32)
+    pos = jnp.maximum(lens - 1, 0)
+    bucketed = entry(q, k, v, pos, lens, attn_len=2 * BLOCK)
+    free = entry(q, k, v, pos, lens, attn_len=None)
+    assert np.array_equal(np.asarray(free, np.float32),
+                          np.asarray(bucketed, np.float32))
+    ref = _dots(q, k, v, lens)
+    err = jnp.abs(free.astype(jnp.float32) - ref.astype(jnp.float32))
+    assert float(err.max()) <= 2 ** -6
 
 
 def _tiny_model():

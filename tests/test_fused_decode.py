@@ -6,7 +6,7 @@ append, greedy + seeded-categorical sampling, stop-token detection, and
 per-lane done masks that freeze finished lanes — and greedy AND
 seeded-sampling outputs stay byte-identical to the step-at-a-time path
 under every composition: prefix-cache splice, chunked prefill
-interleave, depth groups, mid-burst stops at every position in K,
+interleave, lanes at mixed depths, mid-burst stops at every position in K,
 pressure-triggered preemption at a fused poll boundary, and drain
 checkpointing mid-run. Speculation degrades the fused path to the spec
 burst (which fuses draft/verify its own way).
@@ -142,15 +142,15 @@ def test_fused_with_prefix_cache_splice(model_and_params):
         plain.close()
 
 
-def test_fused_with_chunked_prefill_and_depth_groups(model_and_params,
-                                                     references,
-                                                     _sub_tile_attn_buckets):
-    """Chunked prefill interleave + depth-grouped sub-bursts compose with
-    the fused path: same bytes, chunks actually interleave, groups
-    actually split (cost model forced), fused dispatches actually run."""
+def test_fused_with_chunked_prefill_at_mixed_depths(model_and_params,
+                                                    references,
+                                                    _sub_tile_attn_buckets):
+    """Chunked prefill interleave composes with the fused path while
+    lanes sit in different attention buckets: same bytes, fused
+    dispatches actually run."""
     b = make_batcher(
         model_and_params, attn_bucket=16, fused_steps_per_dispatch=16,
-        prefill_chunk=16, depth_groups=4, depth_group_split_bytes=0,
+        prefill_chunk=16,
     )
     try:
         futures = []
@@ -473,8 +473,8 @@ def test_flight_report_k_collapse_diagnosis():
             "entries": [
                 {"type": "poll", "active": 2, "queue": 0, "admitted": 0,
                  "plan": {"mode": "fused", "k": k, "k_max": 64,
-                          "shrunk_by": "pressure", "groups": [],
-                          "distinct_buckets": 1, "merged": 0}}
+                          "shrunk_by": "pressure", "lanes": 2,
+                          "bucket": 128, "distinct_buckets": 1}}
                 for k in ks
             ],
             "recorded_total": len(ks), "dropped": 0,

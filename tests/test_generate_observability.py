@@ -174,10 +174,11 @@ def test_flight_recorder_poll_records(model_and_params):
         plans = [e["plan"] for e in polls if "plan" in e]
         assert any(p["mode"] == "decode" for p in plans)
         decode = next(p for p in plans if p["mode"] == "decode")
-        # the plan explains the poll: burst length + per-group composition
+        # the plan explains the poll: burst length, lanes and bucket
         assert decode["k"] == 4
-        assert decode["groups"] and "bucket" in decode["groups"][0]
-        assert "merged" in decode and "distinct_buckets" in decode
+        assert decode["lanes"] == 1 and decode["bucket"] >= 64
+        assert decode["distinct_buckets"] == 1
+        assert "groups" not in decode and "merged" not in decode
         # seq monotonically increases and the dump is JSON-clean
         seqs = [e["seq"] for e in entries]
         assert seqs == sorted(seqs)

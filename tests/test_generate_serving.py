@@ -1112,10 +1112,10 @@ def test_stream_speculation_mesh_compose(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# Depth-aware scheduling (PR 3 tentpole): grouped sub-bursts + chunked
-# prefill must never change greedy output — on vs off, under speculation,
-# under the prefix cache — and the scheduler must provably never read a
-# lane past its own group's bucket.
+# Lanes at mixed depths: chunked prefill must never change greedy output
+# — on vs off, under speculation, under the prefix cache — and a burst's
+# bucket is the deepest of its lanes' own. Where the read is ragged the
+# bucket is the host's arithmetic only: one executable per K.
 # ---------------------------------------------------------------------------
 
 MIXED_PROMPTS = [(3, 8), (40, 8), (5, 12), (35, 6), (9, 10), (28, 4)]
@@ -1123,8 +1123,8 @@ MIXED_PROMPTS = [(3, 8), (40, 8), (5, 12), (35, 6), (9, 10), (28, 4)]
 
 @pytest.fixture(autouse=True)
 def _sub_tile_attn_buckets():
-    """Lower the MXU-tileability clamp for this module's tests: depth
-    grouping needs several attention buckets inside a 64-token cache,
+    """Lower the MXU-tileability clamp for this module's tests: lanes at
+    mixed depths need several attention buckets inside a 64-token cache,
     which production's 64 floor forbids (by design)."""
     old = ContinuousBatcher.MIN_ATTN_BUCKET
     ContinuousBatcher.MIN_ATTN_BUCKET = 16
@@ -1154,28 +1154,6 @@ def _mixed_run(model, params, **kw):
     return prompts, out, dict(b.stats), b.trace_groups
 
 
-def test_depth_grouping_greedy_identical(model_and_params):
-    """Depth-grouped sub-bursts emit exactly the single-burst scheduler's
-    tokens AND the model's own generate() — while genuinely splitting
-    bursts (group_bursts > 0 with the cost model forced to always
-    split)."""
-    import jax.numpy as jnp
-
-    model, params = model_and_params
-    prompts, off, _, _ = _mixed_run(model, params)
-    _, on, stats, trace = _mixed_run(
-        model, params, depth_groups=4, depth_group_split_bytes=0
-    )
-    assert on == off
-    assert stats["group_bursts"] > 0
-    assert any(t["grouped"] for t in trace)
-    for p, got, (_, m) in zip(prompts, on, MIXED_PROMPTS):
-        exp = np.asarray(
-            model.generate(params, jnp.asarray([p], jnp.int32), m)
-        )[0].tolist()
-        assert got == exp
-
-
 def test_chunked_prefill_greedy_identical(model_and_params):
     """Chunked prefill (long prompts trickling in between decode polls)
     is byte-identical to whole-prompt prefill, and really chunks."""
@@ -1184,19 +1162,12 @@ def test_chunked_prefill_greedy_identical(model_and_params):
     _, on, stats, _ = _mixed_run(model, params, prefill_chunk=16)
     assert on == off
     assert stats["prefill_chunks"] > 0
-    # both knobs together, still identical
-    _, both, bstats, _ = _mixed_run(
-        model, params, prefill_chunk=16, depth_groups=4,
-        depth_group_split_bytes=0,
-    )
-    assert both == off
-    assert bstats["prefill_chunks"] > 0 and bstats["group_bursts"] > 0
 
 
-def test_depth_knobs_with_speculation_exact(model_and_params):
-    """Speculation composes with both knobs: output still equals the
-    target's own greedy decode (chunked prompts feed the draft's full
-    prefill at activation; spec bursts stay whole-batch by design)."""
+def test_chunked_prefill_with_speculation_exact(model_and_params):
+    """Speculation composes with chunked prefill: output still equals
+    the target's own greedy decode (chunked prompts feed the draft's
+    full prefill at activation)."""
     import jax.numpy as jnp
 
     model, params = model_and_params
@@ -1207,8 +1178,7 @@ def test_depth_knobs_with_speculation_exact(model_and_params):
     dparams = draft.init_params(99)
     _, out, stats, _ = _mixed_run(
         model, params, draft_model=draft, draft_params=dparams,
-        speculate_tokens=3, depth_groups=4, depth_group_split_bytes=0,
-        prefill_chunk=16,
+        speculate_tokens=3, prefill_chunk=16,
     )
     rng = np.random.RandomState(17)
     for (n, m), got in zip(MIXED_PROMPTS, out):
@@ -1220,7 +1190,7 @@ def test_depth_knobs_with_speculation_exact(model_and_params):
     assert stats["prefill_chunks"] > 0
 
 
-def test_depth_knobs_with_prefix_cache_exact(model_and_params):
+def test_chunked_prefill_with_prefix_cache_exact(model_and_params):
     """Prefix-cache hits splice the donor slab and CHUNK the remaining
     prompt; outputs stay byte-identical to the model's own generate()
     and hits still register."""
@@ -1234,7 +1204,7 @@ def test_depth_knobs_with_prefix_cache_exact(model_and_params):
         model, params, slots=2, max_seq=64, prefill_buckets=(8, 16, 32),
         attn_bucket=16, steps_per_poll=2,
         prefix_cache_hbm_bytes=1 << 26, prefix_cache_min_tokens=4,
-        depth_groups=4, depth_group_split_bytes=0, prefill_chunk=16,
+        prefill_chunk=16,
     )
     try:
         for p in prompts:
@@ -1249,66 +1219,81 @@ def test_depth_knobs_with_prefix_cache_exact(model_and_params):
         b.close()
 
 
-def test_group_read_bounds_never_exceed_own_bucket(model_and_params):
-    """Scheduler-level invariant: every dispatched sub-burst's read bound
-    equals the deepest need INSIDE that group, and with the cost model
-    forced to always split, no lane ever rides a burst whose bound
-    exceeds its OWN bucket."""
-    model, params = model_and_params
-    _, _, _, trace = _mixed_run(
-        model, params, depth_groups=8, depth_group_split_bytes=0
-    )
-    assert trace
-    for t in trace:
-        assert t["attn_len"] == max(t["need"].values())
-        for lane, need in t["need"].items():
-            assert need <= t["attn_len"]
-        if t["grouped"]:
-            # forced-split mode: a group only holds lanes of ONE bucket,
-            # so no shallow lane pays a deeper lane's read
-            assert len(set(t["need"].values())) == 1
-
-
-def test_group_repack_as_prefixes_cross_buckets(model_and_params):
-    """As a lane's prefix deepens across attn-bucket boundaries its group
-    bucket must follow (groups are re-planned every poll): the same lane
-    appears in sub-bursts of strictly increasing attn_len, and co-tenants
-    at different depths stay in different groups until they converge."""
-    import time
-
-    model, params = model_and_params
+def _sequential_run(model, params, ragged, fused=0):
+    """Three prompts whose runs end in three different buckets, one at a
+    time (so every count below is the same run after run), through a
+    batcher warmed for them. ``ragged``: tell the batcher its platform
+    reads each lane's own length, as a TPU with tileable heads does; on
+    this CPU the dots then read the whole cache under the mask."""
+    rng = np.random.RandomState(5)
+    prompts = [rng.randint(0, 256, n).tolist() for n in (3, 20, 40)]
     b = ContinuousBatcher(
-        model, params, slots=2, max_seq=64, prefill_buckets=(8, 32),
-        attn_bucket=16, steps_per_poll=2,
-        depth_groups=4, depth_group_split_bytes=0,
+        model, params, slots=2, max_seq=64, prefill_buckets=(8, 32, 64),
+        attn_bucket=16, steps_per_poll=2, fused_steps_per_dispatch=fused,
     )
+    assert b._ragged_read is False  # float32 heads of 8 on a CPU
+    b._ragged_read = ragged
     b.trace_groups = []
     try:
-        deep = b.submit(list(range(1, 30)), max_new_tokens=20)  # starts ~29
-        time.sleep(0.05)
-        shallow = b.submit([5, 6, 7], max_new_tokens=30)  # starts ~3
-        deep.result(timeout=120)
-        shallow.result(timeout=120)
+        b.warm(prompt_lens=[3, 20, 40], max_new_tokens=8)
+        warmed = (b._burst_fn._cache_size(), b._fused_burst_fn._cache_size())
+        out = [b.generate(p, max_new_tokens=8) for p in prompts]
+        served = (b._burst_fn._cache_size(), b._fused_burst_fn._cache_size())
     finally:
         b.close()
-    trace = b.trace_groups
-    # the shallow lane's read bound walked UP bucket by bucket
-    shallow_lens = [
-        t["attn_len"] for t in trace
-        if t["grouped"] and len(t["lanes"]) == 1 and max(t["need"].values()) < 48
-    ]
-    assert shallow_lens, "expected dedicated shallow-group dispatches"
-    assert shallow_lens == sorted(shallow_lens)
-    assert len(set(shallow_lens)) >= 2, "bound never re-packed upward"
-    # while split, every grouped dispatch kept each lane within its bucket
+    return out, warmed, served, dict(b.stats), b.trace_groups
+
+
+@pytest.mark.parametrize("fused", [0, 4])
+def test_ragged_read_warms_one_burst_per_k_and_serves_the_same(
+        model_and_params, fused):
+    """Where the read takes each lane's own length the plain and the
+    stop-aware burst have no bucket axis: one executable per K, none
+    compiled under load, while the host's arithmetic (each burst's
+    bucket, ``kv_positions_bucket``, the modeled read bytes) and the
+    greedy tokens are the bucketed batcher's. A batcher whose platform
+    reads through the dots still warms one per bucket."""
+    # a model of its own: the module's one keeps the serving mesh of the
+    # mesh tests above, and arrays laid out over it would add entries to
+    # the jit caches counted here
+    model, params = DecoderLM(**CFG), model_and_params[1]
+    out_b, warmed_b, served_b, stats_b, trace_b = _sequential_run(
+        model, params, ragged=False, fused=fused)
+    out_r, warmed_r, served_r, stats_r, trace_r = _sequential_run(
+        model, params, ragged=True, fused=fused)
+    # K is 2 plain; fused 4 warms K in {2, 4}
+    ks = 2 if fused else 0
+    assert warmed_r == (1, ks) and served_r == warmed_r
+    # the buckets the three runs cross: 16 .. 64
+    assert warmed_b[0] >= 3 and warmed_b[1] == ks * warmed_b[0]
+    assert served_b == warmed_b
+    buckets = [t["attn_len"] for t in trace_b]
+    assert len(set(buckets)) >= 3
+    assert [t["attn_len"] for t in trace_r] == buckets
+    for t in trace_r:
+        assert t["attn_len"] == max(t["need"].values())
+    for key in ("kv_positions_bucket", "kv_positions_read",
+                "burst_read_bytes", "burst_reads", "steps"):
+        assert stats_r[key] == stats_b[key], key
+    assert out_r == out_b
+
+
+def test_burst_bucket_is_the_deepest_lanes_own(model_and_params):
+    """Lanes at mixed depths ride ONE burst whose bucket is the deepest
+    lane's; each lane's own ``need`` is recorded beside it."""
+    model, params = model_and_params
+    _, _, _, trace = _mixed_run(model, params)
+    assert trace
+    assert any(len(set(t["need"].values())) > 1 for t in trace)
     for t in trace:
+        assert sorted(t["need"]) == list(t["lanes"])
         assert t["attn_len"] == max(t["need"].values())
 
 
-def test_generateserver_depth_knobs_and_metrics(tmp_path):
-    """Knob plumbing + observability: GenerateServer forwards the depth
-    knobs, serves identically to a knobs-off server, and exports the
-    per-burst read-bytes and group-occupancy counters."""
+def test_generateserver_chunk_knob_and_burst_metrics(tmp_path):
+    """Knob plumbing + observability: GenerateServer forwards the chunk
+    knob, serves identically to a knobs-off server, and exports the
+    per-burst read-bytes counters."""
     from seldon_core_tpu.servers.generateserver import GenerateServer
 
     d = tmp_path / "llm"
@@ -1320,7 +1305,7 @@ def test_generateserver_depth_knobs_and_metrics(tmp_path):
                            attn_bucket=16)
     tuned = GenerateServer(
         model_uri=str(d), slots=2, steps_per_poll=2, attn_bucket=16,
-        depth_groups=2, prefill_chunk=16, depth_group_split_bytes=0,
+        prefill_chunk=16,
     )
     try:
         body = {"prompt_tokens": [list(range(1, 30)), [5, 17, 42]],
@@ -1329,13 +1314,10 @@ def test_generateserver_depth_knobs_and_metrics(tmp_path):
         out_tuned = tuned.predict(dict(body), [])
         assert out_plain["tokens"] == out_tuned["tokens"]
         assert tuned.batcher.prefill_chunk == 16
-        assert tuned.batcher.depth_groups == 2
         keys = {m["key"]: m for m in tuned.metrics()}
         assert keys["gen_burst_reads"]["type"] == "COUNTER"
         assert keys["gen_burst_read_bytes"]["value"] > 0
         assert keys["gen_prefill_chunks"]["value"] > 0
-        if "gen_group_occupancy" in keys:
-            assert 0 < keys["gen_group_occupancy"]["value"] <= 1
     finally:
         if plain.batcher:
             plain.batcher.close()

@@ -43,12 +43,11 @@ def run(coro):
 
 
 def entry(slots=4, fused=0, tps=100.0, ttft=800.0, tpot=50.0,
-          hbm=1_000_000_000, chunk=0, dg=0, split=0, kv=0, **extra):
+          hbm=1_000_000_000, chunk=0, kv=0, **extra):
     return {
         "config": {
             "slots": slots, "prefill_chunk": chunk,
-            "fused_steps_per_dispatch": fused, "depth_groups": dg,
-            "depth_group_split_bytes": split, "kv_tier_bytes": kv,
+            "fused_steps_per_dispatch": fused, "kv_tier_bytes": kv,
         },
         "tokens_per_s": tps,
         "ttft_p50_ms": ttft / 2, "ttft_p99_ms": ttft,
@@ -114,6 +113,30 @@ def test_profile_bad_magic_and_version_refuse_typed():
     ) + payload
     with pytest.raises(ProfileError, match="version"):
         decode_profile(frame)
+
+
+def test_profile_with_the_retired_depth_group_axes_refuses_typed():
+    """A version-1 artifact keyed its grid on ``depth_groups`` and
+    ``depth_group_split_bytes`` too. Those knobs are gone with the
+    mechanism, so such a file is refused by its version, typed, on
+    decode — never half-read into a grid whose configs collide."""
+    import struct
+    import zlib
+
+    old = dict(profile(*GRID3), v=1)
+    old["grid"] = [
+        dict(e, config=dict(e["config"], depth_groups=2 * i,
+                            depth_group_split_bytes=0))
+        for i, e in enumerate(old["grid"])
+    ]
+    payload = json.dumps(old).encode()
+    frame = b"SPF1" + struct.pack(
+        "<II", len(payload), zlib.crc32(payload)
+    ) + payload
+    with pytest.raises(ProfileError, match="version 1"):
+        decode_profile(frame)
+    assert profile(*GRID3)["v"] == 2
+    assert "depth_groups" not in profile(*GRID3)["grid"][0]["config"]
 
 
 def test_profile_malformed_grid_refuses_on_both_sides():
@@ -291,8 +314,7 @@ def test_trafficsim_replay_orders_and_paces():
 # -- planner decision table ---------------------------------------------------
 
 
-CENSUS = {"fused_ks": (2, 4, 8), "depth_groups": 1,
-          "prefill_chunk": 0, "pipeline_depth": 1}
+CENSUS = {"fused_ks": (2, 4, 8), "prefill_chunk": 0, "pipeline_depth": 1}
 CONFIG0 = dict(GRID3[0]["config"])        # slots=4, fused=0
 
 
@@ -331,29 +353,12 @@ def test_planner_rank3_warn_retunes_toward_measured_config():
     assert d.knobs == {"fused_steps_per_dispatch": 8}
 
 
-def test_planner_rank3_census_pins_depth_groups():
-    """A member booted without group-burst variants can never be asked
-    to retune into depth grouping — the batcher would refuse typed, so
-    the planner must not even rank those configs."""
-    grid = profile(
-        entry(slots=4, fused=0, tps=100, ttft=800, tpot=50),
-        entry(slots=4, fused=8, dg=2, tps=500, ttft=200, tpot=10),
-        entry(slots=4, fused=8, tps=400, ttft=300, tpot=20),
-    )
-    p = ServingPlanner(cost_model=CostModel(grid))
-    d = p.tick(verdicts=[warn("ttft_p99", 0.5)],
-               current_config=CONFIG0, census=CENSUS)
-    assert d.action == "retune"
-    assert d.knobs.get("depth_groups") is None
-
-
 def test_planner_never_churns_unswept_axes():
     """An axis every grid entry shares (never swept) carries no
     measured evidence — the planner must not 'retune' the member's
-    live value (e.g. the batcher's own split-bytes heuristic) to the
-    grid's constant."""
+    live value (here its boot chunk size) to the grid's constant."""
     p = ServingPlanner(cost_model=CostModel(profile(*GRID3)))
-    live = dict(CONFIG0, depth_group_split_bytes=69952)
+    live = dict(CONFIG0, prefill_chunk=32)
     d = p.tick(verdicts=[warn("ttft_p99", 0.5), warn("tpot_p99", 0.03)],
                current_config=live, census=CENSUS)
     assert d.action == "retune"
@@ -655,8 +660,6 @@ def test_retune_out_of_census_refuses_typed(model_and_params):
     try:
         with pytest.raises(RetuneError, match="census"):
             b.retune(fused_steps_per_dispatch=16)   # never warmed
-        with pytest.raises(RetuneError, match="depth_groups"):
-            b.retune(depth_groups=2)                # booted without
         with pytest.raises(RetuneError, match="prefill_chunk"):
             b.retune(prefill_chunk=16)              # no chunk exes
         with pytest.raises(RetuneError, match="knob"):

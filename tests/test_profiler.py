@@ -2,7 +2,7 @@
 
 The load-bearing contracts: (1) the ledger attributes every warmed
 dispatch per (kind, variant, tenant) — including under the full
-composition of fused decode × depth groups × prefix splice, and under
+composition of fused decode × chunked prefill × prefix splice, and under
 pressure preemption/resume — (2) profiler on vs off is byte-identical
 greedy AND seeded with an unchanged jit cache (the hooks wrap calls,
 never args or results, and compile nothing), and (3) the burn engine
@@ -145,17 +145,17 @@ def _sub_tile_attn_buckets():
     ContinuousBatcher.MIN_ATTN_BUCKET = old
 
 
-def test_attribution_fused_depth_groups_prefix_splice(
+def test_attribution_fused_chunked_prefix_splice(
     model_and_params, _sub_tile_attn_buckets
 ):
-    """The full composition: fused decode × depth groups × prefix-cache
+    """The full composition: fused decode × chunked prefill × prefix-cache
     splice, with tenant attribution — every dispatch lands in a typed
     (kind, variant, tenant) bucket and the variant vocabulary carries
     the realized K / bucket the executable was compiled for."""
     prof = DeviceTimeLedger(enabled=True, deep_every=4)
     b = make_batcher(
         model_and_params, attn_bucket=16, fused_steps_per_dispatch=8,
-        depth_groups=4, depth_group_split_bytes=0, prefill_chunk=16,
+        prefill_chunk=16,
         prefill_buckets=(8, 16, 32, 48),
         prefix_cache_hbm_bytes=1 << 20, prefix_cache_min_tokens=4,
         profiler=prof,
@@ -165,13 +165,11 @@ def test_attribution_fused_depth_groups_prefix_splice(
         kinds = ledger_kinds(prof)
         assert "prefill" in kinds
         assert "insert" in kinds
-        # fused decode over mixed depths: fused single-group bursts
-        # and/or grouped variants — both are fused executables
-        assert kinds & {"fused_burst", "group_burst"}
+        assert "fused_burst" in kinds
         for kind, variant, tenant in prof.buckets():
             assert kind in KINDS
-            if kind in ("fused_burst", "group_burst"):
-                assert variant.startswith(("k", "r")), (kind, variant)
+            if kind == "fused_burst":
+                assert variant.startswith("k"), (kind, variant)
                 assert tenant in ("", "acme")
         # a second long prompt sharing a chunk-aligned prefix rides the
         # radix cache through the CHUNKED admission path (suffix longer

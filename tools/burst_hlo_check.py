@@ -21,6 +21,13 @@ reads the optimised HLO:
   fusion or out, has the bucket's shape ``[n <= lanes, KV, attn_len, Dh]``:
   the two dots that read every lane's whole bucket are gone.
 
+The burst is checked with ``attn_len=None`` (what the chip runs since
+ISSUE 31: where the read takes each lane's length the executable has no
+bucket) and at two buckets (what a platform without the kernel's shapes
+still warms). With ``--hlo-dir`` the output also says whether the
+``while`` bodies at ``None`` and at the first bucket differ in anything
+but constants.
+
     python tools/burst_hlo_check.py                      # on the chip
     python tools/burst_hlo_check.py --described v5e:2x2  # no chip: the TPU
         compiler targets a described device (JAX_PLATFORMS=cpu); the HLO
@@ -33,6 +40,7 @@ A skip is not a pass.
 from __future__ import annotations
 
 import argparse
+import collections
 import json
 import os
 import re
@@ -43,7 +51,7 @@ sys.path.insert(0, ROOT)
 
 CONFIGS = ("benchmark/configs/internlm2-1.8b.json",
            "benchmark/configs/mistral-7b-v0.3.json")
-ATTN_LENS = (640, 1280)
+ATTN_LENS = (None, 640, 1280)
 OFFENDERS = ("copy", "copy-start", "slice", "slice-start")
 # temp_size_in_bytes of the burst at these configurations' own depth before
 # the ragged read, for a described v5e at either ATTN_LENS (PR 26's burst;
@@ -137,13 +145,45 @@ def bucket_shaped(hlo: str, lanes: int, kv: int, attn_len: int, dh: int) -> int:
     )
 
 
+def while_body_lines(hlo: str) -> list:
+    """The instructions that run inside the ``while``, fusions' insides
+    included, with what varies between two compilations of one program
+    taken out: instruction names, metadata, and the values of constants."""
+    comps = computations(hlo)
+    names = [n for n, inside in scheduled(comps).items() if inside]
+    seen = set(names)
+    while names:
+        for line in comps[names.pop()]:
+            for callee in _CALLED_RE.findall(line):
+                if callee in comps and callee not in seen:
+                    seen.add(callee)
+                    names.append(callee)
+    out = []
+    for name in seen:
+        for line in comps[name]:
+            line = re.sub(r", metadata=\{[^}]*\}", "", line.strip())
+            line = re.sub(r"constant\([^)]*\)", "constant(_)", line)
+            out.append(re.sub(r"%[\w.\-]+", "%", line))
+    return sorted(out)
+
+
+def while_body_diff(hlo_a: str, hlo_b: str) -> list:
+    """Lines of one ``while`` body that the other lacks (``-`` of a,
+    ``+`` of b), after ``while_body_lines`` took the constants out: empty
+    where the two bursts are one device program."""
+    a = collections.Counter(while_body_lines(hlo_a))
+    b = collections.Counter(while_body_lines(hlo_b))
+    return sorted(["- " + l for l in (a - b).elements()]
+                  + ["+ " + l for l in (b - a).elements()])
+
+
 def alias_count(hlo: str) -> int:
     """Entries of the module's ``input_output_alias={ {3}: (12, {}, may-alias), ...}``."""
     header = hlo.split("\n", 1)[0]
     return len(re.findall(r"\{\d+\}: \(\d+, \{\}", header))
 
 
-def compile_burst(cfg: dict, attn_len: int, device_sharding):
+def compile_burst(cfg: dict, attn_len, device_sharding):
     """``_burst_fn`` compiled from shapes at the configuration's sizes:
     nothing is allocated, so this needs no device memory."""
     import jax
@@ -182,9 +222,10 @@ def compile_burst(cfg: dict, attn_len: int, device_sharding):
     return compiled, (lanes, mc.n_kv_heads, T, mc.head_dim), cache_bytes
 
 
-def check(cfg: dict, attn_len: int, device_sharding, hlo_dir=None,
+def check(cfg: dict, attn_len, device_sharding, hlo_dir=None,
           temp_limit=None) -> dict:
-    """``temp_limit``: ``TEMP_BEFORE``'s entry where ``cfg`` has the
+    """``attn_len``: a bucket, or None for the burst without one.
+    ``temp_limit``: ``TEMP_BEFORE``'s entry where ``cfg`` has the
     configuration's own depth (``main`` passes it), none for a cut one."""
     compiled, (lanes, kv, T, dh), cache_bytes = compile_burst(
         cfg, attn_len, device_sharding)
@@ -194,7 +235,9 @@ def check(cfg: dict, attn_len: int, device_sharding, hlo_dir=None,
         with open(os.path.join(hlo_dir, f"{cfg['name']}.{attn_len}.hlo.txt"), "w") as f:
             f.write(hlo)
     mem = compiled.memory_analysis()
-    found = cache_shaped(hlo, lanes, kv, (T, attn_len), dh)
+    bounded = attn_len is not None and attn_len < T
+    found = cache_shaped(
+        hlo, lanes, kv, (T, attn_len) if bounded else (T,), dh)
     kinds: dict = {}
     for op, result, inside, _line in found:
         key = f"{op} {result} {'inside' if inside else 'outside'} the while"
@@ -203,7 +246,7 @@ def check(cfg: dict, attn_len: int, device_sharding, hlo_dir=None,
     leaves = 2 * layers
     aliases = alias_count(hlo)
     kernels = kernel_calls(hlo)
-    bucket = bucket_shaped(hlo, lanes, kv, attn_len, dh) if attn_len < T else 0
+    bucket = bucket_shaped(hlo, lanes, kv, attn_len, dh) if bounded else 0
     return {
         "lanes": lanes, "attn_len": attn_len,
         "cache_shaped_copies_and_slices": kinds,
@@ -226,7 +269,9 @@ def check(cfg: dict, attn_len: int, device_sharding, hlo_dir=None,
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--config", nargs="*", default=list(CONFIGS))
-    ap.add_argument("--attn-len", nargs="*", type=int, default=list(ATTN_LENS))
+    ap.add_argument("--attn-len", nargs="*", default=list(ATTN_LENS),
+                    type=lambda v: None if v.lower() == "none" else int(v),
+                    help="buckets, or 'none' for the burst without one")
     ap.add_argument("--described", metavar="TOPOLOGY",
                     help="compile for a described TPU (e.g. v5e:2x2), no chip")
     ap.add_argument("--hlo-dir", help="keep each optimised HLO here")
@@ -262,6 +307,21 @@ def main(argv=None) -> int:
                         args.hlo_dir, TEMP_BEFORE.get(cfg["name"]))
             print(json.dumps({"config": cfg["name"], **out}))
             ok = ok and out["ok"]
+        buckets = [a for a in args.attn_len if a is not None]
+        if args.hlo_dir and None in args.attn_len and buckets:
+            texts = []
+            for attn_len in (None, buckets[0]):
+                with open(os.path.join(
+                        args.hlo_dir,
+                        f"{cfg['name']}.{attn_len}.hlo.txt")) as f:
+                    texts.append(f.read())
+            diff = while_body_diff(*texts)
+            print(json.dumps({
+                "config": cfg["name"],
+                "while_body": f"None against {buckets[0]}",
+                "differs_beyond_constants": bool(diff),
+                "differing_lines": len(diff), "first": diff[:6],
+            }))
     print("burst_hlo_check:", "OK" if ok else "FAIL")
     return 0 if ok else 1
 

@@ -6,7 +6,7 @@ the full ``{"units": {name: dump}}`` payload or one unit's dump), from a
 file argument or stdin (``-``). Output: a per-unit report attributing
 where generation time is going — queue wait vs first-token latency vs
 decode pacing — plus what the scheduler actually decided poll by poll
-(depth-group splits and cost-model merges, chunked-prefill interleave,
+(the burst's mode, K and bucket, chunked-prefill interleave,
 prefix-cache hits, shed events).
 
 Usage::
@@ -751,27 +751,16 @@ def diagnose(dump: Dict[str, Any]) -> List[str]:
         f"avg admit-queue depth {avg_queue:.1f}, {admits} admissions"
     )
 
-    # -- depth-group plan + cost-model verdicts ------------------------------
+    # -- the bursts' plans ---------------------------------------------------
     planned = [p for p in polls if "plan" in p]
-    # fused polls carry the same groups/distinct_buckets/merged fields —
-    # the cost-model verdict must not go dark when fused decode is on
     decode = [p for p in planned if p["plan"].get("mode") in ("decode", "fused")]
     if decode:
-        split = [p for p in decode if len(p["plan"].get("groups", [])) > 1]
-        merged_polls = [p for p in decode if p["plan"].get("merged", 0) > 0]
         mixed = [p for p in decode if p["plan"].get("distinct_buckets", 1) > 1]
         lines.append(
-            f"depth grouping: {len(mixed)}/{len(decode)} decode polls had "
-            f"mixed attention depths; {_pct(len(split), len(decode)):.0f}% "
-            f"dispatched split sub-bursts, cost model merged groups on "
-            f"{_pct(len(merged_polls), len(decode)):.0f}% of polls"
+            f"attention depths: {len(mixed)}/{len(decode)} decode polls had "
+            f"lanes in more than one attention bucket (deepest "
+            f"{max(p['plan'].get('bucket', 0) for p in decode)})"
         )
-        if mixed and not split:
-            lines.append(
-                "DIAGNOSIS: depths mix but every poll merged — either "
-                "depth_groups is off/1 or the cost model says splits don't "
-                "pay at this model size (see depth_group_split_bytes)"
-            )
     spec = [p for p in planned if p["plan"].get("mode") == "spec"]
     if spec:
         lines.append(f"speculative decode: {len(spec)} spec-burst polls")

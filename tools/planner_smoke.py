@@ -126,14 +126,12 @@ def main() -> int:
         plan_profile = os.path.join(root, "plan.spf1")
         write_profile(plan_profile, build_profile("llm-smoke", [
             {"config": {"slots": 2, "prefill_chunk": 0,
-                        "fused_steps_per_dispatch": 8, "depth_groups": 0,
-                        "depth_group_split_bytes": 0, "kv_tier_bytes": 0},
+                        "fused_steps_per_dispatch": 8, "kv_tier_bytes": 0},
              "tokens_per_s": 200.0, "ttft_p50_ms": 400.0,
              "ttft_p99_ms": 900.0, "tpot_p50_ms": 30.0,
              "tpot_p99_ms": 60.0, "hbm_bytes": 10**9},
             {"config": {"slots": 2, "prefill_chunk": 0,
-                        "fused_steps_per_dispatch": 4, "depth_groups": 0,
-                        "depth_group_split_bytes": 0, "kv_tier_bytes": 0},
+                        "fused_steps_per_dispatch": 4, "kv_tier_bytes": 0},
              "tokens_per_s": 300.0, "ttft_p50_ms": 120.0,
              "ttft_p99_ms": 250.0, "tpot_p50_ms": 8.0,
              "tpot_p99_ms": 15.0, "hbm_bytes": 10**9},
