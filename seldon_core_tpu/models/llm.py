@@ -270,28 +270,31 @@ class DecoderLM(ServedModel):
             # scalar (uniform batch) or [B] vector (ragged continuous
             # batch: every row writes at its own position)
             ck, cv, cache_pos = kv_cache
-            if getattr(cache_pos, "ndim", 0):
-                ck = self._cache_write(ck, k, cache_pos[:, None])
-                cv = self._cache_write(cv, v, cache_pos[:, None])
-            else:
-                ck = lax.dynamic_update_slice(ck, k, (0, 0, cache_pos, 0))
-                cv = lax.dynamic_update_slice(cv, v, (0, 0, cache_pos, 0))
-            new_cache = (ck, cv)
             if lens is not None:
-                # ragged single-position decode: each lane reads its own
-                # ``lens[b]`` positions of the unsliced cache (the kernel
-                # on a TPU, the two dots over the bucket elsewhere)
+                # ragged single-position decode: each lane's row lands at
+                # its ``cache_pos[b]``, then the lane reads its own
+                # ``lens[b]`` positions of the unsliced cache (both inside
+                # the kernel on a TPU; the scatter and the two dots over
+                # the bucket elsewhere)
                 from ..ops import decode_attention
 
-                o = decode_attention(
-                    q, ck, cv, positions, lens, attn_len=attn_len,
+                o, ck, cv = decode_attention(
+                    q, ck, cv, k, v, cache_pos, positions, lens,
+                    attn_len=attn_len,
                     mesh=getattr(self, "_serving_mesh", None),
                 )
             else:
+                if getattr(cache_pos, "ndim", 0):
+                    ck = self._cache_write(ck, k, cache_pos[:, None])
+                    cv = self._cache_write(cv, v, cache_pos[:, None])
+                else:
+                    ck = lax.dynamic_update_slice(ck, k, (0, 0, cache_pos, 0))
+                    cv = lax.dynamic_update_slice(cv, v, (0, 0, cache_pos, 0))
                 # decode attention over the (sliced) cache — see
                 # _cache_attention for why the GQA repeat must not happen here
                 k, v = self._cache_read(ck, cv, attn_len)
                 o = self._cache_attention(q, k, v, positions, dt)
+            new_cache = (ck, cv)
         else:
             if KVl < Hl:  # GQA: repeat kv groups (compute-bound prefill
                 # path only; the decode path reads grouped to keep the
@@ -311,42 +314,17 @@ class DecoderLM(ServedModel):
 
     @staticmethod
     def _cache_write(cache, new, positions):
-        """The ONE ragged cache write: ``new`` [B, KV, W, Dh] lands in
-        ``cache`` [B, KV, T, Dh] at ``positions`` [B, W] (row b's column j
-        at position positions[b, j]). A position >= T (or < 0) is DROPPED (JAX
-        scatter semantics) — the stop-aware bursts park finished lanes'
-        writes there.
+        """The ragged cache write by scatter: ``new`` [B, KV, W, Dh] lands
+        in ``cache`` [B, KV, T, Dh] at ``positions`` [B, W]; a position
+        outside [0, T) is DROPPED. It is
+        ``ops.decode_attention.cache_write`` (why its window is one ``Dh``
+        row is told there). The speculative and chunked windows, prefix
+        prefill and the stacked scan write through this; the ragged
+        single-position step hands its row to ``ops.decode_attention()``,
+        which on a TPU lands it from inside the read's kernel."""
+        from ..ops.decode_attention import cache_write
 
-        Every (lane, KV head, position) is a scatter row of its own, so
-        the window is the ``Dh`` vector alone — already the minor-most
-        dimension of the cache as every other executable holds it. The
-        textbook ``cache.at[rows, :, pos, :].set(...)`` has a [KV, Dh]
-        window instead, and the TPU compiler then carries the cache
-        through the burst's loop with KV minor to T: a cache-sized
-        relayout copy of every layer's K and V on entry to the burst and
-        another on exit, into scratch as large as the cache, whatever
-        ``donate_argnums`` says (ISSUE 26: 27% of device time at 28 lanes
-        x 24 layers). ``tools/burst_hlo_check.py`` compiles the burst and
-        fails on such a copy; PERF.md section 6 has both outputs."""
-        import jax.numpy as jnp
-        from jax import lax
-
-        B, KV, W, _ = new.shape
-        index = jnp.stack(jnp.broadcast_arrays(
-            jnp.arange(B, dtype=jnp.int32)[:, None, None],
-            jnp.arange(KV, dtype=jnp.int32)[None, :, None],
-            positions.astype(jnp.int32)[:, None, :],
-        ), axis=-1)  # [B, KV, W, 3]: (lane, KV head, position)
-        # lax.scatter itself, not cache.at[..].set: jnp's index handling is
-        # traced once per layer for K and for V in every burst variant
-        # that warm() lowers, and cost ~0.2 s a variant there
-        return lax.scatter(
-            cache, index, new.astype(cache.dtype),
-            lax.ScatterDimensionNumbers(
-                update_window_dims=(3,), inserted_window_dims=(0, 1, 2),
-                scatter_dims_to_operand_dims=(0, 1, 2)),
-            mode=lax.GatherScatterMode.FILL_OR_DROP,
-        )
+        return cache_write(cache, new, positions)
 
     @staticmethod
     def _cache_read(ck, cv, attn_len):
@@ -556,14 +534,16 @@ class DecoderLM(ServedModel):
         prefix (measured ~2.5x step-time on a v5e). The per-layer arrays
         are carried through the caller's step loop (the batcher's fused
         burst donates them), and per step and layer the compiled burst
-        does two things to a cache array and no third: ``_cache_write``'s
-        scatter of one ``Dh`` row per (lane, KV head) into the donated
-        buffer itself, and ``ops.decode_attention()``'s read: on a TPU the
-        ragged kernel copying each lane's live blocks out of the buffer
-        where it lies, elsewhere the two dots reading ``[B, KV, attn_len,
-        Dh]`` of it as a fused operand. No copy on entry or exit, no
-        slice written out: ``tools/burst_hlo_check.py`` compiles the
-        burst at the benchmark's shapes and fails on either
+        does one thing to a cache array: ``ops.decode_attention()``. On a
+        TPU that is ONE kernel call with the array aliased in and out,
+        which puts each live lane's new ``Dh`` row per KV head into the
+        donated buffer itself and copies the lane's live blocks out of
+        the buffer where it lies; elsewhere ``cache_write``'s scatter of
+        those rows into the buffer and the two dots reading ``[B, KV,
+        attn_len, Dh]`` of it as a fused operand. No copy on entry or
+        exit, no slice written out, on a TPU no scatter:
+        ``tools/burst_hlo_check.py`` compiles the burst at the
+        benchmark's shapes and fails on any of them
         (``tests/test_burst_hlo.py`` runs it for a described v5e); before
         ISSUE 26 the same check found 96 cache-sized copies a burst and
         4.3 GB of scratch beside a 5.6 GB cache. The continuous batcher
@@ -571,8 +551,11 @@ class DecoderLM(ServedModel):
 
         ``lens`` ([B] int32, optional): how many positions of its cache
         each row reads: ``pos + 1``, or 0 for a lane that is idle or done
-        (the kernel then copies nothing for it; the caller drops its
-        logits). Defaults to ``pos + 1`` on every row.
+        (the kernel then copies nothing for it, in or out: its K/V row
+        is NOT written, being that of a token nobody sampled at a
+        position no read admits before the lane's next occupant
+        overwrites it; the scatter off a TPU still writes it; the caller
+        drops its logits). Defaults to ``pos + 1`` on every row.
 
         ``write_pos`` ([B] int32, optional): per-row K/V WRITE position
         when it must differ from the attention position — the fused
@@ -595,13 +578,31 @@ class DecoderLM(ServedModel):
         blocks = params["blocks"]
         nks: list = []
         nvs: list = []
+        # a layer's weights are cut from the stacked arrays when the layer
+        # before it has written its cache (the first's: when the embedding
+        # is there), tied to that here: left free, the TPU compiler cuts
+        # every layer's wq, wk and wv at the top of the step, keeps the
+        # few that fit in VMEM there and sends the rest through an HBM
+        # temporary each (written, prefetched, read: 1.0-2.6 ms of a
+        # 6.3-11.3 ms step and 0.35-0.6 GB of scratch, PERF.md section 6,
+        # PR 32). The tie is a value that is written out anyway: tying to
+        # the layer's input splits the fusion that makes it (the next
+        # norm's sum of squares then reads the rounded sum, and greedy
+        # tokens change)
+        tie = x
         for l in range(len(ks)):
+            tie, blocks = jax.lax.optimization_barrier((tie, blocks))
+            if l == 0:
+                x = tie
+            else:
+                nks[-1], nvs[-1] = tie
             layer_p = jax.tree_util.tree_map(lambda a, l=l: a[l], blocks)
             x, nk, nv = self._decode_layer(
                 layer_p, x, pos, ks[l], vs[l], wp, attn_len, lens
             )
             nks.append(self._tp_cache(nk))
             nvs.append(self._tp_cache(nv))
+            tie = (nks[-1], nvs[-1])
         return self._decode_head(params, x), nks, nvs
 
     def decode_chunk_ragged_list(self, params, ks, vs, tokens, pos, attn_len=None):

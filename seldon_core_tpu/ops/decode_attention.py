@@ -1,5 +1,6 @@
-"""Single-position decode attention over the KV cache: a ragged Pallas TPU
-kernel that streams each lane's own length, and the two XLA dots.
+"""Single-position decode attention over the KV cache, the step's write
+included: a ragged Pallas TPU kernel that lands each live lane's new row
+and streams the lane's own length, and the scatter with the two XLA dots.
 
 Motivation: a decode burst reads the cache through one static bucket
 (``attn_len`` >= the deepest lane's end-of-burst position), so the dots
@@ -8,6 +9,16 @@ position included. On a part-loaded replica that is 90-97% of the KV
 bytes (ISSUE 30). The kernel takes ``lens [B]`` and copies
 ``ceil(len / block)`` blocks of K and of V per lane from HBM; a lane of
 length 0 issues no copy.
+
+The write (ISSUE 32): before the kernel took it, this step's K and V row
+of every lane went into the cache by one ``lax.scatter`` each, 19-20 us
+apiece for 224-256 rows of 256 B whatever the number of live lanes:
+twice the time of the read they fed at a part-loaded 28 lanes. The
+kernel has the block that holds the row in VMEM anyway (a live lane
+writes where its read ends), so it puts the row there before the scores
+and copies the row's aligned group of ``GROUP`` positions back to the
+cache, which is aliased in and out of the call. ``cache_write()`` is the
+scatter, for everything that is not this step.
 
 The earlier kernel that lost here (23.7 ms a step against the dots' 6.0,
 16 lanes x 1920 keys) had a grid of (lanes, chunks) programs with
@@ -18,16 +29,17 @@ x all KV heads (512 KB a K and V pair), double-buffered across lane
 boundaries, so the next lane's first block is in flight while this
 lane's last one is computed.
 
-``decode_attention()`` is the public entry: it picks the kernel by what
-it can see (``T == 1``, ``head_dim`` a multiple of 128, the cache length
-a multiple of the block, no serving mesh) and by the platform the
-executable is LOWERED for (``lax.platform_dependent``; a process whose
-backend is the CPU can compile for a described TPU and gets the
-kernel), and takes ``cache_attention()``'s dots everywhere else. That
-rule is ``reads_ragged()``; the scheduler asks it too, because a read
-that bounds itself per lane needs no static bucket and so no executable
-per bucket. ``ragged_decode_attention()`` is the kernel itself
-(``interpret`` runs it on the CPU for the equivalence tests).
+``decode_attention()`` is the public entry for "write this step's row,
+then read": it picks the kernel by what it can see (``T == 1``,
+``head_dim`` a multiple of 128, the cache length a multiple of the
+block, no serving mesh) and by the platform the executable is LOWERED
+for (``lax.platform_dependent``; a process whose backend is the CPU can
+compile for a described TPU and gets the kernel), and takes
+``cache_write()`` twice and ``cache_attention()``'s dots everywhere
+else. That rule is ``reads_ragged()``; the scheduler asks it too,
+because a read that bounds itself per lane needs no static bucket and so
+no executable per bucket. ``ragged_decode_attention()`` is the kernel
+itself (``interpret`` runs it on the CPU for the equivalence tests).
 """
 
 from __future__ import annotations
@@ -52,6 +64,15 @@ NEG_INF = -1e30
 # It is also the scheduler's bucket step (``attn_bucket``), so every
 # bucket tiles.
 BLOCK = 128
+# Positions the in-kernel write copies back to the cache around the new
+# row. One bfloat16 row cannot be addressed: two rows share a 32-bit
+# sublane, and Mosaic (jax 0.9.0, for a v5e) refuses a 1-row slice of a
+# ref ("slice shape along dimension 2 must be aligned to tiling") and a
+# dynamic vector load of 2 rows ("cannot statically prove that index in
+# dimension 2 is a multiple of 8"). So the kernel writes the row's
+# aligned group of 8: one T(8,128)(2,1) tile of the cache as XLA lays it
+# out in HBM, 16 KB a lane for K over 8 KV heads and as much for V.
+GROUP = 8
 
 
 def reads_ragged(platform, q_shape, cache_shape, dtypes, mesh=None) -> bool:
@@ -113,16 +134,67 @@ def cache_attention(q, kc, vc, bound, dt):
     return o.reshape(B, Hl, T, Dh)
 
 
-def _ragged_kernel(lens_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sem,
-                   *, block):
-    """The whole batch of one layer: for each lane with ``len > 0``, walk
-    its ``ceil(len / block)`` blocks with an online softmax.
+def cache_write(cache, new, positions):
+    """The ONE ragged cache write by scatter: ``new`` [B, KV, W, Dh] lands
+    in ``cache`` [B, KV, T, Dh] at ``positions`` [B, W] (row b's column j
+    at position positions[b, j]). A position >= T (or < 0) is DROPPED (JAX
+    scatter semantics): the stop-aware bursts park finished lanes'
+    writes there. The windows (speculation, chunked and prefix prefill),
+    the stacked scan and every platform without the kernel write through
+    this; the burst's single-position step on a TPU writes from inside
+    ``ragged_decode_attention()``.
 
-    lens_ref: SMEM [B]; q_ref / o_ref: VMEM [B, KV, rep, Dh]; k_hbm /
-    v_hbm: the layer's cache [B, KV, T, Dh], left where it is; kbuf /
-    vbuf: VMEM [2, KV, block, Dh]; sem: DMA semaphores [2 (k, v), 2].
+    Every (lane, KV head, position) is a scatter row of its own, so
+    the window is the ``Dh`` vector alone: already the minor-most
+    dimension of the cache as every other executable holds it. The
+    textbook ``cache.at[rows, :, pos, :].set(...)`` has a [KV, Dh]
+    window instead, and the TPU compiler then carries the cache
+    through the burst's loop with KV minor to T: a cache-sized
+    relayout copy of every layer's K and V on entry to the burst and
+    another on exit, into scratch as large as the cache, whatever
+    ``donate_argnums`` says (ISSUE 26: 27% of device time at 28 lanes
+    x 24 layers). ``tools/burst_hlo_check.py`` compiles the burst and
+    fails on such a copy; PERF.md section 6 has both outputs."""
+    B, KV, W, _ = new.shape
+    index = jnp.stack(jnp.broadcast_arrays(
+        jnp.arange(B, dtype=jnp.int32)[:, None, None],
+        jnp.arange(KV, dtype=jnp.int32)[None, :, None],
+        positions.astype(jnp.int32)[:, None, :],
+    ), axis=-1)  # [B, KV, W, 3]: (lane, KV head, position)
+    # lax.scatter itself, not cache.at[..].set: jnp's index handling is
+    # traced once per layer for K and for V in every burst variant
+    # that warm() lowers, and cost ~0.2 s a variant there
+    return lax.scatter(
+        cache, index, new.astype(cache.dtype),
+        lax.ScatterDimensionNumbers(
+            update_window_dims=(3,), inserted_window_dims=(0, 1, 2),
+            scatter_dims_to_operand_dims=(0, 1, 2)),
+        mode=lax.GatherScatterMode.FILL_OR_DROP,
+    )
+
+
+def _ragged_kernel(lens_ref, wpos_ref, q_ref, knew_ref, vnew_ref, _k_in, _v_in,
+                   o_ref, k_hbm, v_hbm, kbuf, vbuf, kstage, vstage, sem, wsem,
+                   rsem, *, block):
+    """The whole batch of one layer: for each lane with ``len > 0``, walk
+    its ``ceil(len / block)`` blocks with an online softmax, and where a
+    block holds the lane's ``write_pos`` put the new row into it first
+    and copy the row's group back to the cache. A ``write_pos`` inside
+    the cache that no block of the lane's read holds (no caller's: a step
+    writes where its read ends) has its group fetched, patched and copied
+    back after the lane's read, so the cache is the scatter's either way.
+
+    lens_ref, wpos_ref: SMEM [B]; q_ref / o_ref: VMEM [B, KV, rep, Dh];
+    knew_ref / vnew_ref: VMEM [B, KV, 1, Dh]; k_hbm / v_hbm: the layer's
+    cache [B, KV, T, Dh], left where it is (the call's aliased outputs:
+    ``_k_in`` / ``_v_in`` are the same buffers); kbuf / vbuf: VMEM [2, KV,
+    block, Dh]; kstage / vstage: VMEM [B, KV, GROUP, Dh], a lane's patched
+    group while its copy to the cache is in flight; sem: DMA semaphores
+    [2 (k, v), 2] of the reads, wsem: [2 (k, v)] of the writes, rsem: [2
+    (k, v)] of the fetch of a group that no block held.
     """
     n_lanes, n_kv, rep, dh = q_ref.shape
+    t = k_hbm.shape[2]
     scale = 1.0 / np.sqrt(dh)
 
     def copies(lane, i, slot):
@@ -140,6 +212,43 @@ def _ragged_kernel(lens_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sem,
         for c in copies(lane, i, slot):
             c.start()
 
+    def write_back(which, lane, wp):
+        stage, hbm = ((kstage, k_hbm), (vstage, v_hbm))[which]
+        group = pl.multiple_of(wp // GROUP * GROUP, GROUP)
+        return pltpu.make_async_copy(
+            stage.at[lane], hbm.at[lane, :, pl.ds(group, GROUP), :],
+            wsem.at[which])
+
+    def patched(which, lane, wp, group):
+        """``group`` [KV, GROUP, Dh] with the lane's new row at ``wp``."""
+        row = lax.broadcasted_iota(jnp.int32, group.shape, 1)
+        return jnp.where(
+            row == wp % GROUP, (knew_ref, vnew_ref)[which][lane], group)
+
+    def land(which, lane, wp, slot):
+        """The block in ``slot`` holds position ``wp`` and its read is
+        done: the new row replaces the stale one there, and its group
+        starts back to the cache (nobody waits for it before the end)."""
+        buf, stage = ((kbuf, kstage), (vbuf, vstage))[which]
+        at = pl.ds(pl.multiple_of(wp % block // GROUP * GROUP, GROUP), GROUP)
+        group = patched(which, lane, wp, buf[slot, :, at, :])
+        buf[slot, :, at, :] = group
+        stage[lane] = group
+        write_back(which, lane, wp).start()
+
+    def land_unread(which, lane, wp):
+        """No block of the lane's read held ``wp``: its group comes from
+        the cache into the staging buffer, takes the row and goes back."""
+        stage, hbm = ((kstage, k_hbm), (vstage, v_hbm))[which]
+        group = pl.multiple_of(wp // GROUP * GROUP, GROUP)
+        fetch = pltpu.make_async_copy(
+            hbm.at[lane, :, pl.ds(group, GROUP), :], stage.at[lane],
+            rsem.at[which])
+        fetch.start()
+        fetch.wait()
+        stage[lane] = patched(which, lane, wp, stage[lane])
+        write_back(which, lane, wp).start()
+
     def next_live(lane):
         """The first lane after ``lane`` that reads anything, or B."""
         return lax.while_loop(
@@ -153,9 +262,17 @@ def _ragged_kernel(lens_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sem,
     def _():
         start(first, 0, 0)
 
-    def lane_body(lane, done_blocks):
+    def lane_body(lane, carry):
+        done_blocks, written = carry
         n = lens_ref[lane]
         n_blocks = (n + block - 1) // block
+        wp = wpos_ref[lane]
+        # a live lane writes unless its position is parked outside the
+        # cache; the block that takes the write, or none (-1) where the
+        # position lies past what the lane reads
+        writes = (n > 0) & (wp >= 0) & (wp < t)
+        w_block = jnp.where(writes & (wp // block < n_blocks),
+                            wp // block, -1)
         q = q_ref[lane]  # [KV, rep, Dh]
 
         def block_body(i, carry):
@@ -178,6 +295,11 @@ def _ragged_kernel(lens_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sem,
 
             k_copy, v_copy = copies(lane, i, slot)
             k_copy.wait()
+
+            @pl.when(i == w_block)
+            def _():
+                land(0, lane, wp, slot)
+
             s = jnp.einsum(
                 "grd,gkd->grk", q, kbuf[slot],
                 preferred_element_type=jnp.float32,
@@ -191,6 +313,11 @@ def _ragged_kernel(lens_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sem,
             p = jnp.exp(s - m_new)
             l = l * alpha + p.sum(axis=-1, keepdims=True)
             v_copy.wait()
+
+            @pl.when(i == w_block)
+            def _():
+                land(1, lane, wp, slot)
+
             o = o * alpha + jnp.einsum(
                 "grk,gkd->grd", p.astype(vbuf.dtype), vbuf[slot],
                 preferred_element_type=jnp.float32,
@@ -205,86 +332,138 @@ def _ragged_kernel(lens_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sem,
         )
         # a lane of length 0 ran no block: o = 0, l = 0, zeros out
         o_ref[lane] = (o / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
-        return done_blocks + n_blocks
 
-    lax.fori_loop(0, n_lanes, lane_body, jnp.int32(0))
+        @pl.when(writes & (w_block < 0))
+        def _():
+            land_unread(0, lane, wp)
+            land_unread(1, lane, wp)
+
+        return done_blocks + n_blocks, written + writes.astype(jnp.int32)
+
+    _, written = lax.fori_loop(
+        0, n_lanes, lane_body, (jnp.int32(0), jnp.int32(0)))
+
+    # the next layer-step of this cache is a later call: every write has
+    # landed when this one returns (each wait takes one group's bytes)
+    def drain(_, carry):
+        write_back(0, 0, 0).wait()
+        write_back(1, 0, 0).wait()
+        return carry
+
+    lax.fori_loop(0, written, drain, 0)
 
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
-def ragged_decode_attention(q, k, v, lens, block: int = BLOCK,
-                            interpret: bool = False):
-    """Pallas ragged decode attention. q [B, H, 1, Dh]; k, v the layer's
-    cache [B, KV, T, Dh], unsliced (``T`` must divide by ``block``);
-    lens [B] int32, clamped to [0, T]. Lane b attends to positions
-    [0, lens[b]); 0 reads nothing and gives zeros."""
+def ragged_decode_attention(q, k, v, lens, k_new, v_new, write_pos,
+                            block: int = BLOCK, interpret: bool = False):
+    """Pallas ragged decode attention with the step's write inside it.
+    q [B, H, 1, Dh]; k, v the layer's cache [B, KV, T, Dh], unsliced
+    (``T`` must divide by ``block``); lens [B] int32, clamped to [0, T];
+    k_new, v_new [B, KV, 1, Dh] this step's rows and write_pos [B] where
+    they go. Returns ``(o, k, v)``: the caches are aliased in and out, so
+    under a caller that donates them nothing but the rows' groups moves.
+
+    Lane b first takes its new row at ``write_pos[b]``, then attends to
+    positions [0, lens[b]): the result is the read of the cache
+    ``cache_write()`` would have made, bit for bit. The row lands from
+    the block that holds it, while the read has that block in VMEM (a
+    step writes at ``lens[b] - 1``, in the read's last block); a
+    ``write_pos[b]`` in a block the lane does not read costs a fetch of
+    its group after the read, and one outside [0, T) is dropped as the
+    scatter drops it. A lane with ``lens[b] == 0`` reads nothing, gives
+    zeros and WRITES NOTHING: its row is the K and V of a token nobody
+    sampled, at a position no read admits before the lane's next
+    occupant overwrites it."""
     b, h, t_q, dh = q.shape
     n_kv, t = k.shape[1], k.shape[2]
-    if t_q != 1 or h % n_kv or t % block:
+    if t_q != 1 or h % n_kv or t % block or block % GROUP:
         raise ValueError(
             f"q {q.shape} / cache {k.shape} do not fit the kernel "
-            f"(T == 1, H a multiple of KV, cache length a multiple of {block})"
+            f"(T == 1, H a multiple of KV, cache length a multiple of {block}"
+            f", block a multiple of {GROUP})"
         )
     rep = h // n_kv
-    out = pl.pallas_call(
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    out, k, v = pl.pallas_call(
         functools.partial(_ragged_kernel, block=block),
-        out_shape=jax.ShapeDtypeStruct((b, n_kv, rep, dh), q.dtype),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec(memory_space=pltpu.VMEM),
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=pl.ANY),
-        ],
-        out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
+        out_shape=(
+            jax.ShapeDtypeStruct((b, n_kv, rep, dh), q.dtype),
+            jax.ShapeDtypeStruct(k.shape, k.dtype),
+            jax.ShapeDtypeStruct(v.shape, v.dtype),
+        ),
+        in_specs=[smem, smem, vmem, vmem, vmem, hbm, hbm],
+        out_specs=(vmem, hbm, hbm),
+        input_output_aliases={5: 1, 6: 2},
         scratch_shapes=[
             pltpu.VMEM((2, n_kv, block, dh), k.dtype),
             pltpu.VMEM((2, n_kv, block, dh), v.dtype),
+            pltpu.VMEM((b, n_kv, GROUP, dh), k.dtype),
+            pltpu.VMEM((b, n_kv, GROUP, dh), v.dtype),
             pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SemaphoreType.DMA((2,)),
         ],
         interpret=interpret,
-    )(jnp.clip(lens.astype(jnp.int32), 0, t), q.reshape(b, n_kv, rep, dh), k, v)
-    return out.reshape(b, h, 1, dh)
+    )(jnp.clip(lens.astype(jnp.int32), 0, t), write_pos.astype(jnp.int32),
+      q.reshape(b, n_kv, rep, dh), k_new.astype(k.dtype),
+      v_new.astype(v.dtype), k, v)
+    return out.reshape(b, h, 1, dh), k, v
 
 
 @functools.partial(jax.jit, static_argnames=("attn_len", "mesh"))
-def decode_attention(q, k, v, pos, lens, attn_len=None, mesh=None):
-    """Dispatching decode attention: q [B, H, 1, Dh] against the layer's
-    UNSLICED cache k, v [B, KV, T, Dh]. ``pos`` [B] is each lane's
-    position and ``lens`` [B] what is read of its cache: ``pos + 1`` for
-    a lane whose output anyone reads, 0 for one that is idle or done.
-    ``attn_len`` (static) is the scheduler's bucket, an upper bound on
-    every ``lens[b]``; None where the caller has none, and the bound is
-    then the cache's length.
+def decode_attention(q, k, v, k_new, v_new, write_pos, pos, lens,
+                     attn_len=None, mesh=None):
+    """The decode step's write and read of one layer's cache: this step's
+    rows k_new, v_new [B, KV, 1, Dh] go into the UNSLICED cache k, v [B,
+    KV, T, Dh] at ``write_pos`` [B] (outside [0, T): dropped), then q [B,
+    H, 1, Dh] attends to it. Returns ``(o, k, v)``. ``pos`` [B] is each
+    lane's position and ``lens`` [B] what is read of its cache: ``pos +
+    1`` for a lane whose output anyone reads, 0 for one that is idle or
+    done. ``attn_len`` (static) is the scheduler's bucket, an upper bound
+    on every ``lens[b]``; None where the caller has none, and the bound
+    is then the cache's length.
 
-    The kernel streams ``lens[b]`` positions of lane b, none for 0, and
-    gives such a lane zeros. The dots cannot skip a lane: they read
-    ``attn_len`` positions of every lane under the ``key_pos <= pos``
-    mask, as they did before there was a kernel, so an idle lane's row is
-    there what its stale position makes it. Nobody reads that row; where
-    ``lens[b] > 0`` the two agree to rounding (tests/test_decode_attention.py).
+    The kernel streams ``lens[b]`` positions of lane b and lands the
+    lane's row from the block that holds it (wherever in the cache
+    ``write_pos[b]`` lies); a lane with ``lens[b] == 0`` is given zeros
+    and its row is NOT written: no read admits that position before the
+    lane's next occupant overwrites it. The scatter and the dots cannot
+    skip a lane: they write every lane's row and read ``attn_len``
+    positions of every lane under the ``key_pos <= pos`` mask, as they
+    did before there was a kernel, so an idle lane's output is there what
+    its stale position makes it. Nobody reads that output; where
+    ``lens[b] > 0`` the two agree to rounding, caches bit for bit
+    (tests/test_decode_attention.py).
 
     Jitted, so the burst's unrolled layers lower it once and call it
     (24 call sites a step would otherwise trace and lower 24 kernels in
     every variant ``warm()`` builds).
 
     ``mesh``: the serving mesh when the caller runs under one
-    (``reads_ragged()``: it takes the dots).
+    (``reads_ragged()``: it takes the scatter and the dots).
     """
     t = k.shape[2]
     bound = t if attn_len is None else min(int(attn_len), t)
 
-    def dots(q, k, v, pos, lens):
-        return cache_attention(
+    def dots(q, k, v, k_new, v_new, write_pos, pos, lens):
+        k = cache_write(k, k_new, write_pos[:, None])
+        v = cache_write(v, v_new, write_pos[:, None])
+        o = cache_attention(
             q, lax.slice_in_dim(k, 0, bound, axis=2),
             lax.slice_in_dim(v, 0, bound, axis=2), pos, q.dtype)
+        return o, k, v
 
-    def kernel(q, k, v, pos, lens):
+    def kernel(q, k, v, k_new, v_new, write_pos, pos, lens):
         return ragged_decode_attention(
-            q, k, v, jnp.minimum(lens, bound), block=BLOCK)
+            q, k, v, jnp.minimum(lens, bound), k_new, v_new, write_pos,
+            block=BLOCK)
 
+    args = (q, k, v, k_new, v_new, write_pos, pos, lens)
     # the platform is known only when this is lowered: ask whether a
     # lowering for a TPU takes the kernel, and let that lowering choose
     if not reads_ragged(
             "tpu", q.shape, k.shape, (q.dtype, k.dtype, v.dtype), mesh):
-        return dots(q, k, v, pos, lens)
-    return lax.platform_dependent(
-        q, k, v, pos, lens, tpu=kernel, default=dots)
+        return dots(*args)
+    return lax.platform_dependent(*args, tpu=kernel, default=dots)

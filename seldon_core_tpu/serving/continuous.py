@@ -29,7 +29,8 @@ calls"). This scheduler closes that gap the TPU way:
   that finished mid-pipeline just decodes a few ignored tokens before the
   host notices and re-admits).
 * The KV cache is held as per-layer arrays and updated IN PLACE: only the
-  one-position scatter touches HBM per step (a stacked cache threaded
+  one-position write touches HBM per step (a scatter, or the decode
+  kernel's own copy where ``reads_ragged`` holds; a stacked cache threaded
   through the layer scan made XLA rewrite every byte of it every step).
   The attention READ follows the live prefix, not the allocated cache:
   where ``ops.decode_attention.reads_ragged`` holds each lane's own
@@ -579,6 +580,11 @@ class ContinuousBatcher:
             # attn_len x steps). Their ratio is the share of the old read
             # still made
             "kv_positions_read": 0, "kv_positions_bucket": 0,
+            # the decode write: K and V rows a dispatched burst lands for
+            # its live lanes (lanes x steps x layers x 2), and how many of
+            # them the read's kernel lands itself (all where
+            # _ragged_read, none where the scatter writes them)
+            "kv_rows_written": 0, "kv_rows_written_in_kernel": 0,
             # disaggregated serving: slabs/bytes shipped out (prefill
             # role), slabs/bytes admitted in (decode role), and transfer
             # bytes the decode-side radix cache deduplicated away
@@ -781,7 +787,7 @@ class ContinuousBatcher:
         # arrays. A stacked [L, ...] cache threaded through the layer scan
         # as xs/ys makes XLA rewrite every layer's cache every step (cost
         # scales with total cache bytes); per-layer arrays carried through
-        # the burst scan update in place — only the one-position scatter
+        # the burst scan update in place — only the one-position write
         # touches HBM (see DecoderLM.decode_step_ragged_list).
         def cache_sharding_for(kv_heads: int):
             """Per-layer cache [S, KV, T, Dh]: KV heads over `model` (tp),
@@ -1032,8 +1038,9 @@ class ContinuousBatcher:
         def fused_masked_step(params, ks, vs, cur_tok, pos, alive, temps,
                               keys, attn_len, park):
             """One decode step under a per-lane ``alive`` mask: finished
-            lanes' K/V writes park OUT OF BOUNDS at ``park`` (dropped by
-            JAX scatter semantics — the lane's cache freezes) and their
+            lanes' K/V writes park OUT OF BOUNDS at ``park`` (dropped, by
+            JAX scatter semantics and by the decode kernel alike — the
+            lane's cache freezes) and their
             token/position carry unchanged, so a lane that hit its stop
             keeps its stop token in ``cur_tok`` for the next burst's
             done0 check. For alive lanes the matmuls, mask bound, key
@@ -5709,6 +5716,10 @@ class ContinuousBatcher:
                         self.stats["kv_positions_bucket"] += (
                             k * self.slots * attn_len
                         )
+                        rows = len(lanes) * k * 2 * len(self._cache["k"])
+                        self.stats["kv_rows_written"] += rows
+                        if self._ragged_read:
+                            self.stats["kv_rows_written_in_kernel"] += rows
                         if use_fused:
                             self.stats["fused_dispatches"] += 1
                             self.stats["fused_steps"] += k

@@ -1,8 +1,10 @@
 """The decode burst's compiled program holds no cache-shaped copy or slice
-(ISSUE 26) and reads the cache through one ragged kernel call per layer,
-with no array of the bucket's shape left (ISSUE 30), checked by
-``tools/burst_hlo_check.py``: its reading of an HLO text on recorded
-snippets, and the burst itself compiled here, without a chip, for a
+(ISSUE 26), reads the cache through one ragged kernel call per layer,
+with no array of the bucket's shape left (ISSUE 30), and writes it from
+inside that call, with no scatter into an array of the cache's shape
+(ISSUE 32), checked by ``tools/burst_hlo_check.py``: its reading of an
+HLO text on recorded snippets, and the burst itself compiled here,
+without a chip, for a
 described v5e at the benchmark configurations' widths (two layers: the
 copies and the kernel calls are per layer, so two show what twenty-four
 would; and once at the configurations' own depth for the scratch), with a
@@ -104,6 +106,46 @@ def test_reader_counts_kernel_calls_and_bucket_shaped_arrays():
     assert tool.kernel_calls(with_kernel) == {"inside": 1, "outside": 1}
 
 
+def test_reader_counts_scatters_into_the_cache():
+    """The write before ISSUE 32, as the parent's burst compiled it: a
+    scatter of ``Dh`` rows, the root of a fusion of its own, one for K and
+    one for V a layer. The reader finds it inside the fusion; a scatter
+    into another shape, or the word in a name or in metadata, is none."""
+    tool = _tool()
+    assert tool.cache_scatters(HLO, 28, 8, 2048, 128) == 0
+    fused = """
+%fused_computation.9 (param_0.6289: bf16[28,8,2048,128], param_1: s32[224,3], param_2: bf16[224,128]) -> bf16[28,8,2048,128] {
+  %param_0.6289 = bf16[28,8,2048,128]{3,2,1,0:T(8,128)(2,1)} parameter(0)
+  %scatter.3 = bf16[]{:T(256)} parameter(1), metadata={op_name="scatter"}
+  ROOT %scatter.625 = bf16[28,8,2048,128]{3,2,1,0:T(8,128)(2,1)} scatter(%param_0.6289, %custom-call.150, %transpose.1120), update_window_dims={2}, inserted_window_dims={0,1,2}, scatter_dims_to_operand_dims={0,1,2}, index_vector_dim=2, to_apply=%region_2.3, metadata={op_name="jit(fused_burst)/while/body/closed_call/scatter"}
+}
+"""
+    other = fused.replace("bf16[28,8,2048,128]", "bf16[28,92544]")
+    assert tool.cache_scatters(HLO + fused, 28, 8, 2048, 128) == 1
+    assert tool.cache_scatters(HLO + fused + fused, 28, 8, 2048, 128) == 2
+    assert tool.cache_scatters(HLO + other, 28, 8, 2048, 128) == 0
+    # fewer lanes than the scatter's operand has: another array
+    assert tool.cache_scatters(HLO + fused, 4, 8, 2048, 128) == 0
+
+
+def test_reader_counts_weight_slices_prefetched_from_an_hbm_temporary():
+    """The burst before the layers' slices were tied to their input: a
+    layer of the stacked ``wq``, cut into an HBM temporary by one op and
+    brought to VMEM by a ``copy-start`` inside the ``while``. A copy of
+    the whole stack, or one outside the loop, is not a layer's slice."""
+    tool = _tool()
+    assert tool.weight_slices_through_hbm(HLO) == 0
+    prefetch = (
+        "  %copy-start.3 = (bf16[1,2048,2048]{1,2,0:T(8,128)(2,1)S(1)},"
+        " bf16[1,2048,2048]{1,2,0:T(8,128)(2,1)}, u32[]{:S(2)})"
+        " copy-start(%get-tuple-element.9)\n")
+    stack = prefetch.replace("bf16[1,", "bf16[24,")
+    inside = HLO.replace("  %slice.4 =", prefetch + stack + "  %slice.4 =")
+    assert tool.weight_slices_through_hbm(inside) == 1
+    outside = HLO.replace("  %copy.2 =", prefetch + "  %copy.2 =")
+    assert tool.weight_slices_through_hbm(outside) == 0
+
+
 def test_reader_counts_the_aliases():
     assert _tool().alias_count(HLO) == 2
     assert _tool().alias_count("HloModule m, is_scheduled=true\n") == 0
@@ -160,6 +202,9 @@ def test_burst_compiled_for_v5e_keeps_the_cache_in_place(
     # for the v5e, and it holds the kernel, once a layer, not the dots
     assert out["kernel_calls"] == {"inside": 2, "outside": 0}
     assert out["bucket_shaped_arrays"] == 0
+    # the write: the kernel lands the step's rows, no scatter does
+    assert out["cache_shaped_scatters"] == 0
+    assert out["weight_slices_through_hbm"] == 0
     assert out["ok"]
 
 
@@ -179,6 +224,8 @@ def test_burst_at_full_depth_takes_no_more_scratch_than_before(
         cfg, attn_len, one_chip, temp_limit=tool.TEMP_BEFORE[config])
     layers = cfg["num_hidden_layers"]
     assert out["kernel_calls"] == {"inside": layers, "outside": 0}
+    assert out["cache_shaped_scatters"] == 0
+    assert out["weight_slices_through_hbm"] == 0
     assert out["input_output_aliases"] >= out["cache_leaves"] == 2 * layers
     assert out["temp_size_in_bytes"] <= out["temp_size_before"]
     assert out["ok"], out

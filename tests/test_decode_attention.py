@@ -1,8 +1,10 @@
 """Ragged decode-attention kernel: interpret-mode equivalence on the CPU.
 
 The kernel's math is checked against ``DecoderLM._cache_attention`` (the
-two dots it replaces on a TPU) at small shapes, and the dispatching entry
-is checked to take the dots here and the kernel when lowered for a TPU.
+two dots it replaces on a TPU) at small shapes, its write against
+``cache_write`` (the scatter it replaces there) bit for bit, and the
+dispatching entry is checked to take the scatter and the dots here and
+the kernel when lowered for a TPU.
 The Mosaic compile at the benchmark's widths is ``tests/test_burst_hlo.py``
 (the one file that loads the TPU compiler); speed is the chip's to say.
 """
@@ -15,6 +17,7 @@ import pytest
 from seldon_core_tpu.models.llm import DecoderLM
 from seldon_core_tpu.ops.decode_attention import (
     BLOCK,
+    cache_write,
     decode_attention,
     ragged_decode_attention,
     reads_ragged,
@@ -34,6 +37,26 @@ def _inputs(rep, dtype, t=T, lanes=len(LENS), kv=2, dh=128, seed=0):
     return q, k, v
 
 
+def _rows(k, seed=9):
+    """This step's K and V rows for a cache like ``k``: [B, KV, 1, Dh]."""
+    ks = jax.random.split(jax.random.PRNGKey(seed), 2)
+    shape = k.shape[:2] + (1, k.shape[3])
+    return (jax.random.normal(ks[0], shape, k.dtype),
+            jax.random.normal(ks[1], shape, k.dtype))
+
+
+def _read(q, k, v, lens, **kw):
+    """The kernel as PR 30 had it, a read and nothing else: every lane's
+    write is parked at T, where it is dropped."""
+    k_new, v_new = _rows(k)
+    parked = jnp.full((q.shape[0],), k.shape[2], jnp.int32)
+    return ragged_decode_attention(q, k, v, lens, k_new, v_new, parked, **kw)[0]
+
+
+def _f32(a):
+    return np.asarray(a, np.float32)
+
+
 def _dots(q, k, v, lens):
     """What the burst computed before the kernel: the two dots over the
     whole cache under the ``key_pos <= len - 1`` mask; an idle lane's row
@@ -48,59 +71,148 @@ def _dots(q, k, v, lens):
 # to bfloat16 before the second dot, the kernel rounds the unnormalised
 # ones and divides after, and both round the output: two bfloat16 ulps of
 # an output below 2, 2 x 2**-7.
+def _assert_step_is_the_scatter_then_the_read(
+        q, k, v, lens, wp, tol, block, seed=9):
+    """The fused kernel against the two things it replaces: the output is
+    bit for bit the read-only kernel's over the cache ``cache_write`` made
+    (and the dots' over it to ``tol``); the caches are that cache's where
+    ``lens > 0`` and untouched where ``lens == 0``."""
+    k_new, v_new = _rows(k, seed)
+    got, gk, gv = ragged_decode_attention(
+        q, k, v, lens, k_new, v_new, wp, block=block, interpret=True)
+    sk = cache_write(k, k_new, wp[:, None])
+    sv = cache_write(v, v_new, wp[:, None])
+    assert np.array_equal(
+        _f32(got), _f32(_read(q, sk, sv, lens, block=block, interpret=True)))
+    err = jnp.abs(got.astype(jnp.float32)
+                  - _dots(q, sk, sv, lens).astype(jnp.float32))
+    assert float(err.max()) <= tol, err.max(axis=(1, 2, 3))
+    live = np.asarray(lens) > 0
+    for mine, scattered, before in ((gk, sk, k), (gv, sv, v)):
+        assert np.array_equal(_f32(mine)[live], _f32(scattered)[live])
+        assert np.array_equal(_f32(mine)[~live], _f32(before)[~live])
+    return got, gk, gv
+
+
+# Tolerance. float32: both sides accumulate in float32 and differ only in
+# the order of the softmax's sums (online, per block), a few ulps: 1e-5.
+# bfloat16 (the served precision): the dots round the normalised weights
+# to bfloat16 before the second dot, the kernel rounds the unnormalised
+# ones and divides after, and both round the output: two bfloat16 ulps of
+# an output below 2, 2 x 2**-7.
+@pytest.mark.parametrize("write", ["parked", "step"])
 @pytest.mark.parametrize("rep", [1, 2, 4])
 @pytest.mark.parametrize("dtype,tol", [("float32", 1e-5), ("bfloat16", 2 ** -6)])
-def test_kernel_matches_the_dots(rep, dtype, tol):
+def test_kernel_matches_the_dots(rep, dtype, tol, write):
+    """``parked``: the read alone. ``step``: every lane writes where its
+    read ends, as a decode step does: ``LENS`` puts that on position 0,
+    a block's last row and the next one's first, and on T - 1, beside an
+    idle lane that neither reads nor writes."""
     q, k, v = _inputs(rep, jnp.dtype(dtype))
     lens = jnp.asarray(LENS, jnp.int32)
-    got = ragged_decode_attention(q, k, v, lens, block=BLK, interpret=True)
-    ref = _dots(q, k, v, lens)
+    if write == "step":
+        got, _, _ = _assert_step_is_the_scatter_then_the_read(
+            q, k, v, lens, lens - 1, tol, BLK)
+    else:
+        got = _read(q, k, v, lens, block=BLK, interpret=True)
+        ref = _dots(q, k, v, lens)
+        err = jnp.abs(got.astype(jnp.float32) - ref.astype(jnp.float32))
+        assert float(err.max()) <= tol, err.max(axis=(1, 2, 3))
     assert got.shape == q.shape and got.dtype == q.dtype
-    err = jnp.abs(got.astype(jnp.float32) - ref.astype(jnp.float32))
-    assert float(err.max()) <= tol, err.max(axis=(1, 2, 3))
     # a lane of length 0 reads nothing and gives zeros, exactly
     assert not np.asarray(got[0], np.float32).any()
 
 
+@pytest.mark.parametrize("write", ["parked", "step"])
 @pytest.mark.parametrize("block", [128, 256])
-def test_kernel_block_sizes_agree(block):
+def test_kernel_block_sizes_agree(block, write):
     q, k, v = _inputs(2, jnp.bfloat16, t=512)
     lens = jnp.asarray([0, 1, 255, 256, 257, 512], jnp.int32)
-    got = ragged_decode_attention(q, k, v, lens, block=block, interpret=True)
+    if write == "step":
+        _assert_step_is_the_scatter_then_the_read(
+            q, k, v, lens, lens - 1, 2 ** -6, block)
+        return
+    got = _read(q, k, v, lens, block=block, interpret=True)
     err = jnp.abs(got.astype(jnp.float32)
                   - _dots(q, k, v, lens).astype(jnp.float32))
     assert float(err.max()) <= 2 ** -6
 
 
+@pytest.mark.parametrize("rep", [2, 4])
+@pytest.mark.parametrize("why,lens,wp", [
+    ("a block's first row", 2 * BLK + 50, 2 * BLK),
+    ("a block's last row", 2 * BLK, 2 * BLK - 1),
+    ("an odd row inside a group of 8", BLK + 44, BLK + 43),
+    ("the cache's last position", T, T - 1),
+    ("position 0, read alone", 1, 0),
+    ("parked at T: dropped", BLK + 9, T),
+    ("parked past T: dropped", BLK + 9, T + 77),
+    ("parked below 0: dropped", BLK + 9, -1),
+    ("an idle lane at a live position: nothing written", 0, 70),
+    ("a block the read has behind it", 2 * BLK + 5, 3),
+    ("a block past what the lane reads", BLK + 9, 2 * BLK + 5),
+    ("the cache's last position, past the read", 5, T - 1),
+])
+def test_kernel_lands_the_row_where_the_scatter_would(why, lens, wp, rep):
+    """One lane's write position at a time, between two lanes that step
+    as usual: what the scatter drops is dropped and the cache untouched,
+    what it lands is landed, bit for bit, and the softmax sees the row."""
+    q, k, v = _inputs(rep, jnp.bfloat16, lanes=3, seed=len(why))
+    lens = jnp.asarray([BLK + 1, lens, 300], jnp.int32)
+    wp = jnp.asarray([BLK, wp, 299], jnp.int32)
+    _, gk, gv = _assert_step_is_the_scatter_then_the_read(
+        q, k, v, lens, wp, 2 ** -6, BLK)
+    landed = 0 <= int(wp[1]) < T and int(lens[1]) > 0
+    for mine, before in ((gk, k), (gv, v)):
+        changed = (_f32(mine)[1] != _f32(before)[1]).any(-1)  # [KV, T]
+        assert changed.sum() == (changed.shape[0] if landed else 0), why
+        if landed:
+            assert changed[:, int(wp[1])].all(), why
+
+
 @pytest.mark.parametrize("rep", [1, 2, 4])
 def test_a_lane_depends_on_its_own_keys_alone(rep):
-    """Lane 2's output is bit-equal whatever the other lanes' lengths,
-    whichever lanes are idle, and however long the cache (the bucket) is
-    beyond the lane's own length."""
+    """Lane 2's output, and what its write leaves in its cache, are
+    bit-equal whatever the other lanes' lengths, whichever lanes are idle
+    or parked, and however long the cache (the bucket) is beyond the
+    lane's own length."""
     q, k, v = _inputs(rep, jnp.bfloat16)
+    k_new, v_new = _rows(k)
     mine = BLK + 37
+
+    def lane_2(k, v, lens, wp):
+        o, gk, gv = ragged_decode_attention(
+            q, k, v, jnp.asarray(lens, jnp.int32), k_new, v_new,
+            jnp.asarray(wp, jnp.int32), block=BLK, interpret=True)
+        return (_f32(o[2]), _f32(gk[2, :, :2 * BLK]), _f32(gv[2, :, :2 * BLK]))
+
     runs = []
     for others in ((0, 0, 0, 0, 0), (T, 1, BLK, 5, 0), (3, T, T, T, T)):
         lens = list(others)
         lens.insert(2, mine)
-        runs.append(ragged_decode_attention(
-            q, k, v, jnp.asarray(lens, jnp.int32), block=BLK, interpret=True)[2])
+        # the others step (idle ones write nothing), or are parked
+        runs.append(lane_2(k, v, lens, [n - 1 for n in lens]))
+        runs.append(lane_2(
+            k, v, lens, [mine - 1 if i == 2 else T for i in range(6)]))
     # a shorter cache holding the same keys: the bound moved, the lane not
-    runs.append(ragged_decode_attention(
-        q, k[:, :, :2 * BLK], v[:, :, :2 * BLK],
-        jnp.asarray([9, 9, mine, 9, 9, 9], jnp.int32),
-        block=BLK, interpret=True)[2])
+    runs.append(lane_2(
+        k[:, :, :2 * BLK], v[:, :, :2 * BLK], [9, 9, mine, 9, 9, 9],
+        [8, 8, mine - 1, 8, 8, 8]))
     for other in runs[1:]:
-        assert np.array_equal(np.asarray(runs[0], np.float32),
-                              np.asarray(other, np.float32))
+        for a, b in zip(runs[0], other):
+            assert np.array_equal(a, b)
+    # and the output depends on the row it wrote: parked, it reads the
+    # stale one
+    stale = lane_2(k, v, [0, 0, mine, 0, 0, 0], [T] * 6)
+    assert not np.array_equal(runs[0][0], stale[0])
 
 
 def test_lengths_are_clamped_to_the_cache():
     q, k, v = _inputs(2, jnp.bfloat16)
-    over = ragged_decode_attention(
+    over = _read(
         q, k, v, jnp.asarray([-3, T + 500, T, 0, 1, 2], jnp.int32),
         block=BLK, interpret=True)
-    ref = ragged_decode_attention(
+    ref = _read(
         q, k, v, jnp.asarray([0, T, T, 0, 1, 2], jnp.int32),
         block=BLK, interpret=True)
     assert np.array_equal(np.asarray(over, np.float32),
@@ -110,46 +222,63 @@ def test_lengths_are_clamped_to_the_cache():
 def test_kernel_rejects_shapes_it_cannot_tile():
     q, k, v = _inputs(2, jnp.bfloat16, t=BLK + 16)
     with pytest.raises(ValueError, match="do not fit"):
-        ragged_decode_attention(q, k, v, jnp.zeros((len(LENS),), jnp.int32),
-                                block=BLK)
+        _read(q, k, v, jnp.zeros((len(LENS),), jnp.int32), block=BLK)
 
 
+@pytest.mark.parametrize("park", [False, True], ids=["step", "one_parked"])
 @pytest.mark.parametrize("attn_len", [None, 2 * BLOCK, 10 * BLOCK])
-def test_entry_takes_the_dots_off_tpu(attn_len):
-    """On this backend the entry is the parent's read, bit for bit: the
-    dots over the bucket's slice under ``key_pos <= pos``, idle lanes
-    (``lens == 0``) computed from their stale position like the rest."""
+def test_entry_takes_the_dots_off_tpu(attn_len, park):
+    """On this backend the entry is the parent's write and read, byte for
+    byte: ``_cache_write``'s scatter of every lane's row (idle lanes'
+    too; a parked one dropped), then the dots over the bucket's slice
+    under ``key_pos <= pos``, idle lanes (``lens == 0``) computed from
+    their stale position like the rest."""
     t = 3 * BLOCK
     q, k, v = _inputs(2, jnp.bfloat16, t=t)
+    k_new, v_new = _rows(k)
     bound = t if attn_len is None else min(attn_len, t)
     pos = jnp.asarray([7, 0, BLOCK - 1, BLOCK, bound - 2, bound - 1], jnp.int32)
     lens = jnp.where(jnp.arange(len(LENS)) == 0, 0, pos + 1)
-    got = decode_attention(q, k, v, pos, lens, attn_len=attn_len)
+    wp = pos.at[2].set(t) if park else pos
+    got, gk, gv = decode_attention(
+        q, k, v, k_new, v_new, wp, pos, lens, attn_len=attn_len)
+    sk = DecoderLM._cache_write(k, k_new, wp[:, None])
+    sv = DecoderLM._cache_write(v, v_new, wp[:, None])
     ref = DecoderLM._cache_attention(
-        q, k[:, :, :bound], v[:, :, :bound], pos, q.dtype)
-    assert np.array_equal(np.asarray(got, np.float32),
-                          np.asarray(ref, np.float32))
+        q, sk[:, :, :bound], sv[:, :, :bound], pos, q.dtype)
+    for a, b in ((got, ref), (gk, sk), (gv, sv)):
+        assert np.array_equal(_f32(a), _f32(b))
+    # the idle lane's row is written here, the parked one's is not
+    assert not np.array_equal(_f32(gk[0]), _f32(k[0]))
+    assert np.array_equal(_f32(gk[2]), _f32(k[2])) is park
     lowered = jax.jit(
         lambda *a: decode_attention(*a, attn_len=attn_len)
-    ).lower(q, k, v, pos, lens).as_text()
+    ).lower(q, k, v, k_new, v_new, wp, pos, lens).as_text()
     assert "tpu_custom_call" not in lowered
+    assert lowered.count('"stablehlo.scatter"(') == 2
 
 
 def _entry_args(**kwargs):
+    """``decode_attention``'s arguments for a step: every lane writes
+    where its read ends (an idle lane at 0, which the kernel skips)."""
     q, k, v = _inputs(2, jnp.bfloat16, **kwargs)
     lens = jnp.asarray(LENS, jnp.int32)
-    return q, k, v, jnp.maximum(lens - 1, 0), lens
+    pos = jnp.maximum(lens - 1, 0)
+    return (q, k, v, *_rows(k), pos, pos, lens)
 
 
 def test_entry_picks_the_kernel_by_the_platform_it_is_lowered_for():
     """``jax.default_backend()`` is the CPU here; lowered for a TPU the
-    same call holds the Mosaic kernel, and no dot over the cache."""
+    same call holds the Mosaic kernel with both caches aliased through
+    it, and no dot over the cache nor scatter into it."""
     args = _entry_args(t=2 * BLOCK)
     fn = jax.jit(lambda *a: decode_attention(*a, attn_len=BLOCK))
     assert jax.default_backend() != "tpu"
     mlir = jax.export.export(fn, platforms=["tpu"])(*args).mlir_module()
     assert mlir.count("tpu_custom_call") == 1
     assert "dot_general" not in mlir
+    assert "scatter" not in mlir
+    assert mlir.count("#stablehlo.output_operand_alias<") == 2
 
 
 @pytest.mark.parametrize("why,kwargs", [
@@ -165,6 +294,7 @@ def test_entry_keeps_the_dots_where_the_kernel_does_not_tile(why, kwargs):
     mlir = jax.export.export(
         jax.jit(decode_attention), platforms=["tpu"])(q, *rest).mlir_module()
     assert "tpu_custom_call" not in mlir, why
+    assert mlir.count('"stablehlo.scatter"(') == 2, why
 
 
 _BF16 = jnp.dtype("bfloat16")
@@ -213,15 +343,22 @@ def on_a_tpu(monkeypatch):
 
 
 def test_entry_takes_its_choice_from_the_rule(on_a_tpu, monkeypatch):
-    """Heads that tile, lowered for a TPU: the kernel. The same call once
-    the rule says no: the dots, bit for bit, and the rule was asked with
-    what the entry can see."""
+    """Heads that tile, lowered for a TPU: the kernel, which leaves an
+    idle lane's cache alone. The same call once the rule says no: the
+    scatter and the dots, bit for bit, and the rule was asked with what
+    the entry can see."""
     mod, entry = on_a_tpu
-    q, k, v, pos, lens = _entry_args(t=2 * BLOCK)
-    dots = DecoderLM._cache_attention(q, k, v, pos, q.dtype)
-    kernel = entry(q, k, v, pos, lens)
+    args = _entry_args(t=2 * BLOCK)
+    q, k, v, k_new, v_new, wp, pos, lens = args
+    sk, sv = (DecoderLM._cache_write(c, n, wp[:, None])
+              for c, n in ((k, k_new), (v, v_new)))
+    dots = DecoderLM._cache_attention(q, sk, sv, pos, q.dtype)
+    kernel, kk, kv = entry(*args)
     assert not np.asarray(kernel[0], np.float32).any()  # lens[0] == 0
     assert np.asarray(dots[0], np.float32).any()
+    assert np.array_equal(_f32(kk[0]), _f32(k[0]))
+    assert np.array_equal(_f32(kk[1:]), _f32(sk[1:]))
+    assert np.array_equal(_f32(kv[1:]), _f32(sv[1:]))
     asked = []
 
     def no(*args):
@@ -229,9 +366,9 @@ def test_entry_takes_its_choice_from_the_rule(on_a_tpu, monkeypatch):
         return False
 
     monkeypatch.setattr(mod, "reads_ragged", no)
-    got = entry(q, k, v, pos, lens, mesh=None)
-    assert np.array_equal(np.asarray(got, np.float32),
-                          np.asarray(dots, np.float32))
+    got = entry(*args, mesh=None)
+    for a, b in zip(got, (dots, sk, sv)):
+        assert np.array_equal(_f32(a), _f32(b))
     assert asked == [("tpu", q.shape, k.shape, (q.dtype,) * 3, None)]
 
 
@@ -243,18 +380,21 @@ def test_entry_takes_its_choice_from_the_rule(on_a_tpu, monkeypatch):
 def test_kernel_without_a_bucket_is_the_bucketed_one_bit_for_bit(on_a_tpu, lens):
     """What the batcher relies on where the read is ragged: the bucket is
     a clamp on lengths that never exceed it, so ``attn_len=None`` (the
-    clamp is the cache's length) gives the bucket's outputs exactly, idle
-    lanes and lanes at the bucket itself included."""
+    clamp is the cache's length) gives the bucket's outputs and caches
+    exactly, idle lanes and lanes at the bucket itself included."""
     _, entry = on_a_tpu
     q, k, v = _inputs(2, jnp.bfloat16, t=3 * BLOCK, seed=3)
     lens = jnp.asarray(lens, jnp.int32)
     pos = jnp.maximum(lens - 1, 0)
-    bucketed = entry(q, k, v, pos, lens, attn_len=2 * BLOCK)
-    free = entry(q, k, v, pos, lens, attn_len=None)
-    assert np.array_equal(np.asarray(free, np.float32),
-                          np.asarray(bucketed, np.float32))
-    ref = _dots(q, k, v, lens)
-    err = jnp.abs(free.astype(jnp.float32) - ref.astype(jnp.float32))
+    args = (q, k, v, *_rows(k), pos, pos, lens)
+    bucketed = entry(*args, attn_len=2 * BLOCK)
+    free = entry(*args, attn_len=None)
+    for a, b in zip(free, bucketed):
+        assert np.array_equal(_f32(a), _f32(b))
+    sk, sv = (DecoderLM._cache_write(c, n, pos[:, None])
+              for c, n in zip((k, v), _rows(k)))
+    ref = _dots(q, sk, sv, lens)
+    err = jnp.abs(free[0].astype(jnp.float32) - ref.astype(jnp.float32))
     assert float(err.max()) <= 2 ** -6
 
 
@@ -301,28 +441,37 @@ def test_model_step_through_the_kernel(monkeypatch):
     the entry gives it on a TPU: the live lanes' logits agree with the
     dots' step to bfloat16 rounding through two layers, and are
     byte-equal whether the idle lanes read their stale positions or
-    nothing."""
+    nothing. The first layer's cache (the same rows on both paths) is the
+    scatter's bit for bit on the live lanes, and an idle lane's is left
+    as it was."""
     import seldon_core_tpu.ops as ops
 
-    def through_kernel(q, k, v, pos, lens, attn_len=None, mesh=None):
+    def through_kernel(q, k, v, k_new, v_new, write_pos, pos, lens,
+                       attn_len=None, mesh=None):
         assert attn_len == 384 and mesh is None
         return ragged_decode_attention(
-            q, k, v, jnp.minimum(lens, attn_len), block=BLOCK, interpret=True)
+            q, k, v, jnp.minimum(lens, attn_len), k_new, v_new, write_pos,
+            block=BLOCK, interpret=True)
 
     model, params = _tiny_model()
     cache_k, cache_v, tokens, pos, active = _step_inputs(model)
     live = np.asarray(active)
-    dots, _, _ = model.decode_step_ragged_list(
+    dots, dk, dv = model.decode_step_ragged_list(
         params, cache_k, cache_v, tokens, pos, attn_len=384)
     monkeypatch.setattr(ops, "decode_attention", through_kernel)
     every, ek, _ = model.decode_step_ragged_list(
         params, cache_k, cache_v, tokens, pos, attn_len=384)
-    some, sk, _ = model.decode_step_ragged_list(
+    some, sk, sv = model.decode_step_ragged_list(
         params, cache_k, cache_v, tokens, pos, attn_len=384,
         lens=jnp.where(active, pos + 1, 0))
     assert np.array_equal(np.asarray(every)[live], np.asarray(some)[live])
     assert np.array_equal(np.asarray(ek[-1], np.float32)[live],
                           np.asarray(sk[-1], np.float32)[live])
+    for mine, scattered, before in ((sk, dk, cache_k), (sv, dv, cache_v)):
+        assert np.array_equal(_f32(mine[0])[live], _f32(scattered[0])[live])
+        assert not np.array_equal(_f32(mine[0])[live], _f32(before[0])[live])
+        for layer, was in zip(mine, before):
+            assert np.array_equal(_f32(layer)[~live], _f32(was)[~live])
     spread = float(jnp.std(dots))
     assert float(jnp.abs(dots - some)[live].max()) < 0.05 * spread
 
@@ -343,7 +492,9 @@ def test_positions_streamed_rounds_each_step_to_the_block(pos, k, bucket, want):
 def test_batcher_counts_what_the_read_streams_and_what_the_bucket_held():
     """``kv_positions_read`` / ``kv_positions_bucket`` in ``stats`` (and so
     in a capture's counters): per dispatched burst, what the ragged read
-    streams for the lanes active against rows x attn_len x steps."""
+    streams for the lanes active against rows x attn_len x steps; and
+    ``kv_rows_written`` / ``kv_rows_written_in_kernel``: the rows the
+    burst's live lanes land, and how many of them the kernel lands."""
     from seldon_core_tpu.serving.continuous import (
         ContinuousBatcher,
         _positions_streamed,
@@ -370,4 +521,11 @@ def test_batcher_counts_what_the_read_streams_and_what_the_bucket_held():
     assert stats["kv_positions_read"] == len(groups) * _positions_streamed(5, 2, 128, BLOCK)
     assert stats["kv_positions_read"] == 2 * BLOCK * len(groups)
     assert stats["kv_positions_read"] * 4 <= stats["kv_positions_bucket"]
-    assert "kv_positions_read" in b.capture_counters()["counters"]
+    counters = b.capture_counters()["counters"]
+    assert "kv_positions_read" in counters
+    # the write: one lane x 2 steps x 2 layers x (K, V) a burst, by the
+    # scatter on this CPU, so none of them from inside the kernel
+    assert stats["kv_rows_written"] == len(groups) * 1 * 2 * 2 * 2
+    assert stats["kv_rows_written_in_kernel"] == 0
+    assert counters["kv_rows_written"] == stats["kv_rows_written"]
+    assert counters["kv_rows_written_in_kernel"] == 0

@@ -1272,9 +1272,12 @@ def test_ragged_read_warms_one_burst_per_k_and_serves_the_same(
     assert [t["attn_len"] for t in trace_r] == buckets
     for t in trace_r:
         assert t["attn_len"] == max(t["need"].values())
-    for key in ("kv_positions_bucket", "kv_positions_read",
+    for key in ("kv_positions_bucket", "kv_positions_read", "kv_rows_written",
                 "burst_read_bytes", "burst_reads", "steps"):
         assert stats_r[key] == stats_b[key], key
+    # whose write it is follows the same rule as whose read
+    assert stats_r["kv_rows_written_in_kernel"] == stats_r["kv_rows_written"] > 0
+    assert stats_b["kv_rows_written_in_kernel"] == 0
     assert out_r == out_b
 
 

@@ -19,7 +19,17 @@ reads the optimised HLO:
   call per layer (``ops.decode_attention``, chosen by the platform the
   burst is lowered for), and nothing anywhere in the module, inside a
   fusion or out, has the bucket's shape ``[n <= lanes, KV, attn_len, Dh]``:
-  the two dots that read every lane's whole bucket are gone.
+  the two dots that read every lane's whole bucket are gone;
+* the decode write (ISSUE 32): a ``scatter`` anywhere in the module, inside
+  a fusion or out, whose operand (and so result) is ``[n <= lanes, KV, T,
+  Dh]`` is a step's rows going into the cache by an op of their own: FAIL.
+  The kernel call above lands them, the cache aliased in and out of it;
+* the weights (ISSUE 32, ROADMAP S12): a ``copy-start`` inside the ``while``
+  whose result is one layer of a stacked weight, ``[1, a, b]``, is that
+  layer's slice written to an HBM temporary and prefetched from there,
+  read twice and written once where one read would do: FAIL. A layer's
+  slices are tied to its input (``decode_step_ragged_list``) and go
+  straight into VMEM.
 
 The burst is checked with ``attn_len=None`` (what the chip runs since
 ISSUE 31: where the read takes each lane's length the executable has no
@@ -135,6 +145,32 @@ def kernel_calls(hlo: str) -> dict:
     return out
 
 
+def cache_scatters(hlo: str, lanes: int, kv: int, T: int, dh: int) -> int:
+    """``scatter`` instructions into an array of the cache's shape ``[n <=
+    lanes, KV, T, Dh]``, anywhere in the module: XLA wraps the write's
+    scatter in a fusion of its own, so the fusions' insides are read too."""
+    return sum(
+        int(n) <= lanes
+        for n in re.findall(
+            r"= bf16\[(\d+),%d,%d,%d\]\S* scatter\(" % (kv, T, dh), hlo)
+    )
+
+
+def weight_slices_through_hbm(hlo: str) -> int:
+    """``copy-start`` instructions inside the ``while`` whose result is one
+    layer of a stacked weight, ``[1, a, b]``: the prefetch into VMEM of a
+    slice that an earlier op wrote to an HBM temporary."""
+    comps = computations(hlo)
+    n = 0
+    for name, inside in scheduled(comps).items():
+        for line in comps[name] if inside else ():
+            m = _INSTR_RE.match(line)
+            if m and m.group(3) == "copy-start" \
+                    and re.match(r"^\(\w+\[1,\d+,\d+\]", m.group(2)):
+                n += 1
+    return n
+
+
 def bucket_shaped(hlo: str, lanes: int, kv: int, attn_len: int, dh: int) -> int:
     """Arrays of the bucket's shape ``[n <= lanes, KV, attn_len, Dh]``
     anywhere in the module, a fusion's inside included: the slice the
@@ -247,6 +283,8 @@ def check(cfg: dict, attn_len, device_sharding, hlo_dir=None,
     aliases = alias_count(hlo)
     kernels = kernel_calls(hlo)
     bucket = bucket_shaped(hlo, lanes, kv, attn_len, dh) if bounded else 0
+    scatters = cache_scatters(hlo, lanes, kv, T, dh)
+    two_hop = weight_slices_through_hbm(hlo)
     return {
         "lanes": lanes, "attn_len": attn_len,
         "cache_shaped_copies_and_slices": kinds,
@@ -259,7 +297,10 @@ def check(cfg: dict, attn_len, device_sharding, hlo_dir=None,
         "cache_leaves": leaves,
         "kernel_calls": kernels,
         "bucket_shaped_arrays": bucket,
-        "ok": not found and aliases >= leaves
+        "cache_shaped_scatters": scatters,
+        "weight_slices_through_hbm": two_hop,
+        "ok": not found and not scatters and not two_hop
+        and aliases >= leaves
         and mem.alias_size_in_bytes >= cache_bytes
         and kernels == {"inside": layers, "outside": 0} and not bucket
         and (temp_limit is None or mem.temp_size_in_bytes <= temp_limit),
