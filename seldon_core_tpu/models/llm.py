@@ -52,10 +52,32 @@ class LLMConfig:
     # depth redundancy) that a plain random init lacks. Bench/synthetic
     # checkpoints only; converted checkpoints never touch it.
     residual_scale: float = 1.0
+    # width of one head; 0 = d_model // n_heads (the llama families).
+    # Stated where heads x head_dim is not the hidden size
+    head_dim: int = 0
+    # -- the block variant, and what only some variants state ------------
+    # "llama": the block above, every layer alike. "afmoe": layers of
+    # mixed kinds (models/afmoe.py: window or full attention per
+    # ``layer_types``, dense or routed FFN by ``n_dense_layers``, four
+    # norms a layer, normed and gated heads, a scaled embedding). The
+    # kinds are resolved when the model is built, never in a traced
+    # function; DecoderLM(block="afmoe", ...) builds that class.
+    block: str = "llama"
+    # per layer "sliding_attention" | "full_attention"; None = all full
+    layer_types: Optional[Tuple[str, ...]] = None
+    sliding_window: int = 0
+    n_dense_layers: int = 0       # leading layers with a dense FFN (d_ff)
+    n_routed_experts: int = 0     # drop-free top-k experts a later layer
+    experts_per_tok: int = 0
+    expert_width: int = 0         # FFN width of one expert
+    n_shared_experts: int = 0     # experts every token takes, beside them
+    route_scale: float = 1.0
 
-    @property
-    def head_dim(self) -> int:
-        return self.d_model // self.n_heads
+    def __post_init__(self):
+        if not self.head_dim:
+            self.head_dim = self.d_model // self.n_heads
+        if self.layer_types is not None:
+            self.layer_types = tuple(self.layer_types)
 
 
 def _rms_norm(x, w, eps=1e-5):
@@ -85,7 +107,40 @@ def _rope(x, positions, theta: float):
     ).astype(x.dtype)
 
 
+class UnsupportedByModel(ValueError):
+    """A serving feature was asked of a model family that has no path for
+    it (``DecoderLM.serving_refuses``): refused at load, not computed
+    as something else."""
+
+
 class DecoderLM(ServedModel):
+    # what ``decode_step_ragged_list`` returns after its caches, where a
+    # family counts what its step did: names of the int32 vector's
+    # entries, which the batcher adds into ``stats``. The llama block has
+    # none and returns no fourth result.
+    step_counter_names: Tuple[str, ...] = ()
+    # serving features this family has no path for -> why; the batcher
+    # refuses them typed at load (``UnsupportedByModel``)
+    serving_refuses: Dict[str, str] = {}
+
+    def check_serves(self, **asked: bool) -> None:
+        """Raise ``UnsupportedByModel`` for the first feature that is
+        asked for (``speculation=True``, ...) and that this family
+        refuses. The server and the batcher call it at load."""
+        for feature, why in self.serving_refuses.items():
+            if asked.get(feature):
+                raise UnsupportedByModel(
+                    f"{type(self).__name__} does not serve with {feature}: {why}")
+
+    def __new__(cls, **config):
+        if cls is DecoderLM and config.get("block", "llama") != "llama":
+            if config["block"] != "afmoe":
+                raise ValueError(f"unknown block variant {config['block']!r}")
+            from .afmoe import AfmoeLM
+
+            return super().__new__(AfmoeLM)
+        return super().__new__(cls)
+
     def __init__(self, **config):
         cfg_fields = {f.name for f in dataclasses.fields(LLMConfig)}
         extra = {k: v for k, v in config.items() if k not in cfg_fields}
@@ -93,6 +148,14 @@ class DecoderLM(ServedModel):
         self._extra = extra
         self.example_input_shape = (16,)  # token ids
         self.compute_dtype = self.cfg.dtype
+
+    def attention_kinds(self) -> Tuple[Tuple[int, Optional[int]], ...]:
+        """``(layers, window)`` per kind of attention layer: how many
+        layers read the cache that way and how many positions back a
+        query sees (None: all of them). The scheduler's arithmetic of
+        what a burst reads (``kv_positions_*``) takes the kinds from
+        here; every layer of the llama block reads everything."""
+        return ((self.cfg.n_layers, None),)
 
     def flops_per_token(self, context_len: int) -> float:
         """Matmul FLOPs to process ONE token attending over ``context_len``
@@ -153,6 +216,7 @@ class DecoderLM(ServedModel):
         kind: str,
         *,
         rows: int = 1,
+        live: int = None,
         k: int = 1,
         bucket: int = 0,
         tokens: int = 0,
@@ -167,7 +231,9 @@ class DecoderLM(ServedModel):
         ``param_bytes``/``kv_row_bytes`` default to the unsharded bf16
         closed forms; the batcher passes its live (shard-aware) values.
         Decode-family bursts read the params once per step plus each
-        row's bucketed KV columns; prefill-family dispatches read the
+        row's bucketed KV columns (``live``, how many of ``rows`` decode,
+        is for a family whose step reads by live lane: this one is priced
+        by its rows); prefill-family dispatches read the
         params once and write (not read) their KV, so params dominate;
         splice/extract move ``tokens`` cache positions; a swap cast
         touches every param byte once."""

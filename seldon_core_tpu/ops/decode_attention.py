@@ -95,9 +95,11 @@ def reads_ragged(platform, q_shape, cache_shape, dtypes, mesh=None) -> bool:
     )
 
 
-def cache_attention(q, kc, vc, bound, dt):
+def cache_attention(q, kc, vc, bound, dt, lo=None):
     """Attention over the (sliced) KV cache with a key_pos <= bound
-    mask, WITHOUT materialising a head-repeated cache copy.
+    mask, WITHOUT materialising a head-repeated cache copy. ``lo``
+    (optional, ``bound``'s shape): the first key position a row sees, for
+    a layer with a window; a row's band is then lo <= key_pos <= bound.
 
     ``jnp.repeat`` on the cache (the textbook GQA read) writes a
     rep-times-larger copy to HBM and reads it back — at 16 lanes /
@@ -120,6 +122,9 @@ def cache_attention(q, kc, vc, bound, dt):
         mask = key_pos[None, None, None, None, :] <= bound[:, None, None, :, None]
     else:  # [B]
         mask = key_pos[None, None, None, None, :] <= bound[:, None, None, None, None]
+    if lo is not None:
+        lo = lo[:, None, None, :, None] if lo.ndim == 2 else lo[:, None, None, None, None]
+        mask = mask & (key_pos[None, None, None, None, :] >= lo)
     qg = q.reshape(B, KVl, rep, T, Dh)
     s = lax.dot_general(
         qg, kc, (((4,), (3,)), ((0, 1), (0, 1))),
@@ -173,9 +178,15 @@ def cache_write(cache, new, positions):
     )
 
 
+def _windowed_kernel(starts_ref, *refs, block):
+    """``_ragged_kernel`` for a layer with a window: ``starts_ref`` (SMEM
+    [B]) is the first position each lane sees."""
+    _ragged_kernel(*refs, block=block, starts_ref=starts_ref)
+
+
 def _ragged_kernel(lens_ref, wpos_ref, q_ref, knew_ref, vnew_ref, _k_in, _v_in,
                    o_ref, k_hbm, v_hbm, kbuf, vbuf, kstage, vstage, sem, wsem,
-                   rsem, *, block):
+                   rsem, *, block, starts_ref=None):
     """The whole batch of one layer: for each lane with ``len > 0``, walk
     its ``ceil(len / block)`` blocks with an online softmax, and where a
     block holds the lane's ``write_pos`` put the new row into it first
@@ -256,11 +267,19 @@ def _ragged_kernel(lens_ref, wpos_ref, q_ref, knew_ref, vnew_ref, _k_in, _v_in,
             & (lens_ref[jnp.minimum(b, n_lanes - 1)] <= 0),
             lambda b: b + 1, lane + 1)
 
+    windowed = starts_ref is not None
+
+    def first_block(lane):
+        """The block a lane's walk begins at."""
+        if not windowed:
+            return 0
+        return starts_ref[jnp.minimum(lane, n_lanes - 1)] // block
+
     first = next_live(jnp.int32(-1))
 
     @pl.when(first < n_lanes)
     def _():
-        start(first, 0, 0)
+        start(first, first_block(first), 0)
 
     def lane_body(lane, carry):
         done_blocks, written = carry
@@ -268,12 +287,21 @@ def _ragged_kernel(lens_ref, wpos_ref, q_ref, knew_ref, vnew_ref, _k_in, _v_in,
         n_blocks = (n + block - 1) // block
         wp = wpos_ref[lane]
         # a live lane writes unless its position is parked outside the
-        # cache; the block that takes the write, or none (-1) where the
-        # position lies past what the lane reads
+        # cache; the block that takes the write (counted from the lane's
+        # first), or none (-1) where the position lies outside what the
+        # lane reads
         writes = (n > 0) & (wp >= 0) & (wp < t)
         w_block = jnp.where(writes & (wp // block < n_blocks),
                             wp // block, -1)
+        if windowed:
+            b0 = first_block(lane)
+            n_blocks = n_blocks - b0
+            w_block = jnp.where(w_block >= b0, w_block - b0, -1)
         q = q_ref[lane]  # [KV, rep, Dh]
+
+        def nth(i):
+            """The cache block that is the ``i``-th of the lane's walk."""
+            return b0 + i if windowed else i
 
         def block_body(i, carry):
             o, m, l = carry
@@ -283,7 +311,7 @@ def _ragged_kernel(lens_ref, wpos_ref, q_ref, knew_ref, vnew_ref, _k_in, _v_in,
             # next live lane's first (so a lane boundary costs no wait)
             @pl.when(i + 1 < n_blocks)
             def _():
-                start(lane, i + 1, 1 - slot)
+                start(lane, nth(i + 1), 1 - slot)
 
             @pl.when(i + 1 == n_blocks)
             def _():
@@ -291,9 +319,9 @@ def _ragged_kernel(lens_ref, wpos_ref, q_ref, knew_ref, vnew_ref, _k_in, _v_in,
 
                 @pl.when(nxt < n_lanes)
                 def _():
-                    start(nxt, 0, 1 - slot)
+                    start(nxt, first_block(nxt), 1 - slot)
 
-            k_copy, v_copy = copies(lane, i, slot)
+            k_copy, v_copy = copies(lane, nth(i), slot)
             k_copy.wait()
 
             @pl.when(i == w_block)
@@ -304,10 +332,13 @@ def _ragged_kernel(lens_ref, wpos_ref, q_ref, knew_ref, vnew_ref, _k_in, _v_in,
                 "grd,gkd->grk", q, kbuf[slot],
                 preferred_element_type=jnp.float32,
             ) * scale  # [KV, rep, block]
-            col = i * block + lax.broadcasted_iota(jnp.int32, s.shape, 2)
-            # position 0 is live in a lane's first block, so m is finite
-            # from there on and a masked entry's exp underflows to 0
-            s = jnp.where(col < n, s, NEG_INF)
+            col = nth(i) * block + lax.broadcasted_iota(jnp.int32, s.shape, 2)
+            # the lane's first position is live in its first block, so m is
+            # finite from there on and a masked entry's exp underflows to 0
+            seen = col < n
+            if windowed:
+                seen = seen & (col >= starts_ref[lane])
+            s = jnp.where(seen, s, NEG_INF)
             m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
             alpha = jnp.exp(m - m_new)
             p = jnp.exp(s - m_new)
@@ -355,7 +386,8 @@ def _ragged_kernel(lens_ref, wpos_ref, q_ref, knew_ref, vnew_ref, _k_in, _v_in,
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
 def ragged_decode_attention(q, k, v, lens, k_new, v_new, write_pos,
-                            block: int = BLOCK, interpret: bool = False):
+                            block: int = BLOCK, interpret: bool = False,
+                            starts=None):
     """Pallas ragged decode attention with the step's write inside it.
     q [B, H, 1, Dh]; k, v the layer's cache [B, KV, T, Dh], unsliced
     (``T`` must divide by ``block``); lens [B] int32, clamped to [0, T];
@@ -373,7 +405,11 @@ def ragged_decode_attention(q, k, v, lens, k_new, v_new, write_pos,
     scatter drops it. A lane with ``lens[b] == 0`` reads nothing, gives
     zeros and WRITES NOTHING: its row is the K and V of a token nobody
     sampled, at a position no read admits before the lane's next
-    occupant overwrites it."""
+    occupant overwrites it.
+
+    ``starts`` ([B] int32, optional: a layer with a window): lane b
+    attends to positions [starts[b], lens[b]) and copies only the blocks
+    that hold them; ``starts[b] < lens[b]`` wherever ``lens[b] > 0``."""
     b, h, t_q, dh = q.shape
     n_kv, t = k.shape[1], k.shape[2]
     if t_q != 1 or h % n_kv or t % block or block % GROUP:
@@ -386,16 +422,20 @@ def ragged_decode_attention(q, k, v, lens, k_new, v_new, write_pos,
     smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
     hbm = pl.BlockSpec(memory_space=pl.ANY)
+    kernel, scalars = _ragged_kernel, ()
+    if starts is not None:
+        kernel = _windowed_kernel
+        scalars = (jnp.clip(starts.astype(jnp.int32), 0, t),)
     out, k, v = pl.pallas_call(
-        functools.partial(_ragged_kernel, block=block),
+        functools.partial(kernel, block=block),
         out_shape=(
             jax.ShapeDtypeStruct((b, n_kv, rep, dh), q.dtype),
             jax.ShapeDtypeStruct(k.shape, k.dtype),
             jax.ShapeDtypeStruct(v.shape, v.dtype),
         ),
-        in_specs=[smem, smem, vmem, vmem, vmem, hbm, hbm],
+        in_specs=[smem] * len(scalars) + [smem, smem, vmem, vmem, vmem, hbm, hbm],
         out_specs=(vmem, hbm, hbm),
-        input_output_aliases={5: 1, 6: 2},
+        input_output_aliases={len(scalars) + 5: 1, len(scalars) + 6: 2},
         scratch_shapes=[
             pltpu.VMEM((2, n_kv, block, dh), k.dtype),
             pltpu.VMEM((2, n_kv, block, dh), v.dtype),
@@ -406,7 +446,8 @@ def ragged_decode_attention(q, k, v, lens, k_new, v_new, write_pos,
             pltpu.SemaphoreType.DMA((2,)),
         ],
         interpret=interpret,
-    )(jnp.clip(lens.astype(jnp.int32), 0, t), write_pos.astype(jnp.int32),
+    )(*scalars,
+      jnp.clip(lens.astype(jnp.int32), 0, t), write_pos.astype(jnp.int32),
       q.reshape(b, n_kv, rep, dh), k_new.astype(k.dtype),
       v_new.astype(v.dtype), k, v)
     return out.reshape(b, h, 1, dh), k, v
@@ -414,7 +455,7 @@ def ragged_decode_attention(q, k, v, lens, k_new, v_new, write_pos,
 
 @functools.partial(jax.jit, static_argnames=("attn_len", "mesh"))
 def decode_attention(q, k, v, k_new, v_new, write_pos, pos, lens,
-                     attn_len=None, mesh=None):
+                     attn_len=None, mesh=None, starts=None):
     """The decode step's write and read of one layer's cache: this step's
     rows k_new, v_new [B, KV, 1, Dh] go into the UNSLICED cache k, v [B,
     KV, T, Dh] at ``write_pos`` [B] (outside [0, T): dropped), then q [B,
@@ -443,9 +484,17 @@ def decode_attention(q, k, v, k_new, v_new, write_pos, pos, lens,
 
     ``mesh``: the serving mesh when the caller runs under one
     (``reads_ragged()``: it takes the scatter and the dots).
+
+    ``starts`` ([B], optional): the first position each lane sees, for a
+    layer with a window (``max(0, lens - window)``); the kernel then
+    copies only the blocks from there on, and the dots take the same
+    band as a mask. None: every lane sees from 0.
     """
     t = k.shape[2]
     bound = t if attn_len is None else min(int(attn_len), t)
+    if starts is not None:
+        return _windowed_decode_attention(
+            q, k, v, k_new, v_new, write_pos, pos, lens, starts, bound, mesh)
 
     def dots(q, k, v, k_new, v_new, write_pos, pos, lens):
         k = cache_write(k, k_new, write_pos[:, None])
@@ -463,6 +512,31 @@ def decode_attention(q, k, v, k_new, v_new, write_pos, pos, lens,
     args = (q, k, v, k_new, v_new, write_pos, pos, lens)
     # the platform is known only when this is lowered: ask whether a
     # lowering for a TPU takes the kernel, and let that lowering choose
+    if not reads_ragged(
+            "tpu", q.shape, k.shape, (q.dtype, k.dtype, v.dtype), mesh):
+        return dots(*args)
+    return lax.platform_dependent(*args, tpu=kernel, default=dots)
+
+
+def _windowed_decode_attention(q, k, v, k_new, v_new, write_pos, pos, lens,
+                               starts, bound, mesh):
+    """``decode_attention()`` for a layer with a window: the same choice
+    between the kernel and the dots, each taking the lanes' ``starts``."""
+
+    def dots(q, k, v, k_new, v_new, write_pos, pos, lens, starts):
+        k = cache_write(k, k_new, write_pos[:, None])
+        v = cache_write(v, v_new, write_pos[:, None])
+        o = cache_attention(
+            q, lax.slice_in_dim(k, 0, bound, axis=2),
+            lax.slice_in_dim(v, 0, bound, axis=2), pos, q.dtype, lo=starts)
+        return o, k, v
+
+    def kernel(q, k, v, k_new, v_new, write_pos, pos, lens, starts):
+        return ragged_decode_attention(
+            q, k, v, jnp.minimum(lens, bound), k_new, v_new, write_pos,
+            block=BLOCK, starts=starts)
+
+    args = (q, k, v, k_new, v_new, write_pos, pos, lens, starts)
     if not reads_ragged(
             "tpu", q.shape, k.shape, (q.dtype, k.dtype, v.dtype), mesh):
         return dots(*args)

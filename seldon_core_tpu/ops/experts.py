@@ -1,0 +1,225 @@
+"""Drop-free routed experts for serving: the router, the grouped prefill and
+a decode step that reads only the experts its live lanes picked.
+
+A layer has ``E`` experts, each a SwiGLU ``(silu(x W1) * (x W3)) W2`` of one
+width, held stacked: ``w1``, ``w3`` [E, D, F] and ``w2`` [E, F, D]. Every row
+takes its ``k`` best experts by the router's score, with no capacity and no
+drop (``parallel/moe.py`` is the training dry-run's top-1 layer with
+capacity drops; nothing here shares its code).
+
+``route()``            scores, picks and weights of each row
+``grouped_experts()``  any number of rows: sort the (row, pick) pairs by
+                       expert, three grouped matmuls (``lax.ragged_dot``:
+                       on a TPU the compiler's own Mosaic grouped matmul,
+                       ``ragged-dot-*`` in a trace), unsort, weighted sum.
+                       Compute-bound from a few hundred rows on, where every
+                       expert is touched
+``decode_experts()``   one row a lane: a Pallas kernel walks the SORTED
+                       list of experts that some live lane picked (scalars
+                       prefetched to SMEM) and streams each one's ``W1``,
+                       ``W3``, ``W2`` from the stacked arrays where they lie
+                       in HBM, ONCE, through the pipeline's two buffers. An
+                       expert nobody picked is never read, an idle lane
+                       routes nowhere. A gather of the touched experts into
+                       a temporary would read them twice; a grouped matmul
+                       over [E, ...] reads what the row tiles ask for. Off a
+                       TPU it is ``grouped_experts()`` with idle lanes'
+                       weights at zero
+
+Both return float32 sums over a row's picks; the caller casts.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+# rows the grouped path takes in one piece: above it the rows go through
+# in groups (``lax.map``), so that the sorted copy of the rows and the three
+# products stay a few hundred MB beside a full chip. At 4096 rows x 8 picks
+# the experts' matmuls (0.41 TFLOP a layer at Trinity-Mini's widths) take
+# about as long as reading the layer's 1.6 GB of experts once more
+GROUP_ROWS = 4096
+# the decode kernel's slice of an expert's width: W1 and W3 blocks [D, TF],
+# W2 [TF, D], 2 MB each at D = 2048 in bfloat16, two buffers apiece
+DECODE_TF = 512
+DECODE_VMEM_BYTES = 48 << 20
+
+
+def route(x, router, bias, k: int, scale: float):
+    """x [N, D], router [D, E], bias [E] -> picks [N, k] int32 and weights
+    [N, k] float32. Scores are ``sigmoid`` of the float32 router logits;
+    the bias enters the SELECTION only; the weights are the picked scores
+    normalised to sum to ``scale``."""
+    logits = jnp.dot(x, router.astype(x.dtype),
+                     preferred_element_type=jnp.float32)
+    s = jax.nn.sigmoid(logits)
+    _, picks = lax.top_k(s + bias.astype(jnp.float32), k)
+    sel = jnp.take_along_axis(s, picks, axis=-1)
+    w = sel / (sel.sum(-1, keepdims=True) + 1e-20) * scale
+    return picks.astype(jnp.int32), w
+
+
+def _grouped(x, picks, weights, w1, w3, w2):
+    n, d = x.shape
+    k = picks.shape[1]
+    n_experts = w1.shape[0]
+    flat = picks.reshape(-1)
+    order = jnp.argsort(flat, stable=True)
+    xs = x[order // k]                                   # [N k, D] by expert
+    sizes = jnp.sum(
+        flat[:, None] == jnp.arange(n_experts, dtype=flat.dtype)[None, :],
+        axis=0, dtype=jnp.int32)
+    a = lax.ragged_dot(xs, w1, sizes, preferred_element_type=jnp.float32)
+    g = lax.ragged_dot(xs, w3, sizes, preferred_element_type=jnp.float32)
+    h = (jax.nn.silu(a) * g).astype(x.dtype)
+    y = lax.ragged_dot(h, w2, sizes, preferred_element_type=jnp.float32)
+    y = (y * weights.reshape(-1)[order][:, None]).astype(x.dtype)
+    back = jnp.argsort(order)                             # the unsort
+    return y[back].reshape(n, k, d).astype(jnp.float32).sum(axis=1)
+
+
+@functools.partial(jax.jit, static_argnames=("group_rows",))
+def grouped_experts(x, picks, weights, w1, w3, w2, group_rows: int = GROUP_ROWS):
+    """x [N, D] rows, picks / weights [N, k] (``route()``), the stacked
+    experts -> float32 [N, D]: sum over a row's picks of weight x expert(x).
+    Drop-free whatever the picks. More than ``group_rows`` rows go through
+    in equal groups of at most that many."""
+    n = x.shape[0]
+    if n <= group_rows:
+        return _grouped(x, picks, weights, w1, w3, w2)
+    groups = -(-n // group_rows)
+    while n % groups:
+        groups += 1
+    split = lambda a: a.reshape(groups, n // groups, *a.shape[1:])  # noqa: E731
+    out = lax.map(
+        lambda r: _grouped(r[0], r[1], r[2], w1, w3, w2),
+        (split(x), split(picks), split(weights)))
+    return out.reshape(n, x.shape[1])
+
+
+def touched_experts(picks, live, n_experts: int):
+    """The experts some LIVE lane picked: ``(ids [E] int32, n)`` with the
+    ``n`` touched ids first, ascending (the rest of ``ids`` is 0 and is
+    never read as an expert). picks [B, k]; live [B] bool."""
+    e = jnp.arange(n_experts, dtype=jnp.int32)
+    hit = (picks[:, :, None] == e[None, None, :]) & live[:, None, None]
+    touched = hit.any(axis=(0, 1))                        # [E]
+    place = jnp.cumsum(touched.astype(jnp.int32)) - 1     # its slot in ids
+    ids = jnp.sum(
+        jnp.where(touched[:, None] & (place[:, None] == e[None, :]),
+                  e[:, None], 0), axis=0)
+    return ids.astype(jnp.int32), touched.sum(dtype=jnp.int32)
+
+
+def _decode_kernel(ids_ref, n_ref, x_ref, picks_ref, wts_ref, w1_ref, w3_ref,
+                   w2_ref, o_ref):
+    """Grid (E, F / TF): step (i, f) adds slice f of the i-th touched
+    expert. Past the touched ones the index maps stay on the last block
+    fetched, so nothing more is copied, and nothing is computed."""
+    i, f = pl.program_id(0), pl.program_id(1)
+
+    @pl.when((i == 0) & (f == 0))
+    def _():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when(i < n_ref[0])
+    def _():
+        x = x_ref[...]
+        a = jnp.dot(x, w1_ref[0], preferred_element_type=jnp.float32)
+        g = jnp.dot(x, w3_ref[0], preferred_element_type=jnp.float32)
+        # each lane's weight for this expert: 0 where it did not pick it
+        c = jnp.sum(jnp.where(picks_ref[...] == ids_ref[i], wts_ref[...], 0.0),
+                    axis=-1, keepdims=True)               # [B, 1]
+        h = (jax.nn.silu(a) * g * c).astype(x.dtype)
+        o_ref[...] += jnp.dot(h, w2_ref[0], preferred_element_type=jnp.float32)
+
+
+@functools.partial(jax.jit, static_argnames=("tf", "interpret"))
+def touched_experts_ffn(x, picks, weights, ids, n, w1, w3, w2,
+                        tf: int = DECODE_TF, interpret: bool = False):
+    """The decode kernel itself. x [B, D]; picks [B, k] int32; weights [B, k]
+    float32 (an idle lane's: zeros); ``ids`` [E], ``n`` [1] from
+    ``touched_experts()``; the stacked experts, left in HBM. Float32 [B, D].
+    HBM bytes read: ``n`` x the three matrices of one expert."""
+    b, d = x.shape
+    n_experts, _, width = w1.shape
+    tf = min(tf, width)
+    if width % tf:
+        raise ValueError(f"expert width {width} is no multiple of {tf}")
+    nf = width // tf
+
+    def expert(i, f, ids, n):
+        last = jnp.maximum(n[0] - 1, 0)
+        return ids[jnp.minimum(i, last)], jnp.where(i < n[0], f, nf - 1)
+
+    def cols(i, f, ids, n):
+        e, f = expert(i, f, ids, n)
+        return e, 0, f
+
+    def rows(i, f, ids, n):
+        e, f = expert(i, f, ids, n)
+        return e, f, 0
+
+    whole = lambda i, f, ids, n: (0, 0)  # noqa: E731
+    return pl.pallas_call(
+        _decode_kernel,
+        out_shape=jax.ShapeDtypeStruct((b, d), jnp.float32),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(n_experts, nf),
+            in_specs=[
+                pl.BlockSpec((b, d), whole),
+                pl.BlockSpec(picks.shape, whole),
+                pl.BlockSpec(weights.shape, whole),
+                pl.BlockSpec((1, d, tf), cols),
+                pl.BlockSpec((1, d, tf), cols),
+                pl.BlockSpec((1, tf, d), rows),
+            ],
+            out_specs=pl.BlockSpec((b, d), whole),
+        ),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=DECODE_VMEM_BYTES,
+        ),
+        name="touched_experts_ffn",
+        interpret=interpret,
+    )(ids, n.reshape(1), x, picks, weights, w1, w3, w2)
+
+
+def decodes_touched(platform, x_shape, w1_shape, mesh=None) -> bool:
+    """Whether ``decode_experts()``, lowered for ``platform``, is the
+    kernel: a TPU, no serving mesh (Mosaic kernels are not partitioned),
+    widths that fill lanes, rows that fill a bfloat16 tile."""
+    return (
+        platform == "tpu" and mesh is None
+        and x_shape[0] % 16 == 0
+        and x_shape[1] % 128 == 0 and w1_shape[2] % 128 == 0
+    )
+
+
+@functools.partial(jax.jit, static_argnames=("mesh",))
+def decode_experts(x, picks, weights, live, w1, w3, w2, mesh=None):
+    """One decode step's routed experts. x [B, D]; picks, weights [B, k];
+    live [B] bool. Returns ``(float32 [B, D], experts touched, rows
+    routed)``: an idle lane adds nothing to either count, reads no expert
+    and gets zeros."""
+    weights = jnp.where(live[:, None], weights, 0.0)
+    ids, n = touched_experts(picks, live, w1.shape[0])
+    routed = live.sum(dtype=jnp.int32) * picks.shape[1]
+
+    def kernel(x, picks, weights, ids, n, w1, w3, w2):
+        return touched_experts_ffn(x, picks, weights, ids, n, w1, w3, w2)
+
+    def grouped(x, picks, weights, ids, n, w1, w3, w2):
+        return grouped_experts(x, picks, weights, w1, w3, w2)
+
+    args = (x, picks, weights, ids, n, w1, w3, w2)
+    if not decodes_touched("tpu", x.shape, w1.shape, mesh):
+        return grouped(*args), n, routed
+    return lax.platform_dependent(*args, tpu=kernel, default=grouped), n, routed

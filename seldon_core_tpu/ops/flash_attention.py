@@ -34,20 +34,42 @@ from jax.sharding import PartitionSpec as P
 NEG_INF = -1e30
 
 
-def _xla_attention(q, k, v, causal: bool, kv_len=None):
+def _xla_attention(q, k, v, causal: bool, kv_len=None, window=None):
     """Reference attention, same contract as the kernel — delegates to
     parallel/ring.full_attention so the fallback and the trained/ring
-    paths share ONE copy of the math."""
+    paths share ONE copy of the math. ``window``: the kernel's band, as
+    the same masked dots."""
     from ..parallel.ring import full_attention
 
-    return full_attention(q, k, v, causal=causal, kv_len=kv_len)
+    if window is None:
+        return full_attention(q, k, v, causal=causal, kv_len=kv_len)
+    if not causal or kv_len is not None:
+        raise ValueError("a window is causal and takes no kv_len")
+    return _banded_attention(q, k, v, window)
 
 
-def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, block_q, block_k, causal):
+def _banded_attention(q, k, v, window: int):
+    """Causal attention in which query i sees key j iff i - window < j <= i:
+    full_attention's math under the band's mask."""
+    scale = 1.0 / (q.shape[-1] ** 0.5)
+    s = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32),
+                   k.astype(jnp.float32)) * scale
+    row = jnp.arange(q.shape[2])[:, None]
+    col = jnp.arange(k.shape[2])[None, :]
+    s = jnp.where(((row >= col) & (col > row - window))[None, None], s, NEG_INF)
+    p = jax.nn.softmax(s, axis=-1)
+    return jnp.einsum("bhqk,bhkd->bhqd", p, v.astype(jnp.float32)).astype(q.dtype)
+
+
+def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, block_q, block_k, causal,
+                  window=None):
     """One (bh, q-block) program: stream K/V blocks with online softmax.
 
     q_ref/o_ref: [1, block_q, Dh]; k_ref/v_ref: [1, Tk, Dh] (whole keys
     for this bh resident in VMEM — serving-sized Tk*Dh fits easily).
+    ``window`` (static, with ``causal``): row i sees columns (i - window,
+    i]; the walk starts at the block that holds the q-block's first row's
+    first column and key blocks wholly left of the band are never read.
     """
     qb = pl.program_id(1)
     dh = q_ref.shape[-1]
@@ -68,7 +90,10 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, block_q, block_k, causal):
         )  # [block_q, block_k]
         if causal:
             col = i * block_k + lax.broadcasted_iota(jnp.int32, (1, block_k), 1)
-            s = jnp.where(row >= col, s, NEG_INF)
+            seen = row >= col
+            if window is not None:
+                seen = seen & (col > row - window)
+            s = jnp.where(seen, s, NEG_INF)
         m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
         alpha = jnp.exp(m - m_new)
         # Masked entries hold NEG_INF (finite -1e30): under this kernel's
@@ -79,6 +104,10 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, block_q, block_k, causal):
         # p == 1 per entry (an unweighted mean of V, not zeros); reuse
         # with such masks requires a p = where(s == NEG_INF, 0, ...) guard.
         p = jnp.exp(s - m_new)
+        if window is not None:
+            # the band hides the walk's first block from the q-block's
+            # later rows whole: the guard the note above asks for
+            p = jnp.where(seen, p, 0.0)
         l = l * alpha + p.sum(axis=-1, keepdims=True)
         o = o * alpha + jax.lax.dot_general(
             p, vb, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
@@ -94,7 +123,10 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, block_q, block_k, causal):
     o = jnp.zeros((block_q, dh), jnp.float32)
     m = jnp.full((block_q, 1), NEG_INF, jnp.float32)
     l = jnp.zeros((block_q, 1), jnp.float32)
-    o, m, l = lax.fori_loop(0, n_k, body, (o, m, l))
+    first = 0
+    if window is not None:
+        first = jnp.maximum(0, (qb * block_q - window + 1) // block_k)
+    o, m, l = lax.fori_loop(first, n_k, body, (o, m, l))
     # l == 0 is unreachable via the causal equal-block dispatch (see the
     # loop-body comment); kept as a belt against 0/0 if the kernel is
     # rebuilt with a row-hiding mask
@@ -103,7 +135,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, block_q, block_k, causal):
 
 
 @functools.partial(
-    jax.jit, static_argnames=("causal", "block_q", "block_k", "interpret")
+    jax.jit,
+    static_argnames=("causal", "block_q", "block_k", "interpret", "window"),
 )
 def flash_attention(
     q,
@@ -113,10 +146,14 @@ def flash_attention(
     block_q: int = 128,
     block_k: int = 128,
     interpret: bool = False,
+    window=None,
 ):
     """Pallas blocked attention. q [B,H,Tq,Dh], k/v [B,H,Tk,Dh].
     Tq must divide by block_q and Tk by block_k (use :func:`attention`
-    for the dispatching fallback)."""
+    for the dispatching fallback). ``window`` (static int, causal only):
+    query i sees keys (i - window, i]."""
+    if window is not None and not causal:
+        raise ValueError("a window is causal")
     b, h, t_q, dh = q.shape
     t_k = k.shape[2]
     if t_q % block_q or t_k % block_k:
@@ -132,6 +169,8 @@ def flash_attention(
         block_k=block_k,
         causal=causal,
     )
+    if window is not None:
+        kernel = functools.partial(kernel, window=int(window))
     out = pl.pallas_call(
         kernel,
         # under shard_map the result varies over the mesh axes q does
@@ -150,11 +189,15 @@ def flash_attention(
     return out.reshape(b, h, t_q, dh)
 
 
-def attention(q, k, v, kv_len=None, causal: bool = True, mesh=None):
+def attention(q, k, v, kv_len=None, causal: bool = True, mesh=None,
+              window=None):
     """Dispatching attention: Pallas flash kernel on TPU when the shape
     tiles onto the MXU, XLA einsum otherwise (CPU, tiny prompts). Inference
     only — the kernel defines no VJP; training paths keep the XLA/ring
     implementations (parallel/ring.py).
+
+    ``window`` (static int, optional): query i sees keys (i - window, i]
+    (a model's sliding-attention layers); both paths take the same band.
 
     ``mesh``: the serving mesh when the caller runs under one. Mosaic
     kernels cannot be partitioned by GSPMD, so the kernel call is wrapped
@@ -182,10 +225,14 @@ def attention(q, k, v, kv_len=None, causal: bool = True, mesh=None):
         and q.shape[-1] in (64, 128, 256)
     )
     if not use_kernel:
-        return _xla_attention(q, k, v, causal=causal, kv_len=kv_len)
+        if window is None:
+            return _xla_attention(q, k, v, causal=causal, kv_len=kv_len)
+        return _xla_attention(q, k, v, causal, kv_len, window)
     kernel = functools.partial(
         flash_attention, causal=causal, block_q=block, block_k=block
     )
+    if window is not None:
+        kernel = functools.partial(kernel, window=int(window))
     if mesh is not None:
         kernel = jax.shard_map(
             kernel, mesh=mesh, in_specs=(P(), P(), P()), out_specs=P()

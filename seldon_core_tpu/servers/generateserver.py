@@ -453,9 +453,10 @@ class GenerateServer(SeldonComponent):
 
         # iterate KEYS only: a list of items() tuples would pin every fp32
         # value for the whole loop, re-creating the double-resident peak
-        for key in list(tree):
+        # a list of layers (models/afmoe.py) is walked by index
+        for key in (range(len(tree)) if isinstance(tree, list) else list(tree)):
             v = tree[key]
-            if isinstance(v, dict):
+            if isinstance(v, (dict, list)):
                 GenerateServer._cast_params_freeing_impl(v, dt)
             elif hasattr(v, "dtype") and v.dtype == jnp.float32:
                 tree[key] = v.astype(dt)
@@ -478,6 +479,14 @@ class GenerateServer(SeldonComponent):
             raise RuntimeError(
                 f"model family {getattr(self._model, '__class__', None)} "
                 "does not support generate(); use family 'llm'"
+            )
+        if hasattr(self._model, "check_serves"):
+            # before a mesh or a draft is built for a family that has no
+            # path for it (typed: models.llm.UnsupportedByModel)
+            self._model.check_serves(
+                speculation=self._speculate_tokens > 0,
+                mesh=self._mesh is not None or self._mesh_shape is not None,
+                kv_tier=self._host_kv_tier_bytes > 0,
             )
         if self._mesh is None and self._mesh_shape is not None:
             # build the serving mesh from the knob: an injected mesh

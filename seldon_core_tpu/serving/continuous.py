@@ -331,6 +331,21 @@ def _positions_streamed(pos: int, k: int, bucket: int, block: int) -> int:
     )
 
 
+def _positions_windowed(pos: int, k: int, window: int, block: int) -> tuple:
+    """``_positions_streamed`` for a layer with a window: ``(streamed,
+    seen, live)`` over the ``k`` steps. The j-th step's lane holds ``pos +
+    j`` keys (live), must see the last ``window`` of them (seen) and
+    streams the blocks from the one that holds the window's start."""
+    streamed = seen = live = 0
+    for j in range(1, k + 1):
+        n = pos + j
+        start = max(0, n - window)
+        streamed += (-(-n // block) - start // block) * block
+        seen += n - start
+        live += n
+    return streamed, seen, live
+
+
 class ContinuousBatcher:
     """Slot-based continuous batching scheduler over a DecoderLM.
 
@@ -381,6 +396,15 @@ class ContinuousBatcher:
         from jax import lax
 
         self.model = model
+        # a family refuses, typed and at load, the features it has no
+        # path for (DecoderLM.serving_refuses) rather than computing
+        # something else under them
+        if hasattr(model, "check_serves"):
+            model.check_serves(
+                speculation=draft_model is not None,
+                mesh=mesh is not None,
+                kv_tier=int(host_kv_tier_bytes) > 0,
+            )
         self.slots = int(slots)
         self.max_seq = int(max_seq or model.cfg.max_seq)
         self.mesh = mesh
@@ -580,6 +604,13 @@ class ContinuousBatcher:
             # attn_len x steps). Their ratio is the share of the old read
             # still made
             "kv_positions_read": 0, "kv_positions_bucket": 0,
+            # the same arithmetic for the model's layers with a window
+            # (model.attention_kinds()): positions such a layer streams
+            # (from the block that holds the window's start), the
+            # positions it must see (min(len, window)), and every live
+            # position of those lanes. 0 in a model with no such layer
+            "kv_positions_read_window": 0, "kv_positions_seen_window": 0,
+            "kv_positions_live_window": 0,
             # the decode write: K and V rows a dispatched burst lands for
             # its live lanes (lanes x steps x layers x 2), and how many of
             # them the read's kernel lands itself (all where
@@ -928,15 +959,25 @@ class ContinuousBatcher:
             )(subs, logits, temps).astype(jnp.int32)
             return keys, jnp.where(temps > 0, sampled, greedy)
 
+        # counters a model's decode step returns after its caches (an
+        # int32 vector, model.step_counter_names): summed over a burst's
+        # steps on the device, they ride home as the burst's last array
+        # and _read_burst adds them into stats. A model with none (the
+        # llama block) returns none and its bursts return what they
+        # always did.
+        self._step_counters = tuple(getattr(model, "step_counter_names", ()))
+        for name in self._step_counters:
+            self.stats.setdefault(name, 0)
+
         def fused_step(params, ks, vs, cur_tok, pos, active, temps, keys, attn_len):
-            logits, ks, vs = model.decode_step_ragged_list(
+            logits, ks, vs, *counts = model.decode_step_ragged_list(
                 params, ks, vs, cur_tok[:, None], pos, attn_len=attn_len,
                 lens=jnp.where(active, pos + 1, 0),
             )
             keys, nxt = sample_next(keys, logits, temps)
             nxt = jnp.where(active, nxt, 0)
             pos = jnp.where(active, pos + 1, pos)
-            return nxt, pos, ks, vs, keys
+            return (nxt, pos, ks, vs, keys, *counts)
 
         def insert(cache, cache_one, slot, first_tok, first_pos, lane_key, cur_tok, pos, keys):
             # cache_one is the prefill's stacked [L, 1, KV, Tb, Dh] slab;
@@ -1021,18 +1062,19 @@ class ContinuousBatcher:
 
             def body(carry, _):
                 ks, vs, cur_tok, pos, keys = carry
-                nxt, pos, ks, vs, keys = fused_step(
+                nxt, pos, ks, vs, keys, *counts = fused_step(
                     params, ks, vs, cur_tok, pos, active, temps, keys, attn_len
                 )
-                return (ks, vs, nxt, pos, keys), nxt
+                return (ks, vs, nxt, pos, keys), (nxt, *counts)
 
-            (ks, vs, cur_tok_out, pos, keys), toks = lax.scan(
+            (ks, vs, cur_tok_out, pos, keys), (toks, *counts) = lax.scan(
                 body, (cache["k"], cache["v"], cur_tok, pos, keys), None, length=k
             )
             # row 0 = the tokens the burst STARTED from (deferred prefill
             # firsts ride home with the burst's one sync)
             toks = jnp.concatenate([cur_tok[None, :], toks], axis=0)
-            return toks, cur_tok_out, pos, {"k": ks, "v": vs}, keys
+            return (toks, cur_tok_out, pos, {"k": ks, "v": vs}, keys,
+                    *(c.sum(axis=0) for c in counts))
 
         # -- stop-aware fused multi-step decode ------------------------------
         def fused_masked_step(params, ks, vs, cur_tok, pos, alive, temps,
@@ -1050,14 +1092,14 @@ class ContinuousBatcher:
             does): a frozen lane's key is dead state its next occupant's
             insert overwrites."""
             wpos = jnp.where(alive, pos, park)
-            logits, ks, vs = model.decode_step_ragged_list(
+            logits, ks, vs, *counts = model.decode_step_ragged_list(
                 params, ks, vs, cur_tok[:, None], pos, attn_len=attn_len,
                 write_pos=wpos, lens=jnp.where(alive, pos + 1, 0),
             )
             keys, nxt = sample_next(keys, logits, temps)
             cur_tok = jnp.where(alive, nxt, cur_tok)
             pos = jnp.where(alive, pos + 1, pos)
-            return cur_tok, pos, ks, vs, keys
+            return (cur_tok, pos, ks, vs, keys, *counts)
 
         def fused_stop_burst(params, cache, cur_tok, pos, active, temps,
                              keys, stops, budgets, k, attn_len):
@@ -1080,13 +1122,13 @@ class ContinuousBatcher:
             def body(carry, _):
                 ks, vs, cur, p, kk, budget, done = carry
                 alive = active & ~done
-                cur, p, ks, vs, kk = fused_masked_step(
+                cur, p, ks, vs, kk, *counts = fused_masked_step(
                     params, ks, vs, cur, p, alive, temps, kk, attn_len, park
                 )
                 budget = budget - alive.astype(jnp.int32)
                 done = done | (alive & ((cur == stops) | (budget <= 0)))
                 return (ks, vs, cur, p, kk, budget, done), (
-                    jnp.where(alive, cur, 0), alive,
+                    jnp.where(alive, cur, 0), alive, *counts,
                 )
 
             # a lane can arrive already-done: its stop token was emitted
@@ -1094,7 +1136,7 @@ class ContinuousBatcher:
             # lag), or its budget was fully covered — either way it runs
             # zero steps here instead of overshoot-decoding
             done0 = ~active | (budgets <= 0) | (cur_tok == stops)
-            (ks, vs, cur, pos, keys, budgets, done), (toks, alive_rows) = (
+            (ks, vs, cur, pos, keys, budgets, done), (toks, alive_rows, *extra) = (
                 lax.scan(
                     body,
                     (cache["k"], cache["v"], cur_tok, pos, keys, budgets,
@@ -1105,7 +1147,7 @@ class ContinuousBatcher:
             counts = alive_rows.astype(jnp.int32).sum(axis=0)
             toks = jnp.concatenate([cur_tok[None, :], toks], axis=0)
             return (toks, counts, done, cur, pos, {"k": ks, "v": vs}, keys,
-                    budgets)
+                    budgets, *(c.sum(axis=0) for c in extra))
 
         # -- prefix-cache executables ---------------------------------------
         def prefix_prefill(params, slab, suffix, start_pos, last_index, seed, temp):
@@ -1187,7 +1229,7 @@ class ContinuousBatcher:
             def body(carry, x):
                 ks, vs, pos = carry
                 tok, a = x
-                _logits, ks, vs = model.decode_step_ragged_list(
+                _logits, ks, vs, *_ = model.decode_step_ragged_list(
                     params, ks, vs, tok[None, None], pos, attn_len=None
                 )
                 pos = jnp.where(a, pos + 1, pos)
@@ -1270,6 +1312,11 @@ class ContinuousBatcher:
         from ..ops.decode_attention import BLOCK, reads_ragged
 
         self._kv_read_block = BLOCK
+        # the windows of the model's layers that have one (the kinds come
+        # from the model; the llama block has none)
+        kinds = model.attention_kinds() if hasattr(
+            model, "attention_kinds") else ()
+        self._kv_windows = tuple(w for _n, w in kinds if w is not None)
         # whether the decode step's read takes each lane's own length on
         # the platform the bursts are lowered for (the cache's devices).
         # Where it does, the bucket bounds nothing in _burst_fn and
@@ -3100,7 +3147,7 @@ class ContinuousBatcher:
                 self._cache["k"][0].block_until_ready()  # seldon-lint: disable=host-sync-hot-path (warm precompile: intentional sync while the loop is idle)
         else:
             for attn_len in burst_lens:
-                toks, self._cur_tok, self._pos, self._cache, self._keys = (
+                toks, self._cur_tok, self._pos, self._cache, self._keys, *_ = (
                     self._burst_fn(
                         self.params, self._cache, self._cur_tok, self._pos,
                         active, temps, self._keys, k, attn_len,
@@ -3132,7 +3179,7 @@ class ContinuousBatcher:
                 for fk in fks:
                     (
                         toks, _counts, _done, self._cur_tok, self._pos,
-                        self._cache, self._keys, budget0,
+                        self._cache, self._keys, budget0, *_,
                     ) = self._fused_burst_fn(
                         self.params, self._cache, self._cur_tok, self._pos,
                         active, temps, self._keys, stops0, budget0, fk,
@@ -4085,6 +4132,10 @@ class ContinuousBatcher:
         t_read = self._clock.to("credit")
         self.stats["bursts"] += 1
         self.stats["burst_read_lag_s_sum"] += t_read - t_dispatch
+        if self._step_counters and mode != "spec":
+            # the model's own counters of the burst's steps: its last array
+            for name, n in zip(self._step_counters, host.pop()):
+                self.stats[name] += int(n)
         if mode == "spec":
             self._process_spec_burst(*host, *rest)
         elif mode == "fused":
@@ -5655,9 +5706,15 @@ class ContinuousBatcher:
                             s.first_pending = False
                             s.dispatched += k + (1 if first else 0)
                             self._pos_host[slot] += adv
-                        read_bytes = k * (
-                            self._param_bytes
-                            + self.slots * attn_len * self._kv_key_bytes
+                        # the family prices its own step: the llama
+                        # block every weight and every row's bucket, one
+                        # with window layers or experts what the live
+                        # lanes make it read
+                        read_bytes = self.model.dispatch_read_bytes(
+                            "decode_burst", rows=self.slots,
+                            live=len(lanes), k=k, bucket=attn_len,
+                            param_bytes=self._param_bytes,
+                            kv_row_bytes=self._kv_key_bytes,
                         )
                         # the executable's own bound: none where the read
                         # takes each lane's length (the host's arithmetic
@@ -5676,7 +5733,7 @@ class ContinuousBatcher:
                                 (
                                     toks, counts, done_bits,
                                     self._cur_tok, self._pos, self._cache,
-                                    self._keys, self._budget_dev,
+                                    self._keys, self._budget_dev, *extra,
                                 ) = self._fused_burst_fn(
                                     self.params, self._cache,
                                     self._cur_tok, self._pos,
@@ -5685,13 +5742,13 @@ class ContinuousBatcher:
                                     k, bound,
                                 )
                                 burst = (
-                                    "fused", (toks, counts, done_bits),
+                                    "fused", (toks, counts, done_bits, *extra),
                                     (snapshot, k), t_dispatch,
                                 )
                             else:
                                 (
                                     toks, self._cur_tok, self._pos,
-                                    self._cache, self._keys,
+                                    self._cache, self._keys, *extra,
                                 ) = self._burst_fn(
                                     self.params, self._cache,
                                     self._cur_tok, self._pos,
@@ -5699,7 +5756,7 @@ class ContinuousBatcher:
                                     k, bound,
                                 )
                                 burst = (
-                                    "plain", (toks,), (snapshot,),
+                                    "plain", (toks, *extra), (snapshot,),
                                     t_dispatch,
                                 )
                             _m.sync(toks)
@@ -5716,6 +5773,14 @@ class ContinuousBatcher:
                         self.stats["kv_positions_bucket"] += (
                             k * self.slots * attn_len
                         )
+                        for window in self._kv_windows:
+                            for slot in lanes:
+                                streamed, seen, live = _positions_windowed(
+                                    self._pos_host[slot] - adv, k, window,
+                                    self._kv_read_block)
+                                self.stats["kv_positions_read_window"] += streamed
+                                self.stats["kv_positions_seen_window"] += seen
+                                self.stats["kv_positions_live_window"] += live
                         rows = len(lanes) * k * 2 * len(self._cache["k"])
                         self.stats["kv_rows_written"] += rows
                         if self._ragged_read:

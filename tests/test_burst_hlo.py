@@ -151,6 +151,32 @@ def test_reader_counts_the_aliases():
     assert _tool().alias_count("HloModule m, is_scheduled=true\n") == 0
 
 
+def test_reader_tells_one_program_from_two_but_for_source_names():
+    """``--against``: a change that must leave the dense bursts alone
+    (ISSUE 33) compiles to the parent's program once what only names the
+    source is left out: every op's metadata and the tables at the head."""
+    tool = _tool()
+    named = HLO.replace(
+        "parameter(0)\n  ROOT %slice.9",
+        'parameter(0), metadata={op_name="jit(x)" source_file="a.py" source_line=7}'
+        "\n  ROOT %slice.9", 1)
+    moved = named.replace("source_line=7", "source_line=90").replace(
+        "\n\n", "\n\nFileNames\n1 \"a.py\"\n2 \"b.py\"\n\nFunctionNames\n1 \"f\"\n\n", 1)
+    assert named != HLO and "FileNames" in moved
+    same = tool.same_program(named, moved)
+    assert same["hlo_equal_but_for_metadata"] and same["kernels_equal_but_for_locations"]
+    assert same["first_differing_line"] is None and same["kernels"] == 0
+    other = tool.same_program(HLO, HLO.replace("[0:640]", "[0:512]", 1))
+    assert not other["hlo_equal_but_for_metadata"]
+    assert "[0:640]" in other["first_differing_line"][0]
+    # a kernel's body that differs and does not parse as the same MLIR is
+    # never reached where the count of kernels differs
+    call = ('  %r.1 = bf16[28,8,2,128]{3,2,1,0} custom-call(%l), custom_call_target='
+            '"tpu_custom_call", backend_config={"custom_call_config": {"body":"QUJD"}}\n')
+    more = tool.same_program(HLO, HLO.replace("  %slice.4 =", call + "  %slice.4 ="))
+    assert not more["kernels_equal_but_for_locations"]
+
+
 def test_reader_compares_while_bodies_up_to_constants():
     """Two compilations of one program differ in instruction names and in
     the values of constants (a burst's bucket is one); an instruction

@@ -36,7 +36,14 @@ ISSUE 31: where the read takes each lane's length the executable has no
 bucket) and at two buckets (what a platform without the kernel's shapes
 still warms). With ``--hlo-dir`` the output also says whether the
 ``while`` bodies at ``None`` and at the first bucket differ in anything
-but constants.
+but constants. With ``--against DIR`` beside it, each HLO is compared with
+the one of its name that another tree's ``--hlo-dir DIR`` kept: one
+program where they are equal once what only names the source is left out
+(the tables of files, functions and stack frames at the head, every op's
+``metadata={...}``, and the locations inside each Mosaic kernel's
+serialised body: an edit above a kernel in its file moves every line
+number in the bytecode), else FAIL. A change that must leave a
+configuration's burst alone shows it so.
 
     python tools/burst_hlo_check.py                      # on the chip
     python tools/burst_hlo_check.py --described v5e:2x2  # no chip: the TPU
@@ -50,6 +57,7 @@ A skip is not a pass.
 from __future__ import annotations
 
 import argparse
+import base64
 import collections
 import json
 import os
@@ -69,6 +77,8 @@ OFFENDERS = ("copy", "copy-start", "slice", "slice-start")
 TEMP_BEFORE = {"internlm2-1.8b": 689441280, "mistral-7b-v0.3": 1332189696}
 _INSTR_RE = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = (.*?) ([\w\-]+)\(")
 _CALLED_RE = re.compile(r"(?:body|condition|to_apply|calls)=%?([\w.\-]+)")
+_TABLE_RE = re.compile(r"^(FileNames|FunctionNames|FileLocations|StackFrames)")
+_BODY_RE = re.compile(r'"body":"([A-Za-z0-9+/=]+)"')
 
 
 def computations(hlo: str) -> dict:
@@ -213,6 +223,51 @@ def while_body_diff(hlo_a: str, hlo_b: str) -> list:
                   + ["+ " + l for l in (b - a).elements()])
 
 
+def kernel_text(body: str) -> str:
+    """A Mosaic kernel's MLIR, from the custom call's base64 bytecode,
+    without debug locations."""
+    from jax._src import tpu_custom_call  # noqa: F401  (loads the dialects)
+    from jax._src.lib import tpu
+    from jax._src.lib.mlir import ir
+
+    ctx = ir.Context()
+    tpu.register_dialect(ctx)
+    ctx.allow_unregistered_dialects = True
+    with ctx:
+        module = ir.Module.parse(base64.b64decode(body))
+        return module.operation.get_asm(enable_debug_info=False)
+
+
+def without_source_names(hlo: str) -> tuple:
+    """``(lines without metadata and the head's tables, kernel bodies in
+    order)``."""
+    hlo = re.sub(r",? ?metadata=\{[^}]*\}", "", hlo)
+    bodies = _BODY_RE.findall(hlo)
+    hlo = _BODY_RE.sub('"body":"<kernel>"', hlo)
+    lines, in_table = [], False
+    for line in hlo.splitlines():
+        if _TABLE_RE.match(line):
+            in_table = True
+        elif in_table:
+            in_table = bool(line.strip())
+        else:
+            lines.append(line)
+    return lines, bodies
+
+
+def same_program(hlo_a: str, hlo_b: str) -> dict:
+    """Whether two optimised HLO texts are one program but for what names
+    the source: the lines, and the kernels decoded without locations."""
+    (a, a_k), (b, b_k) = without_source_names(hlo_a), without_source_names(hlo_b)
+    kernels = len(a_k) == len(b_k) and all(
+        x == y or kernel_text(x) == kernel_text(y) for x, y in zip(a_k, b_k))
+    first = next(([x[:240], y[:240]] for x, y in zip(a, b) if x != y), None)
+    return {"lines": len(a), "kernels": len(a_k),
+            "hlo_equal_but_for_metadata": a == b,
+            "kernels_equal_but_for_locations": kernels,
+            "first_differing_line": first}
+
+
 def alias_count(hlo: str) -> int:
     """Entries of the module's ``input_output_alias={ {3}: (12, {}, may-alias), ...}``."""
     header = hlo.split("\n", 1)[0]
@@ -316,7 +371,12 @@ def main(argv=None) -> int:
     ap.add_argument("--described", metavar="TOPOLOGY",
                     help="compile for a described TPU (e.g. v5e:2x2), no chip")
     ap.add_argument("--hlo-dir", help="keep each optimised HLO here")
+    ap.add_argument("--against", metavar="DIR",
+                    help="another tree's --hlo-dir: each HLO kept here must "
+                         "be the program of its name there")
     args = ap.parse_args(argv)
+    if args.against and not args.hlo_dir:
+        ap.error("--against compares what --hlo-dir keeps")
 
     if args.described:
         os.environ.setdefault("TPU_LOG_DIR", "disabled")
@@ -348,6 +408,14 @@ def main(argv=None) -> int:
                         args.hlo_dir, TEMP_BEFORE.get(cfg["name"]))
             print(json.dumps({"config": cfg["name"], **out}))
             ok = ok and out["ok"]
+            if args.against:
+                name = f"{cfg['name']}.{attn_len}.hlo.txt"
+                with open(os.path.join(args.hlo_dir, name)) as f, \
+                        open(os.path.join(args.against, name)) as g:
+                    same = same_program(g.read(), f.read())
+                print(json.dumps({"config": cfg["name"], "against": name, **same}))
+                ok = (ok and same["hlo_equal_but_for_metadata"]
+                      and same["kernels_equal_but_for_locations"])
         buckets = [a for a in args.attn_len if a is not None]
         if args.hlo_dir and None in args.attn_len and buckets:
             texts = []
