@@ -39,7 +39,7 @@ Model URI layout: same ``jax_config.json`` as jaxserver with
                      staged swap/drain) and byte-identity on vs off is
                      the contract — see docs/generate.md "Fused decode"
     pipeline_depth   bursts in flight before the host reads the oldest
-                     (default 3; 1 = synchronous)
+                     (default 2: one queued; 1 = synchronous)
     speculate_tokens speculative decoding: draft this many tokens per
                      round, verify with one target forward (0 = off).
                      Exact for any draft — greedy lanes reproduce the
@@ -233,7 +233,7 @@ class GenerateServer(SeldonComponent):
         mesh_shape: Optional[str] = None,
         steps_per_poll: int = 8,
         fused_steps_per_dispatch: int = 0,
-        pipeline_depth: int = 3,
+        pipeline_depth: int = 2,
         attn_bucket: int = 128,
         speculate_tokens: int = 0,
         draft_layers: int = 0,

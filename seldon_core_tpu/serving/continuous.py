@@ -19,15 +19,15 @@ calls"). This scheduler closes that gap the TPU way:
   device call (``lax.scan`` over the fused step), so the host syncs once
   per burst — not once per token. Dispatch/sync latency is the decode
   bottleneck off-device; this amortises it k-fold.
-* Bursts are **software-pipelined** (``pipeline_depth``): the scheduler
-  dispatches burst N+1 (and starts its device→host token copy with
-  ``copy_to_host_async``) before reading burst N's tokens, so the device
-  never idles waiting on the host sync round-trip. Decode state lives on
-  device across bursts, so correctness only needs the host to *observe*
-  tokens late: each dispatch snapshots which request occupied each lane,
-  and tokens from a burst are credited strictly to that snapshot (a lane
-  that finished mid-pipeline just decodes a few ignored tokens before the
-  host notices and re-admits).
+* Bursts are **software-pipelined** (``pipeline_depth``, default 2): burst
+  N+1 is dispatched (its token copy started, ``copy_to_host_async``)
+  before burst N is read, so ONE burst is queued behind the running one.
+  That covers a host turn (read, credit, admit, dispatch) shorter than a
+  burst; a second queued burst hides nothing more and adds a burst period
+  to every token's delivery. Decode state lives on device, so the host
+  only *observes* tokens late: each dispatch snapshots which request held
+  each lane and a burst's tokens are credited strictly to that snapshot
+  (a lane that finished mid-pipeline decodes a few ignored tokens).
 * The KV cache is held as per-layer arrays and updated IN PLACE: only the
   one-position write touches HBM per step (a scatter, or the decode
   kernel's own copy where ``reads_ragged`` holds; a stacked cache threaded
@@ -368,7 +368,7 @@ class ContinuousBatcher:
         shard_cache_seq: bool = False,
         prefill_buckets: Sequence[int] = (32, 128, 512, 1024, 1792),
         steps_per_poll: int = 8,
-        pipeline_depth: int = 3,
+        pipeline_depth: int = 2,
         attn_bucket: int = 128,
         fused_steps_per_dispatch: int = 0,
         draft_model=None,
@@ -438,8 +438,8 @@ class ContinuousBatcher:
         # match the host's view; membership changes and mode flips clear
         # it so the next fused dispatch re-uploads (never per burst)
         self._fused_sync = False
-        # how many bursts may be in flight before the host reads the oldest
-        # one's tokens; 1 = fully synchronous (dispatch, read, dispatch ...)
+        # bursts in flight before the host blocks on the oldest: 1 = fully
+        # synchronous (dispatch, read, ...), 2 = one queued behind the running
         self.pipeline_depth = max(1, int(pipeline_depth))
         # attention-read bucket granularity: the per-burst cache read is
         # rounded up to a multiple of this. Smaller = tighter KV reads at
@@ -681,11 +681,11 @@ class ContinuousBatcher:
         # first_dispatch_t, first_tok_t, done_t, FrontStamps). Always on;
         # nothing per token. :meth:`capture_requests` names the fields.
         self.timeline_recent: "collections.deque" = collections.deque(maxlen=2048)
-        # the scheduler loop's own time: working polls, bursts read back,
-        # their dispatch-to-host-read seconds summed, and the
-        # loop_<phase>_s seconds the phase clock keeps
+        # the scheduler loop's own time: working polls, bursts read back (and
+        # those the device had finished first), dispatch-to-read seconds summed
         self.stats.update({
-            "polls": 0, "bursts": 0, "burst_read_lag_s_sum": 0.0,
+            "polls": 0, "bursts": 0, "bursts_read_late": 0,
+            "burst_read_lag_s_sum": 0.0,
         })
         self._clock = PhaseClock(self.stats, "batcher", "loop", LOOP_PHASES)
         # per-tenant splits of the same samples (multi-tenant serving):
@@ -4127,6 +4127,12 @@ class ContinuousBatcher:
         time)``; the ``np.asarray`` here is the burst's one host sync
         (phase ``read_wait``), everything after it is ``credit``."""
         mode, arrays, rest, t_dispatch = entry
+        # the device had finished this burst before the host came for it: the
+        # host was the one waited for (an attribute check, no sync; an array
+        # without is_ready, a test double's, is ready as the loop reads it)
+        ready = getattr(arrays[-1], "is_ready", None)
+        if ready is None or ready():
+            self.stats["bursts_read_late"] += 1
         self._clock.to("read_wait")
         host = [np.asarray(a) for a in arrays]
         t_read = self._clock.to("credit")

@@ -164,37 +164,120 @@ def test_seed_reproducible_across_cotenants(model_and_params):
         b.close()
 
 
-def test_pipeline_depths_equivalent(model_and_params):
-    """Software-pipelined bursts (depth>1) must emit exactly the tokens of
-    the synchronous scheduler (depth=1) under heavy churn: more requests
-    than slots, staggered submission, early EOS, mixed lengths."""
+def _churn(model, params, **kw):
+    """More requests than slots, staggered submission, mixed lengths: the
+    results in submission order."""
     import time
 
-    model, params = model_and_params
     rng = np.random.RandomState(7)
     prompts = [rng.randint(0, 256, n).tolist() for n in (3, 9, 5, 14, 4, 6, 11, 2)]
-    kws = [
-        dict(max_new_tokens=m, eos_id=e)
-        for m, e in ((7, None), (3, None), (12, None), (5, None),
-                     (9, None), (2, None), (6, None), (10, None))
-    ]
-    results = {}
-    for depth in (1, 4):
-        b = ContinuousBatcher(
-            model, params, slots=3, max_seq=64, prefill_buckets=(8, 16),
-            steps_per_poll=2, pipeline_depth=depth,
-        )
-        try:
-            futures = []
-            for i, (p, kw) in enumerate(zip(prompts, kws)):
-                futures.append(b.submit(p, **kw))
-                if i % 3 == 2:
-                    time.sleep(0.05)  # stagger admissions mid-decode
-            results[depth] = [f.result(timeout=120) for f in futures]
-            assert b.stats["finished"] == len(prompts)
-        finally:
-            b.close()
-    assert results[1] == results[4]
+    b = ContinuousBatcher(
+        model, params, slots=3, max_seq=64, prefill_buckets=(8, 16),
+        steps_per_poll=2, **kw,
+    )
+    try:
+        futures = []
+        for i, (p, m) in enumerate(zip(prompts, (7, 3, 12, 5, 9, 2, 6, 10))):
+            futures.append(b.submit(p, max_new_tokens=m))
+            if i % 3 == 2:
+                time.sleep(0.05)  # stagger admissions mid-decode
+        results = [f.result(timeout=120) for f in futures]
+        assert b.stats["finished"] == len(prompts)
+    finally:
+        b.close()
+    return results
+
+
+@pytest.fixture(scope="module")
+def churn_synchronous(model_and_params):
+    return _churn(*model_and_params, pipeline_depth=1)
+
+
+@pytest.mark.parametrize("depth", [None, 2, 3, 4])
+def test_pipeline_depths_equivalent(model_and_params, churn_synchronous, depth):
+    """Software-pipelined bursts (the default depth, and 2 to 4) must emit
+    exactly the tokens of the synchronous scheduler (depth=1) under heavy
+    churn: depth decides when the host LOOKS, not what the device computes."""
+    kw = {} if depth is None else {"pipeline_depth": depth}
+    assert _churn(*model_and_params, **kw) == churn_synchronous
+
+
+class _HeldTokens:
+    """A burst's token array as the loop sees a device array: ``is_ready``
+    says what the test wants, the values are the real ones."""
+
+    def __init__(self, array, ready):
+        self._array, self._ready = array, ready
+
+    def is_ready(self):
+        return self._ready
+
+    def copy_to_host_async(self):
+        pass
+
+    def __array__(self, dtype=None, copy=None):
+        return np.asarray(self._array, dtype=dtype)
+
+
+def _loaded_loop(model, params, ready, **kw):
+    """Six requests over two lanes with every burst's tokens held behind
+    ``_HeldTokens(ready)``: (stats, the flight recorder's polls, results,
+    the counters a capture would read)."""
+    b = ContinuousBatcher(
+        model, params, slots=2, max_seq=64, prefill_buckets=(8,),
+        steps_per_poll=2, **kw,
+    )
+    burst = b._burst_fn
+
+    def held(*args):
+        toks, *rest = burst(*args)
+        return (_HeldTokens(toks, ready), *rest)
+
+    b._burst_fn = held
+    try:
+        futures = [b.submit([3 + i, 17, 42], max_new_tokens=16) for i in range(6)]
+        results = [f.result(timeout=120) for f in futures]
+    finally:
+        b.close()
+    polls = [e for e in b.flight.snapshot() if e.get("type") == "poll"]
+    return dict(b.stats), polls, results, b.capture_counters()["counters"]
+
+
+def test_default_depth_holds_two_bursts(model_and_params):
+    """At the default depth a loaded loop holds one burst behind the running
+    one and no more: with tokens that are never ready before the blocking
+    read, ``pending_bursts`` reaches 2 and never 3, at depth 3 it reaches 3,
+    and the tokens are the same."""
+    model, params = model_and_params
+    _, polls, results, _ = _loaded_loop(model, params, ready=False)
+    assert max(e["pending_bursts"] for e in polls) == 2
+    _, polls3, results3, _ = _loaded_loop(
+        model, params, ready=False, pipeline_depth=3)
+    assert max(e["pending_bursts"] for e in polls3) == 3
+    assert results == results3
+
+
+def test_default_depth_is_two():
+    import inspect
+
+    from seldon_core_tpu.servers.generateserver import GenerateServer
+
+    for cls in (ContinuousBatcher, GenerateServer):
+        assert inspect.signature(cls.__init__).parameters[
+            "pipeline_depth"].default == 2, cls
+
+
+@pytest.mark.parametrize("ready", [False, True], ids=["never_ready", "always_ready"])
+def test_bursts_read_late(model_and_params, ready):
+    """``bursts_read_late`` counts the bursts the device had finished before
+    the host came to read them: none where the tokens are never ready before
+    the read, every burst where they always are; ``capture.json`` gets it
+    with every numeric entry of ``stats``."""
+    stats, _, _, counters = _loaded_loop(*model_and_params, ready=ready)
+    assert stats["bursts"] > 8
+    assert stats["bursts_read_late"] == (stats["bursts"] if ready else 0)
+    assert counters["bursts_read_late"] == stats["bursts_read_late"]
+    assert counters["bursts"] == stats["bursts"]
 
 
 def test_eos_equivalent_across_depths(model_and_params):
