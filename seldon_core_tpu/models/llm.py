@@ -62,6 +62,8 @@ class LLMConfig:
     # norms a layer, normed and gated heads, a scaled embedding). The
     # kinds are resolved when the model is built, never in a traced
     # function; DecoderLM(block="afmoe", ...) builds that class.
+    # "qwen3_next": Gated DeltaNet layers beside gated softmax attention
+    # and a chip's share of softmax-routed experts (models/qwen3_next.py).
     block: str = "llama"
     # per layer "sliding_attention" | "full_attention"; None = all full
     layer_types: Optional[Tuple[str, ...]] = None
@@ -72,12 +74,26 @@ class LLMConfig:
     expert_width: int = 0         # FFN width of one expert
     n_shared_experts: int = 0     # experts every token takes, beside them
     route_scale: float = 1.0
+    # -- the qwen3_next block only -------------------------------------
+    # per layer "linear_attention" | "full_attention" in layer_types
+    linear_key_heads: int = 0
+    linear_value_heads: int = 0
+    linear_key_dim: int = 0
+    linear_value_dim: int = 0
+    linear_conv_kernel: int = 0
+    partial_rotary_factor: float = 1.0   # share of a head's dims rotated
+    shared_expert_width: int = 0
+    # (lo, n): this chip holds experts lo .. lo + n - 1 of the
+    # n_routed_experts the router ranges over; None: all of them
+    experts_held: Optional[Tuple[int, int]] = None
 
     def __post_init__(self):
         if not self.head_dim:
             self.head_dim = self.d_model // self.n_heads
         if self.layer_types is not None:
             self.layer_types = tuple(self.layer_types)
+        if self.experts_held is not None:
+            self.experts_held = tuple(int(n) for n in self.experts_held)
 
 
 def _rms_norm(x, w, eps=1e-5):
@@ -134,11 +150,13 @@ class DecoderLM(ServedModel):
 
     def __new__(cls, **config):
         if cls is DecoderLM and config.get("block", "llama") != "llama":
-            if config["block"] != "afmoe":
+            if config["block"] == "afmoe":
+                from .afmoe import AfmoeLM as family
+            elif config["block"] == "qwen3_next":
+                from .qwen3_next import Qwen3NextLM as family
+            else:
                 raise ValueError(f"unknown block variant {config['block']!r}")
-            from .afmoe import AfmoeLM
-
-            return super().__new__(AfmoeLM)
+            return super().__new__(family)
         return super().__new__(cls)
 
     def __init__(self, **config):
@@ -511,6 +529,15 @@ class DecoderLM(ServedModel):
         dt = jnp.dtype(cfg.dtype)
         return {"k": jnp.zeros(shape, dt), "v": jnp.zeros(shape, dt)}
 
+    def cache_layers(self, batch: int, max_seq: Optional[int] = None):
+        """The cache as the serving bursts carry it: a dict of kinds ("k"
+        and "v"; a family may declare more), each a list over the layers
+        that have the kind of one array whose first axis is the lane."""
+        return {
+            name: [kind[l] for l in range(kind.shape[0])]
+            for name, kind in self.init_cache(batch, max_seq).items()
+        }
+
     def _embed_tokens(self, params, tokens):
         import jax.numpy as jnp
 
@@ -670,6 +697,14 @@ class DecoderLM(ServedModel):
             nvs.append(self._tp_cache(nv))
             tie = (nks[-1], nvs[-1])
         return self._decode_head(params, x), nks, nvs
+
+    def decode_step_cache(self, params, cache, tokens, pos, **how):
+        """``decode_step_ragged_list`` over the dict ``cache_layers`` lays
+        out: ``(logits, cache, *counts)``, the one step the serving bursts
+        call whatever kinds a family's cache holds."""
+        logits, ks, vs, *counts = self.decode_step_ragged_list(
+            params, cache["k"], cache["v"], tokens, pos, **how)
+        return (logits, {"k": ks, "v": vs}, *counts)
 
     def decode_chunk_ragged_list(self, params, ks, vs, tokens, pos, attn_len=None):
         """Decode a WINDOW of tokens per lane in ONE forward over the

@@ -8,6 +8,15 @@ drop (``parallel/moe.py`` is the training dry-run's top-1 layer with
 capacity drops; nothing here shares its code).
 
 ``route()``            scores, picks and weights of each row
+``held``               a chip that holds ``n`` of a layer's experts from
+                       ``lo`` on (its share of an expert-parallel layer)
+                       routes over ALL of them and computes its own:
+                       ``grouped_experts(held=(lo, n))`` and
+                       ``decode_experts(held=(lo, n))`` drop the picks
+                       that land elsewhere before anything is grouped or
+                       counted as touched, so no expert is read and no
+                       row multiplied for them. What the other chips would
+                       add is not here; nothing stands in for it
 ``grouped_experts()``  any number of rows: sort the (row, pick) pairs by
                        expert, three grouped matmuls (``lax.ragged_dot``:
                        on a TPU the compiler's own Mosaic grouped matmul,
@@ -51,21 +60,41 @@ DECODE_TF = 512
 DECODE_VMEM_BYTES = 48 << 20
 
 
-def route(x, router, bias, k: int, scale: float):
-    """x [N, D], router [D, E], bias [E] -> picks [N, k] int32 and weights
-    [N, k] float32. Scores are ``sigmoid`` of the float32 router logits;
-    the bias enters the SELECTION only; the weights are the picked scores
+def route(x, router, bias, k: int, scale: float, score: str = "sigmoid"):
+    """x [N, D], router [D, E], bias [E] or None -> picks [N, k] int32 and
+    weights [N, k] float32. Scores are ``sigmoid`` of the float32 router
+    logits, or with ``score="softmax"`` their softmax over all E; the bias
+    enters the SELECTION only; the weights are the picked scores
     normalised to sum to ``scale``."""
     logits = jnp.dot(x, router.astype(x.dtype),
                      preferred_element_type=jnp.float32)
-    s = jax.nn.sigmoid(logits)
-    _, picks = lax.top_k(s + bias.astype(jnp.float32), k)
+    if score == "softmax":
+        s = jax.nn.softmax(logits, axis=-1)
+    elif score == "sigmoid":
+        s = jax.nn.sigmoid(logits)
+    else:
+        raise ValueError(f"unknown score function {score!r}")
+    _, picks = lax.top_k(s if bias is None else s + bias.astype(jnp.float32), k)
     sel = jnp.take_along_axis(s, picks, axis=-1)
     w = sel / (sel.sum(-1, keepdims=True) + 1e-20) * scale
     return picks.astype(jnp.int32), w
 
 
-def _grouped(x, picks, weights, w1, w3, w2):
+def localise(picks, weights, held, n_experts: int):
+    """Picks over a whole layer -> picks into the ``n_experts`` held from
+    ``held[0]`` on. A pick that lands elsewhere becomes ``n_experts`` (no
+    expert's id: it matches none, sorts last and joins no group) with
+    weight 0."""
+    lo, n = held
+    if n != n_experts:
+        raise ValueError(f"held {held} but the stacks hold {n_experts}")
+    local = picks - lo
+    here = (local >= 0) & (local < n)
+    return (jnp.where(here, local, n).astype(picks.dtype),
+            jnp.where(here, weights, 0.0))
+
+
+def _grouped(x, picks, weights, w1, w3, w2, dropped: bool = False):
     n, d = x.shape
     k = picks.shape[1]
     n_experts = w1.shape[0]
@@ -79,26 +108,36 @@ def _grouped(x, picks, weights, w1, w3, w2):
     g = lax.ragged_dot(xs, w3, sizes, preferred_element_type=jnp.float32)
     h = (jax.nn.silu(a) * g).astype(x.dtype)
     y = lax.ragged_dot(h, w2, sizes, preferred_element_type=jnp.float32)
-    y = (y * weights.reshape(-1)[order][:, None]).astype(x.dtype)
+    by_expert = weights.reshape(-1)[order][:, None]
+    if dropped:
+        # rows past the last group belong to no expert and were given to
+        # none: whatever lies there is not a product
+        y = jnp.where(flat[order][:, None] < n_experts, y, 0.0)
+    y = (y * by_expert).astype(x.dtype)
     back = jnp.argsort(order)                             # the unsort
     return y[back].reshape(n, k, d).astype(jnp.float32).sum(axis=1)
 
 
-@functools.partial(jax.jit, static_argnames=("group_rows",))
-def grouped_experts(x, picks, weights, w1, w3, w2, group_rows: int = GROUP_ROWS):
+@functools.partial(jax.jit, static_argnames=("group_rows", "held"))
+def grouped_experts(x, picks, weights, w1, w3, w2, group_rows: int = GROUP_ROWS,
+                    held=None):
     """x [N, D] rows, picks / weights [N, k] (``route()``), the stacked
     experts -> float32 [N, D]: sum over a row's picks of weight x expert(x).
     Drop-free whatever the picks. More than ``group_rows`` rows go through
-    in equal groups of at most that many."""
+    in equal groups of at most that many. ``held=(lo, n)``: the stacks are
+    experts ``lo .. lo + n - 1`` of the layer the picks range over, and a
+    pick outside them adds nothing (``localise()``)."""
     n = x.shape[0]
+    if held is not None:
+        picks, weights = localise(picks, weights, held, w1.shape[0])
     if n <= group_rows:
-        return _grouped(x, picks, weights, w1, w3, w2)
+        return _grouped(x, picks, weights, w1, w3, w2, held is not None)
     groups = -(-n // group_rows)
     while n % groups:
         groups += 1
     split = lambda a: a.reshape(groups, n // groups, *a.shape[1:])  # noqa: E731
     out = lax.map(
-        lambda r: _grouped(r[0], r[1], r[2], w1, w3, w2),
+        lambda r: _grouped(r[0], r[1], r[2], w1, w3, w2, held is not None),
         (split(x), split(picks), split(weights)))
     return out.reshape(n, x.shape[1])
 
@@ -203,13 +242,17 @@ def decodes_touched(platform, x_shape, w1_shape, mesh=None) -> bool:
     )
 
 
-@functools.partial(jax.jit, static_argnames=("mesh",))
-def decode_experts(x, picks, weights, live, w1, w3, w2, mesh=None):
+@functools.partial(jax.jit, static_argnames=("mesh", "held"))
+def decode_experts(x, picks, weights, live, w1, w3, w2, mesh=None, held=None):
     """One decode step's routed experts. x [B, D]; picks, weights [B, k];
     live [B] bool. Returns ``(float32 [B, D], experts touched, rows
     routed)``: an idle lane adds nothing to either count, reads no expert
-    and gets zeros."""
+    and gets zeros. ``held=(lo, n)`` as ``grouped_experts()`` takes it: a
+    pick that lands elsewhere touches nothing here; rows routed still
+    counts every pick of a live lane."""
     weights = jnp.where(live[:, None], weights, 0.0)
+    if held is not None:
+        picks, weights = localise(picks, weights, held, w1.shape[0])
     ids, n = touched_experts(picks, live, w1.shape[0])
     routed = live.sum(dtype=jnp.int32) * picks.shape[1]
 
@@ -217,7 +260,10 @@ def decode_experts(x, picks, weights, live, w1, w3, w2, mesh=None):
         return touched_experts_ffn(x, picks, weights, ids, n, w1, w3, w2)
 
     def grouped(x, picks, weights, ids, n, w1, w3, w2):
-        return grouped_experts(x, picks, weights, w1, w3, w2)
+        if held is None:
+            return grouped_experts(x, picks, weights, w1, w3, w2)
+        # the picks are local already
+        return _grouped(x, picks, weights, w1, w3, w2, True)
 
     args = (x, picks, weights, ids, n, w1, w3, w2)
     if not decodes_touched("tpu", x.shape, w1.shape, mesh):

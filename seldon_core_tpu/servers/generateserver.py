@@ -487,6 +487,7 @@ class GenerateServer(SeldonComponent):
                 speculation=self._speculate_tokens > 0,
                 mesh=self._mesh is not None or self._mesh_shape is not None,
                 kv_tier=self._host_kv_tier_bytes > 0,
+                migration=self._role != "unified",
             )
         if self._mesh is None and self._mesh_shape is not None:
             # build the serving mesh from the knob: an injected mesh

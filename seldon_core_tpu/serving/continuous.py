@@ -399,12 +399,14 @@ class ContinuousBatcher:
         # a family refuses, typed and at load, the features it has no
         # path for (DecoderLM.serving_refuses) rather than computing
         # something else under them
-        if hasattr(model, "check_serves"):
-            model.check_serves(
-                speculation=draft_model is not None,
-                mesh=mesh is not None,
-                kv_tier=int(host_kv_tier_bytes) > 0,
-            )
+        self._check_family_serves(
+            speculation=draft_model is not None,
+            mesh=mesh is not None,
+            kv_tier=int(host_kv_tier_bytes) > 0,
+            prefix_cache=int(prefix_cache_hbm_bytes) > 0,
+            chunked_prefill=int(prefill_chunk) > 0,
+            preemption=int(hbm_ledger_bytes) > 0 or int(swap_drain_ms) > 0,
+        )
         self.slots = int(slots)
         self.max_seq = int(max_seq or model.cfg.max_seq)
         self.mesh = mesh
@@ -847,12 +849,11 @@ class ContinuousBatcher:
             return NamedSharding(mesh, P(None, model_ax, seq_ax, None))
 
         def unstack_cache(owner, sharding):
-            stacked = owner.init_cache(self.slots, self.max_seq)
-            n_layers = stacked["k"].shape[0]
-            out = {
-                "k": [stacked["k"][l] for l in range(n_layers)],
-                "v": [stacked["v"][l] for l in range(n_layers)],
-            }
+            """The cache the bursts carry, as the model lays it out
+            (``cache_layers``): a dict of kinds, each a list over the
+            layers that have that kind of one array whose first axis is
+            the lane."""
+            out = owner.cache_layers(self.slots, self.max_seq)
             if sharding is not None:
                 out = jax.tree_util.tree_map(
                     lambda a: jax.device_put(a, sharding), out
@@ -969,27 +970,32 @@ class ContinuousBatcher:
         for name in self._step_counters:
             self.stats.setdefault(name, 0)
 
-        def fused_step(params, ks, vs, cur_tok, pos, active, temps, keys, attn_len):
-            logits, ks, vs, *counts = model.decode_step_ragged_list(
-                params, ks, vs, cur_tok[:, None], pos, attn_len=attn_len,
+        def fused_step(params, cache, cur_tok, pos, active, temps, keys, attn_len):
+            logits, cache, *counts = model.decode_step_cache(
+                params, cache, cur_tok[:, None], pos, attn_len=attn_len,
                 lens=jnp.where(active, pos + 1, 0),
             )
             keys, nxt = sample_next(keys, logits, temps)
             nxt = jnp.where(active, nxt, 0)
             pos = jnp.where(active, pos + 1, pos)
-            return (nxt, pos, ks, vs, keys, *counts)
+            return (nxt, pos, cache, keys, *counts)
+
+        def at_lane(layer, rows, slot):
+            """``rows`` [m, ...] written into ``layer`` [S, ...] from lane
+            ``slot`` on, from 0 along every other axis: a prompt's keys
+            fill [slot, :, :bucket], a lane's state its whole row."""
+            return lax.dynamic_update_slice(
+                layer, rows, (slot,) + (0,) * (layer.ndim - 1))
 
         def insert(cache, cache_one, slot, first_tok, first_pos, lane_key, cur_tok, pos, keys):
             # cache_one is the prefill's stacked [L, 1, KV, Tb, Dh] slab;
             # each layer's slice lands in that layer's cache at `slot`
             new = {
                 name: [
-                    lax.dynamic_update_slice(
-                        layer, cache_one[name][l], (slot, 0, 0, 0)
-                    )
-                    for l, layer in enumerate(cache[name])
+                    at_lane(layer, cache_one[name][l], slot)
+                    for l, layer in enumerate(layers)
                 ]
-                for name in ("k", "v")
+                for name, layers in cache.items()
             }
             cur_tok = cur_tok.at[slot].set(first_tok)
             pos = pos.at[slot].set(first_pos)
@@ -1032,20 +1038,12 @@ class ContinuousBatcher:
             # row i lands in its lane slot_ix[i] (traced start indices —
             # one executable per (m, bucket), not per slot assignment)
             m = slab["k"].shape[1]
-            new = {
-                name: [
-                    layer
-                    for layer in cache[name]
-                ]
-                for name in ("k", "v")
-            }
+            new = {name: list(layers) for name, layers in cache.items()}
             for i in range(m):
-                for name in ("k", "v"):
-                    for l in range(len(new[name])):
-                        new[name][l] = lax.dynamic_update_slice(
-                            new[name][l], slab[name][l, i:i + 1],
-                            (slot_ix[i], 0, 0, 0),
-                        )
+                for name, layers in new.items():
+                    for l in range(len(layers)):
+                        layers[l] = at_lane(
+                            layers[l], slab[name][l, i:i + 1], slot_ix[i])
             cur_tok = cur_tok.at[slot_ix].set(firsts)
             pos = pos.at[slot_ix].set(first_pos)
             keys = keys.at[slot_ix].set(lane_keys)
@@ -1061,23 +1059,23 @@ class ContinuousBatcher:
             one executable per k."""
 
             def body(carry, _):
-                ks, vs, cur_tok, pos, keys = carry
-                nxt, pos, ks, vs, keys, *counts = fused_step(
-                    params, ks, vs, cur_tok, pos, active, temps, keys, attn_len
+                cache, cur_tok, pos, keys = carry
+                nxt, pos, cache, keys, *counts = fused_step(
+                    params, cache, cur_tok, pos, active, temps, keys, attn_len
                 )
-                return (ks, vs, nxt, pos, keys), (nxt, *counts)
+                return (cache, nxt, pos, keys), (nxt, *counts)
 
-            (ks, vs, cur_tok_out, pos, keys), (toks, *counts) = lax.scan(
-                body, (cache["k"], cache["v"], cur_tok, pos, keys), None, length=k
+            (cache, cur_tok_out, pos, keys), (toks, *counts) = lax.scan(
+                body, (cache, cur_tok, pos, keys), None, length=k
             )
             # row 0 = the tokens the burst STARTED from (deferred prefill
             # firsts ride home with the burst's one sync)
             toks = jnp.concatenate([cur_tok[None, :], toks], axis=0)
-            return (toks, cur_tok_out, pos, {"k": ks, "v": vs}, keys,
+            return (toks, cur_tok_out, pos, cache, keys,
                     *(c.sum(axis=0) for c in counts))
 
         # -- stop-aware fused multi-step decode ------------------------------
-        def fused_masked_step(params, ks, vs, cur_tok, pos, alive, temps,
+        def fused_masked_step(params, cache, cur_tok, pos, alive, temps,
                               keys, attn_len, park):
             """One decode step under a per-lane ``alive`` mask: finished
             lanes' K/V writes park OUT OF BOUNDS at ``park`` (dropped, by
@@ -1092,14 +1090,14 @@ class ContinuousBatcher:
             does): a frozen lane's key is dead state its next occupant's
             insert overwrites."""
             wpos = jnp.where(alive, pos, park)
-            logits, ks, vs, *counts = model.decode_step_ragged_list(
-                params, ks, vs, cur_tok[:, None], pos, attn_len=attn_len,
+            logits, cache, *counts = model.decode_step_cache(
+                params, cache, cur_tok[:, None], pos, attn_len=attn_len,
                 write_pos=wpos, lens=jnp.where(alive, pos + 1, 0),
             )
             keys, nxt = sample_next(keys, logits, temps)
             cur_tok = jnp.where(alive, nxt, cur_tok)
             pos = jnp.where(alive, pos + 1, pos)
-            return (cur_tok, pos, ks, vs, keys, *counts)
+            return (cur_tok, pos, cache, keys, *counts)
 
         def fused_stop_burst(params, cache, cur_tok, pos, active, temps,
                              keys, stops, budgets, k, attn_len):
@@ -1120,14 +1118,14 @@ class ContinuousBatcher:
             park = cache["k"][0].shape[2]  # static: index >= T is dropped
 
             def body(carry, _):
-                ks, vs, cur, p, kk, budget, done = carry
+                cache, cur, p, kk, budget, done = carry
                 alive = active & ~done
-                cur, p, ks, vs, kk, *counts = fused_masked_step(
-                    params, ks, vs, cur, p, alive, temps, kk, attn_len, park
+                cur, p, cache, kk, *counts = fused_masked_step(
+                    params, cache, cur, p, alive, temps, kk, attn_len, park
                 )
                 budget = budget - alive.astype(jnp.int32)
                 done = done | (alive & ((cur == stops) | (budget <= 0)))
-                return (ks, vs, cur, p, kk, budget, done), (
+                return (cache, cur, p, kk, budget, done), (
                     jnp.where(alive, cur, 0), alive, *counts,
                 )
 
@@ -1136,17 +1134,16 @@ class ContinuousBatcher:
             # lag), or its budget was fully covered — either way it runs
             # zero steps here instead of overshoot-decoding
             done0 = ~active | (budgets <= 0) | (cur_tok == stops)
-            (ks, vs, cur, pos, keys, budgets, done), (toks, alive_rows, *extra) = (
+            (cache, cur, pos, keys, budgets, done), (toks, alive_rows, *extra) = (
                 lax.scan(
                     body,
-                    (cache["k"], cache["v"], cur_tok, pos, keys, budgets,
-                     done0),
+                    (cache, cur_tok, pos, keys, budgets, done0),
                     None, length=k,
                 )
             )
             counts = alive_rows.astype(jnp.int32).sum(axis=0)
             toks = jnp.concatenate([cur_tok[None, :], toks], axis=0)
-            return (toks, counts, done, cur, pos, {"k": ks, "v": vs}, keys,
+            return (toks, counts, done, cur, pos, cache, keys,
                     budgets, *(c.sum(axis=0) for c in extra))
 
         # -- prefix-cache executables ---------------------------------------
@@ -1880,6 +1877,7 @@ class ContinuousBatcher:
         from .disagg import prompt_hash
 
         self._check_alive()
+        self._check_family_serves(migration=True)
         n = len(tokens)
         if not n:
             raise ValueError("empty prompt")
@@ -2045,6 +2043,7 @@ class ContinuousBatcher:
         from .disagg import prompt_hash as _phash
 
         self._check_alive()
+        self._check_family_serves(migration=True)
         if self.speculate_tokens > 0:
             raise DisaggError(
                 "remote admits are not supported with speculative "
@@ -2196,6 +2195,13 @@ class ContinuousBatcher:
                 "— admissions resume on the next poll"
             )
 
+    def _check_family_serves(self, **asked: bool) -> None:
+        """A request that needs what the model's family has no path for
+        (``DecoderLM.serving_refuses``) is refused typed where it comes
+        in, as the constructor refuses a setting."""
+        if hasattr(self.model, "check_serves"):
+            self.model.check_serves(**asked)
+
     @caller_thread
     def submit_checkpoint(self, ck: Dict[str, Any], on_tokens=None) -> Future:
         """Admit a wire checkpoint (an SGC1 dict — a drained peer's
@@ -2217,6 +2223,7 @@ class ContinuousBatcher:
         from .disagg import WeightVersionMismatch
 
         self._check_alive()
+        self._check_family_serves(preemption=True)
         wv = ck.get("weight_version")
         if wv is not None and wv != self.weight_version:
             raise WeightVersionMismatch(

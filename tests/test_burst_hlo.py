@@ -255,3 +255,39 @@ def test_burst_at_full_depth_takes_no_more_scratch_than_before(
     assert out["input_output_aliases"] >= out["cache_leaves"] == 2 * layers
     assert out["temp_size_in_bytes"] <= out["temp_size_before"]
     assert out["ok"], out
+
+
+def test_qwen3_next_burst_compiled_for_v5e_is_kernels_over_a_cache_in_place(one_chip):
+    """The configuration's own burst (its lanes, all 8 layers, no bucket):
+    the two full layers decode through the ragged kernel at a head of 256
+    with 8 query heads a KV head, every linear layer's state through the
+    state kernel and every layer's held experts through the touched-expert
+    kernel, all inside the ``while``; keys, values, states and tails are
+    aliased through, and nothing of a cache leaf's shape is copied or
+    sliced out."""
+    import re
+
+    tool = _tool()
+    with open(os.path.join(ROOT, "benchmark", "configs",
+                           "qwen3-next-80b-a3b.json")) as f:
+        cfg = json.load(f)
+    cfg["name"] = "qwen3-next-80b-a3b"
+    compiled, (lanes, kv, T, dh), cache_bytes, leaves = tool.compile_burst(
+        cfg, None, one_chip)
+    assert (lanes, kv, T, dh, leaves) == (
+        cfg["server"]["slots"], 2, 4096, 256, 16)
+    # keys and values of 2 layers, state and tail of 6
+    assert cache_bytes == lanes * (2 * 2 * 2 * 4096 * 256 * 2
+                                + 6 * (32 * 128 * 128 * 4 + 3 * 8192 * 2))
+    hlo = compiled.as_text()
+    assert tool.kernel_calls(hlo) == {"inside": 2 + 6 + 8, "outside": 0}
+    names = re.findall(r"%([a-z_]+)[.\d]* = [^\n]*? custom-call\(", hlo)
+    assert names.count("gated_delta_step") == 6
+    assert names.count("touched_experts_ffn") == 8
+    assert tool.cache_shaped(hlo, lanes, kv, (T,), dh) == []
+    assert tool.cache_shaped(hlo, lanes, 32, (128,), 128) == []    # the state
+    assert tool.cache_scatters(hlo, lanes, kv, T, dh) == 0
+    assert tool.alias_count(hlo) >= leaves
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= cache_bytes
+    assert mem.temp_size_in_bytes < 256 << 20

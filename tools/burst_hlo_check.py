@@ -301,16 +301,21 @@ def compile_burst(cfg: dict, attn_len, device_sharding):
     params = jax.tree_util.tree_map(
         lambda a: sds(a.shape, dt), jax.eval_shape(model.init_params, 0)
     )
-    layer = sds((lanes, mc.n_kv_heads, T, mc.head_dim), dt)
-    cache = {"k": [layer] * mc.n_layers, "v": [layer] * mc.n_layers}
+    # the cache as the batcher carries it (``cache_layers``): a dict of
+    # kinds, each a list of one array a layer that has the kind
+    cache = jax.tree_util.tree_map(
+        lambda a: sds(a.shape, a.dtype),
+        jax.eval_shape(lambda: model.cache_layers(lanes, T)))
+    leaves = jax.tree_util.tree_leaves(cache)
     lane_i32 = sds((lanes,), jnp.int32)
     compiled = batcher._burst_fn.lower(
         params, cache, lane_i32, lane_i32, sds((lanes,), jnp.bool_),
         sds((lanes,), jnp.float32), sds((lanes, 2), jnp.uint32),
         batcher._k, attn_len,
     ).compile()
-    cache_bytes = model.kv_bytes_per_token() * lanes * T
-    return compiled, (lanes, mc.n_kv_heads, T, mc.head_dim), cache_bytes
+    cache_bytes = sum(a.size * a.dtype.itemsize for a in leaves)
+    return (compiled, (lanes, mc.n_kv_heads, T, mc.head_dim), cache_bytes,
+            len(leaves))
 
 
 def check(cfg: dict, attn_len, device_sharding, hlo_dir=None,
@@ -318,7 +323,7 @@ def check(cfg: dict, attn_len, device_sharding, hlo_dir=None,
     """``attn_len``: a bucket, or None for the burst without one.
     ``temp_limit``: ``TEMP_BEFORE``'s entry where ``cfg`` has the
     configuration's own depth (``main`` passes it), none for a cut one."""
-    compiled, (lanes, kv, T, dh), cache_bytes = compile_burst(
+    compiled, (lanes, kv, T, dh), cache_bytes, leaves = compile_burst(
         cfg, attn_len, device_sharding)
     hlo = compiled.as_text()
     if hlo_dir:
@@ -334,7 +339,6 @@ def check(cfg: dict, attn_len, device_sharding, hlo_dir=None,
         key = f"{op} {result} {'inside' if inside else 'outside'} the while"
         kinds[key] = kinds.get(key, 0) + 1
     layers = cfg["num_hidden_layers"]
-    leaves = 2 * layers
     aliases = alias_count(hlo)
     kernels = kernel_calls(hlo)
     bucket = bucket_shaped(hlo, lanes, kv, attn_len, dh) if bounded else 0
