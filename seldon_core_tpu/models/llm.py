@@ -135,6 +135,11 @@ class DecoderLM(ServedModel):
     # entries, which the batcher adds into ``stats``. The llama block has
     # none and returns no fourth result.
     step_counter_names: Tuple[str, ...] = ()
+    # likewise for a prefill: a family that names counters here has a
+    # ``prefill_counted`` that returns ``prefill``'s two results and the
+    # int32 vector, which the batcher adds up on the device (its insert)
+    # and brings home beside the next burst it reads
+    prefill_counter_names: Tuple[str, ...] = ()
     # serving features this family has no path for -> why; the batcher
     # refuses them typed at load (``UnsupportedByModel``)
     serving_refuses: Dict[str, str] = {}

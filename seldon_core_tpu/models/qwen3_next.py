@@ -68,6 +68,12 @@ class Qwen3NextLM(DecoderLM):
         "moe_experts_touched", "moe_rows_routed", "moe_layer_steps",
         "moe_rows_held", "gdn_lane_steps",
     )
+    prefill_counter_names = (
+        # per prefill, summed over the layers: the (row, pick) pairs the
+        # grouped experts moved (a share: its room x its passes; all of
+        # them where every expert is held) and the pairs routed
+        "moe_prefill_pairs_moved", "moe_prefill_pairs_routed",
+    )
     serving_refuses = {
         "speculation": "the draft is the first layers of a stacked llama "
                        "block, and a rejected window would have to roll "
@@ -428,10 +434,12 @@ class Qwen3NextLM(DecoderLM):
         o = (o.astype(jnp.float32) * jax.nn.silu(zh)).astype(dt)
         return o.reshape(*z.shape) @ p["w_out"].astype(dt)
 
-    def _moe(self, p, h, live=None):
+    def _moe(self, p, h, live=None, real=None):
         """h [B, T, D] after the mixer -> the layer's output, the picks
         [B, T, k] over ALL experts and, for a decode step (``live`` [B]),
-        (held experts touched, rows routed, rows that landed here)."""
+        (held experts touched, rows routed, rows that landed here); for a
+        prefill, the pairs its grouped experts moved. ``real`` [B, T] bool
+        (a prefill's): the rows that are some sequence's tokens."""
         import jax
         import jax.numpy as jnp
 
@@ -445,10 +453,20 @@ class Qwen3NextLM(DecoderLM):
         picks, weights = experts.route(
             rows, p["router"], None, cfg.experts_per_tok, 1.0, score="softmax")
         stacks = tuple(p[n].astype(dt) for n in ("we1", "we3", "we2"))
-        counts = None
         if live is None:
-            y = experts.grouped_experts(rows, picks, weights, *stacks,
-                                        held=cfg.experts_held)
+            sent = picks
+            if real is not None and cfg.experts_held is not None:
+                # a row of padding computes nothing that is read, and
+                # padding routes together: a bucket's worth of it on a
+                # held expert would overflow the share's room. Its picks
+                # go to no expert's id, which no share holds
+                sent = jnp.where(real.reshape(-1, 1), picks,
+                                 cfg.n_routed_experts)
+            y = experts.grouped_experts(
+                rows, sent, weights, *stacks, held=cfg.experts_held,
+                n_routed=cfg.n_routed_experts)
+            # where every expert is held, every pair is moved
+            y, counts = (y, picks.size) if cfg.experts_held is None else y
         else:
             y, touched, routed = experts.decode_experts(
                 rows, picks, weights, live, *stacks,
@@ -484,7 +502,8 @@ class Qwen3NextLM(DecoderLM):
         """One pass over whole prompts tokens [B, T], a sequence's real
         tokens being its first ``last_index + 1``: the residual stream,
         the cache's leaves as ``prefill`` stacks them (None without
-        ``pad_to``) and every layer's picks [B, T, k]."""
+        ``pad_to``), every layer's picks [B, T, k] and the
+        ``prefill_counter_names``."""
         import jax.numpy as jnp
 
         from ..ops import attention as prefill_attention
@@ -496,9 +515,12 @@ class Qwen3NextLM(DecoderLM):
                 else jnp.asarray(last_index, jnp.int32) + 1)
         x = self._embed_tokens(params, tokens)
         positions = jnp.arange(T)
+        real = (None if last_index is None
+                else positions[None, :] < lens[:, None])
         rep = cfg.n_heads // cfg.n_kv_heads
         leaves = {"k": [], "v": [], "conv": [], "state": []}
         picked = []
+        moved = jnp.int32(0)
         for p, linear in zip(params["layers"], self._linear):
             a = self._norm(x, p["ln_in"])
             if linear:
@@ -520,11 +542,13 @@ class Qwen3NextLM(DecoderLM):
                     pad = ((0, 0), (0, 0), (0, pad_to - T), (0, 0))
                     leaves["k"].append(jnp.pad(k, pad))
                     leaves["v"].append(jnp.pad(v, pad))
-            x, picks, _ = self._moe(p, x)
+            x, picks, pairs = self._moe(p, x, real=real)
             picked.append(picks)
+            moved = moved + pairs
         slab = None if pad_to is None else {
             name: jnp.stack(each) for name, each in leaves.items() if each}
-        return x, slab, picked
+        routed = sum(picks.size for picks in picked)
+        return x, slab, picked, jnp.stack([moved, jnp.int32(routed)])
 
     def apply(self, params, tokens):
         """tokens [B, T] int32 -> logits [B, T, V] (float32)."""
@@ -535,7 +559,7 @@ class Qwen3NextLM(DecoderLM):
         """``prefill`` and every layer's picks [B, T, k] (a comparison with
         a reference takes them from this very program, as the afmoe
         block's)."""
-        x, slab, picked = self._forward(params, prompt, max_seq, last_index)
+        x, slab, picked, _ = self._forward(params, prompt, max_seq, last_index)
         return self._head(params, x, last_index), slab, picked
 
     def prefill(self, params, prompt, max_seq: int, last_index=None):
@@ -545,6 +569,13 @@ class Qwen3NextLM(DecoderLM):
         1, C] and ``state`` [Ll, B, Hv, Dk, Dv] AT ``last_index``, whatever
         the prompts were padded to."""
         return self._prefill(params, prompt, max_seq, last_index)[:2]
+
+    def prefill_counted(self, params, prompt, max_seq: int, last_index=None):
+        """``prefill`` and, after the cache's rows, its
+        ``prefill_counter_names`` as an int32 vector (what a decode
+        step's counts are to ``step_counter_names``)."""
+        x, slab, _, counts = self._forward(params, prompt, max_seq, last_index)
+        return self._head(params, x, last_index), slab, counts
 
     # -- the decode step ------------------------------------------------------------------
 

@@ -16,7 +16,24 @@ capacity drops; nothing here shares its code).
                        that land elsewhere before anything is grouped or
                        counted as touched, so no expert is read and no
                        row multiplied for them. What the other chips would
-                       add is not here; nothing stands in for it
+                       add is not here; nothing stands in for it.
+                       ``grouped_experts(held=(lo, n), n_routed=E)`` does
+                       not move them either: after the sort the pairs that
+                       landed here come first, and the gather, the three
+                       grouped matmuls, the weights and the sum back to
+                       rows run over a ROOM of pairs (``room_of()``: what
+                       uniform picks send to ``n`` of ``E`` experts and a
+                       quarter more, 12,928 of 40,960 where a chip holds
+                       a quarter; derived from ``held``, ``E`` and the
+                       shapes, no setting). Where more land here than the
+                       room holds (a skewed router) the same code runs
+                       again over the next room, ``ceil(landed / room)``
+                       passes: drop-free whatever the picks. It returns
+                       the pairs it moved (``room x passes``) beside the
+                       sums: ``Qwen3NextLM.prefill_counted`` adds them up
+                       as ``moe_prefill_pairs_moved`` beside
+                       ``moe_prefill_pairs_routed``, and the batcher
+                       brings both home with a burst
 ``grouped_experts()``  any number of rows: sort the (row, pick) pairs by
                        expert, three grouped matmuls (``lax.ragged_dot``:
                        on a TPU the compiler's own Mosaic grouped matmul,
@@ -41,6 +58,7 @@ Both return float32 sums over a row's picks; the caller casts.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -54,6 +72,16 @@ from jax.experimental.pallas import tpu as pltpu
 # the experts' matmuls (0.41 TFLOP a layer at Trinity-Mini's widths) take
 # about as long as reading the layer's 1.6 GB of experts once more
 GROUP_ROWS = 4096
+# a share's room of pairs is an ODD number of these rows. The compiler's
+# grouped matmul cuts its rows into tiles of the largest of 512, 256, 128
+# that divides them, and does a tile's work once for every group that
+# touches it: at a share's 80 rows an expert, tiles of 128 do least (128
+# experts at the cell's widths, 10,240 rows in groups: 12,928 rows 0.78 ms,
+# 12,800 rows 1.07; 640 rows 0.51, 512 rows 0.91: PERF.md section 6, PR 39)
+ROOM_TILE = 128
+# the rows whose sums one matrix of the way back makes: a block of 128 owns
+# at most 128 k products, so its matrix is [128, 128 k]
+BAND_ROWS = 128
 # the decode kernel's slice of an expert's width: W1 and W3 blocks [D, TF],
 # W2 [TF, D], 2 MB each at D = 2048 in bfloat16, two buffers apiece
 DECODE_TF = 512
@@ -118,28 +146,123 @@ def _grouped(x, picks, weights, w1, w3, w2, dropped: bool = False):
     return y[back].reshape(n, k, d).astype(jnp.float32).sum(axis=1)
 
 
-@functools.partial(jax.jit, static_argnames=("group_rows", "held"))
+def room_of(pairs: int, held, n_routed: int) -> int:
+    """The (row, pick) pairs a share's grouped path moves in one pass: what
+    ``pairs`` uniform picks over ``n_routed`` experts send to the ``held[1]``
+    held ones and a quarter more (5/16 of all pairs where a chip holds a
+    quarter of the layer), rounded up to an odd number of ``ROOM_TILE``;
+    never more than there are."""
+    expected = -(-pairs * held[1] * 5 // (n_routed * 4))
+    return min(pairs, (-(-expected // ROOM_TILE) | 1) * ROOM_TILE)
+
+
+def _grouped_held(x, picks, weights, w1, w3, w2, room: int):
+    """``_grouped(dropped=True)`` over ``room`` pairs at a time. picks [N,
+    k] are local already (``localise()``): after the sort the ``c`` pairs
+    that landed here are the first ``c`` of the order, and pass ``p`` of
+    ``ceil(c / room)`` takes those from ``p room`` on: gathers their rows,
+    multiplies them (each pass's groups are the experts' groups cut to its
+    window), weights them, and adds them to their rows' sums. A pair that
+    landed elsewhere is never gathered, and every pair that landed here
+    is in some pass. Returns ``(float32 [N, D], pairs moved)``.
+
+    The way back to row order: a pass's pairs sorted by their place in
+    ``picks`` lie row by row, a row's beside each other, so rows ``b T ..
+    (b + 1) T - 1`` own a stretch of at most ``T k`` of them, and a [T, T
+    k] matrix of ones and zeros times that stretch is each row's float32
+    sum of its own bfloat16 products: nothing of [N, k, D] is built."""
+    n, d = x.shape
+    k = picks.shape[1]
+    n_experts = w1.shape[0]
+    pairs = n * k
+    flat = picks.reshape(-1)
+    order = jnp.argsort(flat, stable=True)
+    sizes = jnp.sum(
+        flat[:, None] == jnp.arange(n_experts, dtype=flat.dtype)[None, :],
+        axis=0, dtype=jnp.int32)
+    ends = jnp.cumsum(sizes)
+    landed = ends[-1]
+    passes = -(-landed // room)
+    # every pass's window lies inside the order
+    order = jnp.pad(order, (0, -pairs % room)).astype(jnp.int32)
+    by_pair = weights.reshape(-1)
+    band = math.gcd(n, BAND_ROWS)
+    stretch = min(band * k, room)
+    firsts = jnp.arange(0, n, band, dtype=jnp.int32)
+
+    def one_pass(p, out):
+        lo = p * room
+        real = lo + jnp.arange(room, dtype=jnp.int32) < landed
+        pair = lax.dynamic_slice(order, (lo,), (room,))
+        xs = x[pair // k]                                 # [room, D] by expert
+        here = (jnp.clip(ends, lo, lo + room)
+                - jnp.clip(ends - sizes, lo, lo + room))
+        a = lax.ragged_dot(xs, w1, here, preferred_element_type=jnp.float32)
+        g = lax.ragged_dot(xs, w3, here, preferred_element_type=jnp.float32)
+        h = (jax.nn.silu(a) * g).astype(x.dtype)
+        y = lax.ragged_dot(h, w2, here, preferred_element_type=jnp.float32)
+        # rows past the last group were given to no expert: not a product
+        y = (jnp.where(real[:, None], y, 0.0)
+             * by_pair[pair][:, None]).astype(x.dtype)
+        back = jnp.argsort(jnp.where(real, pair, pairs), stable=True)
+        y = y[back]                                       # [room, D] by row
+        row = jnp.where(real, pair // k, n)[back]         # ascending
+        start = jnp.minimum(
+            jnp.sum(row[None, :] < firsts[:, None], axis=1, dtype=jnp.int32),
+            room - stretch)
+
+        def rows_sum(first, start):
+            own = lax.dynamic_slice(row, (start,), (stretch,))[None, :] == (
+                first + jnp.arange(band, dtype=jnp.int32))[:, None]
+            return jnp.dot(
+                own.astype(x.dtype),
+                lax.dynamic_slice(y, (start, 0), (stretch, d)),
+                precision=lax.Precision.HIGHEST,
+                preferred_element_type=jnp.float32)
+
+        return out + lax.map(
+            lambda r: rows_sum(*r), (firsts, start)).reshape(n, d)
+
+    with jax.named_scope("held_experts_prefill"):
+        out = lax.fori_loop(0, passes, one_pass, jnp.zeros((n, d), jnp.float32))
+    return out, passes * room
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("group_rows", "held", "n_routed"))
 def grouped_experts(x, picks, weights, w1, w3, w2, group_rows: int = GROUP_ROWS,
-                    held=None):
+                    held=None, n_routed=None):
     """x [N, D] rows, picks / weights [N, k] (``route()``), the stacked
     experts -> float32 [N, D]: sum over a row's picks of weight x expert(x).
     Drop-free whatever the picks. More than ``group_rows`` rows go through
     in equal groups of at most that many. ``held=(lo, n)``: the stacks are
-    experts ``lo .. lo + n - 1`` of the layer the picks range over, and a
-    pick outside them adds nothing (``localise()``)."""
+    experts ``lo .. lo + n - 1`` of the ``n_routed`` the picks range over,
+    a pick outside them adds nothing (``localise()``) and is not moved
+    either: a group's pairs go through ``room_of()`` at a time, and the
+    result is ``(float32 [N, D], pairs moved)``, the second ``room x
+    passes`` summed over the groups."""
     n = x.shape[0]
-    if held is not None:
-        picks, weights = localise(picks, weights, held, w1.shape[0])
-    if n <= group_rows:
-        return _grouped(x, picks, weights, w1, w3, w2, held is not None)
     groups = -(-n // group_rows)
     while n % groups:
         groups += 1
+    if held is None:
+        def one(x, picks, weights):
+            return _grouped(x, picks, weights, w1, w3, w2)
+    else:
+        if not n_routed:
+            raise ValueError(f"held {held} of how many experts: n_routed")
+        picks, weights = localise(picks, weights, held, w1.shape[0])
+        room = room_of(n // groups * picks.shape[1], held, n_routed)
+
+        def one(x, picks, weights):
+            return _grouped_held(x, picks, weights, w1, w3, w2, room)
+    if groups == 1:
+        return one(x, picks, weights)
     split = lambda a: a.reshape(groups, n // groups, *a.shape[1:])  # noqa: E731
-    out = lax.map(
-        lambda r: _grouped(r[0], r[1], r[2], w1, w3, w2, held is not None),
-        (split(x), split(picks), split(weights)))
-    return out.reshape(n, x.shape[1])
+    out = lax.map(lambda r: one(*r), (split(x), split(picks), split(weights)))
+    if held is None:
+        return out.reshape(n, x.shape[1])
+    return out[0].reshape(n, x.shape[1]), out[1].sum()
 
 
 def touched_experts(picks, live, n_experts: int):

@@ -967,8 +967,18 @@ class ContinuousBatcher:
         # llama block) returns none and its bursts return what they
         # always did.
         self._step_counters = tuple(getattr(model, "step_counter_names", ()))
-        for name in self._step_counters:
+        # counters a model's prefill returns after its slab
+        # (model.prefill_counter_names, prefill_counted): the insert that
+        # follows a prefill adds them to a vector on the device
+        # (_prefill_counts), which the next burst dispatched takes along
+        # for _read_burst: no program and no wait of their own. A model
+        # with none keeps the inserts it always had.
+        self._prefill_counters = tuple(
+            getattr(model, "prefill_counter_names", ()))
+        for name in self._step_counters + self._prefill_counters:
             self.stats.setdefault(name, 0)
+        run_prefill = (model.prefill_counted if self._prefill_counters
+                       else model.prefill)
 
         def fused_step(params, cache, cur_tok, pos, active, temps, keys, attn_len):
             logits, cache, *counts = model.decode_step_cache(
@@ -987,7 +997,14 @@ class ContinuousBatcher:
             return lax.dynamic_update_slice(
                 layer, rows, (slot,) + (0,) * (layer.ndim - 1))
 
-        def insert(cache, cache_one, slot, first_tok, first_pos, lane_key, cur_tok, pos, keys):
+        def summed(counted):
+            """What an insert is given after its registers: nothing, or
+            the prefill counters so far and this prefill's -> nothing, or
+            [their sum], which the insert returns last."""
+            return [counted[0] + counted[1]] if counted else []
+
+        def insert(cache, cache_one, slot, first_tok, first_pos, lane_key,
+                   cur_tok, pos, keys, *counted):
             # cache_one is the prefill's stacked [L, 1, KV, Tb, Dh] slab;
             # each layer's slice lands in that layer's cache at `slot`
             new = {
@@ -1000,13 +1017,13 @@ class ContinuousBatcher:
             cur_tok = cur_tok.at[slot].set(first_tok)
             pos = pos.at[slot].set(first_pos)
             keys = keys.at[slot].set(lane_key)
-            return new, cur_tok, pos, keys
+            return (new, cur_tok, pos, keys, *summed(counted))
 
         def prefill_one(params, prompt, last_index, seed, temp):
             # cache_one spans only the prompt bucket — decode writes extend
             # it in place, so inserting a full max_seq slab per admission
             # would just copy zeros over HBM
-            logits, cache_one = model.prefill(
+            logits, cache_one, *counts = run_prefill(
                 params, prompt, prompt.shape[1], last_index=last_index
             )
             key = jax.random.PRNGKey(seed)
@@ -1016,7 +1033,7 @@ class ContinuousBatcher:
                 sub, logits / jnp.maximum(temp, 1e-6), axis=-1
             ).astype(jnp.int32)
             first = jnp.where(temp > 0, sampled, greedy)
-            return first, cache_one, key
+            return (first, cache_one, key, *counts)
 
         def prefill_many(params, prompts, last_index, seeds, temps):
             # m admissions share ONE forward: the prompt matmuls go from
@@ -1025,15 +1042,15 @@ class ContinuousBatcher:
             # the per-admission forward is the throughput tier's largest
             # non-decode device cost. m is a small static bucket (2/4/8),
             # so at most 3 extra executables exist per prompt bucket.
-            logits, slab = model.prefill(
+            logits, slab, *counts = run_prefill(
                 params, prompts, prompts.shape[1], last_index=last_index
             )
             keys = jax.vmap(jax.random.PRNGKey)(seeds)
             keys, firsts = sample_next(keys, logits, temps)
-            return firsts, slab, keys
+            return (firsts, slab, keys, *counts)
 
         def insert_many(cache, slab, slot_ix, firsts, first_pos, lane_keys,
-                       cur_tok, pos, keys):
+                       cur_tok, pos, keys, *counted):
             # slab is the batched prefill's [L, m, KV, Tb, Dh] stack; each
             # row i lands in its lane slot_ix[i] (traced start indices —
             # one executable per (m, bucket), not per slot assignment)
@@ -1047,7 +1064,7 @@ class ContinuousBatcher:
             cur_tok = cur_tok.at[slot_ix].set(firsts)
             pos = pos.at[slot_ix].set(first_pos)
             keys = keys.at[slot_ix].set(lane_keys)
-            return new, cur_tok, pos, keys
+            return (new, cur_tok, pos, keys, *summed(counted))
 
         def fused_burst(params, cache, cur_tok, pos, active, temps, keys, k, attn_len):
             """k fused decode steps as one executable; returns [k, slots]
@@ -1929,7 +1946,7 @@ class ContinuousBatcher:
                 bytes_read=self._param_bytes + bucket * self._kv_key_bytes,
                 tokens=bucket,
             ) as _m, device_trace("gen.prefill"):
-                first, cache_one, key = self._prefill_fn(
+                first, cache_one, key, *_ = self._prefill_fn(
                     self.params, jnp.asarray(prompt),
                     jnp.asarray([n - 1], jnp.int32),
                     jnp.int32(seed), jnp.float32(temperature),
@@ -2874,6 +2891,12 @@ class ContinuousBatcher:
         self._cur_tok = jnp.zeros((self.slots,), jnp.int32)
         self._pos = jnp.zeros((self.slots,), jnp.int32)
         self._keys = jax.vmap(jax.random.PRNGKey)(jnp.arange(self.slots))
+        # the prefill counters no burst has taken home yet: [] for a model
+        # that names none, else [an int32 vector], which is these zeros
+        # again (no insert consumes them) once a burst has taken it
+        n = len(getattr(self.model, "prefill_counter_names", ()))
+        self._no_prefill_counts = [jnp.zeros((n,), jnp.int32)] if n else []
+        self._prefill_counts = self._no_prefill_counts
         # per-lane stop tokens (-1 = no eos, never matches) and remaining
         # token budgets for the stop-aware fused burst; the device
         # decrements its own budget copy per step, the host re-uploads
@@ -3015,25 +3038,27 @@ class ContinuousBatcher:
                 prompts = jnp.zeros((m, bucket), jnp.int32)
                 last = jnp.zeros((m,), jnp.int32)
                 if m == 1:
-                    first, cache_one, lane_key = self._prefill_fn(
+                    first, cache_one, lane_key, *counts = self._prefill_fn(
                         self.params, prompts, last, jnp.int32(0), jnp.float32(0.0)
                     )
-                    self._cache, self._cur_tok, self._pos, self._keys = (
+                    self._cache, self._cur_tok, self._pos, self._keys, *_ = (
                         self._insert_fn(
                             self._cache, cache_one, 0, first[0], 1, lane_key,
                             self._cur_tok, self._pos, self._keys,
+                            *self._prefill_counts, *counts,
                         )
                     )
                 else:
-                    firsts, slab, lane_keys = self._prefill_many_fn(
+                    firsts, slab, lane_keys, *counts = self._prefill_many_fn(
                         self.params, prompts, last,
                         jnp.zeros((m,), jnp.int32), jnp.zeros((m,), jnp.float32),
                     )
-                    self._cache, self._cur_tok, self._pos, self._keys = (
+                    self._cache, self._cur_tok, self._pos, self._keys, *_ = (
                         self._insert_many_fn(
                             self._cache, slab, jnp.arange(m, dtype=jnp.int32),
                             firsts, last + 1, lane_keys,
                             self._cur_tok, self._pos, self._keys,
+                            *self._prefill_counts, *counts,
                         )
                     )
                 # block so only one warm call is in flight at a time
@@ -4128,6 +4153,16 @@ class ContinuousBatcher:
             self._read_burst(pending.popleft())
 
     @scheduler_only
+    def _take_prefill_counts(self):
+        """The prefill counters since the last call, as a burst's entry
+        takes them along ([] for a model that names none): the device
+        ran the inserts that summed them before the burst, so whoever
+        reads the burst finds them ready."""
+        taken, self._prefill_counts = (
+            self._prefill_counts, self._no_prefill_counts)
+        return taken
+
+    @scheduler_only
     def _read_burst(self, entry) -> None:
         """Bring one in-flight burst's tokens to the host and credit them.
         ``entry`` is ``(mode, device arrays, (snapshot, ...), dispatch
@@ -4148,6 +4183,11 @@ class ContinuousBatcher:
         if self._step_counters and mode != "spec":
             # the model's own counters of the burst's steps: its last array
             for name, n in zip(self._step_counters, host.pop()):
+                self.stats[name] += int(n)
+        if self._prefill_counters and mode != "spec":
+            # and, before it, those of the prefills dispatched since the
+            # burst before (_take_prefill_counts)
+            for name, n in zip(self._prefill_counters, host.pop()):
                 self.stats[name] += int(n)
         if mode == "spec":
             self._process_spec_burst(*host, *rest)
@@ -4719,7 +4759,7 @@ class ContinuousBatcher:
                 bytes_read=self._param_bytes + bucket * self._kv_key_bytes,
                 tokens=bucket,
             ) as _m, device_trace("gen.prefill"):
-                _f, cache_one, _k = self._prefill_fn(
+                _f, cache_one, _k, *_ = self._prefill_fn(
                     self.params, jnp.asarray(prompt),
                     jnp.asarray([n - 1], jnp.int32),
                     jnp.int32(req.seed), jnp.float32(req.temperature),
@@ -4815,7 +4855,7 @@ class ContinuousBatcher:
                 bytes_read=self._param_bytes + bucket * self._kv_key_bytes,
                 tokens=bucket,
             ) as _m, device_trace("gen.prefill"):
-                first, cache_one, lane_key = self._prefill_fn(
+                first, cache_one, lane_key, *counts = self._prefill_fn(
                     self.params,
                     jnp.asarray(prompt),
                     jnp.asarray([n - 1], jnp.int32),
@@ -4828,9 +4868,11 @@ class ContinuousBatcher:
                 "insert", variant=f"b{bucket}", tenant=req.tenant or "",
                 bytes_read=bucket * self._kv_key_bytes, tokens=n,
             ) as _m, device_trace("gen.lane_insert"):
-                self._cache, self._cur_tok, self._pos, self._keys = self._insert_fn(
+                (self._cache, self._cur_tok, self._pos, self._keys,
+                 *self._prefill_counts) = self._insert_fn(
                     self._cache, cache_one, slot, first[0], n, lane_key,
                     self._cur_tok, self._pos, self._keys,
+                    *self._prefill_counts, *counts,
                 )
                 _m.sync(self._cur_tok)
             if self._prefix_index is not None:
@@ -4894,7 +4936,7 @@ class ContinuousBatcher:
             bytes_read=self._param_bytes + m * bucket * self._kv_key_bytes,
             tokens=m * bucket,
         ) as _pm, device_trace("gen.prefill"):
-            firsts, slab, lane_keys = self._prefill_many_fn(
+            firsts, slab, lane_keys, *counts = self._prefill_many_fn(
                 self.params, jnp.asarray(prompts), jnp.asarray(last),
                 jnp.asarray(seeds), jnp.asarray(temps),
             )
@@ -4903,10 +4945,12 @@ class ContinuousBatcher:
             "insert", variant=f"m{m}b{bucket}", tenant=_wave_tenant,
             bytes_read=m * bucket * self._kv_key_bytes, tokens=m * bucket,
         ) as _im, device_trace("gen.lane_insert"):
-            self._cache, self._cur_tok, self._pos, self._keys = self._insert_many_fn(
+            (self._cache, self._cur_tok, self._pos, self._keys,
+             *self._prefill_counts) = self._insert_many_fn(
                 self._cache, slab, jnp.asarray(np.asarray(slots, np.int32)),
                 firsts, jnp.asarray(last + 1), lane_keys,
                 self._cur_tok, self._pos, self._keys,
+                *self._prefill_counts, *counts,
             )
             _im.sync(self._cur_tok)
         t_inserted = time.monotonic()
@@ -5755,7 +5799,9 @@ class ContinuousBatcher:
                                     k, bound,
                                 )
                                 burst = (
-                                    "fused", (toks, counts, done_bits, *extra),
+                                    "fused",
+                                    (toks, counts, done_bits,
+                                     *self._take_prefill_counts(), *extra),
                                     (snapshot, k), t_dispatch,
                                 )
                             else:
@@ -5769,8 +5815,10 @@ class ContinuousBatcher:
                                     k, bound,
                                 )
                                 burst = (
-                                    "plain", (toks, *extra), (snapshot,),
-                                    t_dispatch,
+                                    "plain",
+                                    (toks, *self._take_prefill_counts(),
+                                     *extra),
+                                    (snapshot,), t_dispatch,
                                 )
                             _m.sync(toks)
                         self.stats["steps"] += k

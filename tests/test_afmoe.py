@@ -272,6 +272,10 @@ def test_the_batcher_serves_it_and_counts_what_the_experts_and_windows_do(served
                             list(range(299, 311))).argmax(-1)
     assert len(out) == 312 and out[300:] == want.tolist()
     s = batcher.stats
+    # a family that names no prefill counters: its inserts take and return
+    # what they always did, and no burst brings any home
+    assert batcher._prefill_counters == () and batcher._prefill_counts == []
+    assert "moe_prefill_pairs_moved" not in s
     assert s["moe_layer_steps"] == 4 * s["steps"] > 0
     assert s["moe_experts_touched"] == 2 * s["moe_layer_steps"]    # one lane
     assert s["moe_rows_routed"] == s["moe_experts_touched"]

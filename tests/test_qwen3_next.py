@@ -133,6 +133,14 @@ def test_served_requests_are_the_references_greedy_tokens(served, batcher):
     assert stats["moe_rows_held"] < stats["moe_rows_routed"]
     # a quarter of the experts held, near-uniform routing
     assert 0.1 < stats["moe_rows_held"] / stats["moe_rows_routed"] < 0.45
+    # the prefills' counters came home with the bursts: every padded token
+    # routed 4 picks in 8 layers, and the grouped path moved a room of
+    # them a call (384 of a 128-bucket prompt's 512, and of two prompts'
+    # 1024), never the fall-back's second pass
+    routed = stats["moe_prefill_pairs_routed"]
+    assert routed == stats["prefill_tokens"] * 4 * 8 > 0
+    assert 0.375 * routed <= stats["moe_prefill_pairs_moved"] <= 0.75 * routed
+    assert stats["moe_prefill_pairs_moved"] % experts.ROOM_TILE == 0
 
 
 def test_a_lane_admitted_beside_live_lanes_leaves_them_bit_equal(served, batcher):
@@ -272,8 +280,235 @@ def test_experts_route_by_softmax_and_drop_what_is_held_elsewhere():
                 want[r] += we * np.asarray((jax.nn.silu(a) * a) @ w2[e - 8])
     np.testing.assert_allclose(y, want, atol=1e-4)
     np.testing.assert_allclose(
-        experts.grouped_experts(x, picks, w, w1, w1, w2, held=(8, 8))[:12],
+        experts.grouped_experts(x, picks, w, w1, w1, w2, held=(8, 8),
+                                n_routed=32)[0][:12],
         want[:12], atol=1e-4)
+    with pytest.raises(ValueError):     # a share of how many
+        experts.grouped_experts(x, picks, w, w1, w1, w2, held=(8, 8))
+
+
+def _parent_grouped(x, picks, weights, w1, w3, w2, dropped=False):
+    """``ops.experts._grouped`` as the parent of PR 39 had it, verbatim:
+    every (row, pick) pair sorted, gathered, multiplied, weighted,
+    unsorted and summed. What a share's room must equal."""
+    n, d = x.shape
+    k = picks.shape[1]
+    n_experts = w1.shape[0]
+    flat = picks.reshape(-1)
+    order = jnp.argsort(flat, stable=True)
+    xs = x[order // k]
+    sizes = jnp.sum(
+        flat[:, None] == jnp.arange(n_experts, dtype=flat.dtype)[None, :],
+        axis=0, dtype=jnp.int32)
+    a = lax.ragged_dot(xs, w1, sizes, preferred_element_type=jnp.float32)
+    g = lax.ragged_dot(xs, w3, sizes, preferred_element_type=jnp.float32)
+    h = (jax.nn.silu(a) * g).astype(x.dtype)
+    y = lax.ragged_dot(h, w2, sizes, preferred_element_type=jnp.float32)
+    by_expert = weights.reshape(-1)[order][:, None]
+    if dropped:
+        y = jnp.where(flat[order][:, None] < n_experts, y, 0.0)
+    y = (y * by_expert).astype(x.dtype)
+    back = jnp.argsort(order)
+    return y[back].reshape(n, k, d).astype(jnp.float32).sum(axis=1)
+
+
+def _parent_held(x, picks, weights, stacks, held):
+    local, kept = experts.localise(picks, weights, held, stacks[0].shape[0])
+    return _parent_grouped(x, local, kept, *stacks, True)
+
+
+def _share_case(rows=96, seed=11, dtype=jnp.float32):
+    """Seeded rows routed over the share test's 16 experts, top 4, and
+    the four shares' stacks of 4."""
+    rng = np.random.default_rng(seed)
+    mk = lambda *s: jnp.asarray(rng.normal(size=s), jnp.float32)  # noqa: E731
+    x, router = mk(rows, 128), mk(128, 16) / 8
+    picks, weights = experts.route(x, router, None, 4, 1.0, score="softmax")
+    stacks = [tuple(a.astype(dtype) for a in (
+        mk(4, 128, 64) / 11, mk(4, 128, 64) / 11, mk(4, 64, 128) / 8))
+        for _ in range(4)]
+    return x.astype(dtype), picks, weights, stacks
+
+
+def _one_rounding(want):
+    """The sum over a row's picks keeps float32 and the picks' products,
+    in another order: each of the 4 additions may round once apart."""
+    return 4 * float(np.abs(want).max()) * 2.0 ** -23
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("share", range(4))
+def test_a_share_over_its_room_is_the_parents_grouped_path(share, dtype):
+    """At the four shares of the share test: 384 pairs, about 96 land on a
+    share, its room is 128 and one pass moves them; the result is the
+    parent's over all 384, to the order of a float32 sum of 4 terms."""
+    x, picks, weights, stacks = _share_case(dtype=dtype)
+    held = (4 * share, 4)
+    assert experts.room_of(384, held, 16) == 128
+    want = np.asarray(_parent_held(x, picks, weights, stacks[share], held))
+    got, moved = experts.grouped_experts(
+        x, picks, weights, *stacks[share], held=held, n_routed=16)
+    assert got.dtype == jnp.float32 and int(moved) == 128
+    np.testing.assert_allclose(got, want, rtol=0, atol=_one_rounding(want))
+    # a capture names the share's ops by their scope
+    assert "held_experts_prefill" in experts.grouped_experts.lower(
+        x, picks, weights, *stacks[share], held=held,
+        n_routed=16).as_text(debug_info=True)
+
+
+@pytest.mark.parametrize("skew", ["every_pick_here", "three_times_the_share"])
+def test_the_room_falls_back_drop_free(skew):
+    """A router that sends a share more than its room: every pair that
+    landed is still computed, in passes over the same code, and the count
+    of pairs moved says that the fall-back ran. Equal to ``held=None``
+    over the cut stacks, which drops nothing by construction."""
+    x, picks, weights, stacks = _share_case()
+    held = (8, 4)
+    rng = np.random.default_rng(12)
+    here = (jnp.ones(picks.shape, bool) if skew == "every_pick_here"
+            else jnp.asarray(rng.random(picks.shape) < 0.75))
+    picks = jnp.where(
+        here, jnp.asarray(rng.integers(8, 12, size=picks.shape), jnp.int32),
+        jnp.asarray(rng.integers(0, 8, size=picks.shape), jnp.int32))
+    landed = int(here.sum())
+    assert landed > 2 * 128                       # c > room
+    want = np.asarray(experts.grouped_experts(
+        x, jnp.where(here, picks - 8, 0), jnp.where(here, weights, 0.0),
+        *stacks[2]))
+    got, moved = experts.grouped_experts(
+        x, picks, weights, *stacks[2], held=held, n_routed=16)
+    assert int(moved) == -(-landed // 128) * 128 >= 3 * 128
+    np.testing.assert_allclose(got, want, rtol=0, atol=_one_rounding(want))
+    np.testing.assert_allclose(
+        got, _parent_held(x, picks, weights, stacks[2], held), rtol=0,
+        atol=_one_rounding(want))
+
+
+@pytest.mark.parametrize("rows,group_rows,groups", [
+    (96, 32, 3), (96, 48, 2), (40, 8, 5), (130, 128, 2)])
+def test_more_rows_than_a_group_go_through_the_room_in_groups(
+        rows, group_rows, groups):
+    """``prefill_many``'s rows: above ``group_rows`` the share's path runs
+    group by group (``lax.map``), each group over its own room, also
+    where a group's rows are no multiple of anything (65: one band)."""
+    x, picks, weights, stacks = _share_case(rows=rows, seed=13)
+    held = (12, 4)
+    want = np.asarray(_parent_held(x, picks, weights, stacks[3], held))
+    got, moved = experts.grouped_experts(
+        x, picks, weights, *stacks[3], group_rows=group_rows, held=held,
+        n_routed=16)
+    room = experts.room_of(rows // groups * 4, held, 16)
+    assert int(moved) % room == 0 and int(moved) >= groups * room
+    np.testing.assert_allclose(got, want, rtol=0, atol=_one_rounding(want))
+
+
+def test_a_room_is_the_shares_expected_pairs_and_a_quarter_more():
+    # the cell: 4096 rows x 10 picks, 128 of 512 held: 5/16 of the pairs
+    # is 100 tiles of 128, and an odd number of them is 101
+    assert experts.room_of(40960, (0, 128), 512) == 12928
+    assert experts.room_of(5120, (128, 128), 512) == 1664
+    assert experts.room_of(1280, (384, 128), 512) == 640
+    # never more than there are, and all of them where all are held
+    assert experts.room_of(64, (8, 8), 32) == 64
+    assert experts.room_of(40960, (0, 512), 512) == 40960
+
+
+def test_a_whole_layer_moves_every_pair_and_a_share_its_room(served):
+    """``prefill_counted``: ``prefill`` and the two counters, for a model
+    that holds a share and for one that holds every expert."""
+    model, params = served
+    whole = DecoderLM(**dict(SMALL, experts_held=None))
+    assert model.prefill_counter_names == whole.prefill_counter_names == (
+        "moe_prefill_pairs_moved", "moe_prefill_pairs_routed")
+    prompt = jnp.asarray(
+        np.random.default_rng(2).integers(0, 256, size=(2, 64)), jnp.int32)
+    logits, slab = model.prefill(params, prompt, 64)
+    counted, slab2, counts = model.prefill_counted(params, prompt, 64)
+    np.testing.assert_array_equal(logits, counted)
+    for name in slab:
+        np.testing.assert_array_equal(slab[name], slab2[name])
+    # 128 rows x 4 picks in 8 layers; a layer's room is 384 of its 512
+    assert counts.tolist() == [8 * 384, 8 * 512]
+    assert whole.prefill_counted(whole.init_params(3), prompt, 64)[2].tolist() == [
+        8 * 512, 8 * 512]
+
+
+@pytest.mark.parametrize("held", [(4, 4), None], ids=["a_share", "every_expert"])
+def test_padding_is_sent_to_no_expert_of_a_share(served, held):
+    """Rows past a sequence's last token: padding routes together (here
+    96 equal rows of 128, whose four picks are made the share's own), and
+    would overflow the share's room into further passes. Their picks go
+    nowhere, the real rows' results do not move, and one room is moved.
+    Where every expert is held nothing is masked."""
+    model, params = served
+    model = DecoderLM(**dict(SMALL, experts_held=held))
+    layer = dict(params["layers"][0])
+    if held is None:
+        layer.update(DecoderLM(**dict(SMALL, experts_held=None)).init_params(
+            3)["layers"][0])
+    rng = np.random.default_rng(6)
+    h = jnp.asarray(rng.normal(size=(1, 128, 128)), jnp.float32)
+    h = h.at[:, 32:].set(h[:, 32])
+    # the padding's picks: the router's columns 4..7 are its row, scaled
+    m = model._norm(h, layer["ln_post"])[0, 32]
+    layer["router"] = layer["router"].at[:, 4:8].set(m[:, None] * 4)
+    real = jnp.arange(128)[None, :] < 32
+    out, picks, moved = model._moe(layer, h, real=real)
+    plain, picks2, moved2 = model._moe(layer, h)
+    np.testing.assert_array_equal(picks, picks2)      # what was routed
+    assert sorted(picks[0, 40].tolist()) == [4, 5, 6, 7]
+    np.testing.assert_array_equal(out[:, :32], plain[:, :32])
+    if held is None:
+        np.testing.assert_array_equal(out, plain)
+        assert moved == moved2 == 512
+    else:
+        # 96 x 4 pairs of padding and the real rows' own: three rooms of 384
+        assert int(moved2) >= 2 * 384 and int(moved) == 384
+        assert float(jnp.abs(out[:, 32:] - plain[:, 32:]).max()) > 1e-3
+
+
+@pytest.mark.parametrize("counted", [True, False])
+def test_an_insert_sums_the_prefill_counters_only_where_a_family_names_them(
+        served, batcher, counted):
+    """The qwen3_next batcher's inserts take the counters so far and the
+    prefill's and return their sum last; called without (as a comparison
+    that fills a cache does) they return what they always did. A family
+    that names none has no such argument in use."""
+    model, params = served
+    if counted:
+        b = batcher
+        assert b._prefill_counters == model.prefill_counter_names
+        assert [a.tolist() for a in b._no_prefill_counts] == [[0, 0]]
+    else:
+        dense = DecoderLM(vocab_size=128, d_model=64, n_layers=2, n_heads=4,
+                          n_kv_heads=2, d_ff=128, max_seq=128, dtype="float32")
+        b = ContinuousBatcher(dense, dense.init_params(0), slots=4, max_seq=128)
+        assert b._prefill_counters == () and b._prefill_counts == []
+        assert b._take_prefill_counts() == []
+    try:
+        prompt = jnp.asarray(
+            np.random.default_rng(4).integers(0, 128, size=(1, 128)), jnp.int32)
+        first, one, key, *counts = b._prefill_fn(
+            b.params, prompt, jnp.asarray([9], jnp.int32), jnp.int32(0),
+            jnp.float32(0.0))
+        assert len(counts) == int(counted)
+        fresh = lambda: b.model.cache_layers(4, b.max_seq)  # noqa: E731
+        regs = (jnp.zeros((4,), jnp.int32), jnp.zeros((4,), jnp.int32),
+                jnp.zeros((4, 2), jnp.uint32))
+        plain = b._insert_fn(fresh(), one, 1, first[0], 10, key, *regs)
+        assert len(plain) == 4
+        if counted:
+            so_far = jnp.asarray([5, 7], jnp.int32)
+            *_, total = b._insert_fn(
+                fresh(), one, 1, first[0], 10, key, *regs, so_far, *counts)
+            assert total.tolist() == [5 + 8 * 384, 7 + 8 * 512]
+            *_, total = b._insert_many_fn(
+                fresh(), one, jnp.asarray([2], jnp.int32), first,
+                jnp.asarray([10], jnp.int32), key[None], *regs, so_far, *counts)
+            assert total.tolist() == [5 + 8 * 384, 7 + 8 * 512]
+    finally:
+        if not counted:
+            b.close()
 
 
 @pytest.mark.parametrize("setting", [
@@ -316,6 +551,36 @@ def test_refusals_name_their_reason_and_requests_are_refused_where_they_come_in(
         DecoderLM(**dict(SMALL, experts_held=(14, 4)))
     with pytest.raises(ValueError):
         DecoderLM(**dict(SMALL, partial_rotary_factor=0.0))
+
+
+@pytest.mark.parametrize("group_rows", [4096, 16])
+def test_without_held_the_grouped_path_traces_to_the_parents(group_rows):
+    """``held=None`` (the afmoe block's prefill, every family's decode off a
+    TPU): the jaxpr of ``grouped_experts`` is the one its parent's lines
+    trace, in one piece and in groups."""
+    def parent(x, picks, weights, w1, w3, w2):
+        n = x.shape[0]
+        if n <= group_rows:
+            return _parent_grouped(x, picks, weights, w1, w3, w2, False)
+        groups = -(-n // group_rows)
+        while n % groups:
+            groups += 1
+        split = lambda a: a.reshape(groups, n // groups, *a.shape[1:])  # noqa: E731
+        out = lax.map(
+            lambda r: _parent_grouped(r[0], r[1], r[2], w1, w3, w2, False),
+            (split(x), split(picks), split(weights)))
+        return out.reshape(n, x.shape[1])
+
+    x, picks, weights, stacks = _share_case(rows=48)
+    args = (x, picks % 4, weights, *stacks[0])
+
+    def mine(*args):
+        return experts.grouped_experts.__wrapped__(*args, group_rows=group_rows)
+
+    assert str(jax.make_jaxpr(mine)(*args)) == str(jax.make_jaxpr(parent)(*args))
+    np.testing.assert_array_equal(
+        experts.grouped_experts(*args, group_rows=group_rows),
+        jax.jit(parent)(*args))
 
 
 def test_the_dense_and_afmoe_blocks_take_none_of_the_new_arguments():
