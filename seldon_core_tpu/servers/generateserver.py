@@ -72,9 +72,9 @@ Model URI layout: same ``jax_config.json`` as jaxserver with
     flight_recorder  scheduler flight-recorder capacity: the batcher
                      keeps this many per-poll decision records in a
                      bounded drop-oldest ring, dumped at the engine's
-                     ``/flightrecorder`` route (0 = off; default 512 —
-                     cheap enough to leave on, see docs/operate.md
-                     "Observability")
+                     ``/flightrecorder`` route (0 = off; default 4096,
+                     a few minutes of polls — cheap enough to leave
+                     on, see docs/operate.md "Observability")
     role             ``unified`` (default; serve prefill+decode locally,
                      byte-identical to every prior release) |
                      ``prefill`` (run prompt prefill only and export the
@@ -242,7 +242,7 @@ class GenerateServer(SeldonComponent):
         prefix_cache_min_tokens: int = 16,
         admit_queue_limit: int = 0,
         prefill_chunk: int = 0,
-        flight_recorder: int = 512,
+        flight_recorder: int = 4096,
         role: str = "unified",
         peer: Optional[str] = None,
         kv_port: int = 0,

@@ -120,6 +120,13 @@ class BatcherDead(RuntimeError):
         self.retry_after_s = float(retry_after_s)
 
 
+def _is_ready(array) -> bool:
+    """``array.is_ready()``; one without the method (a test double's, a
+    numpy array) is ready as it stands."""
+    ready = getattr(array, "is_ready", None)
+    return ready is None or ready()
+
+
 # the scheduler's poll, phase by phase (PhaseClock adds "other"): each a
 # ``batcher.<phase>`` span in the profiler's host plane and seconds in
 # ``stats["loop_<phase>_s"]``
@@ -168,6 +175,14 @@ class GenRequest:
     # cache (post-insert) — for chunked admissions this is many polls
     # after admit_t, so decode residency must anchor here, not at admit
     decode_start_t: float = 0.0
+    # the prefill's dispatch returned and the insert's begins: admit_t to
+    # here is the host's time in the prefill's dispatch, here to
+    # decode_start_t in the insert's (a chunked admission: the last chunk's)
+    insert_t: float = 0.0
+    # the loop's poll (``_poll_count``) that activated the lane: the
+    # flight recorder's row with this ``poll`` names the request among its
+    # ``admitted_ids``
+    admit_poll: int = 0
     # the first burst whose snapshot held this lane with its prefill
     # token still pending was dispatched: that burst carries the first
     # token to the host
@@ -378,7 +393,7 @@ class ContinuousBatcher:
         prefix_cache_min_tokens: int = 16,
         admit_queue_limit: int = 0,
         prefill_chunk: int = 0,
-        flight_recorder_capacity: int = 512,
+        flight_recorder_capacity: int = 4096,
         restart_budget: int = 3,
         restart_backoff_s: float = 0.5,
         hbm_ledger_bytes: int = 0,
@@ -669,18 +684,16 @@ class ContinuousBatcher:
         # serving component ships as Meta.metrics TIMERs (drop-oldest
         # under pressure — telemetry must never grow unbounded);
         # ``slo_recent`` is a reservoir benches/diagnostics read for
-        # percentiles. Cumulative sums ride in ``stats`` so window-diffed
-        # bench snapshots get means for free.
+        # percentiles. The count and the TTFT sum ride in ``stats`` so a
+        # window-diffed bench snapshot has the mean (modelbench reads it).
         self.slo_pending: "collections.deque" = collections.deque(maxlen=4096)
         self.slo_recent: "collections.deque" = collections.deque(maxlen=2048)
-        self.stats.update({
-            "slo_samples": 0, "queue_wait_s_sum": 0.0,
-            "ttft_s_sum": 0.0, "tpot_s_sum": 0.0,
-        })
+        self.stats.update({"slo_samples": 0, "ttft_s_sum": 0.0})
         # request timeline: one tuple per completed request beside its
         # SLO triple — (rid, prompt length, padded bucket, tokens emitted,
         # cache_hit_tokens, submit_t, admit_t, decode_start_t,
-        # first_dispatch_t, first_tok_t, done_t, FrontStamps). Always on;
+        # first_dispatch_t, first_tok_t, done_t, insert_t, admit_poll,
+        # FrontStamps). Always on;
         # nothing per token. :meth:`capture_requests` names the fields.
         self.timeline_recent: "collections.deque" = collections.deque(maxlen=2048)
         # the scheduler loop's own time: working polls, bursts read back (and
@@ -692,15 +705,16 @@ class ContinuousBatcher:
         self._clock = PhaseClock(self.stats, "batcher", "loop", LOOP_PHASES)
         # per-tenant splits of the same samples (multi-tenant serving):
         # keyed lazily by tenant id at _resolve time so the single-tenant
-        # path allocates nothing. tenant_slo carries cumulative sums +
-        # counts; the pending deques drain as tenant-tagged TIMERs; the
+        # path allocates nothing. tenant_slo counts a tenant's finished
+        # requests; the pending deques drain as tenant-tagged TIMERs; the
         # recent reservoirs feed the TenantScheduler's TTFT feedback.
         self.tenant_slo: Dict[str, Dict[str, float]] = {}
         self.tenant_slo_pending: Dict[str, "collections.deque"] = {}
         self.tenant_slo_recent: Dict[str, "collections.deque"] = {}
         # scheduler flight recorder: one structured record per poll (batch
-        # composition, the burst's plan, chunk interleave, shed events),
-        # bounded + drop-oldest, cheap enough to leave on (0 = off)
+        # composition, the burst's plan, chunk interleave, shed events, and
+        # the loop's clock over the poll: see _loop), bounded +
+        # drop-oldest, cheap enough to leave on (0 = off)
         from .flightrecorder import FlightRecorder
 
         self.flight: Optional[FlightRecorder] = (
@@ -708,6 +722,13 @@ class ContinuousBatcher:
             if int(flight_recorder_capacity) > 0
             else None
         )
+        # what the next poll record takes along: the bursts read and the
+        # requests admitted since the last one
+        self._row_bursts: List[Dict[str, Any]] = []
+        self._row_admitted: List[int] = []
+        # the last chunk dispatched that was not a job's last, as (an
+        # output of it, self._cur_tok then): see _device_drained
+        self._chunk_newest: Optional[Tuple[Any, Any]] = None
         # device-time ledger (serving/profiler.py): every warmed-
         # executable dispatch below runs inside ``self._prof.measure``.
         # A disabled ledger's measure() is a shared no-op — the hooks
@@ -1620,7 +1641,7 @@ class ContinuousBatcher:
     TIMELINE_FIELDS = (
         "id", "prompt_len", "bucket", "tokens", "cache_hit_tokens",
         "submit_t", "admit_t", "decode_start_t", "first_dispatch_t",
-        "first_tok_t", "done_t",
+        "first_tok_t", "done_t", "insert_t", "admit_poll",
     )
 
     def capture_requests(self) -> List[Dict[str, Any]]:
@@ -1633,6 +1654,11 @@ class ContinuousBatcher:
             row.update(dataclasses.asdict(entry[-1]))
             out.append(row)
         return out
+
+    def capture_polls(self) -> List[Dict[str, Any]]:
+        """The flight recorder's rows as they stand, oldest first, every
+        type (``t`` absolute monotonic seconds); ``[]`` with the ring off."""
+        return self.flight.snapshot() if self.flight is not None else []
 
     @caller_thread
     def _shed_check(
@@ -3472,6 +3498,16 @@ class ContinuousBatcher:
         }
 
     @scheduler_only
+    def _count_admitted(self, *reqs: GenRequest) -> None:
+        """``reqs``' lanes are live from this poll on: the count, each
+        request's ``admit_poll``, and their ids for the poll's record."""
+        self.stats["admitted"] += len(reqs)
+        for req in reqs:
+            req.admit_poll = self._poll_count
+        if self.flight is not None and self.flight.enabled:
+            self._row_admitted.extend(req.rid for req in reqs)
+
+    @scheduler_only
     def _start_chunked(self, slot: int, req: GenRequest, hit=None,
                        resume=None) -> None:
         """Reserve ``slot`` and queue the prompt for interleaved chunked
@@ -3568,6 +3604,7 @@ class ContinuousBatcher:
                     )
                     _m.sync(job.slab)
                 if is_last:
+                    req.insert_t = time.monotonic()
                     if job.resume is not None:
                         # recompute-resume: the checkpointed continuation
                         # state replaces the chunk's own sample
@@ -3593,6 +3630,10 @@ class ContinuousBatcher:
                             )
                         )
                         _m.sync(self._cur_tok)
+                else:
+                    # an output of the chunk's program, and the array an
+                    # insert or a burst after it would have replaced
+                    self._chunk_newest = (lane_key, self._cur_tok)
             except Exception as e:  # noqa: BLE001 - bad request/device state
                 logger.exception("chunked prefill failed")
                 del self._chunked[slot]
@@ -3627,7 +3668,7 @@ class ContinuousBatcher:
                 self._active[slot] = _Slot(request=req)
                 self._pos_host[slot] = n
                 self._masks_dirty = True
-                self.stats["admitted"] += 1
+                self._count_admitted(req)
             else:
                 job.next_start = end
 
@@ -3768,6 +3809,7 @@ class ContinuousBatcher:
             if self._prefix_index is not None:
                 self.stats["prefix_misses"] += 1
         t_inserted = time.monotonic()
+        req.insert_t = t_admit      # no prefill here: the insert is all
         req.decode_start_t = t_inserted
         self._emit_span(
             req, "gen.queue_wait", req.submit_t, t_admit,
@@ -3781,7 +3823,7 @@ class ContinuousBatcher:
         self._active[slot] = _Slot(request=req)
         self._pos_host[slot] = n
         self._masks_dirty = True
-        self.stats["admitted"] += 1
+        self._count_admitted(req)
         self.stats["kv_imports"] += 1
         self.stats["kv_import_bytes"] += r["nbytes"]
         if covered:
@@ -4167,19 +4209,27 @@ class ContinuousBatcher:
         """Bring one in-flight burst's tokens to the host and credit them.
         ``entry`` is ``(mode, device arrays, (snapshot, ...), dispatch
         time)``; the ``np.asarray`` here is the burst's one host sync
-        (phase ``read_wait``), everything after it is ``credit``."""
+        (phase ``read_wait``), everything after it is ``credit``. The next
+        poll record takes the read along in its ``bursts``."""
         mode, arrays, rest, t_dispatch = entry
         # the device had finished this burst before the host came for it: the
         # host was the one waited for (an attribute check, no sync; an array
         # without is_ready, a test double's, is ready as the loop reads it)
-        ready = getattr(arrays[-1], "is_ready", None)
-        if ready is None or ready():
+        late = _is_ready(arrays[-1])
+        if late:
             self.stats["bursts_read_late"] += 1
         self._clock.to("read_wait")
         host = [np.asarray(a) for a in arrays]
         t_read = self._clock.to("credit")
         self.stats["bursts"] += 1
         self.stats["burst_read_lag_s_sum"] += t_read - t_dispatch
+        if self.flight is not None and self.flight.enabled:
+            self._row_bursts.append({
+                "dispatch_t": t_dispatch, "read_t": t_read,
+                # a plain burst's tokens: its k rows under the prefill's
+                "k": rest[1] if len(rest) > 1 else len(host[0]) - 1,
+                "lanes": len(rest[0]), "late": late,
+            })
         if self._step_counters and mode != "spec":
             # the model's own counters of the burst's steps: its last array
             for name, n in zip(self._step_counters, host.pop()):
@@ -4196,6 +4246,21 @@ class ContinuousBatcher:
         else:
             self._process_burst(*host, *rest)
         self._clock.to("other")
+
+    @scheduler_only
+    def _device_drained(self) -> bool:
+        """Whether the device has finished everything the loop ever gave
+        it: the chip runs one program at a time in dispatch order, so the
+        newest array done means all done. That array is ``_cur_tok`` (every
+        insert and every burst replaces it) unless a chunk that was not
+        its job's last went out since. An attribute check, no sync. What
+        only an option that is off by default dispatches (a prefix
+        extract, a draft's admit, a replay, a swap) is not looked at."""
+        newest = self._chunk_newest
+        if newest is not None and newest[1] is self._cur_tok:
+            return _is_ready(newest[0])
+        self._chunk_newest = None
+        return _is_ready(self._cur_tok)
 
     @scheduler_only
     def _pressure_poll(self, pending) -> None:
@@ -4884,6 +4949,7 @@ class ContinuousBatcher:
                 tags={"lane": slot, "bucket": bucket, "dispatch": True},
             )
         t_inserted = time.monotonic()
+        req.insert_t = t_insert
         req.decode_start_t = t_inserted
         self._emit_span(req, "gen.lane_insert", t_insert, t_inserted,
                         tags={"lane": slot, "dispatch": True})
@@ -4903,7 +4969,7 @@ class ContinuousBatcher:
         self._active[slot] = _Slot(request=req)
         self._pos_host[slot] = n
         self._masks_dirty = True
-        self.stats["admitted"] += 1
+        self._count_admitted(req)
 
     @scheduler_only
     def _admit_many(self, slots: List[int], reqs: List[GenRequest], bucket: int) -> None:
@@ -4941,6 +5007,7 @@ class ContinuousBatcher:
                 jnp.asarray(seeds), jnp.asarray(temps),
             )
             _pm.sync(slab)
+        t_insert = time.monotonic()
         with self._prof.measure(
             "insert", variant=f"m{m}b{bucket}", tenant=_wave_tenant,
             bytes_read=m * bucket * self._kv_key_bytes, tokens=m * bucket,
@@ -4956,6 +5023,7 @@ class ContinuousBatcher:
         t_inserted = time.monotonic()
         for slot, req in zip(slots, reqs):
             req.admit_t = t_admit
+            req.insert_t = t_insert
             req.decode_start_t = t_inserted
             self._emit_span(
                 req, "gen.queue_wait", req.submit_t, t_admit,
@@ -4969,7 +5037,7 @@ class ContinuousBatcher:
             self._active[slot] = _Slot(request=req)
             self._pos_host[slot] = len(req.tokens)
         self._masks_dirty = True
-        self.stats["admitted"] += m
+        self._count_admitted(*reqs)
         self.stats["prefill_steps"] += 1
         self.stats["prefill_tokens"] += m * bucket
         if self._prefix_index is not None:
@@ -5013,17 +5081,14 @@ class ContinuousBatcher:
             # a meaningless 0.0 in some views but not others
             tpot = (now - first) / (n_tok - 1) if n_tok > 1 else None
             self.stats["slo_samples"] += 1
-            self.stats["queue_wait_s_sum"] += queue_wait
             self.stats["ttft_s_sum"] += ttft
-            if tpot is not None:
-                self.stats["tpot_s_sum"] += tpot
             self.slo_pending.append((queue_wait, ttft, tpot))
             self.slo_recent.append((queue_wait, ttft, tpot))
             self.timeline_recent.append((
                 req.rid, len(req.tokens), self._bucket(len(req.tokens)),
                 n_tok, req.cache_hit_tokens, req.submit_t, req.admit_t,
                 req.decode_start_t, req.first_dispatch_t, req.first_tok_t,
-                now, req.front,
+                now, req.insert_t, req.admit_poll, req.front,
             ))
             if req.tenant is not None:
                 # per-tenant split of the same triple: the TenantScheduler
@@ -5031,16 +5096,8 @@ class ContinuousBatcher:
                 # the server drains tenant_slo_pending into tagged TIMER
                 # metrics — one sample feeds both, recorded here so a
                 # tenant's own response carries its own numbers
-                sums = self.tenant_slo.setdefault(req.tenant, {
-                    "slo_samples": 0.0, "queue_wait_s_sum": 0.0,
-                    "ttft_s_sum": 0.0, "tpot_s_sum": 0.0, "finished": 0.0,
-                })
-                sums["slo_samples"] += 1
-                sums["finished"] += 1
-                sums["queue_wait_s_sum"] += queue_wait
-                sums["ttft_s_sum"] += ttft
-                if tpot is not None:
-                    sums["tpot_s_sum"] += tpot
+                self.tenant_slo.setdefault(
+                    req.tenant, {"finished": 0.0})["finished"] += 1
                 self.tenant_slo_pending.setdefault(
                     req.tenant, collections.deque(maxlen=1024)
                 ).append((queue_wait, ttft, tpot))
@@ -5316,7 +5373,25 @@ class ContinuousBatcher:
     def _loop(self) -> bool:
         """One supervised run of the poll loop. Returns False on a clean
         ``close()`` stop, or :meth:`_crash_recover`'s verdict after a
-        loop death (True = run again on rebuilt state)."""
+        loop death (True = run again on rebuilt state).
+
+        **The poll record** (``"type": "poll"`` in the flight recorder;
+        one per iteration that admitted, advanced a chunk or dispatched a
+        burst) is a span of the scheduler thread's time. It is written
+        once the iteration's reads are done, so its stretch is the
+        iteration whole, reads included, plus every iteration before it
+        that wrote none (idle, or reads only): ``t`` is where the stretch
+        began (monotonic), ``phase_s`` the clock's seconds by phase over
+        it (``PhaseClock.lap``: the rows lie end to end and sum to the
+        clock's totals), ``bursts`` each burst read in it (``dispatch_t``,
+        ``read_t``, ``k``, ``lanes``, ``late``: the device had finished it
+        first), ``dispatched_t`` when this poll's burst went out,
+        ``poll`` the loop's poll number and ``admitted_ids`` the requests
+        whose lanes it activated (their timeline rows carry the same
+        number as ``admit_poll``), ``drained`` whether the device had
+        finished everything it was ever given when this poll came to its
+        first dispatch (:meth:`_device_drained`): it then sits idle until
+        that dispatch lands."""
         import jax.numpy as jnp
 
         from ..tracing import device_trace
@@ -5369,6 +5444,8 @@ class ContinuousBatcher:
                         self.stats["prefix_hits"], self.stats["prefix_evicted"],
                     )
                 poll_plan: Optional[Dict[str, Any]] = None
+                # sampled before this poll's first dispatch, whichever it is
+                drained: Optional[bool] = None
                 # -- live weight swap: drain, then flip at a poll boundary.
                 # While a swap is staged, admissions HOLD (queued submits
                 # wait) so in-flight lanes — decode, chunked prefill, and
@@ -5462,6 +5539,8 @@ class ContinuousBatcher:
                         wave_cost += cost
                     wave.append(req)
                 if wave:
+                    if flight is not None:
+                        drained = self._device_drained()
                     free_iter = iter(
                         i for i in range(self.slots)
                         if i not in self._active and i not in self._chunked
@@ -5592,9 +5671,13 @@ class ContinuousBatcher:
                     # admission, then the decode burst below — decode
                     # lanes keep their cadence while long prompts land
                     clock.to("chunks")
+                    if flight is not None and drained is None:
+                        drained = self._device_drained()
                     self._advance_chunks()
                 clock.to("dispatch")
                 if self._active:
+                    if flight is not None and drained is None:
+                        drained = self._device_drained()
                     if self._masks_dirty:
                         for i in range(self.slots):
                             temps[i] = (
@@ -5890,21 +5973,26 @@ class ContinuousBatcher:
                         if freed:
                             self._masks_dirty = True
                 clock.to("other")
+                entry: Optional[Dict[str, Any]] = None
                 if flight is not None:
                     admitted = self.stats["admitted"] - f0[0]
                     chunks = self.stats["prefill_chunks"] - f0[1]
                     hits = self.stats["prefix_hits"] - f0[2]
                     evicted = self.stats["prefix_evicted"] - f0[3]
                     if poll_plan is not None or admitted or chunks:
-                        entry: Dict[str, Any] = {
+                        entry = {
                             "type": "poll",
+                            "poll": self._poll_count,
                             "queue": self._queue.qsize(),
                             "active": len(self._active),
                             "chunked": len(self._chunked),
                             "pending_bursts": len(pending),
+                            "drained": drained,
                         }
                         if admitted:
                             entry["admitted"] = admitted
+                            entry["admitted_ids"] = self._row_admitted
+                            self._row_admitted = []
                         if chunks:
                             entry["prefill_chunks"] = chunks
                         if hits:
@@ -5913,6 +6001,7 @@ class ContinuousBatcher:
                             entry["prefix_evicted"] = evicted
                         if poll_plan is not None:
                             entry["plan"] = poll_plan
+                            entry["dispatched_t"] = t_dispatch
                         if self._prof.enabled:
                             # per-poll device-time ledger deltas ride the
                             # poll record; quiet-poll leftovers roll into
@@ -5920,7 +6009,6 @@ class ContinuousBatcher:
                             dt_rows = self._prof.poll_flush()
                             if dt_rows:
                                 entry["device_time"] = dt_rows
-                        flight.record(entry)
                 # read bursts oldest-first: always when the pipeline is full
                 # (or nothing is left to dispatch) — and OPPORTUNISTICALLY
                 # when a burst's token copy has already landed on the host
@@ -5932,13 +6020,17 @@ class ContinuousBatcher:
                         # last-initiated transfer of the oldest burst
                         # (its arrays copy in order): if IT landed,
                         # np.asarray of the earlier ones won't block either
-                        head = pending[0][1][-1]
-                        try:
-                            if not head.is_ready():
-                                break
-                        except AttributeError:
-                            pass  # non-jax array (test doubles): treat as ready
+                        if not _is_ready(pending[0][1][-1]):
+                            break
                     self._read_burst(pending.popleft())
+                if entry is not None:
+                    # the reads are done: the record's stretch ends at the
+                    # last switch of the clock (no read of its own)
+                    entry["t"], entry["phase_s"] = clock.lap()
+                    if self._row_bursts:
+                        entry["bursts"] = self._row_bursts
+                        self._row_bursts = []
+                    flight.record(entry)
         except Exception:  # noqa: BLE001 - every loop death is supervised
             logger.exception("continuous batcher loop died")
             return self._crash_recover(pending)

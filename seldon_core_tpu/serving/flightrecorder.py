@@ -19,14 +19,23 @@ under the GIL); readers snapshot with ``list(...)`` and never block the
 scheduler. ``enabled = False`` short-circuits to a single attribute
 check on the hot path.
 
-Consumed by the engine's ``/flightrecorder`` route (graph/service.py)
-and ``tools/flight_report.py``, which turns a dump into a human-readable
-diagnosis.
+Every record carries ``t`` (``time.monotonic()``: the clock of the
+request stamps and of a capture's ``t0``/``t1``) and ``t_us`` (the same
+moment as wall-clock microseconds, for the route's readers). A ``poll``
+record is a span, not a point: its ``t`` is where the stretch it accounts
+for began, and its ``phase_s`` says where the scheduler thread's time
+went from there (see ``ContinuousBatcher._loop``).
+
+Consumed by the engine's ``/flightrecorder`` route (graph/service.py),
+``tools/flight_report.py``, which turns a dump into a human-readable
+diagnosis, and ``tracing.stop_capture()``, whose report carries the ring
+as ``polls``.
 """
 
 from __future__ import annotations
 
 import itertools
+import time
 from collections import deque
 from typing import Any, Dict, List, Optional
 
@@ -36,7 +45,7 @@ from ..tracing import wall_us
 class FlightRecorder:
     """Bounded, drop-oldest ring buffer of scheduler decision records."""
 
-    def __init__(self, capacity: int = 512, enabled: bool = True):
+    def __init__(self, capacity: int = 4096, enabled: bool = True):
         self.capacity = int(capacity)
         self.enabled = bool(enabled) and self.capacity > 0
         self._ring: deque = deque(maxlen=max(1, self.capacity))
@@ -47,15 +56,16 @@ class FlightRecorder:
 
     def record(self, entry: Dict[str, Any]) -> None:
         """Append one record. The caller owns ``entry`` (it is stored, not
-        copied); ``seq``/``t_us`` are stamped here so every record is
-        orderable and wall-clock attributable."""
+        copied); ``seq``, ``t`` and ``t_us`` are stamped here, from one
+        clock read, so every record is orderable and attributable on the
+        monotonic and the wall clock. A ``t`` the caller set stays (a
+        poll record's is where its stretch began)."""
         if not self.enabled:
             return
         entry["seq"] = next(self._seq)
-        # monotonic-anchored wall stamp: flight_report diffs t_us between
-        # records to attribute poll gaps — an NTP step under a raw
-        # time.time() would turn those intervals into lies
-        entry.setdefault("t_us", wall_us())
+        now = time.monotonic()
+        entry.setdefault("t", now)
+        entry.setdefault("t_us", wall_us(now))
         self._ring.append(entry)
 
     def snapshot(self, limit: Optional[int] = None) -> List[Dict[str, Any]]:

@@ -594,7 +594,8 @@ class PhaseClock:
     unaccounted: between :meth:`start` and :meth:`stop` the thread is in
     exactly one phase. One ``time.monotonic()`` read and one annotation
     per switch; no lock, no allocation beyond the annotation itself.
-    :meth:`read` may be called from any thread."""
+    :meth:`read` may be called from any thread; :meth:`lap` is the
+    owner's."""
 
     def __init__(self, counters: Dict[str, Any], prefix: str, key: str,
                  phases: Sequence[str], rest: str = "other"):
@@ -609,12 +610,20 @@ class PhaseClock:
         self._edge = None                   # see reenter()
         self._seq = 0                       # odd while a switch is half done
         self._t_start = self._t_stop = 0.0
+        self._lap_t = 0.0                   # where the last lap ended
+        self._lap_totals = self._totals()
+
+    def _totals(self) -> list:
+        return [self._counters[k] for k in self._key.values()]
 
     def start(self) -> None:
         now = time.monotonic()
         # a later start takes up where the accounted time left off
-        self._t_start = now - sum(self._counters[k] for k in self._key.values())
+        self._t_start = now - sum(self._totals())
         self._at = (self._rest, now)
+        # laps start over: what a stopped loop left unlapped is dropped
+        self._lap_t = now
+        self._lap_totals = self._totals()
         self._span = device_trace(self._span_name[self._rest])
         self._span.__enter__()
 
@@ -658,6 +667,19 @@ class PhaseClock:
         self._span = None
         self._at = None
 
+    def lap(self) -> tuple:
+        """Owner thread only: ``(t, {phase: seconds})`` of the stretch from
+        where the last lap ended (``t``, monotonic) to the last switch,
+        phases with no time left out. No clock read: the phase in
+        progress goes to the next lap, so laps lie end to end, ``t`` plus
+        a lap's seconds is the next lap's ``t``, and the laps sum to the
+        counters."""
+        t, was = self._lap_t, self._lap_totals
+        now = self._lap_totals = self._totals()
+        if self._at is not None:
+            self._lap_t = self._at[1]
+        return t, {p: b - a for p, a, b in zip(self._key, was, now) if b > a}
+
     def read(self) -> Dict[str, float]:
         """``{<phase>_s..., wall_s}`` up to now, the phase in progress
         included, so the phases sum to ``wall_s``."""
@@ -674,6 +696,11 @@ class PhaseClock:
         return out
 
 
+# a host span of this name, the reading after it, opens every profiled
+# capture: ``time.monotonic()`` as the span began
+CAPTURE_CLOCK_SPAN = "capture.clock monotonic_s="
+
+
 class CaptureError(RuntimeError):
     """``start_capture`` while a capture runs, or ``stop_capture``
     without one."""
@@ -686,6 +713,8 @@ class CaptureControl:
 
     ``capture_counters() -> {group: {name: number}}``  running totals
     ``capture_requests() -> [dict]``                   recent request timelines
+    ``capture_polls() -> [dict]``                      the flight recorder's rows
+                                                       (optional: ``[]`` without)
     ``capture_started()``                              the profiler records now
 
     held weakly, so a closed server drops out. A capture is a call, not a
@@ -703,10 +732,7 @@ class CaptureControl:
         with self._lock:
             if self._running is not None:
                 raise CaptureError("a capture is already running")
-            # one pair read back to back: places monotonic stamps on the
-            # profiler's clock (unix nanoseconds)
             t0 = time.monotonic()
-            wall_unix_ns = time.time_ns()
             # held strongly until the stop, so both readings are of the
             # same source
             source = self._source()
@@ -719,22 +745,28 @@ class CaptureControl:
                 options = jax.profiler.ProfileOptions()
                 options.python_tracer_level = 0
                 jax.profiler.start_trace(logdir, profiler_options=options)
+                # the trace counts from its session's start, which no
+                # caller sees: a span named by the monotonic reading it
+                # begins at places monotonic stamps on the trace itself
+                with device_trace(
+                        f"{CAPTURE_CLOCK_SPAN}{time.monotonic():.9f}"):
+                    pass
                 if source is not None:
                     source.capture_started()
             self._running = {
                 "t0": t0, "source": source, "before": before,
                 "profiling": logdir is not None,
-                "clock": {"monotonic_s": t0, "unix_ns": wall_unix_ns},
             }
             return {"t": t0}
 
     def stop(self) -> Dict[str, Any]:
         """Ends the capture and returns its report: ``t0``/``t1``
-        (monotonic), ``clock`` (one monotonic/unix pair), each counter
-        group of the source as differences over the capture (the
-        batcher's ``loop`` and ``counters``), and ``requests`` — every
-        timeline the source still holds, stamps absolute monotonic, so a
-        reader selects by window."""
+        (monotonic; the ``CAPTURE_CLOCK_SPAN`` in a profiled capture's
+        trace places them, and every other monotonic stamp, on the trace),
+        each counter group of the source as differences over the capture
+        (the batcher's ``loop`` and ``counters``), and ``requests`` and ``polls``
+        — every timeline and every flight-recorder row the source still
+        holds, stamps absolute monotonic, so a reader selects by window."""
         with self._lock:
             run = self._running
             if run is None:
@@ -745,18 +777,19 @@ class CaptureControl:
             try:
                 after = source.capture_counters() if source is not None else {}
                 requests = source.capture_requests() if source is not None else []
+                polls = getattr(source, "capture_polls", list)()
             finally:
                 if run["profiling"]:
                     import jax.profiler
 
                     jax.profiler.stop_trace()
-        report: Dict[str, Any] = {"t0": run["t0"], "t1": t1,
-                                  "clock": run["clock"]}
+        report: Dict[str, Any] = {"t0": run["t0"], "t1": t1}
         for group, values in after.items():
             before = run["before"].get(group, {})
             report[group] = {k: v - before.get(k, 0) for k, v in values.items()
                              if isinstance(v, (int, float))}
         report["requests"] = requests
+        report["polls"] = polls
         return report
 
 

@@ -7,13 +7,22 @@ account for its wall time; (3) a capture changes no token and compiles
 nothing; (4) ``start_capture`` / ``stop_capture`` are a strict pair; (5) a
 streamed request's scheduler and front spans share one trace id; (6) each
 per-layer reader of the report computes what its file says, under both
-shapes of a traced run, and reads nothing where there is no report.
+shapes of a traced run, and reads nothing where there is no report; (7) the
+flight recorder's poll rows are spans of the scheduler thread's time: they
+lie end to end, sum to the loop's clock, name the requests they admitted
+and the bursts they read, and a held dispatch or burst shows as seconds in
+one row; the ring changes no token, compiles nothing, and rides the
+capture's report.
 """
 
 import asyncio
+import importlib.util
+import itertools
 import json
 import os
+import time
 
+import numpy as np
 import pytest
 
 from seldon_core_tpu import tracing
@@ -39,8 +48,9 @@ PROMPTS = [[3, 17, 42, 99, 7], [1, 2, 3], [9, 8, 7, 6], [5, 5, 5, 5, 5, 5]]
 BUDGETS = [20, 7, 13, 9]
 LONG_PROMPTS = [list(range(1, 30)), list(range(40, 60))]   # bucket 32
 
-SCHEDULER_STAMPS = ("submit_t", "admit_t", "decode_start_t",
+SCHEDULER_STAMPS = ("submit_t", "admit_t", "insert_t", "decode_start_t",
                     "first_dispatch_t", "first_tok_t", "done_t")
+PHASES = ("admit", "chunks", "dispatch", "read_wait", "credit", "idle", "other")
 
 
 @pytest.fixture(scope="module")
@@ -64,6 +74,17 @@ def run_batch(b, prompts=PROMPTS, temperature=0.0):
         for i, (p, m) in enumerate(zip(prompts, BUDGETS))
     ]
     return [f.result(timeout=120) for f in futures]
+
+
+def run_one_at_a_time(b, temperature):
+    """Which lanes share a burst never depends on timing."""
+    return [b.submit(p, max_new_tokens=m, temperature=temperature,
+                     seed=11 + i).result(timeout=120)
+            for i, (p, m) in enumerate(zip(PROMPTS, BUDGETS))]
+
+
+def poll_rows(b):
+    return [r for r in b.capture_polls() if r["type"] == "poll"]
 
 
 def run_in_one_wave(b, prompts):
@@ -96,6 +117,7 @@ def test_timeline_stamps_are_ordered(model_and_params, admission):
     try:
         outs = run(b, prompts)
         rows = b.capture_requests()
+        polls = {r["poll"]: r for r in poll_rows(b)}
         if admission == "chunked":
             assert b.stats["prefill_chunks"] >= 2 * len(prompts)
         if admission == "batched":
@@ -113,6 +135,9 @@ def test_timeline_stamps_are_ordered(model_and_params, admission):
             assert row["tokens"] == len(out) - len(prompt)
         assert row["bucket"] >= row["prompt_len"]
         assert row["cache_hit_tokens"] == 0
+        # the request names the poll that activated its lane, and that
+        # poll's row names the request
+        assert row["id"] in polls[row["admit_poll"]]["admitted_ids"]
         # no front served these: its stamps stay unset
         assert row["received_t"] == row["first_write_t"] == row["done_write_t"] == 0.0
     assert len({row["id"] for row in rows}) == len(rows)
@@ -142,8 +167,7 @@ def test_loop_phases_account_for_the_wall_time(model_and_params, kw):
     assert all(v >= 0.0 for v in phases.values())
     assert sum(phases.values()) == pytest.approx(loop["wall_s"], rel=0.05)
     assert loop["wall_s"] == pytest.approx(report["t1"] - report["t0"], rel=0.05)
-    assert report["t0"] == started["t"] == report["clock"]["monotonic_s"]
-    assert report["clock"]["unix_ns"] > 0
+    assert report["t0"] == started["t"] and "clock" not in report
     assert loop["polls"] > 0 and loop["bursts"] > 0
     assert loop["burst_read_lag_s_sum"] > 0.0
     assert loop["dispatch_s"] > 0.0 and loop["read_wait_s"] > 0.0
@@ -197,13 +221,17 @@ def test_phase_in_progress_at_capture_start_is_in_the_trace(tmp_path, reenter):
     control.start(str(tmp_path))
     go.set()
     thread.join()
-    control.stop()
+    report = control.stop()
     found = [os.path.join(d, f) for d, _s, fs in os.walk(tmp_path)
              for f in fs if f.endswith(".xplane.pb")]
-    names = [e.name for plane in ProfileData.from_file(found[0]).planes
-             for line in plane.lines for e in line.events
-             if e.name.startswith("batcher.")]
+    events = [e.name for plane in ProfileData.from_file(found[0]).planes
+              for line in plane.lines for e in line.events]
+    names = [n for n in events if n.startswith("batcher.")]
     assert names == (["batcher.read_wait"] if reenter else []) + ["batcher.other"]
+    # the trace's one anchor to the report's clock: the span that opens it
+    # is named by the monotonic reading it began at
+    mark, = (n for n in events if n.startswith(tracing.CAPTURE_CLOCK_SPAN))
+    assert report["t0"] <= float(mark[len(tracing.CAPTURE_CLOCK_SPAN):]) <= report["t1"]
 
 
 # -- a capture changes nothing ---------------------------------------------------
@@ -213,12 +241,7 @@ def test_phase_in_progress_at_capture_start_is_in_the_trace(tmp_path, reenter):
 def test_capture_on_off_byte_identical_and_no_new_executables(
         model_and_params, tmp_path, temperature):
     def run(b):
-        if not temperature:
-            return run_batch(b)
-        # one at a time: which lanes share a burst never depends on timing
-        return [b.submit(p, max_new_tokens=m, temperature=temperature,
-                         seed=11 + i).result(timeout=120)
-                for i, (p, m) in enumerate(zip(PROMPTS, BUDGETS))]
+        return run_one_at_a_time(b, temperature) if temperature else run_batch(b)
 
     b_off = make_batcher(model_and_params, fused_steps_per_dispatch=8)
     try:
@@ -321,6 +344,232 @@ def test_sse_request_is_one_trace_with_front_and_scheduler_spans(tmp_path):
             server.batcher.close()
 
 
+# -- the poll record carries the loop's clock -----------------------------------------
+
+
+@pytest.mark.parametrize("kw", [{}, {"fused_steps_per_dispatch": 8},
+                                {"prefill_chunk": 16}],
+                         ids=["plain", "fused", "chunked"])
+def test_poll_rows_lie_end_to_end_and_sum_to_the_clock(model_and_params, kw):
+    prompts = LONG_PROMPTS if "prefill_chunk" in kw else PROMPTS
+    b = make_batcher(model_and_params, **kw)
+    try:
+        run_batch(b, prompts)
+        time.sleep(0.2)     # idle iterations write no row: the next one takes them
+        run_batch(b, prompts)
+    finally:
+        b.close()
+    rows = poll_rows(b)
+    assert len(rows) > 4
+    assert [r["seq"] for r in rows] == sorted(r["seq"] for r in rows)
+    # what the stopped loop left after its last row is one more lap
+    t_end, rest = b._clock.lap()
+    total = dict.fromkeys(PHASES, 0.0)
+    for row, t_next in zip(rows, [r["t"] for r in rows[1:]] + [t_end]):
+        assert set(row["phase_s"]) <= set(PHASES)
+        assert all(v > 0.0 for v in row["phase_s"].values())     # zeros left out
+        assert row["t"] + sum(row["phase_s"].values()) == pytest.approx(
+            t_next, abs=1e-6)
+        for phase, v in row["phase_s"].items():
+            total[phase] += v
+    for phase, v in rest.items():
+        total[phase] += v
+    clock = b._clock.read()
+    for phase in PHASES:
+        assert total[phase] == pytest.approx(clock[f"{phase}_s"], rel=1e-9,
+                                             abs=1e-9)
+    # the idle stretch went to ONE row, the first of the second batch
+    after_idle = [r for r in rows if r["phase_s"].get("idle", 0.0) >= 0.15]
+    assert len(after_idle) == 1
+    assert after_idle[0].get("admitted") or after_idle[0]["prefill_chunks"]
+    assert all("chunks" in r["phase_s"] for r in rows if r.get("prefill_chunks"))
+
+
+def hold(fn, seconds):
+    """``fn`` with its first call held ``seconds`` before it goes out: a
+    dispatch that blocks."""
+    calls = itertools.count()
+
+    def held(*args):
+        if next(calls) == 0:
+            time.sleep(seconds)
+        return fn(*args)
+    return held
+
+
+def test_a_held_insert_is_one_row_that_names_its_request(model_and_params):
+    b = make_batcher(model_and_params)
+    try:
+        # one request alone: _admit's own prefill and insert are compiled
+        b.submit([7, 8, 9], max_new_tokens=4).result(timeout=120)
+        warm = len(poll_rows(b))    # those rows hold the compiles
+        b._insert_fn = hold(b._insert_fn, 0.3)
+        b.submit([4, 5, 6], max_new_tokens=4).result(timeout=120)
+        request = b.capture_requests()[-1]
+    finally:
+        b.close()
+    slow = [r for r in poll_rows(b)[warm:]
+            if r["phase_s"].get("admit", 0.0) >= 0.3]
+    assert len(slow) == 1
+    row = slow[0]
+    assert row["admitted"] == 1 and row["admitted_ids"] == [request["id"]]
+    assert request["admit_poll"] == row["poll"]
+    # the seconds lie in the insert's dispatch, not the prefill's
+    assert request["decode_start_t"] - request["insert_t"] >= 0.3
+    assert request["insert_t"] - request["admit_t"] < 0.3
+    end = row["t"] + sum(row["phase_s"].values())
+    assert row["t"] <= request["admit_t"] <= request["decode_start_t"] <= end
+    assert row["dispatched_t"] >= request["decode_start_t"]
+
+
+class HeldTokens:
+    """A burst's tokens as a test double holds them: not ready, and a read
+    of them blocks, until ``until``."""
+
+    def __init__(self, array, until):
+        self.array, self.until = array, until
+
+    def is_ready(self):
+        return time.monotonic() >= self.until
+
+    def copy_to_host_async(self):
+        pass
+
+    def __array__(self, dtype=None, copy=None):
+        time.sleep(max(0.0, self.until - time.monotonic()))
+        return np.asarray(self.array)
+
+
+def test_a_held_burst_is_read_wait_in_one_row_and_the_device_is_not_drained(
+        model_and_params, monkeypatch):
+    from seldon_core_tpu.serving import continuous
+
+    held = []       # (array, until): the double's word on what the device holds
+    is_ready = continuous._is_ready
+    monkeypatch.setattr(continuous, "_is_ready", lambda a: is_ready(a) and not any(
+        a is array and time.monotonic() < until for array, until in held))
+    b = make_batcher(model_and_params)
+    try:
+        run_batch(b)
+        warm = len(poll_rows(b))    # those rows hold the compiles
+        time.sleep(0.2)
+        burst_fn, calls = b._burst_fn, itertools.count()
+
+        def second_burst_held(*args):
+            toks, cur_tok, *rest = burst_fn(*args)
+            if next(calls) == 1:
+                until = time.monotonic() + 0.35
+                held.append((cur_tok, until))       # what a burst leaves newest
+                toks = HeldTokens(toks, until)
+            return (toks, cur_tok, *rest)
+
+        b._burst_fn = second_burst_held
+        b.submit([4, 5, 6], max_new_tokens=12).result(timeout=120)
+        unclaimed = list(b._row_bursts)
+    finally:
+        b.close()
+    rows = poll_rows(b)
+    # the first poll after an idle stretch finds the device drained
+    after_idle = [r for r in rows if r["phase_s"].get("idle", 0.0) >= 0.15]
+    assert len(after_idle) == 1 and after_idle[0]["drained"] is True
+    slow = [r for r in rows[warm:]
+            if r["phase_s"].get("read_wait", 0.0) >= 0.3]
+    assert len(slow) == 1
+    row = slow[0]
+    # the host waited for the held burst; one queued behind it was done by
+    # the time the host came for it
+    waited, *behind = row["bursts"]
+    assert waited["late"] is False and all(x["late"] for x in behind)
+    assert waited["read_t"] - waited["dispatch_t"] >= 0.3
+    assert waited["k"] == 2 and waited["lanes"] == 1
+    # the held burst was in flight when this poll came to its dispatch
+    assert row["drained"] is False and row["pending_bursts"] == 2
+    before = rows[rows.index(row) - 1]
+    assert before["dispatched_t"] == waited["dispatch_t"]
+    # every burst read is in some row, and `late` is the counter's own check
+    read = [x for r in rows for x in r.get("bursts", ())] + unclaimed
+    assert len(read) == b.stats["bursts"]
+    assert sum(x["late"] for x in read) == b.stats["bursts_read_late"]
+    assert all(x["dispatch_t"] <= x["read_t"] for x in read)
+
+
+@pytest.mark.parametrize("temperature", [0.0, 0.8], ids=["greedy", "seeded"])
+def test_ring_on_off_byte_identical_and_no_new_executables(
+        model_and_params, temperature):
+    outs, caches, rows = [], [], []
+    for capacity in (4096, 0):
+        b = make_batcher(model_and_params, fused_steps_per_dispatch=8,
+                         flight_recorder_capacity=capacity)
+        try:
+            outs.append(run_one_at_a_time(b, temperature))
+            caches.append(jit_cache_size(b))
+            rows.append(b.capture_polls())
+        finally:
+            b.close()
+    assert outs[0] == outs[1]
+    assert caches[0] == caches[1]
+    assert rows[0] and rows[1] == [] and b.flight is None
+    assert b.capture_requests()[-1]["admit_poll"] > 0      # stamped all the same
+
+
+class NoRing:
+    """A capture source from before the ring rode the report."""
+
+    def capture_counters(self):
+        return {}
+
+    def capture_requests(self):
+        return []
+
+
+@pytest.mark.parametrize("source", ["batcher", "ring_off", "without_the_method",
+                                    "none"])
+def test_the_report_carries_the_ring(model_and_params, source):
+    control = tracing.CaptureControl()
+    b = None
+    if source in ("batcher", "ring_off"):
+        b = make_batcher(model_and_params,
+                         flight_recorder_capacity=4096 if source == "batcher" else 0)
+        control.register(b)
+    elif source == "without_the_method":
+        keep = NoRing()
+        control.register(keep)
+    try:
+        if b is not None:
+            b.flight and b.flight.record({"type": "shed", "reason": "queue_full"})
+            run_batch(b)
+        control.start()
+        if b is not None:
+            run_batch(b)
+        report = control.stop()
+    finally:
+        if b is not None:
+            b.close()
+    if source != "batcher":
+        assert report["polls"] == []
+        return
+    rows = json.loads(json.dumps(report["polls"]))      # plain types
+    assert rows == report["polls"]
+    # the ring as it stands: every type, and back past the capture's start
+    assert rows[0]["type"] == "shed" and rows[0]["t"] < report["t0"]
+    t0, t1 = report["t0"], report["t1"]
+    polls = [dict(r, end=r["t"] + sum(r["phase_s"].values())) for r in rows[1:]]
+    assert all(r["type"] == "poll" for r in polls)
+    touching = [r for r in polls if r["end"] > t0 and r["t"] < t1]
+    assert sum(r.get("admitted", 0) for r in touching) == len(PROMPTS)
+    # each phase's seconds over the rows that lie inside the capture agree
+    # with the report's own, to within the rows that straddle its edges and
+    # what the loop spent after its last row
+    inside = [r for r in touching if t0 <= r["t"] and r["end"] <= t1]
+    assert inside
+    edges = sum(r["end"] - r["t"] for r in touching if r not in inside)
+    edges += t1 - touching[-1]["end"]
+    for phase in PHASES:
+        over = report["loop"][f"{phase}_s"] - sum(
+            r["phase_s"].get(phase, 0.0) for r in inside)
+        assert -1e-6 <= over <= edges + 1e-6, (phase, over, edges)
+
+
 # -- the benchmark's readers of the report --------------------------------------------
 
 WINDOW = (100.0, 140.0)
@@ -341,9 +590,42 @@ def _request(submit_t, hold_s, front_s):
     }
 
 
+def _poll(seq, t, dispatched_t=None, drained=False, **phase_s):
+    row = {"type": "poll", "seq": seq, "poll": 10 * seq, "t": t,
+           "drained": drained, "phase_s": phase_s, "pending_bursts": 2}
+    if dispatched_t is not None:
+        row["dispatched_t"] = dispatched_t
+    return row
+
+
+def _polls(shape):
+    """The ring of a hand-built run: a stalled row before the window opened,
+    a row of another type, six poll rows inside it of which one closes no
+    burst period (it waited idle) and, where the capture lies after the
+    window, one more inside and a stalled one past its end."""
+    rows = [
+        _poll(0, 95.0, 95.0, True, admit=9.0, read_wait=9.0),
+        {"type": "shed", "seq": 1, "t": 100.2, "reason": "queue_full"},
+        _poll(2, 100.50, 100.50, True, dispatch=0.001, read_wait=0.049),
+        _poll(3, 100.55, 100.55, read_wait=0.045, credit=0.005),
+        _poll(4, 100.60, 101.01, admit=0.4, dispatch=0.01, read_wait=0.05),
+        _poll(5, 101.06, 101.07, read_wait=0.04),
+        _poll(6, 101.10, 101.31, True, idle=0.2, admit=0.01, read_wait=0.11),
+        _poll(7, 101.43, 101.44, read_wait=0.06, other=0.01),
+    ]
+    if shape != "trace1":
+        rows += [_poll(8, 101.50, 101.51, admit=0.7, read_wait=0.02),
+                 _poll(9, 141.0, 141.0, True, admit=3.0, read_wait=3.0)]
+    if shape == "wrapped":      # the window's first rows fell off the ring
+        rows = rows[3:]
+    return rows
+
+
 def _run(shape):
     """A hand-built ``run`` as ``benchmark/run.py`` hands it to a reader:
-    ``trace1`` captures inside the window, ``trace2`` after it."""
+    ``trace1`` captures inside the window, ``trace2`` after it;
+    ``no_polls`` and ``wrapped`` are ``trace2`` from a program whose report
+    carries no ring, and whose ring did not hold the window."""
     before = _request(95.0, 1.0, 0.100)             # before the window opened
     in_window = [_request(101.0, 0.3, 0.004), _request(103.0, 0.5, 0.008)]
     if shape == "trace1":
@@ -356,13 +638,14 @@ def _run(shape):
                     _request(142.0, 0.9, 0.100)]
     program = None if shape == "no_report" else {
         "t0": trace_window[0], "t1": trace_window[1],
-        "clock": {"monotonic_s": trace_window[0], "unix_ns": 1},
         "loop": {"admit_s": 0.2, "chunks_s": 0.0, "dispatch_s": 0.5,
                  "read_wait_s": 2.0, "credit_s": 0.2, "idle_s": 1.0,
                  "other_s": 0.1, "wall_s": 4.0, "polls": 30, "bursts": 20,
                  "burst_read_lag_s_sum": 6.0},
         "counters": {}, "requests": requests,
     }
+    if shape not in ("no_report", "no_polls"):
+        program["polls"] = _polls(shape)
     stop = {"t": trace_window[1], "stats": {}, "slo": []}
     if program is not None:
         stop["program"] = program
@@ -385,17 +668,100 @@ READINGS = {
     "scheduler_host_share": (25.0, 25.0),
     "prefill_device_share": (10.0, 10.0),
 }
+# the readers of the ring over the WINDOW: rows whose ``t`` lies in it
+POLL_READINGS = {
+    "admit_turn_max_ms": (400.0, 700.0),
+    "read_wait_max_ms": (110.0, 110.0),
+    "dispatch_found_drained_share": (100.0 * 2 / 6, 100.0 * 2 / 7),
+    # periods 50, 460, 60, 130 (, 70): 99% of the way to the largest
+    "burst_period_p99_ms": (130.0 + 0.97 * 330.0, 130.0 + 0.96 * 330.0),
+}
 
 
-@pytest.mark.parametrize("shape", ["trace1", "trace2", "no_report"])
-@pytest.mark.parametrize("metric", sorted(READINGS))
-def test_layer_reader_on_a_hand_built_run(metric, shape):
+@pytest.mark.parametrize("shape", ["trace1", "trace2", "no_report", "no_polls",
+                                   "wrapped"])
+@pytest.mark.parametrize("metric", sorted({**READINGS, **POLL_READINGS}))
+def test_layer_reader_on_a_hand_built_run(metric, shape, capsys):
     from benchmark import manifest
 
     man = manifest.load(ROOT)
     assert metric in {m["name"] for m in man["per_layer"]}
     value = manifest.layer_reader(ROOT, man, metric)(_run(shape))
-    if shape == "no_report":
+    said = capsys.readouterr().err
+    if shape == "no_report" or (metric in POLL_READINGS
+                                and shape in ("no_polls", "wrapped")):
         assert value is None
+        assert ("wrapped inside the window" in said) == (
+            shape == "wrapped" and metric in POLL_READINGS)
     else:
-        assert value == pytest.approx(READINGS[metric][shape == "trace2"])
+        expected = {**READINGS, **POLL_READINGS}[metric]
+        assert value == pytest.approx(expected[shape != "trace1"])
+        # a ring read inside the window says how much of it the rows cover
+        assert ("cover its first 5.0s of 40.0s" in said) == (
+            shape == "trace1" and metric in POLL_READINGS)
+        if metric == "burst_period_p99_ms":     # with its counts
+            assert (f"over {4 + (shape != 'trace1')} periods "
+                    f"(1 across an idle wait dropped)") in said
+
+
+def test_a_burst_period_spans_a_row_that_dispatched_no_burst(capsys):
+    """A poll that only admitted or read lies INSIDE the period from the
+    burst before it to the burst after it; only an idle wait breaks a run
+    of bursts, wherever in the gap the row that idled lies."""
+    from benchmark import manifest
+
+    run = _run("trace2")
+    run["trace_counters"][1]["program"]["polls"] = [
+        _poll(0, 100.00, 100.00, read_wait=0.05),
+        _poll(1, 100.05, 100.05, read_wait=0.05),
+        _poll(2, 100.10, None, admit=2.7),              # admitted, no burst
+        _poll(3, 102.80, 102.81, read_wait=0.05),       # 2760 ms after row 1
+        _poll(4, 102.86, None, idle=1.0, admit=0.01),   # idled, no burst
+        _poll(5, 103.87, 103.88, read_wait=0.05),       # across the wait
+        _poll(6, 103.93, 103.93, read_wait=0.05),
+    ]
+    man = manifest.load(ROOT)
+    value = manifest.layer_reader(ROOT, man, "burst_period_p99_ms")(run)
+    assert "over 3 periods (1 across an idle wait dropped)" in capsys.readouterr().err
+    # periods 50, 2760, 50: 98% of the way from the median to the largest
+    assert value == pytest.approx(50.0 + 0.98 * 2710.0)
+
+
+def _dump(stalled):
+    """A recorded ring: sixty polls a burst period of 50 ms apart, the
+    thirtieth of which sat ``stalled`` (a phase) for 2.7 s."""
+    rows, t = [], 500.0
+    for i in range(60):
+        row = _poll(i, t, None, dispatch=0.002, read_wait=0.046, credit=0.002)
+        if i == 30 and stalled:
+            row["phase_s"][stalled] = 2.7
+            row.update(admitted=3, admitted_ids=[71, 72, 73])
+        t += sum(row["phase_s"].values())
+        row["dispatched_t"] = t - 0.048
+        rows.append(row)
+    return {"capacity": 4096, "enabled": True, "recorded_total": 60,
+            "dropped": 0, "entries": rows}
+
+
+@pytest.mark.parametrize("stalled", ["admit", "read_wait", None])
+def test_flight_report_lists_the_slowest_polls_and_diagnoses_a_stall(stalled):
+    spec = importlib.util.spec_from_file_location(
+        "flight_report", os.path.join(ROOT, "tools", "flight_report.py"))
+    flight_report = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(flight_report)
+    unit = flight_report.report(_dump(stalled))["(batcher)"]
+    at = unit["lines"].index("slowest polls (scheduler seconds outside idle):")
+    listed = unit["lines"][at + 1:at + 6]
+    assert len(unit["slowest_polls"]) == len(listed) == 5
+    stalls = [line for line in unit["diagnosis"] if "burst period" in line]
+    if stalled is None:
+        assert stalls == []
+        return
+    assert unit["slowest_polls"][0]["poll"] == 300
+    assert listed[0].startswith("  poll 300 at t=") and f"{stalled} 2700.0" in listed[0]
+    assert "3 admitted, 2 bursts in flight, device busy" in listed[0]
+    assert len(stalls) == 1
+    assert f"poll 300 at t=" in stalls[0] and f"spent 2.700 s in `{stalled}`" in stalls[0]
+    assert "54x the median burst period (50.0 ms over 59)" in stalls[0]
+    assert "[71, 72, 73]" in stalls[0]
+    json.dumps(unit)

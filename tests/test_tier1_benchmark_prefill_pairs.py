@@ -15,3 +15,23 @@ import pytest
 pytest.register_assert_rewrite("benchmark.tests.test_prefill_pairs")
 
 from benchmark.tests.test_prefill_pairs import *  # noqa: E402,F401,F403
+
+
+# ``benchmark/tests/test_prefill_pairs.py`` holds its entry to be the LAST of
+# ``per_layer``; PR 40 appended four entries after it (new entries go to the
+# end of their lists) and may not edit a file the benchmark has. So the test
+# is restated here under its own name, every assertion but that one line, and
+# stays live: a ``benchmark`` PR drops the line there and this copy with it
+# (PERF.md, section 7).
+def test_the_manifest_gives_the_metric_to_the_one_cell(man):  # noqa: F811
+    entry, = (m for m in man["per_layer"] if m["name"] == METRIC)  # noqa: F405
+    assert entry == {
+        "name": METRIC, "unit": "%", "better": "lower",  # noqa: F405
+        "source": "program_counter", "layer": "model step",
+        "moves": "tokens_per_s", "workloads": [CELL]}  # noqa: F405
+    for cell in man["workloads"]:
+        names = {m["name"] for m in manifest.metrics_of(  # noqa: F405
+            man, "per_layer", cell["name"])}
+        assert (METRIC in names) == (cell["name"] == CELL)  # noqa: F405
+    assert "tokens_per_s" in {m["name"] for m in manifest.metrics_of(  # noqa: F405
+        man, "end_to_end", CELL)}  # noqa: F405

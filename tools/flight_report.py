@@ -7,14 +7,17 @@ file argument or stdin (``-``). Output: a per-unit report attributing
 where generation time is going — queue wait vs first-token latency vs
 decode pacing — plus what the scheduler actually decided poll by poll
 (the burst's mode, K and bucket, chunked-prefill interleave,
-prefix-cache hits, shed events).
+prefix-cache hits, shed events) and what each poll cost: the scheduler
+thread's seconds by phase on every poll record (``phase_s``), from which
+the five slowest polls are listed and a stalled one is diagnosed.
 
 Usage::
 
     curl -s localhost:8000/flightrecorder | python tools/flight_report.py -
     python tools/flight_report.py dump.json
     python tools/flight_report.py --json dump.json   # machine-readable:
-    # {unit: {"lines": [...], "diagnosis": [DIAGNOSIS subset]}}
+    # {unit: {"lines": [...], "diagnosis": [DIAGNOSIS subset],
+    #         "slowest_polls": [poll records]}}
 """
 
 from __future__ import annotations
@@ -528,6 +531,87 @@ def _planner_lines(retunes: List[Dict[str, Any]]) -> List[str]:
     return lines
 
 
+def _working_s(poll: Dict[str, Any]) -> float:
+    """A poll record's seconds outside ``idle`` (waiting for a request)."""
+    return sum(v for k, v in poll.get("phase_s", {}).items() if k != "idle")
+
+
+def slowest_polls(polls: List[Dict[str, Any]], n: int = 5) -> List[Dict[str, Any]]:
+    """The ``n`` poll records that held the scheduler thread longest
+    outside ``idle``, longest first; records without ``phase_s`` (an
+    older dump's) are left out."""
+    timed = [p for p in polls if "phase_s" in p]
+    return sorted(timed, key=_working_s, reverse=True)[:n]
+
+
+def burst_periods(polls: List[Dict[str, Any]]) -> List[float]:
+    """Seconds from one burst's dispatch to the next's: each poll record
+    that dispatched one against the last before it that did, but across
+    an idle wait (the benchmark's ``burst_period_p99_ms`` pairs so too)."""
+    periods, last, idled = [], None, False
+    for row in polls:
+        idled = idled or "idle" in row.get("phase_s", {})
+        if "dispatched_t" not in row:
+            continue
+        if last is not None and not idled:
+            periods.append(row["dispatched_t"] - last)
+        last, idled = row["dispatched_t"], False
+    return periods
+
+
+def _clock_lines(polls: List[Dict[str, Any]]) -> List[str]:
+    """What the polls cost: the slowest five by phase, and a DIAGNOSIS
+    when one poll's ``admit`` (a dispatch blocked) or ``read_wait`` (the
+    device or the runtime held a burst) took over ten times the dump's
+    median burst period — every lane stood still for it."""
+    slow = slowest_polls(polls)
+    if not slow:
+        return []
+    lines = ["slowest polls (scheduler seconds outside idle):"]
+    for p in slow:
+        phases = sorted(
+            ((k, v) for k, v in p["phase_s"].items() if k != "idle"),
+            key=lambda kv: -kv[1],
+        )
+        lines.append(
+            f"  poll {p.get('poll', '?')} at t={p['t']:.3f}: "
+            f"{_working_s(p) * 1e3:.1f} ms ("
+            + ", ".join(f"{k} {v * 1e3:.1f}" for k, v in phases)
+            + f"), {p.get('admitted', 0)} admitted, "
+            f"{p.get('pending_bursts', 0)} bursts in flight, "
+            f"device {'drained' if p.get('drained') else 'busy'} at its "
+            "first dispatch"
+        )
+    periods = sorted(burst_periods(polls))
+    if not periods:
+        return lines
+    median = periods[len(periods) // 2]
+    causes = {
+        "admit": "a dispatch of an admission blocked (its programs "
+                 "queued behind the running burst, the runtime held it, or "
+                 "it compiled)",
+        "read_wait": "the device or the runtime took that long to hand "
+                     "a burst's tokens back",
+    }
+    for phase, cause in causes.items():
+        over = [p for p in polls
+                if p.get("phase_s", {}).get(phase, 0.0) > 10 * median]
+        if not over:
+            continue
+        worst = max(over, key=lambda p: p["phase_s"][phase])
+        ids = worst.get("admitted_ids")
+        lines.append(
+            f"DIAGNOSIS: poll {worst.get('poll', '?')} at "
+            f"t={worst['t']:.3f} spent {worst['phase_s'][phase]:.3f} s in "
+            f"`{phase}`, {worst['phase_s'][phase] / median:.0f}x the median "
+            f"burst period ({median * 1e3:.1f} ms over {len(periods)}); "
+            f"{len(over)} poll(s) over ten periods — {cause}; no lane "
+            "got a token meanwhile"
+            + (f"; requests admitted in that poll: {ids}" if ids else "")
+        )
+    return lines
+
+
 def _device_time_lines(
     polls: List[Dict[str, Any]],
     profiler: Dict[str, Any],
@@ -816,6 +900,9 @@ def diagnose(dump: Dict[str, Any]) -> List[str]:
             "chunk between decode bursts)"
         )
 
+    # -- what the polls cost (the loop's clock on each record) ----------------
+    lines.extend(_clock_lines(polls))
+
     # -- live weight swaps ----------------------------------------------------
     lines.extend(_swap_lines(swaps))
 
@@ -881,18 +968,23 @@ def diagnose(dump: Dict[str, Any]) -> List[str]:
     return lines
 
 
-def report(payload: Dict[str, Any]) -> Dict[str, Dict[str, List[str]]]:
-    """Per-unit structured report: every narrative line plus the
-    DIAGNOSIS subset broken out (dashboards key alerts off it)."""
+def report(payload: Dict[str, Any]) -> Dict[str, Dict[str, List[Any]]]:
+    """Per-unit structured report: every narrative line, the DIAGNOSIS
+    subset broken out (dashboards key alerts off it) and the slowest
+    polls' records as they are."""
     units = payload.get("units")
     if units is None:
         units = {"(batcher)": payload}
-    out: Dict[str, Dict[str, List[str]]] = {}
+    out: Dict[str, Dict[str, List[Any]]] = {}
     for name, dump in units.items():
         lines = diagnose(dump)
         out[name] = {
             "lines": lines,
             "diagnosis": [l for l in lines if l.startswith("DIAGNOSIS")],
+            "slowest_polls": slowest_polls([
+                e for e in dump.get("entries") or []
+                if e.get("type") == "poll"
+            ]),
         }
     return out
 
