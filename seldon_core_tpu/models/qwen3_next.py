@@ -73,6 +73,10 @@ class Qwen3NextLM(DecoderLM):
         # grouped experts moved (a share: its room x its passes; all of
         # them where every expert is held) and the pairs routed
         "moe_prefill_pairs_moved", "moe_prefill_pairs_routed",
+        # per (sequence, linear layer): the chunks of the gated delta rule
+        # that hold a token of the sequence (``ceil(lens / CHUNK)``: what
+        # the prefill kernel walks) and the chunks of its bucket
+        "gdn_prefill_chunks_walked", "gdn_prefill_chunks_bucket",
     )
     serving_refuses = {
         "speculation": "the draft is the first layers of a stacked llama "
@@ -528,7 +532,8 @@ class Qwen3NextLM(DecoderLM):
                 u, tail = gated_delta.conv_prefill(qkv, p["conv_w"], lens)
                 q, k, v = self._delta_heads(u)
                 o, state = gated_delta.gated_delta_prefill(
-                    q, k, v, g, beta, lens)
+                    q, k, v, g, beta, lens,
+                    mesh=getattr(self, "_serving_mesh", None))
                 x = x + self._delta_out(p, o, z)
                 leaves["conv"].append(tail)
                 leaves["state"].append(state)
@@ -548,7 +553,11 @@ class Qwen3NextLM(DecoderLM):
         slab = None if pad_to is None else {
             name: jnp.stack(each) for name, each in leaves.items() if each}
         routed = sum(picks.size for picks in picked)
-        return x, slab, picked, jnp.stack([moved, jnp.int32(routed)])
+        # counted beside the calls, from what the kernel is told
+        walked = jnp.sum(-(-lens // gated_delta.CHUNK)) * self._n_linear
+        bucket = B * -(-T // gated_delta.CHUNK) * self._n_linear
+        return x, slab, picked, jnp.stack([
+            moved, jnp.int32(routed), walked, jnp.int32(bucket)])
 
     def apply(self, params, tokens):
         """tokens [B, T] int32 -> logits [B, T, V] (float32)."""

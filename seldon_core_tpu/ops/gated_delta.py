@@ -15,12 +15,19 @@ and write strength ``beta`` in (0, 1) does
 ``gated_delta_prefill()``  whole prompts in chunks of ``CHUNK`` positions
                       (the published kernels' 64): inside a chunk the
                       rule is a unit lower-triangular solve and a few
-                      [C, C] and [C, D] matmuls, between chunks a
-                      ``lax.scan`` carries ``S``. Positions at or past a
-                      sequence's ``lens`` neither decay nor write (``g``
-                      and ``beta`` are zeroed there), so the last carry IS
-                      the state after the sequence's last real token,
-                      whatever bucket it was padded to
+                      [C, C] and [C, D] matmuls, between chunks ``S`` is
+                      carried. Positions at or past a sequence's ``lens``
+                      neither decay nor write (``g`` and ``beta`` are
+                      zeroed there), so the last carry IS the state after
+                      the sequence's last real token, whatever bucket it
+                      was padded to. On a TPU one Pallas kernel: a block
+                      of heads keeps ``S`` in VMEM across a sequence's
+                      chunks, a chunk's intermediates never leave VMEM,
+                      and the chunks past ``ceil(lens / CHUNK)`` are
+                      neither fetched nor computed (``lens`` prefetched;
+                      their outputs are zeros). Elsewhere, and as the
+                      kernel's oracle, ``_chunk`` under a ``lax.scan``
+                      over all of the bucket's chunks
 ``gated_delta_step()``  one token a lane: on a TPU a Pallas kernel whose
                       grid walks the LIVE lanes (scalars prefetched); each
                       program copies a lane's heads in, updates them in
@@ -51,6 +58,12 @@ SOLVE_BASE = 16
 # Dk, Dv] float32 in and out, two buffers each (2 MB at 8 heads of 128 x
 # 128)
 STEP_HEADS = 8
+# value heads one program of the prefill kernel walks a chunk of: q, k, v
+# and the outputs in blocks of [CHUNK, HEADS x D], the state [HEADS, Dk, Dv]
+# float32 resident (9 MB of VMEM at 32 heads with their second buffers and
+# the scratch). On a v5e a chunk of 32 heads took 48.2 us at 8 heads a
+# program, 44.0 at 16 and 41.8 at 32 (PERF.md, section 6, PR 41)
+PREFILL_HEADS = 32
 L2_EPS = 1e-6
 
 
@@ -158,14 +171,301 @@ def _chunk(s, xs):
     return s, o.astype(v.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("chunk",))
-def gated_delta_prefill(q, k, v, g, beta, lens, chunk: int = CHUNK):
-    """Whole prompts. q, k [B, T, H, Dk] (normed, q scaled), v [B, T, H,
-    Dv], g, beta [B, T, H] float32, lens [B] int32: a sequence's real
-    tokens are its first ``lens``. Returns the outputs [B, T, H, Dv] in
-    v's dtype (those at or past ``lens`` are of no use) and each
-    sequence's state after its last real token [B, H, Dk, Dv] float32.
-    ``chunk``: at most ``SOLVE_BASE``, or that times a power of two."""
+def _two_on_the_diagonal(x, same_head):
+    """x [C, 2C], two heads' [C, C] side by side -> [2C, 2C] with one on
+    each diagonal block and zeros off them: what a product with two heads'
+    rows stacked takes, each head's rows meeting its own block alone."""
+    return jnp.where(same_head, jnp.concatenate([x, x], axis=0), 0.0)
+
+
+def _diagonal_blocks_inverses(rows):
+    """The base of ``_unit_lower_inverse`` inside the prefill kernel, for
+    every ``SOLVE_BASE``-row diagonal block of every head of the program at
+    once. ``rows`` [base, L] float32 holds the blocks side by side along
+    the lanes: ``rows[i, (block, j)] = a_block[i, j]``, strictly lower
+    triangular; the result holds ``(I + a_block)^-1`` the same way. The
+    same forward substitution, float32 on the VPU, a column a step: once
+    row j is final, every later row i takes ``a[i, j]`` times it off. The
+    factor ``a[i, j]`` is wanted under the lanes ``(block, n <= j)`` (a
+    finished row is zero right of its diagonal): the column's own lane
+    summed over the lanes that follow (rotations by 1, 2, 4, 8; they do
+    not wait for the rows, so the 15 steps in turn are a broadcast, a
+    multiply and a subtraction each)."""
+    base, lanes = rows.shape
+    i = lax.broadcasted_iota(jnp.int32, rows.shape, 0)
+    j = lax.broadcasted_iota(jnp.int32, rows.shape, 1) % base
+    x = (i == j).astype(jnp.float32)
+    for at in range(base - 1):
+        factor = jnp.where(j == at, rows, 0.0)
+        reach = 1
+        while reach <= at:
+            factor = factor + pltpu.roll(factor, lanes - reach, 1)
+            reach *= 2
+        x = x - factor * x[at:at + 1]
+    return x
+
+
+def _halves_put_together(x, a, row, col, same_head, base):
+    """The merges of ``_unit_lower_inverse`` for two heads: ``x`` [C, 2C],
+    the inverses of the ``base``-row diagonal blocks of two heads' ``I +
+    a`` side by side (zeros off the blocks) -> [2C, 2C] with each head's
+    whole ``(I + a)^-1`` on its diagonal block. Level by level ``x - x
+    a_cross x``: what lies between two neighbouring blocks times the
+    inverses on either side; only the rows of the lower blocks change."""
+    c = a.shape[0]
+    hi = lax.Precision.HIGHEST
+    f32 = jnp.float32
+    x = _two_on_the_diagonal(x, same_head)
+    size = base
+    while size < c:
+        cross = _two_on_the_diagonal(jnp.where(
+            (row // (2 * size) == col // (2 * size))
+            & (row // size != col // size), a, 0.0), same_head)
+        lower = jnp.concatenate(
+            [x[at:at + size] for at in range(size, 2 * c, 2 * size)], axis=0)
+        moved = jnp.dot(
+            jnp.dot(lower, cross, precision=hi, preferred_element_type=f32),
+            x, precision=hi, preferred_element_type=f32)            # [C, 2C]
+        still = jnp.zeros((size, 2 * c), f32)
+        x = x - jnp.concatenate([
+            part for at in range(0, c, size)
+            for part in (still, moved[at:at + size])], axis=0)
+        size *= 2
+    return x
+
+
+def _prefill_kernel(lens_ref, q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, s_ref,
+                    cum_ref, beta_ref, a_ref, scores_ref, blocks_ref):
+    """Grid (B, H / heads, N), the chunks in turn: program (i, j, n) is
+    chunk n of heads block j of sequence i, ``_chunk``'s arithmetic with
+    every intermediate in VMEM, two heads at a time: their [C, C] matrices
+    lie side by side along the lanes ([C, 2C]: whole registers at C = 64),
+    or on the diagonal blocks of one [2C, 2C] where a product takes both.
+    Three passes: a loop over the pairs for the chunk's matrix ``a`` and
+    the scores of each (kept in ``a_ref``, ``scores_ref`` [heads / 2, C,
+    2C]); the substitution of every pair's diagonal blocks in one go
+    (``blocks_ref`` [base, heads C]: its steps wait for each other, so
+    they are taken once a program, not once a pair); a loop over the pairs
+    for the rest. The loops are ``lax.fori_loop``: a pair's code is traced
+    and lowered once (unrolled, every prefill executable took 3 s longer to
+    lower in each process that starts: PERF.md, section 6, PR 41).
+    ``s_ref`` [1, heads, Dk, Dv] float32 is the block's state: its block
+    index does not move with n, so it stays in VMEM from the sequence's
+    first chunk to its last and goes out once. At or past ``ceil(lens[i] /
+    C)`` the index maps park on the last block fetched and the program
+    writes zeros to the chunk's outputs, nothing else. Refs: q, k, v [1,
+    C, heads D] (head h in lanes ``h D .. (h + 1) D``), g, beta [1, 1, C,
+    heads] float32, ``lens_ref`` SMEM [B]; ``cum_ref``, ``beta_ref``
+    [heads / 2, C, 2]: a pair's running sums of g and its betas."""
+    n = pl.program_id(2)
+    c, heads = g_ref.shape[2:]
+    d = s_ref.shape[-1]
+    base = min(SOLVE_BASE, c)
+    length = lens_ref[pl.program_id(0)]
+    f32 = jnp.float32
+    hi = lax.Precision.HIGHEST
+    nt = (((1,), (1,)), ((), ()))
+    tn = (((0,), (0,)), ((), ()))
+
+    @pl.when(n == 0)
+    def _():
+        s_ref[...] = jnp.zeros_like(s_ref)
+
+    @pl.when(n * c >= length)
+    def _():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when(n * c < length)
+    def _():
+        def iota(shape, axis):
+            return lax.broadcasted_iota(jnp.int32, shape, axis)
+
+        # two heads' [C, C] side by side: the row, the column in a head's
+        # own, and which of the two a lane belongs to
+        row, lane = iota((c, 2 * c), 0), iota((c, 2 * c), 1)
+        col, first = lane % c, lane < c
+        lower = col <= row
+        own_block = row // base == col // base
+        same_head = iota((2 * c, 2 * c), 0) // c == iota((2 * c, 2 * c), 1) // c
+        first_d = iota((c, 2 * d), 1) < d
+        # positions at or past the length neither decay nor write
+        real = n * c + iota((c, 1), 0) < length
+        g = jnp.where(real, g_ref[0, 0], 0.0)                       # [C, heads]
+        beta = jnp.where(real, b_ref[0, 0], 0.0)
+        # the running sum of g down the chunk, every head of the block at
+        # once: exact products with ones, float32 sums
+        cums = jnp.dot((iota((c, c), 1) <= iota((c, c), 0)).astype(f32), g,
+                       precision=hi, preferred_element_type=f32)
+        for pair in range(heads // 2):
+            cum_ref[pair] = cums[:, 2 * pair:2 * pair + 2]
+            beta_ref[pair] = beta[:, 2 * pair:2 * pair + 2]
+
+        def lanes(pair, width):
+            """The lanes of a pair in an array that holds ``width`` a head."""
+            return pl.ds(pl.multiple_of(pair * 2 * width, 2 * width), 2 * width)
+
+        def halves(x):
+            """[C, 2D], two heads side by side -> [2C, D], one under the
+            other."""
+            return jnp.concatenate([x[:, :d], x[:, d:]], axis=0)
+
+        def of_each(pair, ref, where):
+            """ref[pair] [C, 2], a value a (position, head) -> [C, 2C] or
+            [C, 2D], each head's under its own lanes."""
+            x = ref[pair]
+            return jnp.where(where, x[:, :1], x[:, 1:])
+
+        def weighted(pair, x):
+            """x [C, 2D] of a pair times beta, in x's dtype."""
+            return (x.astype(f32) * of_each(pair, beta_ref, first_d)).astype(
+                x.dtype)
+
+        def matrices(pair, _):
+            q, k = q_ref[0, :, lanes(pair, d)], k_ref[0, :, lanes(pair, d)]
+            cum = of_each(pair, cum_ref, first)
+            along = jnp.sum(jnp.where(row == col, cum, 0.0), axis=0,
+                            keepdims=True)                          # [1, 2C]
+            decay = jnp.where(lower, jnp.exp(
+                jnp.where(lower, cum - along, 0.0)), 0.0)
+            # kb k^T and q k^T of both heads in one product: a head's own
+            # lie on the diagonal blocks
+            dots = lax.dot_general(
+                jnp.concatenate([halves(weighted(pair, k)), halves(q)], axis=0),
+                halves(k), nt, preferred_element_type=f32)          # [4C, 2C]
+            gram = jnp.where(first, dots[:c], dots[c:2 * c])
+            a = jnp.where(col < row, gram * decay, 0.0)
+            a_ref[pair] = a
+            scores_ref[pair] = jnp.where(
+                first, dots[2 * c:3 * c], dots[3 * c:]) * decay
+            # a block's row i under lane (head, block, j): a[(block, i),
+            # (block, j)]
+            kept = jnp.where(own_block, a, 0.0)
+            blocks_ref[:, lanes(pair, c)] = sum(
+                kept[r:r + base] for r in range(0, c, base))
+
+        lax.fori_loop(0, heads // 2, matrices, None)
+        blocks_ref[...] = _diagonal_blocks_inverses(blocks_ref[...])
+
+        def rest(pair, _):
+            q, k, v = (ref[0, :, lanes(pair, d)] for ref in (q_ref, k_ref, v_ref))
+            inv = _halves_put_together(
+                jnp.where(own_block, jnp.concatenate(
+                    [blocks_ref[:, lanes(pair, c)]] * (c // base), axis=0), 0.0),
+                a_ref[pair], row, col, same_head, base)             # [2C, 2C]
+            cum = of_each(pair, cum_ref, first_d)
+            grown = jnp.exp(cum)
+            last = cum[c - 1:]                                      # [1, 2D]
+            # what each position writes, its own chunk's earlier writes
+            # taken off: [u | w] of one head over the other's
+            kbg = weighted(pair, k).astype(f32) * grown
+            vb = weighted(pair, v).astype(f32)
+            uw = jnp.dot(
+                inv, jnp.concatenate([halves(vb), halves(kbg)], axis=1),
+                precision=hi, preferred_element_type=f32)           # [2C, 2D]
+            qg = q.astype(f32) * grown
+            kl = k.astype(f32) * jnp.exp(last - cum)
+            new, carried = [], []
+            for e in range(2):
+                own = slice(e * d, (e + 1) * d)
+                rows = slice(e * c, (e + 1) * c)
+                s = s_ref[0, 2 * pair + e]
+                # [w; q exp(cum)] s in one product
+                ws = jnp.dot(
+                    jnp.concatenate([uw[rows, d:], qg[:, own]], axis=0), s,
+                    precision=hi, preferred_element_type=f32)
+                new.append(uw[rows, :d] - ws[:c])
+                carried.append(ws[c:])
+                s_ref[0, 2 * pair + e] = s * jnp.exp(last[:, own]) + (
+                    lax.dot_general(kl[:, own], new[e], tn, precision=hi,
+                                    preferred_element_type=f32))
+            o = jnp.concatenate(carried, axis=0) + jnp.dot(
+                _two_on_the_diagonal(scores_ref[pair], same_head),
+                jnp.concatenate(new, axis=0), precision=hi,
+                preferred_element_type=f32)                         # [2C, D]
+            o_ref[0, :, lanes(pair, d)] = jnp.concatenate(
+                [o[:c], o[c:]], axis=1).astype(o_ref.dtype)
+
+        lax.fori_loop(0, heads // 2, rest, None)
+
+
+@functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
+def gated_delta_prefill_kernel(q, k, v, g, beta, lens, chunk: int = CHUNK,
+                               interpret: bool = False):
+    """The prefill kernel itself, ``gated_delta_prefill``'s arguments and
+    results. q, k, v go in as they lie, [B, T, H D] (no relayout); g and
+    beta as [B, H / heads, T, heads]. Chunks walked: ``ceil(lens / chunk)``
+    a sequence; the outputs of the others are zeros."""
+    b, t, h, d = q.shape
+    heads = min(PREFILL_HEADS, h)
+    if not prefills_in_kernel("tpu", q.shape, v.shape, chunk):
+        raise ValueError(f"heads {q.shape}, {v.shape} in chunks of {chunk} "
+                         "do not fit the kernel")
+    pad = -t % chunk
+    n, nh = (t + pad) // chunk, h // heads
+    lens = lens.astype(jnp.int32)
+
+    def flat(a):
+        return jnp.pad(a, ((0, 0), (0, pad), (0, 0), (0, 0))).reshape(
+            b, t + pad, h * d)
+
+    def columns(a):
+        a = jnp.pad(a.astype(jnp.float32), ((0, 0), (0, pad), (0, 0)))
+        return a.reshape(b, t + pad, nh, heads).transpose(0, 2, 1, 3)
+
+    def walked(n_, lens):
+        """The chunk program (., ., n_) reads: its own while the sequence
+        has it, then the last one it had."""
+        return jnp.minimum(n_, jnp.maximum(pl.cdiv(lens, chunk) - 1, 0))
+
+    def wide(i, j, n_, lens):
+        return (i, walked(n_, lens[i]), j)
+
+    def narrow(i, j, n_, lens):
+        return (i, j, walked(n_, lens[i]), 0)
+
+    o, s = pl.pallas_call(
+        _prefill_kernel,
+        out_shape=(jax.ShapeDtypeStruct((b, t + pad, h * d), v.dtype),
+                   jax.ShapeDtypeStruct((b, h, d, d), jnp.float32)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(b, nh, n),
+            in_specs=[pl.BlockSpec((1, chunk, heads * d), wide)] * 3
+            + [pl.BlockSpec((1, 1, chunk, heads), narrow)] * 2,
+            out_specs=(
+                pl.BlockSpec((1, chunk, heads * d),
+                             lambda i, j, n_, lens: (i, n_, j)),
+                pl.BlockSpec((1, heads, d, d),
+                             lambda i, j, n_, lens: (i, j, 0, 0))),
+            scratch_shapes=[
+                pltpu.VMEM((heads // 2, chunk, 2), jnp.float32)] * 2 + [
+                pltpu.VMEM((heads // 2, chunk, 2 * chunk), jnp.float32)] * 2 + [
+                pltpu.VMEM((min(SOLVE_BASE, chunk), heads * chunk),
+                           jnp.float32)],
+        ),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        name="gated_delta_prefill",
+        interpret=interpret,
+    )(lens, flat(q), flat(k), flat(v), columns(g), columns(beta))
+    return o.reshape(b, t + pad, h, d)[:, :t], s
+
+
+def prefills_in_kernel(platform, q_shape, v_shape, chunk=CHUNK,
+                       mesh=None) -> bool:
+    """Whether ``gated_delta_prefill()``, lowered for ``platform``, is the
+    kernel: ``steps_in_kernel``'s rule (a TPU, no serving mesh, square heads
+    that fill lanes), heads in pairs, and chunks two of which fill lanes:
+    64 times a power of two, as the halves are put together."""
+    h, dk = q_shape[2:]
+    return (steps_in_kernel(platform, (q_shape[0], h, dk, v_shape[3]), mesh)
+            and h % min(PREFILL_HEADS, h) == 0 and h % 2 == 0
+            and chunk % 64 == 0 and chunk & (chunk - 1) == 0)
+
+
+def _prefill_scanned(q, k, v, g, beta, lens, chunk: int = CHUNK):
+    """``gated_delta_prefill`` off a TPU, and the kernel's oracle: ``_chunk``
+    under a ``lax.scan`` over all of the bucket's chunks."""
     b, t, h, dk = q.shape
     dv = v.shape[-1]
     pad = -t % chunk
@@ -185,6 +485,29 @@ def gated_delta_prefill(q, k, v, g, beta, lens, chunk: int = CHUNK):
             chunks(beta, True)))
     o = jnp.moveaxis(jnp.moveaxis(o, 0, 1), 2, 3)         # [B, N, C, H, Dv]
     return o.reshape(b, t + pad, h, dv)[:, :t], s
+
+
+@functools.partial(jax.jit, static_argnames=("chunk", "mesh"))
+def gated_delta_prefill(q, k, v, g, beta, lens, chunk: int = CHUNK, mesh=None):
+    """Whole prompts. q, k [B, T, H, Dk] (normed, q scaled), v [B, T, H,
+    Dv], g, beta [B, T, H] float32, lens [B] int32: a sequence's real
+    tokens are its first ``lens``. Returns the outputs [B, T, H, Dv] in
+    v's dtype (those at or past ``lens`` are of no use: zeros from the
+    kernel past a sequence's last chunk) and each sequence's state after
+    its last real token [B, H, Dk, Dv] float32. ``chunk``: at most
+    ``SOLVE_BASE``, or that times a power of two."""
+
+    def kernel(*args):
+        with jax.named_scope("gated_delta_prefill"):
+            return gated_delta_prefill_kernel(*args, chunk=chunk)
+
+    def scanned(*args):
+        return _prefill_scanned(*args, chunk=chunk)
+
+    args = (q, k, v, g, beta, lens)
+    if not prefills_in_kernel("tpu", q.shape, v.shape, chunk, mesh):
+        return scanned(*args)
+    return lax.platform_dependent(*args, tpu=kernel, default=scanned)
 
 
 # -- the decode step -------------------------------------------------------------------

@@ -291,3 +291,45 @@ def test_qwen3_next_burst_compiled_for_v5e_is_kernels_over_a_cache_in_place(one_
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= cache_bytes
     assert mem.temp_size_in_bytes < 256 << 20
+
+
+def test_qwen3_next_prefill_compiled_for_v5e_is_one_delta_kernel_a_linear_layer(one_chip):
+    """The configuration's own prefill of one prompt in the 4096 bucket
+    (all 8 layers, counters and all): each of the 6 linear layers' gated
+    delta rule is ONE ``gated_delta_prefill`` kernel call, and no ``while``
+    is left under that scope (off a TPU it is a ``lax.scan`` over the
+    bucket's 64 chunks); the loops that remain are the held experts' (a
+    pass over the room and its bands, 2 a layer)."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from benchmark import manifest
+    from seldon_core_tpu.models.llm import DecoderLM
+
+    with open(os.path.join(ROOT, "benchmark", "configs",
+                           "qwen3-next-80b-a3b.json")) as f:
+        cfg = json.load(f)
+    kwargs = manifest.architecture(
+        ROOT, manifest.load(ROOT), cfg["architecture"]).model_kwargs(cfg, 0)
+    kwargs.pop("seed")
+    model = DecoderLM(**kwargs)
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(
+        lambda a: sds(a.shape, jnp.dtype(model.cfg.dtype)),
+        jax.eval_shape(model.init_params, 0))
+    T = cfg["server"]["max_seq"]
+    hlo = jax.jit(lambda p, t, last: model.prefill_counted(p, t, T, last)).lower(
+        params, sds((1, T), jnp.int32), sds((1,), jnp.int32)).compile().as_text()
+    names = re.findall(r"%([a-z_]+)[.\d]* = [^\n]*? custom-call\(", hlo)
+    assert names.count("gated_delta_prefill") == 6
+    loops = [line for line in hlo.splitlines() if re.search(r" while\(", line)]
+    assert len(loops) == 2 * 8
+    assert not [line for line in loops if "gated_delta_prefill" in line]
+    scoped = [line for line in hlo.splitlines()
+              if "gated_delta_prefill" in line and "op_name=" in line]
+    assert scoped and not [line for line in scoped if "/while/" in line]

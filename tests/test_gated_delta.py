@@ -1,8 +1,9 @@
 """The gated delta rule's serving ops (``ops/gated_delta.py``) against the
 recurrence itself: the chunked prefill at lengths that are no multiple of
-the chunk and shorter than their bucket, the convolution's tail, the decode
-kernel (interpreted) with idle lanes, and the triangular inverse where the
-keys repeat. CPU only; the kernel's compile for a described v5e is in
+the chunk and shorter than their bucket, the prefill kernel (interpreted)
+against that scan, the convolution's tail, the decode kernel (interpreted)
+with idle lanes, and the triangular inverse where the keys repeat. CPU
+only; the kernels' compiles for a described v5e are in
 ``tests/test_burst_hlo.py``."""
 
 import jax
@@ -160,3 +161,111 @@ def test_the_kernel_is_chosen_by_what_the_lowering_can_see():
     assert not gd.steps_in_kernel("cpu", (64, 32, 128, 128))
     assert not gd.steps_in_kernel("tpu", (64, 32, 128, 128), mesh=object())
     assert not gd.steps_in_kernel("tpu", (4, 4, 32, 32))
+    # the prefill's: the same rule over q's and v's shapes
+    q = (1, 4096, 32, 128)
+    assert gd.prefills_in_kernel("tpu", q, q)
+    assert gd.prefills_in_kernel("tpu", (8, 100, 2, 128), (8, 100, 2, 128), 128)
+    assert not gd.prefills_in_kernel("cpu", q, q)
+    assert not gd.prefills_in_kernel("tpu", q, q, mesh=object())
+    assert not gd.prefills_in_kernel("tpu", (1, 4096, 32, 32), (1, 4096, 32, 32))
+    assert not gd.prefills_in_kernel("tpu", q, (1, 4096, 32, 256))   # not square
+    assert not gd.prefills_in_kernel("tpu", (1, 4096, 12, 128), (1, 4096, 12, 128))
+    assert not gd.prefills_in_kernel("tpu", q, q, chunk=16)
+    assert not gd.prefills_in_kernel("tpu", q, q, chunk=192)
+
+
+# -- the prefill kernel, interpreted -----------------------------------------------------
+
+def _against_the_scan(q, k, v, g, beta, lens, chunk=gd.CHUNK):
+    """The kernel (interpreted) and ``_chunk`` under ``lax.scan`` on the
+    same arguments: outputs at each sequence's real positions, the final
+    state, and zeros in every chunk past the last one walked."""
+    lens = jnp.asarray(lens, jnp.int32)
+    o, s = gd.gated_delta_prefill_kernel(q, k, v, g, beta, lens, chunk=chunk,
+                                         interpret=True)
+    want_o, want_s = gd._prefill_scanned(q, k, v, g, beta, lens, chunk=chunk)
+    assert o.shape == want_o.shape and o.dtype == want_o.dtype
+    assert s.shape == want_s.shape and s.dtype == jnp.float32
+    f32 = jnp.float32
+    # a bfloat16 output may round the other way: one step of its 8 bits
+    ulp = 2.0 ** -7 if o.dtype == jnp.bfloat16 else 0.0
+    for row, n in enumerate(np.asarray(lens)):
+        np.testing.assert_allclose(o[row, :n].astype(f32),
+                                   want_o[row, :n].astype(f32), atol=2e-5,
+                                   rtol=ulp)
+        np.testing.assert_allclose(s[row], want_s[row], atol=2e-5)
+        assert not np.asarray(o[row, -(-n // chunk) * chunk:]).any()
+        if n == 0:
+            assert not np.asarray(s[row]).any()
+    return o, s
+
+
+@pytest.mark.parametrize("t,n", [
+    (256, 1), (256, 63), (256, 64), (256, 65), (4096, 2048), (4096, 4096),
+    (256, 0)], ids=["1", "63", "64", "65", "2048_of_4096", "the_whole_bucket",
+                    "0"])
+def test_prefill_kernel_interpreted_is_the_scan_up_to_the_length(t, n):
+    q, k, v, g, beta = _inputs(np.random.default_rng(7), 1, t, 2, 128)
+    _against_the_scan(q, k, v, g, beta, [n])
+
+
+def test_prefill_kernel_takes_four_lengths_in_one_call():
+    """Rows of one call stop at their own lengths: head blocks of 8 (two
+    of them), bfloat16 operands as served."""
+    q, k, v, g, beta = _inputs(np.random.default_rng(8), 4, 320, 16, 128,
+                               jnp.bfloat16)
+    o, _ = _against_the_scan(q, k, v, g, beta, [320, 129, 64, 7])
+    assert o.dtype == jnp.bfloat16
+
+
+def test_prefill_kernel_pads_a_short_bucket_to_one_chunk():
+    q, k, v, g, beta = _inputs(np.random.default_rng(9), 2, 32, 2, 128)
+    o, _ = _against_the_scan(q, k, v, g, beta, [32, 5])
+    assert o.shape == (2, 32, 2, 128)
+    # and chunks of 128: three levels of halves to put together
+    q, k, v, g, beta = _inputs(np.random.default_rng(9), 2, 200, 2, 128)
+    _against_the_scan(q, k, v, g, beta, [200, 129], chunk=128)
+
+
+def test_prefill_kernel_writes_zeros_where_the_scan_left_values_of_no_use():
+    """Past a sequence's last chunk nothing is fetched or computed, and
+    what the next layer reads there is defined: zeros, whatever lies in
+    the padding (here: NaN)."""
+    q, k, v, g, beta = _inputs(np.random.default_rng(10), 2, 256, 2, 128)
+    past = jnp.arange(256)[None, :, None] >= 128
+    nan = lambda a: jnp.where(past[..., None] if a.ndim == 4 else past,  # noqa: E731
+                              jnp.nan, a)
+    o, s = gd.gated_delta_prefill_kernel(
+        nan(q), nan(k), nan(v), nan(g), nan(beta), jnp.asarray([100, 128]),
+        interpret=True)
+    want_o, want_s = gd._prefill_scanned(q, k, v, g, beta,
+                                         jnp.asarray([100, 128]))
+    assert not np.asarray(o[:, 128:]).any()
+    np.testing.assert_allclose(s, want_s, atol=2e-5)
+    np.testing.assert_allclose(o[0, :100], want_o[0, :100], atol=2e-5)
+    np.testing.assert_allclose(o[1, :128], want_o[1, :128], atol=2e-5)
+
+
+def test_a_kernel_prefills_state_carried_on_by_steps_is_the_longer_prefills():
+    q, k, v, g, beta = _inputs(np.random.default_rng(11), 1, 70, 2, 128)
+    run = lambda n: gd.gated_delta_prefill_kernel(  # noqa: E731
+        q, k, v, g, beta, jnp.asarray([n]), interpret=True)[1]
+    s = run(66)
+    for t in range(66, 70):
+        s, _ = gd.gated_delta_step(s, q[:, t], k[:, t], v[:, t], g[:, t],
+                                   beta[:, t], jnp.asarray([True]))
+    np.testing.assert_allclose(s, run(70), atol=2e-5)
+
+
+def test_off_a_tpu_the_prefill_is_the_scan_bit_for_bit():
+    """Heads the kernel takes and heads it does not: lowered for a CPU,
+    ``gated_delta_prefill`` is ``_chunk`` under ``lax.scan`` either way."""
+    for d in (128, 32):
+        q, k, v, g, beta = _inputs(np.random.default_rng(12), 2, 96, 2, d)
+        lens = jnp.asarray([96, 40])
+        o, s = gd.gated_delta_prefill(q, k, v, g, beta, lens)
+        want_o, want_s = gd._prefill_scanned(q, k, v, g, beta, lens)
+        np.testing.assert_array_equal(o, want_o)
+        np.testing.assert_array_equal(s, want_s)
+    with pytest.raises(ValueError, match="do not fit the kernel"):
+        gd.gated_delta_prefill_kernel(q, k, v, g, beta, lens, interpret=True)

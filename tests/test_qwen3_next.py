@@ -141,6 +141,11 @@ def test_served_requests_are_the_references_greedy_tokens(served, batcher):
     assert routed == stats["prefill_tokens"] * 4 * 8 > 0
     assert 0.375 * routed <= stats["moe_prefill_pairs_moved"] <= 0.75 * routed
     assert stats["moe_prefill_pairs_moved"] % experts.ROOM_TILE == 0
+    # and the chunks of the gated delta rule, in 6 linear layers: what each
+    # prompt has, and what its bucket has (32, 128, 128, 128, 256, 32: a
+    # bucket under a chunk is padded to one)
+    assert stats["gdn_prefill_chunks_walked"] == 6 * (1 + 2 + 2 + 1 + 3 + 1)
+    assert stats["gdn_prefill_chunks_bucket"] == 6 * (1 + 2 + 2 + 2 + 4 + 1)
 
 
 def test_a_lane_admitted_beside_live_lanes_leaves_them_bit_equal(served, batcher):
@@ -419,7 +424,8 @@ def test_a_whole_layer_moves_every_pair_and_a_share_its_room(served):
     model, params = served
     whole = DecoderLM(**dict(SMALL, experts_held=None))
     assert model.prefill_counter_names == whole.prefill_counter_names == (
-        "moe_prefill_pairs_moved", "moe_prefill_pairs_routed")
+        "moe_prefill_pairs_moved", "moe_prefill_pairs_routed",
+        "gdn_prefill_chunks_walked", "gdn_prefill_chunks_bucket")
     prompt = jnp.asarray(
         np.random.default_rng(2).integers(0, 256, size=(2, 64)), jnp.int32)
     logits, slab = model.prefill(params, prompt, 64)
@@ -427,10 +433,28 @@ def test_a_whole_layer_moves_every_pair_and_a_share_its_room(served):
     np.testing.assert_array_equal(logits, counted)
     for name in slab:
         np.testing.assert_array_equal(slab[name], slab2[name])
-    # 128 rows x 4 picks in 8 layers; a layer's room is 384 of its 512
-    assert counts.tolist() == [8 * 384, 8 * 512]
+    # 128 rows x 4 picks in 8 layers; a layer's room is 384 of its 512;
+    # two sequences that fill their one chunk in 6 linear layers
+    assert counts.tolist() == [8 * 384, 8 * 512, 12, 12]
     assert whole.prefill_counted(whole.init_params(3), prompt, 64)[2].tolist() == [
-        8 * 512, 8 * 512]
+        8 * 512, 8 * 512, 12, 12]
+
+
+@pytest.mark.parametrize("bucket,lens", [
+    (64, [64]), (128, [1]), (128, [64, 65]), (256, [63, 129, 256, 192])],
+    ids=["the_whole_bucket", "one_token", "either_side_of_a_chunk", "four_rows"])
+def test_a_prefill_counts_the_chunks_its_sequences_have(served, bucket, lens):
+    """``gdn_prefill_chunks_walked`` is ``ceil(lens / 64)`` a (sequence,
+    linear layer) and ``gdn_prefill_chunks_bucket`` the bucket's chunks,
+    from ``last_index`` and the prompt's shape, whatever the padding holds."""
+    model, params = served
+    prompt = jnp.asarray(np.random.default_rng(5).integers(
+        0, 256, size=(len(lens), bucket)), jnp.int32)
+    *_, counts = model.prefill_counted(
+        params, prompt, 256, jnp.asarray(lens, jnp.int32) - 1)
+    assert counts.tolist()[2:] == [
+        6 * sum(-(-n // 64) for n in lens), 6 * len(lens) * bucket // 64]
+    assert counts.tolist()[1] == 8 * len(lens) * bucket * 4
 
 
 @pytest.mark.parametrize("held", [(4, 4), None], ids=["a_share", "every_expert"])
@@ -478,7 +502,7 @@ def test_an_insert_sums_the_prefill_counters_only_where_a_family_names_them(
     if counted:
         b = batcher
         assert b._prefill_counters == model.prefill_counter_names
-        assert [a.tolist() for a in b._no_prefill_counts] == [[0, 0]]
+        assert [a.tolist() for a in b._no_prefill_counts] == [[0, 0, 0, 0]]
     else:
         dense = DecoderLM(vocab_size=128, d_model=64, n_layers=2, n_heads=4,
                           n_kv_heads=2, d_ff=128, max_seq=128, dtype="float32")
@@ -498,14 +522,16 @@ def test_an_insert_sums_the_prefill_counters_only_where_a_family_names_them(
         plain = b._insert_fn(fresh(), one, 1, first[0], 10, key, *regs)
         assert len(plain) == 4
         if counted:
-            so_far = jnp.asarray([5, 7], jnp.int32)
+            # 10 tokens of a 128 bucket: one chunk of two in 6 linear layers
+            so_far = jnp.asarray([5, 7, 11, 13], jnp.int32)
+            want = [5 + 8 * 384, 7 + 8 * 512, 11 + 6, 13 + 12]
             *_, total = b._insert_fn(
                 fresh(), one, 1, first[0], 10, key, *regs, so_far, *counts)
-            assert total.tolist() == [5 + 8 * 384, 7 + 8 * 512]
+            assert total.tolist() == want
             *_, total = b._insert_many_fn(
                 fresh(), one, jnp.asarray([2], jnp.int32), first,
                 jnp.asarray([10], jnp.int32), key[None], *regs, so_far, *counts)
-            assert total.tolist() == [5 + 8 * 384, 7 + 8 * 512]
+            assert total.tolist() == want
     finally:
         if not counted:
             b.close()
