@@ -64,6 +64,9 @@ class LLMConfig:
     # function; DecoderLM(block="afmoe", ...) builds that class.
     # "qwen3_next": Gated DeltaNet layers beside gated softmax attention
     # and a chip's share of softmax-routed experts (models/qwen3_next.py).
+    # "joyai_llm_flash": latent attention (one compressed row a position,
+    # absorbed into the decode step) and a chip's share of sigmoid-routed
+    # experts (models/joyai_llm_flash.py).
     block: str = "llama"
     # per layer "sliding_attention" | "full_attention"; None = all full
     layer_types: Optional[Tuple[str, ...]] = None
@@ -86,6 +89,13 @@ class LLMConfig:
     # (lo, n): this chip holds experts lo .. lo + n - 1 of the
     # n_routed_experts the router ranges over; None: all of them
     experts_held: Optional[Tuple[int, int]] = None
+    # -- the joyai_llm_flash block only: multi-head latent attention ----
+    # (models/joyai_llm_flash.py; every layer caches one row a position)
+    q_lora_rank: int = 0          # the query's low-rank bottleneck
+    kv_lora_rank: int = 0         # the cached, normed latent c
+    qk_nope_head_dim: int = 0     # a head's key dims expanded from c
+    qk_rope_head_dim: int = 0     # the one rotary key all heads share
+    v_head_dim: int = 0           # a head's value dims expanded from c
 
     def __post_init__(self):
         if not self.head_dim:
@@ -159,6 +169,8 @@ class DecoderLM(ServedModel):
                 from .afmoe import AfmoeLM as family
             elif config["block"] == "qwen3_next":
                 from .qwen3_next import Qwen3NextLM as family
+            elif config["block"] == "joyai_llm_flash":
+                from .joyai_llm_flash import JoyaiLLMFlashLM as family
             else:
                 raise ValueError(f"unknown block variant {config['block']!r}")
             return super().__new__(family)
@@ -542,6 +554,51 @@ class DecoderLM(ServedModel):
             name: [kind[l] for l in range(kind.shape[0])]
             for name, kind in self.init_cache(batch, max_seq).items()
         }
+
+    # -- what the scheduler asks of a cache it did not lay out ------------
+    # (serving/continuous.py names no kind and no family: these say what a
+    # position costs and how a burst reads it)
+
+    def position_layers(self, cache):
+        """The arrays of ``cache_layers``' dict that hold one row a
+        position, positions along the second-to-last axis (a lane's
+        recurrent state, which has no position axis, is not among them):
+        what a decode step writes one row each of."""
+        return [*cache["k"], *cache["v"]]
+
+    def cache_position_bytes(self, cache) -> int:
+        """Bytes ONE cached position occupies over every layer of
+        ``cache``, by the live arrays' dtypes and shapes (an array's bytes
+        over its lanes and positions: ``itemsize x KV x Dh`` of a [S, KV,
+        T, Dh] array): the unit of the modeled burst read and of the
+        pressure ledger."""
+        return sum(a.nbytes // (a.shape[0] * a.shape[-2])
+                   for a in self.position_layers(cache))
+
+    def prefill_slab_bytes(self, rows: int, bucket: int) -> int:
+        """Bytes of the slab a batched prefill of ``rows`` prompts in
+        ``bucket`` returns (a transient beside params and cache)."""
+        cfg = self.cfg
+        return (2 * cfg.n_layers * rows * cfg.n_kv_heads * bucket
+                * cfg.head_dim * 2)
+
+    def burst_reads_ragged(self, cache, mesh=None) -> bool:
+        """Whether the decode step over ``cache``, lowered for the platform
+        its arrays live on, bounds each lane's read by the lane's own
+        length (``ops.decode_attention.reads_ragged``): a burst then needs
+        no bucket and no executable per bucket."""
+        import jax.numpy as jnp
+
+        from ..ops.decode_attention import reads_ragged
+
+        layer0 = cache["k"][0]
+        return reads_ragged(
+            next(iter(layer0.devices())).platform,
+            (layer0.shape[0], self.cfg.n_heads, 1, layer0.shape[3]),
+            layer0.shape,
+            (jnp.dtype(self.cfg.dtype), layer0.dtype, cache["v"][0].dtype),
+            mesh,
+        )
 
     def _embed_tokens(self, params, tokens):
         import jax.numpy as jnp

@@ -312,6 +312,10 @@ def touched_experts_ffn(x, picks, weights, ids, n, w1, w3, w2,
     b, d = x.shape
     n_experts, _, width = w1.shape
     tf = min(tf, width)
+    # the widest slice of whole registers that divides the width: 512 at
+    # 1024 and 512, 384 at 768
+    while width % tf and tf > 128:
+        tf -= 128
     if width % tf:
         raise ValueError(f"expert width {width} is no multiple of {tf}")
     nf = width // tf

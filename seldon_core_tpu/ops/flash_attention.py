@@ -65,8 +65,10 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, block_q, block_k, causal,
                   window=None):
     """One (bh, q-block) program: stream K/V blocks with online softmax.
 
-    q_ref/o_ref: [1, block_q, Dh]; k_ref/v_ref: [1, Tk, Dh] (whole keys
-    for this bh resident in VMEM — serving-sized Tk*Dh fits easily).
+    q_ref: [1, block_q, Dh]; k_ref: [1, Tk, Dh]; v_ref: [1, Tk, Dv] and
+    o_ref: [1, block_q, Dv] (whole keys for this bh resident in VMEM —
+    serving-sized Tk*Dh fits easily). Dv is Dh but where a family's keys
+    are wider than its values (latent attention: 192 and 128).
     ``window`` (static, with ``causal``): row i sees columns (i - window,
     i]; the walk starts at the block that holds the q-block's first row's
     first column and key blocks wholly left of the band are never read.
@@ -120,7 +122,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, block_q, block_k, causal,
         # q-block's last row (block sizes are equal-or-multiples, so the
         # bound lands on a block edge or inside the masked block)
         n_k = jnp.minimum(n_k, (qb * block_q + block_q + block_k - 1) // block_k)
-    o = jnp.zeros((block_q, dh), jnp.float32)
+    o = jnp.zeros((block_q, v_ref.shape[-1]), jnp.float32)
     m = jnp.full((block_q, 1), NEG_INF, jnp.float32)
     l = jnp.zeros((block_q, 1), jnp.float32)
     first = 0
@@ -136,7 +138,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, block_q, block_k, causal,
 
 @functools.partial(
     jax.jit,
-    static_argnames=("causal", "block_q", "block_k", "interpret", "window"),
+    static_argnames=("causal", "block_q", "block_k", "interpret", "window",
+                     "name"),
 )
 def flash_attention(
     q,
@@ -147,11 +150,14 @@ def flash_attention(
     block_k: int = 128,
     interpret: bool = False,
     window=None,
+    name=None,
 ):
-    """Pallas blocked attention. q [B,H,Tq,Dh], k/v [B,H,Tk,Dh].
+    """Pallas blocked attention. q [B,H,Tq,Dh], k [B,H,Tk,Dh], v
+    [B,H,Tk,Dv] (Dv = Dh everywhere but latent attention's prefill).
     Tq must divide by block_q and Tk by block_k (use :func:`attention`
     for the dispatching fallback). ``window`` (static int, causal only):
-    query i sees keys (i - window, i]."""
+    query i sees keys (i - window, i]. ``name``: the kernel's name in a
+    trace, where a caller wants its own."""
     if window is not None and not causal:
         raise ValueError("a window is causal")
     b, h, t_q, dh = q.shape
@@ -162,7 +168,8 @@ def flash_attention(
         )
     qf = q.reshape(b * h, t_q, dh)
     kf = k.reshape(b * h, t_k, dh)
-    vf = v.reshape(b * h, t_k, dh)
+    dv = v.shape[-1]
+    vf = v.reshape(b * h, t_k, dv)
     kernel = functools.partial(
         _flash_kernel,
         block_q=block_q,
@@ -175,22 +182,23 @@ def flash_attention(
         kernel,
         # under shard_map the result varies over the mesh axes q does
         out_shape=jax.ShapeDtypeStruct(
-            qf.shape, q.dtype, vma=jax.typeof(q).vma
+            (b * h, t_q, dv), q.dtype, vma=jax.typeof(q).vma
         ),
         grid=(b * h, t_q // block_q),
         in_specs=[
             pl.BlockSpec((1, block_q, dh), lambda bh, i: (bh, i, 0)),
             pl.BlockSpec((1, t_k, dh), lambda bh, i: (bh, 0, 0)),
-            pl.BlockSpec((1, t_k, dh), lambda bh, i: (bh, 0, 0)),
+            pl.BlockSpec((1, t_k, dv), lambda bh, i: (bh, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, block_q, dh), lambda bh, i: (bh, i, 0)),
+        out_specs=pl.BlockSpec((1, block_q, dv), lambda bh, i: (bh, i, 0)),
         interpret=interpret,
+        **({} if name is None else {"name": name}),
     )(qf, kf, vf)
-    return out.reshape(b, h, t_q, dh)
+    return out.reshape(b, h, t_q, dv)
 
 
 def attention(q, k, v, kv_len=None, causal: bool = True, mesh=None,
-              window=None):
+              window=None, name=None):
     """Dispatching attention: Pallas flash kernel on TPU when the shape
     tiles onto the MXU, XLA einsum otherwise (CPU, tiny prompts). Inference
     only — the kernel defines no VJP; training paths keep the XLA/ring
@@ -198,6 +206,10 @@ def attention(q, k, v, kv_len=None, causal: bool = True, mesh=None,
 
     ``window`` (static int, optional): query i sees keys (i - window, i]
     (a model's sliding-attention layers); both paths take the same band.
+
+    v may be narrower than q and k (latent attention's prefill: keys of
+    192 = 128 + 64 rotary, values of 128): the scale is the keys' and the
+    output the values' width. ``name``: the kernel's name in a trace.
 
     ``mesh``: the serving mesh when the caller runs under one. Mosaic
     kernels cannot be partitioned by GSPMD, so the kernel call is wrapped
@@ -222,7 +234,7 @@ def attention(q, k, v, kv_len=None, causal: bool = True, mesh=None,
         and jax.default_backend() == "tpu"
         and t_q % block == 0
         and t_k % block == 0
-        and q.shape[-1] in (64, 128, 256)
+        and q.shape[-1] in (64, 128, 192, 256)
     )
     if not use_kernel:
         if window is None:
@@ -233,6 +245,8 @@ def attention(q, k, v, kv_len=None, causal: bool = True, mesh=None,
     )
     if window is not None:
         kernel = functools.partial(kernel, window=int(window))
+    if name is not None:
+        kernel = functools.partial(kernel, name=name)
     if mesh is not None:
         kernel = jax.shard_map(
             kernel, mesh=mesh, in_specs=(P(), P(), P()), out_specs=P()

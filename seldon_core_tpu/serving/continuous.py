@@ -1075,7 +1075,7 @@ class ContinuousBatcher:
             # slab is the batched prefill's [L, m, KV, Tb, Dh] stack; each
             # row i lands in its lane slot_ix[i] (traced start indices —
             # one executable per (m, bucket), not per slot assignment)
-            m = slab["k"].shape[1]
+            m = firsts.shape[0]
             new = {name: list(layers) for name, layers in cache.items()}
             for i in range(m):
                 for name, layers in new.items():
@@ -1153,7 +1153,9 @@ class ContinuousBatcher:
             each lane's remaining allowance AFTER its current token
             (decremented on device, re-uploaded only on membership
             changes)."""
-            park = cache["k"][0].shape[2]  # static: index >= T is dropped
+            # static: index >= T is dropped (positions are a cache
+            # array's second-to-last axis, whatever kinds the family has)
+            park = model.position_layers(cache)[0].shape[-2]
 
             def body(carry, _):
                 cache, cur, p, kk, budget, done = carry
@@ -1337,14 +1339,14 @@ class ContinuousBatcher:
             chunk_prefill_step, donate_argnums=(1,), static_argnums=(7, 8)
         )
         self._splice_fn = jax.jit(splice_slab, donate_argnums=(0,))
-        # K and V bytes per cached position, all layers: the unit of the
-        # modeled burst read and of the pressure ledger
-        self._kv_key_bytes = 2 * sum(
-            layer.dtype.itemsize * layer.shape[1] * layer.shape[3]
-            for layer in self._cache["k"]
-        )
+        # bytes per cached position, all layers (K and V, or whatever rows
+        # the family caches: the model sizes them): the unit of the modeled
+        # burst read and of the pressure ledger
+        self._kv_key_bytes = model.cache_position_bytes(self._cache)
+        # the arrays a decode step writes one row each of
+        self._position_layers = len(model.position_layers(self._cache))
         # the ragged decode read's granule (stats["kv_positions_read"])
-        from ..ops.decode_attention import BLOCK, reads_ragged
+        from ..ops.decode_attention import BLOCK
 
         self._kv_read_block = BLOCK
         # the windows of the model's layers that have one (the kinds come
@@ -1359,15 +1361,7 @@ class ContinuousBatcher:
         # attn_len=None, one executable per K and none per bucket. The
         # host's arithmetic (need, the counters, the variant names) keeps
         # the bucket either way.
-        layer0 = self._cache["k"][0]
-        self._ragged_read = reads_ragged(
-            next(iter(layer0.devices())).platform,
-            (self.slots, model.cfg.n_heads, 1, layer0.shape[3]),
-            layer0.shape,
-            (jnp.dtype(model.cfg.dtype), layer0.dtype,
-             self._cache["v"][0].dtype),
-            mesh,
-        )
+        self._ragged_read = model.burst_reads_ragged(self._cache, mesh)
         # the draft cache's per-token K/V price (speculation only): the
         # pressure ledger charges live lanes for BOTH caches while the
         # draft is resident, and stops when rung 2 frees it
@@ -3088,7 +3082,7 @@ class ContinuousBatcher:
                         )
                     )
                 # block so only one warm call is in flight at a time
-                self._cache["k"][0].block_until_ready()  # seldon-lint: disable=host-sync-hot-path (warm precompile: intentional sync while the loop is idle)
+                self._cache_leaf().block_until_ready()  # seldon-lint: disable=host-sync-hot-path (warm precompile: intentional sync while the loop is idle)
                 if self.speculate_tokens > 0:
                     dslab = self._draft_prefill_fn(
                         self._draft_params, prompts, last
@@ -3151,7 +3145,7 @@ class ContinuousBatcher:
                             self._cur_tok, self._pos, self._keys,
                         )
                     )
-                    self._cache["k"][0].block_until_ready()  # seldon-lint: disable=host-sync-hot-path (warm precompile: intentional sync while the loop is idle)
+                    self._cache_leaf().block_until_ready()  # seldon-lint: disable=host-sync-hot-path (warm precompile: intentional sync while the loop is idle)
         if self._kv_tier is not None:
             # tier spill / copy-back executables: a rung-3 preemption
             # extracts the victim lane's cache columns at its ATTENTION
@@ -3172,7 +3166,7 @@ class ContinuousBatcher:
                         self._cur_tok, self._pos, self._keys,
                     )
                 )
-                self._cache["k"][0].block_until_ready()  # seldon-lint: disable=host-sync-hot-path (warm precompile: intentional sync while the loop is idle)
+                self._cache_leaf().block_until_ready()  # seldon-lint: disable=host-sync-hot-path (warm precompile: intentional sync while the loop is idle)
             # census line, PR 13 style: a width-count jump between runs
             # means a config change grew the tier's compile surface
             logger.info(
@@ -3202,7 +3196,7 @@ class ContinuousBatcher:
                 )
                 self._cache = {"k": nc["k"], "v": nc["v"]}
                 self._draft_cache = {"k": nc["dk"], "v": nc["dv"]}
-                self._cache["k"][0].block_until_ready()  # seldon-lint: disable=host-sync-hot-path (warm precompile: intentional sync while the loop is idle)
+                self._cache_leaf().block_until_ready()  # seldon-lint: disable=host-sync-hot-path (warm precompile: intentional sync while the loop is idle)
         else:
             for attn_len in burst_lens:
                 toks, self._cur_tok, self._pos, self._cache, self._keys, *_ = (
@@ -3256,7 +3250,7 @@ class ContinuousBatcher:
             # between runs means a layout change moved bytes across
             # chips. One designed sync makes the census report compiled
             # executables, not queued ones.
-            self._cache["k"][0].block_until_ready()  # seldon-lint: disable=host-sync-hot-path (sharded warm census: intentional sync while the loop is idle so the census reports compiled sharded executables)
+            self._cache_leaf().block_until_ready()  # seldon-lint: disable=host-sync-hot-path (sharded warm census: intentional sync while the loop is idle so the census reports compiled sharded executables)
             leaves = [
                 leaf for leaf in jax.tree_util.tree_leaves(self.params)
                 if hasattr(leaf, "sharding")
@@ -3334,14 +3328,19 @@ class ContinuousBatcher:
 
     # -- scheduler loop --------------------------------------------------------
 
+    def _cache_leaf(self):
+        """One array of the serving cache, whatever kinds it holds: what a
+        warm-up call blocks on."""
+        import jax
+
+        return jax.tree_util.tree_leaves(self._cache)[0]
+
     def _chunk8_ok(self, bucket: int) -> bool:
-        """m=8 batched prefill is admitted when its K/V slab stays small
-        (the slab is a transient [L, 8, KV, bucket, Dh] x2 allocation on
-        top of params + cache; 4 GB keeps flagship configs comfortably
-        inside HBM)."""
-        cfg = self.model.cfg
-        slab = 2 * cfg.n_layers * 8 * cfg.n_kv_heads * bucket * cfg.head_dim * 2
-        return slab <= 4 << 30
+        """m=8 batched prefill is admitted when its slab stays small (the
+        slab is a transient allocation on top of params + cache, [L, 8,
+        KV, bucket, Dh] x2 for a K/V family: the model sizes it; 4 GB
+        keeps flagship configs comfortably inside HBM)."""
+        return self.model.prefill_slab_bytes(8, bucket) <= 4 << 30
 
     def _bucket(self, n: int) -> int:
         for b in self.prefill_buckets:
@@ -5925,7 +5924,7 @@ class ContinuousBatcher:
                                 self.stats["kv_positions_read_window"] += streamed
                                 self.stats["kv_positions_seen_window"] += seen
                                 self.stats["kv_positions_live_window"] += live
-                        rows = len(lanes) * k * 2 * len(self._cache["k"])
+                        rows = len(lanes) * k * self._position_layers
                         self.stats["kv_rows_written"] += rows
                         if self._ragged_read:
                             self.stats["kv_rows_written_in_kernel"] += rows

@@ -333,3 +333,61 @@ def test_qwen3_next_prefill_compiled_for_v5e_is_one_delta_kernel_a_linear_layer(
     scoped = [line for line in hlo.splitlines()
               if "gated_delta_prefill" in line and "op_name=" in line]
     assert scoped and not [line for line in scoped if "/while/" in line]
+
+
+def test_joyai_llm_flash_burst_compiled_for_v5e_is_kernels_over_a_latent_cache_in_place(one_chip):
+    """The configuration's own burst (64 lanes, all 12 layers, no bucket):
+    every layer decodes through the ragged latent kernel (one [64, 6144,
+    640] array a layer, read as key and as value) and every expert layer's
+    held experts through the touched-expert kernel at a width of 768, all
+    inside the ``while``; the rows are aliased through, and nothing of a
+    cache leaf's shape is copied, sliced or scattered into."""
+    import re
+
+    tool = _tool()
+    with open(os.path.join(ROOT, "benchmark", "configs",
+                           "joyai-llm-flash.json")) as f:
+        cfg = json.load(f)
+    cfg["name"] = "joyai-llm-flash"
+    compiled, (lanes, _kv, T, _dh), cache_bytes, leaves = tool.compile_burst(
+        cfg, None, one_chip)
+    assert (lanes, T, leaves) == (64, 6144, 12)
+    assert cache_bytes == 64 * 6144 * 12 * 640 * 2
+    hlo = compiled.as_text()
+    assert tool.kernel_calls(hlo) == {"inside": 12 + 11, "outside": 0}
+    names = re.findall(r"%([a-z_]+)[.\d]* = [^\n]*? custom-call\(", hlo)
+    assert names.count("latent_decode_attention") == 12
+    assert names.count("touched_experts_ffn") == 11
+    leaf = re.compile(r" = bf16\[\d+,6144,640\][^ ]* (copy|copy-start|slice|"
+                      r"slice-start|scatter|dynamic-update-slice)\(")
+    assert not [line for line in hlo.splitlines() if leaf.search(line)]
+    assert tool.alias_count(hlo) >= leaves
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= cache_bytes
+    assert mem.temp_size_in_bytes < 512 << 20
+
+
+def test_flash_kernel_compiled_for_v5e_takes_keys_of_192_and_values_of_128(one_chip):
+    """The latent family's prefill attention: the flash kernel at a key
+    width of 192 and a value width of 128, in the bucket the traffic uses
+    most and in the comparison's, under the family's kernel name."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+
+    from seldon_core_tpu.ops.flash_attention import flash_attention
+
+    def sds(shape):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+
+    for t, block in ((1792, 128), (6144, 512)):
+        fn = jax.jit(functools.partial(
+            flash_attention, causal=True, block_q=block, block_k=block,
+            name="latent_prefill_attention"))
+        compiled = fn.lower(sds((1, 32, t, 192)), sds((1, 32, t, 192)),
+                            sds((1, 32, t, 128))).compile()
+        call, = (line for line in compiled.as_text().splitlines()
+                 if "custom-call(" in line and "tpu_custom_call" in line)
+        assert "latent_prefill_attention" in call
+        assert f"bf16[32,{t},128]" in call

@@ -22,16 +22,23 @@ from benchmark.tests.test_prefill_pairs import *  # noqa: E402,F401,F403
 # end of their lists) and may not edit a file the benchmark has. So the test
 # is restated here under its own name, every assertion but that one line, and
 # stays live: a ``benchmark`` PR drops the line there and this copy with it
-# (PERF.md, section 7).
+# (PERF.md, section 7). PR 42 appended a second cell whose chip holds a
+# share of its experts and whose prefills count the same two counters
+# (``joyai-llm-flash.reasoning``): the metric is held to those two cells,
+# the first its own.
+CELLS = [CELL, "joyai-llm-flash.reasoning"]  # noqa: F405
+
+
 def test_the_manifest_gives_the_metric_to_the_one_cell(man):  # noqa: F811
     entry, = (m for m in man["per_layer"] if m["name"] == METRIC)  # noqa: F405
     assert entry == {
         "name": METRIC, "unit": "%", "better": "lower",  # noqa: F405
         "source": "program_counter", "layer": "model step",
-        "moves": "tokens_per_s", "workloads": [CELL]}  # noqa: F405
+        "moves": "tokens_per_s", "workloads": CELLS}
     for cell in man["workloads"]:
         names = {m["name"] for m in manifest.metrics_of(  # noqa: F405
             man, "per_layer", cell["name"])}
-        assert (METRIC in names) == (cell["name"] == CELL)  # noqa: F405
-    assert "tokens_per_s" in {m["name"] for m in manifest.metrics_of(  # noqa: F405
-        man, "end_to_end", CELL)}  # noqa: F405
+        assert (METRIC in names) == (cell["name"] in CELLS)  # noqa: F405
+    for name in CELLS:
+        assert "tokens_per_s" in {m["name"] for m in manifest.metrics_of(  # noqa: F405
+            man, "end_to_end", name)}
