@@ -47,6 +47,15 @@ class AfmoeLM(DecoderLM):
         # some live lane picked, (lane, pick) pairs routed, expert layers run
         "moe_experts_touched", "moe_rows_routed", "moe_layer_steps",
     )
+    prefill_counter_names = (
+        # per prefill, summed over the expert layers: the (row, pick) pairs
+        # the grouped experts moved (every pair of the bucket: all experts
+        # are held) and routed, and the rows of the row tiles the grouped
+        # kernel worked, a tile once for every group it was worked for (a
+        # pad row's picks join no group)
+        "moe_prefill_pairs_moved", "moe_prefill_pairs_routed",
+        "moe_prefill_tile_rows",
+    )
     serving_refuses = {
         "speculation": "the draft is the first layers of a stacked llama "
                        "block, and the verify window has no counted path",
@@ -252,13 +261,15 @@ class AfmoeLM(DecoderLM):
             k = _rope(k, positions, cfg.rope_theta)
         return q, k, v, g
 
-    def _close(self, p, x, o, g, routed, live=None):
+    def _close(self, p, x, o, g, routed, live=None, real=None):
         """From the attention's output o [B, H, T, Dh] to the layer's: the
         gate, ``wo``, the post-norm and residual, then the FFN between its
         two norms. ``live`` ([B], a decode step: T == 1) sends the routed
-        experts through the touched-only read. Returns ``(x, picks,
-        counts)``: a routed layer's picks [B, T, k], else None, and
-        (experts touched, rows routed) of the touched-only read, else
+        experts through the touched-only read. ``real`` ([B, T] bool, a
+        prefill's): the rows that are some sequence's tokens. Returns
+        ``(x, picks, counts)``: a routed layer's picks [B, T, k], else
+        None, and (experts touched, rows routed) of the touched-only
+        read, ``ops.experts.GROUPED_COUNTS`` of the grouped path, else
         None."""
         import jax
         import jax.numpy as jnp
@@ -288,7 +299,16 @@ class AfmoeLM(DecoderLM):
                 cfg.route_scale)
             stacks = tuple(p[n].astype(dt) for n in ("we1", "we3", "we2"))
             if live is None:
-                y = experts.grouped_experts(rows, picks, weights, *stacks)
+                sent = picks
+                if real is not None:
+                    # a row of padding computes nothing that is read: its
+                    # picks go to no expert's id, join no group and cost
+                    # the grouped kernel nothing
+                    sent = jnp.where(real.reshape(-1, 1), picks,
+                                     cfg.n_routed_experts)
+                y, counts = experts.grouped_experts(
+                    rows, sent, weights, *stacks,
+                    mesh=getattr(self, "_serving_mesh", None))
             else:
                 y, touched, routed_rows = experts.decode_experts(
                     rows, picks, weights, live, *stacks,
@@ -324,10 +344,12 @@ class AfmoeLM(DecoderLM):
 
     # -- whole-prompt forward --------------------------------------------------------
 
-    def _forward(self, params, tokens, pad_to: Optional[int]):
-        """One pass over whole prompts tokens [B, T]: the residual stream,
-        where ``pad_to`` is given each layer's K and V padded to it, and
-        the routed layers' picks [B, T, k]."""
+    def _forward(self, params, tokens, pad_to: Optional[int], last_index=None):
+        """One pass over whole prompts tokens [B, T], a sequence's real
+        tokens being its first ``last_index + 1`` (all T without it): the
+        residual stream, where ``pad_to`` is given each layer's K and V
+        padded to it, the routed layers' picks [B, T, k] and the
+        ``prefill_counter_names``."""
         import jax.numpy as jnp
 
         from ..ops import attention as prefill_attention
@@ -336,22 +358,28 @@ class AfmoeLM(DecoderLM):
         B, T = tokens.shape
         x = self._embed_tokens(params, tokens)
         positions = jnp.arange(T)
+        real = (None if last_index is None else positions[None, :] <= (
+            jnp.asarray(last_index, jnp.int32)[:, None]))
         rep = cfg.n_heads // cfg.n_kv_heads
         ks, vs, picked = [], [], []
+        counts = jnp.zeros((2,), jnp.int32)
         for p, window, routed in zip(params["layers"], self._windows,
                                      self._routed):
             q, k, v, g = self._heads(p, x, positions, window)
             o = prefill_attention(
                 q, jnp.repeat(k, rep, axis=1), jnp.repeat(v, rep, axis=1),
                 causal=True, window=window)
-            x, picks, _ = self._close(p, x, o, g, routed)
+            x, picks, grouped = self._close(p, x, o, g, routed, real=real)
             if routed:
                 picked.append(picks)
+                counts = counts + grouped
             if pad_to is not None:
                 pad = ((0, 0), (0, 0), (0, pad_to - T), (0, 0))
                 ks.append(jnp.pad(k, pad))
                 vs.append(jnp.pad(v, pad))
-        return x, ks, vs, picked
+        routed_pairs = sum(picks.size for picks in picked)
+        return x, ks, vs, picked, jnp.stack([
+            counts[0], jnp.int32(routed_pairs), counts[1]])
 
     def apply(self, params, tokens):
         """tokens [B, T] int32 -> logits [B, T, V] (float32)."""
@@ -362,11 +390,17 @@ class AfmoeLM(DecoderLM):
         """``prefill`` and the routed layers' picks [B, T, k] (a comparison
         with a reference takes them from this very program: another
         program's roundings, and so its picks at near-ties, are its own)."""
-        x, ks, vs, picked = self._forward(params, prompt, max_seq)
+        x, ks, vs, picked, _ = self._forward(params, prompt, max_seq, last_index)
         return self._head(params, x, last_index), self._stack(ks, vs), picked
 
     def prefill(self, params, prompt, max_seq: int, last_index=None):
         return self._prefill(params, prompt, max_seq, last_index)[:2]
+
+    def prefill_counted(self, params, prompt, max_seq: int, last_index=None):
+        """``prefill`` and, after the cache's rows, its
+        ``prefill_counter_names`` as an int32 vector."""
+        x, ks, vs, _, counts = self._forward(params, prompt, max_seq, last_index)
+        return self._head(params, x, last_index), self._stack(ks, vs), counts
 
     # -- a window of positions over a cache --------------------------------------------
 

@@ -102,6 +102,8 @@ class JoyaiLLMFlashLM(DecoderLM):
         # as the qwen3_next block's: the (row, pick) pairs the grouped
         # experts moved and the pairs routed, over the expert layers
         "moe_prefill_pairs_moved", "moe_prefill_pairs_routed",
+        # the rows of the row tiles the grouped experts' kernel worked
+        "moe_prefill_tile_rows",
     )
     serving_refuses = {
         "speculation": "the draft is the first layers of a stacked llama "
@@ -455,8 +457,8 @@ class JoyaiLLMFlashLM(DecoderLM):
         """h [B, T, D] after the attention -> the layer's output, a routed
         layer's picks [B, T, k] over ALL experts (else None) and, for a
         decode step (``live`` [B]), (held experts touched, rows routed,
-        rows that landed here); for a prefill, the pairs its grouped
-        experts moved. ``real`` [B, T] bool (a prefill's): the rows that
+        rows that landed here); for a prefill, its grouped experts'
+        ``GROUPED_COUNTS``. ``real`` [B, T] bool (a prefill's): the rows that
         are some sequence's tokens."""
         import jax
         import jax.numpy as jnp
@@ -487,11 +489,10 @@ class JoyaiLLMFlashLM(DecoderLM):
                 # (the qwen3_next block's finding)
                 sent = jnp.where(real.reshape(-1, 1), picks,
                                  cfg.n_routed_experts)
-            y = experts.grouped_experts(
+            y, counts = experts.grouped_experts(
                 rows, sent, weights, *stacks, held=cfg.experts_held,
-                n_routed=cfg.n_routed_experts)
-            # where every expert is held, every pair is moved
-            y, counts = (y, picks.size) if cfg.experts_held is None else y
+                n_routed=cfg.n_routed_experts,
+                mesh=getattr(self, "_serving_mesh", None))
         else:
             y, touched, n_routed = experts.decode_experts(
                 rows, picks, weights, live, *stacks,
@@ -535,20 +536,21 @@ class JoyaiLLMFlashLM(DecoderLM):
         real = (None if last_index is None else positions[None, :] <= (
             jnp.asarray(last_index, jnp.int32)[:, None]))
         rows, picked = [], []
-        moved = jnp.int32(0)
+        grouped = jnp.zeros((2,), jnp.int32)
         for p, routed in zip(params["layers"], self._routed):
             a = _rms_norm(x, p["ln_in"].astype(x.dtype), cfg.norm_eps)
             q_n, q_r, row = self._latent(p, a, positions)
             x = x + self._attention_out(p, self._expanded(p, q_n, q_r, row))
             if pad_to is not None:
                 rows.append(jnp.pad(row, ((0, 0), (0, pad_to - T), (0, 0))))
-            x, picks, pairs = self._ffn(p, x, routed, real=real)
+            x, picks, counts = self._ffn(p, x, routed, real=real)
             if routed:
                 picked.append(picks)
-                moved = moved + pairs
+                grouped = grouped + counts
         slab = None if pad_to is None else {"latent": jnp.stack(rows)}
         n_routed = sum(picks.size for picks in picked)
-        return x, slab, picked, jnp.stack([moved, jnp.int32(n_routed)])
+        return x, slab, picked, jnp.stack([
+            grouped[0], jnp.int32(n_routed), grouped[1]])
 
     def apply(self, params, tokens):
         """tokens [B, T] int32 -> logits [B, T, V] (float32)."""

@@ -77,6 +77,9 @@ class Qwen3NextLM(DecoderLM):
         # that hold a token of the sequence (``ceil(lens / CHUNK)``: what
         # the prefill kernel walks) and the chunks of its bucket
         "gdn_prefill_chunks_walked", "gdn_prefill_chunks_bucket",
+        # the rows of the row tiles the grouped experts' kernel worked, a
+        # tile once for every group it was worked for
+        "moe_prefill_tile_rows",
     )
     serving_refuses = {
         "speculation": "the draft is the first layers of a stacked llama "
@@ -442,7 +445,7 @@ class Qwen3NextLM(DecoderLM):
         """h [B, T, D] after the mixer -> the layer's output, the picks
         [B, T, k] over ALL experts and, for a decode step (``live`` [B]),
         (held experts touched, rows routed, rows that landed here); for a
-        prefill, the pairs its grouped experts moved. ``real`` [B, T] bool
+        prefill, its grouped experts' ``GROUPED_COUNTS``. ``real`` [B, T] bool
         (a prefill's): the rows that are some sequence's tokens."""
         import jax
         import jax.numpy as jnp
@@ -466,11 +469,10 @@ class Qwen3NextLM(DecoderLM):
                 # go to no expert's id, which no share holds
                 sent = jnp.where(real.reshape(-1, 1), picks,
                                  cfg.n_routed_experts)
-            y = experts.grouped_experts(
+            y, counts = experts.grouped_experts(
                 rows, sent, weights, *stacks, held=cfg.experts_held,
-                n_routed=cfg.n_routed_experts)
-            # where every expert is held, every pair is moved
-            y, counts = (y, picks.size) if cfg.experts_held is None else y
+                n_routed=cfg.n_routed_experts,
+                mesh=getattr(self, "_serving_mesh", None))
         else:
             y, touched, routed = experts.decode_experts(
                 rows, picks, weights, live, *stacks,
@@ -524,7 +526,7 @@ class Qwen3NextLM(DecoderLM):
         rep = cfg.n_heads // cfg.n_kv_heads
         leaves = {"k": [], "v": [], "conv": [], "state": []}
         picked = []
-        moved = jnp.int32(0)
+        grouped = jnp.zeros((2,), jnp.int32)
         for p, linear in zip(params["layers"], self._linear):
             a = self._norm(x, p["ln_in"])
             if linear:
@@ -547,9 +549,9 @@ class Qwen3NextLM(DecoderLM):
                     pad = ((0, 0), (0, 0), (0, pad_to - T), (0, 0))
                     leaves["k"].append(jnp.pad(k, pad))
                     leaves["v"].append(jnp.pad(v, pad))
-            x, picks, pairs = self._moe(p, x, real=real)
+            x, picks, counts = self._moe(p, x, real=real)
             picked.append(picks)
-            moved = moved + pairs
+            grouped = grouped + counts
         slab = None if pad_to is None else {
             name: jnp.stack(each) for name, each in leaves.items() if each}
         routed = sum(picks.size for picks in picked)
@@ -557,7 +559,8 @@ class Qwen3NextLM(DecoderLM):
         walked = jnp.sum(-(-lens // gated_delta.CHUNK)) * self._n_linear
         bucket = B * -(-T // gated_delta.CHUNK) * self._n_linear
         return x, slab, picked, jnp.stack([
-            moved, jnp.int32(routed), walked, jnp.int32(bucket)])
+            grouped[0], jnp.int32(routed), walked, jnp.int32(bucket),
+            grouped[1]])
 
     def apply(self, params, tokens):
         """tokens [B, T] int32 -> logits [B, T, V] (float32)."""

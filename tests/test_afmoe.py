@@ -231,8 +231,11 @@ def _expert_case(n=48, d=256, f=128, e=8, k=2):
 def test_decode_experts_against_the_grouped_path_against_a_plain_loop():
     x, picks, weights, stacks, loop = _expert_case()
     assert np.allclose(np.asarray(weights.sum(-1)), 2.826, atol=1e-5)
-    grouped = experts.grouped_experts(x, picks, weights, *stacks)
-    in_groups = experts.grouped_experts(x, picks, weights, *stacks, group_rows=16)
+    grouped, counts = experts.grouped_experts(x, picks, weights, *stacks)
+    in_groups, _ = experts.grouped_experts(x, picks, weights, *stacks,
+                                           group_rows=16)
+    # every pair moved; off the kernel's shapes the dots work every row
+    assert counts.tolist() == [picks.size, picks.size]
     assert np.abs(np.asarray(grouped) - loop).max() < 1e-4
     assert np.abs(np.asarray(in_groups) - loop).max() < 1e-4
     live = jnp.asarray(np.random.default_rng(6).random(48) < 0.3)
@@ -251,6 +254,62 @@ def test_decode_experts_against_the_grouped_path_against_a_plain_loop():
     none, touched, routed = experts.decode_experts(
         x, picks, weights, jnp.zeros(48, bool), *stacks)
     assert not np.asarray(none).any() and int(touched) == 0 == int(routed)
+
+
+def _tile_rows(picks, tm):
+    """What the grouped kernel works for these (row, pick) pairs in tiles
+    of ``tm`` rows: the pairs sorted by expert lie group after group, and
+    a group is worked in every tile that holds a row of it."""
+    sizes = np.bincount(np.asarray(picks).ravel(), minlength=8)
+    ends = np.cumsum(sizes)
+    return tm * sum(-(-int(e) // tm) - int(e - n) // tm
+                    for e, n in zip(ends, sizes) if n)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_a_prompts_pad_rows_go_to_no_expert_and_the_prefill_counts_it(served, dtype):
+    """80 tokens in a bucket of 256 (the cell's 2048 in 4096, cut small),
+    the pad rows' picks sent to no expert against the pad rows routed: the
+    same logits at ``last_index``, the same cache rows up to the prompt's
+    length and the same served picks. ``prefill_counted`` counts the
+    bucket's pairs as routed and moved, and as tile rows what the real
+    rows' picks say where the shapes are the kernel's (bfloat16), every row
+    where they are the dots'."""
+    _, params, tokens, _, _ = served
+    model = DecoderLM(**dict(CFG, dtype=dtype))
+    n, bucket = 80, 256
+    prompt = jnp.asarray(tokens[:bucket], jnp.int32)[None]
+    prompt = prompt.at[:, n:].set(0)
+    last = jnp.asarray([n - 1], jnp.int32)
+    logits, cache, picked = model._prefill(params, prompt, bucket, last)
+    x, ks, vs, routed_picks, routed_counts = model._forward(
+        params, prompt, bucket, None)
+    routed_logits = model._head(params, x, last)
+    tol = dict(rtol=0, atol=1e-5 if dtype == "float32" else 2.0 ** -6)
+    np.testing.assert_allclose(logits, routed_logits, **tol)
+    for leaf, rows in (("k", ks), ("v", vs)):
+        np.testing.assert_allclose(
+            np.asarray(cache[leaf][:, :, :, :n], np.float32),
+            np.asarray(jnp.stack(rows)[:, :, :, :n], np.float32), **tol)
+    assert len(picked) == 4
+    for mine, theirs in zip(picked, routed_picks):
+        np.testing.assert_array_equal(mine[:, :n], theirs[:, :n])
+    counted, cache2, counts = model.prefill_counted(params, prompt, bucket, last)
+    np.testing.assert_array_equal(counted, logits)
+    np.testing.assert_array_equal(cache2["k"], cache["k"])
+    pairs = 4 * bucket * 2
+    in_kernel = experts.groups_in_kernel(
+        "tpu", (bucket * 2, 256), (8, 256, 128), dtype)
+    assert in_kernel == (dtype == "bfloat16")
+    if in_kernel:
+        assert experts.row_tile(bucket * 2) == 128
+        worked = sum(_tile_rows(p[0, :n], 128) for p in picked)
+        whole = sum(_tile_rows(p[0], 128) for p in routed_picks)
+        assert worked < whole
+    else:
+        worked = whole = pairs
+    assert counts.tolist() == [pairs, pairs, worked]
+    assert routed_counts.tolist() == [pairs, pairs, whole]
 
 
 # -- the scheduler, and what refuses the family ---------------------------------------
@@ -272,10 +331,14 @@ def test_the_batcher_serves_it_and_counts_what_the_experts_and_windows_do(served
                             list(range(299, 311))).argmax(-1)
     assert len(out) == 312 and out[300:] == want.tolist()
     s = batcher.stats
-    # a family that names no prefill counters: its inserts take and return
-    # what they always did, and no burst brings any home
-    assert batcher._prefill_counters == () and batcher._prefill_counts == []
-    assert "moe_prefill_pairs_moved" not in s
+    # the prefill's counters (PR 43) came home beside a burst: 300 tokens
+    # in the 512 bucket, 2 picks, 4 expert layers; every pair of the bucket
+    # is moved (all experts are held) and in float32 the rows are the
+    # dots', which work every row they are given
+    assert batcher._prefill_counters == model.prefill_counter_names == (
+        "moe_prefill_pairs_moved", "moe_prefill_pairs_routed",
+        "moe_prefill_tile_rows")
+    assert [s[name] for name in batcher._prefill_counters] == [4 * 512 * 2] * 3
     assert s["moe_layer_steps"] == 4 * s["steps"] > 0
     assert s["moe_experts_touched"] == 2 * s["moe_layer_steps"]    # one lane
     assert s["moe_rows_routed"] == s["moe_experts_touched"]

@@ -335,6 +335,85 @@ def test_qwen3_next_prefill_compiled_for_v5e_is_one_delta_kernel_a_linear_layer(
     assert scoped and not [line for line in scoped if "/while/" in line]
 
 
+EXPERT_CUTS = {
+    # a dense layer, a window and a full expert layer; a DeltaNet period;
+    # the dense layer and two expert layers
+    "trinity-mini": [0, 6, 7],
+    "qwen3-next-80b-a3b": [0, 1, 2, 3],
+    "joyai-llm-flash": [0, 1, 2],
+}
+
+
+@pytest.mark.parametrize("config", list(EXPERT_CUTS))
+def test_the_expert_cells_burst_is_one_program_with_and_without_the_grouped_kernel(
+        one_chip, config, monkeypatch):
+    """The grouped prefill's kernel (PR 43) is in no decode burst: the
+    burst of each expert cell, compiled for the v5e, is the same program
+    but for metadata whether the grouped path's shapes are the kernel's or
+    (as in the parent) never are, and names no ``grouped_swiglu`` call."""
+    import jax
+
+    from seldon_core_tpu.ops import experts
+
+    tool = _tool()
+    with open(os.path.join(ROOT, "benchmark", "configs", config + ".json")) as f:
+        cfg = json.load(f)
+    cfg["name"] = config
+    cfg["served_layers"] = EXPERT_CUTS[config]
+    cfg["num_hidden_layers"] = len(cfg["served_layers"])
+    mine = tool.compile_burst(cfg, None, one_chip)[0].as_text()
+    assert "touched_experts_ffn" in mine and "grouped_swiglu" not in mine
+    monkeypatch.setattr(experts, "groups_in_kernel", lambda *a, **kw: False)
+    jax.clear_caches()       # ``decode_experts`` is traced anew, the dots in it
+    parents = tool.compile_burst(cfg, None, one_chip)[0].as_text()
+    jax.clear_caches()
+    same = tool.same_program(mine, parents)
+    assert same["hlo_equal_but_for_metadata"], same["first_differing_line"]
+    assert same["kernels_equal_but_for_locations"] and same["kernels"] > 0
+
+
+def test_trinity_mini_prefill_compiled_for_v5e_is_one_grouped_kernel_a_layer(one_chip):
+    """The configuration's own prefill of one prompt in the 4096 bucket (all
+    6 layers, counters and all): each of the 4 expert layers' grouped
+    experts is ONE ``grouped_swiglu`` call over the 32,768 sorted pairs,
+    and no ``ragged-dot`` is left."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from benchmark import manifest
+    from seldon_core_tpu.models.llm import DecoderLM
+
+    with open(os.path.join(ROOT, "benchmark", "configs",
+                           "trinity-mini.json")) as f:
+        cfg = json.load(f)
+    kwargs = manifest.architecture(
+        ROOT, manifest.load(ROOT), cfg["architecture"]).model_kwargs(cfg, 0)
+    kwargs.pop("seed")
+    model = DecoderLM(**kwargs)
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(
+        lambda a: sds(a.shape, jnp.dtype(model.cfg.dtype)),
+        jax.eval_shape(model.init_params, 0))
+    T = cfg["server"]["max_seq"]
+    compiled = jax.jit(
+        lambda p, t, last: model.prefill_counted(p, t, T, last)).lower(
+        params, sds((1, T), jnp.int32), sds((1,), jnp.int32)).compile()
+    hlo = compiled.as_text()
+    names = re.findall(r"%([a-z_]+)[.\d]* = [^\n]*? custom-call\(", hlo)
+    assert names.count("grouped_swiglu") == 4
+    assert "ragged-dot" not in hlo and "ragged_dot" not in hlo
+    # the sorted rows and the weighted products of a layer, in bfloat16:
+    # nothing [32768, 1024] is written for the SwiGLU, in any type
+    assert not re.search(r"= \w+\[32768,1024\]", hlo)
+    # the parent's scratch (2,297 MiB; most of it outside the experts)
+    assert compiled.memory_analysis().temp_size_in_bytes < 2400 << 20
+
+
 def test_joyai_llm_flash_burst_compiled_for_v5e_is_kernels_over_a_latent_cache_in_place(one_chip):
     """The configuration's own burst (64 lanes, all 12 layers, no bucket):
     every layer decodes through the ragged latent kernel (one [64, 6144,

@@ -344,7 +344,7 @@ def test_an_expert_width_of_768_goes_through_the_decode_kernel():
     got = experts.touched_experts_ffn(
         x, picks, jnp.where(live[:, None], weights, 0.0), ids, n, w1, w1, w2,
         interpret=True)
-    want = experts.grouped_experts(
+    want, _ = experts.grouped_experts(
         x, picks, jnp.where(live[:, None], weights, 0.0), w1, w1, w2)
     np.testing.assert_allclose(got, want, atol=1e-4)
 

@@ -351,9 +351,9 @@ def test_a_share_over_its_room_is_the_parents_grouped_path(share, dtype):
     held = (4 * share, 4)
     assert experts.room_of(384, held, 16) == 128
     want = np.asarray(_parent_held(x, picks, weights, stacks[share], held))
-    got, moved = experts.grouped_experts(
+    got, (moved, worked) = experts.grouped_experts(
         x, picks, weights, *stacks[share], held=held, n_routed=16)
-    assert got.dtype == jnp.float32 and int(moved) == 128
+    assert got.dtype == jnp.float32 and int(moved) == 128 == int(worked)
     np.testing.assert_allclose(got, want, rtol=0, atol=_one_rounding(want))
     # a capture names the share's ops by their scope
     assert "held_experts_prefill" in experts.grouped_experts.lower(
@@ -379,8 +379,8 @@ def test_the_room_falls_back_drop_free(skew):
     assert landed > 2 * 128                       # c > room
     want = np.asarray(experts.grouped_experts(
         x, jnp.where(here, picks - 8, 0), jnp.where(here, weights, 0.0),
-        *stacks[2]))
-    got, moved = experts.grouped_experts(
+        *stacks[2])[0])
+    got, (moved, _) = experts.grouped_experts(
         x, picks, weights, *stacks[2], held=held, n_routed=16)
     assert int(moved) == -(-landed // 128) * 128 >= 3 * 128
     np.testing.assert_allclose(got, want, rtol=0, atol=_one_rounding(want))
@@ -399,7 +399,7 @@ def test_more_rows_than_a_group_go_through_the_room_in_groups(
     x, picks, weights, stacks = _share_case(rows=rows, seed=13)
     held = (12, 4)
     want = np.asarray(_parent_held(x, picks, weights, stacks[3], held))
-    got, moved = experts.grouped_experts(
+    got, (moved, _) = experts.grouped_experts(
         x, picks, weights, *stacks[3], group_rows=group_rows, held=held,
         n_routed=16)
     room = experts.room_of(rows // groups * 4, held, 16)
@@ -419,13 +419,14 @@ def test_a_room_is_the_shares_expected_pairs_and_a_quarter_more():
 
 
 def test_a_whole_layer_moves_every_pair_and_a_share_its_room(served):
-    """``prefill_counted``: ``prefill`` and the two counters, for a model
+    """``prefill_counted``: ``prefill`` and the counters, for a model
     that holds a share and for one that holds every expert."""
     model, params = served
     whole = DecoderLM(**dict(SMALL, experts_held=None))
     assert model.prefill_counter_names == whole.prefill_counter_names == (
         "moe_prefill_pairs_moved", "moe_prefill_pairs_routed",
-        "gdn_prefill_chunks_walked", "gdn_prefill_chunks_bucket")
+        "gdn_prefill_chunks_walked", "gdn_prefill_chunks_bucket",
+        "moe_prefill_tile_rows")
     prompt = jnp.asarray(
         np.random.default_rng(2).integers(0, 256, size=(2, 64)), jnp.int32)
     logits, slab = model.prefill(params, prompt, 64)
@@ -434,10 +435,11 @@ def test_a_whole_layer_moves_every_pair_and_a_share_its_room(served):
     for name in slab:
         np.testing.assert_array_equal(slab[name], slab2[name])
     # 128 rows x 4 picks in 8 layers; a layer's room is 384 of its 512;
-    # two sequences that fill their one chunk in 6 linear layers
-    assert counts.tolist() == [8 * 384, 8 * 512, 12, 12]
+    # two sequences that fill their one chunk in 6 linear layers; in
+    # float32 the rows are the dots', which work every row they are given
+    assert counts.tolist() == [8 * 384, 8 * 512, 12, 12, 8 * 384]
     assert whole.prefill_counted(whole.init_params(3), prompt, 64)[2].tolist() == [
-        8 * 512, 8 * 512, 12, 12]
+        8 * 512, 8 * 512, 12, 12, 8 * 512]
 
 
 @pytest.mark.parametrize("bucket,lens", [
@@ -452,7 +454,7 @@ def test_a_prefill_counts_the_chunks_its_sequences_have(served, bucket, lens):
         0, 256, size=(len(lens), bucket)), jnp.int32)
     *_, counts = model.prefill_counted(
         params, prompt, 256, jnp.asarray(lens, jnp.int32) - 1)
-    assert counts.tolist()[2:] == [
+    assert counts.tolist()[2:4] == [
         6 * sum(-(-n // 64) for n in lens), 6 * len(lens) * bucket // 64]
     assert counts.tolist()[1] == 8 * len(lens) * bucket * 4
 
@@ -477,8 +479,8 @@ def test_padding_is_sent_to_no_expert_of_a_share(served, held):
     m = model._norm(h, layer["ln_post"])[0, 32]
     layer["router"] = layer["router"].at[:, 4:8].set(m[:, None] * 4)
     real = jnp.arange(128)[None, :] < 32
-    out, picks, moved = model._moe(layer, h, real=real)
-    plain, picks2, moved2 = model._moe(layer, h)
+    out, picks, (moved, _) = model._moe(layer, h, real=real)
+    plain, picks2, (moved2, _) = model._moe(layer, h)
     np.testing.assert_array_equal(picks, picks2)      # what was routed
     assert sorted(picks[0, 40].tolist()) == [4, 5, 6, 7]
     np.testing.assert_array_equal(out[:, :32], plain[:, :32])
@@ -502,7 +504,7 @@ def test_an_insert_sums_the_prefill_counters_only_where_a_family_names_them(
     if counted:
         b = batcher
         assert b._prefill_counters == model.prefill_counter_names
-        assert [a.tolist() for a in b._no_prefill_counts] == [[0, 0, 0, 0]]
+        assert [a.tolist() for a in b._no_prefill_counts] == [[0, 0, 0, 0, 0]]
     else:
         dense = DecoderLM(vocab_size=128, d_model=64, n_layers=2, n_heads=4,
                           n_kv_heads=2, d_ff=128, max_seq=128, dtype="float32")
@@ -523,8 +525,8 @@ def test_an_insert_sums_the_prefill_counters_only_where_a_family_names_them(
         assert len(plain) == 4
         if counted:
             # 10 tokens of a 128 bucket: one chunk of two in 6 linear layers
-            so_far = jnp.asarray([5, 7, 11, 13], jnp.int32)
-            want = [5 + 8 * 384, 7 + 8 * 512, 11 + 6, 13 + 12]
+            so_far = jnp.asarray([5, 7, 11, 13, 17], jnp.int32)
+            want = [5 + 8 * 384, 7 + 8 * 512, 11 + 6, 13 + 12, 17 + 8 * 384]
             *_, total = b._insert_fn(
                 fresh(), one, 1, first[0], 10, key, *regs, so_far, *counts)
             assert total.tolist() == want
@@ -579,23 +581,49 @@ def test_refusals_name_their_reason_and_requests_are_refused_where_they_come_in(
         DecoderLM(**dict(SMALL, partial_rotary_factor=0.0))
 
 
+def _equations(jaxpr):
+    """The primitives of a jaxpr and of the jaxprs inside it, counted."""
+    import collections
+
+    from jax.extend import core
+
+    found = collections.Counter()
+    for eqn in jaxpr.eqns:
+        found[eqn.primitive.name] += 1
+        for inner in jax.tree_util.tree_leaves(
+                eqn.params, is_leaf=lambda p: isinstance(
+                    p, (core.Jaxpr, core.ClosedJaxpr))):
+            if isinstance(inner, core.ClosedJaxpr):
+                inner = inner.jaxpr
+            if isinstance(inner, core.Jaxpr):
+                found.update(_equations(inner))
+    return found
+
+
 @pytest.mark.parametrize("group_rows", [4096, 16])
 def test_without_held_the_grouped_path_traces_to_the_parents(group_rows):
     """``held=None`` (the afmoe block's prefill, every family's decode off a
-    TPU): the jaxpr of ``grouped_experts`` is the one its parent's lines
-    trace, in one piece and in groups."""
+    TPU) at shapes the grouped kernel does not take: the rows' sums of
+    ``grouped_experts`` trace to the parent's lines (since PR 43 with the
+    mask of the pairs that are no expert's, which a pad row's are), in one
+    piece and in groups."""
+    def one(x, picks, weights, w1, w3, w2):
+        # every pair is moved, and the dots work every row they are given
+        return (_parent_grouped(x, picks, weights, w1, w3, w2, True),
+                jnp.stack([jnp.int32(picks.size), jnp.int32(picks.size)]))
+
     def parent(x, picks, weights, w1, w3, w2):
         n = x.shape[0]
         if n <= group_rows:
-            return _parent_grouped(x, picks, weights, w1, w3, w2, False)
+            return one(x, picks, weights, w1, w3, w2)
         groups = -(-n // group_rows)
         while n % groups:
             groups += 1
         split = lambda a: a.reshape(groups, n // groups, *a.shape[1:])  # noqa: E731
-        out = lax.map(
-            lambda r: _parent_grouped(r[0], r[1], r[2], w1, w3, w2, False),
+        out, counts = lax.map(
+            lambda r: one(r[0], r[1], r[2], w1, w3, w2),
             (split(x), split(picks), split(weights)))
-        return out.reshape(n, x.shape[1])
+        return out.reshape(n, x.shape[1]), counts.sum(axis=0)
 
     x, picks, weights, stacks = _share_case(rows=48)
     args = (x, picks % 4, weights, *stacks[0])
@@ -603,10 +631,17 @@ def test_without_held_the_grouped_path_traces_to_the_parents(group_rows):
     def mine(*args):
         return experts.grouped_experts.__wrapped__(*args, group_rows=group_rows)
 
-    assert str(jax.make_jaxpr(mine)(*args)) == str(jax.make_jaxpr(parent)(*args))
-    np.testing.assert_array_equal(
-        experts.grouped_experts(*args, group_rows=group_rows),
-        jax.jit(parent)(*args))
+    # the same primitives (the parent gathers the pairs' weights after its
+    # dots and masks a column, ``_pairs_ffn`` is handed both): the three
+    # dots, no platform switch, no kernel
+    traced = _equations(jax.make_jaxpr(mine)(*args).jaxpr)
+    assert set(traced) == set(_equations(jax.make_jaxpr(parent)(*args).jaxpr))
+    assert traced["ragged_dot_general"] == 3
+    assert not {"pallas_call", "platform_index", "cond"} & set(traced)
+    got, want = experts.grouped_experts(
+        *args, group_rows=group_rows), jax.jit(parent)(*args)
+    np.testing.assert_array_equal(got[0], want[0])
+    assert got[1].tolist() == want[1].tolist() == [192, 192]
 
 
 def test_the_dense_and_afmoe_blocks_take_none_of_the_new_arguments():
