@@ -67,6 +67,10 @@ class LLMConfig:
     # "joyai_llm_flash": latent attention (one compressed row a position,
     # absorbed into the decode step) and a chip's share of sigmoid-routed
     # experts (models/joyai_llm_flash.py).
+    # "evabyte": EVA attention (exact inside an aligned window, one pooled
+    # summary row a chunk for every earlier window) over a cache of two
+    # kinds of two lengths, a float32 residual stream and several
+    # prediction heads (models/evabyte.py).
     block: str = "llama"
     # per layer "sliding_attention" | "full_attention"; None = all full
     layer_types: Optional[Tuple[str, ...]] = None
@@ -96,6 +100,12 @@ class LLMConfig:
     qk_nope_head_dim: int = 0     # a head's key dims expanded from c
     qk_rope_head_dim: int = 0     # the one rotary key all heads share
     v_head_dim: int = 0           # a head's value dims expanded from c
+    # -- the evabyte block only: EVA attention (models/evabyte.py) ------
+    window_size: int = 0          # positions of one aligned window
+    chunk_size: int = 0           # positions pooled into one summary row
+    num_pred_heads: int = 1       # heads of vocab_size logits a position
+    norm_add_unit_offset: bool = False   # a norm's weight is 1 + w
+    fp32_skip_add: bool = False   # the residual stream adds in float32
 
     def __post_init__(self):
         if not self.head_dim:
@@ -171,6 +181,8 @@ class DecoderLM(ServedModel):
                 from .qwen3_next import Qwen3NextLM as family
             elif config["block"] == "joyai_llm_flash":
                 from .joyai_llm_flash import JoyaiLLMFlashLM as family
+            elif config["block"] == "evabyte":
+                from .evabyte import EvaByteLM as family
             else:
                 raise ValueError(f"unknown block variant {config['block']!r}")
             return super().__new__(family)
@@ -574,6 +586,39 @@ class DecoderLM(ServedModel):
         pressure ledger."""
         return sum(a.nbytes // (a.shape[0] * a.shape[-2])
                    for a in self.position_layers(cache))
+
+    def park_index(self, cache) -> int:
+        """The write position the stop-aware burst gives a lane that must
+        write nothing: past the end of every kind's positions, so that the
+        scatter and the kernel drop the row (a family whose kinds differ in
+        length, or wrap, says where that is)."""
+        return self.position_layers(cache)[0].shape[-2]
+
+    def lane_cache_bytes(self, cache):
+        """``positions -> bytes``: what a lane that holds ``positions``
+        positions occupies of ``cache`` over every layer, the pressure
+        ledger's and the insert records' price. One row a position here;
+        a family whose position costs more in one kind than in another
+        prices its own."""
+        per_position = self.cache_position_bytes(cache)
+        return lambda positions: positions * per_position
+
+    def prefill_lengths(self, buckets, max_seq: int):
+        """Of the batcher's prompt buckets (ascending), the padded lengths
+        this family's ``prefill`` takes; a prompt past the last goes to
+        ``max_seq``. Every length here."""
+        return tuple(buckets)
+
+    def prefill_rows_max(self, bucket: int) -> int:
+        """The most prompts one batched prefill in ``bucket`` takes."""
+        return 8
+
+    def admissions_per_turn(self) -> int:
+        """The most prompts the scheduler admits between two decode bursts;
+        0 for every free lane, so that one batched prefill takes them
+        together. A family whose prefill holds the device for long says
+        fewer, and the live lanes decode between two of them."""
+        return 0
 
     def prefill_slab_bytes(self, rows: int, bucket: int) -> int:
         """Bytes of the slab a batched prefill of ``rows`` prompts in

@@ -61,8 +61,37 @@ def _banded_attention(q, k, v, window: int):
     return jnp.einsum("bhqk,bhkd->bhqd", p, v.astype(jnp.float32)).astype(q.dtype)
 
 
+def _prefixed_attention(q, k, v, prefix: int, prefix_len):
+    """Causal attention behind a visible prefix: keys and values [B, H,
+    prefix + T, D] whose first ``prefix`` rows are a prefix of which every
+    query sees the first ``prefix_len`` (traced) and whose other T rows are
+    the queries' own positions, seen causally. ONE softmax over both; the
+    kernel's mask as masked dots."""
+    scale = 1.0 / (q.shape[-1] ** 0.5)
+    s = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32),
+                   k.astype(jnp.float32)) * scale
+    row = jnp.arange(q.shape[2])[:, None]
+    col = jnp.arange(k.shape[2])[None, :]
+    seen = jnp.where(col < prefix, col < prefix_len, row >= col - prefix)
+    s = jnp.where(seen[None, None], s, NEG_INF)
+    p = jax.nn.softmax(s, axis=-1)
+    return jnp.einsum("bhqk,bhkd->bhqd", p, v.astype(jnp.float32)).astype(q.dtype)
+
+
+def _prefixed_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, *, block_q,
+                     block_k, prefix):
+    """``_flash_kernel`` behind a visible prefix (``len_ref``: SMEM [1],
+    scalar-prefetched): the first ``prefix`` keys are seen by every row
+    where they lie below ``len_ref[0]``, the others causally; the walk
+    takes the prefix's ``ceil(len / block_k)`` blocks and then the causal
+    ones, and a prefix block past the visible ones is never read."""
+    _flash_kernel(q_ref, k_ref, v_ref, o_ref, block_q=block_q,
+                  block_k=block_k, causal=True, prefix=prefix,
+                  prefix_len=len_ref[0])
+
+
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, block_q, block_k, causal,
-                  window=None):
+                  window=None, prefix=None, prefix_len=None):
     """One (bh, q-block) program: stream K/V blocks with online softmax.
 
     q_ref: [1, block_q, Dh]; k_ref: [1, Tk, Dh]; v_ref: [1, Tk, Dv] and
@@ -72,6 +101,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, block_q, block_k, causal,
     ``window`` (static, with ``causal``): row i sees columns (i - window,
     i]; the walk starts at the block that holds the q-block's first row's
     first column and key blocks wholly left of the band are never read.
+    ``prefix`` (static, a multiple of ``block_k``, with ``causal``) and
+    ``prefix_len`` (traced): ``_prefixed_kernel``'s.
     """
     qb = pl.program_id(1)
     dh = q_ref.shape[-1]
@@ -80,7 +111,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, block_q, block_k, causal,
     t_k = k_ref.shape[1]
     row = qb * block_q + lax.broadcasted_iota(jnp.int32, (block_q, 1), 0)
 
-    def body(i, carry):
+    def body(i, carry, in_prefix=False):
         o, m, l = carry
         # the traced start is block-aligned: say so, or Mosaic cannot
         # prove the sublane slice lands on a tile edge
@@ -92,7 +123,12 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, block_q, block_k, causal,
         )  # [block_q, block_k]
         if causal:
             col = i * block_k + lax.broadcasted_iota(jnp.int32, (1, block_k), 1)
-            seen = row >= col
+            if in_prefix:
+                seen = col < prefix_len
+            elif prefix is not None:
+                seen = row >= col - prefix
+            else:
+                seen = row >= col
             if window is not None:
                 seen = seen & (col > row - window)
             s = jnp.where(seen, s, NEG_INF)
@@ -106,9 +142,11 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, block_q, block_k, causal,
         # p == 1 per entry (an unweighted mean of V, not zeros); reuse
         # with such masks requires a p = where(s == NEG_INF, 0, ...) guard.
         p = jnp.exp(s - m_new)
-        if window is not None:
+        if window is not None or in_prefix:
             # the band hides the walk's first block from the q-block's
-            # later rows whole: the guard the note above asks for
+            # later rows whole (and a prefix block its invisible tail from
+            # every row, before any row has a finite m): the guard the note
+            # above asks for
             p = jnp.where(seen, p, 0.0)
         l = l * alpha + p.sum(axis=-1, keepdims=True)
         o = o * alpha + jax.lax.dot_general(
@@ -128,6 +166,16 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, block_q, block_k, causal,
     first = 0
     if window is not None:
         first = jnp.maximum(0, (qb * block_q - window + 1) // block_k)
+    if prefix is not None:
+        # the visible prefix first, then from the first causal block to the
+        # q-block's last row
+        o, m, l = lax.fori_loop(
+            0, (prefix_len + block_k - 1) // block_k,
+            functools.partial(body, in_prefix=True), (o, m, l))
+        first = prefix // block_k
+        n_k = jnp.minimum(
+            t_k // block_k,
+            first + (qb * block_q + block_q + block_k - 1) // block_k)
     o, m, l = lax.fori_loop(first, n_k, body, (o, m, l))
     # l == 0 is unreachable via the causal equal-block dispatch (see the
     # loop-body comment); kept as a belt against 0/0 if the kernel is
@@ -139,7 +187,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, block_q, block_k, causal,
 @functools.partial(
     jax.jit,
     static_argnames=("causal", "block_q", "block_k", "interpret", "window",
-                     "name"),
+                     "name", "prefix"),
 )
 def flash_attention(
     q,
@@ -151,17 +199,31 @@ def flash_attention(
     interpret: bool = False,
     window=None,
     name=None,
+    prefix=None,
+    prefix_len=None,
 ):
     """Pallas blocked attention. q [B,H,Tq,Dh], k [B,H,Tk,Dh], v
     [B,H,Tk,Dv] (Dv = Dh everywhere but latent attention's prefill).
     Tq must divide by block_q and Tk by block_k (use :func:`attention`
     for the dispatching fallback). ``window`` (static int, causal only):
     query i sees keys (i - window, i]. ``name``: the kernel's name in a
-    trace, where a caller wants its own."""
+    trace, where a caller wants its own. ``prefix`` (static int, causal
+    only, a multiple of ``block_k``) with ``prefix_len`` (a traced int32
+    scalar): k and v are [B, H, prefix + Tq, .], their first ``prefix`` rows
+    a prefix every query sees the first ``prefix_len`` rows of, the others
+    the queries' own positions."""
     if window is not None and not causal:
         raise ValueError("a window is causal")
     b, h, t_q, dh = q.shape
     t_k = k.shape[2]
+    if prefix is not None:
+        if not causal or window is not None or prefix % block_k \
+                or t_k != prefix + t_q:
+            raise ValueError(
+                f"a prefix of {prefix} before {t_q} causal keys: Tk={t_k}, "
+                f"block {block_k}, no window")
+        return _prefixed_flash(q, k, v, prefix, prefix_len, block_q, block_k,
+                               interpret, name)
     if t_q % block_q or t_k % block_k:
         raise ValueError(
             f"Tq={t_q} / Tk={t_k} must tile by block ({block_q}, {block_k})"
@@ -197,8 +259,41 @@ def flash_attention(
     return out.reshape(b, h, t_q, dv)
 
 
+def _prefixed_flash(q, k, v, prefix, prefix_len, block_q, block_k,
+                    interpret, name):
+    """``flash_attention``'s call with the visible length prefetched into
+    SMEM (a grid of its own: the other call's is as it was)."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, h, t_q, dh = q.shape
+    t_k, dv = k.shape[2], v.shape[-1]
+    if t_q % block_q:
+        raise ValueError(f"Tq={t_q} must tile by block {block_q}")
+    out = pl.pallas_call(
+        functools.partial(_prefixed_kernel, block_q=block_q, block_k=block_k,
+                          prefix=int(prefix)),
+        out_shape=jax.ShapeDtypeStruct((b * h, t_q, dv), q.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(b * h, t_q // block_q),
+            in_specs=[
+                pl.BlockSpec((1, block_q, dh), lambda bh, i, n: (bh, i, 0)),
+                pl.BlockSpec((1, t_k, dh), lambda bh, i, n: (bh, 0, 0)),
+                pl.BlockSpec((1, t_k, dv), lambda bh, i, n: (bh, 0, 0)),
+            ],
+            out_specs=pl.BlockSpec(
+                (1, block_q, dv), lambda bh, i, n: (bh, i, 0)),
+        ),
+        interpret=interpret,
+        **({} if name is None else {"name": name}),
+    )(jnp.reshape(prefix_len, (1,)).astype(jnp.int32),
+      q.reshape(b * h, t_q, dh), k.reshape(b * h, t_k, dh),
+      v.reshape(b * h, t_k, dv))
+    return out.reshape(b, h, t_q, dv)
+
+
 def attention(q, k, v, kv_len=None, causal: bool = True, mesh=None,
-              window=None, name=None):
+              window=None, name=None, prefix=None, prefix_len=None):
     """Dispatching attention: Pallas flash kernel on TPU when the shape
     tiles onto the MXU, XLA einsum otherwise (CPU, tiny prompts). Inference
     only — the kernel defines no VJP; training paths keep the XLA/ring
@@ -210,6 +305,11 @@ def attention(q, k, v, kv_len=None, causal: bool = True, mesh=None,
     v may be narrower than q and k (latent attention's prefill: keys of
     192 = 128 + 64 rotary, values of 128): the scale is the keys' and the
     output the values' width. ``name``: the kernel's name in a trace.
+
+    ``prefix`` (static int) with ``prefix_len`` (traced): k and v hold
+    ``prefix`` rows before the queries' own, of which every query sees the
+    first ``prefix_len`` (EVA attention's summaries of the earlier windows);
+    causal, no window, no mesh.
 
     ``mesh``: the serving mesh when the caller runs under one. Mosaic
     kernels cannot be partitioned by GSPMD, so the kernel call is wrapped
@@ -223,6 +323,14 @@ def attention(q, k, v, kv_len=None, causal: bool = True, mesh=None,
     256/512 blocks (T >= 4096) lower for the TPU platform but have not been
     compiled on the chip."""
     t_q, t_k = q.shape[2], k.shape[2]
+    if prefix is not None:
+        if mesh is not None or kv_len is not None or window is not None:
+            raise ValueError("a prefix takes no mesh, kv_len or window")
+        if (jax.default_backend() == "tpu" and t_q % 128 == 0
+                and prefix % 128 == 0 and q.shape[-1] in (64, 128, 256)):
+            return flash_attention(q, k, v, causal=True, prefix=int(prefix),
+                                   prefix_len=prefix_len, name=name)
+        return _prefixed_attention(q, k, v, int(prefix), prefix_len)
     # bigger blocks amortise the online-softmax rescale and MXU ramp-up
     # (block-size choice not measured on the current machine)
     block = 128

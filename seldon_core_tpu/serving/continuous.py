@@ -478,9 +478,15 @@ class ContinuousBatcher:
         # how many target forwards each token costs.
         self.draft_model = draft_model
         self.speculate_tokens = int(speculate_tokens) if draft_model is not None else 0
-        self.prefill_buckets = tuple(
-            sorted(b for b in prefill_buckets if b <= self.max_seq)
-        ) or (self.max_seq,)
+        # the family says which of them its prefill takes (all, but for
+        # one whose prefill is laid out in windows)
+        self.prefill_buckets = tuple(model.prefill_lengths(
+            sorted(b for b in prefill_buckets if b <= self.max_seq),
+            self.max_seq,
+        )) or (self.max_seq,)
+        # and how many prompts a turn admits before the next burst (every
+        # free lane, but for a family whose prefill holds the device long)
+        self._admit_cap = int(model.admissions_per_turn()) or self.slots
 
         self._queue: "queue.Queue[GenRequest]" = queue.Queue()
         # -- admit-queue load shedding (shed-before-work) -----------------
@@ -1153,9 +1159,9 @@ class ContinuousBatcher:
             each lane's remaining allowance AFTER its current token
             (decremented on device, re-uploaded only on membership
             changes)."""
-            # static: index >= T is dropped (positions are a cache
-            # array's second-to-last axis, whatever kinds the family has)
-            park = model.position_layers(cache)[0].shape[-2]
+            # static: an index the write of every kind drops (past the
+            # positions' axis; the family says where its kinds end)
+            park = model.park_index(cache)
 
             def body(carry, _):
                 cache, cur, p, kk, budget, done = carry
@@ -1343,6 +1349,10 @@ class ContinuousBatcher:
         # the family caches: the model sizes them): the unit of the modeled
         # burst read and of the pressure ledger
         self._kv_key_bytes = model.cache_position_bytes(self._cache)
+        # positions a lane holds -> bytes of the cache they occupy (the
+        # same product for a family of one row a position; one whose kinds
+        # differ in length prices them itself)
+        self._lane_bytes = model.lane_cache_bytes(self._cache)
         # the arrays a decode step writes one row each of
         self._position_layers = len(model.position_layers(self._cache))
         # the ragged decode read's granule (stats["kv_positions_read"])
@@ -1963,7 +1973,7 @@ class ContinuousBatcher:
             prompt[0, :n] = tokens
             with self._prof.measure(
                 "prefill", variant=f"p{bucket}",
-                bytes_read=self._param_bytes + bucket * self._kv_key_bytes,
+                bytes_read=self._param_bytes + self._lane_bytes(bucket),
                 tokens=bucket,
             ) as _m, device_trace("gen.prefill"):
                 first, cache_one, key, *_ = self._prefill_fn(
@@ -3055,6 +3065,8 @@ class ContinuousBatcher:
                     continue  # a wave can never exceed the lane pool
                 if m == 8 and not self._chunk8_ok(bucket):
                     continue  # slab would not fit; admission won't use it
+                if not self._rows_ok(m, bucket):
+                    continue  # the family's prefill takes fewer a call
                 prompts = jnp.zeros((m, bucket), jnp.int32)
                 last = jnp.zeros((m,), jnp.int32)
                 if m == 1:
@@ -3340,7 +3352,13 @@ class ContinuousBatcher:
         slab is a transient allocation on top of params + cache, [L, 8,
         KV, bucket, Dh] x2 for a K/V family: the model sizes it; 4 GB
         keeps flagship configs comfortably inside HBM)."""
-        return self.model.prefill_slab_bytes(8, bucket) <= 4 << 30
+        return (self._rows_ok(8, bucket)
+                and self.model.prefill_slab_bytes(8, bucket) <= 4 << 30)
+
+    def _rows_ok(self, m: int, bucket: int) -> bool:
+        """Whether the family's prefill in ``bucket`` takes ``m`` prompts a
+        call (one alone always)."""
+        return m == 1 or m <= self.model.prefill_rows_max(bucket)
 
     def _bucket(self, n: int) -> int:
         for b in self.prefill_buckets:
@@ -3796,7 +3814,7 @@ class ContinuousBatcher:
             with self._prof.measure(
                 "insert", variant=f"b{self._bucket(n)}",
                 tenant=req.tenant or "",
-                bytes_read=self._bucket(n) * self._kv_key_bytes, tokens=n,
+                bytes_read=self._lane_bytes(self._bucket(n)), tokens=n,
             ) as _m, device_trace("gen.lane_insert"):
                 self._cache, self._cur_tok, self._pos, self._keys = (
                     self._insert_fn(
@@ -4144,12 +4162,13 @@ class ContinuousBatcher:
         factor for staging/prefix slabs, which carry the model-axis split)
         and a staged swap scales by the param layout's per-shard fraction.
         Unmeshed, every factor is 1 and the arithmetic is unchanged."""
-        per_tok = self._kv_key_bytes
+        draft_per_tok = 0
         if self.speculate_tokens > 0 and not self._spec_suppressed:
-            per_tok += self._draft_kv_key_bytes
+            draft_per_tok = self._draft_kv_key_bytes
         decode = sum(
-            self._attn_need(pos) for pos in self._pos_host.values()
-        ) * per_tok // self._kv_shard
+            self._lane_bytes(need) + need * draft_per_tok
+            for need in map(self._attn_need, self._pos_host.values())
+        ) // self._kv_shard
         staging = sum(
             job.bucket for job in self._chunked.values()
         ) * self._kv_key_bytes // self._kv_model_shard
@@ -4391,11 +4410,12 @@ class ContinuousBatcher:
         a lane that must inevitably trip the high watermark is held at
         the head of the line instead of admitted-then-preempted (the
         thrash the hysteresis gap exists to prevent)."""
-        per_tok = self._kv_key_bytes
+        draft_per_tok = 0
         if self.speculate_tokens > 0 and not self._spec_suppressed:
-            per_tok += self._draft_kv_key_bytes
+            draft_per_tok = self._draft_kv_key_bytes
         end = min(self.max_seq, len(req.tokens) + req.max_new_tokens)
-        return self._attn_need(end) * per_tok
+        need = self._attn_need(end)
+        return self._lane_bytes(need) + need * draft_per_tok
 
     @scheduler_only
     def _pick_victim(self):
@@ -4820,7 +4840,7 @@ class ContinuousBatcher:
             prompt[0, :n] = req.tokens
             with self._prof.measure(
                 "prefill", variant=f"p{bucket}", tenant=req.tenant or "",
-                bytes_read=self._param_bytes + bucket * self._kv_key_bytes,
+                bytes_read=self._param_bytes + self._lane_bytes(bucket),
                 tokens=bucket,
             ) as _m, device_trace("gen.prefill"):
                 _f, cache_one, _k, *_ = self._prefill_fn(
@@ -4831,7 +4851,7 @@ class ContinuousBatcher:
                 _m.sync(cache_one)
             with self._prof.measure(
                 "insert", variant=f"b{bucket}", tenant=req.tenant or "",
-                bytes_read=bucket * self._kv_key_bytes, tokens=end_pos,
+                bytes_read=self._lane_bytes(bucket), tokens=end_pos,
             ) as _m, device_trace("gen.lane_insert"):
                 self._cache, self._cur_tok, self._pos, self._keys = (
                     self._insert_fn(
@@ -4916,7 +4936,7 @@ class ContinuousBatcher:
             prompt[0, :n] = req.tokens
             with self._prof.measure(
                 "prefill", variant=f"p{bucket}", tenant=req.tenant or "",
-                bytes_read=self._param_bytes + bucket * self._kv_key_bytes,
+                bytes_read=self._param_bytes + self._lane_bytes(bucket),
                 tokens=bucket,
             ) as _m, device_trace("gen.prefill"):
                 first, cache_one, lane_key, *counts = self._prefill_fn(
@@ -4930,7 +4950,7 @@ class ContinuousBatcher:
             t_insert = time.monotonic()
             with self._prof.measure(
                 "insert", variant=f"b{bucket}", tenant=req.tenant or "",
-                bytes_read=bucket * self._kv_key_bytes, tokens=n,
+                bytes_read=self._lane_bytes(bucket), tokens=n,
             ) as _m, device_trace("gen.lane_insert"):
                 (self._cache, self._cur_tok, self._pos, self._keys,
                  *self._prefill_counts) = self._insert_fn(
@@ -4998,7 +5018,7 @@ class ContinuousBatcher:
                 _wave_tenant = _ts.pop() or ""
         with self._prof.measure(
             "prefill", variant=f"m{m}p{bucket}", tenant=_wave_tenant,
-            bytes_read=self._param_bytes + m * bucket * self._kv_key_bytes,
+            bytes_read=self._param_bytes + m * self._lane_bytes(bucket),
             tokens=m * bucket,
         ) as _pm, device_trace("gen.prefill"):
             firsts, slab, lane_keys, *counts = self._prefill_many_fn(
@@ -5009,7 +5029,7 @@ class ContinuousBatcher:
         t_insert = time.monotonic()
         with self._prof.measure(
             "insert", variant=f"m{m}b{bucket}", tenant=_wave_tenant,
-            bytes_read=m * bucket * self._kv_key_bytes, tokens=m * bucket,
+            bytes_read=m * self._lane_bytes(bucket), tokens=m * bucket,
         ) as _im, device_trace("gen.lane_insert"):
             (self._cache, self._cur_tok, self._pos, self._keys,
              *self._prefill_counts) = self._insert_many_fn(
@@ -5493,7 +5513,8 @@ class ContinuousBatcher:
                     if not self._active and not self._chunked and not pending:
                         self._do_swap(swap)
                         swap = None
-                # admit as many queued requests as there are free slots —
+                # admit as many queued requests as there are free slots
+                # (or as the family says a turn takes) —
                 # same-bucket admissions are grouped so m lanes share one
                 # batched prefill forward (pow2 chunks bound executables)
                 clock.to("admit")
@@ -5504,6 +5525,7 @@ class ContinuousBatcher:
                     swap is None
                     and not pressure_hold
                     and busy + len(wave) < self.slots
+                    and len(wave) < self._admit_cap
                 ):
                     # preempted requests resume AHEAD of newer work —
                     # their recompute is a price already paid once
@@ -5638,7 +5660,8 @@ class ContinuousBatcher:
                             if self.speculate_tokens == 0:
                                 if len(reqs) >= 8 and self._chunk8_ok(bucket):
                                     m = 8
-                                elif len(reqs) >= 4:
+                                elif len(reqs) >= 4 and self._rows_ok(
+                                        4, bucket):
                                     m = 4
                             chunk, reqs = reqs[:m], reqs[m:]
                             slots_ = [next(free_iter) for _ in chunk]

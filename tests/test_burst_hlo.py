@@ -470,3 +470,66 @@ def test_flash_kernel_compiled_for_v5e_takes_keys_of_192_and_values_of_128(one_c
                  if "custom-call(" in line and "tpu_custom_call" in line)
         assert "latent_prefill_attention" in call
         assert f"bf16[32,{t},128]" in call
+
+
+def test_evabyte_burst_compiled_for_v5e_is_one_kernel_a_layer_over_a_ring_in_place(one_chip):
+    """The configuration's own burst (20 lanes, all 8 layers, no bucket):
+    every layer decodes through the ragged kernel over its ring and its
+    summaries (four arrays a layer, two lengths), inside the ``while``; all
+    32 leaves are aliased through; nothing of the ring's shape is copied,
+    sliced, scattered into or carried in another layout (a gather of a
+    chunk's rows from the ring made the compiler relay every layer's ring,
+    a ring-sized copy a layer and step: the kernel hands the chunk out),
+    and the one op that writes a summary array is the scatter of the rows
+    the step pooled."""
+    import re
+
+    tool = _tool()
+    with open(os.path.join(ROOT, "benchmark", "configs", "evabyte.json")) as f:
+        cfg = json.load(f)
+    cfg["name"] = "evabyte"
+    lanes = cfg["server"]["slots"]
+    compiled, (n, _kv, T, _dh), cache_bytes, leaves = tool.compile_burst(
+        cfg, None, one_chip)
+    assert (n, T, leaves) == (lanes, 16384, 32)
+    assert cache_bytes == lanes * 8 * (2048 + 1024) * 16384
+    hlo = compiled.as_text()
+    assert tool.kernel_calls(hlo) == {"inside": 8, "outside": 0}
+    names = re.findall(r"%([a-z_]+)[.\d]* = [^\n]*? custom-call\(", hlo)
+    assert names.count("eva_decode_attention") == 8
+    ring = re.compile(rf" = bf16\[{lanes},32,2048,128\][^ ]* (copy|copy-start|"
+                      r"slice|slice-start|scatter|dynamic-update-slice|gather)\(")
+    assert not [line for line in hlo.splitlines() if ring.search(line)]
+    # one layout for the ring everywhere: positions second-minor
+    assert not re.search(rf"bf16\[{lanes},32,2048,128\]\{{3,1,0,2", hlo)
+    summ = re.findall(rf" = bf16\[{lanes},32,1024,128\][^ ]* ([a-z\-]+)\(", hlo)
+    assert set(summ) <= {"parameter", "get-tuple-element", "scatter", "fusion",
+                         "bitcast"}
+    assert summ.count("scatter") == 2 * 8
+    assert tool.alias_count(hlo) >= leaves
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= cache_bytes
+    # the weights' relayout once a burst (24 x 33.5 MB: S12) and no more
+    assert mem.temp_size_in_bytes < 1 << 30
+
+
+def test_flash_kernel_compiled_for_v5e_behind_a_visible_prefix(one_chip):
+    """The evabyte family's prefill attention: a window of 2048 queries
+    behind the 896 summary rows of the earlier windows, the visible count
+    prefetched: one Mosaic call under the family's kernel name."""
+    import jax
+    import jax.numpy as jnp
+
+    from seldon_core_tpu.ops.flash_attention import flash_attention
+
+    def sds(shape, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    fn = jax.jit(lambda q, k, v, n: flash_attention(
+        q, k, v, causal=True, prefix=896, prefix_len=n,
+        name="eva_prefill_attention"))
+    compiled = fn.lower(sds((1, 32, 2048, 128)), sds((1, 32, 2944, 128)),
+                        sds((1, 32, 2944, 128)), sds((), jnp.int32)).compile()
+    call, = (line for line in compiled.as_text().splitlines()
+             if "custom-call(" in line and "tpu_custom_call" in line)
+    assert "eva_prefill_attention" in call and "bf16[32,2048,128]" in call
