@@ -33,7 +33,8 @@ the RING ``window_k`` / ``window_v`` [S, H, W, Dh] (row ``t mod W``: a new
 window starts over at row 0 and the old rows are residue) and the SUMMARIES
 ``summary_k`` / ``summary_v`` [S, H, max_seq / C, Dh] (row ``t // C``,
 written by the step that completes the chunk, ``(t + 1) mod C == 0``, from
-the ring's last C rows; visible from the next window on). A position costs
+the ring's last C rows, inside the decode kernel's call; visible from the
+next window on). A position costs
 ``2 H Dh`` values a layer while it is in its window and a C-th of that
 after. What the scheduler must know of such a cache it asks:
 ``park_index``, ``lane_cache_bytes``, ``prefill_lengths``,
@@ -43,8 +44,9 @@ after. What the scheduler must know of such a cache it asks:
 earlier windows' summaries a visible prefix before its causal part; scope
 ``eva_prefill_attention``), ``ops/eva_attention.py`` (a decode step: a
 ragged kernel over both kinds on a TPU, the same arithmetic in
-``jax.numpy`` elsewhere; scope ``eva_decode_attention``) and
-``chunk_summary`` (scope ``eva_chunk_summary``). A prompt longer than a
+``jax.numpy`` elsewhere; scope ``eva_decode_attention``; it pools the chunk
+a step completes and writes its summary row) and ``chunk_summary`` (a
+prompt's chunks; scope ``eva_chunk_summary``). A prompt longer than a
 window is prefilled by ONE executable that walks the prompt's own
 ``ceil(len / W)`` windows, window-major (a window through all layers, then
 the next): window ``w`` needs of each layer its own W rows and the
@@ -259,6 +261,28 @@ class EvaByteLM(DecoderLM):
         # kernel or dots, the read is bounded by each lane's own rows of
         # both kinds and never by a bucket: one burst executable per k
         return True
+
+    def step_counters_in_kernel(self, cache, mesh=None):
+        """``{stats key: step counter}``: the writes a step counts that the
+        decode kernel lands itself where the step over ``cache`` is lowered
+        for the platform its arrays live on (``eva_reads_ragged``: a
+        completed chunk is pooled and its summary row written inside
+        ``eva_decode_attention``); the counter is None where the scatters
+        write them, and the key then stays 0."""
+        import jax.numpy as jnp
+
+        from ..ops.eva_attention import eva_reads_ragged
+
+        ring, summ = cache["window_k"][0], cache["summary_k"][0]
+        lowered = eva_reads_ragged(
+            next(iter(ring.devices())).platform,
+            (ring.shape[0], self.cfg.n_heads, ring.shape[3]), ring.shape,
+            summ.shape,
+            (jnp.dtype(self.cfg.dtype), ring.dtype, cache["window_v"][0].dtype,
+             summ.dtype, cache["summary_v"][0].dtype),
+            self.cfg.chunk_size, mesh)
+        return {"eva_summaries_written_in_kernel":
+                "eva_summaries_written" if lowered else None}
 
     # -- params ----------------------------------------------------------------
 
@@ -605,7 +629,6 @@ class EvaByteLM(DecoderLM):
         """``decode_step_cache`` with every head's logits [B, P, V]."""
         import jax.numpy as jnp
 
-        from ..ops.decode_attention import cache_write
         from ..ops.eva_attention import EVA_BLOCK, eva_decode_attention
 
         cfg = self.cfg
@@ -621,7 +644,8 @@ class EvaByteLM(DecoderLM):
         n_sum = jnp.where(live, t // W * self._per_window, 0)
         ring_at = jnp.where(writes, at, W)           # W: dropped
         ends_chunk = writes & ((t + 1) % C == 0)
-        sum_at = jnp.where(ends_chunk, t // C, n_sum_rows)[:, None]
+        # the step that completes a chunk pools it into its summary row
+        sum_at = jnp.where(ends_chunk, t // C, n_sum_rows)
         mesh = getattr(self, "_serving_mesh", None)
 
         x = self._embed(params, tokens)  # [B, 1, D]
@@ -629,19 +653,13 @@ class EvaByteLM(DecoderLM):
         for l, p in enumerate(params["layers"]):
             a = self._norm(x, p["ln_in"])
             q, k, v = self._heads(p, a, t[:, None])
-            o, ring_k, ring_v, chunk_k, chunk_v = eva_decode_attention(
+            o, *kinds = eva_decode_attention(
                 q[:, :, 0], cache["window_k"][l], cache["window_v"][l],
                 cache["summary_k"][l], cache["summary_v"][l], k[:, :, 0],
-                v[:, :, 0], ring_at, n_ring, n_sum, scale=self._scale,
-                chunk=C, mesh=mesh)
-            # the step that completes a chunk pools it from the ring's rows
-            pooled_k, pooled_v = self._summaries(p, chunk_k, chunk_v)
-            new["window_k"].append(ring_k)
-            new["window_v"].append(ring_v)
-            new["summary_k"].append(
-                cache_write(cache["summary_k"][l], pooled_k, sum_at))
-            new["summary_v"].append(
-                cache_write(cache["summary_v"][l], pooled_v, sum_at))
+                v[:, :, 0], ring_at, n_ring, n_sum, p["mu_k"], p["phi"],
+                sum_at, scale=self._scale, chunk=C, mesh=mesh)
+            for name, kind in zip(self._KINDS, kinds):
+                new[name].append(kind)
             x = self._mix(p, x, o[:, :, None])
 
         def read(n):

@@ -1,4 +1,4 @@
-"""Single-position decode attention over an EVA cache, the step's write
+"""Single-position decode attention over an EVA cache, the step's writes
 included: a lane's WINDOW RING and its SUMMARY ROWS under one softmax.
 
 EVA attention reads a position's own aligned window exactly and every
@@ -25,18 +25,32 @@ inside it over the lane's ``ceil(ring rows / block)`` ring blocks and then
 its ``ceil(summary rows / block)`` summary blocks, each ``[H, block, Dh]``
 of K and of V, double-buffered across kinds and lane boundaries; the ring
 block that holds the step's row takes it before it is read and the row's
-CHUNK (its aligned group of ``chunk`` positions) goes back to the ring,
-which is aliased in and out of the call, and out of the call beside it: the
-step that completes a chunk pools it from there (``chunk_summary`` and a
-scatter, in the model's step; a gather of the chunk from the ring would
+CHUNK (its aligned group of ``chunk`` positions) goes back to the ring.
+
+**The step that completes a chunk pools it here too.** The kernel has the
+patched chunk in VMEM when it lands the row, so for a lane whose
+``sum_at`` is a row of the summaries it computes ``chunk_summary`` of that
+chunk there (the layer's ``mu_k`` and ``phi`` are two more VMEM operands;
+logits, softmax and sums float32, the rows cast to the cache's dtype) and
+lands the two pooled rows itself. One row of a bfloat16 array is half of
+its packed words, which no copy moves alone: the row's aligned group of
+``GROUP`` summary rows is fetched beside the lane's walk, takes the row and
+goes back (``[H, GROUP, Dh]`` each way a kind: the ring's way for a row no
+block held). No hazard with the walk: a lane reads summary rows ``[0,
+(W / C) floor(t / W))``, whole blocks of earlier windows, and writes row
+``t // C`` of the current window's block. All four arrays are aliased in
+and out of the call, so under the burst's donation nothing of them moves
+but the groups written. (Outside the kernel the write is a scatter of every
+lane's row, fifteen in sixteen of them dropped, a tenth of a step on a v5e:
+PERF.md section 6, PR 45; and a gather of the chunk from the ring would
 have the compiler carry the ring through the burst in another layout, a
-ring-sized copy a layer and step). The summaries are only read here. A lane that reads nothing copies nothing,
-gives zeros and writes nothing.
+ring-sized copy a layer and step.) A lane that reads nothing copies
+nothing, gives zeros and writes nothing.
 
 ``eva_decode_attention()`` is the public entry (the kernel where
 ``eva_reads_ragged`` holds for the platform the executable is lowered for,
-the scatter and four dots elsewhere: the same arithmetic in ``jax.numpy``),
-under ``jax.named_scope("eva_decode_attention")``.
+the scatters, four dots and ``chunk_summary`` elsewhere: the same
+arithmetic in ``jax.numpy``), under ``jax.named_scope("eva_decode_attention")``.
 """
 
 from __future__ import annotations
@@ -125,26 +139,31 @@ def eva_cache_attention(q, ring_k, ring_v, sum_k, sum_v, n_ring, n_sum,
     return o.astype(q.dtype)
 
 
-def _eva_kernel(nr_ref, ns_ref, wpos_ref, q_ref, knew_ref, vnew_ref, _k_in,
-                _v_in, sk_hbm, sv_hbm, o_ref, k_hbm, v_hbm, kstage, vstage,
-                kbuf, vbuf, sem, wsem, rsem, *, block, scale):
+def _eva_kernel(nr_ref, ns_ref, wpos_ref, sat_ref, q_ref, knew_ref, vnew_ref,
+                mu_ref, phi_ref, _k_in, _v_in, _sk_in, _sv_in, o_ref, k_hbm,
+                v_hbm, sk_hbm, sv_hbm, kstage, vstage, sstage, kbuf, vbuf, sem,
+                wsem, rsem, ssem, swsem, *, block, scale):
     """The whole batch of one layer (``ops.decode_attention._ragged_kernel``
-    with a second pair of arrays the walk goes on into).
+    with a second pair of arrays the walk goes on into, and a second write).
 
-    nr_ref, ns_ref, wpos_ref: SMEM [B]: ring rows and summary rows a lane
-    reads, and the ring row its new K and V go to (outside [0, W): none);
-    q_ref / o_ref: VMEM [B, H, 1, Dh]; knew_ref / vnew_ref: VMEM [B, H, 1,
-    Dh]; k_hbm / v_hbm: the layer's ring [B, H, W, Dh], left where it is
-    (the call's aliased outputs; ``_k_in`` / ``_v_in`` are the same
-    buffers); sk_hbm / sv_hbm: its summaries [B, H, Ns, Dh], read only;
-    kstage / vstage: VMEM [B, H, chunk, Dh], outputs: a writing lane's
-    patched chunk, on its way to the ring and out of the call (a lane that
-    writes nothing leaves its rows as they were allocated); kbuf / vbuf:
-    VMEM [2, H, block, Dh]; sem [2 (k, v), 2] the reads', wsem [2] the
-    writes', rsem [2] the fetch of a chunk that no block held.
+    nr_ref, ns_ref, wpos_ref, sat_ref: SMEM [B]: ring rows and summary rows
+    a lane reads, the ring row its new K and V go to (outside [0, W): none)
+    and the summary row its completed chunk's pooled rows go to (outside
+    [0, Ns): none); q_ref / o_ref: VMEM [B, H, 1, Dh]; knew_ref / vnew_ref:
+    VMEM [B, H, 1, Dh]; mu_ref / phi_ref: VMEM [H, Dh], the layer's pooling
+    vectors; k_hbm / v_hbm: the layer's ring [B, H, W, Dh] and sk_hbm /
+    sv_hbm: its summaries [B, H, Ns, Dh], all four left where they are (the
+    call's aliased outputs; ``_k_in`` ... ``_sv_in`` are the same buffers); kstage / vstage: VMEM [B, H, chunk, Dh]: a writing
+    lane's patched chunk, on its way to the ring and, where it completes,
+    to the pooling; sstage: VMEM [2 (k, v), B, H, GROUP, Dh]: the aligned
+    group of summary rows the pooled row joins; kbuf / vbuf: VMEM [2, H,
+    block, Dh]; sem [2 (k, v), 2] the reads', wsem [2] the ring writes',
+    rsem [2] the fetch of a chunk that no block held, ssem [2] the fetch of
+    a summary group, swsem [2] the summary writes'.
     """
     n_lanes, n_heads, _one, dh = q_ref.shape
     w = k_hbm.shape[2]
+    n_sum_rows = sk_hbm.shape[2]
     chunk = kstage.shape[2]     # the write's unit is the model's chunk
 
     def blocks(n):
@@ -217,6 +236,37 @@ def _eva_kernel(nr_ref, ns_ref, wpos_ref, q_ref, knew_ref, vnew_ref, _k_in,
         stage[lane] = patched(which, lane, wp, stage[lane])
         write_back(which, lane, wp).start()
 
+    def summary_copy(which, lane, at, back):
+        """The fetch (``back``: the write-back) of the group of ``GROUP``
+        summary rows that holds row ``at``: a row alone is half of its
+        packed words (the ring's way, ``land_unread``)."""
+        group = pl.multiple_of(at // GROUP * GROUP, GROUP)
+        rows = (sk_hbm, sv_hbm)[which].at[lane, :, pl.ds(group, GROUP), :]
+        stage = sstage.at[which, lane]
+        if back:
+            return pltpu.make_async_copy(stage, rows, swsem.at[which])
+        return pltpu.make_async_copy(rows, stage, ssem.at[which])
+
+    def pool(lane, at):
+        """The lane's step completed its chunk: ``chunk_summary`` of the
+        patched chunk in the stages (float32 logits, softmax and sums, the
+        rows cast to the cache's dtype) into row ``at`` of both summary
+        groups, fetched since the lane's walk began, and back they go."""
+        k32 = kstage[lane].astype(jnp.float32)   # [H, chunk, Dh]
+        for which, (by_ref, rows) in enumerate((
+                (mu_ref, k32), (phi_ref, vstage[lane].astype(jnp.float32)))):
+            by = by_ref[...].astype(jnp.float32)[:, None, :]
+            logits = (k32 * by).sum(axis=-1, keepdims=True) * scale
+            p = jnp.exp(logits - logits.max(axis=1, keepdims=True))
+            p = p / p.sum(axis=1, keepdims=True)
+            pooled = (p * rows).sum(axis=1, keepdims=True)   # [H, 1, Dh]
+            summary_copy(which, lane, at, False).wait()
+            group = sstage[which, lane]
+            row = lax.broadcasted_iota(jnp.int32, group.shape, 1)
+            sstage[which, lane] = jnp.where(
+                row == at % GROUP, pooled.astype(group.dtype), group)
+            summary_copy(which, lane, at, True).start()
+
     def next_live(lane):
         """The first lane after ``lane`` that reads anything, or B (a lane
         with no ring row reads nothing: its summaries are never alone)."""
@@ -232,7 +282,7 @@ def _eva_kernel(nr_ref, ns_ref, wpos_ref, q_ref, knew_ref, vnew_ref, _k_in,
         start(first, 0, 0)
 
     def lane_body(lane, carry):
-        done_blocks, written = carry
+        done_blocks, written, n_pooled = carry
         nr = nr_ref[lane]
         ns = jnp.where(nr > 0, ns_ref[lane], 0)
         nb_r = blocks(nr)
@@ -240,7 +290,16 @@ def _eva_kernel(nr_ref, ns_ref, wpos_ref, q_ref, knew_ref, vnew_ref, _k_in,
         wp = wpos_ref[lane]
         writes = (nr > 0) & (wp >= 0) & (wp < w)
         w_block = jnp.where(writes & (wp // block < nb_r), wp // block, -1)
+        at = sat_ref[lane]
+        pools = writes & (at >= 0) & (at < n_sum_rows)
         q = q_ref[lane]  # [H, 1, Dh]
+
+        # the summary groups come beside the walk (the row written lies in
+        # the current window's block, which no lane's walk streams)
+        @pl.when(pools)
+        def _():
+            for which in range(2):
+                summary_copy(which, lane, at, False).start()
 
         def block_body(i, carry):
             o, m, l = carry
@@ -307,43 +366,57 @@ def _eva_kernel(nr_ref, ns_ref, wpos_ref, q_ref, knew_ref, vnew_ref, _k_in,
             land_unread(0, lane, wp)
             land_unread(1, lane, wp)
 
-        return done_blocks + n_blocks, written + writes.astype(jnp.int32)
+        @pl.when(pools)
+        def _():
+            pool(lane, at)
 
-    _, written = lax.fori_loop(
-        0, n_lanes, lane_body, (jnp.int32(0), jnp.int32(0)))
+        return (done_blocks + n_blocks, written + writes.astype(jnp.int32),
+                n_pooled + pools.astype(jnp.int32))
+
+    _, written, n_pooled = lax.fori_loop(
+        0, n_lanes, lane_body, (jnp.int32(0), jnp.int32(0), jnp.int32(0)))
 
     # the next layer-step of this cache is a later call: every write has
     # landed when this one returns (each wait takes one group's bytes)
-    def drain(_, carry):
-        write_back(0, 0, 0).wait()
-        write_back(1, 0, 0).wait()
-        return carry
+    def drain(n, copies):
+        def body(_, carry):
+            for copy in copies:
+                copy.wait()
+            return carry
 
-    lax.fori_loop(0, written, drain, 0)
+        lax.fori_loop(0, n, body, 0)
+
+    drain(written, [write_back(which, 0, 0) for which in range(2)])
+    drain(n_pooled, [summary_copy(which, 0, 0, True) for which in range(2)])
 
 
 @functools.partial(jax.jit,
                    static_argnames=("scale", "chunk", "block", "interpret"))
 def ragged_eva_attention(q, ring_k, ring_v, sum_k, sum_v, n_ring, n_sum,
-                         k_new, v_new, write_pos, *, scale: float, chunk: int,
-                         block: int = EVA_BLOCK, interpret: bool = False):
+                         k_new, v_new, write_pos, mu, phi, sum_at, *,
+                         scale: float, chunk: int, block: int = EVA_BLOCK,
+                         interpret: bool = False):
     """The Pallas kernel. q [B, H, Dh]; ring_k, ring_v [B, H, W, Dh] and
     sum_k, sum_v [B, H, Ns, Dh] one layer's cache, unsliced (``W`` and
     ``Ns`` multiples of ``block``); n_ring, n_sum [B] int32 the rows lane b
     reads of each kind (clamped to the arrays); k_new, v_new [B, H, Dh]
-    this step's rows and write_pos [B] the ring row they go to. Returns
-    ``(o [B, H, Dh], ring_k, ring_v, chunk_k, chunk_v)``: the ring is
-    aliased in and out, so under a caller that donates it nothing but the
-    rows' chunks moves; ``chunk_k``, ``chunk_v`` [B, H, chunk, Dh] are the
-    ring's rows of the chunk that holds ``write_pos``, the new row among
-    them, for a lane that writes (anything at all for one that does not).
+    this step's rows and write_pos [B] the ring row they go to; mu, phi [H,
+    Dh] the layer's pooling vectors and sum_at [B] the summary row a lane
+    whose step completes its chunk writes (outside [0, Ns): none). Returns
+    ``(o [B, H, Dh], ring_k, ring_v, sum_k, sum_v)``: all four arrays are
+    aliased in and out, so under a caller that donates them nothing moves
+    but the ring rows' chunks and the summary rows' groups of ``GROUP``.
 
     Lane b first takes its new row at ``write_pos[b]``, then attends to
     ring rows [0, n_ring[b]) and summary rows [0, n_sum[b]) under one
     softmax: the read of the ring ``cache_write()`` would have made, bit
-    for bit. A ``write_pos`` outside [0, W) is dropped, and a lane with
+    for bit; then, with ``sum_at[b]`` in range, ``chunk_summary`` of the
+    aligned chunk of the ring that holds ``write_pos[b]``, the new row in
+    it, goes to row ``sum_at[b]`` of both summary arrays. A ``write_pos``
+    outside [0, W) is dropped and its lane pools nothing, and a lane with
     ``n_ring[b] == 0`` reads nothing of either kind, gives zeros and WRITES
-    NOTHING (``ops.decode_attention.ragged_decode_attention``'s contract)."""
+    NOTHING in either (``ops.decode_attention.ragged_decode_attention``'s
+    contract)."""
     b, h, dh = q.shape
     w, ns = ring_k.shape[2], sum_k.shape[2]
     if (ring_k.shape[:2] != (b, h) or sum_k.shape[:2] != (b, h)
@@ -357,59 +430,75 @@ def ragged_eva_attention(q, ring_k, ring_v, sum_k, sum_v, n_ring, n_sum,
     smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
     hbm = pl.BlockSpec(memory_space=pl.ANY)
-    out, ring_k, ring_v, chunk_k, chunk_v = pl.pallas_call(
+
+    def int32(a):
+        return lax.convert_element_type(a, jnp.int32)
+
+    def rows(a, dtype):
+        """[..., H, Dh] -> [..., H, 1, Dh]: a row a head, as the kernel
+        broadcasts it over a group's rows."""
+        return lax.expand_dims(lax.convert_element_type(a, dtype), (a.ndim - 1,))
+
+    out, ring_k, ring_v, sum_k, sum_v = pl.pallas_call(
         functools.partial(_eva_kernel, block=block, scale=float(scale)),
         out_shape=(
             jax.ShapeDtypeStruct((b, h, 1, dh), q.dtype),
             jax.ShapeDtypeStruct(ring_k.shape, ring_k.dtype),
             jax.ShapeDtypeStruct(ring_v.shape, ring_v.dtype),
-            jax.ShapeDtypeStruct((b, h, chunk, dh), ring_k.dtype),
-            jax.ShapeDtypeStruct((b, h, chunk, dh), ring_v.dtype),
+            jax.ShapeDtypeStruct(sum_k.shape, sum_k.dtype),
+            jax.ShapeDtypeStruct(sum_v.shape, sum_v.dtype),
         ),
-        in_specs=[smem, smem, smem, vmem, vmem, vmem, hbm, hbm, hbm, hbm],
-        out_specs=(vmem, hbm, hbm, vmem, vmem),
-        input_output_aliases={6: 1, 7: 2},
+        in_specs=[smem] * 4 + [vmem] * 5 + [hbm] * 4,
+        out_specs=(vmem, hbm, hbm, hbm, hbm),
+        input_output_aliases={9: 1, 10: 2, 11: 3, 12: 4},
         scratch_shapes=[
+            pltpu.VMEM((b, h, chunk, dh), ring_k.dtype),
+            pltpu.VMEM((b, h, chunk, dh), ring_v.dtype),
+            pltpu.VMEM((2, b, h, GROUP, dh), sum_k.dtype),
             pltpu.VMEM((2, h, block, dh), ring_k.dtype),
             pltpu.VMEM((2, h, block, dh), ring_v.dtype),
             pltpu.SemaphoreType.DMA((2, 2)),
             pltpu.SemaphoreType.DMA((2,)),
             pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SemaphoreType.DMA((2,)),
         ],
         interpret=interpret,
         name="eva_decode_attention",
-    )(jnp.clip(n_ring.astype(jnp.int32), 0, w),
-      jnp.clip(n_sum.astype(jnp.int32), 0, ns),
-      write_pos.astype(jnp.int32), q[:, :, None, :],
-      k_new.astype(ring_k.dtype)[:, :, None, :],
-      v_new.astype(ring_v.dtype)[:, :, None, :],
-      ring_k, ring_v, sum_k, sum_v)
-    return out[:, :, 0], ring_k, ring_v, chunk_k, chunk_v
+    )(lax.clamp(jnp.int32(0), int32(n_ring), jnp.int32(w)),
+      lax.clamp(jnp.int32(0), int32(n_sum), jnp.int32(ns)),
+      int32(write_pos), int32(sum_at), rows(q, q.dtype),
+      rows(k_new, ring_k.dtype), rows(v_new, ring_v.dtype),
+      mu, phi, ring_k, ring_v, sum_k, sum_v)
+    return lax.squeeze(out, (2,)), ring_k, ring_v, sum_k, sum_v
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "chunk", "mesh"))
 def eva_decode_attention(q, ring_k, ring_v, sum_k, sum_v, k_new, v_new,
-                         write_pos, n_ring, n_sum, *, scale: float,
-                         chunk: int, mesh=None):
-    """The decode step's write and read of one EVA layer's cache: this
+                         write_pos, n_ring, n_sum, mu, phi, sum_at, *,
+                         scale: float, chunk: int, mesh=None):
+    """The decode step's writes and read of one EVA layer's cache: this
     step's rows k_new, v_new [B, H, Dh] go into the UNSLICED ring [B, H, W,
     Dh] at ``write_pos`` [B] (outside [0, W): dropped), then q [B, H, Dh]
     attends to ring rows [0, n_ring[b]) and summary rows [0, n_sum[b])
-    under one softmax. Returns ``(o [B, H, Dh], ring_k, ring_v, chunk_k,
-    chunk_v)``, the last two [B, H, chunk, Dh]: the ring's rows of the
-    aligned chunk that holds ``write_pos``, after the write (of a lane
-    that writes; nobody reads another lane's).
+    under one softmax, and a lane whose step completes a chunk pools it
+    (``chunk_summary`` of the ring's aligned ``chunk`` rows that hold
+    ``write_pos``, after the write, under the layer's ``mu`` and ``phi``
+    [H, Dh]) into row ``sum_at`` [B] of the UNSLICED summaries [B, H, Ns,
+    Dh] (outside [0, Ns): none). Returns ``(o [B, H, Dh], ring_k, ring_v,
+    sum_k, sum_v)``.
 
-    The kernel skips a lane of ``n_ring == 0`` (zeros out, no write); the
-    scatter and the dots write every lane's row that ``write_pos`` admits
-    and read both arrays whole under the masks, with zeros out for such a
-    lane too. Where ``n_ring > 0`` the two agree to rounding, the ring bit
-    for bit (tests/test_eva_attention.py).
+    The kernel skips a lane of ``n_ring == 0`` (zeros out, no write of
+    either kind) and pools nothing for a lane whose ``write_pos`` is
+    dropped; the scatters and the dots write every lane's rows that
+    ``write_pos`` and ``sum_at`` admit and read both arrays whole under the
+    masks, with zeros out for such a lane too. Where ``n_ring > 0`` the two
+    agree to rounding, the ring bit for bit (tests/test_eva_attention.py).
 
     Jitted, so the burst's unrolled layers lower it once and call it."""
 
     def dots(q, ring_k, ring_v, sum_k, sum_v, k_new, v_new, write_pos,
-             n_ring, n_sum):
+             n_ring, n_sum, mu, phi, sum_at):
         ring_k = cache_write(ring_k, k_new[:, :, None], write_pos[:, None])
         ring_v = cache_write(ring_v, v_new[:, :, None], write_pos[:, None])
         o = eva_cache_attention(q, ring_k, ring_v, sum_k, sum_v, n_ring,
@@ -418,18 +507,24 @@ def eva_decode_attention(q, ring_k, ring_v, sum_k, sum_v, k_new, v_new,
 
         def rows(ring):
             return jax.vmap(lambda a, s: lax.dynamic_slice_in_dim(
-                a, s, chunk, axis=1))(ring, at)
+                a, s, chunk, axis=1))(ring, at)[:, :, None]
 
-        return o, ring_k, ring_v, rows(ring_k), rows(ring_v)
+        # pooled for every lane; ``sum_at`` drops all but the completed
+        pooled_k, pooled_v = chunk_summary(
+            rows(ring_k), rows(ring_v), mu, phi, scale)
+        return (o, ring_k, ring_v,
+                cache_write(sum_k, pooled_k, sum_at[:, None]),
+                cache_write(sum_v, pooled_v, sum_at[:, None]))
 
     def kernel(q, ring_k, ring_v, sum_k, sum_v, k_new, v_new, write_pos,
-               n_ring, n_sum):
+               n_ring, n_sum, mu, phi, sum_at):
         return ragged_eva_attention(
             q, ring_k, ring_v, sum_k, sum_v, n_ring, n_sum, k_new, v_new,
-            write_pos, scale=scale, chunk=chunk, block=EVA_BLOCK)
+            write_pos, mu, phi, sum_at, scale=scale, chunk=chunk,
+            block=EVA_BLOCK)
 
     args = (q, ring_k, ring_v, sum_k, sum_v, k_new, v_new, write_pos,
-            n_ring, n_sum)
+            n_ring, n_sum, mu, phi, sum_at)
     with jax.named_scope("eva_decode_attention"):
         # the platform is known only when this is lowered: ask whether a
         # lowering for a TPU takes the kernel, and let that lowering choose

@@ -1004,6 +1004,15 @@ class ContinuousBatcher:
             getattr(model, "prefill_counter_names", ()))
         for name in self._step_counters + self._prefill_counters:
             self.stats.setdefault(name, 0)
+        # the step counters whose writes the decode kernel lands itself
+        # (model.step_counters_in_kernel, as kv_rows_written_in_kernel
+        # shadows kv_rows_written): _read_burst adds each into a stats key
+        # of its own. A family that names none gets no key.
+        in_kernel = getattr(model, "step_counters_in_kernel", None)
+        self._counters_in_kernel = (
+            in_kernel(self._cache, mesh) if in_kernel else {})
+        for name in self._counters_in_kernel:
+            self.stats.setdefault(name, 0)
         run_prefill = (model.prefill_counted if self._prefill_counters
                        else model.prefill)
 
@@ -4250,8 +4259,12 @@ class ContinuousBatcher:
             })
         if self._step_counters and mode != "spec":
             # the model's own counters of the burst's steps: its last array
-            for name, n in zip(self._step_counters, host.pop()):
-                self.stats[name] += int(n)
+            counts = dict(zip(self._step_counters, map(int, host.pop())))
+            for name, n in counts.items():
+                self.stats[name] += n
+            for name, counter in self._counters_in_kernel.items():
+                if counter is not None:
+                    self.stats[name] += counts[counter]
         if self._prefill_counters and mode != "spec":
             # and, before it, those of the prefills dispatched since the
             # burst before (_take_prefill_counts)

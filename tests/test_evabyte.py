@@ -2,8 +2,10 @@
 family's prefill in all its forms and its decode step, through the
 batcher's own ``insert_many`` and burst, against the plain reference
 (``benchmark/reference/evabyte.py``) in float32; the chunk-edge and
-window-edge crossings; what the family tells the scheduler of its cache,
-with the other families' answers unchanged; every typed refusal.
+window-edge crossings, and the cache the steps leave against one prefill
+of the same bytes; what the family tells the scheduler of its cache, with
+the other families' answers unchanged; the summaries the kernel writes,
+counted by the batcher; every typed refusal.
 
     JAX_PLATFORMS=cpu python -m pytest tests/test_evabyte.py -q
 """
@@ -216,6 +218,52 @@ def test_insert_many_and_the_burst_follow_the_reference_over_the_edges(
     # the idle lane (2) was written in neither kind
     for kind in burst_cache:
         assert not np.asarray(burst_cache[kind][0][2]).any()
+
+
+# from inside a chunk and a window (5, 17: 40 steps cross ten chunk edges
+# and one window's, two for the second), and from a chunk's and a window's
+# own last position (31, 63: the first step lands the summary the second
+# one reads; 63 + 40 crosses into the fourth window)
+@pytest.mark.parametrize("starts", [{0: 5, 2: 17}, {1: 31, 4: 63}],
+                         ids=["from_inside_a_chunk", "from_a_windows_edge"])
+def test_decode_steps_leave_the_cache_one_prefill_of_the_same_bytes_would(
+        model, params, tokens, batcher, starts):
+    """Forty steps fed the prompt's own bytes, every summary row pooled
+    and landed by the step's own ``eva_decode_attention`` call, against ONE
+    prefill of the bytes the lane then holds: the ring's rows of the last
+    window and every whole chunk's summary, both kinds of each."""
+    cache, _regs, _ = _admit(batcher, params, tokens, starts)
+    batcher._cache = cache
+    live = np.array([lane in starts for lane in range(6)])
+    at = np.array([starts.get(lane, 0) for lane in range(6)])
+    step = jax.jit(model._step)
+    k = 40
+    for i in range(k):
+        t = np.where(live, at + i, 0)
+        _logits, cache, counts = step(
+            params, cache, jnp.asarray(tokens[t][:, None], jnp.int32),
+            jnp.asarray(t, jnp.int32), None, None,
+            jnp.asarray(np.where(live, t + 1, 0), jnp.int32))
+        assert int(counts[4]) == 2 * int(((t[live] + 1) % C == 0).sum())
+    for lane, n0 in starts.items():
+        n = n0 + k
+        _bucket, (_l, slab) = _prefill(model, params, tokens, n)
+        w0 = (n - 1) // W * W
+        for l in range(2):
+            for kind in ("window_k", "window_v"):
+                np.testing.assert_allclose(
+                    cache[kind][l][lane, :, :n - w0],
+                    slab[kind][l, 0, :, :n - w0], atol=TOL)
+            for kind in ("summary_k", "summary_v"):
+                np.testing.assert_allclose(
+                    cache[kind][l][lane, :, :n // C],
+                    slab[kind][l, 0, :, :n // C], atol=TOL)
+                # and no row past the lane's last whole chunk was written
+                assert not np.asarray(cache[kind][l][lane, :, n // C:]).any()
+    # the idle lanes were written in neither kind
+    for kind in cache:
+        for lane in np.flatnonzero(~live):
+            assert not np.asarray(cache[kind][0][lane]).any()
 
 
 def test_a_lane_admitted_beside_live_lanes_leaves_them_bit_equal(
@@ -448,6 +496,52 @@ def test_the_paths_it_has_not_are_refused_typed(model, params):
         model.param_sharding(None, params)
     with pytest.raises(UnsupportedByModel):
         ContinuousBatcher(model, params, slots=2, max_seq=128, prefill_chunk=32)
+
+
+IN_KERNEL = "eva_summaries_written_in_kernel"
+
+
+@pytest.mark.parametrize("lowered", [False, True],
+                         ids=["off_a_tpu", "where_the_kernel_writes"])
+def test_the_batcher_mirrors_the_summaries_the_kernel_writes(
+        model, params, tokens, monkeypatch, lowered):
+    """``stats["eva_summaries_written_in_kernel"]``: 0 where the model's
+    step is lowered to the scatters (here, off a TPU), the step counter
+    ``eva_summaries_written`` itself where the model says the kernel lands
+    the rows (a stub: no TPU here)."""
+    if lowered:
+        monkeypatch.setattr(
+            model, "step_counters_in_kernel",
+            lambda cache, mesh=None: {IN_KERNEL: "eva_summaries_written"})
+    else:
+        cache = model.init_cache(2, 128)
+        assert model.step_counters_in_kernel(cache) == {IN_KERNEL: None}
+    b = ContinuousBatcher(model, params, slots=2, max_seq=128)
+    try:
+        assert b.stats[IN_KERNEL] == 0
+        b.start()
+        list(b.submit(tokens[:30].tolist(), max_new_tokens=11).result(timeout=300))
+        written = b.stats["eva_summaries_written"]
+        # positions 30 .. 39 and on to the burst's end are stepped: chunks
+        # end at 31, 35, 39, each in two layers
+        assert written >= 2 * 3 and written % 2 == 0
+        assert b.stats[IN_KERNEL] == (written if lowered else 0)
+    finally:
+        b.close()
+
+
+def test_a_family_that_names_no_kernel_write_gets_no_key():
+    m = DecoderLM(**FAMILIES["afmoe"])
+    assert not hasattr(m, "step_counters_in_kernel")
+    b = ContinuousBatcher(m, m.init_params(0), slots=2, max_seq=128)
+    try:
+        assert b._counters_in_kernel == {}
+        assert set(m.step_counter_names) <= set(b.stats)
+        assert not [name for name in b.stats
+                    if name.endswith("_in_kernel")
+                    and name != "kv_rows_written_in_kernel"]
+    finally:
+        b.close()
 
 
 def test_it_serves_bytes_through_the_batcher(model, params, tokens, ref):
