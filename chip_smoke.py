@@ -14,9 +14,10 @@ One process per chip. This parent never imports ``jax``. Each leg is a child
 that takes the chip, finishes and releases it before the next starts:
 
 * ``kernel``: names the device, jit-compiles the Pallas flash kernel at the
-  flagship's shapes, compares it with the XLA reference on the same device,
-  and checks that the compiled prefill executable of every >=128 bucket
-  holds the Mosaic call.
+  flagship's shapes (unequal tiles, a lead tile, and once behind a visible
+  prefix), compares it with the XLA reference on the same device, and
+  checks that the compiled prefill executable of every >=128 bucket holds
+  the Mosaic call.
 * ``serve_one_chip``: the engine child on one chip, every request checked.
 * ``serve_four_chips``: with >=4 chips, the same through the
   ``data=1,model=4`` serving mesh in ONE process; greedy tokens must equal
@@ -76,7 +77,11 @@ MAX_NEW = 16
 # 1024 bucket (Pallas kernel); also the warm-up lengths the spec declares
 PROMPT_LENS = (24, 128, 700)
 # flash_attention leg: H and Dh from FLAGSHIP, these sequence lengths
+# (attention()'s tile: 128 x 128 at 128, 256 query rows x 512 keys from 512,
+# behind a lead tile of 256 at 1792), and the last behind a prefix of
+# KERNEL_PREFIX rows of which KERNEL_VISIBLE are seen (it ends mid-tile)
 KERNEL_LENS = (128, 512, 1024, 1792)
+KERNEL_PREFIX, KERNEL_VISIBLE = 896, 300
 # bf16 tolerance: max|kernel - xla| <= KERNEL_TOL * max(1, max|xla|), about
 # two and a half bf16 ulps (2^-7 relative each) at the output's magnitude
 KERNEL_TOL = 2e-2
@@ -164,27 +169,38 @@ def _kernel_checks() -> list:
     import jax
     import jax.numpy as jnp
 
-    from seldon_core_tpu.ops.flash_attention import _xla_attention, attention
+    from seldon_core_tpu.ops.flash_attention import (
+        _prefixed_attention, _xla_attention, attention)
 
     heads = FLAGSHIP["n_heads"]
     head_dim = FLAGSHIP["d_model"] // heads
-    reference = jax.jit(functools.partial(_xla_attention, causal=True))
     rows = []
-    for t in KERNEL_LENS:
+    for t, prefix in [(t, None) for t in KERNEL_LENS] + [
+            (KERNEL_LENS[-1], KERNEL_PREFIX)]:
         q, k, v = (
-            jax.random.normal(key, (1, heads, t, head_dim), jnp.bfloat16)
-            for key in jax.random.split(jax.random.PRNGKey(t), 3)
+            jax.random.normal(key, (1, heads, n, head_dim), jnp.bfloat16)
+            for key, n in zip(jax.random.split(jax.random.PRNGKey(t), 3),
+                              (t, t + (prefix or 0), t + (prefix or 0)))
         )
-        # attention() itself, so the block sizes are the ones serving gets
-        compiled = jax.jit(attention).lower(q, k, v).compile()
+        # attention() itself, so the tile is the one serving gets
+        if prefix is None:
+            kernel = attention
+            reference = functools.partial(_xla_attention, causal=True)
+        else:
+            kernel = functools.partial(
+                attention, prefix=prefix, prefix_len=jnp.int32(KERNEL_VISIBLE))
+            reference = functools.partial(
+                _prefixed_attention, prefix=prefix,
+                prefix_len=jnp.int32(KERNEL_VISIBLE))
+        compiled = jax.jit(kernel).lower(q, k, v).compile()
         mosaic = "tpu_custom_call" in compiled.as_text()
         got = compiled(q, k, v).astype(jnp.float32)
-        ref = reference(q, k, v).astype(jnp.float32)
+        ref = jax.jit(reference)(q, k, v).astype(jnp.float32)
         err = float(jnp.max(jnp.abs(got - ref)))
         bound = KERNEL_TOL * max(1.0, float(jnp.max(jnp.abs(ref))))
         finite = bool(jnp.isfinite(got).all())
         rows.append({
-            "T": t, "mosaic": mosaic, "finite": finite,
+            "T": t, "prefix": prefix, "mosaic": mosaic, "finite": finite,
             "max_abs_err": err, "bound": bound,
             "ok": mosaic and finite and err <= bound,
         })

@@ -6,7 +6,7 @@ flash kernel streams K/V blocks through VMEM with an online-softmax
 accumulator, so scores never leave VMEM and HBM traffic is O(T * Dh).
 No reference counterpart (the reference ships no kernels at all); the
 algorithm is the standard FlashAttention blocking, tiled for the MXU
-(128-row blocks, f32 accumulators, bf16 operands).
+(256 x 512 tiles, f32 accumulators, bf16 operands).
 
 ``attention()`` is the public entry: it dispatches to the Pallas kernel
 on TPU for shapes that tile cleanly and takes the XLA einsum path
@@ -29,6 +29,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
 
 NEG_INF = -1e30
@@ -78,108 +79,119 @@ def _prefixed_attention(q, k, v, prefix: int, prefix_len):
     return jnp.einsum("bhqk,bhkd->bhqd", p, v.astype(jnp.float32)).astype(q.dtype)
 
 
-def _prefixed_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, *, block_q,
-                     block_k, prefix):
-    """``_flash_kernel`` behind a visible prefix (``len_ref``: SMEM [1],
-    scalar-prefetched): the first ``prefix`` keys are seen by every row
-    where they lie below ``len_ref[0]``, the others causally; the walk
-    takes the prefix's ``ceil(len / block_k)`` blocks and then the causal
-    ones, and a prefix block past the visible ones is never read."""
-    _flash_kernel(q_ref, k_ref, v_ref, o_ref, block_q=block_q,
-                  block_k=block_k, causal=True, prefix=prefix,
-                  prefix_len=len_ref[0])
-
-
-def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, block_q, block_k, causal,
-                  window=None, prefix=None, prefix_len=None):
-    """One (bh, q-block) program: stream K/V blocks with online softmax.
+def _flash_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, *, block_q, block_k,
+                  causal, window=None, prefix=None):
+    """One (bh, q-block) program: stream K/V tiles with online softmax.
 
     q_ref: [1, block_q, Dh]; k_ref: [1, Tk, Dh]; v_ref: [1, Tk, Dv] and
     o_ref: [1, block_q, Dv] (whole keys for this bh resident in VMEM —
     serving-sized Tk*Dh fits easily). Dv is Dh but where a family's keys
     are wider than its values (latent attention: 192 and 128).
-    ``window`` (static, with ``causal``): row i sees columns (i - window,
-    i]; the walk starts at the block that holds the q-block's first row's
-    first column and key blocks wholly left of the band are never read.
-    ``prefix`` (static, a multiple of ``block_k``, with ``causal``) and
-    ``prefix_len`` (traced): ``_prefixed_kernel``'s.
+
+    The tile is [block_q, block_k] and the two may differ: an iteration's
+    time is the chain through the matrix unit and back, not its FLOPs, so
+    a key tile of 512 columns (four of the unit's weight tiles side by
+    side) under 256 query rows costs 0.8 us where 128 x 128 costs 0.4 for
+    an eighth of the work (PERF.md section 6, PR 46). The operands go
+    into the two products in the arrays' own dtype (bfloat16 when
+    serving): Mosaic's default precision rounds a 32-bit operand to
+    bfloat16, so a float32 cast buys no bit; scores, running max, running
+    sum and the accumulator are float32.
+
+    Where ``block_k`` does not divide the keys, what is left over is the
+    walk's FIRST tile, ``lead`` columns wide (every causal row sees column
+    0, so every q-block needs it), and the whole tiles follow from there.
+    ``window`` (static, with ``causal``, no lead): row i sees columns
+    (i - window, i]; the walk starts at the tile that holds the q-block's
+    first row's first column and key tiles wholly left of the band are
+    never read. ``prefix`` (static, with ``causal``) and ``len_ref`` (SMEM
+    [1], scalar-prefetched): the first ``prefix`` keys are a prefix of
+    which every row sees those below ``len_ref[0]``; the walk takes the
+    prefix's ``ceil(len / block_k)`` tiles and then the causal ones, and a
+    prefix tile past the visible ones is never read.
     """
     qb = pl.program_id(1)
-    dh = q_ref.shape[-1]
-    scale = 1.0 / np.sqrt(dh)
-    q = q_ref[0].astype(jnp.float32) * scale  # [block_q, Dh]
-    t_k = k_ref.shape[1]
-    row = qb * block_q + lax.broadcasted_iota(jnp.int32, (block_q, 1), 0)
+    scale = 1.0 / np.sqrt(q_ref.shape[-1])
+    # scaled once a q-block, in float32, then rounded to the operands'
+    # dtype (scaling the float32 scores instead costs 3-5% a call)
+    q = (q_ref[0].astype(jnp.float32) * scale).astype(q_ref.dtype)
+    off = prefix or 0
+    t_own = k_ref.shape[1] - off
+    lead = t_own % block_k
+    row0 = qb * block_q
+    row = row0 + lax.broadcasted_iota(jnp.int32, (block_q, 1), 0)
 
-    def body(i, carry, in_prefix=False):
+    def own(col):
+        seen = row >= col
+        if window is not None:
+            seen = seen & (col > row - window)
+        return seen
+
+    def tile(i, carry, base, col0=0, width=block_k, seen_of=None,
+             hides_rows=False):
+        """Keys ``[base + i * block_k, + width)``, whose first column is
+        ``col0 + i * block_k`` to ``seen_of``."""
         o, m, l = carry
-        # the traced start is block-aligned: say so, or Mosaic cannot
+        # the traced start is tile-aligned: say so, or Mosaic cannot
         # prove the sublane slice lands on a tile edge
-        start = pl.multiple_of(i * block_k, block_k)
-        kb = k_ref[0, pl.ds(start, block_k), :].astype(jnp.float32)
-        vb = v_ref[0, pl.ds(start, block_k), :].astype(jnp.float32)
-        s = jax.lax.dot_general(
+        start = pl.multiple_of(base + i * block_k, 128)
+        kb = k_ref[0, pl.ds(start, width), :]
+        vb = v_ref[0, pl.ds(start, width), :]
+        s = lax.dot_general(
             q, kb, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )  # [block_q, block_k]
-        if causal:
-            col = i * block_k + lax.broadcasted_iota(jnp.int32, (1, block_k), 1)
-            if in_prefix:
-                seen = col < prefix_len
-            elif prefix is not None:
-                seen = row >= col - prefix
-            else:
-                seen = row >= col
-            if window is not None:
-                seen = seen & (col > row - window)
+        )  # [block_q, width]
+        if seen_of is not None:
+            col = col0 + i * block_k + lax.broadcasted_iota(
+                jnp.int32, (1, width), 1)
+            seen = seen_of(col)
             s = jnp.where(seen, s, NEG_INF)
         m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
         alpha = jnp.exp(m - m_new)
-        # Masked entries hold NEG_INF (finite -1e30): under this kernel's
-        # causal dispatch every row admits column 0, so m_new is finite
-        # after the first k-block and exp(NEG_INF - m_new) underflows to
-        # exactly 0 — no NaN, no select needed in the hot loop. A mask
-        # that fully hides a row would leave m_new == NEG_INF and make
-        # p == 1 per entry (an unweighted mean of V, not zeros); reuse
-        # with such masks requires a p = where(s == NEG_INF, 0, ...) guard.
+        # Masked entries hold NEG_INF (finite -1e30): a causal row admits
+        # column 0 in the walk's first tile, so m_new is finite from there
+        # on and exp(NEG_INF - m_new) underflows to exactly 0 — no NaN, no
+        # select needed in the hot loop. A tile that hides ALL its columns
+        # from a row that has seen none yet would leave m_new == NEG_INF and
+        # p == 1 per entry (an unweighted mean of V, not zeros): the band's
+        # first tile does that to the q-block's later rows, and a prefix
+        # tile's invisible tail comes before any row has a finite m.
         p = jnp.exp(s - m_new)
-        if window is not None or in_prefix:
-            # the band hides the walk's first block from the q-block's
-            # later rows whole (and a prefix block its invisible tail from
-            # every row, before any row has a finite m): the guard the note
-            # above asks for
+        if hides_rows:
             p = jnp.where(seen, p, 0.0)
         l = l * alpha + p.sum(axis=-1, keepdims=True)
-        o = o * alpha + jax.lax.dot_general(
-            p, vb, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        o = o * alpha + lax.dot_general(
+            p.astype(vb.dtype), vb, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
         )
         return o, m_new, l
 
-    n_k = t_k // block_k
-    if causal:
-        # blocks fully above the diagonal contribute nothing: stop at the
-        # q-block's last row (block sizes are equal-or-multiples, so the
-        # bound lands on a block edge or inside the masked block)
-        n_k = jnp.minimum(n_k, (qb * block_q + block_q + block_k - 1) // block_k)
-    o = jnp.zeros((block_q, v_ref.shape[-1]), jnp.float32)
-    m = jnp.full((block_q, 1), NEG_INF, jnp.float32)
-    l = jnp.zeros((block_q, 1), jnp.float32)
-    first = 0
-    if window is not None:
-        first = jnp.maximum(0, (qb * block_q - window + 1) // block_k)
+    carry = (jnp.zeros((block_q, v_ref.shape[-1]), jnp.float32),
+             jnp.full((block_q, 1), NEG_INF, jnp.float32),
+             jnp.zeros((block_q, 1), jnp.float32))
     if prefix is not None:
-        # the visible prefix first, then from the first causal block to the
-        # q-block's last row
-        o, m, l = lax.fori_loop(
-            0, (prefix_len + block_k - 1) // block_k,
-            functools.partial(body, in_prefix=True), (o, m, l))
-        first = prefix // block_k
+        visible = len_ref[0]
+        carry = lax.fori_loop(
+            0, (visible + block_k - 1) // block_k,
+            functools.partial(tile, base=0, seen_of=lambda col: col < visible,
+                              hides_rows=True), carry)
+    seen_of = own if causal else None
+    if lead:
+        carry = tile(0, carry, off, width=lead, seen_of=seen_of)
+    first, n_k = 0, (t_own - lead) // block_k
+    if causal:
+        # tiles fully above the diagonal contribute nothing: stop at the
+        # one that holds the q-block's last row
         n_k = jnp.minimum(
-            t_k // block_k,
-            first + (qb * block_q + block_q + block_k - 1) // block_k)
-    o, m, l = lax.fori_loop(first, n_k, body, (o, m, l))
-    # l == 0 is unreachable via the causal equal-block dispatch (see the
-    # loop-body comment); kept as a belt against 0/0 if the kernel is
-    # rebuilt with a row-hiding mask
+            n_k, (row0 + block_q - lead + block_k - 1) // block_k)
+    if window is not None:
+        first = jnp.maximum(0, (row0 - window + 1) // block_k)
+    o, m, l = lax.fori_loop(
+        first, n_k,
+        functools.partial(tile, base=off + lead, col0=lead, seen_of=seen_of,
+                          hides_rows=window is not None), carry)
+    # l == 0 is unreachable via the causal dispatch (see the note in the
+    # tile); kept as a belt against 0/0 if the kernel is rebuilt with a
+    # row-hiding mask
     l = jnp.where(l == 0.0, 1.0, l)
     o_ref[0] = (o / l).astype(o_ref.dtype)
 
@@ -204,75 +216,43 @@ def flash_attention(
 ):
     """Pallas blocked attention. q [B,H,Tq,Dh], k [B,H,Tk,Dh], v
     [B,H,Tk,Dv] (Dv = Dh everywhere but latent attention's prefill).
-    Tq must divide by block_q and Tk by block_k (use :func:`attention`
-    for the dispatching fallback). ``window`` (static int, causal only):
-    query i sees keys (i - window, i]. ``name``: the kernel's name in a
-    trace, where a caller wants its own. ``prefix`` (static int, causal
-    only, a multiple of ``block_k``) with ``prefix_len`` (a traced int32
-    scalar): k and v are [B, H, prefix + Tq, .], their first ``prefix`` rows
-    a prefix every query sees the first ``prefix_len`` rows of, the others
-    the queries' own positions."""
+    Tq must divide by block_q, and Tk and both blocks by 128 (use
+    :func:`attention` for the dispatching fallback and the tile the chip
+    was measured to want); ``block_k`` need not divide Tk but under a
+    window. ``window`` (static int, causal only): query i sees keys
+    (i - window, i]. ``name``: the kernel's name in a trace, where a
+    caller wants its own. ``prefix`` (static int, causal only, a multiple
+    of 128) with ``prefix_len`` (a traced int32 scalar): k and v are [B, H,
+    prefix + Tq, .], their first ``prefix`` rows a prefix every query sees
+    the first ``prefix_len`` rows of, the others the queries' own
+    positions."""
     if window is not None and not causal:
         raise ValueError("a window is causal")
     b, h, t_q, dh = q.shape
-    t_k = k.shape[2]
-    if prefix is not None:
-        if not causal or window is not None or prefix % block_k \
-                or t_k != prefix + t_q:
-            raise ValueError(
-                f"a prefix of {prefix} before {t_q} causal keys: Tk={t_k}, "
-                f"block {block_k}, no window")
-        return _prefixed_flash(q, k, v, prefix, prefix_len, block_q, block_k,
-                               interpret, name)
-    if t_q % block_q or t_k % block_k:
+    t_k, dv = k.shape[2], v.shape[-1]
+    if prefix is not None and (
+            not causal or window is not None or prefix % 128
+            or t_k != prefix + t_q or block_k > t_q):
+        raise ValueError(
+            f"a prefix of {prefix} before {t_q} causal keys: Tk={t_k}, "
+            f"block_k {block_k}, no window")
+    if t_q % block_q or t_k % 128 or block_q % 128 or block_k % 128 \
+            or (window is not None and t_k % block_k):
         raise ValueError(
             f"Tq={t_q} / Tk={t_k} must tile by block ({block_q}, {block_k})"
         )
-    qf = q.reshape(b * h, t_q, dh)
-    kf = k.reshape(b * h, t_k, dh)
-    dv = v.shape[-1]
-    vf = v.reshape(b * h, t_k, dv)
     kernel = functools.partial(
-        _flash_kernel,
-        block_q=block_q,
-        block_k=block_k,
-        causal=causal,
-    )
-    if window is not None:
-        kernel = functools.partial(kernel, window=int(window))
+        _flash_kernel, block_q=block_q, block_k=block_k, causal=causal,
+        window=None if window is None else int(window),
+        prefix=None if prefix is None else int(prefix))
+    visible = jnp.zeros((1,), jnp.int32) if prefix is None else \
+        jnp.reshape(prefix_len, (1,)).astype(jnp.int32)
     out = pl.pallas_call(
         kernel,
         # under shard_map the result varies over the mesh axes q does
         out_shape=jax.ShapeDtypeStruct(
             (b * h, t_q, dv), q.dtype, vma=jax.typeof(q).vma
         ),
-        grid=(b * h, t_q // block_q),
-        in_specs=[
-            pl.BlockSpec((1, block_q, dh), lambda bh, i: (bh, i, 0)),
-            pl.BlockSpec((1, t_k, dh), lambda bh, i: (bh, 0, 0)),
-            pl.BlockSpec((1, t_k, dv), lambda bh, i: (bh, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, block_q, dv), lambda bh, i: (bh, i, 0)),
-        interpret=interpret,
-        **({} if name is None else {"name": name}),
-    )(qf, kf, vf)
-    return out.reshape(b, h, t_q, dv)
-
-
-def _prefixed_flash(q, k, v, prefix, prefix_len, block_q, block_k,
-                    interpret, name):
-    """``flash_attention``'s call with the visible length prefetched into
-    SMEM (a grid of its own: the other call's is as it was)."""
-    from jax.experimental.pallas import tpu as pltpu
-
-    b, h, t_q, dh = q.shape
-    t_k, dv = k.shape[2], v.shape[-1]
-    if t_q % block_q:
-        raise ValueError(f"Tq={t_q} must tile by block {block_q}")
-    out = pl.pallas_call(
-        functools.partial(_prefixed_kernel, block_q=block_q, block_k=block_k,
-                          prefix=int(prefix)),
-        out_shape=jax.ShapeDtypeStruct((b * h, t_q, dv), q.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(b * h, t_q // block_q),
@@ -286,10 +266,30 @@ def _prefixed_flash(q, k, v, prefix, prefix_len, block_q, block_k,
         ),
         interpret=interpret,
         **({} if name is None else {"name": name}),
-    )(jnp.reshape(prefix_len, (1,)).astype(jnp.int32),
-      q.reshape(b * h, t_q, dh), k.reshape(b * h, t_k, dh),
+    )(visible, q.reshape(b * h, t_q, dh), k.reshape(b * h, t_k, dh),
       v.reshape(b * h, t_k, dv))
     return out.reshape(b, h, t_q, dv)
+
+
+def _tile(t_q, t_k, window=None, prefix=0):
+    """The (block_q, block_k) the kernel walks for a call's shapes.
+
+    Measured on a v5e (PERF.md section 6, PR 46; the kernel alone, 32 heads
+    of 128 but where said, us a call at the old rule's 128 x 128 -> at this
+    tile): T 512 157 -> 70, 1024 500 -> 165, 1792 1400 -> 424 (a lead tile
+    of 256), 2048 1791 -> 481, 4096 2562 (256 x 256) -> 1500, 4096 under a
+    window of 2048 2089 -> 1446; 16 heads of 256 at 4096 1483 -> 1300; keys
+    of 192 and values of 128 at 1792 1537 -> 562; 2048 behind 384 visible
+    rows of a prefix of 896 2336 -> 669. A key tile wider than 512 is
+    slower (1024: +3 to +17%), 512 query rows read within 1% of 256 up to
+    2048 and 2% under them from 4096, and an interior tile that skips the
+    mask, or two tiles a loop body, gain nothing at this tile."""
+    block_k = 512
+    own = t_k - prefix
+    if window is not None and own % block_k:
+        # the band's walk has no lead tile: the largest that divides
+        block_k = 256 if own % 256 == 0 else 128
+    return (256 if t_q % 256 == 0 else 128), min(block_k, own)
 
 
 def attention(q, k, v, kv_len=None, causal: bool = True, mesh=None,
@@ -317,44 +317,38 @@ def attention(q, k, v, kv_len=None, causal: bool = True, mesh=None,
     each chip runs the whole single-device kernel, which is the
     replicated-compute contract of ``DecoderLM.set_serving_mesh``.
 
-    Compiled by Mosaic on a v5e (libtpu 0.0.34) at head_dim 128, block 128,
-    T in {128, 512, 1024, 1792}, alone and inside prefill, one chip and a
-    four-chip mesh (``chip_smoke.py``). Head dims 64 and 256 and the
-    256/512 blocks (T >= 4096) lower for the TPU platform but have not been
-    compiled on the chip."""
+    The tile is ``_tile``'s, from the call's shapes alone. Compiled by
+    Mosaic and run on a v5e (libtpu 0.0.34) at that tile for 32 heads of
+    128 at T in {128, 512, 1024, 1792, 2048, 4096}, 4096 under a window of
+    2048, 2048 behind a prefix of 896; 16 heads of 128 at 512 and 1024, of
+    256 at 512 and 4096; keys of 192 with values of 128 at 256, 512, 1792
+    and 6144; alone (PERF.md section 6, PR 46: the table) and inside the
+    prefills of the benchmark's cells. Not run on the chip at this tile:
+    the call under a mesh (``chip_smoke.py``'s four-chip leg; it lowers
+    for the platform in ``tests/test_flash_attention.py``) and head dim 64
+    (it compiles for a described v5e in ``tests/test_burst_hlo.py``)."""
     t_q, t_k = q.shape[2], k.shape[2]
-    if prefix is not None:
-        if mesh is not None or kv_len is not None or window is not None:
-            raise ValueError("a prefix takes no mesh, kv_len or window")
-        if (jax.default_backend() == "tpu" and t_q % 128 == 0
-                and prefix % 128 == 0 and q.shape[-1] in (64, 128, 256)):
-            return flash_attention(q, k, v, causal=True, prefix=int(prefix),
-                                   prefix_len=prefix_len, name=name)
-        return _prefixed_attention(q, k, v, int(prefix), prefix_len)
-    # bigger blocks amortise the online-softmax rescale and MXU ramp-up
-    # (block-size choice not measured on the current machine)
-    block = 128
-    while block < 512 and t_q % (block * 2) == 0 and t_k % (block * 2) == 0 \
-            and block * 16 < t_q:
-        block *= 2
+    if prefix is not None and (
+            mesh is not None or kv_len is not None or window is not None):
+        raise ValueError("a prefix takes no mesh, kv_len or window")
     use_kernel = (
         kv_len is None
         and jax.default_backend() == "tpu"
-        and t_q % block == 0
-        and t_k % block == 0
+        and t_q % 128 == 0
+        and t_k % 128 == 0
+        and (prefix or 0) % 128 == 0
         and q.shape[-1] in (64, 128, 192, 256)
     )
     if not use_kernel:
-        if window is None:
-            return _xla_attention(q, k, v, causal=causal, kv_len=kv_len)
+        if prefix is not None:
+            return _prefixed_attention(q, k, v, int(prefix), prefix_len)
         return _xla_attention(q, k, v, causal, kv_len, window)
+    block_q, block_k = _tile(t_q, t_k, window, prefix or 0)
     kernel = functools.partial(
-        flash_attention, causal=causal, block_q=block, block_k=block
+        flash_attention, causal=causal, block_q=block_q, block_k=block_k,
+        window=None if window is None else int(window), name=name,
+        prefix=None if prefix is None else int(prefix), prefix_len=prefix_len,
     )
-    if window is not None:
-        kernel = functools.partial(kernel, window=int(window))
-    if name is not None:
-        kernel = functools.partial(kernel, name=name)
     if mesh is not None:
         kernel = jax.shard_map(
             kernel, mesh=mesh, in_specs=(P(), P(), P()), out_specs=P()

@@ -455,14 +455,15 @@ def test_flash_kernel_compiled_for_v5e_takes_keys_of_192_and_values_of_128(one_c
     import jax
     import jax.numpy as jnp
 
-    from seldon_core_tpu.ops.flash_attention import flash_attention
+    from seldon_core_tpu.ops.flash_attention import _tile, flash_attention
 
     def sds(shape):
         return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
 
-    for t, block in ((1792, 128), (6144, 512)):
+    for t in (1792, 6144):
+        block_q, block_k = _tile(t, t)
         fn = jax.jit(functools.partial(
-            flash_attention, causal=True, block_q=block, block_k=block,
+            flash_attention, causal=True, block_q=block_q, block_k=block_k,
             name="latent_prefill_attention"))
         compiled = fn.lower(sds((1, 32, t, 192)), sds((1, 32, t, 192)),
                             sds((1, 32, t, 128))).compile()
@@ -527,16 +528,49 @@ def test_flash_kernel_compiled_for_v5e_behind_a_visible_prefix(one_chip):
     import jax
     import jax.numpy as jnp
 
-    from seldon_core_tpu.ops.flash_attention import flash_attention
+    from seldon_core_tpu.ops.flash_attention import _tile, flash_attention
 
     def sds(shape, dt=jnp.bfloat16):
         return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
 
+    block_q, block_k = _tile(2048, 2944, None, 896)
+    assert block_k > 128 and 896 % block_k      # the prefix ends mid-tile
     fn = jax.jit(lambda q, k, v, n: flash_attention(
-        q, k, v, causal=True, prefix=896, prefix_len=n,
-        name="eva_prefill_attention"))
+        q, k, v, causal=True, prefix=896, prefix_len=n, block_q=block_q,
+        block_k=block_k, name="eva_prefill_attention"))
     compiled = fn.lower(sds((1, 32, 2048, 128)), sds((1, 32, 2944, 128)),
                         sds((1, 32, 2944, 128)), sds((), jnp.int32)).compile()
     call, = (line for line in compiled.as_text().splitlines()
              if "custom-call(" in line and "tpu_custom_call" in line)
     assert "eva_prefill_attention" in call and "bf16[32,2048,128]" in call
+
+
+@pytest.mark.parametrize("heads,t,dh,window", [
+    (32, 1792, 128, None),    # mistral docqa: 512 does not divide, a lead tile
+    (32, 2048, 128, None),
+    (16, 1024, 128, None),    # internlm2
+    (32, 4096, 128, 2048),    # trinity-mini's band
+    (32, 4096, 128, None),
+    (16, 4096, 256, None),    # qwen3-next: whole K and V of 2 MiB each resident
+    (8, 1024, 64, None),
+])
+def test_flash_kernel_compiled_for_v5e_at_the_rules_tile(one_chip, heads, t, dh, window):
+    """Each family's largest prefill attention at the tile ``attention()``
+    picks for it (``_tile``): Mosaic takes the unequal tile, the lead tile
+    and the band, inside the scoped VMEM every kernel lives under."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+
+    from seldon_core_tpu.ops.flash_attention import _tile, flash_attention
+
+    x = jax.ShapeDtypeStruct((1, heads, t, dh), jnp.bfloat16, sharding=one_chip)
+    block_q, block_k = _tile(t, t, window)
+    assert (block_q, block_k) == (256, 512)
+    compiled = jax.jit(functools.partial(
+        flash_attention, causal=True, block_q=block_q, block_k=block_k,
+        window=window)).lower(x, x, x).compile()
+    call, = (line for line in compiled.as_text().splitlines()
+             if "custom-call(" in line and "tpu_custom_call" in line)
+    assert f"bf16[{heads},{t},{dh}]" in call
