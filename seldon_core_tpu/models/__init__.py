@@ -3,7 +3,9 @@
 Families are lazy-imported so importing the package costs nothing until a
 server actually builds a model. Families map to BASELINE.json's configs:
 mlp (iris parity), resnet50 (REST image path), bert (gRPC text path),
-llm (generate() with dynamic batching).
+llm (generate() with dynamic batching). The ``llm`` family's block variants
+(llama, afmoe, qwen3_next, joyai_llm_flash, evabyte) have a registry of
+their own, ``family.FAMILIES``, which ``DecoderLM(block=...)`` looks up.
 """
 
 from __future__ import annotations

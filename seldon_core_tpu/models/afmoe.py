@@ -1,10 +1,14 @@
-"""AfmoeLM: a decoder whose layers are of mixed kinds (``LLMConfig.block ==
-"afmoe"``; arcee-ai's Trinity family publishes it as ``model_type: afmoe``).
+"""AfmoeLM: a decoder whose layers are of mixed kinds (arcee-ai's Trinity
+family publishes it as ``model_type: afmoe``).
 
-``DecoderLM(block="afmoe", ...)`` builds this class. It shares with the
-llama block the embedding lookup, the head, ``_rms_norm``, ``_rope``, the
-SwiGLU, the cache's layout and the cache ops (``ops.decode_attention``, the
-flash kernel), and differs layer by layer:
+A ``DecoderFamily`` (``models/family.py``: the interface the scheduler and
+the server ask), registered there as ``"afmoe"``:
+``DecoderLM(block="afmoe", ...)`` and ``AfmoeLM(...)`` build it, over an
+``AfmoeConfig``. It shares with the llama block ``_rms_norm``, ``_rope``
+and the SwiGLU, takes the embedding lookup, the K/V cache's layout and its
+answers to the scheduler from the interface's defaults, reads the cache
+through the same ops (``ops.decode_attention``, the flash kernel), and
+differs layer by layer:
 
 * attention is *window + rotary* (``sliding_attention``: query i sees keys
   (i - window, i]) or *full + no rotary* (``full_attention``), per
@@ -27,21 +31,34 @@ traced. The cache is the llama block's: one [S, KV, T, Dh] pair a layer,
 
 Serving only. What it refuses is ``serving_refuses``; training
 (``loss_fn``, ``backbone``) and the uniform-batch ``generate`` family
-(``decode_step``, ``decode_step_ragged``: the stacked scan) raise.
+(``decode_step``, ``decode_step_ragged``: the stacked scan) are the
+interface's typed refusals.
 """
 
 from __future__ import annotations
 
+import dataclasses
 from typing import Optional, Tuple
 
 import numpy as np
 
-from .llm import DecoderLM, UnsupportedByModel, _rms_norm, _rope
+from .family import DecoderFamily
+from .llm import LLMConfig, _rms_norm, _rope
 
 SLIDING, FULL = "sliding_attention", "full_attention"
 
 
-class AfmoeLM(DecoderLM):
+@dataclasses.dataclass
+class AfmoeConfig(LLMConfig):
+    """The shared fields (``layer_types``: "sliding_attention" |
+    "full_attention" a layer, None = all full; the routed experts') and
+    this family's own."""
+    block: str = "afmoe"
+    sliding_window: int = 0       # keys a sliding_attention query sees
+
+
+class AfmoeLM(DecoderFamily):
+    config_class = AfmoeConfig
     step_counter_names = (
         # per decode step, summed over the expert layers: distinct experts
         # some live lane picked, (lane, pick) pairs routed, expert layers run
@@ -268,9 +285,9 @@ class AfmoeLM(DecoderLM):
         experts through the touched-only read. ``real`` ([B, T] bool, a
         prefill's): the rows that are some sequence's tokens. Returns
         ``(x, picks, counts)``: a routed layer's picks [B, T, k], else
-        None, and (experts touched, rows routed) of the touched-only
-        read, ``ops.experts.GROUPED_COUNTS`` of the grouped path, else
-        None."""
+        None, and ``ops.experts.routed_ffn``'s counts: (experts touched,
+        rows routed, rows here) of the touched-only read,
+        ``GROUPED_COUNTS`` of the grouped path, else None."""
         import jax
         import jax.numpy as jnp
 
@@ -291,29 +308,16 @@ class AfmoeLM(DecoderLM):
         if not routed:
             f = swiglu("w1", "w3", "w2")
         else:
-            from ..ops import experts
+            from ..ops.experts import routed_ffn
 
-            rows = m.reshape(B * T, D)
-            picks, weights = experts.route(
-                rows, p["router"], p["expert_bias"], cfg.experts_per_tok,
-                cfg.route_scale)
-            stacks = tuple(p[n].astype(dt) for n in ("we1", "we3", "we2"))
-            if live is None:
-                sent = picks
-                if real is not None:
-                    # a row of padding computes nothing that is read: its
-                    # picks go to no expert's id, join no group and cost
-                    # the grouped kernel nothing
-                    sent = jnp.where(real.reshape(-1, 1), picks,
-                                     cfg.n_routed_experts)
-                y, counts = experts.grouped_experts(
-                    rows, sent, weights, *stacks,
-                    mesh=getattr(self, "_serving_mesh", None))
-            else:
-                y, touched, routed_rows = experts.decode_experts(
-                    rows, picks, weights, live, *stacks,
-                    mesh=getattr(self, "_serving_mesh", None))
-                counts = (touched, routed_rows)
+            # every expert is held; a pad row's picks go to no expert
+            y, picks, counts = routed_ffn(
+                m.reshape(B * T, D), p["router"], p["expert_bias"],
+                cfg.experts_per_tok, cfg.route_scale, "sigmoid",
+                tuple(p[n].astype(dt) for n in ("we1", "we3", "we2")),
+                live=live, real=real, held=None,
+                n_routed=cfg.n_routed_experts, mesh=self._serving_mesh,
+                redirect_pads=True)
             f = y.astype(dt).reshape(B, T, D)
             if cfg.n_shared_experts:
                 f = f + swiglu("ws1", "ws3", "ws2")
@@ -329,11 +333,7 @@ class AfmoeLM(DecoderLM):
         dt = x.dtype
         x = _rms_norm(x, params["ln_f"].astype(dt), cfg.norm_eps)
         if not every:
-            if last_index is None:
-                x = x[:, -1]
-            else:
-                x = x[jnp.arange(x.shape[0]),
-                      jnp.asarray(last_index, jnp.int32)]
+            x = self._last_rows(x, last_index)
         return (x @ params["unembed"].astype(dt)).astype(jnp.float32)
 
     @staticmethod
@@ -517,7 +517,7 @@ class AfmoeLM(DecoderLM):
             starts = None if window is None else jnp.maximum(0, lens - window)
             o, nk, nv = decode_attention(
                 q, ks[l], vs[l], k, v, wp, pos, lens, attn_len=attn_len,
-                mesh=getattr(self, "_serving_mesh", None), starts=starts)
+                mesh=self._serving_mesh, starts=starts)
             nks.append(nk)
             nvs.append(nv)
             x, picks, counts = self._close(p, x, o, g, routed, live=live)
@@ -528,24 +528,3 @@ class AfmoeLM(DecoderLM):
         counts = jnp.stack(
             [touched, routed_rows, jnp.int32(sum(self._routed))])
         return self._head(params, x), nks, nvs, counts, picked
-
-    # -- what this family does not serve ------------------------------------------------------
-
-    def _no(self, what: str):
-        raise UnsupportedByModel(
-            f"the afmoe block has no {what}: it serves through prefill*, "
-            "decode_step_ragged_list and decode_chunk_ragged_list")
-
-    def backbone(self, *a, **kw):
-        self._no("stacked-scan backbone (training, tp / sp / pp / ep)")
-
-    def loss_fn(self, *a, **kw):
-        self._no("loss (serving only)")
-
-    def _decode(self, *a, **kw):
-        self._no("stacked-cache decode step (decode_step, "
-                 "decode_step_ragged, generate)")
-
-    def param_sharding(self, mesh, params):
-        raise UnsupportedByModel(
-            "the afmoe block has no serving mesh: " + self.serving_refuses["mesh"])

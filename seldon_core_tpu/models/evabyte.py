@@ -1,13 +1,14 @@
-"""EvaByteLM: a byte-level decoder with EVA attention (``LLMConfig.block ==
-"evabyte"``; EvaByte publishes the family as ``model_type: evabyte``,
-``attention_class: eva``).
+"""EvaByteLM: a byte-level decoder with EVA attention (EvaByte publishes the
+family as ``model_type: evabyte``, ``attention_class: eva``).
 
-``DecoderLM(block="evabyte", ...)`` builds this class. With the other
-blocks it shares the embedding lookup, the rotary embedding (half-split
-pairs), the flash kernel (here behind a visible prefix) and the batcher's
-cache dict. Every layer is pre-norm, ``N(x) = x * rsqrt(mean(x^2) + eps) *
-(1 + w)`` (``norm_add_unit_offset``), and the residual stream adds in
-float32 (``fp32_skip_add``):
+A ``DecoderFamily`` (``models/family.py``: the interface the scheduler and
+the server ask), registered there as ``"evabyte"``:
+``DecoderLM(block="evabyte", ...)`` and ``EvaByteLM(...)`` build it, over an
+``EvaByteConfig``. With the other blocks it shares the embedding lookup, the
+rotary embedding (half-split pairs), the flash kernel (here behind a
+visible prefix) and the batcher's cache dict. Every layer is pre-norm,
+``N(x) = x * rsqrt(mean(x^2) + eps) * (1 + w)`` (``norm_add_unit_offset``),
+and the residual stream adds in float32 (``fp32_skip_add``):
 
     h = x + EVA(N(x));   y = h + W_down(silu(W_gate N(h)) * W_up N(h))
 
@@ -61,11 +62,13 @@ samples from head 0, and accepting several bytes a step is refused
 
 from __future__ import annotations
 
+import dataclasses
 from typing import Sequence, Tuple
 
 import numpy as np
 
-from .llm import DecoderLM, UnsupportedByModel, _rope
+from .family import DecoderFamily
+from .llm import LLMConfig, _rope
 
 _RING = (
     "the batcher's {0} copies the cache's ``k`` and ``v`` kinds by name, "
@@ -73,7 +76,20 @@ _RING = (
     "the earlier ones is neither")
 
 
-class EvaByteLM(DecoderLM):
+@dataclasses.dataclass
+class EvaByteConfig(LLMConfig):
+    """The shared fields and this family's own: EVA attention, the
+    norms' and the residual stream's conventions, the prediction heads."""
+    block: str = "evabyte"
+    window_size: int = 0          # positions of one aligned window
+    chunk_size: int = 0           # positions pooled into one summary row
+    num_pred_heads: int = 1       # heads of vocab_size logits a position
+    norm_add_unit_offset: bool = False   # a norm's weight is 1 + w
+    fp32_skip_add: bool = False   # the residual stream adds in float32
+
+
+class EvaByteLM(DecoderFamily):
+    config_class = EvaByteConfig
     step_counter_names = (
         # per decode step, summed over the live lanes and the layers: ring
         # rows a lane's position admits (``(t mod W) + 1``), summary rows
@@ -381,11 +397,6 @@ class EvaByteLM(DecoderLM):
         return dict(zip(self._KINDS, (layers(ring), layers(ring),
                                       layers(summ), layers(summ))))
 
-    def cache_layers(self, batch: int, max_seq=None):
-        """``init_cache`` is laid out by kind and layer already (the cache
-        is allocated once)."""
-        return self.init_cache(batch, max_seq)
-
     # -- one layer ---------------------------------------------------------------
 
     def _embed(self, params, tokens):
@@ -646,7 +657,7 @@ class EvaByteLM(DecoderLM):
         ends_chunk = writes & ((t + 1) % C == 0)
         # the step that completes a chunk pools it into its summary row
         sum_at = jnp.where(ends_chunk, t // C, n_sum_rows)
-        mesh = getattr(self, "_serving_mesh", None)
+        mesh = self._serving_mesh
 
         x = self._embed(params, tokens)  # [B, 1, D]
         new = {name: [] for name in self._KINDS}
@@ -671,39 +682,3 @@ class EvaByteLM(DecoderLM):
             ends_chunk.sum(dtype=jnp.int32),
             live.sum(dtype=jnp.int32)]).astype(jnp.int32) * jnp.int32(L)
         return self._head(params, x[:, 0]), new, counts
-
-    # -- what this family does not serve ------------------------------------------------------
-
-    def _no(self, what: str):
-        raise UnsupportedByModel(
-            f"the evabyte block has no {what}: it serves through prefill "
-            "and decode_step_cache")
-
-    def backbone(self, *a, **kw):
-        self._no("stacked-scan backbone (training, tp / sp / pp / ep)")
-
-    def loss_fn(self, *a, **kw):
-        self._no("loss (serving only)")
-
-    def _decode(self, *a, **kw):
-        self._no("stacked-cache decode step (decode_step, "
-                 "decode_step_ragged, generate)")
-
-    def decode_step_ragged_list(self, *a, **kw):
-        self._no("k/v decode step: its cache holds a ring and summaries "
-                 "(decode_step_cache)")
-
-    def decode_chunk_ragged_list(self, *a, **kw):
-        self._no("window of positions over a cache: "
-                 + self.serving_refuses["speculation"])
-
-    def prefill_chunk(self, *a, **kw):
-        self._no("chunked prefill: " + self.serving_refuses["chunked_prefill"])
-
-    def prefill_with_prefix(self, *a, **kw):
-        self._no("prefix splice: " + self.serving_refuses["prefix_cache"])
-
-    def param_sharding(self, mesh, params):
-        raise UnsupportedByModel(
-            "the evabyte block has no serving mesh: "
-            + self.serving_refuses["mesh"])

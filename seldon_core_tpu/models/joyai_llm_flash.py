@@ -1,13 +1,14 @@
 """JoyaiLLMFlashLM: multi-head latent attention (MLA) with a compressed
 cache of one row a position, and a chip's share of sigmoid-routed experts
-(``LLMConfig.block == "joyai_llm_flash"``; jdopensource publishes the
-family as ``model_type: joyai_llm_flash``, with the keys of the
-DeepSeek-V3 family's config).
+(jdopensource publishes the family as ``model_type: joyai_llm_flash``, with
+the keys of the DeepSeek-V3 family's config).
 
-``DecoderLM(block="joyai_llm_flash", ...)`` builds this class. With the
-other blocks it shares the embedding lookup, ``_rms_norm``, the flash
-kernel, the routed experts (``ops/experts.py``) and the batcher's cache
-dict. Every layer is pre-norm,
+A ``DecoderFamily`` (``models/family.py``: the interface the scheduler and
+the server ask), registered there as ``"joyai_llm_flash"``:
+``DecoderLM(block="joyai_llm_flash", ...)`` and ``JoyaiLLMFlashLM(...)``
+build it, over a ``JoyaiLLMFlashConfig``. With the other blocks it shares
+the embedding lookup, ``_rms_norm``, the flash kernel, the routed experts
+(``ops/experts.py``) and the batcher's cache dict. Every layer is pre-norm,
 
     h = x + MLA(N(x));   y = h + FFN(N(h))
 
@@ -53,11 +54,13 @@ Serving only, as the other two expert families; what it refuses is
 
 from __future__ import annotations
 
+import dataclasses
 from typing import Tuple
 
 import numpy as np
 
-from .llm import DecoderLM, UnsupportedByModel, _rms_norm
+from .family import DecoderFamily
+from .llm import LLMConfig, _rms_norm
 
 _KV_BY_NAME = (
     "the batcher's {0} copies the cache's ``k`` and ``v`` kinds by name "
@@ -84,7 +87,21 @@ def rope_pairs(x, positions, theta: float):
     return out.reshape(x.shape).astype(x.dtype)
 
 
-class JoyaiLLMFlashLM(DecoderLM):
+@dataclasses.dataclass
+class JoyaiLLMFlashConfig(LLMConfig):
+    """The shared fields (the routed experts', ``experts_held``) and this
+    family's own: multi-head latent attention, every layer caching one row
+    a position."""
+    block: str = "joyai_llm_flash"
+    q_lora_rank: int = 0          # the query's low-rank bottleneck
+    kv_lora_rank: int = 0         # the cached, normed latent c
+    qk_nope_head_dim: int = 0     # a head's key dims expanded from c
+    qk_rope_head_dim: int = 0     # the one rotary key all heads share
+    v_head_dim: int = 0           # a head's value dims expanded from c
+
+
+class JoyaiLLMFlashLM(DecoderFamily):
+    config_class = JoyaiLLMFlashConfig
     step_counter_names = (
         # per decode step, summed over the expert layers: distinct held
         # experts some live lane picked, (lane, pick) pairs routed over ALL
@@ -385,11 +402,6 @@ class JoyaiLLMFlashLM(DecoderLM):
         return {"latent": [jnp.zeros((batch, T, self._row), dt)
                            for _ in range(cfg.n_layers)]}
 
-    def cache_layers(self, batch: int, max_seq=None):
-        """``init_cache`` is laid out by kind and layer already (the cache
-        is allocated once)."""
-        return self.init_cache(batch, max_seq)
-
     # -- one layer ---------------------------------------------------------------
 
     def _latent(self, p, a, positions):
@@ -461,9 +473,8 @@ class JoyaiLLMFlashLM(DecoderLM):
         ``GROUPED_COUNTS``. ``real`` [B, T] bool (a prefill's): the rows that
         are some sequence's tokens."""
         import jax
-        import jax.numpy as jnp
 
-        from ..ops import experts
+        from ..ops.experts import routed_ffn
 
         cfg = self.cfg
         dt = h.dtype
@@ -476,31 +487,14 @@ class JoyaiLLMFlashLM(DecoderLM):
 
         if not routed:
             return h + swiglu("w1", "w3", "w2"), None, None
-        rows = m.reshape(B * T, D)
-        picks, weights = experts.route(
-            rows, p["router"], p["expert_bias"], cfg.experts_per_tok,
-            cfg.route_scale)
-        stacks = tuple(p[n].astype(dt) for n in ("we1", "we3", "we2"))
-        if live is None:
-            sent = picks
-            if real is not None and cfg.experts_held is not None:
-                # a row of padding computes nothing that is read, and
-                # padding routes together: its picks go to no expert's id
-                # (the qwen3_next block's finding)
-                sent = jnp.where(real.reshape(-1, 1), picks,
-                                 cfg.n_routed_experts)
-            y, counts = experts.grouped_experts(
-                rows, sent, weights, *stacks, held=cfg.experts_held,
-                n_routed=cfg.n_routed_experts,
-                mesh=getattr(self, "_serving_mesh", None))
-        else:
-            y, touched, n_routed = experts.decode_experts(
-                rows, picks, weights, live, *stacks,
-                mesh=getattr(self, "_serving_mesh", None),
-                held=cfg.experts_held)
-            lo, n = cfg.experts_held or (0, cfg.n_routed_experts)
-            here = (picks >= lo) & (picks < lo + n) & live[:, None]
-            counts = (touched, n_routed, here.sum(dtype=jnp.int32))
+        # a share sends its padding nowhere (the qwen3_next block's finding)
+        y, picks, counts = routed_ffn(
+            m.reshape(B * T, D), p["router"], p["expert_bias"],
+            cfg.experts_per_tok, cfg.route_scale, "sigmoid",
+            tuple(p[n].astype(dt) for n in ("we1", "we3", "we2")),
+            live=live, real=real, held=cfg.experts_held,
+            n_routed=cfg.n_routed_experts, mesh=self._serving_mesh,
+            redirect_pads=cfg.experts_held is not None)
         out = h + y.astype(dt).reshape(B, T, D)
         if cfg.n_shared_experts:
             out = out + swiglu("ws1", "ws3", "ws2")
@@ -511,11 +505,7 @@ class JoyaiLLMFlashLM(DecoderLM):
 
         dt = x.dtype
         if not every:
-            if last_index is None:
-                x = x[:, -1]
-            else:
-                x = x[jnp.arange(x.shape[0]),
-                      jnp.asarray(last_index, jnp.int32)]
+            x = self._last_rows(x, last_index)
         x = _rms_norm(x, params["ln_f"].astype(dt), self.cfg.norm_eps)
         return (x @ params["unembed"].astype(dt)).astype(jnp.float32)
 
@@ -603,7 +593,7 @@ class JoyaiLLMFlashLM(DecoderLM):
         wp = pos if write_pos is None else write_pos.astype(jnp.int32)
         lens = pos + 1 if lens is None else lens.astype(jnp.int32)
         live = lens > 0
-        mesh = getattr(self, "_serving_mesh", None)
+        mesh = self._serving_mesh
         x = self._embed_tokens(params, tokens)  # [B, 1, D]
         new, picked = [], []
         touched = routed_rows = held = jnp.int32(0)
@@ -630,39 +620,3 @@ class JoyaiLLMFlashLM(DecoderLM):
             jnp.sum(lens) * n,
             live.sum(dtype=jnp.int32) * n])
         return self._head(params, x), {"latent": new}, counts, picked
-
-    # -- what this family does not serve ------------------------------------------------------
-
-    def _no(self, what: str):
-        raise UnsupportedByModel(
-            f"the joyai_llm_flash block has no {what}: it serves through "
-            "prefill and decode_step_cache")
-
-    def backbone(self, *a, **kw):
-        self._no("stacked-scan backbone (training, tp / sp / pp / ep)")
-
-    def loss_fn(self, *a, **kw):
-        self._no("loss (serving only)")
-
-    def _decode(self, *a, **kw):
-        self._no("stacked-cache decode step (decode_step, "
-                 "decode_step_ragged, generate)")
-
-    def decode_step_ragged_list(self, *a, **kw):
-        self._no("k/v decode step: its cache holds latent rows "
-                 "(decode_step_cache)")
-
-    def decode_chunk_ragged_list(self, *a, **kw):
-        self._no("window of positions over a cache: "
-                 + self.serving_refuses["speculation"])
-
-    def prefill_chunk(self, *a, **kw):
-        self._no("chunked prefill: " + self.serving_refuses["chunked_prefill"])
-
-    def prefill_with_prefix(self, *a, **kw):
-        self._no("prefix splice: " + self.serving_refuses["prefix_cache"])
-
-    def param_sharding(self, mesh, params):
-        raise UnsupportedByModel(
-            "the joyai_llm_flash block has no serving mesh: "
-            + self.serving_refuses["mesh"])

@@ -1,5 +1,12 @@
 """DecoderLM: llama-style decoder-only transformer (flagship model family).
 
+The llama block of the decoder families (``models/family.py``: the
+interface every family answers, and the registry ``DecoderLM(block=...)``
+looks a block up in), and the one with every optional path: training, the
+stacked scan, the serving mesh, the chunk and prefix prefills. Here too:
+the configuration the families' own extend (``LLMConfig``) and the two
+primitives they share (``_rms_norm``, ``_rope``).
+
 Serves BASELINE.json's "Llama-2-7B generate() with engine-side dynamic
 batching" config class. Architecture: RMSNorm, rotary embeddings, GQA,
 SwiGLU FFN (optionally Switch-MoE every k-th layer), tied-free unembed.
@@ -26,7 +33,7 @@ from typing import Any, Dict, Optional, Tuple
 
 import numpy as np
 
-from .base import ServedModel
+from .family import DecoderFamily, UnsupportedByModel, family_class  # noqa: F401
 
 
 @dataclasses.dataclass
@@ -55,57 +62,26 @@ class LLMConfig:
     # width of one head; 0 = d_model // n_heads (the llama families).
     # Stated where heads x head_dim is not the hidden size
     head_dim: int = 0
-    # -- the block variant, and what only some variants state ------------
-    # "llama": the block above, every layer alike. "afmoe": layers of
-    # mixed kinds (models/afmoe.py: window or full attention per
-    # ``layer_types``, dense or routed FFN by ``n_dense_layers``, four
-    # norms a layer, normed and gated heads, a scaled embedding). The
-    # kinds are resolved when the model is built, never in a traced
-    # function; DecoderLM(block="afmoe", ...) builds that class.
-    # "qwen3_next": Gated DeltaNet layers beside gated softmax attention
-    # and a chip's share of softmax-routed experts (models/qwen3_next.py).
-    # "joyai_llm_flash": latent attention (one compressed row a position,
-    # absorbed into the decode step) and a chip's share of sigmoid-routed
-    # experts (models/joyai_llm_flash.py).
-    # "evabyte": EVA attention (exact inside an aligned window, one pooled
-    # summary row a chunk for every earlier window) over a cache of two
-    # kinds of two lengths, a float32 residual stream and several
-    # prediction heads (models/evabyte.py).
+    # which family's file serves this configuration: a key of
+    # ``family.FAMILIES``. "llama" is the block of this file, every layer
+    # alike; ``DecoderLM(block=...)`` builds the registered class of any
+    # other, whose own dataclass extends this one with the fields only it
+    # reads. A family's kinds of layer are resolved when the model is
+    # built, never in a traced function.
     block: str = "llama"
-    # per layer "sliding_attention" | "full_attention"; None = all full
+    # -- routed experts and mixed layers: what two or three families read --
+    # per layer, a kind the family names ("sliding_attention" |
+    # "full_attention" | "linear_attention"); None = all alike
     layer_types: Optional[Tuple[str, ...]] = None
-    sliding_window: int = 0
     n_dense_layers: int = 0       # leading layers with a dense FFN (d_ff)
     n_routed_experts: int = 0     # drop-free top-k experts a later layer
     experts_per_tok: int = 0
     expert_width: int = 0         # FFN width of one expert
     n_shared_experts: int = 0     # experts every token takes, beside them
     route_scale: float = 1.0
-    # -- the qwen3_next block only -------------------------------------
-    # per layer "linear_attention" | "full_attention" in layer_types
-    linear_key_heads: int = 0
-    linear_value_heads: int = 0
-    linear_key_dim: int = 0
-    linear_value_dim: int = 0
-    linear_conv_kernel: int = 0
-    partial_rotary_factor: float = 1.0   # share of a head's dims rotated
-    shared_expert_width: int = 0
     # (lo, n): this chip holds experts lo .. lo + n - 1 of the
     # n_routed_experts the router ranges over; None: all of them
     experts_held: Optional[Tuple[int, int]] = None
-    # -- the joyai_llm_flash block only: multi-head latent attention ----
-    # (models/joyai_llm_flash.py; every layer caches one row a position)
-    q_lora_rank: int = 0          # the query's low-rank bottleneck
-    kv_lora_rank: int = 0         # the cached, normed latent c
-    qk_nope_head_dim: int = 0     # a head's key dims expanded from c
-    qk_rope_head_dim: int = 0     # the one rotary key all heads share
-    v_head_dim: int = 0           # a head's value dims expanded from c
-    # -- the evabyte block only: EVA attention (models/evabyte.py) ------
-    window_size: int = 0          # positions of one aligned window
-    chunk_size: int = 0           # positions pooled into one summary row
-    num_pred_heads: int = 1       # heads of vocab_size logits a position
-    norm_add_unit_offset: bool = False   # a norm's weight is 1 + w
-    fp32_skip_add: bool = False   # the residual stream adds in float32
 
     def __post_init__(self):
         if not self.head_dim:
@@ -143,66 +119,21 @@ def _rope(x, positions, theta: float):
     ).astype(x.dtype)
 
 
-class UnsupportedByModel(ValueError):
-    """A serving feature was asked of a model family that has no path for
-    it (``DecoderLM.serving_refuses``): refused at load, not computed
-    as something else."""
-
-
-class DecoderLM(ServedModel):
-    # what ``decode_step_ragged_list`` returns after its caches, where a
-    # family counts what its step did: names of the int32 vector's
-    # entries, which the batcher adds into ``stats``. The llama block has
-    # none and returns no fourth result.
-    step_counter_names: Tuple[str, ...] = ()
-    # likewise for a prefill: a family that names counters here has a
-    # ``prefill_counted`` that returns ``prefill``'s two results and the
-    # int32 vector, which the batcher adds up on the device (its insert)
-    # and brings home beside the next burst it reads
-    prefill_counter_names: Tuple[str, ...] = ()
-    # serving features this family has no path for -> why; the batcher
-    # refuses them typed at load (``UnsupportedByModel``)
-    serving_refuses: Dict[str, str] = {}
-
-    def check_serves(self, **asked: bool) -> None:
-        """Raise ``UnsupportedByModel`` for the first feature that is
-        asked for (``speculation=True``, ...) and that this family
-        refuses. The server and the batcher call it at load."""
-        for feature, why in self.serving_refuses.items():
-            if asked.get(feature):
-                raise UnsupportedByModel(
-                    f"{type(self).__name__} does not serve with {feature}: {why}")
+class DecoderLM(DecoderFamily):
+    config_class = LLMConfig
+    # set_serving_mesh's other knob: the cache length sharded over ``seq``
+    _serving_shard_seq = False
 
     def __new__(cls, **config):
-        if cls is DecoderLM and config.get("block", "llama") != "llama":
-            if config["block"] == "afmoe":
-                from .afmoe import AfmoeLM as family
-            elif config["block"] == "qwen3_next":
-                from .qwen3_next import Qwen3NextLM as family
-            elif config["block"] == "joyai_llm_flash":
-                from .joyai_llm_flash import JoyaiLLMFlashLM as family
-            elif config["block"] == "evabyte":
-                from .evabyte import EvaByteLM as family
-            else:
-                raise ValueError(f"unknown block variant {config['block']!r}")
-            return super().__new__(family)
+        # ``DecoderLM(block=...)`` builds the block's registered family
+        # (``family.FAMILIES``). Another class's instance comes back fully
+        # built: Python runs ``__init__`` on what ``__new__`` returns only
+        # where that is an instance of ``cls``
+        if cls is DecoderLM:
+            family = family_class(config.get("block", "llama"))
+            if family is not DecoderLM:
+                return family(**config)
         return super().__new__(cls)
-
-    def __init__(self, **config):
-        cfg_fields = {f.name for f in dataclasses.fields(LLMConfig)}
-        extra = {k: v for k, v in config.items() if k not in cfg_fields}
-        self.cfg = LLMConfig(**{k: v for k, v in config.items() if k in cfg_fields})
-        self._extra = extra
-        self.example_input_shape = (16,)  # token ids
-        self.compute_dtype = self.cfg.dtype
-
-    def attention_kinds(self) -> Tuple[Tuple[int, Optional[int]], ...]:
-        """``(layers, window)`` per kind of attention layer: how many
-        layers read the cache that way and how many positions back a
-        query sees (None: all of them). The scheduler's arithmetic of
-        what a burst reads (``kv_positions_*``) takes the kinds from
-        here; every layer of the llama block reads everything."""
-        return ((self.cfg.n_layers, None),)
 
     def flops_per_token(self, context_len: int) -> float:
         """Matmul FLOPs to process ONE token attending over ``context_len``
@@ -219,11 +150,6 @@ class DecoderLM(ServedModel):
             + 6.0 * D * F                # SwiGLU: gate, up, down
         )
         return cfg.n_layers * per_layer + 2.0 * D * cfg.vocab_size
-
-    def flops_per_row(self, seq_len: int = None) -> float:
-        """Full-forward FLOPs for one sequence (causal: average context T/2)."""
-        T = int(seq_len or self.example_input_shape[0])
-        return T * self.flops_per_token(T / 2.0)
 
     def n_params(self) -> int:
         """Exact parameter count of ``init_params``' pytree (closed form)."""
@@ -249,58 +175,6 @@ class DecoderLM(ServedModel):
         kv_bytes_per_tok_layer = 2 * cfg.n_kv_heads * cfg.head_dim * 2  # k+v, bf16
         cache_read = cfg.n_layers * kv_bytes_per_tok_layer * context_len
         return self.n_params() * param_bytes / max(1, batch) + cache_read
-
-    def kv_bytes_per_token(self) -> int:
-        """K+V bytes ONE cached position occupies across every layer
-        (bf16) — the per-(row, position) unit every read model below is
-        priced in, and the closed-form twin of the batcher's
-        ``_kv_key_bytes`` (which reads the live cache's dtypes/shapes)."""
-        cfg = self.cfg
-        return cfg.n_layers * 2 * cfg.n_kv_heads * cfg.head_dim * 2
-
-    def dispatch_read_bytes(
-        self,
-        kind: str,
-        *,
-        rows: int = 1,
-        live: int = None,
-        k: int = 1,
-        bucket: int = 0,
-        tokens: int = 0,
-        param_bytes: float = None,
-        kv_row_bytes: float = None,
-    ) -> float:
-        """Modeled HBM bytes READ by ONE warmed-executable dispatch of the
-        given kind — the static cost model the serving-time device-time
-        ledger attributes MBU with (``serving/profiler.py``), shared with
-        modelbench's offline MBU so live and bench numbers use one basis.
-
-        ``param_bytes``/``kv_row_bytes`` default to the unsharded bf16
-        closed forms; the batcher passes its live (shard-aware) values.
-        Decode-family bursts read the params once per step plus each
-        row's bucketed KV columns (``live``, how many of ``rows`` decode,
-        is for a family whose step reads by live lane: this one is priced
-        by its rows); prefill-family dispatches read the
-        params once and write (not read) their KV, so params dominate;
-        splice/extract move ``tokens`` cache positions; a swap cast
-        touches every param byte once."""
-        if param_bytes is None:
-            param_bytes = self.n_params() * 2.0
-        if kv_row_bytes is None:
-            kv_row_bytes = float(self.kv_bytes_per_token())
-        if kind in ("decode_burst", "fused_burst"):
-            return k * (param_bytes + rows * bucket * kv_row_bytes)
-        if kind == "spec_burst":
-            # verify chunk: one full forward over gamma+1 positions per
-            # lane; drafts are priced by the caller (their params differ)
-            return k * (param_bytes + rows * bucket * kv_row_bytes)
-        if kind in ("prefill", "chunk_prefill", "replay"):
-            return param_bytes + tokens * kv_row_bytes
-        if kind in ("splice", "insert", "extract"):
-            return tokens * kv_row_bytes
-        if kind == "swap_cast":
-            return param_bytes
-        return 0.0
 
     # ------------------------------------------------------------------
     # params
@@ -394,7 +268,7 @@ class DecoderLM(ServedModel):
                 o, ck, cv = decode_attention(
                     q, ck, cv, k, v, cache_pos, positions, lens,
                     attn_len=attn_len,
-                    mesh=getattr(self, "_serving_mesh", None),
+                    mesh=self._serving_mesh,
                 )
             else:
                 if getattr(cache_pos, "ndim", 0):
@@ -425,34 +299,21 @@ class DecoderLM(ServedModel):
             o = lax.psum(o, tp_axis)
         return o, new_cache
 
-    @staticmethod
-    def _cache_write(cache, new, positions):
-        """The ragged cache write by scatter: ``new`` [B, KV, W, Dh] lands
-        in ``cache`` [B, KV, T, Dh] at ``positions`` [B, W]; a position
-        outside [0, T) is DROPPED. It is
-        ``ops.decode_attention.cache_write`` (why its window is one ``Dh``
-        row is told there). The speculative and chunked windows, prefix
-        prefill and the stacked scan write through this; the ragged
-        single-position step hands its row to ``ops.decode_attention()``,
-        which on a TPU lands it from inside the read's kernel."""
-        from ..ops.decode_attention import cache_write
-
-        return cache_write(cache, new, positions)
-
-    @staticmethod
-    def _cache_read(ck, cv, attn_len):
-        """The ONE narrowed cache read: the prefix the scheduler proved can
-        hold keys (``attn_len``: a STATIC bucket >= every lane's position
-        + 1, so one executable per bucket; ``None`` reads it all). The
-        write above always addresses the full cache — only the read
-        narrows. The slice is an operand of ``_cache_attention``'s two
-        dots, not an array of its own."""
-        from jax import lax
-
-        if attn_len is None or attn_len >= ck.shape[2]:
-            return ck, cv
-        return (lax.slice_in_dim(ck, 0, attn_len, axis=2),
-                lax.slice_in_dim(cv, 0, attn_len, axis=2))
+    def _qkv(self, p, x, positions):
+        """A layer's input norm, its three projections and the rotary
+        embedding at ``positions``: q [B, Hl, T, Dh], k and v [B, KVl, T,
+        Dh] (the local heads under tp)."""
+        cfg = self.cfg
+        dt = x.dtype
+        B, T, _ = x.shape
+        h = _rms_norm(x, p["ln1"].astype(dt), cfg.norm_eps)
+        q = h @ p["wq"].astype(dt)
+        k = h @ p["wk"].astype(dt)
+        v = h @ p["wv"].astype(dt)
+        q, k, v = (a.reshape(B, T, -1, cfg.head_dim).transpose(0, 2, 1, 3)
+                   for a in (q, k, v))
+        return (_rope(q, positions, cfg.rope_theta),
+                _rope(k, positions, cfg.rope_theta), v)
 
     @staticmethod
     def _cache_attention(q, kc, vc, bound, dt):
@@ -546,110 +407,10 @@ class DecoderLM(ServedModel):
 
     # ------------------------------------------------------------------
     # KV-cache generate (single chip; engine-side continuous batching sits
-    # in front of this via graph/batching.py)
+    # in front of this via graph/batching.py). The cache's layout, its
+    # write and narrowed read, and ``decode_step_cache`` are the
+    # interface's defaults (models/family.py)
     # ------------------------------------------------------------------
-
-    def init_cache(self, batch: int, max_seq: Optional[int] = None):
-        import jax.numpy as jnp
-
-        cfg = self.cfg
-        T = max_seq or cfg.max_seq
-        shape = (cfg.n_layers, batch, cfg.n_kv_heads, T, cfg.head_dim)
-        dt = jnp.dtype(cfg.dtype)
-        return {"k": jnp.zeros(shape, dt), "v": jnp.zeros(shape, dt)}
-
-    def cache_layers(self, batch: int, max_seq: Optional[int] = None):
-        """The cache as the serving bursts carry it: a dict of kinds ("k"
-        and "v"; a family may declare more), each a list over the layers
-        that have the kind of one array whose first axis is the lane."""
-        return {
-            name: [kind[l] for l in range(kind.shape[0])]
-            for name, kind in self.init_cache(batch, max_seq).items()
-        }
-
-    # -- what the scheduler asks of a cache it did not lay out ------------
-    # (serving/continuous.py names no kind and no family: these say what a
-    # position costs and how a burst reads it)
-
-    def position_layers(self, cache):
-        """The arrays of ``cache_layers``' dict that hold one row a
-        position, positions along the second-to-last axis (a lane's
-        recurrent state, which has no position axis, is not among them):
-        what a decode step writes one row each of."""
-        return [*cache["k"], *cache["v"]]
-
-    def cache_position_bytes(self, cache) -> int:
-        """Bytes ONE cached position occupies over every layer of
-        ``cache``, by the live arrays' dtypes and shapes (an array's bytes
-        over its lanes and positions: ``itemsize x KV x Dh`` of a [S, KV,
-        T, Dh] array): the unit of the modeled burst read and of the
-        pressure ledger."""
-        return sum(a.nbytes // (a.shape[0] * a.shape[-2])
-                   for a in self.position_layers(cache))
-
-    def park_index(self, cache) -> int:
-        """The write position the stop-aware burst gives a lane that must
-        write nothing: past the end of every kind's positions, so that the
-        scatter and the kernel drop the row (a family whose kinds differ in
-        length, or wrap, says where that is)."""
-        return self.position_layers(cache)[0].shape[-2]
-
-    def lane_cache_bytes(self, cache):
-        """``positions -> bytes``: what a lane that holds ``positions``
-        positions occupies of ``cache`` over every layer, the pressure
-        ledger's and the insert records' price. One row a position here;
-        a family whose position costs more in one kind than in another
-        prices its own."""
-        per_position = self.cache_position_bytes(cache)
-        return lambda positions: positions * per_position
-
-    def prefill_lengths(self, buckets, max_seq: int):
-        """Of the batcher's prompt buckets (ascending), the padded lengths
-        this family's ``prefill`` takes; a prompt past the last goes to
-        ``max_seq``. Every length here."""
-        return tuple(buckets)
-
-    def prefill_rows_max(self, bucket: int) -> int:
-        """The most prompts one batched prefill in ``bucket`` takes."""
-        return 8
-
-    def admissions_per_turn(self) -> int:
-        """The most prompts the scheduler admits between two decode bursts;
-        0 for every free lane, so that one batched prefill takes them
-        together. A family whose prefill holds the device for long says
-        fewer, and the live lanes decode between two of them."""
-        return 0
-
-    def prefill_slab_bytes(self, rows: int, bucket: int) -> int:
-        """Bytes of the slab a batched prefill of ``rows`` prompts in
-        ``bucket`` returns (a transient beside params and cache)."""
-        cfg = self.cfg
-        return (2 * cfg.n_layers * rows * cfg.n_kv_heads * bucket
-                * cfg.head_dim * 2)
-
-    def burst_reads_ragged(self, cache, mesh=None) -> bool:
-        """Whether the decode step over ``cache``, lowered for the platform
-        its arrays live on, bounds each lane's read by the lane's own
-        length (``ops.decode_attention.reads_ragged``): a burst then needs
-        no bucket and no executable per bucket."""
-        import jax.numpy as jnp
-
-        from ..ops.decode_attention import reads_ragged
-
-        layer0 = cache["k"][0]
-        return reads_ragged(
-            next(iter(layer0.devices())).platform,
-            (layer0.shape[0], self.cfg.n_heads, 1, layer0.shape[3]),
-            layer0.shape,
-            (jnp.dtype(self.cfg.dtype), layer0.dtype, cache["v"][0].dtype),
-            mesh,
-        )
-
-    def _embed_tokens(self, params, tokens):
-        import jax.numpy as jnp
-
-        dt = jnp.dtype(self.cfg.dtype)
-        return params["embed"][tokens.astype(jnp.int32)].astype(dt)
 
     def _decode_layer(self, layer_p, x, positions, ck, cv, cache_pos, attn_len,
                       lens=None):
@@ -805,14 +566,6 @@ class DecoderLM(ServedModel):
             tie = (nks[-1], nvs[-1])
         return self._decode_head(params, x), nks, nvs
 
-    def decode_step_cache(self, params, cache, tokens, pos, **how):
-        """``decode_step_ragged_list`` over the dict ``cache_layers`` lays
-        out: ``(logits, cache, *counts)``, the one step the serving bursts
-        call whatever kinds a family's cache holds."""
-        logits, ks, vs, *counts = self.decode_step_ragged_list(
-            params, cache["k"], cache["v"], tokens, pos, **how)
-        return (logits, {"k": ks, "v": vs}, *counts)
-
     def decode_chunk_ragged_list(self, params, ks, vs, tokens, pos, attn_len=None):
         """Decode a WINDOW of tokens per lane in ONE forward over the
         unstacked cache: ``tokens`` [B, W], ``pos`` [B] start positions —
@@ -847,17 +600,8 @@ class DecoderLM(ServedModel):
         nvs: list = []
         for l in range(len(ks)):
             p = jax.tree_util.tree_map(lambda a, l=l: a[l], blocks)
-            h = _rms_norm(x, p["ln1"].astype(dt), cfg.norm_eps)
-            q = h @ p["wq"].astype(dt)
-            k = h @ p["wk"].astype(dt)
-            v = h @ p["wv"].astype(dt)
-            Hl = q.shape[-1] // cfg.head_dim
-            KVl = k.shape[-1] // cfg.head_dim
-            q = q.reshape(B, W, Hl, cfg.head_dim).transpose(0, 2, 1, 3)
-            k = k.reshape(B, W, KVl, cfg.head_dim).transpose(0, 2, 1, 3)
-            v = v.reshape(B, W, KVl, cfg.head_dim).transpose(0, 2, 1, 3)
-            q = _rope(q, positions, cfg.rope_theta)
-            k = _rope(k, positions, cfg.rope_theta)
+            q, k, v = self._qkv(p, x, positions)
+            Hl = q.shape[1]
             # the whole window lands first: ck[b,:,pos[b]+j,:] = k[b,:,j,:]
             ck = self._cache_write(ks[l], k, positions)
             cv = self._cache_write(vs[l], v, positions)
@@ -919,17 +663,8 @@ class DecoderLM(ServedModel):
 
         def body(x, xs):
             p, pk, pv = xs  # pk/pv: [1, KV, B, Dh]
-            h = _rms_norm(x, p["ln1"].astype(dt), cfg.norm_eps)
-            q = h @ p["wq"].astype(dt)
-            k = h @ p["wk"].astype(dt)
-            v = h @ p["wv"].astype(dt)
-            Hl = q.shape[-1] // cfg.head_dim
-            KVl = k.shape[-1] // cfg.head_dim
-            q = q.reshape(B, C, Hl, cfg.head_dim).transpose(0, 2, 1, 3)
-            k = k.reshape(B, C, KVl, cfg.head_dim).transpose(0, 2, 1, 3)
-            v = v.reshape(B, C, KVl, cfg.head_dim).transpose(0, 2, 1, 3)
-            q = _rope(q, positions, cfg.rope_theta)
-            k = _rope(k, positions, cfg.rope_theta)
+            q, k, v = self._qkv(p, x, positions)
+            Hl = q.shape[1]
             ck = lax.dynamic_update_slice(pk, k, (0, 0, start_pos, 0))
             cv = lax.dynamic_update_slice(pv, v, (0, 0, start_pos, 0))
             gk, gv = self._cache_read(ck, cv, attn_len)
@@ -947,11 +682,8 @@ class DecoderLM(ServedModel):
         if not want_logits:
             return None, new_slab
         x = _rms_norm(x, params["ln_f"].astype(dt), cfg.norm_eps)
-        if last_index is None:
-            x_last = x[:, -1]
-        else:
-            x_last = x[jnp.arange(B), jnp.asarray(last_index, jnp.int32)]
-        logits = (x_last @ params["unembed"].astype(dt)).astype(jnp.float32)
+        logits = (self._last_rows(x, last_index)
+                  @ params["unembed"].astype(dt)).astype(jnp.float32)
         return logits, new_slab
 
     def prefill_with_prefix(self, params, prefix_kv, tokens, start_pos,
@@ -993,17 +725,8 @@ class DecoderLM(ServedModel):
 
         def body(x, xs):
             layer_p, pk, pv = xs  # pk/pv: [1, KV, Tp, Dh]
-            h = _rms_norm(x, layer_p["ln1"].astype(dt), cfg.norm_eps)
-            q = h @ layer_p["wq"].astype(dt)
-            k = h @ layer_p["wk"].astype(dt)
-            v = h @ layer_p["wv"].astype(dt)
-            Hl = q.shape[-1] // cfg.head_dim
-            KVl = k.shape[-1] // cfg.head_dim
-            q = q.reshape(B, W, Hl, cfg.head_dim).transpose(0, 2, 1, 3)
-            k = k.reshape(B, W, KVl, cfg.head_dim).transpose(0, 2, 1, 3)
-            v = v.reshape(B, W, KVl, cfg.head_dim).transpose(0, 2, 1, 3)
-            q = _rope(q, positions, cfg.rope_theta)
-            k = _rope(k, positions, cfg.rope_theta)
+            q, k, v = self._qkv(layer_p, x, positions)
+            Hl, KVl = q.shape[1], k.shape[1]
             # W-extended combined cache: start_pos <= Tp always (the slab
             # covers at least the match), so the traced-start splice never
             # clamps
@@ -1026,11 +749,8 @@ class DecoderLM(ServedModel):
             body, x, (params["blocks"], prefix_kv["k"], prefix_kv["v"])
         )
         x = _rms_norm(x, params["ln_f"].astype(dt), cfg.norm_eps)
-        if last_index is None:
-            x_last = x[:, -1]
-        else:
-            x_last = x[jnp.arange(B), jnp.asarray(last_index, jnp.int32)]
-        logits = (x_last @ params["unembed"].astype(dt)).astype(jnp.float32)
+        logits = (self._last_rows(x, last_index)
+                  @ params["unembed"].astype(dt)).astype(jnp.float32)
         return logits, self._tp_slab({"k": sk, "v": sv})
 
     def prefill(self, params, prompt, max_seq: int, last_index=None):
@@ -1054,17 +774,8 @@ class DecoderLM(ServedModel):
         positions = jnp.arange(Tp)
 
         def body(x, layer_p):
-            h = _rms_norm(x, layer_p["ln1"].astype(dt), cfg.norm_eps)
-            q = h @ layer_p["wq"].astype(dt)
-            k = h @ layer_p["wk"].astype(dt)
-            v = h @ layer_p["wv"].astype(dt)
-            Hl = q.shape[-1] // cfg.head_dim
-            KVl = k.shape[-1] // cfg.head_dim
-            q = q.reshape(B, Tp, Hl, cfg.head_dim).transpose(0, 2, 1, 3)
-            k = k.reshape(B, Tp, KVl, cfg.head_dim).transpose(0, 2, 1, 3)
-            v = v.reshape(B, Tp, KVl, cfg.head_dim).transpose(0, 2, 1, 3)
-            q = _rope(q, positions, cfg.rope_theta)
-            k = _rope(k, positions, cfg.rope_theta)
+            q, k, v = self._qkv(layer_p, x, positions)
+            Hl, KVl = q.shape[1], k.shape[1]
             kr, vr = k, v
             if KVl < Hl:
                 rep = Hl // KVl
@@ -1077,7 +788,7 @@ class DecoderLM(ServedModel):
 
             o = prefill_attention(
                 q, kr, vr, causal=True,
-                mesh=getattr(self, "_serving_mesh", None),
+                mesh=self._serving_mesh,
             )
             o = o.transpose(0, 2, 1, 3).reshape(B, Tp, Hl * cfg.head_dim)
             x = x + o @ layer_p["wo"].astype(dt)
@@ -1090,11 +801,8 @@ class DecoderLM(ServedModel):
 
         x, (ck, cv) = lax.scan(body, x, params["blocks"])
         x = _rms_norm(x, params["ln_f"].astype(dt), cfg.norm_eps)
-        if last_index is None:
-            x_last = x[:, -1]
-        else:
-            x_last = x[jnp.arange(B), last_index.astype(jnp.int32)]
-        logits = (x_last @ params["unembed"].astype(dt)).astype(jnp.float32)
+        logits = (self._last_rows(x, last_index)
+                  @ params["unembed"].astype(dt)).astype(jnp.float32)
         return logits, self._tp_slab({"k": ck, "v": cv})
 
     def generate(self, params, prompt, max_new_tokens: int, temperature: float = 0.0, seed: int = 0):
@@ -1150,40 +858,6 @@ class DecoderLM(ServedModel):
         logits = (x @ params["unembed"].astype(dt)).astype(jnp.float32)
         ce = optax.softmax_cross_entropy_with_integer_labels(logits, tokens[:, 1:])
         return ce.mean() + cfg.aux_loss_weight * aux
-
-    @staticmethod
-    def params_swappable(old, new) -> "Tuple[bool, str]":
-        """Whether ``new`` can replace ``old`` under live serving without
-        recompiling a single executable: the jitted prefill/decode/burst
-        functions are specialized on the param pytree's STRUCTURE and
-        every leaf's shape+dtype, so a hot-swap (continuous batching's
-        ``request_weight_swap``) is only sound when both match leaf for
-        leaf. Returns ``(ok, reason)`` — reason names the first offender
-        so a wrong-checkpoint swap fails with an actionable message
-        instead of an XLA retrace mid-traffic."""
-        import jax
-
-        old_leaves, old_def = jax.tree_util.tree_flatten(old)
-        new_leaves, new_def = jax.tree_util.tree_flatten(new)
-        if old_def != new_def:
-            return False, (
-                "param tree structure differs (different architecture or "
-                "checkpoint family)"
-            )
-        paths = [
-            jax.tree_util.keystr(p)
-            for p, _ in jax.tree_util.tree_flatten_with_path(old)[0]
-        ]
-        for path, a, b in zip(paths, old_leaves, new_leaves):
-            sa = getattr(a, "shape", None)
-            sb = getattr(b, "shape", None)
-            if sa != sb:
-                return False, f"{path}: shape {sb} != served {sa}"
-            da = getattr(a, "dtype", None)
-            db = getattr(b, "dtype", None)
-            if da != db:
-                return False, f"{path}: dtype {db} != served {da}"
-        return True, ""
 
     def input_sharding(self, mesh):
         from jax.sharding import NamedSharding, PartitionSpec as P
@@ -1251,7 +925,7 @@ class DecoderLM(ServedModel):
         """Constrain every leaf of ``tree`` to full replication — the
         exact entry all-gather of the serving mesh mode. No-op when no
         serving mesh is armed."""
-        mesh = getattr(self, "_serving_mesh", None)
+        mesh = self._serving_mesh
         if mesh is None:
             return tree
         import jax
@@ -1273,7 +947,7 @@ class DecoderLM(ServedModel):
         the compute into partial-sum tensor parallelism, which is
         exactly the reduction reordering this mode exists to avoid.
         No-op unmeshed."""
-        mesh = getattr(self, "_serving_mesh", None)
+        mesh = self._serving_mesh
         if mesh is None:
             return arr
         import jax
@@ -1285,7 +959,7 @@ class DecoderLM(ServedModel):
         return jax.lax.with_sharding_constraint(
             arr,
             self.cache_sharding(
-                mesh, shard_seq=getattr(self, "_serving_shard_seq", False)
+                mesh, shard_seq=self._serving_shard_seq
             ),
         )
 
@@ -1294,7 +968,7 @@ class DecoderLM(ServedModel):
         back to the sharded staging layout at executable exit, pinning
         each leaf replicated first to stop backward propagation into
         the compute (see :meth:`_tp_cache`). No-op unmeshed."""
-        mesh = getattr(self, "_serving_mesh", None)
+        mesh = self._serving_mesh
         if mesh is None:
             return tree
         import jax

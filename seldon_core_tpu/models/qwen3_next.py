@@ -1,11 +1,14 @@
 """Qwen3NextLM: Gated DeltaNet layers beside gated softmax attention, and a
-chip's share of softmax-routed experts (``LLMConfig.block ==
-"qwen3_next"``; Qwen publishes the family as ``model_type: qwen3_next``).
+chip's share of softmax-routed experts (Qwen publishes the family as
+``model_type: qwen3_next``).
 
-``DecoderLM(block="qwen3_next", ...)`` builds this class. With the other
-blocks it shares the embedding lookup, ``_rms_norm``, ``_rope``, the KV
-cache's layout and its ops (``ops.decode_attention``, the flash kernel)
-and the routed experts (``ops/experts.py``). Every layer is
+A ``DecoderFamily`` (``models/family.py``: the interface the scheduler and
+the server ask), registered there as ``"qwen3_next"``:
+``DecoderLM(block="qwen3_next", ...)`` and ``Qwen3NextLM(...)`` build it,
+over a ``Qwen3NextConfig``. With the other blocks it shares the embedding
+lookup, ``_rms_norm``, ``_rope``, the KV cache's ops
+(``ops.decode_attention``, the flash kernel) and the routed experts
+(``ops/experts.py``). Every layer is
 
     h = x + Mixer(N_in(x));   y = h + MoE(N_post(h))
 
@@ -46,11 +49,13 @@ a snapshot of the state here, and has none yet.
 
 from __future__ import annotations
 
+import dataclasses
 from typing import Tuple
 
 import numpy as np
 
-from .llm import DecoderLM, UnsupportedByModel, _rms_norm, _rope
+from .family import DecoderFamily
+from .llm import LLMConfig, _rms_norm, _rope
 
 LINEAR, FULL = "linear_attention", "full_attention"
 _NEEDS_SNAPSHOT = (
@@ -59,7 +64,23 @@ _NEEDS_SNAPSHOT = (
     "position, and none is kept")
 
 
-class Qwen3NextLM(DecoderLM):
+@dataclasses.dataclass
+class Qwen3NextConfig(LLMConfig):
+    """The shared fields (``layer_types``: "linear_attention" |
+    "full_attention" a layer; the routed experts', ``experts_held``) and
+    this family's own."""
+    block: str = "qwen3_next"
+    linear_key_heads: int = 0
+    linear_value_heads: int = 0
+    linear_key_dim: int = 0
+    linear_value_dim: int = 0
+    linear_conv_kernel: int = 0
+    partial_rotary_factor: float = 1.0   # share of a head's dims rotated
+    shared_expert_width: int = 0
+
+
+class Qwen3NextLM(DecoderFamily):
+    config_class = Qwen3NextConfig
     step_counter_names = (
         # per decode step, summed over the layers: distinct held experts
         # some live lane picked, (lane, pick) pairs routed over ALL
@@ -344,10 +365,6 @@ class Qwen3NextLM(DecoderLM):
                       for _ in range(self._n_linear)],
         }
 
-    def cache_layers(self, batch: int, max_seq=None):
-        """``init_cache`` is laid out by kind and layer already."""
-        return self.init_cache(batch, max_seq)
-
     # -- the layers ----------------------------------------------------------------
 
     def _norm(self, x, w):
@@ -450,37 +467,20 @@ class Qwen3NextLM(DecoderLM):
         import jax
         import jax.numpy as jnp
 
-        from ..ops import experts
+        from ..ops.experts import routed_ffn
 
         cfg = self.cfg
         dt = h.dtype
         B, T, D = h.shape
         m = self._norm(h, p["ln_post"])
-        rows = m.reshape(B * T, D)
-        picks, weights = experts.route(
-            rows, p["router"], None, cfg.experts_per_tok, 1.0, score="softmax")
-        stacks = tuple(p[n].astype(dt) for n in ("we1", "we3", "we2"))
-        if live is None:
-            sent = picks
-            if real is not None and cfg.experts_held is not None:
-                # a row of padding computes nothing that is read, and
-                # padding routes together: a bucket's worth of it on a
-                # held expert would overflow the share's room. Its picks
-                # go to no expert's id, which no share holds
-                sent = jnp.where(real.reshape(-1, 1), picks,
-                                 cfg.n_routed_experts)
-            y, counts = experts.grouped_experts(
-                rows, sent, weights, *stacks, held=cfg.experts_held,
-                n_routed=cfg.n_routed_experts,
-                mesh=getattr(self, "_serving_mesh", None))
-        else:
-            y, touched, routed = experts.decode_experts(
-                rows, picks, weights, live, *stacks,
-                mesh=getattr(self, "_serving_mesh", None),
-                held=cfg.experts_held)
-            lo, n = cfg.experts_held or (0, cfg.n_routed_experts)
-            here = (picks >= lo) & (picks < lo + n) & live[:, None]
-            counts = (touched, routed, here.sum(dtype=jnp.int32))
+        # padding routes together: a bucket's worth of it on a held expert
+        # would overflow the share's room, so a share sends it nowhere
+        y, picks, counts = routed_ffn(
+            m.reshape(B * T, D), p["router"], None, cfg.experts_per_tok, 1.0,
+            "softmax", tuple(p[n].astype(dt) for n in ("we1", "we3", "we2")),
+            live=live, real=real, held=cfg.experts_held,
+            n_routed=cfg.n_routed_experts, mesh=self._serving_mesh,
+            redirect_pads=cfg.experts_held is not None)
         shared = (jax.nn.silu(m @ p["ws1"].astype(dt))
                   * (m @ p["ws3"].astype(dt))) @ p["ws2"].astype(dt)
         gate = jax.nn.sigmoid(jnp.dot(
@@ -494,11 +494,7 @@ class Qwen3NextLM(DecoderLM):
 
         dt = x.dtype
         if not every:
-            if last_index is None:
-                x = x[:, -1]
-            else:
-                x = x[jnp.arange(x.shape[0]),
-                      jnp.asarray(last_index, jnp.int32)]
+            x = self._last_rows(x, last_index)
         x = self._norm(x, params["ln_f"])
         return (x @ params["unembed"].astype(dt)).astype(jnp.float32)
 
@@ -535,7 +531,7 @@ class Qwen3NextLM(DecoderLM):
                 q, k, v = self._delta_heads(u)
                 o, state = gated_delta.gated_delta_prefill(
                     q, k, v, g, beta, lens,
-                    mesh=getattr(self, "_serving_mesh", None))
+                    mesh=self._serving_mesh)
                 x = x + self._delta_out(p, o, z)
                 leaves["conv"].append(tail)
                 leaves["state"].append(state)
@@ -615,7 +611,7 @@ class Qwen3NextLM(DecoderLM):
         wp = pos if write_pos is None else write_pos.astype(jnp.int32)
         lens = pos + 1 if lens is None else lens.astype(jnp.int32)
         live = lens > 0
-        mesh = getattr(self, "_serving_mesh", None)
+        mesh = self._serving_mesh
         x = self._embed_tokens(params, tokens)  # [B, 1, D]
         new = {name: [] for name in cache}
         picked = []
@@ -652,39 +648,3 @@ class Qwen3NextLM(DecoderLM):
             touched, routed, jnp.int32(len(self._linear)), held,
             live.sum(dtype=jnp.int32) * self._n_linear])
         return self._head(params, x), new, counts, picked
-
-    # -- what this family does not serve ------------------------------------------------------
-
-    def _no(self, what: str):
-        raise UnsupportedByModel(
-            f"the qwen3_next block has no {what}: it serves through "
-            "prefill and decode_step_cache")
-
-    def backbone(self, *a, **kw):
-        self._no("stacked-scan backbone (training, tp / sp / pp / ep)")
-
-    def loss_fn(self, *a, **kw):
-        self._no("loss (serving only)")
-
-    def _decode(self, *a, **kw):
-        self._no("stacked-cache decode step (decode_step, "
-                 "decode_step_ragged, generate)")
-
-    def decode_step_ragged_list(self, *a, **kw):
-        self._no("k/v-only decode step: its cache holds more kinds "
-                 "(decode_step_cache)")
-
-    def decode_chunk_ragged_list(self, *a, **kw):
-        self._no("window of positions over a cache: "
-                 + self.serving_refuses["preemption"])
-
-    def prefill_chunk(self, *a, **kw):
-        self._no("chunked prefill: " + self.serving_refuses["chunked_prefill"])
-
-    def prefill_with_prefix(self, *a, **kw):
-        self._no("prefix splice: " + self.serving_refuses["prefix_cache"])
-
-    def param_sharding(self, mesh, params):
-        raise UnsupportedByModel(
-            "the qwen3_next block has no serving mesh: "
-            + self.serving_refuses["mesh"])

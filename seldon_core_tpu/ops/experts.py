@@ -70,7 +70,14 @@ capacity drops; nothing here shares its code).
                        TPU it is ``grouped_experts()`` with idle lanes'
                        weights at zero
 
-Both return float32 sums over a row's picks (beside their counts); the
+``routed_ffn()``       a layer's routed experts from its normed rows: ``route()``,
+                       a prefill's pad rows sent to no expert, then
+                       ``grouped_experts()`` (a prefill) or
+                       ``decode_experts()`` (a step) and their counts: the
+                       one glue the expert families' layers call between
+                       their own norms, shared expert and residual
+
+All three return float32 sums over a row's picks (beside their counts); the
 caller casts.
 """
 
@@ -666,3 +673,36 @@ def decode_experts(x, picks, weights, live, w1, w3, w2, mesh=None, held=None):
     if not decodes_touched("tpu", x.shape, w1.shape, mesh):
         return grouped(*args), n, routed
     return lax.platform_dependent(*args, tpu=kernel, default=grouped), n, routed
+
+
+def routed_ffn(rows, router, bias, k: int, scale: float, score: str, stacks,
+               *, live, real, held, n_routed: int, mesh, redirect_pads: bool):
+    """A layer's routed experts over its normed rows [N, D]: ``route()``
+    by ``router``, ``bias``, ``k``, ``scale``, ``score``, then the ``stacks``
+    (``w1``, ``w3``, ``w2``; the chip's share where ``held`` is given, of the
+    ``n_routed`` the router ranges over). Returns ``(float32 [N, D], picks
+    [N, k] over ALL experts, counts)``.
+
+    ``live`` None, a prefill: ``grouped_experts()`` and its
+    ``GROUPED_COUNTS``. Of the rows ``real`` (bool, or None: all) says are no
+    sequence's tokens, the picks go to ``n_routed``, no expert's id, where
+    ``redirect_pads``: padding computes nothing that is read, and it routes
+    together, so a bucket's worth of it would overflow a share's room (PR
+    39). The family says: always (afmoe), or under ``held`` (the shares).
+
+    ``live`` [N] bool, a decode step: ``decode_experts()`` and ``(experts
+    touched, rows routed, rows landed on a held expert)``, idle lanes in none."""
+    picks, weights = route(rows, router, bias, k, scale, score=score)
+    if live is None:
+        sent = picks
+        if real is not None and redirect_pads:
+            sent = jnp.where(real.reshape(-1, 1), picks, n_routed)
+        y, counts = grouped_experts(rows, sent, weights, *stacks, held=held,
+                                    n_routed=n_routed, mesh=mesh)
+    else:
+        y, touched, routed = decode_experts(rows, picks, weights, live, *stacks,
+                                            mesh=mesh, held=held)
+        lo, n = held or (0, n_routed)
+        here = (picks >= lo) & (picks < lo + n) & live[:, None]
+        counts = (touched, routed, here.sum(dtype=jnp.int32))
+    return y, picks, counts

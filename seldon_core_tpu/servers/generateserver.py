@@ -188,6 +188,7 @@ from concurrent.futures import TimeoutError as FuturesTimeout
 from typing import Any, Callable, Dict, Iterable, List, Optional, Sequence
 
 from ..analysis.roles import caller_thread
+from ..models.family import DecoderFamily
 from ..user_model import SeldonComponent
 from .jaxserver import JAXServer
 
@@ -472,23 +473,22 @@ class GenerateServer(SeldonComponent):
         self._model = server._model
         import jax.numpy as jnp
 
-        dt = jnp.dtype(getattr(self._model, "compute_dtype", "bfloat16"))
-        if dt != jnp.float32 and isinstance(params, dict):
-            params = self._cast_params_freeing_impl(params, dt)
-        if self._model is None or not hasattr(self._model, "decode_step_ragged"):
+        if not isinstance(self._model, DecoderFamily):
             raise RuntimeError(
-                f"model family {getattr(self._model, '__class__', None)} "
+                f"model family {type(self._model)} "
                 "does not support generate(); use family 'llm'"
             )
-        if hasattr(self._model, "check_serves"):
-            # before a mesh or a draft is built for a family that has no
-            # path for it (typed: models.llm.UnsupportedByModel)
-            self._model.check_serves(
-                speculation=self._speculate_tokens > 0,
-                mesh=self._mesh is not None or self._mesh_shape is not None,
-                kv_tier=self._host_kv_tier_bytes > 0,
-                migration=self._role != "unified",
-            )
+        dt = jnp.dtype(self._model.compute_dtype)
+        if dt != jnp.float32 and isinstance(params, dict):
+            params = self._cast_params_freeing_impl(params, dt)
+        # before a mesh or a draft is built for a family that has no
+        # path for it (typed: models.family.UnsupportedByModel)
+        self._model.check_serves(
+            speculation=self._speculate_tokens > 0,
+            mesh=self._mesh is not None or self._mesh_shape is not None,
+            kv_tier=self._host_kv_tier_bytes > 0,
+            migration=self._role != "unified",
+        )
         if self._mesh is None and self._mesh_shape is not None:
             # build the serving mesh from the knob: an injected mesh
             # object (the engine placement path) always wins, so a
@@ -536,15 +536,13 @@ class GenerateServer(SeldonComponent):
                 # early-exit self-draft: the first N layers of the served
                 # model (shared embed/head/norm, blocks sliced) — no second
                 # checkpoint, and the proposals improve with the model
-                import dataclasses as _dc
-
                 import jax
 
-                cfg = _dc.asdict(self._model.cfg)
-                cfg["n_layers"] = self._draft_layers
                 from ..models.llm import DecoderLM
 
-                draft_model = DecoderLM(**cfg)
+                draft_model = DecoderLM(**dict(
+                    dataclasses.asdict(self._model.cfg),
+                    n_layers=self._draft_layers))
                 draft_params = {
                     **params,
                     "blocks": jax.tree_util.tree_map(
@@ -684,8 +682,6 @@ class GenerateServer(SeldonComponent):
         executable set serves all tenants — THE scale-to-zero
         property), cast to the serving dtype before staging so page-in
         is decode+upload, never a cast."""
-        import dataclasses as _dc
-
         import jax.numpy as jnp
 
         from ..serving.weightpager import TenantScheduler, WeightPager
@@ -700,25 +696,18 @@ class GenerateServer(SeldonComponent):
             )
         v0 = pager.put(primary, primary_params, primary_slo)
         pager.mark_resident(primary)
-        dt = jnp.dtype(getattr(self._model, "compute_dtype", "bfloat16"))
-        served_cfg = _dc.asdict(self._model.cfg)
-        served_cfg.pop("residual_scale", None)
+        dt = jnp.dtype(self._model.compute_dtype)
         for name, slo, uri in self._tenant_spec[1:]:
             server = JAXServer(uri or self.model_uri)
             _apply, params = server.build()
             other = server._model
-            if other is None or not hasattr(other, "cfg"):
+            if not isinstance(other, DecoderFamily):
                 raise ValueError(
                     f"tenant {name!r} checkpoint at {uri!r} is not an "
                     "llm-family model dir"
                 )
-            other_cfg = _dc.asdict(other.cfg)
-            other_cfg.pop("residual_scale", None)
-            if other_cfg != served_cfg:
-                changed = sorted(
-                    k for k in set(other_cfg) | set(served_cfg)
-                    if other_cfg.get(k) != served_cfg.get(k)
-                )
+            changed = self._model.config_differs(other)
+            if changed:
                 raise ValueError(
                     f"tenant {name!r} checkpoint architecture differs "
                     f"from the served model ({', '.join(changed)}); "
@@ -1591,29 +1580,20 @@ class GenerateServer(SeldonComponent):
         server = JAXServer(model_uri)
         _apply, params = server.build()
         new_model = server._model
-        if new_model is None or not hasattr(new_model, "cfg"):
+        if not isinstance(new_model, DecoderFamily):
             raise ValueError(
                 f"hot-swap checkpoint at {model_uri!r} is not an llm-family "
                 "model dir"
             )
-        old_cfg = dataclasses.asdict(self._model.cfg)
-        new_cfg = dataclasses.asdict(new_model.cfg)
-        # residual_scale only shapes synthetic INIT draws, not the forward
-        for skip in ("residual_scale",):
-            old_cfg.pop(skip, None)
-            new_cfg.pop(skip, None)
-        if old_cfg != new_cfg:
-            changed = sorted(
-                k for k in set(old_cfg) | set(new_cfg)
-                if old_cfg.get(k) != new_cfg.get(k)
-            )
+        changed = self._model.config_differs(new_model)
+        if changed:
             raise ValueError(
                 f"hot-swap checkpoint architecture differs from the served "
                 f"model ({', '.join(changed)}); same-shape checkpoints only"
             )
         import jax.numpy as jnp
 
-        dt = jnp.dtype(getattr(self._model, "compute_dtype", "bfloat16"))
+        dt = jnp.dtype(self._model.compute_dtype)
         if dt != jnp.float32 and isinstance(params, dict):
             params = self._cast_params_freeing_impl(params, dt)
         self._swap_count += 1

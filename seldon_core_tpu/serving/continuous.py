@@ -362,7 +362,8 @@ def _positions_windowed(pos: int, k: int, window: int, block: int) -> tuple:
 
 
 class ContinuousBatcher:
-    """Slot-based continuous batching scheduler over a DecoderLM.
+    """Slot-based continuous batching scheduler over a decoder family
+    (``models/family.py:DecoderFamily``: everything it asks of a model).
 
     ``submit()`` is thread-safe and returns a Future resolving to the
     generated token list. A single scheduler thread owns the device loop.
@@ -412,7 +413,7 @@ class ContinuousBatcher:
 
         self.model = model
         # a family refuses, typed and at load, the features it has no
-        # path for (DecoderLM.serving_refuses) rather than computing
+        # path for (DecoderFamily.serving_refuses) rather than computing
         # something else under them
         self._check_family_serves(
             speculation=draft_model is not None,
@@ -859,21 +860,9 @@ class ContinuousBatcher:
             the mesh + seq knob for the supervisor's crash-restart."""
             if mesh is None:
                 return None
-            if hasattr(model, "cache_sharding"):
-                return model.cache_sharding(
-                    mesh, kv_heads=kv_heads, shard_seq=shard_cache_seq
-                )
-            from jax.sharding import NamedSharding, PartitionSpec as P
-
-            model_ax = "model" if "model" in mesh.axis_names else None
-            seq_ax = (
-                "seq"
-                if shard_cache_seq and "seq" in mesh.axis_names and mesh.shape["seq"] > 1
-                else None
+            return model.cache_sharding(
+                mesh, kv_heads=kv_heads, shard_seq=shard_cache_seq
             )
-            if model_ax is not None and kv_heads % dict(mesh.shape)["model"] != 0:
-                model_ax = None
-            return NamedSharding(mesh, P(None, model_ax, seq_ax, None))
 
         def unstack_cache(owner, sharding):
             """The cache the bursts carry, as the model lays it out
@@ -900,7 +889,7 @@ class ContinuousBatcher:
             the target (embed/unembed/ln_f in generateserver's self-draft)
             stay one device array instead of casting into two copies
             (~262MB duplicated at the flagship config otherwise)."""
-            dt = jnp.dtype(getattr(model_, "compute_dtype", "bfloat16"))
+            dt = jnp.dtype(model_.compute_dtype)
             if dt == jnp.float32:
                 return p
 
@@ -916,13 +905,12 @@ class ContinuousBatcher:
 
         params = serving_cast(model, params)
         if mesh is not None:
-            if hasattr(model, "set_serving_mesh"):
-                # arm sharded-STORAGE / replicated-COMPUTE serving BEFORE
-                # any executable traces: every entry gathers params/cache
-                # to full replication (exact all-gather, no arithmetic) so
-                # the math is the byte-identical 1-device program, and
-                # every exit re-shards cache writes (models/llm.py)
-                model.set_serving_mesh(mesh, shard_seq=shard_cache_seq)
+            # arm sharded-STORAGE / replicated-COMPUTE serving BEFORE
+            # any executable traces: every entry gathers params/cache
+            # to full replication (exact all-gather, no arithmetic) so
+            # the math is the byte-identical 1-device program, and
+            # every exit re-shards cache writes (models/llm.py)
+            model.set_serving_mesh(mesh, shard_seq=shard_cache_seq)
             params = jax.device_put(params, model.param_sharding(mesh, params))
         self.params = params
         # the cast memo pins the boot params' cast leaves; a weight swap
@@ -938,9 +926,7 @@ class ContinuousBatcher:
         # resume, fresh chunked-prefill slab) lands pre-sharded through
         # _upload_slab so the insert/splice executables never reshard
         self._slab_sharding = (
-            model.slab_sharding(mesh)
-            if mesh is not None and hasattr(model, "slab_sharding")
-            else None
+            model.slab_sharding(mesh) if mesh is not None else None
         )
         # per-shard split factors for the pressure ledger: how many ways
         # the persistent cache's bytes divide across chips (model axis,
@@ -951,7 +937,7 @@ class ContinuousBatcher:
         if mesh is not None:
             mshape = dict(mesh.shape)
             tp = int(mshape.get("model", 1))
-            kvh = int(getattr(model.cfg, "n_kv_heads", 0) or 0)
+            kvh = int(model.cfg.n_kv_heads)
             if tp > 1 and kvh and kvh % tp == 0:
                 self._kv_model_shard = tp
             sq = int(mshape.get("seq", 1))
@@ -963,8 +949,7 @@ class ContinuousBatcher:
         if self.speculate_tokens > 0:
             dp = serving_cast(draft_model, draft_params)
             if mesh is not None:
-                if hasattr(draft_model, "set_serving_mesh"):
-                    draft_model.set_serving_mesh(mesh)
+                draft_model.set_serving_mesh(mesh)
                 dp = jax.device_put(dp, draft_model.param_sharding(mesh, dp))
             self._draft_params = dp
         self._alloc_device_state()
@@ -993,24 +978,22 @@ class ContinuousBatcher:
         # and _read_burst adds them into stats. A model with none (the
         # llama block) returns none and its bursts return what they
         # always did.
-        self._step_counters = tuple(getattr(model, "step_counter_names", ()))
+        self._step_counters = tuple(model.step_counter_names)
         # counters a model's prefill returns after its slab
         # (model.prefill_counter_names, prefill_counted): the insert that
         # follows a prefill adds them to a vector on the device
         # (_prefill_counts), which the next burst dispatched takes along
         # for _read_burst: no program and no wait of their own. A model
         # with none keeps the inserts it always had.
-        self._prefill_counters = tuple(
-            getattr(model, "prefill_counter_names", ()))
+        self._prefill_counters = tuple(model.prefill_counter_names)
         for name in self._step_counters + self._prefill_counters:
             self.stats.setdefault(name, 0)
         # the step counters whose writes the decode kernel lands itself
         # (model.step_counters_in_kernel, as kv_rows_written_in_kernel
         # shadows kv_rows_written): _read_burst adds each into a stats key
         # of its own. A family that names none gets no key.
-        in_kernel = getattr(model, "step_counters_in_kernel", None)
-        self._counters_in_kernel = (
-            in_kernel(self._cache, mesh) if in_kernel else {})
+        self._counters_in_kernel = model.step_counters_in_kernel(
+            self._cache, mesh)
         for name in self._counters_in_kernel:
             self.stats.setdefault(name, 0)
         run_prefill = (model.prefill_counted if self._prefill_counters
@@ -1370,9 +1353,8 @@ class ContinuousBatcher:
         self._kv_read_block = BLOCK
         # the windows of the model's layers that have one (the kinds come
         # from the model; the llama block has none)
-        kinds = model.attention_kinds() if hasattr(
-            model, "attention_kinds") else ()
-        self._kv_windows = tuple(w for _n, w in kinds if w is not None)
+        self._kv_windows = tuple(
+            w for _n, w in model.attention_kinds() if w is not None)
         # whether the decode step's read takes each lane's own length on
         # the platform the bursts are lowered for (the cache's devices).
         # Where it does, the bucket bounds nothing in _burst_fn and
@@ -2145,7 +2127,7 @@ class ContinuousBatcher:
                 f"model's {want} (prompt {n} -> bucket {bucket}, "
                 f"covered {covered})"
             )
-        dt = jnp.dtype(getattr(self.model, "compute_dtype", cfg.dtype))
+        dt = jnp.dtype(self.model.compute_dtype)
         if str(k.dtype) != str(dt):
             raise DisaggError(
                 f"slab dtype {k.dtype} vs serving compute dtype {dt} — "
@@ -2253,10 +2235,9 @@ class ContinuousBatcher:
 
     def _check_family_serves(self, **asked: bool) -> None:
         """A request that needs what the model's family has no path for
-        (``DecoderLM.serving_refuses``) is refused typed where it comes
+        (``DecoderFamily.serving_refuses``) is refused typed where it comes
         in, as the constructor refuses a setting."""
-        if hasattr(self.model, "check_serves"):
-            self.model.check_serves(**asked)
+        self.model.check_serves(**asked)
 
     @caller_thread
     def submit_checkpoint(self, ck: Dict[str, Any], on_tokens=None) -> Future:
@@ -2398,7 +2379,7 @@ class ContinuousBatcher:
                 "weight hot-swap is not supported with speculative decoding "
                 "(the draft shares or derives from the served params)"
             )
-        dt = jnp.dtype(getattr(self.model, "compute_dtype", "bfloat16"))
+        dt = jnp.dtype(self.model.compute_dtype)
         if dt != jnp.float32:
             with self._prof.measure(
                 "swap_cast", variant=str(dt),
@@ -2411,12 +2392,7 @@ class ContinuousBatcher:
                     params,
                 )
                 _m.sync(params)
-        from ..models.llm import DecoderLM
-
-        check = getattr(self.model, "params_swappable", None)
-        if check is None:
-            check = DecoderLM.params_swappable
-        ok, why = check(self.params, params)
+        ok, why = self.model.params_swappable(self.params, params)
         if not ok:
             raise ValueError(f"weight hot-swap rejected: {why}")
         if self.mesh is not None:
@@ -2933,7 +2909,7 @@ class ContinuousBatcher:
         # the prefill counters no burst has taken home yet: [] for a model
         # that names none, else [an int32 vector], which is these zeros
         # again (no insert consumes them) once a burst has taken it
-        n = len(getattr(self.model, "prefill_counter_names", ()))
+        n = len(self.model.prefill_counter_names)
         self._no_prefill_counts = [jnp.zeros((n,), jnp.int32)] if n else []
         self._prefill_counts = self._no_prefill_counts
         # per-lane stop tokens (-1 = no eos, never matches) and remaining
@@ -3496,7 +3472,7 @@ class ContinuousBatcher:
 
         cfg = self.model.cfg
         shape = (cfg.n_layers, 1, cfg.n_kv_heads, bucket, cfg.head_dim)
-        dt = jnp.dtype(getattr(self.model, "compute_dtype", cfg.dtype))
+        dt = jnp.dtype(self.model.compute_dtype)
         slab = {"k": jnp.zeros(shape, dt), "v": jnp.zeros(shape, dt)}
         if self._slab_sharding is not None:
             slab = {

@@ -11,6 +11,7 @@ import jax
 import jax.numpy as jnp
 
 from benchmark.reference import afmoe as reference
+from seldon_core_tpu.models.family import DecoderFamily
 from seldon_core_tpu.models.llm import DecoderLM, UnsupportedByModel
 from seldon_core_tpu.ops import experts
 from seldon_core_tpu.ops.decode_attention import (
@@ -48,7 +49,7 @@ def _err(got, ref, scale):
 
 def test_the_block_variant_builds_the_class_and_knows_its_kinds(served):
     model = served[0]
-    assert type(model).__name__ == "AfmoeLM" and isinstance(model, DecoderLM)
+    assert type(model).__name__ == "AfmoeLM" and isinstance(model, DecoderFamily)
     assert sorted(model.attention_kinds(), key=str) == [(1, None), (4, 256)]
     assert DecoderLM(d_model=256, n_heads=2).attention_kinds() == ((8, None),)
     assert model.cfg.head_dim == 128 != model.cfg.d_model // model.cfg.n_heads

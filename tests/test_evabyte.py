@@ -532,7 +532,8 @@ def test_the_batcher_mirrors_the_summaries_the_kernel_writes(
 
 def test_a_family_that_names_no_kernel_write_gets_no_key():
     m = DecoderLM(**FAMILIES["afmoe"])
-    assert not hasattr(m, "step_counters_in_kernel")
+    # the interface's default: no counter of this family is a kernel's write
+    assert m.step_counters_in_kernel(m.cache_layers(2, 128)) == {}
     b = ContinuousBatcher(m, m.init_params(0), slots=2, max_seq=128)
     try:
         assert b._counters_in_kernel == {}
