@@ -30,6 +30,7 @@ FAMILIES: Dict[str, str] = {
     "qwen3_next": "seldon_core_tpu.models.qwen3_next.Qwen3NextLM",
     "joyai_llm_flash": "seldon_core_tpu.models.joyai_llm_flash.JoyaiLLMFlashLM",
     "evabyte": "seldon_core_tpu.models.evabyte.EvaByteLM",
+    "sdar_moe": "seldon_core_tpu.models.sdar_moe.SdarMoeLM",
 }
 
 
@@ -234,6 +235,17 @@ class DecoderFamily(ServedModel):
         """The most prompts one batched prefill in ``bucket`` takes."""
         return 8
 
+    def block_tokens(self) -> int:
+        """Positions ONE decode step covers in a lane: 1, a token a lane and
+        step, everywhere but in a family that generates by blocks, whose
+        step is a pass over a block of this many positions
+        (``decode_block_cache``) that a lane's mask bits say are filled in
+        or not, and which the scheduler then drives through
+        ``block_unmask`` pass by pass until a block has no masked position,
+        one more pass commits its rows to the cache, and its tokens go to
+        the client. The cache's length is a multiple of it."""
+        return 1
+
     def admissions_per_turn(self) -> int:
         """The most prompts the scheduler admits between two decode bursts;
         0 for every free lane, so that one batched prefill takes them
@@ -423,6 +435,25 @@ class DecoderFamily(ServedModel):
         self._no("serving mesh", "mesh")
 
     set_serving_mesh = cache_sharding = slab_sharding = param_sharding
+
+    def decode_block_cache(self, *a, **kw):
+        """Generation by blocks (``block_tokens() > 1``): a pass over a block
+        of ``W`` positions a lane over the dict ``cache_layers`` lays out,
+        ``(params, cache, tokens [B, W], base [B], masked=None [B, W] bool,
+        attn_len=None, lens=None) -> (logits [B, W, V], cache, counts)``:
+        the block's rows land at ``base .. base + W - 1`` (``base`` a
+        multiple of ``W``; a later pass overwrites them, the pass over a
+        block with nothing masked, the commit, leaves them), every query
+        sees the lane's cache and the whole block, logits at each position
+        itself; ``counts`` the family's ``step_counter_names``. And
+        ``block_unmask(logits, tokens, masked, n_pass, alive, temps, keys,
+        any_stoch) -> (tokens, masked, keys, counts)``: how a pass fills
+        in: which masked positions of a lane take which token, by the
+        family's configuration; ``counts`` again the whole vector, which
+        the scheduler adds to the pass's."""
+        self._no("pass over a block of positions (generation by blocks)")
+
+    block_unmask = decode_block_cache
 
     # -- the llama block's own: the stacked scan, training, and the K/V-only
     # step ``decode_step_cache``'s default calls (a family of "k" and "v"

@@ -186,7 +186,7 @@ def _windowed_kernel(starts_ref, *refs, block):
 
 def _ragged_kernel(lens_ref, wpos_ref, q_ref, knew_ref, vnew_ref, _k_in, _v_in,
                    o_ref, k_hbm, v_hbm, kbuf, vbuf, kstage, vstage, sem, wsem,
-                   rsem, *, block, starts_ref=None):
+                   rsem, *, block, starts_ref=None, rows=1):
     """The whole batch of one layer: for each lane with ``len > 0``, walk
     its ``ceil(len / block)`` blocks with an online softmax, and where a
     block holds the lane's ``write_pos`` put the new row into it first
@@ -203,6 +203,13 @@ def _ragged_kernel(lens_ref, wpos_ref, q_ref, knew_ref, vnew_ref, _k_in, _v_in,
     group while its copy to the cache is in flight; sem: DMA semaphores
     [2 (k, v), 2] of the reads, wsem: [2 (k, v)] of the writes, rsem: [2
     (k, v)] of the fetch of a group that no block held.
+
+    ``rows`` > 1, a window of that many positions a lane (``GROUP`` is a
+    multiple of it, ``write_pos`` of the window's first a multiple of
+    ``rows``, so the window lies in one group): knew_ref / vnew_ref are
+    [B, KV, GROUP, Dh], the window's rows laid where they go in their
+    group, and the queries' ``rep`` counts every row of the window: all
+    of them see the same keys, the lane's ``len`` with the window in it.
     """
     n_lanes, n_kv, rep, dh = q_ref.shape
     t = k_hbm.shape[2]
@@ -233,6 +240,10 @@ def _ragged_kernel(lens_ref, wpos_ref, q_ref, knew_ref, vnew_ref, _k_in, _v_in,
     def patched(which, lane, wp, group):
         """``group`` [KV, GROUP, Dh] with the lane's new row at ``wp``."""
         row = lax.broadcasted_iota(jnp.int32, group.shape, 1)
+        if rows > 1:
+            at = wp % GROUP
+            return jnp.where((row >= at) & (row < at + rows),
+                             (knew_ref, vnew_ref)[which][lane], group)
         return jnp.where(
             row == wp % GROUP, (knew_ref, vnew_ref)[which][lane], group)
 
@@ -409,16 +420,27 @@ def ragged_decode_attention(q, k, v, lens, k_new, v_new, write_pos,
 
     ``starts`` ([B] int32, optional: a layer with a window): lane b
     attends to positions [starts[b], lens[b]) and copies only the blocks
-    that hold them; ``starts[b] < lens[b]`` wherever ``lens[b] > 0``."""
+    that hold them; ``starts[b] < lens[b]`` wherever ``lens[b] > 0``.
+
+    The second entry, a block of ``W`` positions a lane (q [B, H, W, Dh],
+    k_new, v_new [B, KV, W, Dh], ``W`` a divisor of ``GROUP``, no
+    ``starts``): the rows land at ``write_pos[b] .. + W - 1``
+    (``write_pos[b]`` a multiple of ``W``: the caller's to hold) and EVERY
+    query of the block attends to [0, lens[b]), the block's own rows among
+    them, which is attention that is open inside a block. The kernel is the
+    same walk with ``W`` times the query rows a KV head; in a trace its
+    name is ``block_decode_attention``."""
     b, h, t_q, dh = q.shape
     n_kv, t = k.shape[1], k.shape[2]
-    if t_q != 1 or h % n_kv or t % block or block % GROUP:
+    if GROUP % t_q or (t_q > 1 and starts is not None) or h % n_kv \
+            or t % block or block % GROUP:
         raise ValueError(
             f"q {q.shape} / cache {k.shape} do not fit the kernel "
-            f"(T == 1, H a multiple of KV, cache length a multiple of {block}"
-            f", block a multiple of {GROUP})"
+            f"(T == 1, or a divisor of {GROUP} and no starts; H a multiple "
+            f"of KV, cache length a multiple of {block}, block a multiple "
+            f"of {GROUP})"
         )
-    rep = h // n_kv
+    rep = h // n_kv * t_q
     smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
     hbm = pl.BlockSpec(memory_space=pl.ANY)
@@ -426,6 +448,15 @@ def ragged_decode_attention(q, k, v, lens, k_new, v_new, write_pos,
     if starts is not None:
         kernel = _windowed_kernel
         scalars = (jnp.clip(starts.astype(jnp.int32), 0, t),)
+    named = {}
+    if t_q > 1:
+        # [B, KV, W, Dh] -> [B, KV, GROUP, Dh]: row r holds the window's
+        # row r mod W, so the rows lie where they go wherever in its group
+        # the window starts
+        k_new, v_new = (jnp.tile(new, (1, 1, GROUP // t_q, 1))
+                        for new in (k_new, v_new))
+        kernel = functools.partial(kernel, rows=t_q)
+        named = {"name": "block_decode_attention"}
     out, k, v = pl.pallas_call(
         functools.partial(kernel, block=block),
         out_shape=(
@@ -446,11 +477,12 @@ def ragged_decode_attention(q, k, v, lens, k_new, v_new, write_pos,
             pltpu.SemaphoreType.DMA((2,)),
         ],
         interpret=interpret,
+        **named,
     )(*scalars,
       jnp.clip(lens.astype(jnp.int32), 0, t), write_pos.astype(jnp.int32),
       q.reshape(b, n_kv, rep, dh), k_new.astype(k.dtype),
       v_new.astype(v.dtype), k, v)
-    return out.reshape(b, h, 1, dh), k, v
+    return out.reshape(b, h, t_q, dh), k, v
 
 
 @functools.partial(jax.jit, static_argnames=("attn_len", "mesh"))
@@ -541,3 +573,59 @@ def _windowed_decode_attention(q, k, v, k_new, v_new, write_pos, pos, lens,
             "tpu", q.shape, k.shape, (q.dtype, k.dtype, v.dtype), mesh):
         return dots(*args)
     return lax.platform_dependent(*args, tpu=kernel, default=dots)
+
+
+def block_reads_ragged(platform, q_shape, cache_shape, dtypes, mesh=None) -> bool:
+    """``reads_ragged()`` for ``block_decode_attention()``: the kernel's
+    second entry takes a block of ``W = q_shape[2]`` positions a lane where
+    ``W`` divides ``GROUP`` (the block then lies in one group of the cache's
+    rows) and everything else is as the single position's."""
+    b, h, w, dh = q_shape
+    return GROUP % w == 0 and reads_ragged(
+        platform, (b, h, 1, dh), cache_shape, dtypes, mesh)
+
+
+@functools.partial(jax.jit, static_argnames=("attn_len", "mesh"))
+def block_decode_attention(q, k, v, k_new, v_new, base, lens, attn_len=None,
+                           mesh=None):
+    """A block of ``W`` positions a lane over one layer's cache, attention
+    open inside the block: the block's rows k_new, v_new [B, KV, W, Dh] go
+    into the UNSLICED cache k, v [B, KV, T, Dh] at ``base[b] .. + W - 1``
+    (``base`` [B], each a multiple of ``W``; a later pass over the same
+    block overwrites them), then every query of q [B, H, W, Dh] attends to
+    positions [0, base[b] + W). Returns ``(o, k, v)``. ``lens`` [B]:
+    ``base + W`` for a live lane, 0 for one that is idle or done: it reads
+    nothing, is given what nobody reads and WRITES NOTHING, under the
+    kernel and under the dots alike. ``attn_len`` / ``mesh``: as
+    ``decode_attention()`` takes them.
+
+    Under ``jax.named_scope("block_decode_attention")``, the kernel's own
+    name in a trace."""
+    t = k.shape[2]
+    w = q.shape[2]
+    bound = t if attn_len is None else min(int(attn_len), t)
+
+    def dots(q, k, v, k_new, v_new, base, lens):
+        at = jnp.where(lens[:, None] > 0,
+                       base[:, None] + jnp.arange(w, dtype=jnp.int32), t)
+        k = cache_write(k, k_new, at)
+        v = cache_write(v, v_new, at)
+        o = cache_attention(
+            q, lax.slice_in_dim(k, 0, bound, axis=2),
+            lax.slice_in_dim(v, 0, bound, axis=2),
+            jnp.broadcast_to(base[:, None] + (w - 1), (q.shape[0], w)),
+            q.dtype)
+        return o, k, v
+
+    def kernel(q, k, v, k_new, v_new, base, lens):
+        return ragged_decode_attention(
+            q, k, v, jnp.minimum(lens, bound), k_new, v_new, base,
+            block=BLOCK)
+
+    args = (q, k, v, k_new, v_new, base.astype(jnp.int32),
+            lens.astype(jnp.int32))
+    with jax.named_scope("block_decode_attention"):
+        if not block_reads_ragged(
+                "tpu", q.shape, k.shape, (q.dtype, k.dtype, v.dtype), mesh):
+            return dots(*args)
+        return lax.platform_dependent(*args, tpu=kernel, default=dots)

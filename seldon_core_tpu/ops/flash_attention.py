@@ -35,18 +35,36 @@ from jax.sharding import PartitionSpec as P
 NEG_INF = -1e30
 
 
-def _xla_attention(q, k, v, causal: bool, kv_len=None, window=None):
+def _xla_attention(q, k, v, causal: bool, kv_len=None, window=None,
+                   block=None):
     """Reference attention, same contract as the kernel — delegates to
     parallel/ring.full_attention so the fallback and the trained/ring
     paths share ONE copy of the math. ``window``: the kernel's band, as
-    the same masked dots."""
+    the same masked dots; ``block``: its mask that is open inside a block."""
     from ..parallel.ring import full_attention
 
-    if window is None:
+    if window is None and block is None:
         return full_attention(q, k, v, causal=causal, kv_len=kv_len)
-    if not causal or kv_len is not None:
-        raise ValueError("a window is causal and takes no kv_len")
+    if not causal or kv_len is not None or not (window is None or block is None):
+        raise ValueError("a window or a block is causal, takes no kv_len, "
+                         "and not the other")
+    if block is not None:
+        return _block_attention(q, k, v, block)
     return _banded_attention(q, k, v, window)
+
+
+def _block_attention(q, k, v, block: int):
+    """Attention causal over blocks of ``block`` positions (a power of two)
+    and open inside one: query i sees key j iff j <= i | (block - 1), the
+    last position of i's block. full_attention's math under that mask."""
+    scale = 1.0 / (q.shape[-1] ** 0.5)
+    s = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32),
+                   k.astype(jnp.float32)) * scale
+    row = jnp.arange(q.shape[2])[:, None]
+    col = jnp.arange(k.shape[2])[None, :]
+    s = jnp.where(((row | (block - 1)) >= col)[None, None], s, NEG_INF)
+    p = jax.nn.softmax(s, axis=-1)
+    return jnp.einsum("bhqk,bhkd->bhqd", p, v.astype(jnp.float32)).astype(q.dtype)
 
 
 def _banded_attention(q, k, v, window: int):
@@ -80,7 +98,7 @@ def _prefixed_attention(q, k, v, prefix: int, prefix_len):
 
 
 def _flash_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, *, block_q, block_k,
-                  causal, window=None, prefix=None):
+                  causal, window=None, prefix=None, block=None):
     """One (bh, q-block) program: stream K/V tiles with online softmax.
 
     q_ref: [1, block_q, Dh]; k_ref: [1, Tk, Dh]; v_ref: [1, Tk, Dv] and
@@ -108,7 +126,11 @@ def _flash_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, *, block_q, block_k,
     [1], scalar-prefetched): the first ``prefix`` keys are a prefix of
     which every row sees those below ``len_ref[0]``; the walk takes the
     prefix's ``ceil(len / block_k)`` tiles and then the causal ones, and a
-    prefix tile past the visible ones is never read.
+    prefix tile past the visible ones is never read. ``block`` (static, a
+    power of two that divides 128, with ``causal``, no window): the mask is
+    causal over blocks of that many positions and open inside one, row i
+    sees columns up to i | (block - 1); only the compare in the diagonal
+    tiles differs, a q-block's last row ends a block.
     """
     qb = pl.program_id(1)
     scale = 1.0 / np.sqrt(q_ref.shape[-1])
@@ -122,7 +144,7 @@ def _flash_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, *, block_q, block_k,
     row = row0 + lax.broadcasted_iota(jnp.int32, (block_q, 1), 0)
 
     def own(col):
-        seen = row >= col
+        seen = row >= col if block is None else (row | (block - 1)) >= col
         if window is not None:
             seen = seen & (col > row - window)
         return seen
@@ -199,7 +221,7 @@ def _flash_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, *, block_q, block_k,
 @functools.partial(
     jax.jit,
     static_argnames=("causal", "block_q", "block_k", "interpret", "window",
-                     "name", "prefix"),
+                     "name", "prefix", "block"),
 )
 def flash_attention(
     q,
@@ -213,6 +235,7 @@ def flash_attention(
     name=None,
     prefix=None,
     prefix_len=None,
+    block=None,
 ):
     """Pallas blocked attention. q [B,H,Tq,Dh], k [B,H,Tk,Dh], v
     [B,H,Tk,Dv] (Dv = Dh everywhere but latent attention's prefill).
@@ -225,9 +248,14 @@ def flash_attention(
     of 128) with ``prefix_len`` (a traced int32 scalar): k and v are [B, H,
     prefix + Tq, .], their first ``prefix`` rows a prefix every query sees
     the first ``prefix_len`` rows of, the others the queries' own
-    positions."""
+    positions. ``block`` (static int, causal only, no window): the mask is
+    causal over blocks of ``block`` positions and open inside one."""
     if window is not None and not causal:
         raise ValueError("a window is causal")
+    if block is not None and (not causal or window is not None
+                              or block & (block - 1) or 128 % block):
+        raise ValueError(f"a block of {block}: causal, no window, a power "
+                         "of two that divides 128")
     b, h, t_q, dh = q.shape
     t_k, dv = k.shape[2], v.shape[-1]
     if prefix is not None and (
@@ -244,7 +272,8 @@ def flash_attention(
     kernel = functools.partial(
         _flash_kernel, block_q=block_q, block_k=block_k, causal=causal,
         window=None if window is None else int(window),
-        prefix=None if prefix is None else int(prefix))
+        prefix=None if prefix is None else int(prefix),
+        **({} if block is None else {"block": int(block)}))
     visible = jnp.zeros((1,), jnp.int32) if prefix is None else \
         jnp.reshape(prefix_len, (1,)).astype(jnp.int32)
     out = pl.pallas_call(
@@ -293,7 +322,8 @@ def _tile(t_q, t_k, window=None, prefix=0):
 
 
 def attention(q, k, v, kv_len=None, causal: bool = True, mesh=None,
-              window=None, name=None, prefix=None, prefix_len=None):
+              window=None, name=None, prefix=None, prefix_len=None,
+              block=None):
     """Dispatching attention: Pallas flash kernel on TPU when the shape
     tiles onto the MXU, XLA einsum otherwise (CPU, tiny prompts). Inference
     only — the kernel defines no VJP; training paths keep the XLA/ring
@@ -310,6 +340,11 @@ def attention(q, k, v, kv_len=None, causal: bool = True, mesh=None,
     ``prefix`` rows before the queries' own, of which every query sees the
     first ``prefix_len`` (EVA attention's summaries of the earlier windows);
     causal, no window, no mesh.
+
+    ``block`` (static int, optional: a power of two): causal over blocks of
+    that many positions and open inside one (generation by blocks); both
+    paths take the same mask. A Python ``None`` for every other caller,
+    whose programs it leaves as they were.
 
     ``mesh``: the serving mesh when the caller runs under one. Mosaic
     kernels cannot be partitioned by GSPMD, so the kernel call is wrapped
@@ -342,12 +377,13 @@ def attention(q, k, v, kv_len=None, causal: bool = True, mesh=None,
     if not use_kernel:
         if prefix is not None:
             return _prefixed_attention(q, k, v, int(prefix), prefix_len)
-        return _xla_attention(q, k, v, causal, kv_len, window)
+        return _xla_attention(q, k, v, causal, kv_len, window, block)
     block_q, block_k = _tile(t_q, t_k, window, prefix or 0)
     kernel = functools.partial(
         flash_attention, causal=causal, block_q=block_q, block_k=block_k,
         window=None if window is None else int(window), name=name,
         prefix=None if prefix is None else int(prefix), prefix_len=prefix_len,
+        **({} if block is None else {"block": int(block)}),
     )
     if mesh is not None:
         kernel = jax.shard_map(

@@ -335,6 +335,9 @@ class _Slot:
     # rows that now belong to the lane's next occupant — are never
     # appended or streamed to a finished request
     credit_done: bool = False
+    # generation by blocks, a traced request: when the lane's last block
+    # went to the client (the start of the next ``gen.block`` span)
+    block_t: float = 0.0
 
 
 def _positions_streamed(pos: int, k: int, bucket: int, block: int) -> int:
@@ -422,9 +425,18 @@ class ContinuousBatcher:
             prefix_cache=int(prefix_cache_hbm_bytes) > 0,
             chunked_prefill=int(prefill_chunk) > 0,
             preemption=int(hbm_ledger_bytes) > 0 or int(swap_drain_ms) > 0,
+            fused=int(fused_steps_per_dispatch) > 0,
         )
         self.slots = int(slots)
         self.max_seq = int(max_seq or model.cfg.max_seq)
+        # positions a lane's decode step covers: 1, or the block of a
+        # family that generates by blocks (model.block_tokens()), whose
+        # bursts are passes over each live lane's block (_block_burst_fn)
+        self._block_w = int(model.block_tokens())
+        if self.max_seq % self._block_w:
+            raise ValueError(
+                f"max_seq {self.max_seq} is no multiple of the family's "
+                f"block of {self._block_w} positions")
         self.mesh = mesh
         self.steps_per_poll = int(steps_per_poll)
         # burst length actually dispatched: pow2 floor of steps_per_poll —
@@ -1183,6 +1195,92 @@ class ContinuousBatcher:
             toks = jnp.concatenate([cur_tok[None, :], toks], axis=0)
             return (toks, counts, done, cur, pos, cache, keys,
                     budgets, *(c.sum(axis=0) for c in extra))
+
+        # -- generation by blocks ---------------------------------------------
+        def fused_burst_blocks(params, cache, regs, pos, active, temps, keys,
+                               k, attn_len, any_stoch):
+            """k passes as one executable, for a family whose step is a pass
+            over a block of W positions a lane (``model.block_tokens()``).
+            ``pos`` [S] is each lane's block's first position; ``regs`` the
+            lanes' block registers: ``tok`` / ``masked`` [S, W] the block's
+            tokens and which of them are not filled in yet, ``n_pass`` the
+            denoising passes it has had, ``skip`` how many of its first
+            positions are a prompt's tail (emitted to nobody), ``budget``
+            the positions the lane has still to commit (its first block's
+            tail and what is left of ``max_new_tokens``), ``stops`` its
+            eos or -1. Lanes are in different phases of one forward: a lane
+            whose block has a masked position has ``model.block_unmask``
+            fill some in; one whose block has none was on its COMMIT pass:
+            its rows stay in the cache, its tokens are the pass's output
+            row, and it moves on to the next block, all ``[MASK]``. A lane
+            whose budget is spent (or that emitted its eos) reads and
+            writes nothing more. Returns ``(toks [k, S, W], counts [k, S],
+            bits [k, S], regs, pos, cache, keys, *counters)``, the first two
+            in the speculative burst's shape: a pass that committed a block
+            gives its ``counts`` tokens for the client (W less the first
+            block's skip) first in its row of ``toks``, the row turned by
+            the skip; any other pass gives 0 and the block as it found it.
+            ``bits`` is the block's mask bits as the pass found them (bit i:
+            position i), for whoever follows the passes (the comparison
+            with the reference; the scheduler does not read it)."""
+            W = self._block_w
+            mask_id = jnp.int32(model.cfg.mask_token_id)
+            col = jnp.arange(W, dtype=jnp.int32)[None, :]
+
+            def body(carry, _):
+                cache, regs, pos, keys = carry
+                tok, masked = regs["tok"], regs["masked"]
+                alive = active & (regs["budget"] > 0)
+                logits, cache, counts = model.decode_block_cache(
+                    params, cache, tok, pos, masked=masked, attn_len=attn_len,
+                    lens=jnp.where(alive, pos + W, 0))
+                commit = alive & ~masked.any(axis=-1)
+                new_tok, new_masked, keys, unmasked = model.block_unmask(
+                    logits, tok, masked, regs["n_pass"], alive, temps, keys,
+                    any_stoch)
+                sent = col >= regs["skip"][:, None]
+                stopped = commit & (
+                    (tok == regs["stops"][:, None]) & sent).any(axis=-1)
+                count = jnp.where(commit, W - regs["skip"], 0)
+                turned = jnp.take_along_axis(
+                    tok, (col + (W - count)[:, None]) % W, axis=1)
+                bits = (masked << col).sum(axis=-1)
+                regs = {
+                    "tok": jnp.where(commit[:, None], mask_id, new_tok),
+                    "masked": commit[:, None] | new_masked,
+                    "n_pass": jnp.where(
+                        commit, 0, regs["n_pass"] + alive.astype(jnp.int32)),
+                    "skip": jnp.where(commit, 0, regs["skip"]),
+                    "budget": jnp.where(
+                        stopped, 0,
+                        regs["budget"] - jnp.where(commit, W, 0)),
+                    "stops": regs["stops"],
+                }
+                pos = jnp.where(commit, pos + W, pos)
+                return (cache, regs, pos, keys), (turned, count, bits,
+                                                  counts + unmasked)
+
+            (cache, regs, pos, keys), (toks, emitted, bits, counts) = lax.scan(
+                body, (cache, regs, pos, keys), None, length=k)
+            return (toks, emitted, bits, regs, pos, cache, keys,
+                    counts.sum(axis=0))
+
+        def block_insert(regs, slot_ix, tok, masked, skip, budget, stops):
+            """The block registers of m lanes an insert has just filled:
+            their first block (the prompt's tail, then ``[MASK]``s)."""
+            new = {"tok": tok, "masked": masked, "skip": skip,
+                   "budget": budget, "stops": stops,
+                   "n_pass": jnp.zeros_like(skip)}
+            return {name: regs[name].at[slot_ix].set(new[name])
+                    for name in regs}
+
+        # the burst's executable is ``jit_fused_burst`` whatever its step is:
+        # what reads a trace by that name reads a pass as a step
+        fused_burst_blocks.__name__ = fused_burst.__name__
+        self._block_burst_fn = jax.jit(
+            fused_burst_blocks, donate_argnums=(1, 2),
+            static_argnums=(7, 8, 9))
+        self._block_insert_fn = jax.jit(block_insert, donate_argnums=(0,))
 
         # -- prefix-cache executables ---------------------------------------
         def prefix_prefill(params, slab, suffix, start_pos, last_index, seed, temp):
@@ -2919,6 +3017,22 @@ class ContinuousBatcher:
         self._stops_dev = jnp.full((self.slots,), -1, jnp.int32)
         self._budget_dev = jnp.zeros((self.slots,), jnp.int32)
         self._fused_sync = False
+        self._block_regs = self._fresh_block_regs()
+
+    def _fresh_block_regs(self):
+        """The lanes' block registers with no lane in them (a budget of 0:
+        nothing runs), for a family that generates by blocks
+        (``fused_burst_blocks``); None for every other."""
+        import jax.numpy as jnp
+
+        if self._block_w == 1:
+            return None
+        S, W = self.slots, self._block_w
+        # an array each: the burst donates them
+        return {"tok": jnp.zeros((S, W), jnp.int32),
+                "masked": jnp.ones((S, W), bool),
+                **{name: jnp.full((S,), fill, jnp.int32) for name, fill in (
+                    ("n_pass", 0), ("skip", 0), ("budget", 0), ("stops", -1))}}
 
     @scheduler_only
     def _rebuild(self) -> None:
@@ -3194,6 +3308,25 @@ class ContinuousBatcher:
                 self._cache = {"k": nc["k"], "v": nc["v"]}
                 self._draft_cache = {"k": nc["dk"], "v": nc["dv"]}
                 self._cache_leaf().block_until_ready()  # seldon-lint: disable=host-sync-hot-path (warm precompile: intentional sync while the loop is idle)
+        elif self._block_w > 1:
+            # generation by blocks: the burst of passes and the registers'
+            # insert at each wave size; the greedy variant only, as the
+            # speculative burst (a lane with a temperature compiles its own)
+            for m in batch_sizes:
+                if m <= self.slots:
+                    self._block_admit(
+                        list(range(m)),
+                        [GenRequest(tokens=[0], max_new_tokens=1)] * m)
+            for attn_len in burst_lens:
+                (toks, _n, _bits, self._block_regs, self._pos, self._cache,
+                 self._keys, *_) = self._block_burst_fn(
+                    self.params, self._cache, self._block_regs, self._pos,
+                    active, temps, self._keys, k, attn_len, False)
+                toks.block_until_ready()  # seldon-lint: disable=host-sync-hot-path (warm precompile: intentional sync while the loop is idle)
+            logger.info(
+                "warm: block burst compile census: %d variant(s) "
+                "(k=%d x attn=%s)", len(burst_lens), k, burst_lens,
+            )
         else:
             for attn_len in burst_lens:
                 toks, self._cur_tok, self._pos, self._cache, self._keys, *_ = (
@@ -3272,6 +3405,7 @@ class ContinuousBatcher:
         self._stops_dev = jnp.full((self.slots,), -1, jnp.int32)
         self._budget_dev = jnp.zeros((self.slots,), jnp.int32)
         self._fused_sync = False
+        self._block_regs = self._fresh_block_regs()
 
     @caller_thread
     def close(self) -> None:
@@ -4247,7 +4381,16 @@ class ContinuousBatcher:
             for name, n in zip(self._prefill_counters, host.pop()):
                 self.stats[name] += int(n)
         if mode == "spec":
-            self._process_spec_burst(*host, *rest)
+            start_tok, toks, counts = host
+            # acceptance telemetry over ALL lanes that ran rounds
+            # (device-true, independent of host-side crediting cutoffs)
+            self.stats["spec_rounds"] += int((counts > 0).sum())
+            self.stats["spec_emitted"] += int(counts.sum())
+            self._credit_rounds(
+                toks, counts, rest[0],
+                rest[1] * (self.speculate_tokens + 1), start_tok=start_tok)
+        elif mode == "block":
+            self._credit_rounds(*host, rest[0], rest[2], width=self._block_w)
         elif mode == "fused":
             self._process_fused_burst(*host, *rest)
         else:
@@ -4943,7 +5086,8 @@ class ContinuousBatcher:
             ) as _m, device_trace("gen.lane_insert"):
                 (self._cache, self._cur_tok, self._pos, self._keys,
                  *self._prefill_counts) = self._insert_fn(
-                    self._cache, cache_one, slot, first[0], n, lane_key,
+                    self._cache, cache_one, slot, first[0],
+                    self._lane_start(n), lane_key,
                     self._cur_tok, self._pos, self._keys,
                     *self._prefill_counts, *counts,
                 )
@@ -4975,9 +5119,47 @@ class ContinuousBatcher:
         # no host read here: prefill + insert stay fully async; the first
         # token reaches the host with the next burst's sync
         self._active[slot] = _Slot(request=req)
-        self._pos_host[slot] = n
+        self._pos_host[slot] = self._lane_start(n)
+        self._block_admit([slot], [req])
         self._masks_dirty = True
         self._count_admitted(req)
+
+    def _lane_start(self, n):
+        """Where a lane that holds a prompt of ``n`` tokens starts to
+        decode: at ``n``, or at the first position of the block that holds
+        the prompt's tail (or follows its last whole block) for a family
+        that generates by blocks."""
+        return n - n % self._block_w
+
+    def _block_admit(self, slots: List[int], reqs: List[GenRequest]) -> None:
+        """Generation by blocks: the first block of each lane an insert has
+        just filled, into the lanes' block registers (one small dispatch
+        behind the insert; nothing for every other family). The block
+        starts as the prompt's tail, ``[MASK]`` elsewhere; which is which
+        is the lane's mask bits, whatever ids the prompt holds."""
+        W = self._block_w
+        if W == 1:
+            return
+        import jax.numpy as jnp
+
+        m = len(reqs)
+        tok = np.full((m, W), self.model.cfg.mask_token_id, np.int32)
+        masked = np.ones((m, W), bool)
+        skip = np.zeros((m,), np.int32)
+        budget = np.zeros((m,), np.int32)
+        stops = np.full((m,), -1, np.int32)
+        for i, req in enumerate(reqs):
+            tail = len(req.tokens) % W
+            if tail:
+                tok[i, :tail] = req.tokens[-tail:]
+                masked[i, :tail] = False
+            skip[i] = tail
+            budget[i] = tail + req.max_new_tokens
+            if req.eos_id is not None:
+                stops[i] = int(req.eos_id)
+        self._block_regs = self._block_insert_fn(
+            self._block_regs, jnp.asarray(np.asarray(slots, np.int32)),
+            *(jnp.asarray(a) for a in (tok, masked, skip, budget, stops)))
 
     @scheduler_only
     def _admit_many(self, slots: List[int], reqs: List[GenRequest], bucket: int) -> None:
@@ -5023,7 +5205,7 @@ class ContinuousBatcher:
             (self._cache, self._cur_tok, self._pos, self._keys,
              *self._prefill_counts) = self._insert_many_fn(
                 self._cache, slab, jnp.asarray(np.asarray(slots, np.int32)),
-                firsts, jnp.asarray(last + 1), lane_keys,
+                firsts, jnp.asarray(self._lane_start(last + 1)), lane_keys,
                 self._cur_tok, self._pos, self._keys,
                 *self._prefill_counts, *counts,
             )
@@ -5043,7 +5225,8 @@ class ContinuousBatcher:
                       "dispatch": True},
             )
             self._active[slot] = _Slot(request=req)
-            self._pos_host[slot] = len(req.tokens)
+            self._pos_host[slot] = self._lane_start(len(req.tokens))
+        self._block_admit(slots, reqs)
         self._masks_dirty = True
         self._count_admitted(*reqs)
         self.stats["prefill_steps"] += 1
@@ -5221,7 +5404,7 @@ class ContinuousBatcher:
         verdict; crediting re-derives it from the tokens (``_credit``
         checks eos/budget per token), so the two can never disagree
         without the identity tests catching it. Like
-        :meth:`_process_spec_burst`, tightens the host position bound
+        :meth:`_credit_rounds`, tightens the host position bound
         from the worst-case k advance to the lane's actual alive steps —
         a lane frozen early must not inflate the pressure ledger or the
         attention-bucket need until the host observes it."""
@@ -5241,31 +5424,105 @@ class ContinuousBatcher:
         self._check_done()
 
     @scheduler_only
-    def _process_spec_burst(self, start_tok, host_toks, counts, snapshot, k) -> None:
-        """Spec-mode crediting: per round, a lane emitted counts[r, slot]
-        tokens (accepted drafts + the target's correction); ``host_toks``
-        is [k, S, gamma+1], ``counts`` [k, S]. Also tightens the host
-        position bound from worst-case (k*(gamma+1)) to actual."""
-        worst = k * (self.speculate_tokens + 1)
-        # acceptance telemetry over ALL lanes that ran rounds (device-true,
-        # independent of host-side crediting cutoffs)
-        ran = counts > 0
-        self.stats["spec_rounds"] += int(ran.sum())
-        self.stats["spec_emitted"] += int(counts.sum())
+    def _credit_rounds(self, host_toks, counts, snapshot, worst, width=0,
+                       start_tok=None) -> None:
+        """Credit a burst whose rounds each emit a number of tokens of their
+        own a lane: speculation's rounds (accepted drafts + the target's
+        correction, under the deferred first token ``start_tok``) and
+        generation by blocks' passes (a commit's block, else nothing).
+        ``host_toks`` [k, S, W] holds a round's tokens first in its row,
+        ``counts`` [k, S] how many. Also tightens the host position bound
+        from the ``worst`` the dispatch assumed to what the lane advanced:
+        the tokens it emitted, or ``width`` positions a round that emitted
+        any (a block: its first may hold a prompt's tail, sent to nobody).
+        A block is one span of tokens to the client and one ``gen.block``
+        span of its request."""
         for slot, (s, start) in snapshot.items():
             if self._active.get(slot) is not s:
                 continue
-            actual = int(counts[:, slot].sum())
+            n = counts[:, slot]
             if slot in self._pos_host:
-                self._pos_host[slot] -= worst - actual
-            done = False
-            if start == 0:
-                done = self._credit(s, [int(start_tok[slot])])
-            for r in range(k):
+                self._pos_host[slot] -= worst - (
+                    width * int((n > 0).sum()) if width else int(n.sum()))
+            req = s.request
+            done = start_tok is not None and start == 0 and self._credit(
+                s, [int(start_tok[slot])])
+            for r in np.flatnonzero(n):
                 if done:
                     break
-                done = self._credit(s, host_toks[r, slot, : int(counts[r, slot])])
+                had = len(s.emitted)
+                done = self._credit(s, host_toks[r, slot, : int(n[r])])
+                if width and req.trace is not None:
+                    # the block's span: from the block before it (the
+                    # lane's insert, for the first) to its tokens' credit
+                    now = time.monotonic()
+                    self._emit_span(
+                        req, "gen.block", s.block_t or req.decode_start_t,
+                        now, tags={"tokens": len(s.emitted) - had,
+                                   "emitted": len(s.emitted)})
+                    s.block_t = now
         self._check_done()
+
+    @scheduler_only
+    def _dispatch_block_burst(self, active_dev, temps_dev, pending):
+        """Generation by blocks: one burst of ``_k`` passes over every live
+        lane's block (``fused_burst_blocks``), dispatched and queued on
+        ``pending`` as the plain burst is. A pass commits a block or
+        not, so what a lane advances is known only when the burst is read
+        (:meth:`_credit_rounds`): until then its host position is
+        the most it can be, a commit every second pass. Returns the poll
+        record's plan and the dispatch time."""
+        from ..tracing import device_trace
+
+        k, W = self._k, self._block_w
+        worst = W * ((k + 1) // 2)
+        # the cache read's bound: the deepest lane's last possible block
+        attn_len = self._attn_need(
+            max(self._pos_host[i] for i in self._active) + worst + W)
+        lanes = sorted(self._active)
+        snapshot = {}
+        t_dispatch = time.monotonic()
+        for slot in lanes:
+            s = self._active[slot]
+            snapshot[slot] = (s, 0)
+            if s.first_pending:
+                s.request.first_dispatch_t = t_dispatch
+            s.first_pending = False
+            self._pos_host[slot] += worst
+        read_bytes = self.model.dispatch_read_bytes(
+            "decode_burst", rows=self.slots, live=len(lanes), k=k,
+            bucket=attn_len, param_bytes=self._param_bytes,
+            kv_row_bytes=self._kv_key_bytes)
+        with self._prof.measure(
+            "decode_burst", variant=f"w{W}b{attn_len}",
+            tenant=self._burst_tenant() if self._prof.enabled else "",
+            bytes_read=read_bytes, tokens=k * self.slots * W,
+        ) as _m, device_trace("gen.decode_burst"):
+            (toks, counts, _bits, self._block_regs, self._pos, self._cache,
+             self._keys, *extra) = self._block_burst_fn(
+                self.params, self._cache, self._block_regs, self._pos,
+                active_dev, temps_dev, self._keys, k,
+                None if self._ragged_read else attn_len, self._any_stoch)
+            _m.sync(toks)
+        burst = ("block",
+                 (toks, counts, *self._take_prefill_counts(), *extra),
+                 (snapshot, k, worst), t_dispatch)
+        self.stats["steps"] += k
+        self.stats["lane_steps"] += k * self.slots
+        self.stats["burst_reads"] += 1
+        self.stats["burst_read_bytes"] += read_bytes
+        rows = len(lanes) * k * W * self._position_layers
+        self.stats["kv_rows_written"] += rows
+        if self._ragged_read:
+            self.stats["kv_rows_written_in_kernel"] += rows
+        for t in burst[1]:
+            try:
+                t.copy_to_host_async()
+            except AttributeError:  # non-jax (test doubles)
+                pass
+        pending.append(burst)
+        return {"mode": "block", "k": k, "lanes": len(lanes),
+                "bucket": attn_len}, t_dispatch
 
     def _run(self) -> None:
         """Scheduler thread entrypoint: the supervision shell around the
@@ -5763,7 +6020,12 @@ class ContinuousBatcher:
                     attn_len = self._attn_need(
                         max(self._pos_host[i] for i in self._active) + adv
                     )
-                    if self._spec_active():
+                    if self._block_w > 1:
+                        poll_plan, t_dispatch = self._dispatch_block_burst(
+                            active_dev, temps_dev, pending)
+                        if flight is None:
+                            poll_plan = None
+                    elif self._spec_active():
                         # snapshot BEFORE dispatch: tokens of this burst
                         # belong to these occupants, whatever the host
                         # learns later.

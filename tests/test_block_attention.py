@@ -1,0 +1,138 @@
+"""Attention that is open inside a block (generation by blocks): the ragged
+decode kernel's second entry, a block of W positions a lane
+(``ops/decode_attention.py``), interpreted on the CPU against the scatter
+and the dots; and the flash kernel's block mask
+(``ops/flash_attention.py``) against the masked dots, and those against
+the mask written out."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from seldon_core_tpu.ops import attention
+from seldon_core_tpu.ops.decode_attention import (
+    BLOCK, GROUP, block_decode_attention, block_reads_ragged, cache_write,
+    ragged_decode_attention)
+from seldon_core_tpu.ops.flash_attention import (
+    _block_attention, _xla_attention, flash_attention)
+
+B, KV, REP, DH, T = 7, 2, 3, 128, 512
+
+
+def _arrays(w, seed=0, dtype=jnp.float32):
+    rng = np.random.default_rng(seed)
+    draw = lambda *s: jnp.asarray(rng.standard_normal(s), dtype)  # noqa: E731
+    return (draw(B, KV * REP, w, DH), draw(B, KV, T, DH), draw(B, KV, T, DH),
+            draw(B, KV, w, DH), draw(B, KV, w, DH))
+
+
+@pytest.mark.parametrize("w", [2, 4, 8])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_the_block_kernel_is_the_scatter_and_the_dots(w, dtype):
+    """Blocks that end at the kernel's block edge, start at it, lie in the
+    first and in the last group of the cache, an idle lane among them: the
+    kernel's output and both caches are the dots' (the caches bit for bit),
+    and the idle lane's rows are left alone by both."""
+    q, k, v, k_new, v_new = _arrays(w, w, dtype)
+    base = np.array([0, BLOCK - w, BLOCK, 0, 2 * BLOCK + 3 * w, T - w, 40 // w * w])
+    lens = np.where(np.arange(B) == 3, 0, base + w)
+    got = ragged_decode_attention(
+        q, k, v, jnp.asarray(lens), k_new, v_new, jnp.asarray(base),
+        interpret=True)
+    want = block_decode_attention(
+        q, k, v, k_new, v_new, jnp.asarray(base), jnp.asarray(lens))
+    live = lens > 0
+    tol = 2e-6 if dtype == jnp.float32 else 2e-2
+    np.testing.assert_allclose(
+        np.asarray(got[0], np.float32)[live], np.asarray(want[0], np.float32)[live],
+        atol=tol)
+    for mine, theirs, before in zip(got[1:], want[1:], (k, v)):
+        np.testing.assert_array_equal(np.asarray(mine), np.asarray(theirs))
+        np.testing.assert_array_equal(np.asarray(mine)[3], np.asarray(before)[3])
+    # the rows landed where the scatter puts them, and nowhere else
+    at = jnp.asarray(np.where(live[:, None], base[:, None] + np.arange(w), T))
+    np.testing.assert_array_equal(
+        np.asarray(got[1]), np.asarray(cache_write(k, k_new, at)))
+
+
+def test_every_query_of_a_block_sees_the_whole_block_and_nothing_after():
+    """Against the mask written out: query i of a block at ``base`` sees
+    keys [0, base + W), its own block's later positions among them."""
+    w = 4
+    q, k, v, k_new, v_new = _arrays(w, 9)
+    base = jnp.asarray([0, 124, 128, 4, 300, 508, 40])
+    lens = base + w
+    o, k2, v2 = block_decode_attention(q, k, v, k_new, v_new, base, lens)
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, jnp.repeat(k2, REP, axis=1)) / np.sqrt(DH)
+    seen = jnp.arange(T)[None, None, None, :] < lens[:, None, None, None]
+    p = jax.nn.softmax(jnp.where(seen, s, -jnp.inf), axis=-1)
+    want = jnp.einsum("bhqk,bhkd->bhqd", p, jnp.repeat(v2, REP, axis=1))
+    np.testing.assert_allclose(np.asarray(o), np.asarray(want), atol=2e-5)
+
+
+def test_the_kernel_takes_a_block_that_lies_in_one_group():
+    shapes = ((4, 32, 4, 128), (4, 4, 4096, 128))
+    dts = (jnp.bfloat16,) * 3
+    assert block_reads_ragged("tpu", *shapes, dts)
+    assert not block_reads_ragged("cpu", *shapes, dts)
+    assert not block_reads_ragged("tpu", (4, 32, 3, 128), shapes[1], dts)
+    assert not block_reads_ragged("tpu", (4, 32, 16, 128), shapes[1], dts)
+    assert not block_reads_ragged("tpu", *shapes, dts, mesh=object())
+    assert GROUP % 4 == 0
+    q, k, v, k_new, v_new = _arrays(3)
+    with pytest.raises(ValueError, match="do not fit the kernel"):
+        ragged_decode_attention(q, k, v, jnp.zeros((B,), jnp.int32), k_new,
+                                v_new, jnp.zeros((B,), jnp.int32), interpret=True)
+    q, k, v, k_new, v_new = _arrays(4)
+    with pytest.raises(ValueError, match="no starts"):
+        ragged_decode_attention(
+            q, k, v, jnp.zeros((B,), jnp.int32), k_new, v_new,
+            jnp.zeros((B,), jnp.int32), interpret=True,
+            starts=jnp.zeros((B,), jnp.int32))
+
+
+@pytest.mark.parametrize("t,block_q,block_k,block", [
+    (256, 128, 128, 4), (512, 256, 512, 4), (384, 128, 256, 4),
+    (256, 128, 128, 8), (256, 128, 256, 2), (512, 128, 128, 1)])
+def test_the_flash_kernels_block_mask_is_the_masked_dots(t, block_q, block_k, block):
+    rng = np.random.default_rng(t + block)
+    q, k, v = (jnp.asarray(rng.standard_normal((1, 2, t, 128)), jnp.float32)
+               for _ in range(3))
+    got = flash_attention(q, k, v, block_q=block_q, block_k=block_k,
+                          interpret=True, block=block)
+    want = _xla_attention(q, k, v, True, block=block)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=3e-5)
+    if block == 1:
+        # blocks of one position: the causal mask
+        np.testing.assert_allclose(
+            np.asarray(want), np.asarray(_xla_attention(q, k, v, True)), atol=3e-5)
+    else:
+        assert np.abs(np.asarray(want) - np.asarray(
+            _xla_attention(q, k, v, True))).max() > 0.1
+
+
+def test_the_masked_dots_are_the_mask_written_out():
+    rng = np.random.default_rng(3)
+    q, k, v = (jnp.asarray(rng.standard_normal((2, 2, 24, 16)), jnp.float32)
+               for _ in range(3))
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k) / 4.0
+    i, j = np.arange(24)[:, None], np.arange(24)[None, :]
+    seen = j < (i // 4 + 1) * 4
+    want = jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(
+        jnp.where(seen[None, None], s, -jnp.inf), -1), v)
+    np.testing.assert_allclose(np.asarray(_block_attention(q, k, v, 4)),
+                               np.asarray(want), atol=1e-5)
+    # the dispatching entry takes the same argument on the CPU
+    np.testing.assert_allclose(np.asarray(attention(q, k, v, block=4)),
+                               np.asarray(want), atol=1e-5)
+
+
+def test_a_block_is_causal_a_power_of_two_and_takes_no_window():
+    q = jnp.zeros((1, 1, 128, 128))
+    for how in (dict(block=3), dict(block=256), dict(block=4, window=64),
+                dict(block=4, causal=False)):
+        with pytest.raises(ValueError):
+            flash_attention(q, q, q, interpret=True, **how)
+    with pytest.raises(ValueError):
+        _xla_attention(q, q, q, True, window=64, block=4)

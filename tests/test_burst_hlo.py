@@ -574,3 +574,74 @@ def test_flash_kernel_compiled_for_v5e_at_the_rules_tile(one_chip, heads, t, dh,
     call, = (line for line in compiled.as_text().splitlines()
              if "custom-call(" in line and "tpu_custom_call" in line)
     assert f"bf16[{heads},{t},{dh}]" in call
+
+
+def test_sdar_burst_compiled_for_v5e_is_a_block_kernel_a_layer_over_a_cache_in_place(one_chip):
+    """The configuration's own burst of passes (32 lanes, all 6 layers, a
+    block of 4 positions a lane, no bucket): every layer reads and writes
+    its cache through the ragged kernel's block entry, under its own name,
+    beside the touched-experts kernel, inside the ``while``; all 12 cache
+    leaves are aliased through; NO pass copies, slices, scatters into or
+    relays anything of the cache's shape (a pass lands four rows a lane and
+    layer: the kernel's, not a scatter's), and the block registers ride in
+    the loop's carry."""
+    import re
+
+    tool = _tool()
+    with open(os.path.join(ROOT, "benchmark", "configs", "sdar-30b-a3b.json")) as f:
+        cfg = json.load(f)
+    cfg["name"] = "sdar-30b-a3b"
+    compiled, (lanes, kv, T, dh), cache_bytes, leaves = tool.compile_burst(
+        cfg, None, one_chip)
+    assert (lanes, kv, T, dh, leaves) == (32, 4, 4096, 128, 12)
+    assert cache_bytes == 32 * 4096 * 12288
+    hlo = compiled.as_text()
+    assert hlo.startswith("HloModule jit_fused_burst,")
+    assert not tool.cache_shaped(hlo, lanes, kv, (T,), dh)
+    assert tool.cache_scatters(hlo, lanes, kv, T, dh) == 0
+    assert not re.search(rf"bf16\[{lanes},{kv},{T},{dh}\]\{{3,1,", hlo)
+    # an attention and an expert kernel a layer, every one inside the loop
+    assert tool.kernel_calls(hlo) == {"inside": 12, "outside": 0}
+    names = re.findall(r"%([a-z_]+)[.\d]* = [^\n]*? custom-call\(", hlo)
+    assert names.count("block_decode_attention") == 6
+    assert names.count("touched_experts_ffn") == 6
+    calls = [line for line in hlo.splitlines()
+             if "custom-call(" in line and "block_decode_attention" in line]
+    for call in calls:
+        # the queries of a block ride as 4 x 8 rows a KV head
+        assert f"bf16[{lanes},{kv},32,{dh}]" in call
+        assert call.count(f"bf16[{lanes},{kv},{T},{dh}]") >= 4
+    assert tool.weight_slices_through_hbm(hlo) == 0
+    assert tool.alias_count(hlo) >= leaves
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= cache_bytes
+    assert mem.temp_size_in_bytes < 256 << 20
+
+
+def test_flash_kernel_compiled_for_v5e_under_the_block_mask(one_chip):
+    """The sdar_moe family's prefill attention: the flash kernel at the
+    rule's tile with the mask open inside blocks of 4, in the cell's
+    buckets; and the same call without a block is the program it was."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+
+    from seldon_core_tpu.ops.flash_attention import _tile, flash_attention
+
+    def sds(shape):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+
+    for t in (512, 1792, 4096):
+        block_q, block_k = _tile(t, t)
+        texts = []
+        for block in (4, None):
+            fn = jax.jit(functools.partial(
+                flash_attention, causal=True, block_q=block_q,
+                block_k=block_k, block=block))
+            compiled = fn.lower(*(sds((1, 32, t, 128)),) * 3).compile()
+            call, = (line for line in compiled.as_text().splitlines()
+                     if "custom-call(" in line and "tpu_custom_call" in line)
+            assert f"bf16[32,{t},128]" in call
+            texts.append(call)
+        assert texts[0] != texts[1]

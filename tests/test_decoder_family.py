@@ -41,11 +41,16 @@ SMALL = {
                     n_kv_heads=4, d_ff=128, max_seq=128, window_size=32,
                     chunk_size=8, num_pred_heads=2, norm_add_unit_offset=True,
                     fp32_skip_add=True),
+    "sdar_moe": dict(vocab_size=64, d_model=64, n_layers=2, n_heads=4,
+                     n_kv_heads=2, head_dim=16, max_seq=128,
+                     n_routed_experts=4, experts_per_tok=2, expert_width=32,
+                     denoising_steps=2, mask_token_id=63),
 }
 # a field that is one family's own, for every OTHER block an unknown keyword
 OWN_FIELD = {
     "afmoe": "sliding_window", "qwen3_next": "linear_conv_kernel",
     "joyai_llm_flash": "kv_lora_rank", "evabyte": "window_size",
+    "sdar_moe": "block_length",
 }
 # the optional paths and the ``serving_refuses`` feature that guards each
 GUARDED = {
@@ -59,8 +64,26 @@ LLAMA_OWN = ("decode_step_ragged_list", "backbone", "loss_fn", "_decode",
              "decode_step", "decode_step_ragged", "generate")
 
 
-def test_the_registry_names_the_five_blocks():
+def test_the_registry_names_the_six_blocks():
     assert sorted(SMALL) == sorted(families.FAMILIES)
+
+
+@pytest.mark.parametrize("block", sorted(SMALL))
+def test_a_step_is_one_token_a_lane_but_where_a_family_generates_by_blocks(block):
+    """``block_tokens()`` is what the scheduler asks; the pass over a block
+    and how it fills in are defined once as a typed refusal and served by
+    the family that generates so."""
+    model = DecoderLM(block=block, **SMALL[block])
+    by_blocks = block == "sdar_moe"
+    assert model.block_tokens() == (4 if by_blocks else 1)
+    for path in ("decode_block_cache", "block_unmask"):
+        inherited = getattr(type(model), path) is getattr(DecoderFamily, path)
+        assert inherited != by_blocks, path
+        if inherited:
+            with pytest.raises(UnsupportedByModel,
+                               match=f"the {block} block has no pass over a block"):
+                getattr(model, path)()
+    assert ("block_forwards" in model.step_counter_names) == by_blocks
 
 
 @pytest.mark.parametrize("block", sorted(SMALL))

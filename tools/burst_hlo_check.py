@@ -308,11 +308,21 @@ def compile_burst(cfg: dict, attn_len, device_sharding):
         jax.eval_shape(lambda: model.cache_layers(lanes, T)))
     leaves = jax.tree_util.tree_leaves(cache)
     lane_i32 = sds((lanes,), jnp.int32)
-    compiled = batcher._burst_fn.lower(
-        params, cache, lane_i32, lane_i32, sds((lanes,), jnp.bool_),
-        sds((lanes,), jnp.float32), sds((lanes, 2), jnp.uint32),
-        batcher._k, attn_len,
-    ).compile()
+    lane_state = (sds((lanes,), jnp.bool_), sds((lanes,), jnp.float32),
+                  sds((lanes, 2), jnp.uint32))
+    if batcher._block_w > 1:
+        # generation by blocks: the burst of passes over the lanes' blocks
+        regs = jax.tree_util.tree_map(
+            lambda a: sds((lanes,) + a.shape[1:], a.dtype),
+            batcher._block_regs)
+        lowered = batcher._block_burst_fn.lower(
+            params, cache, regs, lane_i32, *lane_state, batcher._k, attn_len,
+            False)
+    else:
+        lowered = batcher._burst_fn.lower(
+            params, cache, lane_i32, lane_i32, *lane_state, batcher._k,
+            attn_len)
+    compiled = lowered.compile()
     cache_bytes = sum(a.size * a.dtype.itemsize for a in leaves)
     return (compiled, (lanes, mc.n_kv_heads, T, mc.head_dim), cache_bytes,
             len(leaves))
