@@ -403,8 +403,8 @@ class SdarMoeLM(DecoderFamily):
         with jax.named_scope("block_unmask"):
             logits = self._head(params, x, every=True)
         n_layers = len(params["layers"])
-        kv_heads, max_seq = cache["k"][0].shape[1:3]
-        block = walk_block(self.cfg.n_heads // kv_heads * W, max_seq)
+        k0 = cache["k"][0]                                # [B, KV, T, Dh]
+        block = walk_block(k0.shape[1], k0.shape[3], k0.dtype, k0.shape[2])
         commits = live if masked is None else live & ~masked.any(axis=-1)
         counts = self._counts(
             block_forwards=live.sum(dtype=jnp.int32),

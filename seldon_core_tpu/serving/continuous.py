@@ -343,9 +343,11 @@ class _Slot:
 def _positions_streamed(pos: int, k: int, bucket: int, block: int) -> int:
     """Positions the ragged decode kernel streams for one lane over ``k``
     steps, the first of which writes at ``pos``: the j-th reads ``pos + j``
-    keys, rounded up to the kernel's ``block`` and never past the bucket."""
+    keys, never more than the bucket (the entry clamps a lane's length to
+    it), rounded up to the kernel's ``block``: the walk copies whole
+    blocks, past a bucket that is no multiple of the block too."""
     return sum(
-        min(bucket, -(-(pos + j) // block) * block) for j in range(1, k + 1)
+        -(-min(pos + j, bucket) // block) * block for j in range(1, k + 1)
     )
 
 
@@ -1444,14 +1446,15 @@ class ContinuousBatcher:
         # differ in length prices them itself)
         self._lane_bytes = model.lane_cache_bytes(self._cache)
         # the arrays a decode step writes one row each of
-        self._position_layers = len(model.position_layers(self._cache))
+        position_layers = model.position_layers(self._cache)
+        self._position_layers = len(position_layers)
         # the ragged decode read's granule (stats["kv_positions_read"]):
-        # the kernel's own rule, by the query rows a KV head brings
+        # the kernel's own rule, by the bytes a block of the cache holds
         from ..ops.decode_attention import walk_block
 
         self._kv_read_block = walk_block(
-            model.cfg.n_heads // model.cfg.n_kv_heads * self._block_w,
-            self.max_seq)
+            model.cfg.n_kv_heads, model.cfg.head_dim,
+            position_layers[0].dtype, self.max_seq)
         # the windows of the model's layers that have one (the kinds come
         # from the model; the llama block has none)
         self._kv_windows = tuple(

@@ -15,7 +15,8 @@ from seldon_core_tpu.models.family import DecoderFamily
 from seldon_core_tpu.models.llm import DecoderLM, UnsupportedByModel
 from seldon_core_tpu.ops import experts
 from seldon_core_tpu.ops.decode_attention import (
-    cache_attention, cache_write, decode_attention, ragged_decode_attention)
+    cache_attention, cache_write, decode_attention, ragged_decode_attention,
+    walk_block)
 from seldon_core_tpu.ops.flash_attention import _banded_attention, flash_attention
 
 CFG = dict(
@@ -153,18 +154,24 @@ def _kernel_case(seed, lens, window, lanes=5, heads=8, kv=1, t=1024):
     return q, k, v, k_new, v_new, lens, wp, jnp.maximum(0, lens - window)
 
 
+@pytest.mark.parametrize("block", [None, 128], ids=["rule", "128"])
 @pytest.mark.parametrize("seed, lens, window", [
     (0, [700, 0, 1024, 130, 257], 256),     # starts inside, at and off a block
     (1, [256, 255, 1, 0, 640], 256),        # window not yet full; one key
     (2, [1024, 1000, 900, 513, 385], 384),  # a window of three blocks
 ])
-def test_the_ragged_kernel_with_starts_against_the_masked_dots(seed, lens, window):
-    """Interpreted, as PR 30's tests run the kernel: the caches bit for bit
-    the scatter's, the read the masked dots' to bfloat16 rounding, and a
-    lane's blocks left of its window never asked for."""
+def test_the_ragged_kernel_with_starts_against_the_masked_dots(
+        seed, lens, window, block):
+    """Interpreted, as PR 30's tests run the kernel, under the block the
+    rule gives the call (256: one KV head of 128) and under 128: the caches
+    bit for bit the scatter's, the read the masked dots' to bfloat16
+    rounding, and a lane's blocks left of its window never asked for."""
     q, k, v, k_new, v_new, lens, wp, starts = _kernel_case(seed, lens, window)
+    walked = block or walk_block(k.shape[1], k.shape[3], k.dtype, k.shape[2])
+    assert walked == (block or 256)
     o, k2, v2 = ragged_decode_attention(
-        q, k, v, lens, k_new, v_new, wp, interpret=True, starts=starts)
+        q, k, v, lens, k_new, v_new, wp, interpret=True, starts=starts,
+        block=block)
     kr, vr = cache_write(k, k_new, wp[:, None]), cache_write(v, v_new, wp[:, None])
     assert bool((k2 == kr).all()) and bool((v2 == vr).all())
     want = cache_attention(q, kr, vr, lens - 1, q.dtype, lo=starts)
@@ -174,10 +181,10 @@ def test_the_ragged_kernel_with_starts_against_the_masked_dots(seed, lens, windo
     assert not np.asarray(o, np.float32)[~live].any()
     # poison what lies left of each window's first block: nothing changes
     col = jnp.arange(k.shape[2])[None, None, :, None]
-    left = col < (starts // 128 * 128)[:, None, None, None]
+    left = col < (starts // walked * walked)[:, None, None, None]
     o3, _, _ = ragged_decode_attention(
         q, jnp.where(left, jnp.nan, k), jnp.where(left, jnp.nan, v), lens,
-        k_new, v_new, wp, interpret=True, starts=starts)
+        k_new, v_new, wp, interpret=True, starts=starts, block=block)
     assert bool((o3 == o).all())
     # and the dispatcher's dots take the same band (the CPU's path)
     o4, k4, _ = decode_attention(q, k, v, k_new, v_new, wp, lens - 1, lens,
@@ -373,6 +380,55 @@ def test_a_dense_batcher_counts_no_window_and_returns_what_it_did():
     # reads blocks 0-2 (the window starts in block 0), sees 256, holds 301-303
     assert _positions_windowed(300, 3, 256, 128) == (3 * 384, 3 * 256, 301 + 302 + 303)
     assert _positions_windowed(500, 1, 256, 128) == (384, 256, 501)
+    # blocks of 256: both edges of the window round twice as far
+    assert _positions_windowed(300, 3, 256, 256) == (3 * 512, 3 * 256, 301 + 302 + 303)
+    assert _positions_windowed(500, 1, 256, 256) == (512, 256, 501)
+
+
+@pytest.fixture(scope="module")
+def windowed_at_4_kv_heads():
+    """A batcher over 4 KV heads of 128 in bfloat16 (the cells' shape), one
+    window layer and one full one."""
+    from seldon_core_tpu.serving.continuous import ContinuousBatcher
+
+    model = DecoderLM(**dict(
+        CFG, n_kv_heads=4, dtype="bfloat16", n_layers=2, n_dense_layers=1,
+        layer_types=["sliding_attention", "full_attention"]))
+    batcher = ContinuousBatcher(model, model.init_params(0), slots=2,
+                                max_seq=1024, prefill_buckets=(128,),
+                                steps_per_poll=4)
+    batcher.start()
+    yield batcher
+    batcher.close()
+
+
+@pytest.mark.parametrize("prompt_len,read,window_read,seen,live", [
+    # one burst of 4 steps from position p reads p + 1 .. p + 4 keys, in
+    # blocks of 256, a window of 256. Inside the window (101-104 keys): block
+    # 0 in either kind of layer, every key seen
+    (100, 4 * 256, 4 * 256, 410, 410),
+    # one block past it (601-604 keys): a full layer walks blocks 0-2; the
+    # window starts at 345-348, in block 1, so blocks 1-2 for 256 keys seen
+    (600, 4 * 768, 4 * 512, 4 * 256, 2410),
+    # a request that ends at max_seq (1020-1023 keys): all four blocks; the
+    # window starts at 764-767, in block 2
+    (1019, 4 * 1024, 4 * 512, 4 * 256, 4086),
+])
+def test_a_windowed_batcher_at_4_kv_heads_counts_the_walk_of_256(
+        windowed_at_4_kv_heads, prompt_len, read, window_read, seen, live):
+    """``kv_positions_read`` and the window's three counters are what the
+    kernel streams at the block its rule gives the cache, counted by hand:
+    ``kv_window_read_share`` is their ratio."""
+    batcher = windowed_at_4_kv_heads
+    assert batcher._kv_read_block == 256 and batcher._kv_windows == (256,)
+    names = ("steps", "kv_positions_read", "kv_positions_read_window",
+             "kv_positions_seen_window", "kv_positions_live_window")
+    before = [batcher.stats[name] for name in names]
+    prompt = np.random.default_rng(prompt_len).integers(0, 1024, prompt_len)
+    out = batcher.generate(prompt.tolist(), max_new_tokens=5)
+    assert len(out) == prompt_len + 5
+    assert [batcher.stats[name] - was for name, was in zip(names, before)] == [
+        4, read, window_read, seen, live]
 
 
 @pytest.mark.parametrize("asked", [

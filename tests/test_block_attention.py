@@ -20,34 +20,41 @@ from seldon_core_tpu.ops.flash_attention import (
 B, KV, REP, DH, T = 7, 2, 3, 128, 512
 
 
-def _arrays(w, seed=0, dtype=jnp.float32, lanes=B, rep=REP, t=T):
+def _arrays(w, seed=0, dtype=jnp.float32, lanes=B, rep=REP, t=T, kv=KV):
     rng = np.random.default_rng(seed)
     draw = lambda *s: jnp.asarray(rng.standard_normal(s), dtype)  # noqa: E731
-    return (draw(lanes, KV * rep, w, DH), draw(lanes, KV, t, DH),
-            draw(lanes, KV, t, DH), draw(lanes, KV, w, DH), draw(lanes, KV, w, DH))
+    return (draw(lanes, kv * rep, w, DH), draw(lanes, kv, t, DH),
+            draw(lanes, kv, t, DH), draw(lanes, kv, w, DH), draw(lanes, kv, w, DH))
 
 
 F32, BF16 = jnp.float32, jnp.bfloat16
 # (positions a lane, heads a KV head, cache length, dtype, block: None is
-# the rule's own, ``walk_block``); the block the rule gives
+# the rule's own, ``walk_block``, KV heads of 128); the block the rule gives
 WALKS = [
-    # up to 24 query rows a KV head: the rule's block is the kernel's 128
-    *[((w, REP, T, dtype, block), BLOCK) for w in (2, 4, 8)
+    # 2 KV heads of 128: 128 keys of K and V are 128 KiB in bfloat16 and
+    # 256 in float32, so the rule's block is the wide one, whatever the
+    # query rows a KV head brings (6 to 24 here); under 128 too
+    *[((w, REP, T, dtype, block, KV), 256) for w in (2, 4, 8)
       for dtype in (F32, BF16) for block in (None, BLOCK)],
-    # 32 rows (8 heads x 4 positions, 4 x 8): the wide block where it
-    # divides the cache; 512, which the chip read no faster, walks too
-    *[((4, 8, 1024, dtype, block), 256) for dtype in (F32, BF16)
+    # the block pass's 32 rows (8 heads x 4 positions, 4 x 8); 512, which
+    # the chip read no faster, walks too
+    *[((4, 8, 1024, dtype, block, KV), 256) for dtype in (F32, BF16)
       for block in (None, 512, BLOCK)],
-    ((8, 4, 1024, BF16, None), 256),
-    ((4, 8, 768, BF16, None), 256),
-    ((4, 8, 640, BF16, None), BLOCK),
+    ((8, 4, 1024, BF16, None, KV), 256),
+    ((4, 8, 768, BF16, None, KV), 256),
+    # a cache the wide block does not divide
+    ((4, 8, 640, BF16, None, KV), BLOCK),
+    # 128 keys of 512 KiB (8 KV heads in bfloat16, 4 in float32): their
+    # copy covers the chain, the rule's block is 128
+    ((4, 2, 512, BF16, None, 8), BLOCK),
+    ((4, 2, 512, F32, None, 4), BLOCK),
 ]
 
 
 @pytest.mark.parametrize(
     "walk,ruled", WALKS,
-    ids=[f"w{w}-rep{r * w}-t{t}-{np.dtype(d).name}-{b or 'rule'}"
-         for (w, r, t, d, b), _ in WALKS])
+    ids=[f"w{w}-rep{r * w}-t{t}-{np.dtype(d).name}-{b or 'rule'}-kv{kv}"
+         for (w, r, t, d, b, kv), _ in WALKS])
 def test_the_block_kernel_is_the_scatter_and_the_dots(walk, ruled):
     """Lanes whose blocks end at an edge of 128, 256 and 512 keys and start
     at it, in the first and in the last group of the cache, an idle lane and
@@ -56,15 +63,15 @@ def test_the_block_kernel_is_the_scatter_and_the_dots(walk, ruled):
     both caches are the scatter's and the dots' (the caches bit for bit),
     the idle lane's rows are left alone, under the block the rule gives the
     call's shapes and under 128."""
-    w, rep, t, dtype, block = walk
-    assert walk_block(rep * w, t) == ruled
+    w, rep, t, dtype, block, kv = walk
+    assert walk_block(kv, DH, dtype, t) == ruled
     edges = [e for e in (128, 256, 512) if e < t]
     base = np.array([0, *(e - w for e in edges), *edges, 0,
                      2 * BLOCK + 3 * w, t - w, 40 // w * w, t - 2 * w])
     lanes, idle, unread = len(base), len(edges) * 2 + 1, len(base) - 1
     lens = base + w
     lens[idle], lens[unread] = 0, 72
-    q, k, v, k_new, v_new = _arrays(w, w, dtype, lanes, rep, t)
+    q, k, v, k_new, v_new = _arrays(w, w, dtype, lanes, rep, t, kv)
     got = ragged_decode_attention(
         q, k, v, jnp.asarray(lens), k_new, v_new, jnp.asarray(base),
         block=block, interpret=True)
@@ -93,19 +100,21 @@ def test_the_block_kernel_is_the_scatter_and_the_dots(walk, ruled):
     np.testing.assert_array_equal(np.asarray(entry[1]), np.asarray(want_k))
 
 
-@pytest.mark.parametrize("rep,t,block", [
-    # a KV head's query rows and the cache's length in the six cells whose
-    # one-position bursts call the kernel: InternLM chat and batch, the two
-    # Mistral cells, trinity-mini, qwen3-next
-    (2, 2048, BLOCK), (4, 2048, BLOCK), (8, 4096, BLOCK), (8, 4096, BLOCK),
-    (1, 2048, BLOCK), (16, 4096, BLOCK), (31, 4096, BLOCK),
-    # the block pass of the sdar cell: 8 heads a KV head x 4 positions
-    (32, 4096, 256), (64, 4096, 256), (32, 2048, 256), (32, 768, 256),
+@pytest.mark.parametrize("kv,dh,dtype,t,block", [
+    # the block pass of the sdar cell: 4 KV heads of 128 in bfloat16 (128
+    # keys of K and V: 256 KiB), whatever the cache that 256 divides
+    (4, 128, BF16, 4096, 256), (4, 128, BF16, 2048, 256), (4, 128, BF16, 768, 256),
+    # fewer bytes still
+    (2, 128, BF16, 4096, 256), (1, 128, BF16, 256, 256), (2, 256, BF16, 4096, 256),
+    (7, 128, BF16, 1024, 256), (2, 128, F32, 1024, 256),
+    # 128 keys of 512 KiB or more: the copy covers the chain
+    (8, 128, BF16, 4096, BLOCK), (4, 256, BF16, 4096, BLOCK),
+    (4, 128, F32, 4096, BLOCK), (16, 128, BF16, 2048, BLOCK),
     # a cache the wide block does not divide
-    (32, 640, BLOCK), (32, 128, BLOCK), (32, 1152, BLOCK),
+    (4, 128, BF16, 640, BLOCK), (4, 128, BF16, 128, BLOCK), (4, 128, BF16, 1152, BLOCK),
 ])
-def test_the_walks_block_follows_the_query_rows_a_kv_head_brings(rep, t, block):
-    assert walk_block(rep, t) == block
+def test_the_walks_block_follows_the_bytes_a_block_copies(kv, dh, dtype, t, block):
+    assert walk_block(kv, dh, dtype, t) == block
     assert t % block == 0
 
 

@@ -17,10 +17,13 @@ import pytest
 from seldon_core_tpu.models.llm import DecoderLM
 from seldon_core_tpu.ops.decode_attention import (
     BLOCK,
+    WIDE_BLOCK,
+    cache_attention,
     cache_write,
     decode_attention,
     ragged_decode_attention,
     reads_ragged,
+    walk_block,
 )
 
 BLK = 128  # the tests' block: three of them make the cache
@@ -136,6 +139,97 @@ def test_kernel_block_sizes_agree(block, write):
     err = jnp.abs(got.astype(jnp.float32)
                   - _dots(q, k, v, lens).astype(jnp.float32))
     assert float(err.max()) <= 2 ** -6
+
+
+@pytest.mark.parametrize("why,kv,dh,dtype,t,block", [
+    # the decode shapes of the benchmark's seven configurations
+    # (benchmark/configs/*.json: KV heads, head size, the cache's dtype,
+    # server.max_seq): 128 keys of K and V over 8 KV heads of 128 are 512
+    # KiB, a copy that covers the walk's chain
+    ("internlm2-1.8b", 8, 128, "bfloat16", 2048, BLOCK),
+    ("mistral-7b-v0.3", 8, 128, "bfloat16", 2048, BLOCK),
+    # 256 KiB: the wide block
+    ("trinity-mini", 4, 128, "bfloat16", 4096, WIDE_BLOCK),
+    ("qwen3-next-80b-a3b", 2, 256, "bfloat16", 4096, WIDE_BLOCK),
+    ("sdar-30b-a3b", 4, 128, "bfloat16", 4096, WIDE_BLOCK),
+    # kernels of their own; the batcher's count asks for them all the same
+    ("joyai-llm-flash", 32, 64, "bfloat16", 6144, BLOCK),
+    ("evabyte", 32, 128, "bfloat16", 16384, BLOCK),
+    ("a cache length 256 does not divide", 4, 128, "bfloat16", 4096 - 128, BLOCK),
+    ("a float32 cache of 4 KV heads of 128", 4, 128, "float32", 4096, BLOCK),
+    ("a float32 cache of 2 KV heads of 128", 2, 128, "float32", 4096, WIDE_BLOCK),
+    ("an 8-bit cache of 8 KV heads of 128", 8, 128, "int8", 4096, WIDE_BLOCK),
+    ("7 KV heads of 128: 448 KiB", 7, 128, jnp.bfloat16, 2048, WIDE_BLOCK),
+])
+def test_the_walks_block_is_set_by_the_bytes_a_block_copies(
+        why, kv, dh, dtype, t, block):
+    """``walk_block``: ONE rule on the call's shapes, for both of the
+    kernel's entries, the scheduler's count and the sdar family's."""
+    assert walk_block(kv, dh, dtype, t) == block, why
+    assert t % block == 0
+
+
+def test_the_rule_answers_for_the_configurations_as_the_benchmark_holds_them():
+    """The same answers from the files themselves, so that a configuration
+    whose shapes move is seen to move its kernel's block."""
+    import json
+    import pathlib
+
+    configs = pathlib.Path(__file__).parent.parent / "benchmark" / "configs"
+    got = {}
+    for path in sorted(configs.glob("*.json")):
+        c = json.loads(path.read_text())
+        dh = c.get("head_dim") or c["hidden_size"] // c["num_attention_heads"]
+        got[path.stem] = walk_block(
+            c["num_key_value_heads"], dh, c["torch_dtype"], c["server"]["max_seq"])
+    assert got == {
+        "internlm2-1.8b": BLOCK, "mistral-7b-v0.3": BLOCK, "evabyte": BLOCK,
+        "joyai-llm-flash": BLOCK, "trinity-mini": WIDE_BLOCK,
+        "qwen3-next-80b-a3b": WIDE_BLOCK, "sdar-30b-a3b": WIDE_BLOCK}
+
+
+# lanes of the one-position call at the rule's 256 keys a block: (length,
+# write position). Under 256, on its edges, no multiple of it, the whole
+# cache; a write in the walk's last block (a step's: ``len - 1``), in a
+# block the read does not hold, parked; an idle lane
+_WIDE_T = 4 * WIDE_BLOCK
+_WIDE_LANES = [
+    (0, 70), (1, 0), (72, 71), (255, 254), (256, 255), (257, 256),
+    (300, 299), (511, 510), (513, 512), (700, 699), (_WIDE_T, _WIDE_T - 1),
+    (640, 900), (72, 600), (530, 3), (333, _WIDE_T),
+]
+
+
+@pytest.mark.parametrize("window", [None, 200, 256, 300, 600])
+@pytest.mark.parametrize("dtype,tol", [("float32", 1e-5), ("bfloat16", 2 ** -6)])
+def test_the_one_position_kernel_at_the_rules_wide_block(dtype, tol, window):
+    """No ``block=``: the call takes ``walk_block``'s 256 for its 2 KV heads
+    of 128. With and without ``starts`` (a window whose start lies inside a
+    block, on its edge, and before the cache's first position): the output
+    is the masked dots' over the cache the scatter made, both caches are
+    that cache bit for bit, an idle lane gives zeros and writes nothing."""
+    dtype = jnp.dtype(dtype)
+    q, k, v = _inputs(4, dtype, t=_WIDE_T, lanes=len(_WIDE_LANES), seed=7)
+    assert walk_block(k.shape[1], k.shape[3], k.dtype, _WIDE_T) == WIDE_BLOCK
+    lens, wp = (jnp.asarray(a, jnp.int32) for a in zip(*_WIDE_LANES))
+    starts = None if window is None else jnp.maximum(0, lens - window)
+    k_new, v_new = _rows(k)
+    got, gk, gv = ragged_decode_attention(
+        q, k, v, lens, k_new, v_new, wp, interpret=True, starts=starts)
+    live = np.asarray(lens) > 0
+    at = jnp.where(lens > 0, wp, _WIDE_T)[:, None]
+    sk, sv = cache_write(k, k_new, at), cache_write(v, v_new, at)
+    assert np.array_equal(_f32(gk), _f32(sk)) and np.array_equal(_f32(gv), _f32(sv))
+    want = cache_attention(q, sk, sv, lens - 1, dtype, lo=starts)
+    err = np.abs(_f32(got) - _f32(want))[live]
+    assert float(err.max()) <= tol, err.max(axis=(1, 2, 3))
+    assert not _f32(got)[~live].any()
+    # the same walk under 128 keys a block: the softmax's order alone
+    narrow, nk, nv = ragged_decode_attention(
+        q, k, v, lens, k_new, v_new, wp, interpret=True, starts=starts,
+        block=BLOCK)
+    assert np.array_equal(_f32(nk), _f32(gk)) and np.array_equal(_f32(nv), _f32(gv))
+    assert float(np.abs(_f32(narrow) - _f32(got)).max()) <= tol
 
 
 @pytest.mark.parametrize("rep", [2, 4])
@@ -476,20 +570,33 @@ def test_model_step_through_the_kernel(monkeypatch):
     assert float(jnp.abs(dots - some)[live].max()) < 0.05 * spread
 
 
-@pytest.mark.parametrize("pos,k,bucket,want", [
-    (0, 1, 128, BLOCK),                       # one key is one block
-    (BLOCK - 1, 1, 2 * BLOCK, BLOCK),         # the block's last position
-    (BLOCK, 1, 2 * BLOCK, 2 * BLOCK),         # the next block's first
-    (BLOCK - 2, 4, 4 * BLOCK, 2 * BLOCK + 2 * 2 * BLOCK),  # crossing inside
-    (3 * BLOCK, 8, 2 * BLOCK, 8 * 2 * BLOCK),  # never past the bucket
+@pytest.mark.parametrize("pos,k,bucket,block,want", [
+    (0, 1, 128, BLOCK, BLOCK),                       # one key is one block
+    (BLOCK - 1, 1, 2 * BLOCK, BLOCK, BLOCK),         # the block's last position
+    (BLOCK, 1, 2 * BLOCK, BLOCK, 2 * BLOCK),         # the next block's first
+    (BLOCK - 2, 4, 4 * BLOCK, BLOCK, 2 * BLOCK + 2 * 2 * BLOCK),  # crossing inside
+    # a lane's length is clamped to the bucket
+    (3 * BLOCK, 8, 2 * BLOCK, BLOCK, 8 * 2 * BLOCK),
+    # the wide block: whole blocks, past a bucket that 256 does not divide
+    (0, 1, 128, WIDE_BLOCK, 256),
+    (255, 1, 640, WIDE_BLOCK, 256),
+    (256, 1, 640, WIDE_BLOCK, 512),
+    (254, 4, 640, WIDE_BLOCK, 2 * 256 + 2 * 512),
+    (600, 4, 640, WIDE_BLOCK, 4 * 768),
+    (3 * BLOCK, 8, 2 * BLOCK, WIDE_BLOCK, 8 * 2 * BLOCK),
 ])
-def test_positions_streamed_rounds_each_step_to_the_block(pos, k, bucket, want):
+def test_positions_streamed_rounds_each_step_to_the_block(pos, k, bucket, block, want):
     from seldon_core_tpu.serving.continuous import _positions_streamed
 
-    assert _positions_streamed(pos, k, bucket, BLOCK) == want
+    assert _positions_streamed(pos, k, bucket, block) == want
 
 
-def test_batcher_counts_what_the_read_streams_and_what_the_bucket_held():
+@pytest.mark.parametrize("max_seq,block", [
+    (3 * BLOCK, BLOCK),       # a cache the wide block does not divide
+    (4 * BLOCK, WIDE_BLOCK),  # 2 KV heads of 8 in float32: the wide block
+])
+def test_batcher_counts_what_the_read_streams_and_what_the_bucket_held(
+        max_seq, block):
     """``kv_positions_read`` / ``kv_positions_bucket`` in ``stats`` (and so
     in a capture's counters): per dispatched burst, what the ragged read
     streams for the lanes active against rows x attn_len x steps; and
@@ -501,10 +608,11 @@ def test_batcher_counts_what_the_read_streams_and_what_the_bucket_held():
     )
 
     model = DecoderLM(vocab_size=256, d_model=32, n_layers=2, n_heads=4,
-                      n_kv_heads=2, d_ff=64, max_seq=4 * BLOCK, dtype="float32")
+                      n_kv_heads=2, d_ff=64, max_seq=max_seq, dtype="float32")
     b = ContinuousBatcher(model, model.init_params(0), slots=4,
-                          max_seq=4 * BLOCK, prefill_buckets=(8, 16),
+                          max_seq=max_seq, prefill_buckets=(8, 16),
                           steps_per_poll=2)
+    assert b._kv_read_block == block == walk_block(2, 8, "float32", max_seq)
     b.trace_groups = []
     try:
         assert b.stats["kv_positions_read"] == b.stats["kv_positions_bucket"] == 0
@@ -514,13 +622,13 @@ def test_batcher_counts_what_the_read_streams_and_what_the_bucket_held():
     finally:
         b.close()
     assert groups and {len(g["lanes"]) for g in groups} == {1}
-    # one lane of four, a few positions deep: a block a step, whatever the
-    # bucket; the dots read the bucket of all four rows
+    # one lane of four, a few positions deep: a block of the kernel's walk a
+    # step, whatever the bucket; the dots read the bucket of all four rows
     assert stats["kv_positions_bucket"] == sum(
         2 * 4 * g["attn_len"] for g in groups)
-    assert stats["kv_positions_read"] == len(groups) * _positions_streamed(5, 2, 128, BLOCK)
-    assert stats["kv_positions_read"] == 2 * BLOCK * len(groups)
-    assert stats["kv_positions_read"] * 4 <= stats["kv_positions_bucket"]
+    assert stats["kv_positions_read"] == len(groups) * _positions_streamed(5, 2, 128, block)
+    assert stats["kv_positions_read"] == 2 * block * len(groups)
+    assert stats["kv_positions_read"] * 2 <= stats["kv_positions_bucket"]
     counters = b.capture_counters()["counters"]
     assert "kv_positions_read" in counters
     # the write: one lane x 2 steps x 2 layers x (K, V) a burst, by the

@@ -157,30 +157,36 @@ def test_lanes_of_their_own_lengths_with_idle_ones_among_them(tiny):
         np.testing.assert_array_equal(np.asarray(cache["k"][l][1]), idle_before[l])
     named = dict(zip(model.step_counter_names, np.asarray(counts)))
     assert named["block_forwards"] == 6 and named["block_commit_forwards"] == 0
-    # a lane's length rounded up to the kernel's block (128 at this
-    # model's 8 query rows a KV head), two layers; and the lengths alone
-    assert named["block_rows_read"] == 2 * (3 * 128 + 3 * 256)
+    # a lane's length rounded up to the kernel's block (256 over this
+    # model's 2 KV heads of 16 in this cache of 256), two layers; and the
+    # lengths alone
+    assert named["block_rows_read"] == 2 * 6 * 256
     assert named["block_rows_live"] == 2 * sum(
         n - n % W + W for n in lens if n is not None)
     assert named["moe_rows_routed"] == 6 * W * 2 * 2
     assert named["moe_layer_steps"] == 2
 
 
-@pytest.mark.parametrize("heads,cache_len,block", [
-    (4, 1024, 128),     # 8 query rows a KV head: the one-position kernel's
-    (16, 1024, 256),    # 32 rows: the wide block
-    (16, 768, 256),
-    (16, 640, 128),     # which does not divide this cache
+@pytest.mark.parametrize("shape,cache_len,block", [
+    # 2 KV heads of 16 in float32: 128 keys of K and V are 32 KiB, the wide
+    # block, at 8 query rows a KV head as at 32
+    (dict(n_heads=4), 1024, 256),
+    (dict(n_heads=16), 1024, 256),
+    (dict(n_heads=16), 768, 256),
+    (dict(n_heads=16), 640, 128),     # which does not divide this cache
+    # 4 KV heads of 128 in float32: 512 KiB, a copy that covers the chain
+    (dict(n_heads=4, n_kv_heads=4, head_dim=128), 768, 128),
 ])
-def test_the_rows_read_round_to_the_block_the_kernel_walks(heads, cache_len, block):
+def test_the_rows_read_round_to_the_block_the_kernel_walks(shape, cache_len, block):
     """``block_rows_read`` is what the kernel streams (each live lane's
     length rounded up to ``walk_block``'s answer for the pass's shapes) and
     ``block_rows_live`` what the lengths hold, both over the layers; an
     idle lane adds to neither."""
     from seldon_core_tpu.ops.decode_attention import walk_block
 
-    model = DecoderLM(block="sdar_moe", **dict(SMALL, n_heads=heads))
-    assert walk_block(heads // 2 * W, cache_len) == block
+    model = DecoderLM(block="sdar_moe", **dict(SMALL, **shape))
+    cfg = model.cfg
+    assert walk_block(cfg.n_kv_heads, cfg.head_dim, cfg.dtype, cache_len) == block
     params = model.init_params(5)
     base = np.array([0, 124, 128, 252, 256, 508, 512, 600], np.int32)
     live = np.array([1, 1, 1, 1, 0, 1, 1, 1], bool)
