@@ -31,6 +31,7 @@ FAMILIES: Dict[str, str] = {
     "joyai_llm_flash": "seldon_core_tpu.models.joyai_llm_flash.JoyaiLLMFlashLM",
     "evabyte": "seldon_core_tpu.models.evabyte.EvaByteLM",
     "sdar_moe": "seldon_core_tpu.models.sdar_moe.SdarMoeLM",
+    "lfm2_moe": "seldon_core_tpu.models.lfm2_moe.Lfm2MoeLM",
 }
 
 
@@ -227,8 +228,9 @@ class DecoderFamily(ServedModel):
 
     def prefill_lengths(self, buckets, max_seq: int):
         """Of the batcher's prompt buckets (ascending), the padded lengths
-        this family's ``prefill`` takes; a prompt past the last goes to
-        ``max_seq``. Every length here."""
+        this family's ``prefill`` takes, and any of its own past them (a
+        family whose prompts run far past the batcher's last bucket); a
+        prompt past the last goes to ``max_seq``. Every bucket here."""
         return tuple(buckets)
 
     def prefill_rows_max(self, bucket: int) -> int:

@@ -11,7 +11,9 @@ and write strength ``beta`` in (0, 1) does
 
 ``conv_prefill()`` / ``conv_step()``  the causal depthwise convolution in
                       front of it (width ``K``, SiLU, no bias) and the
-                      tail of ``K - 1`` inputs a lane keeps between tokens
+                      tail of ``K - 1`` inputs a lane keeps between tokens;
+                      ``activation=None`` is the lfm2_moe block's gated
+                      short convolution, whose gates lie outside
 ``gated_delta_prefill()``  whole prompts in chunks of ``CHUNK`` positions
                       (the published kernels' 64): inside a chunk the
                       rule is a unit lower-triangular solve and a few
@@ -75,12 +77,24 @@ def l2norm(x):
 
 # -- the convolution in front -----------------------------------------------------
 
-def conv_prefill(x, w, lens):
-    """x [B, T, C] (the layer's q, k, v side by side), w [K, C] -> the
-    causal depthwise convolution with SiLU [B, T, C], and each sequence's
-    tail [B, K - 1, C]: its inputs at positions ``lens - K + 1 ..
-    lens - 1`` (zeros before the sequence's start), which is what the
-    next token's convolution reads."""
+def _activated(y, activation):
+    """The convolution's float32 output under ``activation``: "silu" (the
+    Gated DeltaNet's) or None (a gated short convolution's: its gates lie
+    outside). A Python constant in every caller's trace."""
+    if activation is None:
+        return y
+    if activation != "silu":
+        raise ValueError(f"unknown activation {activation!r}")
+    return jax.nn.silu(y)
+
+
+def conv_prefill(x, w, lens, activation="silu"):
+    """x [B, T, C] (the layer's q, k, v side by side; a gated short
+    convolution's ``B * u``), w [K, C] (tap j multiplies the input ``K - 1
+    - j`` positions back) -> the causal depthwise convolution under
+    ``activation`` [B, T, C], and each sequence's tail [B, K - 1, C]: its
+    inputs at positions ``lens - K + 1 .. lens - 1`` (zeros before the
+    sequence's start), which is what the next token's convolution reads."""
     t = x.shape[1]
     k = w.shape[0]
     padded = jnp.pad(x, ((0, 0), (k - 1, 0), (0, 0)))
@@ -90,17 +104,17 @@ def conv_prefill(x, w, lens):
           + jnp.arange(k - 1, dtype=jnp.int32))
     tail = jnp.take_along_axis(x, jnp.maximum(at, 0)[:, :, None], axis=1)
     tail = jnp.where(at[:, :, None] >= 0, tail, jnp.zeros_like(tail))
-    return jax.nn.silu(y).astype(x.dtype), tail
+    return _activated(y, activation).astype(x.dtype), tail
 
 
-def conv_step(x, tail, w, live):
+def conv_step(x, tail, w, live, activation="silu"):
     """x [B, C] this token's input, tail [B, K - 1, C] -> the convolution's
     output at this token [B, C] and the new tail; an idle lane's tail is
     kept as it is."""
     window = jnp.concatenate([tail, x[:, None].astype(tail.dtype)], axis=1)
     y = jnp.sum(window.astype(jnp.float32) * w.astype(jnp.float32)[None], 1)
     new = jnp.where(live[:, None, None], window[:, 1:], tail)
-    return jax.nn.silu(y).astype(x.dtype), new
+    return _activated(y, activation).astype(x.dtype), new
 
 
 # -- prefill -------------------------------------------------------------------------

@@ -645,3 +645,93 @@ def test_flash_kernel_compiled_for_v5e_under_the_block_mask(one_chip):
             assert f"bf16[32,{t},128]" in call
             texts.append(call)
         assert texts[0] != texts[1]
+
+
+def _lfm2(one_chip):
+    import jax
+    import jax.numpy as jnp
+
+    from benchmark import manifest
+    from seldon_core_tpu.models.llm import DecoderLM
+
+    with open(os.path.join(ROOT, "benchmark", "configs",
+                           "lfm2-24b-a2b.json")) as f:
+        cfg = json.load(f)
+    cfg["name"] = "lfm2-24b-a2b"
+    kwargs = manifest.architecture(
+        ROOT, manifest.load(ROOT), cfg["architecture"]).model_kwargs(cfg, 0)
+    kwargs.pop("seed")
+    model = DecoderLM(**kwargs)
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(
+        lambda a: sds(a.shape, jnp.dtype(model.cfg.dtype)),
+        jax.eval_shape(model.init_params, 0))
+    return cfg, model, params, sds
+
+
+def test_lfm2_burst_compiled_for_v5e_is_the_kernel_over_two_heads_a_row_in_place(one_chip):
+    """The configuration's own burst (64 lanes of 16,384, all 13 layers, no
+    bucket): the three attention layers decode through the ragged kernel
+    (not the dots) over rows of two heads of 64, 4 rows of 128 with 8 query
+    rows each, every expert layer's held experts through the touched-expert
+    kernel at width 1,536, all inside the ``while``; keys, values and the
+    ten convolution layers' tails are aliased through, and nothing of a
+    cache leaf's shape is copied or sliced out: no cache-sized copy a
+    step."""
+    import re
+
+    tool = _tool()
+    cfg, _model, _params, _sds = _lfm2(one_chip)
+    compiled, (lanes, kv, T, dh), cache_bytes, leaves = tool.compile_burst(
+        cfg, None, one_chip)
+    assert (lanes, kv, T, dh, leaves) == (64, 8, 16384, 64, 3 + 3 + 10)
+    # keys and values of 3 layers (6,144 B a position), tails of 10
+    assert cache_bytes == lanes * (3 * 2 * 8 * 64 * 2 * T + 10 * 2 * 2048 * 2)
+    hlo = compiled.as_text()
+    assert tool.kernel_calls(hlo) == {"inside": 3 + 12, "outside": 0}
+    names = re.findall(r"%([a-z_]+)[.\d]* = [^\n]*? custom-call\(", hlo)
+    assert names.count("touched_experts_ffn") == 12
+    assert names.count("ragged_decode_attention") == 3
+    # the kernel's call: 4 rows of two heads, 8 query rows each, 128 wide
+    call = next(line for line in hlo.splitlines()
+                if "custom-call(" in line and "ragged_decode_attention" in line)
+    assert "bf16[64,4,8,128]" in call and "bf16[64,4,16384,128]" in call
+    # as the cache lies ([lanes, 4, T, 128]) and as the config names it
+    for rows, width in ((4, 128), (8, 64)):
+        assert tool.cache_shaped(hlo, lanes, rows, (T,), width) == []
+        assert tool.cache_scatters(hlo, lanes, rows, T, width) == 0
+    assert tool.alias_count(hlo) >= leaves
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= cache_bytes
+    assert mem.temp_size_in_bytes < 256 << 20
+
+
+def test_lfm2_prefill_compiled_for_v5e_is_flash_at_head_64_and_grouped_at_1536(
+        one_chip, monkeypatch):
+    """The configuration's own prefill of one prompt in the 8192 bucket (all
+    13 layers, counters and all), lowered as on a TPU: the three attention
+    layers through the flash kernel at a head of 64 and the rule's tile,
+    every expert layer's held experts through ONE grouped kernel call a
+    layer at width 1,536 (an expert's three matrices whole, 18.9 MB, two
+    slots apiece, inside its 64 MB of VMEM)."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    cfg, model, params, sds = _lfm2(one_chip)
+    # ``ops.attention`` asks the process's backend, which is the CPU here
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    T = 8192
+    hlo = jax.jit(lambda p, t, last: model.prefill_counted(p, t, T, last)).lower(
+        params, sds((1, T), jnp.int32), sds((1,), jnp.int32)).compile().as_text()
+    names = re.findall(r"%([a-z_]+)[.\d]* = [^\n]*? custom-call\(", hlo)
+    assert names.count("grouped_swiglu") == 12
+    flash = [line for line in hlo.splitlines()
+             if "custom-call(" in line and "flash_attention" in line]
+    assert len(flash) == 3 and all(f"bf16[32,{T},64]" in line for line in flash)
+    # the tails come out at the prompts' own lengths: [10, 1, 2, 2048]
+    assert "bf16[10,1,2,2048]" in hlo

@@ -45,12 +45,17 @@ SMALL = {
                      n_kv_heads=2, head_dim=16, max_seq=128,
                      n_routed_experts=4, experts_per_tok=2, expert_width=32,
                      denoising_steps=2, mask_token_id=63),
+    "lfm2_moe": dict(vocab_size=64, d_model=64, n_layers=2, n_heads=4,
+                     n_kv_heads=2, head_dim=16, d_ff=128, max_seq=128,
+                     layer_types=("conv", "full_attention"), conv_kernel=3,
+                     n_dense_layers=1, n_routed_experts=4, experts_per_tok=2,
+                     expert_width=32),
 }
 # a field that is one family's own, for every OTHER block an unknown keyword
 OWN_FIELD = {
     "afmoe": "sliding_window", "qwen3_next": "linear_conv_kernel",
     "joyai_llm_flash": "kv_lora_rank", "evabyte": "window_size",
-    "sdar_moe": "block_length",
+    "sdar_moe": "block_length", "lfm2_moe": "conv_kernel",
 }
 # the optional paths and the ``serving_refuses`` feature that guards each
 GUARDED = {
@@ -64,7 +69,7 @@ LLAMA_OWN = ("decode_step_ragged_list", "backbone", "loss_fn", "_decode",
              "decode_step", "decode_step_ragged", "generate")
 
 
-def test_the_registry_names_the_six_blocks():
+def test_the_registry_names_the_seven_blocks():
     assert sorted(SMALL) == sorted(families.FAMILIES)
 
 
