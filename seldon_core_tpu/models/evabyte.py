@@ -253,7 +253,7 @@ class EvaByteLM(DecoderFamily):
             b % cfg.chunk_size == 0 and b <= cfg.window_size)
             or b % cfg.window_size == 0)
 
-    def prefill_rows_max(self, bucket: int) -> int:
+    def prefill_rows_max(self, bucket: int, added: bool = False) -> int:
         """A walked bucket takes one prompt a call: a batch would walk
         every row through the longest row's windows and return a ring and
         all summaries a row (``prefill_slab_bytes``)."""

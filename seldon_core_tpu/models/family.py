@@ -226,16 +226,37 @@ class DecoderFamily(ServedModel):
         per_position = self.cache_position_bytes(cache)
         return lambda positions: positions * per_position
 
-    def prefill_lengths(self, buckets, max_seq: int):
-        """Of the batcher's prompt buckets (ascending), the padded lengths
-        this family's ``prefill`` takes, and any of its own past them (a
-        family whose prompts run far past the batcher's last bucket); a
-        prompt past the last goes to ``max_seq``. Every bucket here."""
-        return tuple(buckets)
+    # the padded lengths a prefill takes past the batcher's own buckets:
+    # every multiple of this below the cache's length. The batcher's
+    # buckets end at 1792, and a prompt of 2,048 padded to a ``max_seq`` of
+    # 4096 pays twice its projections and four times its attention. 512 is
+    # the flash kernel's key tile (``ops.flash_attention._tile``) and a
+    # multiple of every family's chunk and block: a constant, not a knob
+    PREFILL_STEP = 512
 
-    def prefill_rows_max(self, bucket: int) -> int:
-        """The most prompts one batched prefill in ``bucket`` takes."""
-        return 8
+    def prefill_lengths(self, buckets, max_seq: int):
+        """The padded lengths this family's ``prefill`` takes, ascending: of
+        the batcher's prompt buckets those it takes (every one here), then
+        every multiple of ``PREFILL_STEP`` past the last and below
+        ``max_seq``, so that a prompt past the buckets is prefilled at its
+        own length rounded up and not at the cache's. A prompt past them
+        all goes to ``max_seq``. A length is compiled where ``warm()`` was
+        told of a prompt that pads to it, else on first use."""
+        step = self.PREFILL_STEP
+        more = range(-(-(max(buckets, default=0) + 1) // step) * step,
+                     max_seq, step)
+        return (*buckets, *more)
+
+    def prefill_rows_max(self, bucket: int, added: bool = False) -> int:
+        """The most prompts one batched prefill in ``bucket`` takes;
+        ``added`` where the batcher was not configured with the length and
+        ``prefill_lengths`` added it. Such a length takes ONE: a batched
+        prefill exists to fill the matrix unit and to read the weights once
+        for several prompts, which the rows of one prompt past the
+        batcher's last bucket do already, a turn that admits four of one
+        long length is rare outside the ramp, and so a length costs one
+        executable to warm and not three."""
+        return 1 if added else 8
 
     def block_tokens(self) -> int:
         """Positions ONE decode step covers in a lane: 1, a token a lane and

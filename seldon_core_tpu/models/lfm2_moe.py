@@ -278,24 +278,14 @@ class Lfm2MoeLM(DecoderFamily):
         return rows * (bucket * self.kv_bytes_per_token()
                        + self.tail_bytes_per_lane())
 
-    # the padded lengths a prefill takes past the batcher's own buckets:
-    # every multiple of this up to the cache's length. A context here is
-    # 1.5k-14k where the batcher's buckets end at 1792 and a prompt past
-    # them is padded to ``max_seq``: 16,384 rows for a prompt of 4,100 are
-    # four times its projections and sixteen times its attention. 512 is
-    # the flash kernel's key tile (``ops.flash_attention._tile``)
-    PREFILL_STEP = 512
     # the rows (prompts x bucket) one batched prefill takes: ``x W_in`` of
-    # eight prompts of 16,384 alone is 1.6 GB beside a cache that leaves 5
+    # eight prompts of 16,384 alone is 1.6 GB beside a cache that leaves 5.
+    # A context here is 1.5k-14k: past the batcher's 1792 the lengths are
+    # the default rule's (``DecoderFamily.prefill_lengths``: a prompt of
+    # 4,100 pads to 4,608 and not to 16,384), one prompt a call either way
     PREFILL_ROWS = 16384
 
-    def prefill_lengths(self, buckets, max_seq: int):
-        last = max(buckets, default=0)
-        more = range(-(-(last + 1) // self.PREFILL_STEP) * self.PREFILL_STEP,
-                     max_seq, self.PREFILL_STEP)
-        return (*buckets, *more)
-
-    def prefill_rows_max(self, bucket: int) -> int:
+    def prefill_rows_max(self, bucket: int, added: bool = False) -> int:
         return max(1, min(8, self.PREFILL_ROWS // max(1, bucket)))
 
     # ``admissions_per_turn`` stays the default, every free lane: the

@@ -494,11 +494,16 @@ class ContinuousBatcher:
         self.draft_model = draft_model
         self.speculate_tokens = int(speculate_tokens) if draft_model is not None else 0
         # the family says which of them its prefill takes (all, but for
-        # one whose prefill is laid out in windows)
-        self.prefill_buckets = tuple(model.prefill_lengths(
-            sorted(b for b in prefill_buckets if b <= self.max_seq),
-            self.max_seq,
-        )) or (self.max_seq,)
+        # one whose prefill is laid out in windows) and which lengths past
+        # them a long prompt pads to instead of ``max_seq``
+        own = sorted(b for b in prefill_buckets if b <= self.max_seq)
+        self.prefill_buckets = tuple(
+            model.prefill_lengths(own, self.max_seq)) or (self.max_seq,)
+        # the lengths the family added: they take the prompts a call the
+        # family gives an added length (``_rows_ok``), the configured
+        # buckets and ``max_seq`` what they always took
+        self._added_lengths = frozenset(self.prefill_buckets) - {
+            *own, self.max_seq}
         # and how many prompts a turn admits before the next burst (every
         # free lane, but for a family whose prefill holds the device long)
         self._admit_cap = int(model.admissions_per_turn()) or self.slots
@@ -619,7 +624,9 @@ class ContinuousBatcher:
         # emitted/rounds ranges 1 (nothing accepted) .. gamma+1 (all).
         # prefill_steps/prefill_tokens split device prefill work out from
         # decode steps (the prefix cache's win shows up as prefill_tokens
-        # dropping while prefix_tokens_saved climbs)
+        # dropping while prefix_tokens_saved climbs); prefill_tokens counts
+        # the padded rows computed, prefill_prompt_tokens those of them
+        # that held a token of a prompt: their ratio is what padding costs
         # burst_reads/burst_read_bytes: modeled HBM read traffic of
         # dispatched decode bursts — params once per step plus each
         # lane-row's bucketed KV read (spec rounds are excluded: their
@@ -631,6 +638,7 @@ class ContinuousBatcher:
             "lane_steps": 0,
             "tokens": 0, "spec_rounds": 0, "spec_emitted": 0,
             "prefill_steps": 0, "prefill_tokens": 0, "prefill_chunks": 0,
+            "prefill_prompt_tokens": 0,
             "prefix_hits": 0, "prefix_misses": 0, "prefix_evicted": 0,
             "prefix_tokens_saved": 0, "prefix_cache_bytes": 0,
             "shed": 0,
@@ -2032,7 +2040,7 @@ class ContinuousBatcher:
         bucket = self._bucket(n)
         covered = max(0, min(int(covered_len), n - 1))
         C = self.prefill_chunk
-        chunks = 0
+        chunks = held = 0
         if C and bucket > C:
             # the staging path: one _chunk_fn slice at a time, same
             # offsets/slide-back as _advance_chunks, final slice samples
@@ -2059,6 +2067,7 @@ class ContinuousBatcher:
                     )
                     _m.sync(slab)
                 chunks += 1
+                held += end - s
                 if is_last:
                     break
                 start = end
@@ -2121,6 +2130,7 @@ class ContinuousBatcher:
             self.stats["kv_export_bytes"] += nbytes
             self.stats["prefill_steps"] += max(1, chunks)
             self.stats["prefill_tokens"] += chunks * C if chunks else bucket
+            self.stats["prefill_prompt_tokens"] += held if chunks else n
             self.stats["prefill_chunks"] += chunks
             # kv_transfer_bytes_saved is counted on the DECODE side only
             # (the pool whose radix cache made the dedup decision): the
@@ -3483,7 +3493,8 @@ class ContinuousBatcher:
     def _rows_ok(self, m: int, bucket: int) -> bool:
         """Whether the family's prefill in ``bucket`` takes ``m`` prompts a
         call (one alone always)."""
-        return m == 1 or m <= self.model.prefill_rows_max(bucket)
+        return m == 1 or m <= self.model.prefill_rows_max(
+            bucket, added=bucket in self._added_lengths)
 
     def _bucket(self, n: int) -> int:
         for b in self.prefill_buckets:
@@ -3788,6 +3799,7 @@ class ContinuousBatcher:
             # its whole bucket): prefill_tokens is a device-work proxy,
             # not a real-prompt-token count
             self.stats["prefill_tokens"] += C
+            self.stats["prefill_prompt_tokens"] += end - start
             self.stats["prefill_chunks"] += 1
             self._emit_span(
                 req, "gen.prefill_chunk", t_chunk, time.monotonic(),
@@ -4972,6 +4984,7 @@ class ContinuousBatcher:
             self.stats["prefix_tokens_saved"] += m
             self.stats["prefill_steps"] += 1
             self.stats["prefill_tokens"] += wb
+            self.stats["prefill_prompt_tokens"] += n - m
         else:
             bucket = self._bucket(n)
             prompt = np.zeros((1, bucket), np.int32)
@@ -5002,6 +5015,7 @@ class ContinuousBatcher:
                 self.stats["prefix_misses"] += 1
             self.stats["prefill_steps"] += 1
             self.stats["prefill_tokens"] += bucket
+            self.stats["prefill_prompt_tokens"] += n
         self._activate_resumed(slot, req, emitted)
         self._emit_span(
             req, "gen.resume", t_admit, time.monotonic(),
@@ -5063,6 +5077,7 @@ class ContinuousBatcher:
             self.stats["prefix_tokens_saved"] += m
             self.stats["prefill_steps"] += 1
             self.stats["prefill_tokens"] += wb
+            self.stats["prefill_prompt_tokens"] += n - m
             self._emit_span(
                 req, "gen.prefill", t_admit, t_insert,
                 tags={"lane": slot, "bucket": wb, "cache_hit_tokens": m,
@@ -5102,6 +5117,7 @@ class ContinuousBatcher:
                 self.stats["prefix_misses"] += 1
             self.stats["prefill_steps"] += 1
             self.stats["prefill_tokens"] += bucket
+            self.stats["prefill_prompt_tokens"] += n
             self._emit_span(
                 req, "gen.prefill", t_admit, t_insert,
                 tags={"lane": slot, "bucket": bucket, "dispatch": True},
@@ -5237,6 +5253,8 @@ class ContinuousBatcher:
         self._count_admitted(*reqs)
         self.stats["prefill_steps"] += 1
         self.stats["prefill_tokens"] += m * bucket
+        self.stats["prefill_prompt_tokens"] += sum(
+            len(req.tokens) for req in reqs)
         if self._prefix_index is not None:
             self.stats["prefix_misses"] += m
 

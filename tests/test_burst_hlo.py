@@ -293,8 +293,12 @@ def test_qwen3_next_burst_compiled_for_v5e_is_kernels_over_a_cache_in_place(one_
     assert mem.temp_size_in_bytes < 256 << 20
 
 
-def test_qwen3_next_prefill_compiled_for_v5e_is_one_delta_kernel_a_linear_layer(one_chip):
-    """The configuration's own prefill of one prompt in the 4096 bucket
+# the cache's length (a prompt past 3584) and the padded length the longbatch
+# mix's 3328-token prompts prefill at (``DecoderFamily.prefill_lengths``)
+@pytest.mark.parametrize("T", [4096, 3584])
+def test_qwen3_next_prefill_compiled_for_v5e_is_one_delta_kernel_a_linear_layer(
+        one_chip, T):
+    """The configuration's own prefill of one prompt at ``T`` rows
     (all 8 layers, counters and all): each of the 6 linear layers' gated
     delta rule is ONE ``gated_delta_prefill`` kernel call, and no ``while``
     is left under that scope (off a TPU it is a ``lax.scan`` over the
@@ -322,7 +326,7 @@ def test_qwen3_next_prefill_compiled_for_v5e_is_one_delta_kernel_a_linear_layer(
     params = jax.tree_util.tree_map(
         lambda a: sds(a.shape, jnp.dtype(model.cfg.dtype)),
         jax.eval_shape(model.init_params, 0))
-    T = cfg["server"]["max_seq"]
+    assert T <= cfg["server"]["max_seq"]
     hlo = jax.jit(lambda p, t, last: model.prefill_counted(p, t, T, last)).lower(
         params, sds((1, T), jnp.int32), sds((1,), jnp.int32)).compile().as_text()
     names = re.findall(r"%([a-z_]+)[.\d]* = [^\n]*? custom-call\(", hlo)
@@ -372,10 +376,12 @@ def test_the_expert_cells_burst_is_one_program_with_and_without_the_grouped_kern
     assert same["kernels_equal_but_for_locations"] and same["kernels"] > 0
 
 
-def test_trinity_mini_prefill_compiled_for_v5e_is_one_grouped_kernel_a_layer(one_chip):
-    """The configuration's own prefill of one prompt in the 4096 bucket (all
+@pytest.mark.parametrize("T", [4096, 3584])
+def test_trinity_mini_prefill_compiled_for_v5e_is_one_grouped_kernel_a_layer(
+        one_chip, T):
+    """The configuration's own prefill of one prompt at ``T`` rows (all
     6 layers, counters and all): each of the 4 expert layers' grouped
-    experts is ONE ``grouped_swiglu`` call over the 32,768 sorted pairs,
+    experts is ONE ``grouped_swiglu`` call over the ``8 T`` sorted pairs,
     and no ``ragged-dot`` is left."""
     import re
 
@@ -399,7 +405,7 @@ def test_trinity_mini_prefill_compiled_for_v5e_is_one_grouped_kernel_a_layer(one
     params = jax.tree_util.tree_map(
         lambda a: sds(a.shape, jnp.dtype(model.cfg.dtype)),
         jax.eval_shape(model.init_params, 0))
-    T = cfg["server"]["max_seq"]
+    assert T <= cfg["server"]["max_seq"]
     compiled = jax.jit(
         lambda p, t, last: model.prefill_counted(p, t, T, last)).lower(
         params, sds((1, T), jnp.int32), sds((1,), jnp.int32)).compile()
@@ -408,8 +414,8 @@ def test_trinity_mini_prefill_compiled_for_v5e_is_one_grouped_kernel_a_layer(one
     assert names.count("grouped_swiglu") == 4
     assert "ragged-dot" not in hlo and "ragged_dot" not in hlo
     # the sorted rows and the weighted products of a layer, in bfloat16:
-    # nothing [32768, 1024] is written for the SwiGLU, in any type
-    assert not re.search(r"= \w+\[32768,1024\]", hlo)
+    # nothing [8 T, 1024] is written for the SwiGLU, in any type
+    assert not re.search(rf"= \w+\[{8 * T},1024\]", hlo)
     # the parent's scratch (2,297 MiB; most of it outside the experts)
     assert compiled.memory_analysis().temp_size_in_bytes < 2400 << 20
 
@@ -552,6 +558,9 @@ def test_flash_kernel_compiled_for_v5e_behind_a_visible_prefix(one_chip):
     (32, 4096, 128, 2048),    # trinity-mini's band
     (32, 4096, 128, None),
     (16, 4096, 256, None),    # qwen3-next: whole K and V of 2 MiB each resident
+    (32, 3584, 128, 2048),    # the lengths a prompt past 1792 pads to: seven
+    (16, 3584, 256, None),    # key tiles, and four
+    (16, 2048, 256, None),
     (8, 1024, 64, None),
 ])
 def test_flash_kernel_compiled_for_v5e_at_the_rules_tile(one_chip, heads, t, dh, window):

@@ -396,7 +396,9 @@ def test_the_accepted_families_answers_are_what_they_were(family):
     """One row a position, positions from 0, one length for every kind: the
     drop index is the positions' axis's end, a lane's bytes are its
     positions times ``cache_position_bytes``, every bucket is taken and a
-    batched prefill takes eight rows, as before the scheduler asked."""
+    batched prefill in one takes eight rows, as before the scheduler asked
+    (past the buckets every 512 below ``max_seq``, the default rule since
+    PR 52: ``tests/test_prefill_lengths.py``)."""
     m = DecoderLM(**FAMILIES[family])
     cache = m.cache_layers(3, 128)
     assert m.park_index(cache) == 128 == m.position_layers(cache)[0].shape[-2]
@@ -405,7 +407,9 @@ def test_the_accepted_families_answers_are_what_they_were(family):
     price = m.lane_cache_bytes(cache)
     assert [price(n) for n in (0, 1, 100, 128)] == [0, per, 100 * per, 128 * per]
     buckets = (32, 128, 512, 1024, 1792)
-    assert m.prefill_lengths(buckets, 4096) == buckets
+    assert m.prefill_lengths(buckets, 2048) == buckets
+    assert m.prefill_lengths(buckets, 4096) == (
+        *buckets, 2048, 2560, 3072, 3584)
     assert [m.prefill_rows_max(b) for b in buckets] == [8] * 5
     assert m.admissions_per_turn() == 0
 
