@@ -2668,7 +2668,7 @@ def bench_rag(
     from .graph.spec import PredictorSpec, default_predictor
     from .graph.units import RagPromptBuilder
     from .resilience.faults import FaultInjector
-    from .servers.generateserver import GenerateServer
+    from .servers.generateserver import COMPILE_TELEMETRY_KEYS, GenerateServer
     from .servers.jaxserver import JAXServer
 
     vocab = (llm_config or {}).get("vocab_size", 256)
@@ -2740,11 +2740,14 @@ def bench_rag(
     def scrub(out: Dict[str, Any]) -> Dict[str, Any]:
         out = json.loads(json.dumps(out))
         out.get("meta", {}).pop("puid", None)
-        # TIMER metrics are wall-clock telemetry, not data
+        # TIMER metrics are wall-clock telemetry, not data; nor is what
+        # XLA compiled when (a shape's first call compiles, whichever
+        # executor makes it)
         m = out.get("meta", {})
         if "metrics" in m:
             m["metrics"] = [
                 x for x in m["metrics"] if x.get("type") != "TIMER"
+                and x.get("key") not in COMPILE_TELEMETRY_KEYS
             ]
         return out
 

@@ -194,6 +194,12 @@ from .jaxserver import JAXServer
 
 logger = logging.getLogger(__name__)
 
+# the counters that say what XLA compiled after the unit said ready. When a
+# shape's first call comes is not data: a comparison of two responses byte
+# for byte leaves these out, as it leaves TIMERs out
+COMPILE_TELEMETRY_KEYS = ("gen_compiles_after_ready",
+                          "gen_compile_after_ready_s")
+
 
 @dataclasses.dataclass
 class StreamHandle:
@@ -466,8 +472,15 @@ class GenerateServer(SeldonComponent):
 
     def load(self) -> None:
         from ..serving.continuous import ContinuousBatcher
+        from ..tracing import (
+            compile_report, compile_stage, install_compile_log,
+            register_capture_source,
+        )
 
         t_load = time.monotonic()
+        # every XLA compile from here on lands in the process's compile
+        # log under its name and the stage in force: load, warm, serve
+        install_compile_log()
         server = JAXServer(self.model_uri)
         apply_fn, params = server.build()
         self._model = server._model
@@ -589,8 +602,6 @@ class GenerateServer(SeldonComponent):
         )
         # tracing.start_capture / stop_capture report this batcher's
         # counters, loop phases and request timelines
-        from ..tracing import register_capture_source
-
         register_capture_source(self.batcher)
         # chaos harness (off without SELDON_FAULTS): the scheduler
         # section wires induced poll death onto the batcher's fault
@@ -622,6 +633,7 @@ class GenerateServer(SeldonComponent):
         # the UNSHARDED tree, whole on the first chip through warm-up
         del params
         t_warm = time.monotonic()
+        compile_stage("warm")
         if self._warmup_prompt_lens:
             # compile-before-listen: every prefill/insert/burst variant the
             # declared traffic shape needs is built here, so the first
@@ -630,6 +642,8 @@ class GenerateServer(SeldonComponent):
                 prompt_lens=self._warmup_prompt_lens,
                 max_new_tokens=self._warmup_max_new_tokens,
             )
+        # what compiles from here on, a request waits for
+        compile_stage("serve")
         if self._role == "prefill":
             # no scheduler loop: export_prefill runs on the transport's
             # handler threads, decode lanes never activate
@@ -662,16 +676,26 @@ class GenerateServer(SeldonComponent):
             list(self._mesh.devices.flat) if self._mesh is not None
             else jax.devices()[:1]
         )
+        # of warm_s: the seconds JAX traced and lowered (what a warm
+        # compile cache does not save) and those in the backend (it does);
+        # the cache's hits and misses over load and warm
+        stages = compile_report()["stages"]
+        warm = stages.get("warm", {})
         logger.info(
             "generateserver: %s ready (role=%s, slots=%d, max_seq=%d) "
             "platform=%s device_kind=%r visible_devices=%d serving_devices=%d "
-            "bytes_in_use=%s load_s=%.1f warm_s=%.1f",
+            "bytes_in_use=%s load_s=%.1f warm_s=%.1f warm_trace_lower_s=%.1f "
+            "warm_compile_s=%.1f cache_hits=%d cache_misses=%d",
             self.model_uri, self._role, self._slots, self.batcher.max_seq,
             devices[0].platform, devices[0].device_kind, jax.device_count(),
             len(devices),
             # None where the backend keeps no memory stats (CPU)
             [(d.memory_stats() or {}).get("bytes_in_use") for d in devices],
             t_warm - t_load, time.monotonic() - t_warm,
+            warm.get("trace_s", 0.0) + warm.get("lower_s", 0.0),
+            warm.get("backend_s", 0.0),
+            *(sum(stages.get(s, {}).get(k, 0) for s in ("load", "warm"))
+              for k in ("cache_hits", "cache_misses")),
         )
 
     def _load_tenants(self, primary_params) -> None:
@@ -1737,6 +1761,11 @@ class GenerateServer(SeldonComponent):
             delta("gen_burst_reads", s["burst_reads"]),
             delta("gen_burst_read_bytes", s["burst_read_bytes"]),
         ]
+        if s.get("compiles_after_ready"):
+            # XLA compiled while serving: a request waited for an
+            # executable warm() was not told of (declare the length)
+            out.extend(delta(key, s[key[len("gen_"):]])
+                       for key in COMPILE_TELEMETRY_KEYS)
         if s.get("prefill_chunks"):
             out.append(delta("gen_prefill_chunks", s["prefill_chunks"]))
         if s.get("fused_dispatches"):

@@ -68,7 +68,9 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from ..analysis.roles import caller_thread, scheduler_only
-from ..tracing import PhaseClock, get_tracer, wall_us
+from ..tracing import (
+    HostClock, PhaseClock, compile_report, compiles_since, get_tracer, wall_us,
+)
 
 logger = logging.getLogger(__name__)
 
@@ -730,6 +732,10 @@ class ContinuousBatcher:
         self.stats.update({
             "polls": 0, "bursts": 0, "bursts_read_late": 0,
             "burst_read_lag_s_sum": 0.0,
+            # XLA compiles since the generate unit said ready (stage
+            # ``serve`` of tracing.CompileLog), as the poll rows took them
+            # along: backend compiles, and the seconds of every kind
+            "compiles_after_ready": 0, "compile_after_ready_s": 0.0,
         })
         self._clock = PhaseClock(self.stats, "batcher", "loop", LOOP_PHASES)
         # per-tenant splits of the same samples (multi-tenant serving):
@@ -755,6 +761,13 @@ class ContinuousBatcher:
         # requests admitted since the last one
         self._row_bursts: List[Dict[str, Any]] = []
         self._row_admitted: List[int] = []
+        # and, with the ring on, the host's account of the scheduler
+        # thread over the record's stretch (its heartbeat lives as long
+        # as that thread: _run) and the compile log's events since
+        self._host: Optional[HostClock] = (
+            HostClock() if self.flight is not None else None
+        )
+        self._compile_cursor = compiles_since()[0]
         # the last chunk dispatched that was not a job's last, as (an
         # output of it, self._cur_tok then): see _device_drained
         self._chunk_newest: Optional[Tuple[Any, Any]] = None
@@ -1766,6 +1779,11 @@ class ContinuousBatcher:
         """The flight recorder's rows as they stand, oldest first, every
         type (``t`` absolute monotonic seconds); ``[]`` with the ring off."""
         return self.flight.snapshot() if self.flight is not None else []
+
+    def capture_compiles(self) -> Dict[str, Any]:
+        """The process's compile log as it stands
+        (``tracing.CompileLog.report``: absolute, by stage and by name)."""
+        return compile_report()
 
     @caller_thread
     def _shed_check(
@@ -5556,12 +5574,18 @@ class ContinuousBatcher:
         transient device/driver fault costs seconds, not a pod."""
         self._started.set()
         self._clock.start()
+        if self._host is not None:
+            self._host.start()
+            self._host.beat.start()
         try:
             while not self._stop.is_set():
                 if not self._loop():
                     return
         finally:
             self._clock.stop()
+            if self._host is not None:
+                self._host.beat.stop()
+                self._host.stop()
 
     @scheduler_only
     def _fail_inflight(self, pending, err: Exception) -> None:
@@ -5672,7 +5696,15 @@ class ContinuousBatcher:
         that wrote none (idle, or reads only): ``t`` is where the stretch
         began (monotonic), ``phase_s`` the clock's seconds by phase over
         it (``PhaseClock.lap``: the rows lie end to end and sum to the
-        clock's totals), ``bursts`` each burst read in it (``dispatch_t``,
+        clock's totals), ``host`` what the host did to the thread over the
+        same stretch (``HostClock.lap``, taken where the clock is lapped:
+        its seconds on a core and runnable with none, the machine's busy
+        share, the latest heartbeat, collector seconds), ``compiles`` what
+        XLA compiled since the generate unit said ready and the last
+        record (``tracing.CompileLog``'s ``serve`` events, each with its
+        name, kind, seconds and what the persistent cache said; counted
+        into ``stats["compiles_after_ready"]`` and
+        ``["compile_after_ready_s"]``), ``bursts`` each burst read in it (``dispatch_t``,
         ``read_t``, ``k``, ``lanes``, ``late``: the device had finished it
         first), ``dispatched_t`` when this poll's burst went out,
         ``poll`` the loop's poll number and ``admitted_ids`` the requests
@@ -5685,7 +5717,6 @@ class ContinuousBatcher:
 
         from ..tracing import device_trace
 
-        temps = np.zeros((self.slots,), np.float32)
         # in-flight bursts, oldest first: (mode, device arrays to read,
         # (lane snapshot, ...), dispatch time) — see _read_burst
         pending: "collections.deque" = collections.deque()
@@ -5971,12 +6002,15 @@ class ContinuousBatcher:
                     if flight is not None and drained is None:
                         drained = self._device_drained()
                     if self._masks_dirty:
-                        for i in range(self.slots):
-                            temps[i] = (
-                                self._active[i].request.temperature
-                                if i in self._active
-                                else 0.0
-                            )
+                        # a NEW array each time, as ``active`` is: the
+                        # CPU backend's jnp.asarray may alias the numpy
+                        # buffer, and a burst still in flight reads the
+                        # temperatures it was dispatched with (one array
+                        # rewritten in place let a lane freed at its last
+                        # burst's dispatch sample that burst greedily)
+                        temps = np.zeros((self.slots,), np.float32)
+                        for i, state in self._active.items():
+                            temps[i] = state.request.temperature
                         active = np.zeros((self.slots,), bool)
                         for i in self._active:
                             active[i] = True
@@ -6324,6 +6358,15 @@ class ContinuousBatcher:
                     # the reads are done: the record's stretch ends at the
                     # last switch of the clock (no read of its own)
                     entry["t"], entry["phase_s"] = clock.lap()
+                    entry["host"] = self._host.lap()
+                    self._compile_cursor, compiled = compiles_since(
+                        self._compile_cursor)
+                    if compiled:
+                        entry["compiles"] = compiled
+                        self.stats["compiles_after_ready"] += sum(
+                            e["kind"] == "backend" for e in compiled)
+                        self.stats["compile_after_ready_s"] += sum(
+                            e["s"] for e in compiled)
                     if self._row_bursts:
                         entry["bursts"] = self._row_bursts
                         self._row_bursts = []

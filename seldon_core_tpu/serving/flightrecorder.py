@@ -19,12 +19,46 @@ under the GIL); readers snapshot with ``list(...)`` and never block the
 scheduler. ``enabled = False`` short-circuits to a single attribute
 check on the hot path.
 
-Every record carries ``t`` (``time.monotonic()``: the clock of the
-request stamps and of a capture's ``t0``/``t1``) and ``t_us`` (the same
-moment as wall-clock microseconds, for the route's readers). A ``poll``
-record is a span, not a point: its ``t`` is where the stretch it accounts
-for began, and its ``phase_s`` says where the scheduler thread's time
-went from there (see ``ContinuousBatcher._loop``).
+Every record carries ``type``, ``seq``, ``t`` (``time.monotonic()``: the
+clock of the request stamps and of a capture's ``t0``/``t1``) and ``t_us``
+(the same moment as wall-clock microseconds, for the route's readers).
+
+A ``poll`` record (one per scheduler poll that admitted, advanced a chunk
+or dispatched a burst; ``ContinuousBatcher._loop``) is a span, not a
+point. ``t`` is where the stretch it accounts for began; over that
+stretch, end to end with the record before it:
+
+``phase_s``       the scheduler thread's seconds by phase
+                  (``tracing.PhaseClock.lap``)
+``host``          the host's account of that thread
+                  (``tracing.HostClock.lap``): ``cpu_s`` on a core and
+                  ``runq_s`` runnable with none, the machine's
+                  ``busy_share``, the latest heartbeat ``beat_late_s``,
+                  collector seconds ``gc_s`` (left out at 0; any field the
+                  host cannot give is left out)
+``compiles``      what XLA compiled since the unit said ready
+                  (``tracing.CompileLog``: ``{t, name, kind, s, cache}``
+                  each; left out when nothing did)
+``bursts``        each burst read back (``dispatch_t``, ``read_t``, ``k``,
+                  ``lanes``, ``late``)
+
+and as points: ``poll`` (the loop's poll number), ``queue``, ``active``,
+``chunked``, ``pending_bursts``, ``drained`` (the device had finished all
+it was given at the poll's first dispatch), ``plan`` and ``dispatched_t``
+(the burst it sent), ``admitted`` / ``admitted_ids``, ``prefill_chunks``,
+``prefix_hits``, ``prefix_evicted``, and ``device_time`` with the
+device-time ledger on.
+
+The other types are points, written where the event happens: ``shed``;
+``preempt``, ``preempt_resume``, ``pressure_reclaim``, ``pressure_budget``
+(HBM pressure); ``kv_demote``, ``kv_promote``, ``tier_hit`` (the host KV
+tier); ``kv_export``, ``remote_insert``, ``degraded_local_prefill``,
+``peer_ejected``, ``peer_readmitted`` (disaggregated prefill);
+``weight_swap``, ``swap_straggler_preempt``, ``drain``,
+``checkpoint_export``, ``migrated_resume`` (rollout and migration);
+``weight_page_in``, ``weight_page_out``, ``tenant_switch`` (the weight
+pager); ``planner_retune``; ``batcher_restart``; and the fusion
+pseudo-unit's ``fused_dispatch``, ``fusion_fallback``, ``fusion_skipped``.
 
 Consumed by the engine's ``/flightrecorder`` route (graph/service.py),
 ``tools/flight_report.py``, which turns a dump into a human-readable

@@ -16,7 +16,10 @@ so traces stitch across engine → microservice process hops.
 
 TPU deltas: ``device_trace`` wraps ``jax.profiler.TraceAnnotation`` so a
 span's name shows up inside XLA device profiles; :class:`PhaseClock`
-partitions one thread's time into such spans and always-on counters; and
+partitions one thread's time into such spans and always-on counters;
+:class:`HostClock` (with its :class:`Heartbeat`) is that thread's account
+of what the host did to it meanwhile, and :class:`CompileLog` the
+process's log of every XLA compile by name and stage; and
 ``start_capture``/``stop_capture`` are the one control that brackets a
 window in the running process — the JAX profiler if asked for, and a
 report of what the registered sources counted and stamped meanwhile.
@@ -27,6 +30,7 @@ from __future__ import annotations
 import contextlib
 import contextvars
 import functools
+import gc
 import os
 import random
 import threading
@@ -696,6 +700,326 @@ class PhaseClock:
         return out
 
 
+
+class Heartbeat:
+    """A daemon thread that sleeps to every multiple of ``period`` seconds
+    and keeps the most any beat came late since it was last taken.
+
+    A beat needs the interpreter lock to note anything, so it tells the two
+    readings of a wait with no CPU and no run-queue time apart: beats on
+    time, the process was alive and its owner's thread alone was held (the
+    runtime, the driver, the device); beats late by the wait, the whole
+    process stood (the lock held, frozen, paged out). ``on_beat``, where
+    set, is called after every beat, on this thread (a ``HostClock``
+    samples ``/proc`` there). ``clock`` and ``sleep`` are the tests' to
+    replace; the thread's own sleep is a wait on its stop."""
+
+    def __init__(self, period: float = 0.05, clock=time.monotonic, sleep=None):
+        self.on_beat = None
+        self._period = period
+        self._clock = clock
+        self._stopped = threading.Event()
+        self._sleep = sleep if sleep is not None else self._stopped.wait
+        self._lock = threading.Lock()
+        self._late = 0.0
+        self._due: Optional[float] = None   # the beat slept towards
+        self._thread: Optional[threading.Thread] = None
+
+    def start(self) -> None:
+        with self._lock:
+            if self._thread is not None and self._thread.is_alive():
+                return
+            self._stopped.clear()
+            self._thread = threading.Thread(
+                target=self._run, name="host-heartbeat", daemon=True)
+            self._thread.start()
+
+    def stop(self) -> None:
+        self._stopped.set()
+        with self._lock:
+            thread, self._thread = self._thread, None
+        if thread is not None:
+            thread.join(timeout=1.0)
+        self._due = None
+
+    def _run(self) -> None:
+        while not self._stopped.is_set():
+            self.beat()
+
+    def beat(self) -> None:
+        """One beat: sleep to the next multiple of the period, note how
+        late the wake-up came (a stop wakes it early: nothing to note)."""
+        now = self._clock()
+        self._due = due = (now // self._period + 1) * self._period
+        self._sleep(due - now)
+        late = self._clock() - due
+        with self._lock:
+            if late > self._late:
+                self._late = late
+        if self.on_beat is not None:
+            self.on_beat()
+
+    def take(self, now: float) -> Optional[float]:
+        """The most a beat came late since the last take, in seconds; a
+        beat overdue at ``now`` and not yet noted counts as late as it is
+        by then (the taker may run before the beat it waited with). None
+        before the first beat and after a stop."""
+        due = self._due
+        if due is None:
+            return None
+        with self._lock:
+            late, self._late = self._late, 0.0
+        return max(late, now - due, 0.0)
+
+
+# collector seconds, process-wide (a collection stops every Python thread):
+# [summed over finished collections, start of the one in progress]
+_GC_S = [0.0, 0.0]
+
+
+def _gc_note(phase: str, info: Dict[str, Any]) -> None:
+    if phase == "start":
+        _GC_S[1] = time.monotonic()
+    else:
+        _GC_S[0] += time.monotonic() - _GC_S[1]
+
+
+class HostClock:
+    """What the host did to one thread, stretch by stretch: beside a
+    :class:`PhaseClock` (where the thread's time went by the program's own
+    phases) this says whether the thread ran at all.
+
+    The owning thread calls :meth:`start`, then :meth:`lap` where it laps
+    its ``PhaseClock``; a lap is the differences since the lap before, so
+    laps lie end to end:
+
+    ``cpu_s``        the thread's seconds on a core (its CPU-time clock,
+                     ``time.thread_time``)
+    ``runq_s``       its seconds runnable but waiting for a core
+                     (``/proc/thread-self/schedstat``, field 2)
+    ``busy_share``   1 - (idle + iowait) / total of the machine's CPU time
+                     (``/proc/stat``'s first line)
+    ``beat_late_s``  the most a :class:`Heartbeat` of the stretch came late
+    ``gc_s``         collector seconds, process-wide; left out at 0
+
+    A lap costs its thread two clock reads and no system call that gives
+    the interpreter lock away: the two files are read by the heartbeat's
+    thread at every beat (:meth:`sample`: two ``pread`` on descriptors held
+    open), and a lap takes the newest sample, so ``runq_s`` and
+    ``busy_share`` are those of a stretch up to a beat older than the
+    lap's. A file ``/proc`` does not give, gives in another form or leaves
+    at zero (a sandbox kernel's ``/proc/stat`` accounts nothing) is not
+    read again and its field is left out: no fallback, no guess."""
+
+    def __init__(self, beat: Optional[Heartbeat] = None,
+                 schedstat: str = "/proc/thread-self/schedstat",
+                 stat: str = "/proc/stat", clock=time.monotonic,
+                 cpu_clock=time.thread_time):
+        self.beat = beat if beat is not None else Heartbeat()
+        self._paths = (schedstat, stat)
+        self._fds: List[Optional[int]] = [None, None]
+        self._clock, self._cpu_clock = clock, cpu_clock
+        self._sampled: tuple = (None, None)     # (runq ns, (total, idle) ticks)
+        self._was: tuple = (0.0, None, None, 0.0)
+
+    def start(self) -> None:
+        """Owner thread: ``thread-self`` names the thread that opens it
+        (the descriptor then reads that thread's file from any thread)."""
+        self.stop()
+        for i, path in enumerate(self._paths):
+            try:
+                self._fds[i] = os.open(path, os.O_RDONLY)
+            except OSError:
+                self._fds[i] = None
+        if _gc_note not in gc.callbacks:
+            gc.callbacks.append(_gc_note)
+        self.sample()
+        if self._sampled[1] is not None and self._sampled[1][0] == 0:
+            self._close(1)              # a /proc/stat that counts nothing
+        self.beat.on_beat = self.sample if any(
+            fd is not None for fd in self._fds) else None
+        self._was = (self._cpu_clock(), *self._sampled, _GC_S[0])
+
+    def stop(self) -> None:
+        self.beat.on_beat = None
+        for i in range(len(self._fds)):
+            self._close(i)
+
+    def _close(self, i: int) -> None:
+        fd, self._fds[i] = self._fds[i], None
+        if fd is not None:
+            os.close(fd)
+
+    def sample(self) -> None:
+        """Reads the two files as they stand now: the heartbeat's thread
+        at every beat, and :meth:`start`."""
+        runq = stat = None
+        sched_fd, stat_fd = self._fds
+        try:
+            if sched_fd is not None:
+                runq = int(os.pread(sched_fd, 128, 0).split()[1])
+        except (OSError, ValueError, IndexError):
+            pass
+        try:
+            if stat_fd is not None:
+                line = os.pread(stat_fd, 512, 0).split(b"\n", 1)[0].split()
+                # user nice system idle iowait irq softirq steal (guest
+                # time is inside user and nice)
+                ticks = [int(x) for x in line[1:9]]
+                if line[0] == b"cpu" and len(ticks) >= 5:
+                    stat = (sum(ticks), ticks[3] + ticks[4])
+        except (OSError, ValueError, IndexError):
+            pass
+        self._sampled = (runq, stat)
+
+    def lap(self) -> Dict[str, float]:
+        """Owner thread only: the fields over the stretch since the last
+        lap (or the start)."""
+        was = self._was
+        now = self._was = (self._cpu_clock(), *self._sampled, _GC_S[0])
+        out: Dict[str, float] = {"cpu_s": now[0] - was[0]}
+        if was[1] is not None and now[1] is not None:
+            out["runq_s"] = (now[1] - was[1]) * 1e-9
+        if was[2] is not None and now[2] is not None:
+            total = now[2][0] - was[2][0]
+            if total > 0:   # no tick of the machine's between the samples
+                out["busy_share"] = 1.0 - (now[2][1] - was[2][1]) / total
+        late = self.beat.take(self._clock())
+        if late is not None:
+            out["beat_late_s"] = late
+        if now[3] > was[3]:
+            out["gc_s"] = now[3] - was[3]
+        return out
+
+
+class CompileLog:
+    """Every XLA compile of the process, by name and by stage, from
+    ``jax.monitoring``'s own events: the seconds JAX spent tracing a
+    function, lowering it and in the backend (the persistent cache's
+    retrieval included: it is inside that event), and whether the cache
+    held it. A name is XLA's for the module, ``jit_<function>``: what the
+    device trace and the compile log call it.
+
+    :meth:`install` registers the listeners once a process; :meth:`stage`
+    stamps what follows (``load``, ``warm``, ``serve``). Kept: per stage
+    and per ``(stage, name)`` the totals ``n`` (backend compiles),
+    ``trace_s``, ``lower_s``, ``backend_s``, ``cache_hits``,
+    ``cache_misses``; and a ring of the last ``ring`` events of stage
+    ``serve``, ``{t, name, kind, s, cache}``, ``t`` monotonic at the
+    event's end, ``serve_total`` counting them all (a reader's cursor:
+    :meth:`since`). Trace events nest (the ``jnp`` functions a traced
+    function calls fire their own), so a trace is counted once its name
+    goes on to lower, under that name, and the rest are dropped."""
+
+    KINDS = {
+        "/jax/core/compile/jaxpr_trace_duration": "trace",
+        "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+        "/jax/core/compile/backend_compile_duration": "backend",
+    }
+    CACHE = {
+        "/jax/compilation_cache/cache_hits": "hit",
+        "/jax/compilation_cache/cache_misses": "miss",
+    }
+
+    def __init__(self, ring: int = 256):
+        self._lock = threading.Lock()
+        self._installed = False
+        self._stage: Optional[str] = None
+        self._stages: Dict[str, Dict[str, float]] = {}
+        self._names: Dict[tuple, Dict[str, float]] = {}
+        self._serve: deque = deque(maxlen=ring)
+        self.serve_total = 0
+        # the compiling thread's own: traces waiting for their lowering,
+        # and what the cache said inside the backend event in progress
+        self._local = threading.local()
+
+    def install(self) -> None:
+        """Registers the listeners (once, however often called) and opens
+        stage ``load``. Before anything is jitted: an executable compiled
+        earlier is in no count."""
+        import jax.monitoring
+
+        with self._lock:
+            if not self._installed:
+                jax.monitoring.register_event_duration_secs_listener(
+                    self._on_duration)
+                jax.monitoring.register_event_listener(self._on_event)
+                self._installed = True
+        self.stage("load")
+
+    def stage(self, name: str) -> None:
+        self._stage = name
+
+    def _on_event(self, event: str, **kwargs) -> None:
+        said = self.CACHE.get(event)
+        if said is not None:
+            self._local.cache = said
+
+    def _on_duration(self, event: str, secs: float, **kwargs) -> None:
+        kind = self.KINDS.get(event)
+        if kind is None:
+            return
+        now = time.monotonic()
+        name = str(kwargs.get("fun_name", "?"))
+        local = self._local.__dict__
+        if kind == "trace":
+            local.setdefault("traced", {})[name] = secs
+            return
+        events = [{"t": now, "name": name.replace("(", "_").replace(")", ""),
+                   "kind": kind, "s": secs,
+                   "cache": local.pop("cache", None) if kind == "backend" else None}]
+        if kind == "lower":
+            traced = local.pop("traced", {})
+            inner = name[name.find("(") + 1:-1] if name.endswith(")") else name
+            if inner in traced:     # it ended where the lowering began
+                events.insert(0, dict(events[0], kind="trace", s=traced[inner],
+                                      t=now - secs))
+        with self._lock:
+            stage = self._stage
+            for totals in (self._stages.setdefault(stage, self._zero()),
+                           self._names.setdefault((stage, events[0]["name"]),
+                                                  self._zero())):
+                for e in events:
+                    totals[e["kind"] + "_s"] += e["s"]
+                    if e["kind"] == "backend":
+                        totals["n"] += 1
+                        if e["cache"] is not None:
+                            totals["cache_hits" if e["cache"] == "hit"
+                                   else "cache_misses"] += 1
+            if stage == "serve":
+                self._serve.extend(events)
+                self.serve_total += len(events)
+
+    @staticmethod
+    def _zero() -> Dict[str, float]:
+        return {"n": 0, "trace_s": 0.0, "lower_s": 0.0, "backend_s": 0.0,
+                "cache_hits": 0, "cache_misses": 0}
+
+    def since(self, cursor: Optional[int] = None) -> tuple:
+        """``(serve_total, the serve events past the first ``cursor``)``,
+        oldest first, as far back as the ring holds (none without a
+        cursor: the first call's); the events are the ring's own dicts.
+        One comparison where nothing compiled."""
+        if cursor is None or cursor == self.serve_total:
+            return self.serve_total, ()
+        with self._lock:
+            new = min(self.serve_total - cursor, len(self._serve))
+            return self.serve_total, list(self._serve)[len(self._serve) - new:]
+
+    def report(self, top: int = 20) -> Dict[str, Any]:
+        """Absolute, not differenced: ``stages`` (each stage's totals),
+        ``executables`` (the ``top`` largest by seconds, with their stage
+        and name) and ``serve_events`` (the ring)."""
+        with self._lock:
+            stages = {s: dict(v) for s, v in self._stages.items()}
+            names = [dict(v, stage=s, name=n)
+                     for (s, n), v in self._names.items()]
+            events = list(self._serve)
+        names.sort(key=lambda v: -(v["trace_s"] + v["lower_s"] + v["backend_s"]))
+        return {"stages": stages, "executables": names[:top],
+                "serve_events": events}
+
+
 # a host span of this name, the reading after it, opens every profiled
 # capture: ``time.monotonic()`` as the span began
 CAPTURE_CLOCK_SPAN = "capture.clock monotonic_s="
@@ -715,6 +1039,8 @@ class CaptureControl:
     ``capture_requests() -> [dict]``                   recent request timelines
     ``capture_polls() -> [dict]``                      the flight recorder's rows
                                                        (optional: ``[]`` without)
+    ``capture_compiles() -> dict``                     the compile log's report
+                                                       (optional: left out without)
     ``capture_started()``                              the profiler records now
 
     held weakly, so a closed server drops out. A capture is a call, not a
@@ -766,7 +1092,9 @@ class CaptureControl:
         each counter group of the source as differences over the capture
         (the batcher's ``loop`` and ``counters``), and ``requests`` and ``polls``
         — every timeline and every flight-recorder row the source still
-        holds, stamps absolute monotonic, so a reader selects by window."""
+        holds, stamps absolute monotonic, so a reader selects by window —
+        and ``compiles``, the compile log's report (:meth:`CompileLog.report`:
+        absolute too)."""
         with self._lock:
             run = self._running
             if run is None:
@@ -778,6 +1106,7 @@ class CaptureControl:
                 after = source.capture_counters() if source is not None else {}
                 requests = source.capture_requests() if source is not None else []
                 polls = getattr(source, "capture_polls", list)()
+                compiles = getattr(source, "capture_compiles", lambda: None)()
             finally:
                 if run["profiling"]:
                     import jax.profiler
@@ -790,6 +1119,8 @@ class CaptureControl:
                              if isinstance(v, (int, float))}
         report["requests"] = requests
         report["polls"] = polls
+        if compiles is not None:
+            report["compiles"] = compiles
         return report
 
 
@@ -797,3 +1128,9 @@ _CAPTURE = CaptureControl()
 register_capture_source = _CAPTURE.register
 start_capture = _CAPTURE.start
 stop_capture = _CAPTURE.stop
+
+_COMPILES = CompileLog()
+install_compile_log = _COMPILES.install
+compile_stage = _COMPILES.stage
+compiles_since = _COMPILES.since
+compile_report = _COMPILES.report

@@ -385,6 +385,118 @@ def test_poll_rows_lie_end_to_end_and_sum_to_the_clock(model_and_params, kw):
     assert all("chunks" in r["phase_s"] for r in rows if r.get("prefill_chunks"))
 
 
+def test_poll_rows_carry_the_hosts_account_of_their_stretch(model_and_params):
+    """``host`` lies end to end as ``phase_s`` does: the scheduler thread's
+    seconds on a core and waiting for one never exceed the row's stretch
+    (the kernel adds to both at its ticks: a tick of slack), nor their sum
+    the rows' span; the heartbeat lives as long as the scheduler thread."""
+    b = make_batcher(model_and_params)
+    try:
+        run_batch(b)
+        time.sleep(0.2)
+        run_batch(b)
+        beat = b._host.beat._thread
+        assert beat.is_alive() and beat.daemon
+    finally:
+        b.close()
+    assert not beat.is_alive() and b._host._fds == [None, None]
+    rows = poll_rows(b)
+    assert len(rows) > 4 and all("host" in r for r in rows)
+    proc = os.path.exists("/proc/thread-self/schedstat")
+    on_thread = 0.0
+    for row in rows:
+        host = row["host"]
+        assert set(host) <= {"cpu_s", "runq_s", "busy_share", "beat_late_s", "gc_s"}
+        assert "cpu_s" in host and ("runq_s" in host) == proc
+        assert 0.0 <= host["beat_late_s"] < 60.0
+        assert host.get("gc_s", 1.0) > 0.0              # left out at zero
+        assert 0.0 <= host.get("busy_share", 0.0) <= 1.0
+        stretch = sum(row["phase_s"].values())
+        on_core_or_waiting = host["cpu_s"] + host.get("runq_s", 0.0)
+        assert 0.0 <= on_core_or_waiting <= stretch + 0.02
+        on_thread += on_core_or_waiting
+    span = rows[-1]["t"] + sum(rows[-1]["phase_s"].values()) - rows[0]["t"]
+    assert 0.0 < on_thread <= span + 0.02
+    # the idle stretch between the batches went to one row: the thread
+    # slept through it, on no core and in no run queue
+    idled, = (r for r in rows if r["phase_s"].get("idle", 0.0) >= 0.15)
+    assert idled["host"]["cpu_s"] + idled["host"].get("runq_s", 0.0) < 0.1
+    assert json.loads(json.dumps(rows)) == rows
+
+
+def test_a_ring_set_to_zero_starts_no_heartbeat(model_and_params):
+    b = make_batcher(model_and_params, flight_recorder_capacity=0)
+    try:
+        run_batch(b)
+        assert b._host is None
+        assert b._host is None and b.capture_polls() == []
+    finally:
+        b.close()
+
+
+@pytest.fixture
+def serving_stage():
+    """The process's compile log installed, as ``GenerateServer.load``
+    installs it, and put back to ``load`` afterwards so that what later
+    tests compile lands on no row."""
+    tracing.install_compile_log()
+    try:
+        yield tracing.compile_stage
+    finally:
+        tracing.compile_stage("load")
+
+
+def test_a_length_warm_was_not_told_of_compiles_on_the_next_row(
+        model_and_params, serving_stage):
+    b = make_batcher(model_and_params)
+    control = tracing.CaptureControl()
+    control.register(b)
+    try:
+        serving_stage("warm")
+        b.warm(prompt_lens=(len(PROMPTS[0]),), max_new_tokens=BUDGETS[0])
+        serving_stage("serve")
+        # a process's first admission compiles one eager conversion that
+        # warm() does not reach (milliseconds); from then on a warmed
+        # length compiles nothing
+        b.submit(PROMPTS[0], max_new_tokens=BUDGETS[0]).result(timeout=120)
+        seen = b.stats["compiles_after_ready"]
+        rows_before = len(poll_rows(b))
+        b.submit(PROMPTS[0], max_new_tokens=BUDGETS[0]).result(timeout=120)
+        assert b.stats["compiles_after_ready"] == seen
+        assert not any("compiles" in r for r in poll_rows(b)[rows_before:])
+        # bucket 32 was never warmed: its prefill and insert compile while
+        # the request waits, in the admit turn of the row that names them
+        control.start()
+        rows_before = len(poll_rows(b))
+        seconds = b.stats["compile_after_ready_s"]
+        b.submit(LONG_PROMPTS[0], max_new_tokens=4).result(timeout=120)
+        report = control.stop()
+    finally:
+        b.close()
+    row, = (r for r in poll_rows(b)[rows_before:] if "compiles" in r)
+    assert row["admitted"] == 1
+    backends = [e for e in row["compiles"] if e["kind"] == "backend"]
+    assert {"jit_prefill_one", "jit_insert"} <= {e["name"] for e in backends}
+    assert all(e["cache"] in ("hit", "miss") for e in backends)
+    assert {e["kind"] for e in row["compiles"]} == {"trace", "lower", "backend"}
+    assert b.stats["compiles_after_ready"] == seen + len(backends)
+    spent = sum(e["s"] for e in row["compiles"])
+    assert b.stats["compile_after_ready_s"] == pytest.approx(seconds + spent)
+    # the thread compiled inside its admit phase, inside the row's stretch
+    assert spent <= row["phase_s"]["admit"]
+    assert all(row["t"] <= e["t"] <= row["t"] + sum(row["phase_s"].values())
+               for e in row["compiles"])
+    # the report: the log absolute, the counters differenced
+    log = report["compiles"]
+    assert set(log) == {"stages", "executables", "serve_events"}
+    assert [e for e in log["serve_events"] if e in row["compiles"]] == row["compiles"]
+    assert log["stages"]["serve"]["n"] >= seen + len(backends)
+    assert log["stages"]["warm"]["n"] > 0
+    assert report["counters"]["compiles_after_ready"] == len(backends)
+    assert any(e["stage"] == "serve" and e["name"] == "jit_prefill_one"
+               for e in log["executables"])
+
+
 def hold(fn, seconds):
     """``fn`` with its first call held ``seconds`` before it goes out: a
     dispatch that blocks."""
@@ -465,9 +577,11 @@ def test_a_held_burst_is_read_wait_in_one_row_and_the_device_is_not_drained(
 
         b._burst_fn = second_burst_held
         b.submit([4, 5, 6], max_new_tokens=12).result(timeout=120)
-        unclaimed = list(b._row_bursts)
     finally:
         b.close()
+    # once the loop has stopped: a request resolves in its burst's credit,
+    # before the poll's record claims the burst
+    unclaimed = list(b._row_bursts)
     rows = poll_rows(b)
     # the first poll after an idle stretch finds the device drained
     after_idle = [r for r in rows if r["phase_s"].get("idle", 0.0) >= 0.15]
@@ -743,12 +857,18 @@ def _dump(stalled):
             "dropped": 0, "entries": rows}
 
 
-@pytest.mark.parametrize("stalled", ["admit", "read_wait", None])
-def test_flight_report_lists_the_slowest_polls_and_diagnoses_a_stall(stalled):
+def _flight_report():
+    """``tools/flight_report.py`` as a module."""
     spec = importlib.util.spec_from_file_location(
         "flight_report", os.path.join(ROOT, "tools", "flight_report.py"))
-    flight_report = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(flight_report)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+@pytest.mark.parametrize("stalled", ["admit", "read_wait", None])
+def test_flight_report_lists_the_slowest_polls_and_diagnoses_a_stall(stalled):
+    flight_report = _flight_report()
     unit = flight_report.report(_dump(stalled))["(batcher)"]
     at = unit["lines"].index("slowest polls (scheduler seconds outside idle):")
     listed = unit["lines"][at + 1:at + 6]
@@ -765,3 +885,83 @@ def test_flight_report_lists_the_slowest_polls_and_diagnoses_a_stall(stalled):
     assert "54x the median burst period (50.0 ms over 59)" in stalls[0]
     assert "[71, 72, 73]" in stalls[0]
     json.dumps(unit)
+
+
+QUIET = {"cpu_s": 0.004, "runq_s": 0.0002, "busy_share": 0.41,
+         "beat_late_s": 0.0004}
+# what the stalled row's ``host`` and ``compiles`` read, and what the
+# diagnosis must name from them
+CAUSES = {
+    "compiled": (dict(QUIET, cpu_s=2.6), [
+        {"t": 501.6, "name": "jit_prefill_one", "kind": "trace", "s": 0.3,
+         "cache": None},
+        {"t": 501.9, "name": "jit_prefill_one", "kind": "lower", "s": 0.3,
+         "cache": None},
+        {"t": 503.9, "name": "jit_prefill_one", "kind": "backend", "s": 2.0,
+         "cache": "miss"}],
+        "XLA compiled meanwhile: jit_prefill_one 2.600 s (cache miss)"),
+    "starved": (dict(QUIET, runq_s=2.6, busy_share=0.99), None,
+                "stood runnable with no core for 2.600 s of it (`runq_s`): "
+                "the host starved the thread; the machine was 99% busy"),
+    "process_late": (dict(QUIET, beat_late_s=2.65), None,
+                     "the whole process stood: the heartbeat came 2.650 s late"),
+    "collector": (dict(QUIET, gc_s=1.9, beat_late_s=0.3), None,
+                  "the collector ran 1.900 s"),
+    "machine_full": (dict(QUIET, runq_s=0.3, busy_share=0.97), None,
+                     "the machine was over its cores (97% busy"),
+    "runtime": (QUIET, None,
+                "the thread slept (cpu 0.004 s, run-queue wait 0.000 s) while "
+                "the process's beats came on time (latest 0.4 ms) and nothing "
+                "compiled; the machine was 41% busy: the runtime or the device "
+                "held the burst"),
+    # the machine the chips are on: no schedstat, a /proc/stat of zeros
+    "runtime_sandbox": ({"cpu_s": 0.004, "beat_late_s": 0.0011}, None,
+                        "the thread slept (cpu 0.004 s, this host gives no "
+                        "run-queue wait) while the process's beats came on "
+                        "time (latest 1.1 ms) and nothing compiled: the "
+                        "runtime or the device held the burst"),
+    "late_sandbox": ({"cpu_s": 0.004, "beat_late_s": 2.5}, None,
+                     "the whole process stood: the heartbeat came 2.500 s late"),
+    "no_beat": ({"cpu_s": 0.1, "busy_share": 0.2}, None,
+                "the record has no heartbeat to say whether"),
+    "older_dump": (None, None, "the record carries no `host` account"),
+}
+
+
+@pytest.mark.parametrize("cause", sorted(CAUSES))
+def test_flight_report_names_the_cause_of_a_stall_from_the_row(cause):
+    flight_report = _flight_report()
+    host, compiles, said = CAUSES[cause]
+    dump = _dump("read_wait")
+    for row in dump["entries"]:
+        if cause != "older_dump":
+            row["host"] = dict(QUIET)
+    stalled = dump["entries"][30]
+    if host is not None:
+        stalled["host"] = host
+    if compiles:
+        stalled["compiles"] = compiles
+    unit = flight_report.report(dump)["(batcher)"]
+    stall, = (line for line in unit["diagnosis"] if "burst period" in line)
+    assert "spent 2.700 s in `read_wait`" in stall and said in stall, stall
+    assert stall.endswith("no lane got a token meanwhile; requests admitted "
+                          "in that poll: [71, 72, 73]")
+    # of the causes one is named
+    assert sum(text in stall for _h, _c, text in CAUSES.values()) == 1
+    # the slowest polls print the row's own account
+    at = unit["lines"].index("slowest polls (scheduler seconds outside idle):")
+    first = unit["lines"][at + 1]
+    assert first.startswith("  poll 300 at t=")
+    if cause == "older_dump":
+        assert "host:" not in first
+    if cause == "starved":
+        assert first.endswith("; host: cpu 4.0 ms, run-queue wait 2600.0 ms, "
+                              "machine busy 99.0%, heartbeat late 0.4 ms")
+    if cause == "compiled":
+        assert first.endswith("; compiled: jit_prefill_one 2.600 s (cache miss)")
+    # an unloaded dump's rows, host and all, are diagnosed as nothing
+    quiet = _dump(None)
+    for row in quiet["entries"]:
+        row["host"] = dict(QUIET)
+    assert not [line for line in flight_report.report(quiet)["(batcher)"]["diagnosis"]
+                if "burst period" in line]

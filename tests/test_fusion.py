@@ -27,6 +27,7 @@ from seldon_core_tpu.graph.spec import (
     default_predictor,
     parse_fuse_annotation,
 )
+from seldon_core_tpu.servers.generateserver import COMPILE_TELEMETRY_KEYS
 from seldon_core_tpu.user_model import JAXComponent, JAXTransformComponent
 
 FUSE_ANN = {"seldon.io/fuse": "true"}
@@ -518,6 +519,9 @@ def test_rag_graph_fused_vs_hop_byte_identity(rag_components):
             o["meta"]["metrics"] = [
                 m for m in o["meta"].get("metrics", [])
                 if m.get("type") != "TIMER"
+                # nor is what XLA compiled when: a shape's first call
+                # compiles, whichever executor makes it
+                and m.get("key") not in COMPILE_TELEMETRY_KEYS
             ]
         assert of == oh
         assert of["jsonData"]["tokens"]  # the greedy tail actually ran

@@ -8,6 +8,7 @@ import asyncio
 import importlib.util
 import json
 import os
+import time
 
 import pytest
 
@@ -298,6 +299,12 @@ def test_flight_report_diagnosis(shared_server):
     spec.loader.exec_module(mod)
     shared_server.predict({"prompt_tokens": [[1, 2, 3, 4]],
                            "max_new_tokens": 5}, [])
+    # a request resolves in its burst's credit; the poll's record is
+    # written once the poll's reads are done, a moment later
+    deadline = time.monotonic() + 10.0
+    while (not shared_server.flight_dump()["entries"]
+           and time.monotonic() < deadline):
+        time.sleep(0.01)
     report = mod.render({"units": {"gen": shared_server.flight_dump()}})
     assert "flight report: gen" in report
     assert "SLO over" in report

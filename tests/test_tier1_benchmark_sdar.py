@@ -24,7 +24,11 @@ ADDED = ("block_rows_read_share",)
 # PR 51 appended a configuration, a cell, three metrics of its own and its
 # cell to three of the lists this cell joined: the lines that held this
 # cell, its configuration and its metrics to be the LAST are restated
-# without "last" (the entries are still there, in their order)
+# without "last" (the entries are still there, in their order). PR 53
+# appended four readers of the program's compile log that every cell
+# reports, this one too
+EVERY_CELLS = ("compiles_in_window", "warm_compile_s", "warm_trace_lower_s",
+               "warm_cache_miss_share")
 
 
 def test_the_cell_its_files_and_its_metrics_are_found(man, cfg, arch):  # noqa: F811
@@ -40,7 +44,7 @@ def test_the_cell_its_files_and_its_metrics_are_found(man, cfg, arch):  # noqa: 
                    "scheduler_host_share", "prefill_device_share",
                    "moe_experts_touched_share",
                    "moe_rows_per_touched_expert", "moe_expert_hbm_roofline",
-                   *NEW_METRICS, *ADDED]  # noqa: F405
+                   *NEW_METRICS, *ADDED, *EVERY_CELLS]  # noqa: F405
     assert {m["name"] for m in manifest.metrics_of(man, "end_to_end", CELL)} == {  # noqa: F405
         "tpot_p50_ms", "setup_s"}
     names = [m["name"] for m in man["per_layer"]]

@@ -4,8 +4,13 @@ engine TracingProvider + wrapper FlaskTracer, SURVEY §5)."""
 
 import asyncio
 import json
+import os
+import random
+import threading
+import time
 
 import numpy as np
+import pytest
 
 from seldon_core_tpu import tracing
 from seldon_core_tpu.graph.service import EngineApp
@@ -421,3 +426,312 @@ def test_flight_recorder_t_us_survives_wall_step(monkeypatch):
     entries = rec.snapshot()
     assert entries[1]["seq"] == entries[0]["seq"] + 1
     assert entries[1]["t_us"] >= entries[0]["t_us"]
+
+
+# -- the host's account of a thread: Heartbeat, HostClock ----------------------
+
+
+class MadeUpTime:
+    """A clock the test moves, and a sleeper that oversleeps by ``late``."""
+
+    def __init__(self, t):
+        self.t, self.late = t, 0.0
+
+    def clock(self):
+        return self.t
+
+    def sleep(self, seconds):
+        self.t += seconds + self.late
+
+
+def no_proc_clock(made_up, beat):
+    """A host clock on a machine whose ``/proc`` gives neither file, its
+    thread's CPU clock standing still."""
+    return tracing.HostClock(beat=beat, schedstat="/nonexistent/schedstat",
+                             stat="/nonexistent/stat", clock=made_up.clock,
+                             cpu_clock=lambda: 7.0)
+
+
+def laps_but_gc(host):
+    """A lap without ``gc_s``: the process's collector runs when it will."""
+    lap = host.lap()
+    lap.pop("gc_s", None)
+    return lap
+
+
+def test_a_late_beat_is_the_next_laps_maximum_and_the_one_after_reads_zero():
+    at = MadeUpTime(1000.012)
+    # a period the clock's floats hold exactly
+    beat = tracing.Heartbeat(period=0.0625, clock=at.clock, sleep=at.sleep)
+    host = no_proc_clock(at, beat)
+    host.start()
+    assert laps_but_gc(host) == {"cpu_s": 0.0}     # no beat yet: left out
+    beat.beat()                             # on time, at the next multiple
+    assert at.t == 1000.0625
+    at.late = 0.4
+    beat.beat()                             # due at 1000.125, woke at 1000.525
+    at.late = 0.0
+    beat.beat()                             # the next multiple: 1000.5625
+    beat.beat()
+    assert at.t == 1000.625
+    assert laps_but_gc(host) == {"cpu_s": 0.0, "beat_late_s": pytest.approx(0.4)}
+    beat.beat()
+    assert laps_but_gc(host) == {"cpu_s": 0.0,
+                                 "beat_late_s": pytest.approx(0.0, abs=1e-9)}
+    # a beat overdue and not yet noted when the lap is taken counts as late
+    # as it is by then: the taker may run before the beat it waited with
+    at.t += 0.3
+    assert laps_but_gc(host) == {"cpu_s": 0.0, "beat_late_s": pytest.approx(0.3)}
+    host.stop()
+
+
+def test_the_heartbeat_thread_beats_stops_and_starts_once():
+    beat = tracing.Heartbeat(period=0.01)
+    beat.start()
+    thread = beat._thread
+    beat.start()                            # a second start is the same thread
+    assert beat._thread is thread and thread.daemon
+    time.sleep(0.05)
+    late = beat.take(time.monotonic())
+    assert late is not None and 0.0 <= late < 5.0
+    beat.stop()
+    assert not thread.is_alive()
+    assert beat.take(time.monotonic()) is None
+
+
+def test_host_clock_differences_made_up_proc_text(tmp_path):
+    sched, stat = tmp_path / "schedstat", tmp_path / "stat"
+    sched.write_text("1000000000 500000000 7\n")
+    stat.write_text("cpu  100 0 50 800 50 0 0 0 0 0\ncpu0 100 0 50 800 50 0 0 0 0 0\n")
+    at = MadeUpTime(5.0)
+    cpu = iter([3.0, 3.03, 3.03, 3.03, 3.03])
+    beat = tracing.Heartbeat(period=0.0625, clock=at.clock, sleep=at.sleep)
+    host = tracing.HostClock(beat=beat, schedstat=str(sched), stat=str(stat),
+                             clock=at.clock, cpu_clock=lambda: next(cpu))
+    host.start()
+    # the files are the heartbeat's to read, at every beat: the owner's lap
+    # makes no system call
+    assert beat.on_beat == host.sample
+    # 30 ms on a core, 2.5 s runnable and not run; the machine's 200 ticks:
+    # 20 idle, 10 in iowait
+    sched.write_text("1030000000 3000000000 9\n")
+    stat.write_text("cpu  250 0 70 820 60 0 0 0 0 0\ncpu0 250 0 70 820 60 0 0 0 0 0\n")
+    # not yet sampled: the lap sees the files as the last beat left them
+    assert laps_but_gc(host) == {"cpu_s": pytest.approx(0.03), "runq_s": 0.0}
+    beat.beat()
+    assert laps_but_gc(host) == {"cpu_s": 0.0, "runq_s": pytest.approx(2.5),
+                          "busy_share": pytest.approx(1.0 - 30 / 200),
+                          "beat_late_s": pytest.approx(0.0, abs=1e-9)}
+    # laps lie end to end: nothing moved since, and no tick of the
+    # machine's fell between the samples, so no share of it can be given
+    beat.beat()
+    assert laps_but_gc(host) == {"cpu_s": 0.0, "runq_s": 0.0,
+                          "beat_late_s": pytest.approx(0.0, abs=1e-9)}
+    # text in another form leaves its fields out
+    sched.write_text("no numbers here\n")
+    stat.write_text("intr 1 2 3\n")
+    beat.beat()
+    assert laps_but_gc(host) == {"cpu_s": 0.0,
+                          "beat_late_s": pytest.approx(0.0, abs=1e-9)}
+    host.stop()
+    assert host._fds == [None, None] and beat.on_beat is None
+
+
+def test_a_proc_stat_that_counts_nothing_is_not_read_again(tmp_path):
+    """A sandbox kernel's ``/proc/stat`` stands at zero (the machine the
+    chips are on, PR 53): the descriptor is closed at the start, and with
+    no file left to read the heartbeat is given nothing to do."""
+    stat = tmp_path / "stat"
+    stat.write_text("cpu  0 0 0 0 0 0 0 0 0 0\ncpu0 0 0 0 0 0 0 0 0 0 0\n")
+    at = MadeUpTime(5.0)
+    beat = tracing.Heartbeat(clock=at.clock, sleep=at.sleep)
+    host = tracing.HostClock(beat=beat, schedstat="/nonexistent/schedstat",
+                             stat=str(stat), clock=at.clock,
+                             cpu_clock=lambda: 1.0)
+    host.start()
+    assert host._fds == [None, None] and beat.on_beat is None
+    stat.write_text("cpu  9 9 9 9 9 9 9 9 0 0\n")
+    host.sample()
+    assert laps_but_gc(host) == {"cpu_s": 0.0}
+    host.stop()
+
+
+def test_host_clock_on_this_machine():
+    host = tracing.HostClock()
+    seen = {}
+
+    def owner():                            # thread-self is the opener's
+        host.start()
+        t0 = time.monotonic()
+        while time.monotonic() - t0 < 0.1:
+            pass
+        seen.update(host.lap(), stretch=time.monotonic() - t0)
+        host.stop()
+    thread = threading.Thread(target=owner)
+    thread.start()
+    thread.join(timeout=30)
+    assert not thread.is_alive()
+    # the thread spun: on a core or waiting for one, never more than the
+    # stretch (the kernel adds to the wait at its ticks: a tick of slack)
+    assert 0.0 < seen["cpu_s"] <= seen["stretch"]
+    assert ("runq_s" in seen) == os.path.exists("/proc/thread-self/schedstat")
+    assert seen["cpu_s"] + seen.get("runq_s", 0.0) <= seen["stretch"] + 0.02
+    assert 0.0 <= seen.get("busy_share", 0.0) <= 1.0
+    assert "beat_late_s" not in seen        # its heartbeat was never started
+
+
+def test_collector_seconds_land_on_the_lap_and_are_left_out_at_zero():
+    import gc
+
+    at = MadeUpTime(0.0)
+    host = no_proc_clock(at, tracing.Heartbeat(clock=at.clock, sleep=at.sleep))
+    was = gc.isenabled()
+    gc.disable()
+    try:
+        host.start()
+        assert "gc_s" not in host.lap()
+        gc.collect()
+        assert host.lap()["gc_s"] > 0.0
+        assert "gc_s" not in host.lap()
+    finally:
+        if was:
+            gc.enable()
+        host.stop()
+
+
+# -- the compile log -----------------------------------------------------------
+
+
+@pytest.fixture
+def compile_log():
+    import jax.monitoring
+
+    log = tracing.CompileLog(ring=8)
+    raw = []
+
+    def listen(event, secs, **kw):
+        raw.append((event.rsplit("/", 1)[-1], kw.get("fun_name"), secs))
+    jax.monitoring.register_event_duration_secs_listener(listen)
+    log.install()
+    log.install()
+    log.raw = raw
+    try:
+        yield log
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listen)
+        jax.monitoring.unregister_event_duration_listener(log._on_duration)
+        jax.monitoring.unregister_event_listener(log._on_event)
+
+
+def fresh_function():
+    """A function no cache has seen: a constant of its own in its HLO."""
+    import jax.numpy as jnp
+
+    c = random.random()
+
+    def my_fn(x):
+        return jnp.sin(x) * c + x
+    return my_fn
+
+
+def test_install_twice_registers_once(compile_log):
+    from jax._src import monitoring
+
+    assert monitoring.get_event_duration_listeners().count(
+        compile_log._on_duration) == 1
+    assert monitoring.get_event_listeners().count(compile_log._on_event) == 1
+    assert compile_log.report()["stages"] == {}     # stage "load", nothing yet
+
+
+def test_a_first_call_leaves_a_trace_a_lower_and_a_backend_event(compile_log):
+    import jax
+    import jax.numpy as jnp
+
+    x = jnp.ones(4)
+    f = jax.jit(fresh_function())
+    compile_log.stage("warm")
+    f(x).block_until_ready()
+    f(x).block_until_ready()                # a second call compiles nothing
+    compile_log.stage("load")
+    rep = compile_log.report()
+    warm = rep["stages"]["warm"]
+    assert warm["n"] == 1 and warm["cache_hits"] == 0 and warm["cache_misses"] == 1
+    assert min(warm["trace_s"], warm["lower_s"], warm["backend_s"]) > 0.0
+    mine, = (e for e in rep["executables"] if e["stage"] == "warm")
+    assert mine == dict(warm, stage="warm", name="jit_my_fn")
+    assert rep["serve_events"] == []        # the ring is stage serve's alone
+    # nested trace events (sin, multiply, add under my_fn) are not summed
+    # twice: the log's trace seconds are my_fn's own event, which holds them
+    traces = [(name, s) for kind, name, s in compile_log.raw
+              if kind == "jaxpr_trace_duration"]
+    assert {"sin", "my_fn"} <= {name for name, _ in traces}
+    assert warm["trace_s"] == dict(traces)["my_fn"]
+    assert warm["trace_s"] < sum(s for _, s in traces)
+
+
+def test_the_cache_says_miss_then_hit_and_serve_events_fill_the_ring(compile_log):
+    import jax
+    import jax.numpy as jnp
+
+    # conftest.py turns the persistent cache on for every executable
+    assert jax.config.jax_compilation_cache_dir
+    x = jnp.ones(4)
+    f = jax.jit(fresh_function())
+    compile_log.stage("serve")
+    cursor = compile_log.since()[0]
+    assert cursor == 0 and compile_log.since(cursor) == (0, ())
+    t0 = time.monotonic()
+    f(x).block_until_ready()
+    jax.clear_caches()
+    f(x).block_until_ready()
+    t1 = time.monotonic()
+    compile_log.stage("load")
+    total, events = compile_log.since(cursor)
+    assert total == compile_log.serve_total == 6
+    assert [(e["name"], e["kind"], e["cache"]) for e in events] == [
+        ("jit_my_fn", "trace", None), ("jit_my_fn", "lower", None),
+        ("jit_my_fn", "backend", "miss"),
+        ("jit_my_fn", "trace", None), ("jit_my_fn", "lower", None),
+        ("jit_my_fn", "backend", "hit")]
+    stamps = [e["t"] for e in events]
+    assert stamps == sorted(stamps) and t0 < stamps[0] and stamps[-1] < t1
+    assert compile_log.since(total) == (6, ())
+    assert compile_log.since(4)[1] == events[4:]
+    serve = compile_log.report()["stages"]["serve"]
+    assert (serve["n"], serve["cache_hits"], serve["cache_misses"]) == (2, 1, 1)
+    # a ring of 8: four more compiles and the oldest events are gone, the
+    # count is not
+    compile_log.stage("serve")
+    for _ in range(4):
+        jax.jit(fresh_function())(x).block_until_ready()
+    compile_log.stage("load")
+    total, events = compile_log.since(0)
+    assert total == 18 and len(events) == 8
+    assert events == compile_log.report()["serve_events"]
+    assert json.loads(json.dumps(compile_log.report())) == compile_log.report()
+
+
+class SourceWithoutALog:
+    def capture_counters(self):
+        return {}
+
+    def capture_requests(self):
+        return []
+
+
+class SourceWithALog(SourceWithoutALog):
+    def capture_compiles(self):
+        return {"stages": {}, "executables": [], "serve_events": []}
+
+
+@pytest.mark.parametrize("source", [SourceWithALog(), SourceWithoutALog()],
+                         ids=["with_a_log", "without"])
+def test_the_capture_report_carries_the_compile_log_where_the_source_has_one(source):
+    control = tracing.CaptureControl()
+    control.register(source)
+    control.start()
+    report = control.stop()
+    if isinstance(source, SourceWithALog):
+        assert report["compiles"] == source.capture_compiles()
+    else:
+        assert "compiles" not in report

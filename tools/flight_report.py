@@ -8,8 +8,11 @@ where generation time is going — queue wait vs first-token latency vs
 decode pacing — plus what the scheduler actually decided poll by poll
 (the burst's mode, K and bucket, chunked-prefill interleave,
 prefix-cache hits, shed events) and what each poll cost: the scheduler
-thread's seconds by phase on every poll record (``phase_s``), from which
-the five slowest polls are listed and a stalled one is diagnosed.
+thread's seconds by phase on every poll record (``phase_s``) beside the
+host's account of the same stretch (``host``: the thread's CPU and
+run-queue seconds, the machine's busy share, the heartbeat, the
+collector; ``compiles``: what XLA compiled), from which the five slowest
+polls are listed and a stalled one is diagnosed by its cause.
 
 Usage::
 
@@ -559,11 +562,88 @@ def burst_periods(polls: List[Dict[str, Any]]) -> List[float]:
     return periods
 
 
+HOST_FIELDS = (   # key, label, scale, unit
+    ("cpu_s", "cpu", 1e3, " ms"), ("runq_s", "run-queue wait", 1e3, " ms"),
+    ("busy_share", "machine busy", 1e2, "%"),
+    ("beat_late_s", "heartbeat late", 1e3, " ms"),
+    ("gc_s", "collector", 1e3, " ms"),
+)
+
+
+def _host_text(poll: Dict[str, Any]) -> str:
+    """A poll record's ``host`` fields and compiles on one line, empty
+    for a record without them (an older dump's)."""
+    host = poll.get("host") or {}
+    parts = [f"{label} {host[key] * scale:.1f}{unit}"
+             for key, label, scale, unit in HOST_FIELDS if key in host]
+    text = "; host: " + ", ".join(parts) if parts else ""
+    if poll.get("compiles"):
+        text += "; compiled: " + _compiled_text(poll["compiles"])
+    return text
+
+
+def _compiled_text(compiles: List[Dict[str, Any]]) -> str:
+    """``name seconds (cache)`` of a record's compile events, by name."""
+    by_name: Dict[str, List[Any]] = {}
+    for e in compiles:
+        entry = by_name.setdefault(e["name"], [0.0, None])
+        entry[0] += e["s"]
+        entry[1] = e.get("cache") or entry[1]
+    return ", ".join(
+        f"{name} {s:.3f} s" + (f" (cache {cache})" if cache else "")
+        for name, (s, cache) in sorted(by_name.items(), key=lambda kv: -kv[1][0]))
+
+
+def held_by(poll: Dict[str, Any], phase: str) -> str:
+    """What held the scheduler thread through ``phase`` of a poll record,
+    named from the record's own account: a compile (``compiles``), the
+    thread runnable and not run (``host.runq_s``), the whole process late
+    (``beat_late_s``, ``gc_s``), the machine over its cores
+    (``busy_share``), or, none of these with the beats on time, the
+    runtime or the device."""
+    held = poll["phase_s"][phase]
+    compiles = poll.get("compiles") or []
+    if sum(e["s"] for e in compiles) > held / 2:
+        return "XLA compiled meanwhile: " + _compiled_text(compiles)
+    host = poll.get("host")
+    if not host:
+        return ("the record carries no `host` account (an older program's "
+                "dump): a starved thread, a stopped process, the runtime "
+                "and the device cannot be told apart")
+    busy = (f"; the machine was {host['busy_share']:.0%} busy"
+            if "busy_share" in host else "")
+    if host.get("runq_s", 0.0) > held / 2:
+        return (f"the scheduler thread stood runnable with no core for "
+                f"{host['runq_s']:.3f} s of it (`runq_s`): the host starved "
+                f"the thread{busy}")
+    late, gc_s = host.get("beat_late_s", 0.0), host.get("gc_s", 0.0)
+    if max(late, gc_s) > held / 2:
+        return (f"the whole process stood: the heartbeat came {late:.3f} s "
+                f"late, the collector ran {gc_s:.3f} s (the interpreter "
+                f"lock held, the process stopped or paged out){busy}")
+    if host.get("busy_share", 0.0) >= 0.95:
+        return (f"the machine was over its cores ({host['busy_share']:.0%} "
+                "busy over the record's stretch) though this thread waited "
+                f"for one only {host.get('runq_s', 0.0):.3f} s")
+    if "beat_late_s" not in host:
+        return ("no compile and no collection, but the record has no "
+                "heartbeat to say whether the process's threads ran: a "
+                "starved process and the runtime cannot be told apart")
+    waited = (f"run-queue wait {host['runq_s']:.3f} s" if "runq_s" in host
+              else "this host gives no run-queue wait")
+    return (f"the thread slept (cpu {host['cpu_s']:.3f} s, {waited}) while "
+            f"the process's beats came on time (latest {late * 1e3:.1f} "
+            f"ms) and nothing compiled{busy}: the runtime or the device "
+            "held the burst")
+
+
 def _clock_lines(polls: List[Dict[str, Any]]) -> List[str]:
-    """What the polls cost: the slowest five by phase, and a DIAGNOSIS
-    when one poll's ``admit`` (a dispatch blocked) or ``read_wait`` (the
-    device or the runtime held a burst) took over ten times the dump's
-    median burst period — every lane stood still for it."""
+    """What the polls cost: the slowest five by phase with the host's
+    account of each (``host``, ``compiles``), and a DIAGNOSIS when one
+    poll's ``admit`` (a dispatch of an admission blocked) or ``read_wait``
+    (a burst's tokens did not come back) took over ten times the dump's
+    median burst period — every lane stood still for it. The cause is
+    named from the record (:func:`held_by`), not guessed."""
     slow = slowest_polls(polls)
     if not slow:
         return []
@@ -580,20 +660,13 @@ def _clock_lines(polls: List[Dict[str, Any]]) -> List[str]:
             + f"), {p.get('admitted', 0)} admitted, "
             f"{p.get('pending_bursts', 0)} bursts in flight, "
             f"device {'drained' if p.get('drained') else 'busy'} at its "
-            "first dispatch"
+            "first dispatch" + _host_text(p)
         )
     periods = sorted(burst_periods(polls))
     if not periods:
         return lines
     median = periods[len(periods) // 2]
-    causes = {
-        "admit": "a dispatch of an admission blocked (its programs "
-                 "queued behind the running burst, the runtime held it, or "
-                 "it compiled)",
-        "read_wait": "the device or the runtime took that long to hand "
-                     "a burst's tokens back",
-    }
-    for phase, cause in causes.items():
+    for phase in ("admit", "read_wait"):
         over = [p for p in polls
                 if p.get("phase_s", {}).get(phase, 0.0) > 10 * median]
         if not over:
@@ -605,8 +678,8 @@ def _clock_lines(polls: List[Dict[str, Any]]) -> List[str]:
             f"t={worst['t']:.3f} spent {worst['phase_s'][phase]:.3f} s in "
             f"`{phase}`, {worst['phase_s'][phase] / median:.0f}x the median "
             f"burst period ({median * 1e3:.1f} ms over {len(periods)}); "
-            f"{len(over)} poll(s) over ten periods — {cause}; no lane "
-            "got a token meanwhile"
+            f"{len(over)} poll(s) over ten periods — "
+            f"{held_by(worst, phase)}; no lane got a token meanwhile"
             + (f"; requests admitted in that poll: {ids}" if ids else "")
         )
     return lines
