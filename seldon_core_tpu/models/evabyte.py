@@ -375,6 +375,17 @@ class EvaByteLM(DecoderFamily):
             "w2": init((F, D), F, res),
         }
 
+    def burst_params(self, params):
+        """Every layer's q / k / v projection weights [D, H Dh] and the
+        head [D, P V] held [out, D]: the burst's projections of its 20 rows
+        consume them contraction-minor, and handed the stored layout the
+        TPU compiler relays all 25 at the top of every burst (826 MB
+        written and read again: all of the burst's scratch; PERF.md
+        section 6, PR 54). (No serving mesh: ``serving_refuses``.)"""
+        return {**self.relaid(params, ("unembed",)),
+                "layers": [self.relaid(p, ("wq", "wk", "wv"))
+                           for p in params["layers"]]}
+
     # -- the cache ---------------------------------------------------------------
 
     def init_cache(self, batch: int, max_seq=None):
@@ -424,11 +435,10 @@ class EvaByteLM(DecoderFamily):
         """The layer's projections of the normed input a [B, T, D]: q, k
         (rotated at ``positions``: [T] or [B, T]) and v, [B, H, T, Dh]."""
         cfg = self.cfg
-        dt = a.dtype
         B, T, _ = a.shape
 
         def heads(w):
-            return (a @ p[w].astype(dt)).reshape(
+            return self.project(p, w, a).reshape(
                 B, T, cfg.n_heads, cfg.head_dim).transpose(0, 2, 1, 3)
 
         return (_rope(heads("wq"), positions, cfg.rope_theta),
@@ -465,8 +475,8 @@ class EvaByteLM(DecoderFamily):
 
         cfg = self.cfg
         a = self._norm(x, params["ln_f"])
-        logits = jnp.dot(a, params["unembed"].astype(a.dtype),
-                         preferred_element_type=jnp.float32)
+        logits = self.project(params, "unembed", a,
+                              preferred_element_type=jnp.float32)
         return logits.reshape(*x.shape[:-1], cfg.num_pred_heads,
                               cfg.vocab_size)
 

@@ -283,6 +283,57 @@ class DecoderFamily(ServedModel):
         return (2 * cfg.n_layers * rows * cfg.n_kv_heads * bucket
                 * cfg.head_dim * 2)
 
+    def burst_params(self, params):
+        """``params`` as the serving bursts take them: the tree the batcher
+        derives once where it takes its params (and again at a weight
+        swap) and hands to the executables that run the decode step alone
+        (the bursts, a checkpoint's replay); prefill and every other
+        executable keep ``params``. The default is the object it was
+        given: the burst is then the program it always was. A family whose
+        compiled burst wants a weight in another layout than the stored one
+        (the TPU compiler else relays it at the top of EVERY burst, a copy
+        in and out of HBM of a weight that never changes) answers with a
+        tree that holds that weight in that layout under another name
+        (``relaid``), and its step contracts against the leaf the tree it
+        is handed has (``project``). Under a serving mesh the answer is
+        ``params``: ``param_sharding``'s column and row sets name the
+        stored layout."""
+        return params
+
+    # the suffix of a leaf ``relaid`` holds contraction-minor, and the one
+    # place that names it
+    _RELAID = "_t"
+
+    @classmethod
+    def relaid(cls, p: dict, names) -> dict:
+        """``p`` with each weight of ``names`` ([..., D, out]) replaced by
+        its transpose over the last two axes ([..., out, D], row-major)
+        under ``<name>_t``: what a projection of a few rows fused with what
+        follows it consumes on a TPU, the contraction dimension minor."""
+        import jax.numpy as jnp
+
+        out = {k: v for k, v in p.items() if k not in names}
+        for name in names:
+            out[name + cls._RELAID] = jnp.swapaxes(p[name], -1, -2)
+        return out
+
+    @classmethod
+    def project(cls, p: dict, name: str, x, **how):
+        """``x [..., D] @ p[name] [D, out]`` in ``x``'s dtype, against the
+        leaf ``p`` has: the stored weight, or its ``relaid`` transpose (a
+        Python test of the tree while tracing: a trace handed the stored
+        tree is the code it always was). ``how``: ``dot_general``'s
+        keywords (``preferred_element_type``)."""
+        from jax import lax
+
+        w = p.get(name)
+        if w is not None:
+            dims = (((x.ndim - 1,), (0,)), ((), ()))
+        else:
+            w = p[name + cls._RELAID]
+            dims = (((x.ndim - 1,), (1,)), ((), ()))
+        return lax.dot_general(x, w.astype(x.dtype), dims, **how)
+
     def burst_reads_ragged(self, cache, mesh=None) -> bool:
         """Whether the decode step over ``cache``, lowered for the platform
         its arrays live on, bounds each lane's read by the lane's own

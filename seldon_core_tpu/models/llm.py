@@ -238,9 +238,9 @@ class DecoderLM(DecoderFamily):
         dt = x.dtype
         B, T, D = x.shape
         h = _rms_norm(x, p["ln1"].astype(dt), cfg.norm_eps)
-        q = h @ p["wq"].astype(dt)  # [B,T,Hl*Dh] (Hl = local heads under tp)
-        k = h @ p["wk"].astype(dt)
-        v = h @ p["wv"].astype(dt)
+        q = self.project(p, "wq", h)  # [B,T,Hl*Dh] (Hl = local heads under tp)
+        k = self.project(p, "wk", h)
+        v = self.project(p, "wv", h)
         Hl = q.shape[-1] // cfg.head_dim
         KVl = k.shape[-1] // cfg.head_dim
         q = q.reshape(B, T, Hl, cfg.head_dim).transpose(0, 2, 1, 3)
@@ -307,9 +307,9 @@ class DecoderLM(DecoderFamily):
         dt = x.dtype
         B, T, _ = x.shape
         h = _rms_norm(x, p["ln1"].astype(dt), cfg.norm_eps)
-        q = h @ p["wq"].astype(dt)
-        k = h @ p["wk"].astype(dt)
-        v = h @ p["wv"].astype(dt)
+        q = self.project(p, "wq", h)
+        k = self.project(p, "wk", h)
+        v = self.project(p, "wv", h)
         q, k, v = (a.reshape(B, T, -1, cfg.head_dim).transpose(0, 2, 1, 3)
                    for a in (q, k, v))
         return (_rope(q, positions, cfg.rope_theta),
@@ -864,6 +864,19 @@ class DecoderLM(DecoderFamily):
 
         axis = "data" if "data" in mesh.axis_names else None
         return NamedSharding(mesh, P(axis, None))
+
+    def burst_params(self, params):
+        """The stacked q / k / v projection weights [L, D, out] held [L,
+        out, D]: the burst's projection of its few rows, fused with the
+        head split and the rotary, consumes them contraction-minor, and
+        handed the stored layout the TPU compiler relays all three at the
+        top of every burst (402 MB written and read again in InternLM, 705
+        MB in Mistral: all of the burst's scratch; PERF.md section 6, PR
+        54)."""
+        if self._serving_mesh is not None:
+            return params
+        return {**params,
+                "blocks": self.relaid(params["blocks"], ("wq", "wk", "wv"))}
 
     def param_sharding(self, mesh, params):
         """TP layout over the ``model`` axis for pjit-style serving."""

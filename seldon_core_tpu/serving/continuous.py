@@ -368,6 +368,28 @@ def _positions_windowed(pos: int, k: int, window: int, block: int) -> tuple:
     return streamed, seen, live
 
 
+class _BurstExecutable:
+    """A jitted function of the batcher that runs the family's decode step
+    (a burst, the replay). Called with the batcher's ``params`` (by the
+    scheduler, ``warm()``, a comparison with a reference that drives the
+    batcher's own executables) it runs on the tree derived from them
+    (``_derive_burst_params``): no caller knows the burst's layout. Any
+    other tree goes through as given, and what else is asked of it
+    (``lower``, ``_cache_size``) is the jitted function's."""
+
+    def __init__(self, batcher: "ContinuousBatcher", jitted):
+        self._batcher = batcher
+        self._jitted = jitted
+
+    def __call__(self, params, *args):
+        b = self._batcher
+        return self._jitted(
+            b._burst_params if params is b.params else params, *args)
+
+    def __getattr__(self, name):
+        return getattr(self._jitted, name)
+
+
 class ContinuousBatcher:
     """Slot-based continuous batching scheduler over a decoder family
     (``models/family.py:DecoderFamily``: everything it asks of a model).
@@ -988,6 +1010,9 @@ class ContinuousBatcher:
                 dp = jax.device_put(dp, draft_model.param_sharding(mesh, dp))
             self._draft_params = dp
         self._alloc_device_state()
+        # after the cache is there, so that the load's transient peak is
+        # params and cache as it always was
+        self._derive_burst_params()
 
         # -- executables -----------------------------------------------------
 
@@ -1701,6 +1726,13 @@ class ContinuousBatcher:
 
             self._draft_prefill_fn = jax.jit(draft_prefill)
             self._draft_insert_fn = jax.jit(draft_insert, donate_argnums=(0,))
+
+        # the executables that run the family's decode step take the params
+        # in the layout that step consumes (_derive_burst_params)
+        for name in ("_burst_fn", "_fused_burst_fn", "_block_burst_fn",
+                     "_spec_burst_fn", "_replay_fn"):
+            if getattr(self, name) is not None:
+                setattr(self, name, _BurstExecutable(self, getattr(self, name)))
 
     # -- public api ----------------------------------------------------------
 
@@ -2850,6 +2882,7 @@ class ContinuousBatcher:
                 return  # cancelled between the drain check and here
             old_v = self.weight_version
             self.params = swap.params
+            self._derive_burst_params()
             self.weight_version = swap.version
             # drop the boot-cast memo so the old buffer's last pin dies
             # with the pointer flip (double-buffering ends here)
@@ -3013,6 +3046,25 @@ class ContinuousBatcher:
             job, self._pending_drain = self._pending_drain, None
         if job is not None and not job.future.done():
             job.future.set_exception(err)
+
+    @scheduler_only
+    def _derive_burst_params(self) -> None:
+        """``self.params`` as the burst executables take them
+        (``model.burst_params``: the weights whose layout the family's
+        compiled burst consumes, transposed once here and not at the top of
+        every burst; ``self.params`` itself where the family states none).
+        The tree held before goes first, so a swap holds one derived copy
+        at a time. ``stats["burst_params_relaid_bytes"]``: the bytes of the
+        leaves held beside the stored ones."""
+        import jax
+
+        self._burst_params = None
+        self._burst_params = self.model.burst_params(self.params)
+        stored = {id(leaf) for leaf in jax.tree_util.tree_leaves(self.params)}
+        self.stats["burst_params_relaid_bytes"] = sum(
+            leaf.nbytes
+            for leaf in jax.tree_util.tree_leaves(self._burst_params)
+            if id(leaf) not in stored)
 
     @scheduler_only
     def _alloc_device_state(self) -> None:

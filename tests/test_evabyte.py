@@ -572,3 +572,80 @@ def test_it_serves_bytes_through_the_batcher(model, params, tokens, ref):
         assert b.stats["eva_summaries_written"] > 0
     finally:
         b.close()
+
+
+# -- the burst's params (ISSUE 54): every layer's q / k / v weights and the
+# head, held contraction-minor once beside the stored ones -------------------
+
+
+def test_burst_params_hold_the_projections_and_the_head_contraction_minor(
+        model, params):
+    bp = model.burst_params(params)
+    assert "unembed" not in bp and bp["unembed_t"].shape == (P * V, 64)
+    assert bp["embed"] is params["embed"] and bp["ln_f"] is params["ln_f"]
+    for p, q in zip(params["layers"], bp["layers"]):
+        assert sorted(q) == sorted(
+            {"wq_t", "wk_t", "wv_t"} | (set(p) - {"wq", "wk", "wv"}))
+        for w in ("wq", "wk", "wv"):
+            np.testing.assert_array_equal(q[w + "_t"], np.asarray(p[w]).T)
+        assert all(q[k] is p[k] for k in p if k not in ("wq", "wk", "wv"))
+    assert "wq" in params["layers"][0] and "unembed" in params
+
+
+def test_the_step_on_burst_params_is_the_step_on_params_bit_for_bit(tokens):
+    """In the serving dtype: every head's logits, both kinds of rows and the
+    counters, at a step that completes a chunk and its window (31), one
+    inside a chunk and an idle lane."""
+    m = DecoderLM(**dict(KW, dtype="bfloat16"))
+    p = jax.tree_util.tree_map(lambda a: a.astype(jnp.bfloat16),
+                               m.init_params(0))
+    prompt = np.zeros((1, 32), np.int32)
+    prompt[0] = tokens[:32]
+    _logits, slab = m.prefill(p, jnp.asarray(prompt), 32,
+                              jnp.asarray([30], jnp.int32))
+    cache = m.cache_layers(3, 128)
+    for kind, layers in cache.items():
+        for l in range(2):
+            layers[l] = layers[l].at[:2, :, :slab[kind].shape[3]].set(
+                jnp.broadcast_to(slab[kind][l], (2,) + slab[kind].shape[2:]))
+    t = jnp.asarray([31, 17, 0], jnp.int32)
+    feed = jnp.asarray(tokens[np.asarray(t)][:, None], jnp.int32)
+    lens = jnp.asarray([32, 18, 0], jnp.int32)
+    step = jax.jit(m._step)
+    stored = step(p, cache, feed, t, None, None, lens)
+    relaid = step(m.burst_params(p), cache, feed, t, None, None, lens)
+    for a, b in zip(jax.tree_util.tree_leaves(stored),
+                    jax.tree_util.tree_leaves(relaid)):
+        np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                      np.asarray(b, np.float32))
+    assert np.asarray(stored[0], np.float32)[:2].any()
+    assert np.asarray(stored[2]).tolist()[4] == 2      # the chunk lane 0 ended
+
+
+def test_the_batchers_burst_runs_on_the_derived_tree_whoever_calls_it(
+        model, params, tokens, batcher):
+    """A caller that drives the batcher's own ``_burst_fn`` with the
+    batcher's params (the benchmark's comparison does) gets the executable
+    the scheduler runs, on the derived tree, and compiles no second one; a
+    tree of its own goes through as it is, to the same tokens."""
+    relaid = 4 * (sum(p[w].size for p in params["layers"]
+                      for w in ("wq", "wk", "wv")) + params["unembed"].size)
+    assert batcher.stats["burst_params_relaid_bytes"] == relaid
+    assert batcher.params is params and batcher._burst_params is not params
+    live = jnp.asarray([True, True, False, True, False, False])
+    zeros = jnp.zeros((6,), jnp.float32)
+
+    def burst(tree):
+        cache, (cur_tok, pos, keys), _ = _admit(
+            batcher, params, tokens, {0: 6, 1: 30, 3: 94})
+        toks, *_rest, cache, _keys, _counts = batcher._burst_fn(
+            tree, cache, cur_tok, pos, live, zeros, keys, 5, None)
+        batcher._cache = cache
+        return np.asarray(toks)
+
+    mine = burst(params)
+    assert batcher._burst_fn._cache_size() == 1
+    np.testing.assert_array_equal(burst(batcher._burst_params), mine)
+    assert batcher._burst_fn._cache_size() == 1
+    np.testing.assert_array_equal(burst(dict(params)), mine)
+    assert batcher._burst_fn._cache_size() == 2

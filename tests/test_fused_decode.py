@@ -594,9 +594,13 @@ def test_burst_executable_matches_stepwise_model(model_and_params, kind,
         budgets = np.array([BURST_K, BURST_K, 3, BURST_K], np.int32)
         toks, pos, cache, counts, done = _run_burst(
             b, kind, params, lists, cur0, pos0, active, budgets, attn_len)
+        # the stepwise model on the tree the burst is handed (its q / k / v
+        # weights contraction-minor: in float32 the CPU's matmul sums in
+        # another order by layout, and the rows are compared bit for bit)
+        assert b.params is params
         want_toks, want_pos, want = _reference_burst(
-            model, params, stacked, cur0, pos0, active, attn_len,
-            budgets=budgets if kind == "stop_burst" else None)
+            model, model.burst_params(params), stacked, cur0, pos0, active,
+            attn_len, budgets=budgets if kind == "stop_burst" else None)
         if kind == "stop_burst":
             np.testing.assert_array_equal(np.asarray(counts), [8, 0, 3, 8])
             assert np.asarray(done).all()

@@ -29,7 +29,15 @@ reads the optimised HLO:
   layer's slice written to an HBM temporary and prefetched from there,
   read twice and written once where one read would do: FAIL. A layer's
   slices are tied to its input (``decode_step_ragged_list``) and go
-  straight into VMEM.
+  straight into VMEM;
+* the weights' layout (ISSUE 54, ROADMAP S12): a ``copy`` / ``copy-start``
+  in the entry computation, outside the ``while``, of 1 MiB or more of
+  bf16 whose operand is a parameter, or comes from one through nothing but
+  moves, is a weight relaid at the top of every burst, written to HBM and
+  read back where the stored bytes never change: its bytes are printed
+  (``weights_relaid_on_entry``), and FAIL where the configuration's family
+  states the layout its burst consumes (``DecoderFamily.burst_params``:
+  the burst is compiled on that tree, as the batcher hands it over).
 
 The burst is checked with ``attn_len=None`` (what the chip runs since
 ISSUE 31: where the read takes each lane's length the executable has no
@@ -181,6 +189,55 @@ def weight_slices_through_hbm(hlo: str) -> int:
     return n
 
 
+# ops that hand their operand on as it is, or move it: what lies between a
+# parameter and the copy that relays it (a prefetch into fast memory in
+# slices, joined by a ``ConcatBitcast`` custom call)
+_MOVES = ("copy", "copy-start", "copy-done", "slice-start", "slice-done",
+          "bitcast", "custom-call", "get-tuple-element", "tuple")
+_RELAID_MIN = 1 << 20
+
+
+def weights_relaid_on_entry(hlo: str) -> int:
+    """Bytes of bf16 that ``copy`` / ``copy-start`` instructions of the
+    entry computation (outside the ``while``: once a burst) write, 1 MiB or
+    more each, in another order of dimensions than their operand has, where
+    the operand is a parameter or comes from one through ``_MOVES`` alone: a
+    weight the burst relays before its first step. (A copy in the operand's
+    own order is a prefetch into another memory, not a layout.)"""
+    comps = computations(hlo)
+    made: dict = {}
+    for line in comps[comps[""]]:
+        m = _INSTR_RE.match(line)
+        if m:
+            args = line[m.end():].split(")", 1)[0]
+            made[m.group(1)] = (m.group(3), m.group(2),
+                                re.findall(r"%([\w.\-]+)", args))
+
+    def from_parameter(name):
+        op, _result, operands = made.get(name, ("", "", []))
+        return op == "parameter" or (
+            op in _MOVES and any(from_parameter(o) for o in operands))
+
+    def laid(result):
+        """``(dims, minor-to-major)`` of a result's (first) bf16 array."""
+        m = re.match(r"^\(*bf16\[([\d,]+)\]\{([\d,]+)", result)
+        return m.groups() if m else None
+
+    total = 0
+    for op, result, operands in made.values():
+        mine = laid(result)
+        if op not in ("copy", "copy-start") or not mine:
+            continue
+        size = 2
+        for d in mine[0].split(","):
+            size *= int(d)
+        if size >= _RELAID_MIN and any(
+                from_parameter(o) and laid(made[o][1]) != mine
+                for o in operands):
+            total += size
+    return total
+
+
 def bucket_shaped(hlo: str, lanes: int, kv: int, attn_len: int, dh: int) -> int:
     """Arrays of the bucket's shape ``[n <= lanes, KV, attn_len, Dh]``
     anywhere in the module, a fusion's inside included: the slice the
@@ -274,33 +331,63 @@ def alias_count(hlo: str) -> int:
     return len(re.findall(r"\{\d+\}: \(\d+, \{\}", header))
 
 
-def compile_burst(cfg: dict, attn_len, device_sharding):
-    """``_burst_fn`` compiled from shapes at the configuration's sizes:
-    nothing is allocated, so this needs no device memory."""
-    import jax
-    import jax.numpy as jnp
-
+def served_model(cfg: dict):
+    """The configuration's model, as the benchmark builds it."""
     from benchmark import manifest
     from seldon_core_tpu.models.llm import DecoderLM
-    from seldon_core_tpu.serving.continuous import ContinuousBatcher
 
     kwargs = manifest.architecture(
         ROOT, manifest.load(ROOT), cfg["architecture"]).model_kwargs(cfg, 0)
     kwargs.pop("seed")
-    model = DecoderLM(**kwargs)
+    return DecoderLM(**kwargs)
+
+
+def burst_layout_bytes(model) -> int:
+    """Bytes (bf16) of the leaves that the family's ``burst_params`` holds
+    in a layout of its own, beside the stored ones: 0 where the burst takes
+    the stored tree."""
+    import jax
+
+    stored = jax.eval_shape(model.init_params, 0)
+    derived = jax.eval_shape(model.burst_params, stored)
+    have = {jax.tree_util.keystr(path)
+            for path, _ in jax.tree_util.tree_flatten_with_path(stored)[0]}
+    return sum(2 * leaf.size for path, leaf in
+               jax.tree_util.tree_flatten_with_path(derived)[0]
+               if jax.tree_util.keystr(path) not in have)
+
+
+def compile_burst(cfg: dict, attn_len, device_sharding):
+    """``_burst_fn`` compiled from shapes at the configuration's sizes, on
+    the tree the batcher hands its bursts (``burst_params``): nothing is
+    allocated, so this needs no device memory."""
+    import jax
+    import jax.numpy as jnp
+
+    from seldon_core_tpu.serving.continuous import ContinuousBatcher
+
+    model = served_model(cfg)
     mc = model.cfg
     lanes, T = cfg["server"]["slots"], cfg["server"]["max_seq"]
-    # the executables are closures of the constructor; its own device state
-    # is one lane of 128 positions, and no parameter is touched
-    batcher = ContinuousBatcher(model, {}, slots=1, max_seq=128)
+
+    class Executables(ContinuousBatcher):
+        """The executables are closures of the constructor; its own device
+        state is one lane of 128 positions, and it is given no parameter:
+        none to touch, and none to derive the burst's tree from."""
+
+        def _derive_burst_params(self):
+            self._burst_params = self.params
+
+    batcher = Executables(model, {}, slots=1, max_seq=128)
 
     def sds(shape, dt):
         return jax.ShapeDtypeStruct(shape, dt, sharding=device_sharding)
 
     dt = jnp.dtype(mc.dtype)
     params = jax.tree_util.tree_map(
-        lambda a: sds(a.shape, dt), jax.eval_shape(model.init_params, 0)
-    )
+        lambda a: sds(a.shape, dt),
+        jax.eval_shape(model.burst_params,
+                       jax.eval_shape(model.init_params, 0)))
     # the cache as the batcher carries it (``cache_layers``): a dict of
     # kinds, each a list of one array a layer that has the kind
     cache = jax.tree_util.tree_map(
@@ -354,6 +441,8 @@ def check(cfg: dict, attn_len, device_sharding, hlo_dir=None,
     bucket = bucket_shaped(hlo, lanes, kv, attn_len, dh) if bounded else 0
     scatters = cache_scatters(hlo, lanes, kv, T, dh)
     two_hop = weight_slices_through_hbm(hlo)
+    relaid = weights_relaid_on_entry(hlo)
+    layout_bytes = burst_layout_bytes(served_model(cfg))
     return {
         "lanes": lanes, "attn_len": attn_len,
         "cache_shaped_copies_and_slices": kinds,
@@ -368,7 +457,10 @@ def check(cfg: dict, attn_len, device_sharding, hlo_dir=None,
         "bucket_shaped_arrays": bucket,
         "cache_shaped_scatters": scatters,
         "weight_slices_through_hbm": two_hop,
+        "weights_relaid_on_entry": relaid,
+        "burst_layout_bytes": layout_bytes,
         "ok": not found and not scatters and not two_hop
+        and not (layout_bytes and relaid)
         and aliases >= leaves
         and mem.alias_size_in_bytes >= cache_bytes
         and kernels == {"inside": layers, "outside": 0} and not bucket
