@@ -32,6 +32,7 @@ FAMILIES: Dict[str, str] = {
     "evabyte": "seldon_core_tpu.models.evabyte.EvaByteLM",
     "sdar_moe": "seldon_core_tpu.models.sdar_moe.SdarMoeLM",
     "lfm2_moe": "seldon_core_tpu.models.lfm2_moe.Lfm2MoeLM",
+    "jamba": "seldon_core_tpu.models.jamba.JambaLM",
 }
 
 

@@ -13,7 +13,8 @@ and write strength ``beta`` in (0, 1) does
                       front of it (width ``K``, SiLU, no bias) and the
                       tail of ``K - 1`` inputs a lane keeps between tokens;
                       ``activation=None`` is the lfm2_moe block's gated
-                      short convolution, whose gates lie outside
+                      short convolution, whose gates lie outside; ``bias``
+                      is the jamba block's Mamba convolution's
 ``gated_delta_prefill()``  whole prompts in chunks of ``CHUNK`` positions
                       (the published kernels' 64): inside a chunk the
                       rule is a unit lower-triangular solve and a few
@@ -88,13 +89,16 @@ def _activated(y, activation):
     return jax.nn.silu(y)
 
 
-def conv_prefill(x, w, lens, activation="silu"):
+def conv_prefill(x, w, lens, activation="silu", bias=None):
     """x [B, T, C] (the layer's q, k, v side by side; a gated short
-    convolution's ``B * u``), w [K, C] (tap j multiplies the input ``K - 1
-    - j`` positions back) -> the causal depthwise convolution under
-    ``activation`` [B, T, C], and each sequence's tail [B, K - 1, C]: its
-    inputs at positions ``lens - K + 1 .. lens - 1`` (zeros before the
-    sequence's start), which is what the next token's convolution reads."""
+    convolution's ``B * u``; a Mamba mixer's input), w [K, C] (tap j
+    multiplies the input ``K - 1 - j`` positions back) -> the causal
+    depthwise convolution under ``activation`` [B, T, C], and each
+    sequence's tail [B, K - 1, C]: its inputs at positions ``lens - K + 1
+    .. lens - 1`` (zeros before the sequence's start), which is what the
+    next token's convolution reads. ``bias`` [C] (the jamba block's; a
+    Python None in every other caller's trace) goes in before the
+    activation."""
     t = x.shape[1]
     k = w.shape[0]
     padded = jnp.pad(x, ((0, 0), (k - 1, 0), (0, 0)))
@@ -104,15 +108,19 @@ def conv_prefill(x, w, lens, activation="silu"):
           + jnp.arange(k - 1, dtype=jnp.int32))
     tail = jnp.take_along_axis(x, jnp.maximum(at, 0)[:, :, None], axis=1)
     tail = jnp.where(at[:, :, None] >= 0, tail, jnp.zeros_like(tail))
+    if bias is not None:
+        y = y + bias.astype(jnp.float32)
     return _activated(y, activation).astype(x.dtype), tail
 
 
-def conv_step(x, tail, w, live, activation="silu"):
+def conv_step(x, tail, w, live, activation="silu", bias=None):
     """x [B, C] this token's input, tail [B, K - 1, C] -> the convolution's
     output at this token [B, C] and the new tail; an idle lane's tail is
-    kept as it is."""
+    kept as it is. ``bias``: as ``conv_prefill`` takes it."""
     window = jnp.concatenate([tail, x[:, None].astype(tail.dtype)], axis=1)
     y = jnp.sum(window.astype(jnp.float32) * w.astype(jnp.float32)[None], 1)
+    if bias is not None:
+        y = y + bias.astype(jnp.float32)
     new = jnp.where(live[:, None, None], window[:, 1:], tail)
     return _activated(y, activation).astype(x.dtype), new
 

@@ -733,17 +733,18 @@ def test_flash_kernel_compiled_for_v5e_under_the_block_mask(one_chip):
         assert texts[0] != texts[1]
 
 
-def _lfm2(one_chip):
+def _configured(one_chip, name):
+    """``(configuration, model, parameter shapes, sds)`` of a configuration's
+    file, the parameters as shapes on the described chip."""
     import jax
     import jax.numpy as jnp
 
     from benchmark import manifest
     from seldon_core_tpu.models.llm import DecoderLM
 
-    with open(os.path.join(ROOT, "benchmark", "configs",
-                           "lfm2-24b-a2b.json")) as f:
+    with open(os.path.join(ROOT, "benchmark", "configs", name + ".json")) as f:
         cfg = json.load(f)
-    cfg["name"] = "lfm2-24b-a2b"
+    cfg["name"] = name
     kwargs = manifest.architecture(
         ROOT, manifest.load(ROOT), cfg["architecture"]).model_kwargs(cfg, 0)
     kwargs.pop("seed")
@@ -756,6 +757,10 @@ def _lfm2(one_chip):
         lambda a: sds(a.shape, jnp.dtype(model.cfg.dtype)),
         jax.eval_shape(model.init_params, 0))
     return cfg, model, params, sds
+
+
+def _lfm2(one_chip):
+    return _configured(one_chip, "lfm2-24b-a2b")
 
 
 def test_lfm2_burst_compiled_for_v5e_is_the_kernel_over_two_heads_a_row_in_place(one_chip):
@@ -821,3 +826,78 @@ def test_lfm2_prefill_compiled_for_v5e_is_flash_at_head_64_and_grouped_at_1536(
     assert len(flash) == 3 and all(f"bf16[32,{T},64]" in line for line in flash)
     # the tails come out at the prompts' own lengths: [10, 1, 2, 2048]
     assert "bf16[10,1,2,2048]" in hlo
+
+
+def _jamba(one_chip):
+    return _configured(one_chip, "jamba2-3b")
+
+
+def test_jamba_burst_compiled_for_v5e_is_three_scanned_runs_over_a_state_in_place(one_chip):
+    """The configuration's own burst (the cell's lanes of 8,192, all 28
+    layers, no bucket): the 26 Mamba layers are three scanned runs, so the
+    program holds THREE state kernels (one a run's body) and the two
+    attention layers' ragged kernels at 20 query rows on one KV head, all
+    inside the ``while``; keys, values, the tails and the float32 state [lanes,
+    26, 16, 5120] are aliased through, and nothing of the state's shape is
+    copied or sliced out: no layer's state leaves its array."""
+    import re
+
+    tool = _tool()
+    cfg, _model, _params, _sds = _jamba(one_chip)
+    compiled, (lanes, kv, T, dh), cache_bytes, leaves = tool.compile_burst(
+        cfg, None, one_chip)
+    assert (lanes, kv, T, dh, leaves) == (
+        cfg["server"]["slots"], 1, 8192, 128, 2 + 2 + 1 + 1)
+    # 1,024 B a position, 358,400 B a lane and Mamba layer
+    assert cache_bytes == lanes * (T * 1024 + 26 * 358_400)
+    hlo = compiled.as_text()
+    assert tool.kernel_calls(hlo) == {"inside": 3 + 2, "outside": 0}
+    names = re.findall(r"%([a-z_]+)[.\d]* = [^\n]*? custom-call\(", hlo)
+    assert names.count("selective_scan_step") == 3
+    assert names.count("ragged_decode_attention") == 2
+    call = next(line for line in hlo.splitlines()
+                if "custom-call(" in line and "ragged_decode_attention" in line)
+    assert f"bf16[{lanes},1,20,128]" in call and f"bf16[{lanes},1,8192,128]" in call
+    step = next(line for line in hlo.splitlines()
+                if "custom-call(" in line and "selective_scan_step" in line)
+    assert f"f32[{lanes},26,16,5120]" in step
+    assert "output_to_operand_aliasing" in step
+    assert tool.cache_shaped(hlo, lanes, 1, (T,), 128) == []
+    assert tool.cache_scatters(hlo, lanes, 1, T, 128) == 0
+    # the state itself: no copy, slice or dynamic-slice of a layer's [lanes,
+    # 16, 5120] or of the whole array, a fusion's inside included
+    assert not re.search(
+        rf"f32\[{lanes},(26|1),16,5120\][^\n]*? (copy|slice|dynamic-slice)\(", hlo)
+    assert tool.alias_count(hlo) >= leaves
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= cache_bytes
+    assert mem.temp_size_in_bytes < 512 << 20
+
+
+def test_jamba_prefill_compiled_for_v5e_is_a_scan_kernel_a_run_and_flash_at_20_heads(
+        one_chip, monkeypatch):
+    """The configuration's own prefill of eight prompts in the 1024 bucket
+    (all 28 layers, counters and all), lowered as on a TPU: one
+    ``selective_scan_prefill`` kernel a scanned run, the two attention
+    layers through the flash kernel at 20 heads of 128 without a rotary; the
+    states and tails come out at the prompts' own lengths, one array a kind
+    over the 26 layers."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    _cfg, model, params, sds = _jamba(one_chip)
+    # ``ops.attention`` asks the process's backend, which is the CPU here
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    B, T = 8, 1024
+    hlo = jax.jit(lambda p, t, last: model.prefill_counted(p, t, T, last)).lower(
+        params, sds((B, T), jnp.int32), sds((B,), jnp.int32)).compile().as_text()
+    names = re.findall(r"%([a-z_]+)[.\d]* = [^\n]*? custom-call\(", hlo)
+    assert names.count("selective_scan_prefill") == 3
+    flash = [line for line in hlo.splitlines()
+             if "custom-call(" in line and "flash_attention" in line]
+    assert len(flash) == 2 and all(f"bf16[{B * 20},{T},128]" in line
+                                   for line in flash)
+    assert f"f32[1,{B},26,16,5120]" in hlo and f"bf16[1,{B},26,3,5120]" in hlo
+    assert "sine" not in hlo and "cosine" not in hlo

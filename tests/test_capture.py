@@ -241,7 +241,13 @@ def test_phase_in_progress_at_capture_start_is_in_the_trace(tmp_path, reenter):
 def test_capture_on_off_byte_identical_and_no_new_executables(
         model_and_params, tmp_path, temperature):
     def run(b):
-        return run_one_at_a_time(b, temperature) if temperature else run_batch(b)
+        # greedy: all four queued before the first poll, so that which
+        # prompts share a prefill (and so which executables exist) does not
+        # depend on how fast the submits ran (under a loaded machine
+        # ``run_batch`` compiled 4 executables for one batcher and 5 for the
+        # other: PR 55, on the parent's tree as on the change's)
+        return (run_one_at_a_time(b, temperature) if temperature
+                else run_in_one_wave(b, PROMPTS))
 
     b_off = make_batcher(model_and_params, fused_steps_per_dispatch=8)
     try:

@@ -188,7 +188,10 @@ def test_the_rule_answers_for_the_configurations_as_the_benchmark_holds_them():
         "qwen3-next-80b-a3b": WIDE_BLOCK, "sdar-30b-a3b": WIDE_BLOCK,
         # 8 KV heads of 64 are 4 rows of 128: 256 KiB a 128 keys, as the
         # cache lies (two heads a row) and as the file states it
-        "lfm2-24b-a2b": WIDE_BLOCK}
+        "lfm2-24b-a2b": WIDE_BLOCK,
+        # ONE KV head of 128: 64 KiB a 128 keys, the rule's wide block (a
+        # wider one still is faster there: PERF.md section 5, ROADMAP S19 b)
+        "jamba2-3b": WIDE_BLOCK}
 
 
 # lanes of the one-position call at the rule's 256 keys a block: (length,
