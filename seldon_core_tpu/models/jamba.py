@@ -9,7 +9,8 @@ blocks it shares the embedding lookup, ``_rms_norm``, the KV cache's ops
 (``ops.decode_attention``, the flash kernel) and the causal depthwise
 convolution with its tail (``ops/gated_delta.py``: ``conv_prefill`` /
 ``conv_step``, the qwen3_next block's at its own width of 4 with SiLU, here
-with a ``bias``); the recurrence is ``ops/selective_scan.py``. Every layer is
+with a ``bias``; the step's, on a TPU, is ``conv_tail_step``'s kernel over
+the one array of tails); the recurrence is ``ops/selective_scan.py``. Every layer is
 pre-norm,
 
     h = x + Mixer(N(x));   y = h + SwiGLU(N(h))
@@ -47,8 +48,11 @@ layers, [S, Lm, K - 1, C] and float32 [S, Lm, N, C] (the published state is
 [C, N]: held transposed, ``N`` along sublanes and the channels along lanes,
 so that a row of the state fills lanes), the lane axis first as the batcher
 asks and the layer next: the runs' scans carry the two arrays whole and the
-step's kernel updates one (lane, layer) block of the state in place, so no
-layer's state is sliced out of a stack or written back into one.
+step's two kernels (``ops/selective_scan.py``: ``conv_tail_step``,
+``selective_scan_step``) update the layer's blocks of the tails and of the
+state in place, so no layer's tails or state are sliced out of a stack or
+written back into one, and no op of its own touches a per-lane array
+between the mixer's matrix products.
 
 Serving only; what it refuses is ``serving_refuses``: everything that
 truncates, splices or copies COLUMNS of a KV cache needs the state and the
@@ -401,11 +405,13 @@ class JambaLM(DecoderFamily):
         return h + (jax.nn.silu(m @ p["w1"].astype(dt))
                     * (m @ p["w3"].astype(dt))) @ p["w2"].astype(dt)
 
-    def _mamba_in(self, p, u):
+    def _mamba_in(self, p, u, whole=False):
         """The normed input u [..., D] -> the convolution's input ``a`` and
-        the gate ``z`` [..., C]."""
+        the gate ``z`` [..., C]; ``whole``: ``a`` is the product itself,
+        [..., 2 C], for a reader that stops at C."""
         az = u @ p["w_in"].astype(u.dtype)
-        return az[..., :self._channels], az[..., self._channels:]
+        return (az if whole else az[..., :self._channels],
+                az[..., self._channels:])
 
     def _ssm_inputs(self, p, c):
         """The convolution's output c [..., C] -> what the scan takes
@@ -592,13 +598,16 @@ class JambaLM(DecoderFamily):
         from jax import lax
 
         from ..ops import decode_attention
-        from ..ops.gated_delta import conv_step
-        from ..ops.selective_scan import selective_scan_step
+        from ..ops.selective_scan import (conv_tail_step, lanes_walked,
+                                          selective_scan_step)
 
         pos = pos.astype(jnp.int32)
         wp = pos if write_pos is None else write_pos.astype(jnp.int32)
         lens = pos + 1 if lens is None else lens.astype(jnp.int32)
         live = lens > 0
+        # what the mixers' kernels take of ``live``: once a step, not once a
+        # layer
+        walk = lanes_walked(live)
         mesh = self._serving_mesh
         x = self._embed_tokens(params, tokens)  # [B, 1, D]
         new = {name: list(layers) for name, layers in cache.items()}
@@ -607,13 +616,15 @@ class JambaLM(DecoderFamily):
             x, tails, states = carry
             p, layer = xs
             with jax.named_scope("mamba_mixer"):
-                a, z = self._mamba_in(p, self._norm(x[:, 0], p["ln_in"]))
-                tail = lax.dynamic_index_in_dim(tails, layer, 1, keepdims=False)
-                c, tail = conv_step(a, tail, p["conv_w"], live,
-                                    bias=p["conv_b"])
-                tails = lax.dynamic_update_index_in_dim(tails, tail, layer, 1)
+                # ``a`` as it lies in ``x W_in``: no slice of its own
+                a, z = self._mamba_in(p, self._norm(x[:, 0], p["ln_in"]),
+                                      whole=True)
+                c, tails = conv_tail_step(
+                    tails, layer, a, p["conv_w"], p["conv_b"], live,
+                    mesh=mesh, walk=walk)
                 states, y = selective_scan_step(
-                    states, layer, c, *self._ssm_inputs(p, c), live, mesh=mesh)
+                    states, layer, c, *self._ssm_inputs(p, c), live,
+                    mesh=mesh, walk=walk)
                 x = x + self._mamba_out(p, y, z)[:, None]
             return (self._ffn(p, x), tails, states), None
 

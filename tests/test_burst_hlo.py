@@ -106,6 +106,51 @@ def test_reader_counts_kernel_calls_and_bucket_shaped_arrays():
     assert tool.kernel_calls(with_kernel) == {"inside": 1, "outside": 1}
 
 
+def test_reader_finds_the_mixers_bodies_their_kernels_and_their_per_lane_ops():
+    """A Mamba run's ``while`` body as the parent of ISSUE 56 compiled it (the
+    state kernel alone, and the tail's slice, ``a``'s copy, the kernel's
+    operands as ops of their own) and as the issue leaves it."""
+    tool = _tool()
+    assert tool.mixer_bodies(HLO, 28, 5120, 4) == []
+    body = """
+%mamba.1 (p: (bf16[192,26,3,5120], f32[192,26,16,5120])) -> (bf16[192,26,3,5120], f32[192,26,16,5120]) {
+  %p = (bf16[192,26,3,5120]{3,0,2,1}, f32[192,26,16,5120]{3,2,1,0}) parameter(0)
+  %fusion.589 = bf16[192,10240]{1,0:T(8,128)(2,1)} fusion(%p), kind=kOutput, calls=%fused_computation.1
+  %copy.174 = bf16[192,1,5120]{2,1,0:T(2,128)(2,1)} copy(%fusion.589)
+  %dynamic-slice_bitcast_fusion.6 = bf16[192,3,5120]{2,1,0:T(4,128)(2,1)} fusion(%p), kind=kLoop, calls=%fused_computation.1
+  %bitcast.4 = f32[192,1,5120]{2,1,0} bitcast(%copy.174)
+  %reshape.570 = f32[192,1,5120]{2,1,0:T(1,128)} reshape(%copy.174)
+  %bitcast_add_fusion.15 = bf16[192,1,2560]{2,0,1:T(8,128)(2,1)} fusion(%p), kind=kOutput, calls=%fused_computation.1
+  %selective_scan_step.15 = (f32[192,26,16,5120]{3,2,1,0}, f32[192,1,5120]{2,1,0}) custom-call(%p), custom_call_target="tpu_custom_call"
+  ROOT %t = (bf16[192,26,3,5120]{3,0,2,1}, f32[192,26,16,5120]{3,2,1,0}) tuple(%p)
+}
+"""
+    loop = ("  %while.9 = (bf16[192,26,3,5120]{3,0,2,1}, f32[192,26,16,5120]{3,2,1,0})"
+            " while(%tup), condition=%cond.3, body=%mamba.1\n")
+    hlo = HLO.replace("ENTRY", body + "\nENTRY", 1).replace(
+        "  %gte.9 =", loop + "  %gte.9 =")
+    (found,) = tool.mixer_bodies(hlo, 192, 5120, 4)
+    assert found["body"] == "mamba.1"
+    assert found["kernels"] == ["selective_scan_step"]
+    # the residual stream's [192, 1, 2560] is no per-lane array of the mixer,
+    # and a bitcast is no op
+    assert [line.split(" = ")[0] for line in found["per_lane_ops"]] == [
+        "%copy.174", "%dynamic-slice_bitcast_fusion.6", "%reshape.570"]
+    # other lanes, another width: not these arrays
+    assert tool.mixer_bodies(hlo, 64, 5120, 4)[0]["per_lane_ops"] == []
+    assert tool.mixer_bodies(hlo, 192, 2048, 4)[0]["per_lane_ops"] == []
+    tails = ('  %conv_tail_step.15 = (bf16[26,3,192,5120]{3,2,1,0}, bf16[192,5120]{1,0})'
+             ' custom-call(%p), custom_call_target="tpu_custom_call"\n')
+    after = "\n".join(
+        line for line in hlo.splitlines()
+        if not line.strip().startswith(("%copy.174", "%dynamic-slice_bitcast",
+                                        "%reshape.570", "%bitcast.4"))
+    ).replace("  %selective_scan_step.15 =", tails + "  %selective_scan_step.15 =")
+    (found,) = tool.mixer_bodies(after, 192, 5120, 4)
+    assert found["kernels"] == ["conv_tail_step", "selective_scan_step"]
+    assert found["per_lane_ops"] == []
+
+
 def test_reader_counts_scatters_into_the_cache():
     """The write before ISSUE 32, as the parent's burst compiled it: a
     scatter of ``Dh`` rows, the root of a fusion of its own, one for K and
@@ -835,11 +880,14 @@ def _jamba(one_chip):
 def test_jamba_burst_compiled_for_v5e_is_three_scanned_runs_over_a_state_in_place(one_chip):
     """The configuration's own burst (the cell's lanes of 8,192, all 28
     layers, no bucket): the 26 Mamba layers are three scanned runs, so the
-    program holds THREE state kernels (one a run's body) and the two
-    attention layers' ragged kernels at 20 query rows on one KV head, all
-    inside the ``while``; keys, values, the tails and the float32 state [lanes,
-    26, 16, 5120] are aliased through, and nothing of the state's shape is
-    copied or sliced out: no layer's state leaves its array."""
+    program holds THREE tails kernels and THREE state kernels (one of each
+    a run's body) and the two attention layers' ragged kernels at 20 query
+    rows on one KV head, all inside the ``while``; keys, values, the tails
+    [lanes, 26, 3, 5120] and the float32 state [lanes, 26, 16, 5120] are
+    aliased through, and nothing of the state's or the tails' shape is copied
+    or sliced out: no layer's state or tails leave their array, and between
+    a mixer's matrix products no op of its own holds a per-lane [lanes, 1,
+    5120] or [lanes, 3, 5120] (ISSUE 56)."""
     import re
 
     tool = _tool()
@@ -851,8 +899,9 @@ def test_jamba_burst_compiled_for_v5e_is_three_scanned_runs_over_a_state_in_plac
     # 1,024 B a position, 358,400 B a lane and Mamba layer
     assert cache_bytes == lanes * (T * 1024 + 26 * 358_400)
     hlo = compiled.as_text()
-    assert tool.kernel_calls(hlo) == {"inside": 3 + 2, "outside": 0}
+    assert tool.kernel_calls(hlo) == {"inside": 3 + 3 + 2, "outside": 0}
     names = re.findall(r"%([a-z_]+)[.\d]* = [^\n]*? custom-call\(", hlo)
+    assert names.count("conv_tail_step") == 3
     assert names.count("selective_scan_step") == 3
     assert names.count("ragged_decode_attention") == 2
     call = next(line for line in hlo.splitlines()
@@ -862,13 +911,36 @@ def test_jamba_burst_compiled_for_v5e_is_three_scanned_runs_over_a_state_in_plac
                 if "custom-call(" in line and "selective_scan_step" in line)
     assert f"f32[{lanes},26,16,5120]" in step
     assert "output_to_operand_aliasing" in step
+    # x, delta and y as they lie, [lanes, 5120] in bfloat16; b and c [16, lanes]
+    assert step.count(f"bf16[{lanes},5120]") >= 3
+    assert step.count(f"bf16[16,{lanes}]") == 2 and "f32[192,1,5120]" not in step
+    tails = next(line for line in hlo.splitlines()
+                 if "custom-call(" in line and "conv_tail_step" in line)
+    # the tails as they lie between executables, a layer's tap [lanes, 5120];
+    # ``a`` where it lies in the in-projection's product
+    assert f"bf16[26,3,{lanes},5120]" in tails
+    assert f"bf16[{lanes},10240]" in tails
+    assert "output_to_operand_aliasing" in tails
+    # a run's body: the two kernels and no per-lane op of its own
+    bodies = tool.mixer_bodies(hlo, lanes, 5120, 4)
+    assert len(bodies) == 3
+    for body in bodies:
+        assert body["kernels"] == ["conv_tail_step", "selective_scan_step"]
+        assert body["per_lane_ops"] == []
     assert tool.cache_shaped(hlo, lanes, 1, (T,), 128) == []
     assert tool.cache_scatters(hlo, lanes, 1, T, 128) == 0
-    # the state itself: no copy, slice or dynamic-slice of a layer's [lanes,
-    # 16, 5120] or of the whole array, a fusion's inside included
+    # the state and the tails themselves: no copy, slice or dynamic-slice of
+    # a layer's [lanes, 16 | 3, 5120] or of the whole array, a fusion's
+    # inside included, whichever way round the tails are shown
     assert not re.search(
         rf"f32\[{lanes},(26|1),16,5120\][^\n]*? (copy|slice|dynamic-slice)\(", hlo)
+    assert not re.search(
+        rf"bf16\[({lanes},(26|1),3|26,3,{lanes}|{lanes},3),5120\][^\n]*? "
+        r"(copy|slice|dynamic-slice|transpose)\(", hlo)
     assert tool.alias_count(hlo) >= leaves
+    # what is still relaid at the burst's top: ``wq`` and ``W_x`` (S12 b),
+    # not the tails' 153 MB each way
+    assert tool.weights_relaid_on_entry(hlo) < 80 << 20
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= cache_bytes
     assert mem.temp_size_in_bytes < 512 << 20

@@ -103,3 +103,47 @@ def test_a_used_lane_is_readmitted_and_the_counters_come_home(served):
         len(p) for p, _ in asked)
     assert stats["ssm_prefill_steps_bucket"] == N_MAMBA * stats["prefill_tokens"]
     assert stats["ssm_prefill_steps_walked"] < stats["ssm_prefill_steps_bucket"]
+
+
+def test_the_burst_through_the_steps_kernels_is_the_reference_loops(monkeypatch):
+    """The mixer's two kernels (``ops/selective_scan.py``: the tails in
+    place, the state fed ``c`` and ``delta`` as they lie), interpreted, in
+    the batcher's own burst at 16 lanes: five requests, so a lane idle
+    beside live ones and a whole group of 8 idle lanes at every step; the
+    tokens are the reference loop's, and a lane no request took still
+    holds the zeros it was made with."""
+    from seldon_core_tpu.ops import selective_scan as ss
+
+    traced = []
+
+    def scan(s, layer, x, delta, b, c, a, d, live, mesh=None, walk=None):
+        traced.append("state")
+        return ss.selective_scan_step_kernel(
+            s, layer, x, delta, b, c, a, d, live, walk, interpret=True)
+
+    def tails(t, layer, a, w, bias, live, mesh=None, walk=None):
+        traced.append("tails")
+        return ss.conv_tail_step_kernel(
+            t, layer, a, w, bias, live, walk, interpret=True)
+
+    monkeypatch.setattr(ss, "selective_scan_step", scan)
+    monkeypatch.setattr(ss, "conv_tail_step", tails)
+    model = DecoderLM(**dict(SMALL, d_model=128, n_layers=4))
+    params = model.init_params(5)
+    assert ss.steps_in_kernel("tpu", (16, 3, 16, 256))
+    batcher = ContinuousBatcher(
+        model, params, slots=16, max_seq=128, prefill_buckets=(16, 32),
+        steps_per_poll=4, attn_bucket=128)
+    try:
+        asked = [(_prompt(200 + n, n), new) for n, new in (
+            (3, 9), (20, 6), (11, 10), (1, 7), (30, 5))]
+        futures = [batcher.submit(p, max_new_tokens=new) for p, new in asked]
+        for (prompt, new), f in zip(asked, futures):
+            assert f.result(timeout=600)[len(prompt):] == reference.generate(
+                params, model.cfg, prompt, new)
+        assert batcher.stats["ssm_lane_steps"] > 0
+        assert {"state", "tails"} == set(traced)
+        for name in ("conv", "state"):
+            assert not np.asarray(batcher._cache[name][0])[8:].any()
+    finally:
+        batcher.close()

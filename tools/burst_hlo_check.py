@@ -37,7 +37,16 @@ reads the optimised HLO:
   read back where the stored bytes never change: its bytes are printed
   (``weights_relaid_on_entry``), and FAIL where the configuration's family
   states the layout its burst consumes (``DecoderFamily.burst_params``:
-  the burst is compiled on that tree, as the batcher hands it over).
+  the burst is compiled on that tree, as the batcher hands it over);
+* the Mamba mixers (ISSUE 56; a configuration with ``mamba_d_conv``): each
+  scanned run's ``while`` body (the computation that calls
+  ``selective_scan_step``) holds exactly two Mosaic kernels,
+  ``conv_tail_step`` and ``selective_scan_step``, and no instruction of its
+  own whose result is ``[lanes, 1, C]`` or ``[lanes, K - 1, C]``: a slice,
+  a copy, a cast or a reshape of a per-lane array between the mixer's
+  matrix products (what a fusion that feeds a dot holds inside is the
+  dot's). The burst then needs one kernel call an attention layer and two a
+  run, not one a layer.
 
 The burst is checked with ``attn_len=None`` (what the chip runs since
 ISSUE 31: where the read takes each lane's length the executable has no
@@ -149,6 +158,12 @@ def cache_shaped(hlo: str, lanes: int, kv: int, lengths, dh: int) -> list:
     return found
 
 
+def _is_kernel(m, line: str) -> bool:
+    """Whether the instruction ``_INSTR_RE`` matched is a Mosaic kernel call."""
+    return bool(m) and m.group(3) == "custom-call" \
+        and 'custom_call_target="tpu_custom_call"' in line
+
+
 def kernel_calls(hlo: str) -> dict:
     """Mosaic kernel calls that run as ops of their own:
     ``{"inside": n, "outside": m}`` the ``while``."""
@@ -156,10 +171,35 @@ def kernel_calls(hlo: str) -> dict:
     out = {"inside": 0, "outside": 0}
     for name, inside in scheduled(comps).items():
         for line in comps[name]:
-            m = _INSTR_RE.match(line)
-            if m and m.group(3) == "custom-call" \
-                    and 'custom_call_target="tpu_custom_call"' in line:
+            if _is_kernel(_INSTR_RE.match(line), line):
                 out["inside" if inside else "outside"] += 1
+    return out
+
+
+_NO_OP = ("bitcast", "get-tuple-element", "parameter", "tuple", "constant")
+
+
+def mixer_bodies(hlo: str, lanes: int, channels: int, taps: int) -> list:
+    """The Mamba runs' ``while`` bodies (each scheduled computation that
+    calls a ``selective_scan_step`` kernel): ``{"body", "kernels": the
+    Mosaic kernels it calls, in order, "per_lane_ops": its own instructions
+    whose result is [lanes, 1, channels] or [lanes, taps - 1, channels]}``."""
+    comps = computations(hlo)
+    shape = re.compile(r"^\(*\w+\[%d,(1|%d),%d\]" % (lanes, taps - 1, channels))
+    out = []
+    for name, inside in scheduled(comps).items():
+        kernels, per_lane = [], []
+        for line in comps[name] if inside else ():
+            m = _INSTR_RE.match(line)
+            if not m:
+                continue
+            if _is_kernel(m, line):
+                kernels.append(re.sub(r"[.\d]+$", "", m.group(1)))
+            elif m.group(3) not in _NO_OP and shape.match(m.group(2)):
+                per_lane.append(line.strip()[:160])
+        if "selective_scan_step" in kernels:
+            out.append({"body": name, "kernels": kernels,
+                        "per_lane_ops": per_lane})
     return out
 
 
@@ -443,6 +483,16 @@ def check(cfg: dict, attn_len, device_sharding, hlo_dir=None,
     two_hop = weight_slices_through_hbm(hlo)
     relaid = weights_relaid_on_entry(hlo)
     layout_bytes = burst_layout_bytes(served_model(cfg))
+    mixers = None
+    if cfg.get("mamba_d_conv"):
+        # one kernel an attention layer, two a scanned run of Mamba layers
+        mixers = mixer_bodies(
+            hlo, lanes, cfg["mamba_expand"] * cfg["hidden_size"],
+            cfg["mamba_d_conv"])
+        attention = sum(
+            i % cfg["attn_layer_period"] == cfg["attn_layer_offset"]
+            for i in range(layers))
+        layers = attention + 2 * len(mixers)
     return {
         "lanes": lanes, "attn_len": attn_len,
         "cache_shaped_copies_and_slices": kinds,
@@ -459,11 +509,14 @@ def check(cfg: dict, attn_len, device_sharding, hlo_dir=None,
         "weight_slices_through_hbm": two_hop,
         "weights_relaid_on_entry": relaid,
         "burst_layout_bytes": layout_bytes,
+        **({"mixer_bodies": mixers} if mixers is not None else {}),
         "ok": not found and not scatters and not two_hop
         and not (layout_bytes and relaid)
         and aliases >= leaves
         and mem.alias_size_in_bytes >= cache_bytes
         and kernels == {"inside": layers, "outside": 0} and not bucket
+        and all(m["kernels"] == ["conv_tail_step", "selective_scan_step"]
+                and not m["per_lane_ops"] for m in mixers or ())
         and (temp_limit is None or mem.temp_size_in_bytes <= temp_limit),
     }
 
