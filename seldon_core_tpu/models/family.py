@@ -33,6 +33,7 @@ FAMILIES: Dict[str, str] = {
     "sdar_moe": "seldon_core_tpu.models.sdar_moe.SdarMoeLM",
     "lfm2_moe": "seldon_core_tpu.models.lfm2_moe.Lfm2MoeLM",
     "jamba": "seldon_core_tpu.models.jamba.JambaLM",
+    "mimo_v2": "seldon_core_tpu.models.mimo_v2.MimoV2LM",
 }
 
 
@@ -190,6 +191,15 @@ class DecoderFamily(ServedModel):
         what a burst reads (``kv_positions_*``) takes the kinds from
         here; every layer of the llama block reads everything."""
         return ((self.cfg.n_layers, None),)
+
+    def row_cache_windows(self) -> Tuple[int, ...]:
+        """The window of every kind of layer that has one and holds a row a
+        position, ``max_seq`` long: the scheduler counts what such a layer's
+        step streams (``kv_positions_*_window``: from the block that holds
+        the window's start) from these. A family whose window layers hold a
+        ring instead counts their reads in its own step
+        (``step_counter_names``) and answers ``()``."""
+        return tuple(w for _n, w in self.attention_kinds() if w is not None)
 
     # -- what the scheduler asks of a cache it did not lay out -------------
     # (serving/continuous.py names no kind and no family: these say what a

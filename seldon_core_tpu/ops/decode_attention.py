@@ -101,34 +101,40 @@ COVERS = 512 * 1024
 WIDE_BLOCK = 256
 
 
-def walk_block(kv_heads: int, head_dim: int, dtype, t: int) -> int:
+def walk_block(kv_heads: int, head_dim: int, dtype, t: int,
+               v_dim: int = None) -> int:
     """Positions a block of the kernel's walk holds, for a cache [B,
     ``kv_heads``, ``t``, ``head_dim``] of ``dtype``: ``WIDE_BLOCK`` where
     ``BLOCK`` keys of K and V are under ``COVERS`` bytes (their copy would
     not cover the iteration's chain) and ``WIDE_BLOCK`` divides the
     cache's length, else ``BLOCK``. A rule on the call's shapes alone.
     Whoever states what the kernel streams (a counter, a roofline's
-    bytes, the scheduler's ``kv_positions_read``) asks here."""
-    copied = 2 * kv_heads * head_dim * jnp.dtype(dtype).itemsize * BLOCK
+    bytes, the scheduler's ``kv_positions_read``) asks here. ``v_dim``: a
+    value row's width where it is not the key row's."""
+    copied = (kv_heads * (head_dim + (v_dim or head_dim))
+              * jnp.dtype(dtype).itemsize * BLOCK)
     if copied < COVERS and t % WIDE_BLOCK == 0:
         return WIDE_BLOCK
     return BLOCK
 
 
-def reads_ragged(platform, q_shape, cache_shape, dtypes, mesh=None) -> bool:
+def reads_ragged(platform, q_shape, cache_shape, dtypes, mesh=None,
+                 v_dim=None) -> bool:
     """Whether ``decode_attention()``, lowered for ``platform``, reads
     each lane's own length (the kernel) and not a static bound of every
     lane (the dots). ``q_shape`` [B, H, T, Dh]; ``cache_shape`` [B, KV,
-    Tc, Dh] of one layer's K (V's is the same); ``dtypes`` of q, K and V.
+    Tc, Dh] of one layer's K (V's is the same, but for its rows' width
+    ``v_dim`` where a caller's keys are wider than its values); ``dtypes``
+    of q, K and V.
 
     The kernel wants one query position, a head size that fills lanes,
     whole GQA groups, whole blocks and one dtype; Mosaic kernels cannot
     be partitioned by GSPMD, so a serving mesh takes the dots."""
     return q_shape[2] == 1 and block_reads_ragged(
-        platform, q_shape, cache_shape, dtypes, mesh)
+        platform, q_shape, cache_shape, dtypes, mesh, v_dim)
 
 
-def cache_attention(q, kc, vc, bound, dt, lo=None):
+def cache_attention(q, kc, vc, bound, dt, lo=None, sink=None):
     """Attention over the (sliced) KV cache with a key_pos <= bound
     mask, WITHOUT materialising a head-repeated cache copy. ``lo``
     (optional, ``bound``'s shape): the first key position a row sees, for
@@ -146,6 +152,11 @@ def cache_attention(q, kc, vc, bound, dt, lo=None):
     its own prefix) or [B, T] (chunked decode — prefix + in-window
     causality). Scores accumulate in f32 (preferred_element_type);
     the bf16 cache is never cast or copied.
+
+    ``vc`` may be narrower than ``kc`` ([B, KV, Ta, Dv]): the output is
+    the values' width. ``sink`` ([H] float32, optional): a learned logit a
+    query head that joins each softmax and has no value row, so that a
+    row's weights sum to ``1 - p_sink``.
     """
     B, Hl, T, Dh = q.shape
     KVl, Ta = kc.shape[1], kc.shape[2]
@@ -164,12 +175,18 @@ def cache_attention(q, kc, vc, bound, dt, lo=None):
         preferred_element_type=jnp.float32,
     ) / np.sqrt(Dh)  # [B, KV, rep, T, Ta]
     s = jnp.where(mask, s, NEG_INF)
-    w = jax.nn.softmax(s, -1).astype(dt)
+    if sink is None:
+        w = jax.nn.softmax(s, -1).astype(dt)
+    else:
+        col = jnp.broadcast_to(
+            sink.astype(jnp.float32).reshape(1, KVl, rep, 1, 1),
+            (B, KVl, rep, T, 1))
+        w = jax.nn.softmax(jnp.concatenate([s, col], -1), -1)[..., :Ta].astype(dt)
     o = lax.dot_general(
         w, vc, (((4,), (2,)), ((0, 1), (0, 1))),
         preferred_element_type=jnp.float32,
-    ).astype(dt)  # [B, KV, rep, T, Dh]
-    return o.reshape(B, Hl, T, Dh)
+    ).astype(dt)  # [B, KV, rep, T, Dv]
+    return o.reshape(B, Hl, T, vc.shape[3])
 
 
 def cache_write(cache, new, positions):
@@ -217,9 +234,15 @@ def _windowed_kernel(starts_ref, *refs, block):
     _ragged_kernel(*refs, block=block, starts_ref=starts_ref)
 
 
+def _sink_kernel(sink_ref, *refs, **how):
+    """``_ragged_kernel`` for a layer whose softmax has a sink:
+    ``sink_ref`` (VMEM [KV, rep, 1] float32) is each query head's logit."""
+    _ragged_kernel(*refs, sink_ref=sink_ref, **how)
+
+
 def _ragged_kernel(lens_ref, wpos_ref, q_ref, knew_ref, vnew_ref, _k_in, _v_in,
                    o_ref, k_hbm, v_hbm, kbuf, vbuf, kstage, vstage, sem, wsem,
-                   rsem, *, block, starts_ref=None, rows=1):
+                   rsem, *, block, starts_ref=None, rows=1, sink_ref=None):
     """The whole batch of one layer: for each lane with ``len > 0``, walk
     its ``ceil(len / block)`` blocks with an online softmax, and where a
     block holds the lane's ``write_pos`` put the new row into it first
@@ -243,8 +266,13 @@ def _ragged_kernel(lens_ref, wpos_ref, q_ref, knew_ref, vnew_ref, _k_in, _v_in,
     [B, KV, GROUP, Dh], the window's rows laid where they go in their
     group, and the queries' ``rep`` counts every row of the window: all
     of them see the same keys, the lane's ``len`` with the window in it.
+
+    A value row may be narrower than a key row (v_hbm [B, KV, T, Dv], o_ref
+    [B, KV, rep, Dv]). ``sink_ref``: a logit a query head that joins the
+    lane's softmax after its last block and has no value row.
     """
     n_lanes, n_kv, rep, dh = q_ref.shape
+    dv = v_hbm.shape[3]
     t = k_hbm.shape[2]
     scale = 1.0 / np.sqrt(dh)
 
@@ -399,12 +427,17 @@ def _ragged_kernel(lens_ref, wpos_ref, q_ref, knew_ref, vnew_ref, _k_in, _v_in,
             )
             return o, m_new, l
 
-        o, _, l = lax.fori_loop(
+        o, m, l = lax.fori_loop(
             0, n_blocks, block_body,
-            (jnp.zeros((n_kv, rep, dh), jnp.float32),
+            (jnp.zeros((n_kv, rep, dv), jnp.float32),
              jnp.full((n_kv, rep, 1), NEG_INF, jnp.float32),
              jnp.zeros((n_kv, rep, 1), jnp.float32)),
         )
+        if sink_ref is not None:
+            sink = sink_ref[...]
+            m_all = jnp.maximum(m, sink)
+            alpha = jnp.exp(m - m_all)
+            o, l = o * alpha, l * alpha + jnp.exp(sink - m_all)
         # a lane of length 0 ran no block: o = 0, l = 0, zeros out
         o_ref[lane] = (o / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
 
@@ -428,10 +461,10 @@ def _ragged_kernel(lens_ref, wpos_ref, q_ref, knew_ref, vnew_ref, _k_in, _v_in,
     lax.fori_loop(0, written, drain, 0)
 
 
-@functools.partial(jax.jit, static_argnames=("block", "interpret"))
+@functools.partial(jax.jit, static_argnames=("block", "interpret", "name"))
 def ragged_decode_attention(q, k, v, lens, k_new, v_new, write_pos,
                             block: int = None, interpret: bool = False,
-                            starts=None):
+                            starts=None, sink=None, name: str = None):
     """Pallas ragged decode attention with the step's write inside it.
     q [B, H, 1, Dh]; k, v the layer's cache [B, KV, T, Dh], unsliced
     (``T`` must divide by ``block``: ``walk_block()``'s for the call's
@@ -463,11 +496,23 @@ def ragged_decode_attention(q, k, v, lens, k_new, v_new, write_pos,
     query of the block attends to [0, lens[b]), the block's own rows among
     them, which is attention that is open inside a block. The kernel is the
     same walk with ``W`` times the query rows a KV head; in a trace its
-    name is ``block_decode_attention``."""
+    name is ``block_decode_attention``.
+
+    v may hold narrower rows than k ([B, KV, T, Dv], v_new [B, KV, 1, Dv]):
+    the output is [B, H, 1, Dv] and the scale the keys'. ``sink`` ([H]
+    float32, optional, one position a lane and no ``starts``): a logit a
+    query head that joins the lane's softmax and has no value row. A
+    cache that is a RING is this call as it is: ``T`` the ring's length,
+    ``write_pos`` the position modulo it, ``lens`` at most ``T`` (keys
+    carry their rotary, so a softmax over a ring needs no order, only the
+    bound of the slots written). ``name``: the kernel's name in a trace,
+    where a caller wants its own."""
     b, h, t_q, dh = q.shape
-    n_kv, t = k.shape[1], k.shape[2]
+    n_kv, t, dv = k.shape[1], k.shape[2], v.shape[3]
     if block is None:
-        block = walk_block(n_kv, dh, k.dtype, t)
+        block = walk_block(n_kv, dh, k.dtype, t, None if dv == dh else dv)
+    if sink is not None and (t_q > 1 or starts is not None):
+        raise ValueError("a sink: one position a lane and no starts")
     if GROUP % t_q or (t_q > 1 and starts is not None) or h % n_kv \
             or t % block or block % GROUP:
         raise ValueError(
@@ -484,7 +529,12 @@ def ragged_decode_attention(q, k, v, lens, k_new, v_new, write_pos,
     if starts is not None:
         kernel = _windowed_kernel
         scalars = (jnp.clip(starts.astype(jnp.int32), 0, t),)
-    named = {}
+    lead_specs = [smem] * len(scalars)
+    if sink is not None:
+        kernel = _sink_kernel
+        scalars = (sink.astype(jnp.float32).reshape(n_kv, rep, 1),)
+        lead_specs = [vmem]
+    named = {} if name is None else {"name": name}
     if t_q > 1:
         # [B, KV, W, Dh] -> [B, KV, GROUP, Dh]: row r holds the window's
         # row r mod W, so the rows lie where they go wherever in its group
@@ -496,18 +546,18 @@ def ragged_decode_attention(q, k, v, lens, k_new, v_new, write_pos,
     out, k, v = pl.pallas_call(
         functools.partial(kernel, block=block),
         out_shape=(
-            jax.ShapeDtypeStruct((b, n_kv, rep, dh), q.dtype),
+            jax.ShapeDtypeStruct((b, n_kv, rep, dv), q.dtype),
             jax.ShapeDtypeStruct(k.shape, k.dtype),
             jax.ShapeDtypeStruct(v.shape, v.dtype),
         ),
-        in_specs=[smem] * len(scalars) + [smem, smem, vmem, vmem, vmem, hbm, hbm],
+        in_specs=lead_specs + [smem, smem, vmem, vmem, vmem, hbm, hbm],
         out_specs=(vmem, hbm, hbm),
         input_output_aliases={len(scalars) + 5: 1, len(scalars) + 6: 2},
         scratch_shapes=[
             pltpu.VMEM((2, n_kv, block, dh), k.dtype),
-            pltpu.VMEM((2, n_kv, block, dh), v.dtype),
+            pltpu.VMEM((2, n_kv, block, dv), v.dtype),
             pltpu.VMEM((b, n_kv, GROUP, dh), k.dtype),
-            pltpu.VMEM((b, n_kv, GROUP, dh), v.dtype),
+            pltpu.VMEM((b, n_kv, GROUP, dv), v.dtype),
             pltpu.SemaphoreType.DMA((2, 2)),
             pltpu.SemaphoreType.DMA((2,)),
             pltpu.SemaphoreType.DMA((2,)),
@@ -518,12 +568,13 @@ def ragged_decode_attention(q, k, v, lens, k_new, v_new, write_pos,
       jnp.clip(lens.astype(jnp.int32), 0, t), write_pos.astype(jnp.int32),
       q.reshape(b, n_kv, rep, dh), k_new.astype(k.dtype),
       v_new.astype(v.dtype), k, v)
-    return out.reshape(b, h, t_q, dh), k, v
+    return out.reshape(b, h, t_q, dv), k, v
 
 
-@functools.partial(jax.jit, static_argnames=("attn_len", "mesh"))
+@functools.partial(jax.jit, static_argnames=("attn_len", "mesh", "name"))
 def decode_attention(q, k, v, k_new, v_new, write_pos, pos, lens,
-                     attn_len=None, mesh=None, starts=None):
+                     attn_len=None, mesh=None, starts=None, sink=None,
+                     name: str = None):
     """The decode step's write and read of one layer's cache: this step's
     rows k_new, v_new [B, KV, 1, Dh] go into the UNSLICED cache k, v [B,
     KV, T, Dh] at ``write_pos`` [B] (outside [0, T): dropped), then q [B,
@@ -557,75 +608,69 @@ def decode_attention(q, k, v, k_new, v_new, write_pos, pos, lens,
     layer with a window (``max(0, lens - window)``); the kernel then
     copies only the blocks from there on, and the dots take the same
     band as a mask. None: every lane sees from 0.
+
+    Keys wider than values (k [B, KV, T, Dk], v [B, KV, T, Dv]), ``sink``
+    ([H] float32: a logit a query head in the softmax, with no value row)
+    and ``name`` (the kernel's name in a trace): as
+    ``ragged_decode_attention()`` takes them (a sink and no ``starts``),
+    the ring among them. ONE choice between the kernel and the dots for
+    every caller: ``starts`` or ``sink`` is an operand of both where given
+    and a Python ``None`` where not, which no trace sees.
     """
+    if sink is not None and starts is not None:
+        raise ValueError("a sink: one position a lane and no starts")
     t = k.shape[2]
     bound = t if attn_len is None else min(int(attn_len), t)
-    if starts is not None:
-        return _windowed_decode_attention(
-            q, k, v, k_new, v_new, write_pos, pos, lens, starts, bound, mesh)
+    given = {n: a for n, a in (("starts", starts), ("sink", sink))
+             if a is not None}
 
-    def dots(q, k, v, k_new, v_new, write_pos, pos, lens):
+    def dots(q, k, v, k_new, v_new, write_pos, pos, lens, *more):
+        more = dict(zip(given, more))
         k = cache_write(k, k_new, write_pos[:, None])
         v = cache_write(v, v_new, write_pos[:, None])
         o = cache_attention(
             q, lax.slice_in_dim(k, 0, bound, axis=2),
-            lax.slice_in_dim(v, 0, bound, axis=2), pos, q.dtype)
+            lax.slice_in_dim(v, 0, bound, axis=2), pos, q.dtype,
+            lo=more.get("starts"), sink=more.get("sink"))
         return o, k, v
 
-    def kernel(q, k, v, k_new, v_new, write_pos, pos, lens):
+    def kernel(q, k, v, k_new, v_new, write_pos, pos, lens, *more):
         return ragged_decode_attention(
-            q, k, v, jnp.minimum(lens, bound), k_new, v_new, write_pos)
+            q, k, v, jnp.minimum(lens, bound), k_new, v_new, write_pos,
+            name=name, **dict(zip(given, more)))
 
-    args = (q, k, v, k_new, v_new, write_pos, pos, lens)
+    args = (q, k, v, k_new, v_new, write_pos, pos, lens, *given.values())
+    dv = v.shape[3]
     # the platform is known only when this is lowered: ask whether a
     # lowering for a TPU takes the kernel, and let that lowering choose
     if not reads_ragged(
-            "tpu", q.shape, k.shape, (q.dtype, k.dtype, v.dtype), mesh):
+            "tpu", q.shape, k.shape, (q.dtype, k.dtype, v.dtype), mesh,
+            None if dv == k.shape[3] else dv):
         return dots(*args)
     return lax.platform_dependent(*args, tpu=kernel, default=dots)
 
 
-def _windowed_decode_attention(q, k, v, k_new, v_new, write_pos, pos, lens,
-                               starts, bound, mesh):
-    """``decode_attention()`` for a layer with a window: the same choice
-    between the kernel and the dots, each taking the lanes' ``starts``."""
-
-    def dots(q, k, v, k_new, v_new, write_pos, pos, lens, starts):
-        k = cache_write(k, k_new, write_pos[:, None])
-        v = cache_write(v, v_new, write_pos[:, None])
-        o = cache_attention(
-            q, lax.slice_in_dim(k, 0, bound, axis=2),
-            lax.slice_in_dim(v, 0, bound, axis=2), pos, q.dtype, lo=starts)
-        return o, k, v
-
-    def kernel(q, k, v, k_new, v_new, write_pos, pos, lens, starts):
-        return ragged_decode_attention(
-            q, k, v, jnp.minimum(lens, bound), k_new, v_new, write_pos,
-            starts=starts)
-
-    args = (q, k, v, k_new, v_new, write_pos, pos, lens, starts)
-    if not reads_ragged(
-            "tpu", q.shape, k.shape, (q.dtype, k.dtype, v.dtype), mesh):
-        return dots(*args)
-    return lax.platform_dependent(*args, tpu=kernel, default=dots)
-
-
-def block_reads_ragged(platform, q_shape, cache_shape, dtypes, mesh=None) -> bool:
+def block_reads_ragged(platform, q_shape, cache_shape, dtypes, mesh=None,
+                       v_dim=None) -> bool:
     """``reads_ragged()`` for ``block_decode_attention()``: the kernel's
     second entry takes a block of ``W = q_shape[2]`` positions a lane where
     ``W`` divides ``GROUP`` (the block then lies in one group of the cache's
     rows) and everything else is as the single position's: a head size
     that fills lanes, whole GQA groups, whole blocks of the walk
-    (``walk_block()``) and one dtype, and no serving mesh."""
+    (``walk_block()``) and one dtype, and no serving mesh. ``v_dim`` (a
+    value row's width where it is not the key row's) fills lanes too:
+    Mosaic slices no row of 192 ("must be aligned to tiling (128)"; such a
+    row occupies 256 in HBM either way), so a family with such keys holds
+    them in rows of 256, zero past the key."""
     _, h, w, dh = q_shape
     n_kv, t = cache_shape[1:3]
     return (
         platform == "tpu"
         and mesh is None
         and GROUP % w == 0
-        and dh % 128 == 0
+        and dh % 128 == 0 and (v_dim or dh) % 128 == 0
         and h % n_kv == 0
-        and t % walk_block(n_kv, dh, dtypes[1], t) == 0
+        and t % walk_block(n_kv, dh, dtypes[1], t, v_dim) == 0
         and len(set(dtypes)) == 1
     )
 

@@ -47,9 +47,11 @@ capacity drops; nothing here shares its code).
                        group that has a row in it: the group's ``W1`` and
                        ``W3`` side by side, then its ``W2``, copied from the
                        stacked arrays where they lie, once, a group ahead
-                       of the tiles; ``silu(a) * g`` never reaches HBM and
-                       the pair's routing weight is applied in float32
-                       before the one store. A tile past the last
+                       of the tiles (an expert too wide for the kernel's
+                       VMEM goes through in slices of its width, a call a
+                       slice: ``width_slices()``); ``silu(a) * g`` never
+                       reaches HBM and the pair's routing weight is applied
+                       in float32 before the one store. A tile past the last
                        group's end is neither fetched nor multiplied, an
                        expert without a row is never read: a pad row's
                        picks (sent to no expert's id by the model) and a
@@ -115,8 +117,10 @@ BAND_ROWS = 128
 # W2 [TF, D], 2 MB each at D = 2048 in bfloat16, two buffers apiece
 DECODE_TF = 512
 DECODE_VMEM_BYTES = 48 << 20
-# the grouped kernel holds an expert's three matrices whole (12.6 MB at 2048
-# x 1024 in bfloat16), two slots apiece, beside a row tile and its products
+# the grouped kernel's ``vmem_limit_bytes``: it holds an expert's three
+# matrices (12.6 MB at 2048 x 1024 in bfloat16), two slots apiece, beside a
+# row tile and its products; an expert whose whole width does not fit goes
+# through in slices of it (``width_slices()``)
 GROUPED_VMEM_BYTES = 64 << 20
 # what ``grouped_experts()`` counts, in order
 GROUPED_COUNTS = ("pairs_moved", "tile_rows")
@@ -171,15 +175,44 @@ def row_tile(pairs: int) -> int:
     return 0
 
 
+def width_slices(w1_shape, dtype) -> int:
+    """The calls of the grouped kernel an expert stack [E, D, F] takes: 1
+    where an expert's whole width fits the kernel's ``GROUPED_VMEM_BYTES``,
+    else the fewest equal slices of the width, each whole registers of 128
+    columns, that do; 0: none does. What a call of ``n`` columns takes: the
+    three matrices' slices, two slots apiece, the row tile and the block of
+    the product, two buffers apiece (the product float32 where sliced), the
+    tile's float32 product and its two float32 halves and ``h``. Mosaic
+    counts the slots, the buffered blocks and ~1.5 MiB of its own, 3 MiB
+    under this sum at D = 4096; compiled for a described v5e at D = 4096: a
+    width of 1152 fits whole (61.4 MiB by this sum) and 1280 does not
+    ("Scoped allocation with size 65.56M and limit 64.00M"; 67.5 by this
+    sum), 2048 does not ("96.00M") and goes in two slices of 1024 (57.3)."""
+    _, d, width = w1_shape
+    item = jnp.dtype(dtype).itemsize
+    tm = ROOM_TILE
+    for slices in range(1, width // 128 + 1):
+        n = width // slices
+        if width % slices or n % 128:
+            continue
+        out = item if slices == 1 else 4
+        if (2 * 3 * d * n * item + 2 * tm * d * (item + out) + tm * d * 4
+                + tm * n * (8 + item)) <= GROUPED_VMEM_BYTES:
+            return slices
+    return 0
+
+
 def groups_in_kernel(platform, xs_shape, w1_shape, dtype) -> bool:
     """Whether the sorted pairs' SwiGLU, lowered for ``platform``, can be
     ``grouped_swiglu()``: a TPU, bfloat16 rows, widths that fill lanes,
-    pairs that cut into tiles."""
+    pairs that cut into tiles, and an expert of which some slice of the
+    width fits the kernel's VMEM (``width_slices()``)."""
     return (
         platform == "tpu"
         and jnp.dtype(dtype) == jnp.bfloat16
         and xs_shape[1] % 128 == 0 and w1_shape[2] % 128 == 0
         and row_tile(xs_shape[0]) > 0
+        and width_slices(w1_shape, dtype) > 0
     )
 
 
@@ -247,7 +280,7 @@ def tile_visits(sizes, pairs: int, tm: int):
 
 def _grouped_kernel(offs_ref, ids_ref, turn_ref, tile_ref, counts_ref, x_ref,
                     wt_ref, w1_hbm, w3_hbm, w2_hbm, y_ref, w1_buf, w3_buf,
-                    w2_buf, sem):
+                    w2_buf, sem, part=None):
     """Grid (V,): one visit. The experts' matrices stay in HBM; ``w*_buf``
     are VMEM [2, ...], two slots apiece, ``sem`` their DMA semaphores [3,
     2]. At a group's first visit its three matrices are waited for (the
@@ -260,17 +293,27 @@ def _grouped_kernel(offs_ref, ids_ref, turn_ref, tile_ref, counts_ref, x_ref,
     are stored; the other rows keep what an earlier visit of the tile
     stored. Past the ``n`` visits the row tile's index stays where it was:
     nothing more is copied, and nothing is computed. (``lax`` primitives:
-    ``tile_visits()`` says why.)"""
+    ``tile_visits()`` says why.)
+
+    ``part = (first, n)`` (static): this call works columns ``first ..
+    first + n - 1`` of every expert's width alone (``W1`` and ``W3`` [D, n],
+    ``W2`` [n, D], copied from where they lie in the whole matrices), and
+    ``y_ref`` is that slice's share of the product, float32 (``_swiglu()``
+    sums the slices)."""
     i32 = jnp.int32
     v = pl.program_id(0)
     tile = tile_ref[v]
 
     def copies(turn, slot):
+        if part is None:
+            srcs = [hbm.at[ids_ref[turn]] for hbm in (w1_hbm, w3_hbm, w2_hbm)]
+        else:
+            e, cols = ids_ref[turn], pl.ds(*part)
+            srcs = [w1_hbm.at[e, :, cols], w3_hbm.at[e, :, cols],
+                    w2_hbm.at[e, cols, :]]
         return [
-            pltpu.make_async_copy(
-                hbm.at[ids_ref[turn]], buf.at[slot], sem.at[i, slot])
-            for i, (hbm, buf) in enumerate((
-                (w1_hbm, w1_buf), (w3_hbm, w3_buf), (w2_hbm, w2_buf)))]
+            pltpu.make_async_copy(src, buf.at[slot], sem.at[i, slot])
+            for i, (src, buf) in enumerate(zip(srcs, (w1_buf, w3_buf, w2_buf)))]
 
     @pl.when(lax.lt(v, counts_ref[0]))
     def _():
@@ -309,15 +352,20 @@ def _grouped_kernel(offs_ref, ids_ref, turn_ref, tile_ref, counts_ref, x_ref,
         y_ref[...] = lax.select(own, y, y_ref[...])
 
 
-def _swiglu_call(xs, walk, by_pair, w1, w3, w2, tm: int, interpret: bool):
-    """``grouped_swiglu()`` over a walk already made (``tile_visits()``)."""
+def _swiglu_call(xs, walk, by_pair, w1, w3, w2, tm: int, interpret: bool,
+                 part=None):
+    """One call of the grouped kernel over a walk already made
+    (``tile_visits()``): the experts' whole width, or with ``part = (first,
+    n)`` those columns of it, in float32."""
     m, d = xs.shape
-    _, _, width = w1.shape
+    width = w1.shape[2] if part is None else part[1]
     rows = lambda v, offs, ids, turn, tile, counts: (tile[v], 0)  # noqa: E731
     hbm = pl.BlockSpec(memory_space=pl.ANY)
     return pl.pallas_call(
-        _grouped_kernel,
-        out_shape=jax.ShapeDtypeStruct((m, d), xs.dtype),
+        _grouped_kernel if part is None
+        else functools.partial(_grouped_kernel, part=part),
+        out_shape=jax.ShapeDtypeStruct(
+            (m, d), xs.dtype if part is None else jnp.float32),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=5,
             grid=(walk[3].shape[0],),
@@ -340,6 +388,20 @@ def _swiglu_call(xs, walk, by_pair, w1, w3, w2, tm: int, interpret: bool):
     )(*walk, xs, by_pair.astype(jnp.float32)[:, None], w1, w3, w2)
 
 
+def _swiglu(xs, walk, by_pair, w1, w3, w2, tm: int, interpret: bool):
+    """``grouped_swiglu()`` over a walk already made: one call of the
+    kernel where an expert's whole width fits it, else one a slice of the
+    width (``width_slices()``), the slices' float32 shares summed and
+    rounded once, as the one call rounds its product."""
+    slices = width_slices(w1.shape, w1.dtype)
+    if slices == 1:
+        return _swiglu_call(xs, walk, by_pair, w1, w3, w2, tm, interpret)
+    n = w1.shape[2] // slices
+    return sum(
+        _swiglu_call(xs, walk, by_pair, w1, w3, w2, tm, interpret, (s * n, n))
+        for s in range(slices)).astype(xs.dtype)
+
+
 @functools.partial(jax.jit, static_argnames=("tm", "interpret"))
 def grouped_swiglu(xs, sizes, by_pair, w1, w3, w2, tm: int = 0,
                    interpret: bool = False):
@@ -358,8 +420,8 @@ def grouped_swiglu(xs, sizes, by_pair, w1, w3, w2, tm: int = 0,
     tm = tm or row_tile(m)
     if not tm or m % tm:
         raise ValueError(f"{m} pairs do not cut into tiles of {tm} rows")
-    return _swiglu_call(xs, tile_visits(sizes, m, tm), by_pair, w1, w3, w2,
-                        tm, interpret)
+    return _swiglu(xs, tile_visits(sizes, m, tm), by_pair, w1, w3, w2, tm,
+                   interpret)
 
 
 def _pairs_ffn(xs, sizes, real, by_pair, w1, w3, w2, kernel: bool = True):
@@ -392,7 +454,7 @@ def _pairs_ffn(xs, sizes, real, by_pair, w1, w3, w2, kernel: bool = True):
     walk = tile_visits(sizes, m, tm)
 
     def grouped(xs, sizes, real, by_pair, w1, w3, w2, *walk):
-        y = _swiglu_call(xs, walk, by_pair, w1, w3, w2, tm, False)
+        y = _swiglu(xs, walk, by_pair, w1, w3, w2, tm, False)
         return jnp.where(real[:, None], y, jnp.zeros((), y.dtype))
 
     # tile rows: counted beside the call, from what the kernel is told

@@ -33,16 +33,28 @@ from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
 
 NEG_INF = -1e30
+# Whole keys and values of one head are resident, two buffers each, rows
+# padded to 128 lanes: up to this many bytes of them the compiler's default
+# scoped limit (16 MiB) holds the call (14,336 keys at a head of 64, on the
+# chip: PR 51); past it (9,728 keys of 192 beside values of 128: "exceeded
+# scoped vmem limit by 416.0K" with a sink's epilogue) the call asks for
+# what it holds and ``RESIDENT_ROOM`` more
+RESIDENT_DEFAULT = 14 << 20
+RESIDENT_ROOM = 16 << 20
 
 
 def _xla_attention(q, k, v, causal: bool, kv_len=None, window=None,
-                   block=None):
+                   block=None, sink=None):
     """Reference attention, same contract as the kernel — delegates to
     parallel/ring.full_attention so the fallback and the trained/ring
     paths share ONE copy of the math. ``window``: the kernel's band, as
     the same masked dots; ``block``: its mask that is open inside a block."""
     from ..parallel.ring import full_attention
 
+    if sink is not None:
+        if not causal or kv_len is not None or block is not None:
+            raise ValueError("a sink is causal, takes no kv_len and no block")
+        return _banded_attention(q, k, v, window, sink)
     if window is None and block is None:
         return full_attention(q, k, v, causal=causal, kv_len=kv_len)
     if not causal or kv_len is not None or not (window is None or block is None):
@@ -67,16 +79,26 @@ def _block_attention(q, k, v, block: int):
     return jnp.einsum("bhqk,bhkd->bhqd", p, v.astype(jnp.float32)).astype(q.dtype)
 
 
-def _banded_attention(q, k, v, window: int):
-    """Causal attention in which query i sees key j iff i - window < j <= i:
-    full_attention's math under the band's mask."""
+def _banded_attention(q, k, v, window, sink=None):
+    """Causal attention in which query i sees key j iff i - window < j <= i
+    (``window`` None: every j <= i): full_attention's math under the band's
+    mask. ``sink`` [H]: a logit a head that joins the softmax as one more
+    column, with no value row."""
     scale = 1.0 / (q.shape[-1] ** 0.5)
     s = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32),
                    k.astype(jnp.float32)) * scale
     row = jnp.arange(q.shape[2])[:, None]
     col = jnp.arange(k.shape[2])[None, :]
-    s = jnp.where(((row >= col) & (col > row - window))[None, None], s, NEG_INF)
-    p = jax.nn.softmax(s, axis=-1)
+    seen = row >= col
+    if window is not None:
+        seen = seen & (col > row - window)
+    s = jnp.where(seen[None, None], s, NEG_INF)
+    if sink is None:
+        p = jax.nn.softmax(s, axis=-1)
+    else:
+        column = jnp.broadcast_to(
+            sink.astype(jnp.float32)[None, :, None, None], (*s.shape[:3], 1))
+        p = jax.nn.softmax(jnp.concatenate([s, column], -1), -1)[..., :-1]
     return jnp.einsum("bhqk,bhkd->bhqd", p, v.astype(jnp.float32)).astype(q.dtype)
 
 
@@ -97,8 +119,16 @@ def _prefixed_attention(q, k, v, prefix: int, prefix_len):
     return jnp.einsum("bhqk,bhkd->bhqd", p, v.astype(jnp.float32)).astype(q.dtype)
 
 
+def _flash_sink_kernel(len_ref, sink_ref, *refs, heads, **how):
+    """``_flash_kernel`` for a layer whose softmax has a sink: ``sink_ref``
+    (SMEM [H] float32, scalar-prefetched) holds a logit a query head, and
+    program ``bh`` is head ``bh mod H``'s."""
+    _flash_kernel(len_ref, *refs, sink=sink_ref[pl.program_id(0) % heads],
+                  **how)
+
+
 def _flash_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, *, block_q, block_k,
-                  causal, window=None, prefix=None, block=None):
+                  causal, window=None, prefix=None, block=None, sink=None):
     """One (bh, q-block) program: stream K/V tiles with online softmax.
 
     q_ref: [1, block_q, Dh]; k_ref: [1, Tk, Dh]; v_ref: [1, Tk, Dv] and
@@ -130,7 +160,9 @@ def _flash_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, *, block_q, block_k,
     power of two that divides 128, with ``causal``, no window): the mask is
     causal over blocks of that many positions and open inside one, row i
     sees columns up to i | (block - 1); only the compare in the diagonal
-    tiles differs, a q-block's last row ends a block.
+    tiles differs, a q-block's last row ends a block. ``sink`` (a float32
+    scalar): a logit that joins every row's softmax after its last tile
+    and has no value row.
     """
     qb = pl.program_id(1)
     scale = 1.0 / np.sqrt(q_ref.shape[-1])
@@ -211,6 +243,10 @@ def _flash_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, *, block_q, block_k,
         first, n_k,
         functools.partial(tile, base=off + lead, col0=lead, seen_of=seen_of,
                           hides_rows=window is not None), carry)
+    if sink is not None:
+        m_all = jnp.maximum(m, sink)
+        alpha = jnp.exp(m - m_all)
+        o, l = o * alpha, l * alpha + jnp.exp(sink - m_all)
     # l == 0 is unreachable via the causal dispatch (see the note in the
     # tile); kept as a belt against 0/0 if the kernel is rebuilt with a
     # row-hiding mask
@@ -236,6 +272,7 @@ def flash_attention(
     prefix=None,
     prefix_len=None,
     block=None,
+    sink=None,
 ):
     """Pallas blocked attention. q [B,H,Tq,Dh], k [B,H,Tk,Dh], v
     [B,H,Tk,Dv] (Dv = Dh everywhere but latent attention's prefill).
@@ -249,7 +286,9 @@ def flash_attention(
     prefix + Tq, .], their first ``prefix`` rows a prefix every query sees
     the first ``prefix_len`` rows of, the others the queries' own
     positions. ``block`` (static int, causal only, no window): the mask is
-    causal over blocks of ``block`` positions and open inside one."""
+    causal over blocks of ``block`` positions and open inside one.
+    ``sink`` ([H] float32, optional): a learned logit a query head that
+    joins every row's softmax and has no value row."""
     if window is not None and not causal:
         raise ValueError("a window is causal")
     if block is not None and (not causal or window is not None
@@ -276,6 +315,15 @@ def flash_attention(
         **({} if block is None else {"block": int(block)}))
     visible = jnp.zeros((1,), jnp.int32) if prefix is None else \
         jnp.reshape(prefix_len, (1,)).astype(jnp.int32)
+    lanes = lambda d: -(-d // 128) * 128  # noqa: E731
+    resident = 2 * t_k * (lanes(dh) + lanes(dv)) * q.dtype.itemsize
+    limits = {} if resident <= RESIDENT_DEFAULT else {
+        "compiler_params": pltpu.CompilerParams(
+            vmem_limit_bytes=resident + RESIDENT_ROOM)}
+    scalars = (visible,)
+    if sink is not None:
+        kernel = functools.partial(_flash_sink_kernel, heads=h, **kernel.keywords)
+        scalars += (sink.astype(jnp.float32).reshape(h),)
     out = pl.pallas_call(
         kernel,
         # under shard_map the result varies over the mesh axes q does
@@ -283,19 +331,20 @@ def flash_attention(
             (b * h, t_q, dv), q.dtype, vma=jax.typeof(q).vma
         ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
+            num_scalar_prefetch=len(scalars),
             grid=(b * h, t_q // block_q),
             in_specs=[
-                pl.BlockSpec((1, block_q, dh), lambda bh, i, n: (bh, i, 0)),
-                pl.BlockSpec((1, t_k, dh), lambda bh, i, n: (bh, 0, 0)),
-                pl.BlockSpec((1, t_k, dv), lambda bh, i, n: (bh, 0, 0)),
+                pl.BlockSpec((1, block_q, dh), lambda bh, i, *_: (bh, i, 0)),
+                pl.BlockSpec((1, t_k, dh), lambda bh, i, *_: (bh, 0, 0)),
+                pl.BlockSpec((1, t_k, dv), lambda bh, i, *_: (bh, 0, 0)),
             ],
             out_specs=pl.BlockSpec(
-                (1, block_q, dv), lambda bh, i, n: (bh, i, 0)),
+                (1, block_q, dv), lambda bh, i, *_: (bh, i, 0)),
         ),
         interpret=interpret,
         **({} if name is None else {"name": name}),
-    )(visible, q.reshape(b * h, t_q, dh), k.reshape(b * h, t_k, dh),
+        **limits,
+    )(*scalars, q.reshape(b * h, t_q, dh), k.reshape(b * h, t_k, dh),
       v.reshape(b * h, t_k, dv))
     return out.reshape(b, h, t_q, dv)
 
@@ -323,7 +372,7 @@ def _tile(t_q, t_k, window=None, prefix=0):
 
 def attention(q, k, v, kv_len=None, causal: bool = True, mesh=None,
               window=None, name=None, prefix=None, prefix_len=None,
-              block=None):
+              block=None, sink=None):
     """Dispatching attention: Pallas flash kernel on TPU when the shape
     tiles onto the MXU, XLA einsum otherwise (CPU, tiny prompts). Inference
     only — the kernel defines no VJP; training paths keep the XLA/ring
@@ -346,6 +395,10 @@ def attention(q, k, v, kv_len=None, causal: bool = True, mesh=None,
     paths take the same mask. A Python ``None`` for every other caller,
     whose programs it leaves as they were.
 
+    ``sink`` ([H] float32, optional): a learned logit a query head that
+    joins every row's softmax and has no value row (causal, with or
+    without a window, no mesh); a Python ``None`` likewise.
+
     ``mesh``: the serving mesh when the caller runs under one. Mosaic
     kernels cannot be partitioned by GSPMD, so the kernel call is wrapped
     in a ``shard_map`` with every operand and the result replicated —
@@ -366,6 +419,8 @@ def attention(q, k, v, kv_len=None, causal: bool = True, mesh=None,
     if prefix is not None and (
             mesh is not None or kv_len is not None or window is not None):
         raise ValueError("a prefix takes no mesh, kv_len or window")
+    if sink is not None and (mesh is not None or prefix is not None):
+        raise ValueError("a sink takes no mesh and no prefix")
     use_kernel = (
         kv_len is None
         and jax.default_backend() == "tpu"
@@ -377,13 +432,14 @@ def attention(q, k, v, kv_len=None, causal: bool = True, mesh=None,
     if not use_kernel:
         if prefix is not None:
             return _prefixed_attention(q, k, v, int(prefix), prefix_len)
-        return _xla_attention(q, k, v, causal, kv_len, window, block)
+        return _xla_attention(q, k, v, causal, kv_len, window, block, sink)
     block_q, block_k = _tile(t_q, t_k, window, prefix or 0)
     kernel = functools.partial(
         flash_attention, causal=causal, block_q=block_q, block_k=block_k,
         window=None if window is None else int(window), name=name,
         prefix=None if prefix is None else int(prefix), prefix_len=prefix_len,
         **({} if block is None else {"block": int(block)}),
+        **({} if sink is None else {"sink": sink}),
     )
     if mesh is not None:
         kernel = jax.shard_map(

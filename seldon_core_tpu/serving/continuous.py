@@ -1501,10 +1501,9 @@ class ContinuousBatcher:
         self._kv_read_block = walk_block(
             model.cfg.n_kv_heads, model.cfg.head_dim,
             position_layers[0].dtype, self.max_seq)
-        # the windows of the model's layers that have one (the kinds come
-        # from the model; the llama block has none)
-        self._kv_windows = tuple(
-            w for _n, w in model.attention_kinds() if w is not None)
+        # the windows of the model's layers that have one and hold a row a
+        # position (the kinds come from the model; the llama block has none)
+        self._kv_windows = tuple(model.row_cache_windows())
         # whether the decode step's read takes each lane's own length on
         # the platform the bursts are lowered for (the cache's devices).
         # Where it does, the bucket bounds nothing in _burst_fn and

@@ -973,3 +973,98 @@ def test_jamba_prefill_compiled_for_v5e_is_a_scan_kernel_a_run_and_flash_at_20_h
                                    for line in flash)
     assert f"f32[1,{B},26,16,5120]" in hlo and f"bf16[1,{B},26,3,5120]" in hlo
     assert "sine" not in hlo and "cosine" not in hlo
+
+
+def _mimo(one_chip):
+    return _configured(one_chip, "mimo-v2.5")
+
+
+def test_mimo_burst_compiled_for_v5e_is_two_named_reads_over_a_cache_of_kinds_in_place(one_chip):
+    """The configuration's own burst (64 lanes of 12,288, all 7 layers, no
+    bucket): the two full layers decode through the ragged kernel at 16
+    query rows a KV head over key rows of 256 beside values of 128, the five
+    window layers through the same kernel under its own name over a RING of
+    128 rows with 8 KV heads and a sink, every expert layer's held experts
+    through the touched-expert kernel at width 2,048, all inside the
+    ``while``; every kind's leaves are aliased through, and nothing of a
+    full layer's leaf's shape is copied, sliced out or scattered into: no
+    cache-sized copy a step, and no window layer holds a ``max_seq``-long
+    array."""
+    import re
+
+    tool = _tool()
+    cfg, _model, _params, _sds = _mimo(one_chip)
+    compiled, (lanes, kv, T, dh), cache_bytes, leaves = tool.compile_burst(
+        cfg, None, one_chip)
+    assert (lanes, kv, T, dh, leaves) == (64, 4, 12288, 192, 2 * 2 + 2 * 5)
+    # as allocated: key rows of 256; 3,072 B a position and full layer, and
+    # five rings of 128 rows of 6,144 B a lane
+    assert cache_bytes == lanes * (2 * 4 * (256 + 128) * 2 * T
+                                   + 5 * 8 * (256 + 128) * 2 * 128)
+    hlo = compiled.as_text()
+    assert tool.kernel_calls(hlo) == {"inside": 7 + 6, "outside": 0}
+    names = re.findall(r"%([a-z_]+)[.\d]* = [^\n]*? custom-call\(", hlo)
+    assert names.count("touched_experts_ffn") == 6
+    assert names.count("ragged_decode_attention") == 2
+    assert names.count("swa_ring_attention") == 5
+    full = next(line for line in hlo.splitlines()
+                if "custom-call(" in line and "ragged_decode_attention" in line)
+    assert "bf16[64,4,16,256]" in full and "bf16[64,4,12288,256]" in full
+    assert "bf16[64,4,12288,128]" in full and "bf16[64,4,16,128]" in full
+    ring = next(line for line in hlo.splitlines()
+                if "custom-call(" in line and "swa_ring_attention" in line)
+    # a layer's ring is an array of its own, 128 rows a lane
+    assert "bf16[64,8,128,256]" in ring and "bf16[64,8,128,128]" in ring
+    assert "f32[8,8,1]" in ring and "12288" not in ring.split("custom-call(")[1]
+    # (a layer's KEY ring, 33.5 MB and all of it read by a step of 64 lanes
+    # past the window, the compiler may prefetch to VMEM before the call
+    # and copy back after it: on the chip one layer's of five, 0.02 ms of a
+    # 12.2 ms step: PERF.md section 6, PR 57)
+    for rows, length, width in ((4, T, 256), (4, T, 128)):
+        assert tool.cache_shaped(hlo, lanes, rows, (length,), width) == []
+        assert tool.cache_scatters(hlo, lanes, rows, length, width) == 0
+    assert tool.alias_count(hlo) >= leaves
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= cache_bytes
+    assert mem.temp_size_in_bytes < 256 << 20
+
+
+def test_mimo_prefill_compiled_for_v5e_is_flash_at_two_widths_a_sink_and_a_band(
+        one_chip, monkeypatch):
+    """The configuration's own prefill of one prompt in the 9728 bucket (all
+    7 layers, counters and all), lowered as on a TPU: the two full layers
+    through the flash kernel at keys of 192 beside values of 128, the five
+    window layers through it under their own name with the band of 128 and
+    the sinks (9,728 keys of one head resident: past the default scoped
+    VMEM, which the call asks for); the rings come out 128 rows long. An
+    expert of 4096 x 2048 (50 MB, two slots apiece 101 MB) does not fit the
+    grouped kernel's VMEM whole: the held experts' rows go through it in
+    two slices of the width (``ops.experts.width_slices``), a call a slice
+    in each of the six expert layers, and no ``ragged_dot``'s loop is left."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    cfg, model, params, sds = _mimo(one_chip)
+    # ``ops.attention`` asks the process's backend, which is the CPU here
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    T = 9728
+    compiled = jax.jit(
+        lambda p, t, last: model.prefill_counted(p, t, T, last)).lower(
+        params, sds((1, T), jnp.int32), sds((1,), jnp.int32)).compile()
+    hlo = compiled.as_text()
+    names = re.findall(r"%([a-z_]+)[.\d]* = [^\n]*? custom-call\(", hlo)
+    assert names.count("grouped_swiglu") == 6 * 2
+    assert names.count("swa_prefill_attention") == 5
+    assert names.count("flash_attention") == 2
+    band = next(line for line in hlo.splitlines()
+                if "custom-call(" in line and "swa_prefill_attention" in line)
+    assert f"bf16[64,{T},192]" in band and f"bf16[64,{T},128]" in band
+    assert "f32[64]" in band
+    # the slab: the full layers' rows and the last 128 rows of the window's
+    assert f"bf16[2,1,4,{T},256]" in hlo and "bf16[5,1,8,128,256]" in hlo
+    assert f"bf16[5,1,8,{T}" not in hlo
+    # beside 11.9 GB of weights and cache the transients have 4 GB
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < 3 << 30, temp

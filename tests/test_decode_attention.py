@@ -191,7 +191,14 @@ def test_the_rule_answers_for_the_configurations_as_the_benchmark_holds_them():
         "lfm2-24b-a2b": WIDE_BLOCK,
         # ONE KV head of 128: 64 KiB a 128 keys, the rule's wide block (a
         # wider one still is faster there: PERF.md section 5, ROADMAP S19 b)
-        "jamba2-3b": WIDE_BLOCK}
+        "jamba2-3b": WIDE_BLOCK,
+        # the FULL layers' 4 KV heads, keys of 192 as the file states them:
+        # 384 KiB a 128 keys; as the cache holds them (key rows of 256
+        # beside values of 128) the same bytes and the same answer
+        "mimo-v2.5": WIDE_BLOCK}
+    assert walk_block(4, 256, "bfloat16", 12288, 128) == WIDE_BLOCK
+    # a ring of 128 rows is one block of the walk, whatever its bytes
+    assert walk_block(8, 256, "bfloat16", 128, 128) == BLOCK
 
 
 # lanes of the one-position call at the rule's 256 keys a block: (length,
@@ -469,7 +476,8 @@ def test_entry_takes_its_choice_from_the_rule(on_a_tpu, monkeypatch):
     got = entry(*args, mesh=None)
     for a, b in zip(got, (dots, sk, sv)):
         assert np.array_equal(_f32(a), _f32(b))
-    assert asked == [("tpu", q.shape, k.shape, (q.dtype,) * 3, None)]
+    # (the last: the values' width, None where it is the keys')
+    assert asked == [("tpu", q.shape, k.shape, (q.dtype,) * 3, None, None)]
 
 
 @pytest.mark.parametrize("lens", [
@@ -643,3 +651,121 @@ def test_batcher_counts_what_the_read_streams_and_what_the_bucket_held(
     assert stats["kv_rows_written_in_kernel"] == 0
     assert counters["kv_rows_written"] == stats["kv_rows_written"]
     assert counters["kv_rows_written_in_kernel"] == 0
+
+
+# -- keys wider than values, a sink in the softmax, a cache that is a ring ------
+# (the mimo_v2 block's calls: ISSUE 57)
+
+def _wide(seed, lanes, kv, rep, t, dk, dv, dtype):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 6)
+    dt = jnp.dtype(dtype)
+    return (jax.random.normal(ks[0], (lanes, kv * rep, 1, dk), dt),
+            jax.random.normal(ks[1], (lanes, kv, t, dk), dt),
+            jax.random.normal(ks[2], (lanes, kv, t, dv), dt),
+            jax.random.normal(ks[3], (lanes, kv, 1, dk), dt),
+            jax.random.normal(ks[4], (lanes, kv, 1, dv), dt),
+            3.0 + jax.random.normal(ks[5], (kv * rep,), jnp.float32))
+
+
+@pytest.mark.parametrize("why,kv,rep,t,lens,wp,sink", [
+    # 16 queries a KV head over a long cache: the full layers' call
+    ("full: 16 a head", 2, 16, 2 * WIDE_BLOCK,
+     [0, 1, 255, 256, 257, 2 * WIDE_BLOCK], [9, 0, 254, 255, 256, 511], False),
+    # a ring of one block: lanes not yet once round (the bound of the slots
+    # written), a full ring written anywhere in it, an idle lane, a parked one
+    ("ring: a sink", 2, 4, BLOCK,
+     [0, 1, 77, BLOCK, BLOCK, BLOCK, 5], [BLOCK, 0, 76, 127, 0, 63, BLOCK],
+     True),
+    ("ring: no sink", 2, 4, BLOCK, [3, BLOCK, 0], [2, 40, BLOCK], False),
+])
+@pytest.mark.parametrize("dtype,tol", [("float32", 1e-5), ("bfloat16", 2 ** -6)])
+def test_keys_wider_than_values_a_sink_and_a_ring(why, kv, rep, t, lens, wp,
+                                                  sink, dtype, tol):
+    """The kernel (interpreted) against the scatter and the dots at keys of
+    256 beside values of 128: outputs of the values' width, caches bit for
+    bit, a lane of length 0 zeros and unwritten; the sink is one more logit
+    a head with no value row, so a row's weights sum to less than one."""
+    q, k, v, kn, vn, logits = _wide(7, len(lens), kv, rep, t, 256, 128, dtype)
+    lens, wp = jnp.asarray(lens, jnp.int32), jnp.asarray(wp, jnp.int32)
+    s = logits if sink else None
+    o, k2, v2 = ragged_decode_attention(
+        q, k, v, lens, kn, vn, wp, interpret=True, sink=s,
+        name="swa_ring_attention" if sink else None)
+    assert o.shape == (len(lens), kv * rep, 1, 128)
+    live = np.asarray(lens) > 0
+    kk = cache_write(k, kn, jnp.where(lens > 0, wp, t)[:, None])
+    vv = cache_write(v, vn, jnp.where(lens > 0, wp, t)[:, None])
+    want = cache_attention(q, kk, vv, lens - 1, q.dtype, sink=s)
+    np.testing.assert_allclose(
+        np.asarray(o, np.float32)[live], np.asarray(want, np.float32)[live],
+        atol=tol, rtol=tol)
+    assert not np.asarray(o, np.float32)[~live].any()
+    assert jnp.array_equal(k2, kk) and jnp.array_equal(v2, vv)
+    if sink:
+        # without the sink the same rows weigh more: it took its share
+        bare = cache_attention(q, kk, vv, lens - 1, q.dtype)
+        assert float(jnp.abs(bare.astype(jnp.float32)
+                             - want.astype(jnp.float32))[live].max()) > 10 * tol
+
+
+def test_a_sink_takes_one_position_a_lane_and_no_starts():
+    q, k, v, kn, vn, s = _wide(1, 2, 1, 2, BLOCK, 128, 128, "float32")
+    lens = jnp.asarray([4, 9], jnp.int32)
+    with pytest.raises(ValueError, match="a sink"):
+        ragged_decode_attention(q, k, v, lens, kn, vn, lens - 1,
+                                interpret=True, sink=s, starts=lens * 0)
+
+
+@pytest.mark.parametrize("why,dk,dv,want", [
+    ("keys of 256 beside values of 128", 256, 128, True),
+    ("a key row of 192: Mosaic slices none", 192, 128, False),
+    ("values that fill no lanes", 256, 64, False),
+])
+def test_the_rule_for_a_ragged_read_at_two_widths(why, dk, dv, want):
+    dts = (jnp.bfloat16,) * 3
+    assert reads_ragged("tpu", (64, 64, 1, dk), (64, 4, 12288, dk), dts,
+                        None, dv) is want, why
+    assert reads_ragged("tpu", (64, 64, 1, dk), (64, 8, BLOCK, dk), dts,
+                        None, dv) is want, why
+    assert not reads_ragged("cpu", (64, 64, 1, dk), (64, 4, 12288, dk), dts,
+                            None, dv)
+
+
+@pytest.mark.parametrize("sink", [False, True])
+def test_entry_takes_the_dots_off_tpu_at_two_widths(sink):
+    """``decode_attention()`` on the CPU: the scatter and the dots, the ring
+    bounded by ``pos = lens - 1``; a parked lane's row is dropped."""
+    q, k, v, kn, vn, logits = _wide(3, 4, 2, 4, BLOCK, 256, 128, "float32")
+    lens = jnp.asarray([1, 60, BLOCK, BLOCK], jnp.int32)
+    wp = jnp.asarray([0, 59, 17, BLOCK], jnp.int32)
+    s = logits if sink else None
+    o, k2, v2 = decode_attention(q, k, v, kn, vn, wp, lens - 1, lens, sink=s,
+                                 name="swa_ring_attention")
+    o3, k3, v3 = ragged_decode_attention(q, k, v, lens, kn, vn, wp,
+                                         interpret=True, sink=s)
+    np.testing.assert_allclose(np.asarray(o), np.asarray(o3), atol=1e-5)
+    assert jnp.array_equal(k2, k3) and jnp.array_equal(v2, v3)
+    assert jnp.array_equal(k2[3], k[3])
+
+
+@pytest.mark.parametrize("dtype,tol", [("float32", 1e-5), ("bfloat16", 2 ** -6)])
+def test_one_entry_takes_a_window_at_two_widths(dtype, tol):
+    """``decode_attention()`` is ONE choice between the kernel and the dots
+    for every caller, so ``starts`` meets keys wider than values there: the
+    dots under the band are the kernel's windowed walk (interpreted), caches
+    bit for bit; a sink beside ``starts`` is refused before either."""
+    t = 2 * WIDE_BLOCK
+    q, k, v, kn, vn, logits = _wide(11, 4, 2, 4, t, 256, 128, dtype)
+    lens = jnp.asarray([1, 200, 300, t], jnp.int32)
+    starts = jnp.maximum(lens - 128, 0)
+    o, k2, v2 = decode_attention(q, k, v, kn, vn, lens - 1, lens - 1, lens,
+                                 starts=starts)
+    assert o.shape == (4, 8, 1, 128)
+    want, k3, v3 = ragged_decode_attention(q, k, v, lens, kn, vn, lens - 1,
+                                           interpret=True, starts=starts)
+    assert jnp.array_equal(k2, k3) and jnp.array_equal(v2, v3)
+    np.testing.assert_allclose(np.asarray(o, np.float32),
+                               np.asarray(want, np.float32), atol=tol, rtol=tol)
+    with pytest.raises(ValueError, match="a sink"):
+        decode_attention(q, k, v, kn, vn, lens - 1, lens - 1, lens,
+                         starts=starts, sink=logits)

@@ -54,13 +54,19 @@ SMALL = {
                   n_kv_heads=1, head_dim=16, d_ff=128, max_seq=128,
                   attn_layer_period=2, attn_layer_offset=1, mamba_d_state=16,
                   mamba_d_conv=4, mamba_dt_rank=4, mamba_expand=2),
+    "mimo_v2": dict(vocab_size=64, d_model=64, n_layers=2, n_heads=4,
+                    n_kv_heads=1, head_dim=24, d_ff=128, max_seq=128,
+                    layer_types=("full_attention", "sliding_attention"),
+                    v_head_width=16, rotary_dim=8, swa_window=16,
+                    swa_n_kv_heads=2, n_dense_layers=1, n_routed_experts=4,
+                    experts_per_tok=2, expert_width=32),
 }
 # a field that is one family's own, for every OTHER block an unknown keyword
 OWN_FIELD = {
     "afmoe": "sliding_window", "qwen3_next": "linear_conv_kernel",
     "joyai_llm_flash": "kv_lora_rank", "evabyte": "window_size",
     "sdar_moe": "block_length", "lfm2_moe": "conv_kernel",
-    "jamba": "mamba_d_state",
+    "jamba": "mamba_d_state", "mimo_v2": "swa_window",
 }
 # the optional paths and the ``serving_refuses`` feature that guards each
 GUARDED = {
@@ -74,7 +80,7 @@ LLAMA_OWN = ("decode_step_ragged_list", "backbone", "loss_fn", "_decode",
              "decode_step", "decode_step_ragged", "generate")
 
 
-def test_the_registry_names_the_eight_blocks():
+def test_the_registry_names_the_nine_blocks():
     assert sorted(SMALL) == sorted(families.FAMILIES)
 
 

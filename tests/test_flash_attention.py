@@ -284,3 +284,39 @@ def test_meshed_prefill_lowers_for_tpu(monkeypatch):
     mesh = make_mesh({"data": 1, "model": 2})
     exported = _export_prefill_for_tpu(monkeypatch, mesh=mesh)
     assert "tpu_custom_call" in exported.mlir_module()
+
+
+# -- a sink in the softmax, and a window smaller than every tile (ISSUE 57) -----
+
+@pytest.mark.parametrize("t,window,dh,dv", [
+    (512, 128, 192, 128),     # the band inside one key tile of 512
+    (768, 128, 192, 128),     # 256 divides, 512 does not: a key tile of 256
+    (512, None, 192, 128),    # a sink under the plain causal mask
+    (256, 16, 64, 64),
+])
+def test_kernel_with_a_sink_matches_the_concatenated_logit(t, window, dh, dv):
+    """The sink joins every row's softmax after its last tile and has no
+    value row: the kernel (interpreted, at the rule's tile) against the
+    masked dots with the logit concatenated; without it the rows differ."""
+    q, k, v = _qkv(5, 1, 4, t, t, dh, dv)
+    sink = 3.0 + jax.random.normal(jax.random.PRNGKey(9), (4,), jnp.float32)
+    block_q, block_k = _tile(t, t, window)
+    got = flash_attention(q, k, v, block_q=block_q, block_k=block_k,
+                          window=window, sink=sink, interpret=True,
+                          name="swa_prefill_attention")
+    want = _xla_attention(q, k, v, True, None, window, None, sink)
+    assert got.shape == (1, 4, t, dv)
+    assert float(jnp.abs(got - want).max()) < 2e-5
+    bare = flash_attention(q, k, v, block_q=block_q, block_k=block_k,
+                           window=window, interpret=True)
+    assert float(jnp.abs(got - bare).max()) > 1e-2
+
+
+def test_dispatcher_takes_a_sink_off_tpu_and_refuses_it_under_a_mesh():
+    q, k, v = _qkv(6, 1, 2, 128, 128, 64)
+    sink = jnp.asarray([2.0, -1.0], jnp.float32)
+    got = attention(q, k, v, window=32, sink=sink)
+    want = _xla_attention(q, k, v, True, None, 32, None, sink)
+    assert float(jnp.abs(got - want).max()) < 1e-6
+    with pytest.raises(ValueError, match="a sink takes no mesh"):
+        attention(q, k, v, sink=sink, mesh=object())

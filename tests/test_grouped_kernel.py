@@ -110,6 +110,57 @@ def test_the_kernel_is_the_ragged_dots_and_the_swiglu(cell, pattern):
     assert offsets.tolist() == [0] + np.cumsum(np.asarray(sizes)).tolist()
 
 
+@pytest.mark.parametrize("pattern", [
+    "uniform", "experts_with_no_rows", "a_group_ends_on_a_tiles_edge",
+    "trailing_pairs_of_no_expert"])
+def test_an_expert_too_wide_for_the_kernel_goes_through_in_slices(
+        pattern, monkeypatch):
+    """An expert whose whole width is past the kernel's VMEM: one call a
+    slice of the width, each copying its columns of ``W1`` / ``W3`` and its
+    rows of ``W2`` from where they lie, the slices' float32 shares summed
+    and rounded once: the dots rounded alike, to an accumulation's order."""
+    rng, mk, (w1, w3, w2) = _case("trinity_all_of_16_at_256")
+    assert experts.width_slices(w1.shape, w1.dtype) == 1
+    # the three [128, 256] matrices two slots apiece are 393 KB; halves fit
+    monkeypatch.setattr(experts, "GROUPED_VMEM_BYTES", 700 << 10)
+    assert experts.width_slices(w1.shape, w1.dtype) == 2
+    sizes = jnp.asarray(_sizes(pattern, w1.shape[0]), jnp.int32)
+    landed = int(sizes.sum())
+    xs = mk(PAIRS, 128).astype(jnp.bfloat16)
+    by_pair = jnp.asarray(rng.random(PAIRS), jnp.float32)
+    walk = experts.tile_visits(sizes, PAIRS, TM)
+    got = experts._swiglu(xs, walk, by_pair, w1, w3, w2, TM, True)
+    assert got.shape == xs.shape and got.dtype == xs.dtype
+    dot = lambda a, w: lax.ragged_dot(  # noqa: E731
+        a, w, sizes, preferred_element_type=jnp.float32)
+    h = (jax.nn.silu(dot(xs, w1)) * dot(xs, w3)).astype(xs.dtype)
+    rounded = (dot(h, w2) * by_pair[:, None]).astype(xs.dtype)
+    got, rounded = (np.asarray(a.astype(jnp.float32))[:landed]
+                    for a in (got, rounded))
+    scale = float(np.abs(rounded).max())
+    assert scale > 0.1
+    assert np.abs(got - rounded).max() <= 2.0 ** -8 * scale
+    # one slice alone is not the product: both were summed
+    half = experts._swiglu_call(xs, walk, by_pair, w1, w3, w2, TM, True,
+                                (0, 128))
+    assert half.dtype == jnp.float32
+    assert np.abs(np.asarray(half)[:landed] - rounded).max() > 0.05 * scale
+
+
+@pytest.mark.parametrize("d,width,want", [
+    # the benchmark's experts: whole
+    (2048, 512, 1), (2048, 768, 1), (2048, 1024, 1), (2048, 1536, 1),
+    # compiled for a described v5e at D = 4096: 1152 fits whole, 1280 does
+    # not, 2048 goes in two slices of 1024
+    (4096, 1152, 1), (4096, 1280, 2), (4096, 2048, 2),
+    # whole registers of 128 columns, or none
+    (65536, 128, 0)])
+def test_the_slices_of_an_experts_width(d, width, want):
+    assert experts.width_slices((16, d, width), jnp.bfloat16) == want
+    assert experts.groups_in_kernel(
+        "tpu", (1920, d), (16, d, width), jnp.bfloat16) is (want > 0)
+
+
 def _kernel_interpreted(xs, sizes, real, by_pair, w1, w3, w2, kernel=True):
     """``_pairs_ffn`` as a TPU lowers it, the kernel interpreted."""
     tm = experts.row_tile(xs.shape[0])

@@ -6,7 +6,8 @@ that the run that gates every PR guards them too.
 
 Two of them pinned the manifest as PR 53 left it: ten cells, and the five
 entries the LAST of ``per_layer``. A cell or a metric appended since (PR 55:
-an eleventh cell in all four lists, four entries after them) is what
+an eleventh cell in all four lists, four entries after them; PR 57: a
+twelfth cell, two entries) is what
 ``BENCHMARK.json`` is for, and a PR that may only add to the benchmark
 cannot edit that file: the two are held here in the form that outlives an
 append (every cell the manifest has, in its order; the five entries
