@@ -244,27 +244,37 @@ def rehearsal(cfg: dict) -> dict:
 IDLE_EVERY = 8      # lanes 5, 13, 21, ... idle among the live ones
 INSERT_ROWS = 8     # rows of one ``insert_many``
 BLOCK = 128         # what the comparison's lengths are rounded to
-READ_BLOCK = 512    # the ragged read's block (``ops.latent_attention.
-#                     LATENT_BLOCK``): lengths lie on both its sides, and
-#                     ``mla_positions_read`` counts whole ones
 PROMPT_LEN = 5888   # where the cell's longest contexts end
 SHORT_BUCKET = 1792  # the prefill bucket the traffic uses most
 
 
-def lane_lengths(lanes: int, prompt_len: int, decode_steps: int) -> dict:
+def read_block() -> int:
+    """The positions the ragged read streams a lane's length in whole
+    multiples of, ASKED of the program when the comparison runs (a later
+    kernel may rightly stream fewer): ``ops.latent_attention.LATENT_BLOCK``
+    (512). The lengths lie on both its sides, and ``mla_positions_read``
+    must count whole ones."""
+    from seldon_core_tpu.ops import latent_attention
+
+    return int(latent_attention.LATENT_BLOCK)
+
+
+def lane_lengths(lanes: int, prompt_len: int, decode_steps: int,
+                 block: int) -> dict:
     """``{lane: tokens it holds before its first step}`` for the live
     lanes: spread evenly from ``prompt_len // 23`` (256 of 5888) to
     ``prompt_len`` (the lane that goes on where the whole prompt ended), no
     two lanes' steps at one position, and three of them moved to an edge
-    of the read's block: a multiple of ``READ_BLOCK`` (the first, the
-    middle one, the last), one under it and one over it, each the lane
-    that lay nearest."""
+    of the read's ``block`` (``read_block()``): a multiple of it (the
+    first, the middle one, the last), one under it and one over it, each
+    the lane that lay nearest."""
     import numpy as np
 
     live = [j for j in range(lanes) if j % IDLE_EVERY != 5]
     lens = np.linspace(max(4, prompt_len // 23), prompt_len,
                        len(live)).round().astype(int)
-    edges = READ_BLOCK * np.arange(1, prompt_len // READ_BLOCK + 1)
+    # the edges inside the prompt (one at its very end has no far side)
+    edges = block * np.arange(1, (prompt_len - 1) // block + 1)
     if len(edges) and len(live) >= 6 \
             and np.diff(lens).min() >= 3 * decode_steps:
         for edge, off in zip(edges[[0, len(edges) // 2, -1]], (0, -1, 1)):
@@ -400,7 +410,8 @@ def serve(model, params, seed: int, prompt_len: int = 0,
         raise ValueError(f"{total} positions in a cache of {cache_len}")
     rng = np.random.default_rng(seed % (2**63))
     tokens = rng.integers(0, cfg.vocab_size, size=total, dtype=np.int64)
-    start = lane_lengths(lanes, prompt_len, decode_steps)
+    block = read_block()
+    start = lane_lengths(lanes, prompt_len, decode_steps, block)
     live = np.array([j in start for j in range(lanes)])
     at = np.array([start.get(j, 0) for j in range(lanes)])
     n_layers = cfg.n_layers
@@ -546,8 +557,7 @@ def serve(model, params, seed: int, prompt_len: int = 0,
         distinct = sum(len(np.unique(h)) for h in here)
         pairs = sum(r[live].size for r in routed)
         landed = sum(h.size for h in here)
-        n_read = int((-(-(lens_live + i + 1) // READ_BLOCK)
-                      * READ_BLOCK).sum())
+        n_read = int((-(-(lens_live + i + 1) // block) * block).sum())
         counters_hold &= counts.tolist() == [
             distinct, pairs, n_routed_layers, landed, n_read * n_layers,
             int((lens_live + i + 1).sum()) * n_layers,
@@ -570,8 +580,9 @@ def serve(model, params, seed: int, prompt_len: int = 0,
         picks=picks, slab_rows=slab_rows, step_rows=step_rows, new_at=new_at,
         short=short, short_logits=short_logits, short_picks=short_picks,
         prompt_len=prompt_len, lanes=lanes, cache_len=cache_len,
-        lanes_live=int(live.sum()), borrowed=borrowed, touched=touched,
-        rows=rows, rows_held=rows_held, decode_steps=decode_steps,
+        read_block=block, lanes_live=int(live.sum()), borrowed=borrowed,
+        touched=touched, rows=rows, rows_held=rows_held,
+        decode_steps=decode_steps,
         counters_hold=bool(counters_hold), agree=float(np.mean(agree)),
         burst_margin=burst_margin, burst_rows_ratio=burst_rows_ratio,
         burst_counters_hold=burst_counters_hold, inserted=inserted,
@@ -648,7 +659,7 @@ def judge(model, served: dict, params, variant: str = "") -> dict:
         "logit_std": scale, "positions": len(positions),
         "prompt_len": prompt_len, "lanes_live": s["lanes_live"],
         "lanes": s["lanes"], "cache_len": s["cache_len"],
-        "borrowed": s["borrowed"],
+        "read_block": s["read_block"], "borrowed": s["borrowed"],
         "experts_touched_a_layer_step": per_layer_step,
         "rows_per_touched_expert": rows_held / max(1, touched),
         "held_rows_share": rows_held / max(1, rows),

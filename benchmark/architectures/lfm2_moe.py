@@ -32,6 +32,9 @@ from __future__ import annotations
 # one copy of the margin among the architecture modules (numpy only, as
 # this module jax-free at import)
 from benchmark.architectures.afmoe import picks_margin
+# a kernel's device seconds from the run's own events, beyond the ten ops
+# the reduction names: one copy among the modules
+from benchmark.architectures.jamba import kernel_seconds  # noqa: F401
 # the engine's batcher found by its parameters, and the process's peak: one
 # copy among the modules whose comparison borrows the serving cache (a
 # second cache of 64 lanes x 16,384 positions would not fit beside the first)

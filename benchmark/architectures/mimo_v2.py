@@ -314,6 +314,101 @@ def rehearsal(cfg: dict) -> dict:
     }
 
 
+# -- the rows a cache holds, asked of the family ----------------------------------
+
+# The comparison reads what a cache HOLDS, never how its arrays are laid
+# out: a family whose comparison borrows the serving cache offers
+#
+#   ``model.cached_rows(cache, kind, layer, lanes, positions)``: of layer
+#   ``layer`` of the ``kind`` (``"full"`` | ``"window"``, counted within the
+#   kind), the K and V rows of ``lanes`` [n] at ``positions`` [n, m] (a
+#   window layer's: ring slots) as ``([n, m, KV, head_dim], [n, m, KV,
+#   v_head_width])``, the stored bits in any float dtype, padding dropped;
+#   a gather of the rows asked, never a layer materialised (the comparison
+#   runs beside a 14.3 GB peak). ``cache``: the serving cache, or a
+#   prefill's slab (the same leaves stacked over a kind's layers, a row a
+#   prompt);
+#   ``model.read_block(cache_len)``: the block of positions the full
+#   layers' ragged walk streams over a cache that long (``kv_rows_read``
+#   counts a lane's length rounded up to it).
+#
+# The window layers' leaves, which the comparison copies, puts back and
+# holds bit for bit in the idle lanes, are WHATEVER arrays of the cache hold
+# ``swa_window`` slots a lane and not the cache's positions, told by what
+# they hold and not by a name (``window_leaf_names``, which refuses a cache
+# it cannot tell apart), each led by the lanes (as the batcher's own insert
+# takes them).
+#
+# ``models/mimo_v2.py`` offers neither yet (a ``benchmark`` PR touches no
+# program file: PERF.md section 7, PR 58), so the two functions below stand
+# in for it and are the ONLY lines of this module that know today's layout
+# (``{"k", "v"}`` [lanes, KV, positions, row] a full layer, ``{"wk", "wv"}``
+# a window layer's ring, a key row of 192 held 256 wide): they go when the
+# family answers itself.
+
+def _rows_as_laid_out_today(model):
+    import jax
+
+    dk = model.cfg.head_dim
+
+    @jax.jit
+    def gather(k, v, lanes, positions):
+        at = lanes[:, None]
+        return k[at, :, positions][..., :dk], v[at, :, positions]
+
+    def cached_rows(cache, kind, layer, lanes, positions):
+        k, v = ("wk", "wv") if kind == "window" else ("k", "v")
+        return gather(cache[k][layer], cache[v][layer], lanes, positions)
+
+    return cached_rows
+
+
+def _read_block_as_laid_out_today(cache, cache_len: int) -> int:
+    from seldon_core_tpu.ops.decode_attention import walk_block
+
+    k, v = cache["k"][0], cache["v"][0]
+    return walk_block(k.shape[1], k.shape[3], k.dtype, cache_len, v.shape[3])
+
+
+def window_leaf_names(cache, cfg, lanes: int, cache_len: int) -> tuple:
+    """The cache's keys that hold the window layers' leaves: every array of
+    the cache is led by the lanes; one that holds ``cache_len`` positions a
+    lane is a full layer's, any other holds ``swa_window`` slots a lane and
+    is a ring's. A cache that does not divide so (a leaf of neither kind,
+    both kinds under one key, or rings too small for the rows the window
+    layers hold) is REFUSED: the comparison would restart and hold the
+    wrong arrays, and nothing else would say so."""
+    import jax
+    import numpy as np
+
+    window = cfg.swa_window
+    if window == cache_len:
+        raise ValueError(f"a cache of {cache_len} positions and rings of as "
+                         f"many slots cannot be told apart")
+    names, slots = [], 0
+    for name, held in cache.items():
+        shapes = [a.shape for a in jax.tree_util.tree_leaves(held)]
+        if not shapes:
+            continue
+        full = [cache_len in shape[1:] for shape in shapes]
+        ring = [window in shape[1:] and not f for shape, f in zip(shapes, full)]
+        if any(shape[0] != lanes for shape in shapes) or not (
+                all(full) or all(ring)):
+            raise ValueError(
+                f"cache[{name!r}] holds {shapes}: not {lanes} lanes of "
+                f"{cache_len} positions each, nor of {window} slots each")
+        if all(ring):
+            names.append(name)
+            slots += sum(int(np.prod(shape)) for shape in shapes)
+    n_window = sum(t == SLIDING for t in cfg.layer_types)
+    need = lanes * n_window * window * cfg.swa_n_kv_heads * (
+        cfg.head_dim + cfg.v_head_width)
+    if slots < need:
+        raise ValueError(f"the rings {names} hold {slots} numbers, the window "
+                         f"layers' rows are {need}")
+    return tuple(names)
+
+
 # -- the served model against the plain reference ----------------------------------
 
 def compare_served(model, params, seed: int, prompt_len: int = 0,
@@ -396,8 +491,6 @@ def serve(model, params, seed: int, prompt_len: int = 0,
     import jax.numpy as jnp
     import numpy as np
 
-    from seldon_core_tpu.ops.decode_attention import walk_block
-
     t0 = time.monotonic()
     peak_before = _memory_peak()
     cfg = model.cfg
@@ -409,7 +502,7 @@ def serve(model, params, seed: int, prompt_len: int = 0,
                          "parameters, and none was given")
     lanes, cache_len = batcher.slots, batcher.max_seq
     decode_steps = decode_steps or batcher._k
-    window, dk = cfg.swa_window, cfg.head_dim
+    window = cfg.swa_window
     warmed = batcher._warm_args or {}
     asked = tuple(sorted({n for n in warmed.get("prompt_lens", ())
                           if n <= cache_len}))
@@ -443,10 +536,18 @@ def serve(model, params, seed: int, prompt_len: int = 0,
     n_routed_layers = len(kinds) - cfg.n_dense_layers
     lo, n_held = cfg.experts_held or (0, cfg.n_routed_experts)
 
-    def rows_of(a, width):
-        """Cache rows [..., KV, T, row] -> [..., T, KV, width] float32: the
-        key row's zeros past the key dropped."""
-        return np.moveaxis(np.asarray(a[..., :width], np.float32), -3, -2)
+    ask_rows = getattr(model, "cached_rows", None) or _rows_as_laid_out_today(
+        model)
+
+    def rows(cache, kind, layer, lanes, positions):
+        """``cached_rows`` (the section above) of lanes [n] at positions [m]
+        (every lane's) or [n, m], as float32 numpy: ([n, m, KV, Dk], [n, m,
+        KV, Dv])."""
+        lanes = np.asarray(lanes, np.int32).reshape(-1)
+        at = np.broadcast_to(np.asarray(positions, np.int32),
+                             (len(lanes), np.shape(positions)[-1]))
+        k, v = ask_rows(cache, kind, layer, jnp.asarray(lanes), jnp.asarray(at))
+        return np.asarray(k, np.float32), np.asarray(v, np.float32)
 
     top = batcher._bucket(prompt_len)
 
@@ -469,11 +570,12 @@ def serve(model, params, seed: int, prompt_len: int = 0,
     picks = [np.concatenate([np.asarray(r[0, :prompt_len]),
                              np.zeros_like(r[0, :decode_steps])])
              for r in routed]
-    slab_kv = [(rows_of(slab["k"][l, 0, :, :prompt_len], dk),
-                rows_of(slab["v"][l, 0, :, :prompt_len], cfg.v_head_width))
+    # the one prompt's rows, and its rings as its last token left them
+    slots = np.arange(min(top, window))
+    slab_kv = [tuple(a[0] for a in rows(slab, "full", l, [0],
+                                        np.arange(prompt_len)))
                for l in range(n_full)]
-    slab_ring = [(rows_of(slab["wk"][l, 0], dk),
-                  rows_of(slab["wv"][l, 0], cfg.v_head_width))
+    slab_ring = [tuple(a[0] for a in rows(slab, "window", l, [0], slots))
                  for l in range(n_window)]
     del logits, slab, routed
 
@@ -494,7 +596,7 @@ def serve(model, params, seed: int, prompt_len: int = 0,
     keys = jnp.zeros((lanes, 2), jnp.uint32)
     no_counts = batcher._no_prefill_counts
     sampled = {}
-    ring_names = ("wk", "wv")
+    ring_names = window_leaf_names(cache, cfg, lanes, cache_len)
     try:
         for bucket, group in calls:
             m = len(group)
@@ -526,39 +628,41 @@ def serve(model, params, seed: int, prompt_len: int = 0,
         shown = sorted({j for j in start if start[j] in asked}
                        | {max(start, key=start.get)})
         lane_rows = {
-            j: [(rows_of(cache["k"][l][j, :, :start[j]], dk),
-                 rows_of(cache["v"][l][j, :, :start[j]], cfg.v_head_width))
+            j: [tuple(a[0] for a in rows(cache, "full", l, [j],
+                                         np.arange(start[j])))
                 for l in range(n_full)] for j in shown}
+
+        def ring_leaves(cache):
+            """A copy of whatever the cache holds for the window layers."""
+            return jax.tree_util.tree_map(
+                jnp.copy, {n: cache[n] for n in ring_names})
+
         # the rings as the inserts left them, every lane's: each run below
         # starts from these (0.25 GB in all)
-        rings0 = {n: [jnp.copy(a) for a in cache[n]] for n in ring_names}
-        live_ix = jnp.asarray(np.flatnonzero(live), jnp.int32)
+        rings0 = ring_leaves(cache)
+        live_ix = np.flatnonzero(live)
 
         def rings_of(cache):
             """The live lanes' rings per window layer, ([live, W, KV, Dk],
-            [live, W, KV, Dv]) float32, and the idle lanes' as they are."""
-            out = [(rows_of(k[live_ix], dk),
-                    rows_of(v[live_ix], cfg.v_head_width))
-                   for k, v in zip(cache["wk"], cache["wv"])]
-            idle = [np.asarray(a)[~live] for n in ring_names for a in cache[n]]
+            [live, W, KV, Dv]) float32, and the idle lanes' leaves as they
+            are."""
+            out = [rows(cache, "window", l, live_ix, np.arange(window))
+                   for l in range(n_window)]
+            idle = [np.asarray(a)[~live] for a in jax.tree_util.tree_leaves(
+                {n: cache[n] for n in ring_names})]
             return out, idle
 
         rings_at_insert, idle_rings = rings_of(cache)
         new_at = at[live, None] + np.arange(decode_steps)[None]   # [live, steps]
-        gather = jax.jit(lambda cache, j, p: (
-            [a[j[:, None], :, p] for a in cache["k"]],
-            [a[j[:, None], :, p] for a in cache["v"]]))
 
         def written(cache):
             """The K and V rows at each live lane's new positions, per full
             layer ([live, steps, KV, Dk], [live, steps, KV, Dv])."""
-            ks, vs = gather(cache, live_ix, jnp.asarray(new_at, jnp.int32))
-            return [(np.asarray(k[..., :dk], np.float32),
-                     np.asarray(v, np.float32)) for k, v in zip(ks, vs)]
+            return [rows(cache, "full", l, live_ix, new_at)
+                    for l in range(n_full)]
 
         def restarted(cache):
-            return dict(cache, **{n: [jnp.copy(a) for a in rings0[n]]
-                                  for n in ring_names})
+            return dict(cache, **ring_leaves(rings0))
 
         # (1) the batcher's burst: the timed executable where k is its _k
         active = live.copy()
@@ -627,10 +731,9 @@ def serve(model, params, seed: int, prompt_len: int = 0,
         step_rings, _idle = rings_of(cache)
     finally:
         batcher._cache = cache      # handed back, the comparison's rows in it
-    k0, v0 = cache["k"][0], cache["v"][0]
-    read_block = walk_block(k0.shape[1], k0.shape[3], k0.dtype, cache_len,
-                            v0.shape[3])
-    del cache, k0, v0
+    read_block = (model.read_block(cache_len) if hasattr(model, "read_block")
+                  else _read_block_as_laid_out_today(cache, cache_len))
+    del cache
     served, positions = [first], [prompt_len - 1]
     counters_hold = True
     touched = rows = rows_held = 0

@@ -2,10 +2,10 @@
 their live lanes' lengths held: the program's ``block_rows_read /
 block_rows_live`` over the capture. The kernel copies whole blocks of its
 walk, so a lane's length is rounded up to the block (the program's own
-rule, by the query rows a KV head brings to a block: a larger block hides
-the latency of the loop's chain and rounds further): 100% is a read with no
-rounding. None where the program has no such counters or no lane was
-live."""
+rule, ``ops.decode_attention.walk_block``, by the bytes a block of 128
+keys copies: where that copy is too short to cover the loop's chain the
+block is wider, and rounds further): 100% is a read with no rounding. None
+where the program has no such counters or no lane was live."""
 from benchmark import capture
 
 

@@ -6,13 +6,17 @@ the architecture module's ``decode_attn_bytes`` sizes them) over the chip's
 HBM rate, as a share of the ``ragged_decode_attention`` kernel's device
 time inside ``jit_fused_burst`` over the same capture. The kernel copies
 each of those positions once and computes on a block while the next one
-streams, so its time cannot be under the bytes' at the peak rate. None
-without the counter (a program whose step counts no such rows, or takes
-the dots), or where the kernel is not among the ops the trace's reduction
-names."""
+streams, so its time cannot be under the bytes' at the peak rate. The
+seconds are every event of the kernel in the burst, from the run's own
+events (the architecture module's ``kernel_seconds``, as
+``ssm_state_hbm_roofline`` and ``swa_ring_hbm_roofline`` read theirs): a
+kernel made faster may leave the ten ops the reduction names and must not
+fall silent for it. None without the counter (a program whose step counts no such rows, or takes
+the dots), or where the trace names no such kernel."""
 from benchmark import capture
 
-KERNEL = "jit_fused_burst:ragged_decode_attention"
+BURST = ("jit_fused_burst",)
+KERNEL = "ragged_decode_attention"
 
 
 def read(run):
@@ -20,8 +24,9 @@ def read(run):
     if not hasattr(arch, "decode_attn_bytes"):
         return None
     need = arch.decode_attn_bytes(run["config"], capture.counters(run))
-    seconds = sum(s for name, s in (run["trace"] or {}).get("device_ops", [])
-                  if name.startswith(KERNEL))
-    if not need or seconds <= 0:
+    if not need:
+        return None
+    seconds = arch.kernel_seconds(run, BURST, KERNEL)
+    if not seconds:
         return None
     return 100.0 * need / run["peaks"]["hbm_bytes_per_s"] / seconds

@@ -294,6 +294,64 @@ def test_the_kernels_seconds_come_from_the_runs_events_where_it_left_them(
                                "selective_scan_step") == 0.75
 
 
+def test_the_decode_kernels_share_does_not_fall_silent_below_the_ten_ops(
+        man, cfg, arch, tmp_path, monkeypatch):
+    """On the recorded chip trace (``trace_fixture.json.gz``: two bursts of
+    a dense cell), with the ragged decode kernel's events put into its
+    bursts: where the kernel is among the ten ops the reduction names, the
+    share from the run's events is the ten ops' own; made faster, under
+    the tenth op, it is the eleventh, the ten name it no more, and the
+    share still reads, higher by what the kernel gained."""
+    import copy
+
+    from benchmark import trace
+    from benchmark.tests.test_benchmark import FIXTURE
+
+    read = manifest.layer_reader(ROOT, man, "decode_attn_hbm_roofline")
+    with gzip.open(FIXTURE, "rt") as f:
+        recorded = json.load(f)["events"]
+    tenth = trace.reduce(recorded)["device_ops"][-1][1]
+    bursts = [m for m in recorded["devices"][0]["modules"]
+              if trace.executable_of(m[0]) == "jit_fused_burst"]
+    kernel = ("%ragged_decode_attention.7 = bf16[192,1,20,128]{3,2,1,0} "
+              "custom-call(%q, %k, %v), custom_call_target=\"tpu_custom_call\"")
+    counters = {"kv_rows_read": 2 * 16 * 190 * 1200}
+    need = arch.decode_attn_bytes(cfg, counters)
+    here = tmp_path / "benchmark"
+    monkeypatch.setattr(arch, "__file__", str(here / "architectures" / "jamba.py"))
+
+    def run_with(seconds, cell):
+        """The recorded events with the kernel taking ``seconds`` in all,
+        as a run of ``cell`` leaves them and reduces them."""
+        events = copy.deepcopy(recorded)
+        events["devices"][0]["ops"] += [
+            [kernel, start + 1e-4, seconds / len(bursts), ""]
+            for _name, start, _d in bursts]
+        run_dir = here / "_runs" / cell / "seed1-trace2-0"
+        run_dir.mkdir(parents=True)
+        with gzip.open(run_dir / "trace_events.json.gz", "wt") as f:
+            json.dump(events, f)
+        return dict(_run(cfg, arch, counters), cell={"name": cell},
+                    trace=trace.reduce(events))
+
+    def share(seconds):
+        return 100.0 * need / 819e9 / seconds
+
+    label = "jit_fused_burst:ragged_decode_attention_bf16_192_1_20_128"
+    among = run_with(3 * tenth, "among-the-ten")
+    assert label in dict(among["trace"]["device_ops"])
+    assert read(among) == pytest.approx(share(3 * tenth), rel=1e-9)
+    eleventh = run_with(0.5 * tenth, "the-eleventh")
+    assert label not in dict(eleventh["trace"]["device_ops"])
+    assert len(eleventh["trace"]["device_ops"]) == trace.TOP
+    assert read(eleventh) == pytest.approx(share(0.5 * tenth), rel=1e-9)
+    assert read(eleventh) == pytest.approx(6 * read(among))
+    # no such kernel in the burst, or no counter: silent either way
+    assert read(dict(eleventh, cell={"name": "no-such-cell"})) is None
+    assert read(dict(eleventh, trace_counters=({}, {"program": {
+        "counters": {}}}))) is None
+
+
 @pytest.fixture(scope="module")
 def tiny(cfg, arch):
     small = dict(cfg, **arch.rehearsal(cfg), name="tiny")

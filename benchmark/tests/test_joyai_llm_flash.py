@@ -293,15 +293,53 @@ def test_the_comparisons_lanes_are_the_cells(cfg, arch):
     going on where the whole prompt ended, three at a block's edge, and no
     two stepping at one position."""
     assert cfg["server"]["slots"] == 64
-    start = arch.lane_lengths(64, arch.PROMPT_LEN, 4)
-    lens = sorted(start.values())
-    assert len(start) == 56 and set(range(64)) - set(start) == set(range(5, 64, 8))
-    assert lens[0] == 256 and lens[-1] == 5888
-    assert {n % arch.READ_BLOCK for n in lens} >= {0, 1, arch.READ_BLOCK - 1}
-    assert arch.READ_BLOCK == 512    # ops.latent_attention.LATENT_BLOCK
-    assert min(b - a for a, b in zip(lens, lens[1:])) >= 4
+    from seldon_core_tpu.ops.latent_attention import LATENT_BLOCK
+
+    # asked of the program, not held here: 512 today
+    assert arch.read_block() == LATENT_BLOCK == 512
+    for block in (512, 256):
+        start = arch.lane_lengths(64, arch.PROMPT_LEN, 4, block)
+        lens = sorted(start.values())
+        assert len(start) == 56 and set(range(64)) - set(start) == set(
+            range(5, 64, 8))
+        assert lens[0] == 256 and lens[-1] == 5888
+        assert {n % block for n in lens} >= {0, 1, block - 1}
+        assert min(b - a for a, b in zip(lens, lens[1:])) >= 4
     with pytest.raises(ValueError):
-        arch.lane_lengths(64, 100, 4)
+        arch.lane_lengths(64, 100, 4, 512)
+
+
+def test_the_read_block_is_asked_of_the_program_when_the_comparison_runs(
+        arch, tiny, monkeypatch):
+    """A program whose latent read streams blocks of 256 (``LATENT_BLOCK``
+    patched; on a CPU the dots run and the step counts what the kernel
+    would stream) is held to ``256 x ceil(len / 256)``, lengths on both
+    sides of ITS block, and passes; the equality stays in: a comparison
+    that asked 512 of the same program does not hold."""
+    from seldon_core_tpu.ops import latent_attention
+    from seldon_core_tpu.serving.continuous import ContinuousBatcher
+
+    model, params = tiny
+    monkeypatch.setattr(latent_attention, "LATENT_BLOCK", 256)
+    assert arch.read_block() == 256
+    served = {}
+    for asked in (256, 512):
+        if asked != 256:
+            monkeypatch.setattr(arch, "read_block", lambda: asked)
+        batcher = ContinuousBatcher(model, params, slots=32, max_seq=768)
+        try:
+            served[asked] = arch.serve(model, params, 2**31 + 3,
+                                       prompt_len=640, decode_steps=3,
+                                       batcher=batcher)
+        finally:
+            batcher.close()
+    mine = served[256]
+    assert mine["read_block"] == 256 and mine["counters_hold"]
+    assert mine["burst_counters_hold"]
+    out = arch.judge(model, mine, params)
+    assert out["ok"] and out["read_block"] == 256, out
+    assert not served[512]["counters_hold"]
+    assert not arch.judge(model, served[512], params)["ok"]
 
 
 @pytest.mark.parametrize("variant", [

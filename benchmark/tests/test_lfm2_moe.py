@@ -211,6 +211,7 @@ def test_costs_against_the_configs_own_arithmetic(cfg, arch):
 
 def _run(cfg, arch, counters, device_ops=(), modules=None):
     return {"config": cfg, "architecture": arch,
+            "cell": {"name": "no-such-cell"},
             "peaks": {"hbm_bytes_per_s": 819e9},
             "trace": {"device_ops": [list(op) for op in device_ops],
                       "modules": modules or {}},

@@ -349,6 +349,166 @@ def test_the_served_model_agrees_with_the_reference_at_a_tiny_size(
     assert 0.07 < out["held_rows_share"] < 0.2
 
 
+def _split_keys_family(arch, lose_the_narrow_part: bool = False):
+    """The file's tiny family with ANOTHER layout of its cache: a key of
+    24 held as a 16-wide part (``k`` / ``wk``) and an 8-wide part (``kr`` /
+    ``wkr``: a third array a kind; at the published widths 128 + 64), no
+    padding stored. Its step and its prefill are the program's own between
+    a join and a split, and it answers the comparison's two questions
+    itself: ``cached_rows`` puts the parts together, ``read_block`` is the
+    walk's. ``lose_the_narrow_part``: an accessor that hands out zeros for
+    the narrow part (a layout that lost it)."""
+    import jax
+    import jax.numpy as jnp
+
+    from seldon_core_tpu.ops.decode_attention import walk_block
+
+    WIDE = 16
+    PARTS = {"k": "kr", "wk": "wkr"}
+
+    class SplitKeys(arch.SeededMimoV2LM):
+        def _split(self, tree):
+            out = dict(tree)
+            for name, rest in PARTS.items():
+                if name in tree:
+                    out[name] = jax.tree_util.tree_map(
+                        lambda a: a[..., :WIDE], tree[name])
+                    out[rest] = jax.tree_util.tree_map(
+                        lambda a: a[..., WIDE:self.cfg.head_dim], tree[name])
+            return out
+
+        def _joined(self, tree):
+            # (a tree already whole passes through: the program's own
+            # methods call each other with what they were handed)
+            out = {n: a for n, a in tree.items() if n not in PARTS.values()}
+            for name, rest in PARTS.items():
+                if rest in tree:
+                    out[name] = jax.tree_util.tree_map(
+                        lambda a, b: self._key_rows(
+                            jnp.concatenate([a, b], axis=-1)),
+                        tree[name], tree[rest])
+            return out
+
+        def init_cache(self, batch, max_seq=None):
+            return self._split(super().init_cache(batch, max_seq))
+
+        def _forward(self, params, tokens, pad_to, last_index):
+            x, slab, picked, counts = super()._forward(
+                params, tokens, pad_to, last_index)
+            return x, None if slab is None else self._split(slab), picked, counts
+
+        def _step(self, params, cache, *args, **kwargs):
+            out, new, counts, picked = super()._step(
+                params, self._joined(cache), *args, **kwargs)
+            return out, self._split(new), counts, picked
+
+        def position_layers(self, cache):
+            return [a for name in cache for a in cache[name]]
+
+        def cache_position_bytes(self, cache):
+            return super().cache_position_bytes(self._joined(cache))
+
+        def lane_cache_bytes(self, cache):
+            return super().lane_cache_bytes(self._joined(cache))
+
+        def burst_reads_ragged(self, cache, mesh=None):
+            return super().burst_reads_ragged(self._joined(cache), mesh)
+
+        # -- what the comparison asks ---------------------------------------
+
+        def cached_rows(self, cache, kind, layer, lanes, positions):
+            k, rest, v = (("wk", "wkr", "wv") if kind == "window"
+                          else ("k", "kr", "v"))
+            at = lanes[:, None]
+            wide, narrow, values = (cache[n][layer][at, :, positions]
+                                    for n in (k, rest, v))
+            if lose_the_narrow_part:
+                narrow = jnp.zeros_like(narrow)
+            return jnp.concatenate([wide, narrow], axis=-1), values
+
+        def read_block(self, cache_len):
+            cfg = self.cfg
+            return walk_block(cfg.n_kv_heads, self._key_row,
+                              jnp.dtype(cfg.dtype), cache_len, cfg.v_head_width)
+
+    return SplitKeys
+
+
+def test_the_comparison_reads_the_rows_a_cache_holds_not_its_layout(
+        cfg, arch, tiny):
+    """A family that lays its keys out otherwise (two parts, a third array
+    a kind, no padding) and answers ``cached_rows`` and ``read_block``
+    itself is compared on the rows: the same numbers as the plain family's,
+    the rings' third array restarted and held in the idle lanes with the
+    others; and an accessor that loses the narrow part of every key fails
+    the rows' limit and the rings'."""
+    from seldon_core_tpu.serving.continuous import ContinuousBatcher
+
+    model, params = tiny
+    small = dict(cfg, **arch.rehearsal(cfg), name="tiny")
+    kw = arch.model_kwargs(small, 7)
+    kw.pop("seed")
+    outs = {}
+    for name, family in (("plain", type(model)),
+                         ("split", _split_keys_family(arch)),
+                         ("lost", _split_keys_family(arch, True))):
+        other = family(**kw)
+        batcher = ContinuousBatcher(other, params, slots=32, max_seq=1024,
+                                    steps_per_poll=4)
+        try:
+            if name != "plain":
+                cache = batcher._cache
+                assert set(cache) == {"k", "kr", "v", "wk", "wkr", "wv"}
+                assert set(arch.window_leaf_names(
+                    cache, other.cfg, 32, 1024)) == {"wk", "wkr", "wv"}
+                assert [a.shape for a in cache["k"]] == [(32, 1, 1024, 16)] * 2
+                assert [a.shape for a in cache["kr"]] == [(32, 1, 1024, 8)] * 2
+                assert [a.shape for a in cache["wkr"]] == [(32, 2, 16, 8)] * 5
+            outs[name] = arch.compare_served(other, params, seed=2**31 + 3,
+                                             prompt_len=512, batcher=batcher)
+        finally:
+            batcher.close()
+    plain, split, lost = outs["plain"], outs["split"], outs["lost"]
+    assert plain["ok"] and split["ok"], split
+    for key in ("ratio", "picks_margin", "rows_ratio", "rows_ratio_prefill",
+                "rows_ratio_steps", "rows_ratio_lanes", "rings_ratio",
+                "rings_ratio_insert", "rings_ratio_steps",
+                "rings_ratio_prefill", "prefill_margin", "burst_margin",
+                "burst_rows_ratio", "burst_rings_ratio", "read_block",
+                "lanes_wrapped", "positions", "picks_agree"):
+        assert split[key] == plain[key], key
+    assert split["idle_untouched"] and split["counters_are_the_picks"]
+    assert split["burst_counters_hold"] and split["inserted"]
+    # the logits and the picks are the served path's, whatever the accessor
+    # says of the cache; the rows and the rings are what it says
+    assert not lost["ok"], lost
+    assert lost["ratio"] == plain["ratio"]
+    assert lost["rows_ratio"] > 10 * arch.ROWS_TOLERANCE
+    assert lost["rings_ratio"] > 10 * arch.RINGS_TOLERANCE
+
+
+def test_a_cache_whose_rings_cannot_be_told_is_refused(arch, tiny):
+    """The window layers' leaves are told by what they hold, lanes of
+    ``swa_window`` slots and not of the cache's positions, whatever their
+    names; a cache that does not divide so raises instead of having the
+    wrong arrays restarted and held."""
+    model, _params = tiny
+    cfg, window = model.cfg, model.cfg.swa_window
+    cache = model.init_cache(4, 64)
+    assert arch.window_leaf_names(cache, cfg, 4, 64) == ("wk", "wv")
+    renamed = {"a": cache["k"], "b": cache["v"], "rings": {
+        "keys": cache["wk"], "values": cache["wv"]}}
+    assert arch.window_leaf_names(renamed, cfg, 4, 64) == ("rings",)
+    for broken in (
+            {n: a for n, a in cache.items() if n != "wk"},     # too few for the rows
+            dict(cache, wv=[a[:2] for a in cache["wv"]]),      # not led by the lanes
+            dict(cache, wk=cache["wk"] + cache["k"][:1]),      # both kinds a key
+            dict(cache, scales=[a[..., :1, :1] for a in cache["wk"]]),  # neither
+            model.init_cache(4, window)):                      # rings as long as the cache
+        with pytest.raises(ValueError):
+            arch.window_leaf_names(broken, cfg, 4, len(broken["k"][0][0, 0]))
+
+
 @pytest.fixture(scope="module")
 def served_once(arch, tiny):
     """One serving for every wrong reference: ``serve`` is the program's
