@@ -43,10 +43,23 @@ cached position past the last ``swa_window`` costs it nothing. Keys carry
 their rotary, so the softmax over a ring needs no order, only the bound of
 the slots written. A key row of 192 occupies 256 lanes in HBM whoever lays
 it out and Mosaic slices no row of 192 (``ops.decode_attention.
-block_reads_ragged``), so key rows are HELD 256 wide, zero past the key:
-``_key_row``. The step's queries go in 256 wide too and times ``sqrt(256 /
-192)`` (the op scales by a ROW's width; the factor goes into the query in
-float32 before its one rounding: the lfm2_moe block's way).
+block_reads_ragged``), so a key is CUT at 128: its first 128 dims a row of
+its head's own, and its 64-wide rest beside another head's in one of KV / 2
+rows that follow the heads' on the same axis (``ops.decode_attention.
+pack_keys``): ``k`` [S, KV + KV / 2, max_seq, 128], a ring ``wk`` [S, KVw +
+KVw / 2, swa_window, 128], 2,560 B a position in a full layer and 5,120 a
+ring row, none of it padding. The step hands the op its queries' two parts
+and the new row so laid, and the scores are ``(q . k + q_rest . k_rest) /
+sqrt(192)`` (a dot product is a sum over dims: where the cut falls is
+free). The rule is on the shapes, a kind at a time
+(``ops.decode_attention.packed_key_rows``): a key of 128 < ``head_dim`` <
+256 whose rest divides 128, in a kind whose KV heads fill whole rows. Any
+other key is HELD in a row of the next multiple of 128, zero past the key
+(``_key_row``; the same kernel with no packed row), and the step's queries
+go in as wide and times ``sqrt(key row / head_dim)`` (the op scales by a
+ROW's width there; the factor goes into the query in float32 before its
+one rounding: the lfm2_moe block's way). ``cached_rows`` gives back the
+keys whole whichever way they are held.
 
 Serving only; what it refuses is ``serving_refuses``: everything that
 truncates, splices or copies COLUMNS of a KV cache would have to rebuild a
@@ -56,6 +69,7 @@ ring at that position, and nothing does.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Tuple
 
 import numpy as np
@@ -83,6 +97,24 @@ class MimoV2Config(LLMConfig):
     swa_n_kv_heads: int = 8       # a window layer's key / value heads
     swa_rope_theta: float = 10000.0
     value_scale: float = 0.707    # ``attention_value_scale``
+
+
+@functools.lru_cache(maxsize=None)
+def _row_gather(packed: int, head_dim: int):
+    """``MimoV2LM.cached_rows`` over one layer's K and V arrays, jitted: a
+    program a (kind's packing, key width)."""
+    import jax
+
+    from ..ops.decode_attention import unpack_keys
+
+    def gather(k, v, lanes, positions):
+        at = lanes[:, None]
+        rows = k[at, :, positions]                   # [n, m, KV + packed, .]
+        if packed:
+            rows = unpack_keys(rows[..., None, :], packed)[..., 0, :]
+        return rows[..., :head_dim], v[at, :, positions]
+
+    return jax.jit(gather)
 
 
 class MimoV2LM(DecoderFamily):
@@ -168,6 +200,13 @@ class MimoV2LM(DecoderFamily):
         self._n_routed_layers = sum(self._routed)
         self._n_held = cfg.n_routed_experts if held is None else held[1]
         self._key_row = -(-cfg.head_dim // self._LANES) * self._LANES
+        from ..ops.decode_attention import packed_key_rows
+
+        # by kind (``window``): the rows of its K arrays' KV axis that hold
+        # keys' rests (the module's note); 0: keys padded to ``_key_row``
+        self._packed = {window: packed_key_rows(cfg.head_dim,
+                                                self._kv_heads(window))
+                        for window in (False, True)}
 
     def attention_kinds(self):
         cfg = self.cfg
@@ -185,11 +224,16 @@ class MimoV2LM(DecoderFamily):
 
     # -- sizes ---------------------------------------------------------------
 
+    def _key_held(self, window: bool) -> int:
+        """What one head's key occupies of the kind's K arrays: the key's
+        own width where its rest is packed, else its padded row's."""
+        return self.cfg.head_dim if self._packed[window] else self._key_row
+
     def row_bytes(self, window: bool) -> int:
         """K and V of one position in one layer of the kind, as the cache
-        holds them (key rows ``_key_row`` wide), bfloat16."""
+        holds them (``_key_held``), bfloat16."""
         return self._kv_heads(window) * (
-            self._key_row + self.cfg.v_head_width) * 2
+            self._key_held(window) + self.cfg.v_head_width) * 2
 
     def _attention_params(self, window: bool) -> int:
         cfg = self.cfg
@@ -345,7 +389,37 @@ class MimoV2LM(DecoderFamily):
         return reads_ragged(
             next(iter(k.devices())).platform,
             (k.shape[0], self.cfg.n_heads, 1, k.shape[3]), k.shape,
-            (jnp.dtype(self.cfg.dtype), k.dtype, v.dtype), mesh, v.shape[3])
+            (jnp.dtype(self.cfg.dtype), k.dtype, v.dtype), mesh, v.shape[3],
+            k.shape[1] - v.shape[1])
+
+    # -- what a comparison that borrows the serving cache asks --------------------
+
+    def cached_rows(self, cache, kind: str, layer: int, lanes, positions):
+        """Of layer ``layer`` of the ``kind`` (``"full"`` | ``"window"``,
+        counted within the kind) the K and V rows of ``lanes`` [n] at
+        ``positions`` [n, m] (a window layer's: ring slots): ``([n, m, KV,
+        head_dim], [n, m, KV, v_head_width])``, the stored bits, the keys
+        whole however they are held (packed rests put back beside their
+        parts, padding dropped). A jitted gather of the rows asked; no
+        layer is materialised. ``cache``: the serving cache, or a prefill's
+        slab (the same leaves stacked over a kind's layers, a row a
+        prompt)."""
+        window = kind == "window"
+        k, v = ("wk", "wv") if window else ("k", "v")
+        return _row_gather(self._packed[window], self.cfg.head_dim)(
+            cache[k][layer], cache[v][layer], lanes, positions)
+
+    def read_block(self, cache_len: int) -> int:
+        """The block of positions the full layers' ragged walk streams over
+        a cache ``cache_len`` long: ``kv_rows_read`` counts a live lane's
+        length rounded up to it."""
+        import jax.numpy as jnp
+
+        from ..ops.decode_attention import walk_block
+
+        cfg = self.cfg
+        return walk_block(cfg.n_kv_heads, self._key_held(False),
+                          jnp.dtype(cfg.dtype), cache_len, cfg.v_head_width)
 
     # -- params ----------------------------------------------------------------
 
@@ -449,31 +523,52 @@ class MimoV2LM(DecoderFamily):
         contraction-minor, and handed the stored layout the TPU compiler
         relays all 21 at the top of every burst (830 MB written and read
         again, all of the burst's scratch: ``tools/burst_hlo_check.py``
-        names them; the evabyte and llama blocks' finding). (No serving
-        mesh: ``serving_refuses``.)"""
-        return {**params, "layers": [self.relaid(p, ("wq", "wk", "wv"))
-                                     for p in params["layers"]]}
+        names them; the evabyte and llama blocks' finding). Where a kind's
+        keys are held packed, ``wq`` and ``wk`` go under ``<name>_cut_t``
+        with their outputs in the order the step hands them on: every
+        head's first 128 dims, then every head's rest (heads in order, so
+        the rests of ``wk`` ARE the packed rows): the step then cuts with a
+        slice at a multiple of 128 and a reshape, no gather a step. Only
+        where the rotary lies inside the first 128, which the step rotates
+        after the cut. (No serving mesh: ``serving_refuses``.)"""
+        import jax.numpy as jnp
+
+        cut = self.cfg.rotary_dim <= self._LANES
+        layers = []
+        for p, window in zip(params["layers"], self._window):
+            p = self.relaid(p, ("wq", "wk", "wv"))
+            for name in ("wq", "wk") if cut and self._packed[window] else ():
+                w = p.pop(name + self._RELAID)
+                heads = w.reshape(-1, self.cfg.head_dim, w.shape[-1])
+                p[name + "_cut" + self._RELAID] = jnp.concatenate([
+                    heads[:, :self._LANES].reshape(-1, w.shape[-1]),
+                    heads[:, self._LANES:].reshape(-1, w.shape[-1])])
+            layers.append(p)
+        return {**params, "layers": layers}
 
     # -- the cache ---------------------------------------------------------------
 
     def init_cache(self, batch: int, max_seq=None):
-        """``{"k", "v"}``: a [batch, KV, T, key row] / [batch, KV, T, Dv]
-        pair a FULL layer; ``{"wk", "wv"}``: a [batch, KVw, swa_window, .]
-        pair a WINDOW layer, the rings; lists in the layers' order."""
+        """``{"k", "v"}``: a [batch, KV + packed, T, 128] (keys held
+        packed; else [batch, KV, T, key row]) / [batch, KV, T, Dv] pair a
+        FULL layer; ``{"wk", "wv"}``: the same with KVw heads and
+        ``swa_window`` rows a WINDOW layer, the rings; lists in the layers'
+        order."""
         import jax.numpy as jnp
 
         cfg = self.cfg
         T = max_seq or cfg.max_seq
         dt = jnp.dtype(cfg.dtype)
         cache = {}
-        for (k, v), n, rows in (
-                (("k", "v"), self._n_full, (batch, cfg.n_kv_heads, T)),
-                (("wk", "wv"), self._n_window,
-                 (batch, cfg.swa_n_kv_heads, cfg.swa_window))):
+        for (k, v), n, window, rows in (
+                (("k", "v"), self._n_full, False, T),
+                (("wk", "wv"), self._n_window, True, cfg.swa_window)):
             if n or k == "k":
-                cache[k] = [jnp.zeros((*rows, self._key_row), dt)
+                kv, packed = self._kv_heads(window), self._packed[window]
+                held = self._LANES if packed else self._key_row
+                cache[k] = [jnp.zeros((batch, kv + packed, rows, held), dt)
                             for _ in range(n)]
-                cache[v] = [jnp.zeros((*rows, cfg.v_head_width), dt)
+                cache[v] = [jnp.zeros((batch, kv, rows, cfg.v_head_width), dt)
                             for _ in range(n)]
         return cache
 
@@ -508,26 +603,67 @@ class MimoV2LM(DecoderFamily):
                 self._rotated(k, positions, theta), v)
 
     def _key_rows(self, k):
-        """Keys [..., Dk] as the cache holds them: [..., key row], zeros
-        past the key."""
+        """Keys [..., KV, T, Dk] as the cache holds them: packed where the
+        shapes meet the rule ([..., KV + packed, T, 128]), else [..., key
+        row], zeros past the key."""
+        from ..ops.decode_attention import pack_keys, packed_key_rows
+
+        packed = k.ndim > 2 and packed_key_rows(k.shape[-1], k.shape[-3])
+        return pack_keys(k, packed) if packed else self._padded(k)
+
+    def _padded(self, x):
+        """x [..., Dk] -> [..., key row], zeros past the key."""
         import jax.numpy as jnp
 
-        pad = self._key_row - k.shape[-1]
-        return k if not pad else jnp.pad(
-            k, [(0, 0)] * (k.ndim - 1) + [(0, pad)])
+        pad = self._key_row - x.shape[-1]
+        return x if not pad else jnp.pad(
+            x, [(0, 0)] * (x.ndim - 1) + [(0, pad)])
+
+    def _cut_heads(self, p, a, pos, window: bool):
+        """The step's projections of a [B, 1, D] for a kind whose keys are
+        held packed, rotated: the queries' first 128 dims [B, H, 1, 128]
+        and their rests [B, H, 1, Dk - 128], this step's key rows as the
+        cache holds them [B, KV + packed, 1, 128] (a position's rests pack
+        by a reshape) and v [B, KV, 1, Dv]. Against the burst's tree
+        (``burst_params``) a cut is a slice at a multiple of 128; against
+        the stored one the heads are rotated whole and cut after."""
+        import jax.numpy as jnp
+
+        cfg = self.cfg
+        B, lanes, Dk = a.shape[0], self._LANES, cfg.head_dim
+        theta = cfg.swa_rope_theta if window else cfg.rope_theta
+
+        def cut(name, heads):
+            if name in p or name + self._RELAID in p:
+                y = self._rotated(
+                    self.project(p, name, a).reshape(B, heads, 1, Dk),
+                    pos[:, None], theta)
+                return y[..., :lanes], y[..., lanes:]
+            y = self.project(p, name + "_cut", a)
+            part = y[..., :heads * lanes].reshape(B, heads, 1, lanes)
+            return (self._rotated(part, pos[:, None], theta),
+                    y[..., heads * lanes:].reshape(B, heads, 1, Dk - lanes))
+
+        kv = self._kv_heads(window)
+        q, q_rest = cut("wq", cfg.n_heads)
+        k, k_rest = cut("wk", kv)
+        rows = jnp.concatenate(
+            [k, k_rest.reshape(B, self._packed[window], 1, lanes)], axis=1)
+        v = self.project(p, "wv", a).reshape(B, kv, 1, cfg.v_head_width)
+        return q, q_rest, rows, v
 
     def _row_queries(self, q):
-        """The step's queries [B, H, 1, Dk] against key rows: [B, H, 1, key
-        row], zero past the key and times ``sqrt(key row / Dk)``:
+        """The step's queries [B, H, 1, Dk] against PADDED key rows: [B, H,
+        1, key row], zero past the key and times ``sqrt(key row / Dk)``:
         ``ops.decode_attention`` scales the scores by ``1 / sqrt(key row)``
         where a head's is ``1 / sqrt(Dk)``. The product is float32's,
-        rounded once."""
+        rounded once. (Packed keys need neither: ``_cut_heads``.)"""
         import jax.numpy as jnp
 
         if self._key_row == q.shape[-1]:
             return q
         wide = q.astype(jnp.float32) * np.sqrt(self._key_row / q.shape[-1])
-        return self._key_rows(wide.astype(q.dtype))
+        return self._padded(wide.astype(q.dtype))
 
     def _attention_out(self, p, o):
         """o [B, H, T, Dv] -> the attention's output [B, T, D]: the value
@@ -659,8 +795,9 @@ class MimoV2LM(DecoderFamily):
     def prefill(self, params, prompt, max_seq: int, last_index=None):
         """Logits [B, V] at each prompt's ``last_index`` and the cache's
         rows of these prompts, each leaf stacked over the layers of its
-        kind: ``k`` [Lf, B, KV, max_seq, key row], ``v`` [Lf, B, KV,
-        max_seq, Dv]; ``wk``, ``wv`` [Lw, B, KVw, min(T, swa_window), .]:
+        kind, as ``init_cache`` lays a layer's out: ``k`` [Lf, B, KV +
+        packed, max_seq, 128] (else [Lf, B, KV, max_seq, key row]), ``v`` [Lf,
+        B, KV, max_seq, Dv]; ``wk``, ``wv`` [Lw, B, ., min(T, swa_window), .]:
         each prompt's rings as its last REAL token leaves them, whatever
         the prompts were padded to."""
         return self._prefill(params, prompt, max_seq, last_index)[:2]
@@ -701,18 +838,21 @@ class MimoV2LM(DecoderFamily):
 
         dt = jnp.dtype(self.cfg.dtype)
         out = []
-        for names, lengths, n in ((("k", "v"), lens, self._n_full),
-                                  (("wk", "wv"), ring_lens, self._n_window)):
+        for names, lengths, n, window in (
+                (("k", "v"), lens, self._n_full, False),
+                (("wk", "wv"), ring_lens, self._n_window, True)):
             if not n:
                 out.append(jnp.int32(0))
                 continue
             k, v = cache[names[0]][0], cache[names[1]][0]
-            B, heads, T, width = one = k.shape
+            B, _rows, T, width = one = k.shape
             bound = T if attn_len is None else min(int(attn_len), T)
             every = jnp.int32(B * bound)
-            block = walk_block(heads, width, k.dtype, T, v.shape[-1])
+            block = walk_block(self._kv_heads(window), self._key_held(window),
+                               k.dtype, T, v.shape[-1])
             if reads_ragged("tpu", (B, self.cfg.n_heads, 1, width), one,
-                            (dt, k.dtype, v.dtype), mesh, v.shape[-1]):
+                            (dt, k.dtype, v.dtype), mesh, v.shape[-1],
+                            self._packed[window]):
                 read = lax.platform_dependent(
                     jnp.minimum(lengths, bound),
                     tpu=lambda n_, block=block: jnp.sum(
@@ -749,22 +889,27 @@ class MimoV2LM(DecoderFamily):
         touched = routed_rows = held = jnp.int32(0)
         for p, window, routed in zip(params["layers"], self._window,
                                      self._routed):
-            q, k, v = self._heads(p, self._norm(x, p["ln_op"]), pos[:, None],
-                                  window)
-            q, k = self._row_queries(q), self._key_rows(k)
+            a = self._norm(x, p["ln_op"])
+            if self._packed[window]:
+                q, q_rest, k, v = self._cut_heads(p, a, pos, window)
+                how = dict(mesh=mesh, q_rest=q_rest)
+            else:
+                q, k, v = self._heads(p, a, pos[:, None], window)
+                q, k = self._row_queries(q), self._key_rows(k)
+                how = dict(mesh=mesh)
             if window:
                 at = len(new["wk"])
                 o, nk, nv = decode_attention(
                     q, cache["wk"][at], cache["wv"][at], k, v, ring_at,
-                    ring_lens - 1, ring_lens, mesh=mesh, sink=p["sink"],
-                    name="swa_ring_attention")
+                    ring_lens - 1, ring_lens, sink=p["sink"],
+                    name="swa_ring_attention", **how)
                 new["wk"].append(nk)
                 new["wv"].append(nv)
             else:
                 at = len(new["k"])
                 o, nk, nv = decode_attention(
                     q, cache["k"][at], cache["v"][at], k, v, wp, pos, lens,
-                    attn_len=attn_len, mesh=mesh)
+                    attn_len=attn_len, **how)
                 new["k"].append(nk)
                 new["v"].append(nv)
             x = x + self._attention_out(p, o)

@@ -31,7 +31,8 @@ is in flight while this lane's last one is computed.
 
 ``decode_attention()`` is the public entry for "write this step's row,
 then read": it picks the kernel by what it can see (``T == 1``,
-``head_dim`` a multiple of 128, the cache length a multiple of the
+``head_dim`` a multiple of 128 or cut at 128 with its rest packed
+(``packed_key_rows``: ISSUE 59), the cache length a multiple of the
 block, no serving mesh) and by the platform the executable is LOWERED
 for (``lax.platform_dependent``; a process whose backend is the CPU can
 compile for a described TPU and gets the kernel), and takes
@@ -54,6 +55,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+# A row of the K and V arrays as the kernel slices it: a register's lanes.
+LANES = 128
 # Positions a block of the walk holds where a block of them covers the
 # chain (``walk_block``); also the scheduler's bucket step (``attn_bucket``),
 # so every bucket tiles.
@@ -110,7 +113,11 @@ def walk_block(kv_heads: int, head_dim: int, dtype, t: int,
     cache's length, else ``BLOCK``. A rule on the call's shapes alone.
     Whoever states what the kernel streams (a counter, a roofline's
     bytes, the scheduler's ``kv_positions_read``) asks here. ``v_dim``: a
-    value row's width where it is not the key row's."""
+    value row's width where it is not the key row's. ``copied`` counts what
+    a block's two copies MOVE: ``head_dim`` is what a KV head's key
+    occupies of the K array, the row's width where a key is padded to one
+    and the key's own where its rest is packed (``packed_key_rows``: a
+    head of 192 in a row of 128 and half a row is 192 wide here)."""
     copied = (kv_heads * (head_dim + (v_dim or head_dim))
               * jnp.dtype(dtype).itemsize * BLOCK)
     if copied < COVERS and t % WIDE_BLOCK == 0:
@@ -118,20 +125,70 @@ def walk_block(kv_heads: int, head_dim: int, dtype, t: int,
     return BLOCK
 
 
+def packed_key_rows(head_dim: int, kv_heads: int) -> int:
+    """Rows of a K array's KV axis that hold the RESTS of keys wider than a
+    row of ``LANES``: a key of ``LANES < head_dim < 2 x LANES`` is kept as a
+    ``LANES``-wide part in its head's own row and a rest of ``r = head_dim
+    - LANES``, and ``LANES / r`` heads' rests share a row after the
+    ``kv_heads`` parts (``pack_keys``), so the array is ``[B, kv_heads +
+    packed, T, LANES]`` and nothing of it is padding. 0 where the shapes do
+    not divide so (``r`` divides ``LANES`` and ``kv_heads`` divides by
+    ``LANES / r``): such keys are held in rows padded to a multiple of
+    ``LANES``, which is the same kernel with no packed row. Mosaic slices
+    no row of 192 (``block_reads_ragged``), which is why a key is cut at
+    ``LANES``; a dot product is a sum over dims, so where the cut falls is
+    free."""
+    rest = head_dim - LANES
+    if not 0 < rest < LANES or LANES % rest or kv_heads % (LANES // rest):
+        return 0
+    return kv_heads // (LANES // rest)
+
+
+def pack_keys(k, packed: int):
+    """Keys [..., KV, T, Dk] as a K array with ``packed`` rest rows holds
+    them ([..., KV + packed, T, LANES]): each head's first ``LANES`` dims in
+    its own row, then row ``j`` of the rests the dims past them of heads
+    ``j x pack .. (j + 1) x pack - 1``, side by side. The stored bits."""
+    *lead, kv, t, dk = k.shape
+    pack, r = kv // packed, dk - LANES
+    rests = k[..., LANES:].reshape(*lead, packed, pack, t, r)
+    rests = jnp.moveaxis(rests, -3, -2).reshape(*lead, packed, t, pack * r)
+    return jnp.concatenate([k[..., :LANES], rests], axis=-3)
+
+
+def unpack_keys(rows, packed: int):
+    """``pack_keys``'s inverse over the last three axes: [..., KV + packed,
+    T, LANES] -> the keys whole, [..., KV, T, Dk]."""
+    *lead, n, t, width = rows.shape
+    kv = n - packed
+    pack = kv // packed
+    rests = rows[..., kv:, :, :].reshape(*lead, packed, t, pack, width // pack)
+    rests = jnp.moveaxis(rests, -2, -3).reshape(*lead, kv, t, width // pack)
+    return jnp.concatenate([rows[..., :kv, :, :], rests], axis=-1)
+
+
+def _key_width(dh: int, n_kv: int, packed: int) -> int:
+    """What a KV head's key occupies of a K array [B, n_kv + packed, T,
+    dh]: its row and its share of a packed one."""
+    return dh + dh * packed // n_kv
+
+
 def reads_ragged(platform, q_shape, cache_shape, dtypes, mesh=None,
-                 v_dim=None) -> bool:
+                 v_dim=None, packed=0) -> bool:
     """Whether ``decode_attention()``, lowered for ``platform``, reads
     each lane's own length (the kernel) and not a static bound of every
     lane (the dots). ``q_shape`` [B, H, T, Dh]; ``cache_shape`` [B, KV,
     Tc, Dh] of one layer's K (V's is the same, but for its rows' width
-    ``v_dim`` where a caller's keys are wider than its values); ``dtypes``
-    of q, K and V.
+    ``v_dim`` where a caller's keys are wider than its values, and for the
+    ``packed`` rows of K's KV axis that hold the keys' rests:
+    ``packed_key_rows``; ``q_shape`` is then the queries' ``Dh``-wide
+    part's); ``dtypes`` of q, K and V.
 
     The kernel wants one query position, a head size that fills lanes,
     whole GQA groups, whole blocks and one dtype; Mosaic kernels cannot
     be partitioned by GSPMD, so a serving mesh takes the dots."""
     return q_shape[2] == 1 and block_reads_ragged(
-        platform, q_shape, cache_shape, dtypes, mesh, v_dim)
+        platform, q_shape, cache_shape, dtypes, mesh, v_dim, packed)
 
 
 def cache_attention(q, kc, vc, bound, dt, lo=None, sink=None):
@@ -228,10 +285,10 @@ def cache_write(cache, new, positions):
     )
 
 
-def _windowed_kernel(starts_ref, *refs, block):
+def _windowed_kernel(starts_ref, *refs, **how):
     """``_ragged_kernel`` for a layer with a window: ``starts_ref`` (SMEM
     [B]) is the first position each lane sees."""
-    _ragged_kernel(*refs, block=block, starts_ref=starts_ref)
+    _ragged_kernel(*refs, starts_ref=starts_ref, **how)
 
 
 def _sink_kernel(sink_ref, *refs, **how):
@@ -240,9 +297,17 @@ def _sink_kernel(sink_ref, *refs, **how):
     _ragged_kernel(*refs, sink_ref=sink_ref, **how)
 
 
+def _packed_kernel(qrest_ref, *refs, inner, **how):
+    """``inner`` (one of the three above) over a K array whose KV axis ends
+    in packed rest rows: ``qrest_ref`` (VMEM [B, packed, pack x rep, Dh]) is
+    the queries' rests, each laid in its head's part of a row."""
+    inner(*refs, qrest_ref=qrest_ref, **how)
+
+
 def _ragged_kernel(lens_ref, wpos_ref, q_ref, knew_ref, vnew_ref, _k_in, _v_in,
                    o_ref, k_hbm, v_hbm, kbuf, vbuf, kstage, vstage, sem, wsem,
-                   rsem, *, block, starts_ref=None, rows=1, sink_ref=None):
+                   rsem, *, block, starts_ref=None, rows=1, sink_ref=None,
+                   qrest_ref=None):
     """The whole batch of one layer: for each lane with ``len > 0``, walk
     its ``ceil(len / block)`` blocks with an online softmax, and where a
     block holds the lane's ``write_pos`` put the new row into it first
@@ -270,11 +335,22 @@ def _ragged_kernel(lens_ref, wpos_ref, q_ref, knew_ref, vnew_ref, _k_in, _v_in,
     A value row may be narrower than a key row (v_hbm [B, KV, T, Dv], o_ref
     [B, KV, rep, Dv]). ``sink_ref``: a logit a query head that joins the
     lane's softmax after its last block and has no value row.
+
+    Keys wider than a row (``packed_key_rows``): k_hbm, knew_ref, kbuf and
+    kstage are [., KV + packed, ., Dh], the ``packed`` rows after the heads'
+    own each holding the rests of ``pack = KV / packed`` heads, and
+    ``qrest_ref`` [B, packed, pack x rep, Dh] the queries' rests, head
+    ``j x pack + p``'s in columns ``[p x Dh / pack, (p + 1) x Dh / pack)``
+    of its ``rep`` rows and zeros in the others: a head's score is its
+    part's product plus its row of the packed one's. The copies, the write
+    and the staging move whole rows of the KV axis and know nothing of it;
+    only the scores and their scale (the key's own width) do.
     """
     n_lanes, n_kv, rep, dh = q_ref.shape
     dv = v_hbm.shape[3]
     t = k_hbm.shape[2]
-    scale = 1.0 / np.sqrt(dh)
+    packed = k_hbm.shape[1] - n_kv
+    scale = 1.0 / np.sqrt(_key_width(dh, n_kv, packed))
 
     def copies(lane, i, slot):
         start = pl.multiple_of(i * block, block)
@@ -401,9 +477,15 @@ def _ragged_kernel(lens_ref, wpos_ref, q_ref, knew_ref, vnew_ref, _k_in, _v_in,
                 land(0, lane, wp, slot)
 
             s = jnp.einsum(
-                "grd,gkd->grk", q, kbuf[slot],
+                "grd,gkd->grk", q, kbuf[slot, :n_kv],
                 preferred_element_type=jnp.float32,
-            ) * scale  # [KV, rep, block]
+            )  # [KV, rep, block]
+            if packed:
+                s = s + jnp.einsum(
+                    "jrd,jkd->jrk", qrest_ref[lane], kbuf[slot, n_kv:],
+                    preferred_element_type=jnp.float32,
+                ).reshape(s.shape)
+            s = s * scale
             col = nth(i) * block + lax.broadcasted_iota(jnp.int32, s.shape, 2)
             # the lane's first position is live in its first block, so m is
             # finite from there on and a masked entry's exp underflows to 0
@@ -464,7 +546,8 @@ def _ragged_kernel(lens_ref, wpos_ref, q_ref, knew_ref, vnew_ref, _k_in, _v_in,
 @functools.partial(jax.jit, static_argnames=("block", "interpret", "name"))
 def ragged_decode_attention(q, k, v, lens, k_new, v_new, write_pos,
                             block: int = None, interpret: bool = False,
-                            starts=None, sink=None, name: str = None):
+                            starts=None, sink=None, name: str = None,
+                            q_rest=None):
     """Pallas ragged decode attention with the step's write inside it.
     q [B, H, 1, Dh]; k, v the layer's cache [B, KV, T, Dh], unsliced
     (``T`` must divide by ``block``: ``walk_block()``'s for the call's
@@ -506,11 +589,28 @@ def ragged_decode_attention(q, k, v, lens, k_new, v_new, write_pos,
     ``write_pos`` the position modulo it, ``lens`` at most ``T`` (keys
     carry their rotary, so a softmax over a ring needs no order, only the
     bound of the slots written). ``name``: the kernel's name in a trace,
-    where a caller wants its own."""
+    where a caller wants its own.
+
+    Keys wider than a row, held packed (``packed_key_rows``, ``pack_keys``):
+    k [B, KV + packed, T, Dh] and k_new [B, KV + packed, 1, Dh] with the
+    heads' rests in the last ``packed`` rows of the KV axis (v's says how
+    many heads there are), q the queries' first ``Dh`` dims and ``q_rest``
+    [B, H, 1, Dk - Dh] the dims past them, as projected: the scores are
+    ``(q . k + q_rest . k_rest) / sqrt(Dk)``, the read of the keys held
+    whole. An iteration still issues two copies, K's 1 + 1 / (2 pack) rows a
+    head where a row padded to ``2 Dh`` was 2."""
     b, h, t_q, dh = q.shape
-    n_kv, t, dv = k.shape[1], k.shape[2], v.shape[3]
+    n_kv, t, dv = v.shape[1], k.shape[2], v.shape[3]
+    packed = k.shape[1] - n_kv
+    if (q_rest is None) != (packed == 0) or (packed and (
+            n_kv % packed or dh % (n_kv // packed)
+            or q_rest.shape != (b, h, t_q, dh * packed // n_kv))):
+        raise ValueError(
+            f"k {k.shape} beside v {v.shape}: {packed} packed rows want the "
+            f"queries' rests [B, H, T, Dh x packed / KV], and no others do")
     if block is None:
-        block = walk_block(n_kv, dh, k.dtype, t, None if dv == dh else dv)
+        dk = _key_width(dh, n_kv, packed)
+        block = walk_block(n_kv, dk, k.dtype, t, None if dv == dk else dv)
     if sink is not None and (t_q > 1 or starts is not None):
         raise ValueError("a sink: one position a lane and no starts")
     if GROUP % t_q or (t_q > 1 and starts is not None) or h % n_kv \
@@ -543,6 +643,18 @@ def ragged_decode_attention(q, k, v, lens, k_new, v_new, write_pos,
                         for new in (k_new, v_new))
         kernel = functools.partial(kernel, rows=t_q)
         named = {"name": "block_decode_attention"}
+    if packed:
+        # [B, H, T, r] -> [B, packed, pack x rep, pack x r]: head j x pack +
+        # p's rows hold its rest in the p-th part of the row, zeros beside it
+        pack = n_kv // packed
+        r = dh // pack
+        rests = q_rest.reshape(b, packed, pack, rep, r)
+        laid = jnp.concatenate([
+            jnp.pad(rests[:, :, p], ((0, 0),) * 3 + ((p * r, dh - (p + 1) * r),))
+            for p in range(pack)], axis=2)
+        kernel = functools.partial(_packed_kernel, inner=kernel)
+        scalars = (laid, *scalars)
+        lead_specs = [vmem] + lead_specs
     out, k, v = pl.pallas_call(
         functools.partial(kernel, block=block),
         out_shape=(
@@ -554,9 +666,9 @@ def ragged_decode_attention(q, k, v, lens, k_new, v_new, write_pos,
         out_specs=(vmem, hbm, hbm),
         input_output_aliases={len(scalars) + 5: 1, len(scalars) + 6: 2},
         scratch_shapes=[
-            pltpu.VMEM((2, n_kv, block, dh), k.dtype),
+            pltpu.VMEM((2, k.shape[1], block, dh), k.dtype),
             pltpu.VMEM((2, n_kv, block, dv), v.dtype),
-            pltpu.VMEM((b, n_kv, GROUP, dh), k.dtype),
+            pltpu.VMEM((b, k.shape[1], GROUP, dh), k.dtype),
             pltpu.VMEM((b, n_kv, GROUP, dv), v.dtype),
             pltpu.SemaphoreType.DMA((2, 2)),
             pltpu.SemaphoreType.DMA((2,)),
@@ -574,7 +686,7 @@ def ragged_decode_attention(q, k, v, lens, k_new, v_new, write_pos,
 @functools.partial(jax.jit, static_argnames=("attn_len", "mesh", "name"))
 def decode_attention(q, k, v, k_new, v_new, write_pos, pos, lens,
                      attn_len=None, mesh=None, starts=None, sink=None,
-                     name: str = None):
+                     name: str = None, q_rest=None):
     """The decode step's write and read of one layer's cache: this step's
     rows k_new, v_new [B, KV, 1, Dh] go into the UNSLICED cache k, v [B,
     KV, T, Dh] at ``write_pos`` [B] (outside [0, T): dropped), then q [B,
@@ -614,23 +726,33 @@ def decode_attention(q, k, v, k_new, v_new, write_pos, pos, lens,
     and ``name`` (the kernel's name in a trace): as
     ``ragged_decode_attention()`` takes them (a sink and no ``starts``),
     the ring among them. ONE choice between the kernel and the dots for
-    every caller: ``starts`` or ``sink`` is an operand of both where given
-    and a Python ``None`` where not, which no trace sees.
+    every caller: ``starts``, ``sink`` or ``q_rest`` is an operand of both
+    where given and a Python ``None`` where not, which no trace sees.
+
+    Keys held packed (``packed_key_rows``): k [B, KV + packed, T, Dh] with
+    v [B, KV, T, Dv], k_new as ``pack_keys`` lays it, q the queries' first
+    ``Dh`` dims and ``q_rest`` the rest. The dots write the same rows and
+    read the keys put back whole (``unpack_keys``: a copy of what they
+    read, on the platforms that take them).
     """
     if sink is not None and starts is not None:
         raise ValueError("a sink: one position a lane and no starts")
     t = k.shape[2]
+    packed = k.shape[1] - v.shape[1]
     bound = t if attn_len is None else min(int(attn_len), t)
-    given = {n: a for n, a in (("starts", starts), ("sink", sink))
-             if a is not None}
+    given = {n: a for n, a in (("starts", starts), ("sink", sink),
+                               ("q_rest", q_rest)) if a is not None}
 
     def dots(q, k, v, k_new, v_new, write_pos, pos, lens, *more):
         more = dict(zip(given, more))
         k = cache_write(k, k_new, write_pos[:, None])
         v = cache_write(v, v_new, write_pos[:, None])
+        keys = lax.slice_in_dim(k, 0, bound, axis=2)
+        if packed:
+            q = jnp.concatenate([q, more["q_rest"]], axis=-1)
+            keys = unpack_keys(keys, packed)
         o = cache_attention(
-            q, lax.slice_in_dim(k, 0, bound, axis=2),
-            lax.slice_in_dim(v, 0, bound, axis=2), pos, q.dtype,
+            q, keys, lax.slice_in_dim(v, 0, bound, axis=2), pos, q.dtype,
             lo=more.get("starts"), sink=more.get("sink"))
         return o, k, v
 
@@ -645,13 +767,13 @@ def decode_attention(q, k, v, k_new, v_new, write_pos, pos, lens,
     # lowering for a TPU takes the kernel, and let that lowering choose
     if not reads_ragged(
             "tpu", q.shape, k.shape, (q.dtype, k.dtype, v.dtype), mesh,
-            None if dv == k.shape[3] else dv):
+            None if dv == k.shape[3] else dv, packed):
         return dots(*args)
     return lax.platform_dependent(*args, tpu=kernel, default=dots)
 
 
 def block_reads_ragged(platform, q_shape, cache_shape, dtypes, mesh=None,
-                       v_dim=None) -> bool:
+                       v_dim=None, packed=0) -> bool:
     """``reads_ragged()`` for ``block_decode_attention()``: the kernel's
     second entry takes a block of ``W = q_shape[2]`` positions a lane where
     ``W`` divides ``GROUP`` (the block then lies in one group of the cache's
@@ -660,17 +782,21 @@ def block_reads_ragged(platform, q_shape, cache_shape, dtypes, mesh=None,
     (``walk_block()``) and one dtype, and no serving mesh. ``v_dim`` (a
     value row's width where it is not the key row's) fills lanes too:
     Mosaic slices no row of 192 ("must be aligned to tiling (128)"; such a
-    row occupies 256 in HBM either way), so a family with such keys holds
-    them in rows of 256, zero past the key."""
+    row occupies 256 in HBM either way), so a family with such keys cuts
+    them at 128 and packs the rests (``packed_key_rows``: ``packed`` rows
+    of ``cache_shape``'s KV axis, ``q_shape`` the queries' 128-wide part)
+    or, where its shapes do not divide so, holds them in rows of 256, zero
+    past the key."""
     _, h, w, dh = q_shape
-    n_kv, t = cache_shape[1:3]
+    n_kv, t = cache_shape[1] - packed, cache_shape[2]
     return (
         platform == "tpu"
         and mesh is None
         and GROUP % w == 0
-        and dh % 128 == 0 and (v_dim or dh) % 128 == 0
+        and dh % LANES == 0 and (v_dim or dh) % LANES == 0
         and h % n_kv == 0
-        and t % walk_block(n_kv, dh, dtypes[1], t, v_dim) == 0
+        and t % walk_block(n_kv, _key_width(dh, n_kv, packed), dtypes[1], t,
+                           v_dim) == 0
         and len(set(dtypes)) == 1
     )
 

@@ -982,9 +982,11 @@ def _mimo(one_chip):
 def test_mimo_burst_compiled_for_v5e_is_two_named_reads_over_a_cache_of_kinds_in_place(one_chip):
     """The configuration's own burst (64 lanes of 12,288, all 7 layers, no
     bucket): the two full layers decode through the ragged kernel at 16
-    query rows a KV head over key rows of 256 beside values of 128, the five
-    window layers through the same kernel under its own name over a RING of
-    128 rows with 8 KV heads and a sink, every expert layer's held experts
+    query rows a KV head over keys CUT at 128 (4 rows of parts and 2 of two
+    heads' 64-wide rests each a position, the queries' rests 32 rows a
+    packed row) beside values of 128, the five window layers through the
+    same kernel under its own name over a RING of 128 rows with 8 KV heads
+    (8 + 4 key rows) and a sink, every expert layer's held experts
     through the touched-expert kernel at width 2,048, all inside the
     ``while``; every kind's leaves are aliased through, and nothing of a
     full layer's leaf's shape is copied, sliced out or scattered into: no
@@ -997,10 +999,10 @@ def test_mimo_burst_compiled_for_v5e_is_two_named_reads_over_a_cache_of_kinds_in
     compiled, (lanes, kv, T, dh), cache_bytes, leaves = tool.compile_burst(
         cfg, None, one_chip)
     assert (lanes, kv, T, dh, leaves) == (64, 4, 12288, 192, 2 * 2 + 2 * 5)
-    # as allocated: key rows of 256; 3,072 B a position and full layer, and
-    # five rings of 128 rows of 6,144 B a lane
-    assert cache_bytes == lanes * (2 * 4 * (256 + 128) * 2 * T
-                                   + 5 * 8 * (256 + 128) * 2 * 128)
+    # as allocated: what holds something; 2,560 B a position and full
+    # layer, and five rings of 128 rows of 5,120 B a lane
+    assert cache_bytes == lanes * (2 * 4 * (192 + 128) * 2 * T
+                                   + 5 * 8 * (192 + 128) * 2 * 128)
     hlo = compiled.as_text()
     assert tool.kernel_calls(hlo) == {"inside": 7 + 6, "outside": 0}
     names = re.findall(r"%([a-z_]+)[.\d]* = [^\n]*? custom-call\(", hlo)
@@ -1009,18 +1011,20 @@ def test_mimo_burst_compiled_for_v5e_is_two_named_reads_over_a_cache_of_kinds_in
     assert names.count("swa_ring_attention") == 5
     full = next(line for line in hlo.splitlines()
                 if "custom-call(" in line and "ragged_decode_attention" in line)
-    assert "bf16[64,4,16,256]" in full and "bf16[64,4,12288,256]" in full
-    assert "bf16[64,4,12288,128]" in full and "bf16[64,4,16,128]" in full
+    assert "bf16[64,4,16,128]" in full and "bf16[64,6,12288,128]" in full
+    assert "bf16[64,4,12288,128]" in full and "bf16[64,2,32,128]" in full
+    assert "256]" not in full.split("custom-call(")[1].split(")")[0]
     ring = next(line for line in hlo.splitlines()
                 if "custom-call(" in line and "swa_ring_attention" in line)
     # a layer's ring is an array of its own, 128 rows a lane
-    assert "bf16[64,8,128,256]" in ring and "bf16[64,8,128,128]" in ring
+    assert "bf16[64,12,128,128]" in ring and "bf16[64,8,128,128]" in ring
+    assert "bf16[64,8,8,128]" in ring and "bf16[64,4,16,128]" in ring
     assert "f32[8,8,1]" in ring and "12288" not in ring.split("custom-call(")[1]
     # (a layer's KEY ring, 33.5 MB and all of it read by a step of 64 lanes
     # past the window, the compiler may prefetch to VMEM before the call
     # and copy back after it: on the chip one layer's of five, 0.02 ms of a
     # 12.2 ms step: PERF.md section 6, PR 57)
-    for rows, length, width in ((4, T, 256), (4, T, 128)):
+    for rows, length, width in ((6, T, 128), (4, T, 128)):
         assert tool.cache_shaped(hlo, lanes, rows, (length,), width) == []
         assert tool.cache_scatters(hlo, lanes, rows, length, width) == 0
     assert tool.alias_count(hlo) >= leaves
@@ -1063,7 +1067,8 @@ def test_mimo_prefill_compiled_for_v5e_is_flash_at_two_widths_a_sink_and_a_band(
     assert f"bf16[64,{T},192]" in band and f"bf16[64,{T},128]" in band
     assert "f32[64]" in band
     # the slab: the full layers' rows and the last 128 rows of the window's
-    assert f"bf16[2,1,4,{T},256]" in hlo and "bf16[5,1,8,128,256]" in hlo
+    # (keys cut and packed: 4 + 2 rows a position, 8 + 4 a ring's)
+    assert f"bf16[2,1,6,{T},128]" in hlo and "bf16[5,1,12,128,128]" in hlo
     assert f"bf16[5,1,8,{T}" not in hlo
     # beside 11.9 GB of weights and cache the transients have 4 GB
     temp = compiled.memory_analysis().temp_size_in_bytes

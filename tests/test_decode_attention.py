@@ -21,8 +21,11 @@ from seldon_core_tpu.ops.decode_attention import (
     cache_attention,
     cache_write,
     decode_attention,
+    pack_keys,
+    packed_key_rows,
     ragged_decode_attention,
     reads_ragged,
+    unpack_keys,
     walk_block,
 )
 
@@ -476,8 +479,9 @@ def test_entry_takes_its_choice_from_the_rule(on_a_tpu, monkeypatch):
     got = entry(*args, mesh=None)
     for a, b in zip(got, (dots, sk, sv)):
         assert np.array_equal(_f32(a), _f32(b))
-    # (the last: the values' width, None where it is the keys')
-    assert asked == [("tpu", q.shape, k.shape, (q.dtype,) * 3, None, None)]
+    # (the last two: the values' width, None where it is the keys', and
+    # the rows of K's KV axis that hold packed rests)
+    assert asked == [("tpu", q.shape, k.shape, (q.dtype,) * 3, None, None, 0)]
 
 
 @pytest.mark.parametrize("lens", [
@@ -769,3 +773,137 @@ def test_one_entry_takes_a_window_at_two_widths(dtype, tol):
     with pytest.raises(ValueError, match="a sink"):
         decode_attention(q, k, v, kn, vn, lens - 1, lens - 1, lens,
                          starts=starts, sink=logits)
+
+
+# -- keys wider than a row, held cut and packed (the mimo_v2 block's: ISSUE 59) --
+
+def _packed_case(seed, lanes, kv, rep, t, dk, dtype, w=1):
+    """A case at keys of ``dk`` beside values of 128: the keys whole, for
+    the scatter and the dots, and as the cache holds them packed."""
+    q, k, v, kn, vn, logits = _wide(seed, lanes, kv, rep, t, dk, 128, dtype)
+    if w > 1:
+        ks = jax.random.split(jax.random.PRNGKey(seed + 1), 3)
+        dt = jnp.dtype(dtype)
+        q = jax.random.normal(ks[0], (lanes, kv * rep, w, dk), dt)
+        kn = jax.random.normal(ks[1], (lanes, kv, w, dk), dt)
+        vn = jax.random.normal(ks[2], (lanes, kv, w, 128), dt)
+    packed = packed_key_rows(dk, kv)
+    assert packed
+    return (q, k, v, kn, vn, logits, packed, pack_keys(k, packed),
+            pack_keys(kn, packed))
+
+
+@pytest.mark.parametrize("why,kv,rep,t,dk,lens,wp,sink", [
+    # lanes on both sides of a block's edge, one of length 0, a parked
+    # write, the write landing in the last block of the read and in none
+    ("full: two heads' rests a row", 4, 4, 2 * WIDE_BLOCK, 192,
+     [0, 1, 255, 256, 257, 2 * WIDE_BLOCK, 300, 40],
+     [9, 0, 254, 255, 256, 511, 2 * WIDE_BLOCK, 400], False),
+    # a ring of one block under its own name: lanes not yet once round, a
+    # full ring written anywhere in it, an idle lane, a parked one
+    ("ring: a sink", 4, 2, BLOCK, 192,
+     [0, 1, 77, BLOCK, BLOCK, BLOCK, 5], [BLOCK, 0, 76, 127, 0, 63, BLOCK],
+     True),
+    ("ring: four heads' rests a row", 4, 2, BLOCK, 160,
+     [3, BLOCK, 0, 64], [2, 40, BLOCK, 63], True),
+])
+@pytest.mark.parametrize("dtype,tol", [("float32", 1e-5), ("bfloat16", 2 ** -6)])
+def test_packed_key_rows_are_the_read_of_the_keys_held_whole(
+        why, kv, rep, t, dk, lens, wp, sink, dtype, tol):
+    """The kernel (interpreted) over K ``[B, KV + packed, T, 128]`` against
+    ``cache_write`` + ``cache_attention`` over the same keys held whole:
+    the scores are the part's product plus the packed row's, scaled by the
+    KEY's width; the cache it leaves is the scatter's rows packed, bit for
+    bit, and a lane of length 0 gives zeros and writes nothing."""
+    q, k, v, kn, vn, logits, packed, rows, new = _packed_case(
+        13, len(lens), kv, rep, t, dk, dtype)
+    lens, wp = jnp.asarray(lens, jnp.int32), jnp.asarray(wp, jnp.int32)
+    s = logits if sink else None
+    o, k2, v2 = ragged_decode_attention(
+        q[..., :128], rows, v, lens, new, vn, wp, interpret=True, sink=s,
+        name="swa_ring_attention" if sink else None, q_rest=q[..., 128:])
+    assert o.shape == (len(lens), kv * rep, 1, 128)
+    assert k2.shape == (len(lens), kv + packed, t, 128)
+    live = np.asarray(lens) > 0
+    at = jnp.where(lens > 0, wp, t)[:, None]
+    kk, vv = cache_write(k, kn, at), cache_write(v, vn, at)
+    want = cache_attention(q, kk, vv, lens - 1, q.dtype, sink=s)
+    np.testing.assert_allclose(
+        np.asarray(o, np.float32)[live], np.asarray(want, np.float32)[live],
+        atol=tol, rtol=tol)
+    assert not np.asarray(o, np.float32)[~live].any()
+    assert jnp.array_equal(k2, pack_keys(kk, packed))
+    assert jnp.array_equal(unpack_keys(k2, packed), kk)
+    assert jnp.array_equal(v2, vv)
+    # the rest matters: the parts alone are another read
+    bare = cache_attention(q[..., :128], kk[..., :128], vv, lens - 1, q.dtype,
+                           sink=s)
+    assert float(jnp.abs(bare.astype(jnp.float32)
+                         - want.astype(jnp.float32))[live].max()) > 10 * tol
+
+
+def test_a_block_of_positions_over_packed_key_rows():
+    """The second entry is the same walk with ``W`` times the query rows a
+    KV head, so packed rows meet it as they are: a block of 4 positions a
+    lane against the scatter and the dots over the keys held whole."""
+    lens = jnp.asarray([4, 260, 0, 512], jnp.int32)
+    wp = jnp.asarray([0, 256, 8, 508], jnp.int32)
+    q, k, v, kn, vn, _s, packed, rows, new = _packed_case(
+        17, 4, 2, 2, 2 * WIDE_BLOCK, 192, "float32", w=4)
+    o, k2, v2 = ragged_decode_attention(
+        q[..., :128], rows, v, lens, new, vn, wp, interpret=True,
+        q_rest=q[..., 128:])
+    at = jnp.where(lens[:, None] > 0, wp[:, None] + jnp.arange(4), 512)
+    kk, vv = cache_write(k, kn, at), cache_write(v, vn, at)
+    want = cache_attention(q, kk, vv, jnp.broadcast_to(
+        lens[:, None] - 1, (4, 4)), q.dtype)
+    live = np.asarray(lens) > 0
+    np.testing.assert_allclose(np.asarray(o)[live], np.asarray(want)[live],
+                               atol=1e-5)
+    assert jnp.array_equal(k2, pack_keys(kk, packed)) and jnp.array_equal(v2, vv)
+
+
+@pytest.mark.parametrize("sink", [False, True])
+def test_entry_takes_the_dots_off_tpu_over_packed_key_rows(sink):
+    """``decode_attention()`` on the CPU: the scatter of the packed row and
+    the dots over the keys put back whole are the kernel's read
+    (interpreted), caches bit for bit; and the rule answers for the shapes
+    as the mimo cell holds them."""
+    q, k, v, kn, vn, logits, packed, rows, new = _packed_case(
+        3, 4, 4, 2, BLOCK, 192, "float32")
+    lens = jnp.asarray([1, 60, BLOCK, BLOCK], jnp.int32)
+    wp = jnp.asarray([0, 59, 17, BLOCK], jnp.int32)
+    s = logits if sink else None
+    o, k2, v2 = decode_attention(
+        q[..., :128], rows, v, new, vn, wp, lens - 1, lens, sink=s,
+        name="swa_ring_attention", q_rest=q[..., 128:])
+    o3, k3, v3 = ragged_decode_attention(
+        q[..., :128], rows, v, lens, new, vn, wp, interpret=True, sink=s,
+        q_rest=q[..., 128:])
+    np.testing.assert_allclose(np.asarray(o), np.asarray(o3), atol=1e-5)
+    assert jnp.array_equal(k2, k3) and jnp.array_equal(v2, v3)
+    assert jnp.array_equal(k2[3], rows[3])
+    dts = (jnp.bfloat16,) * 3
+    assert reads_ragged("tpu", (64, 64, 1, 128), (64, 6, 12288, 128), dts,
+                        None, 128, 2)
+    assert reads_ragged("tpu", (64, 64, 1, 128), (64, 12, BLOCK, 128), dts,
+                        None, 128, 4)
+    # 64 query heads do not group over 6 rows: the packed ones are not heads
+    assert not reads_ragged("tpu", (64, 64, 1, 128), (64, 6, 12288, 128), dts,
+                            None, 128)
+
+
+def test_packed_rows_and_the_queries_rests_come_together():
+    q, k, v, kn, vn, _s, packed, rows, new = _packed_case(
+        5, 2, 4, 2, BLOCK, 192, "float32")
+    lens = jnp.asarray([4, 9], jnp.int32)
+    with pytest.raises(ValueError, match="packed rows"):
+        ragged_decode_attention(q[..., :128], rows, v, lens, new, vn, lens - 1,
+                                interpret=True)
+    with pytest.raises(ValueError, match="packed rows"):
+        ragged_decode_attention(q[..., :128], k[..., :128], v, lens,
+                                kn[..., :128], vn, lens - 1, interpret=True,
+                                q_rest=q[..., 128:])
+    with pytest.raises(ValueError, match="packed rows"):
+        ragged_decode_attention(q[..., :128], rows, v, lens, new, vn, lens - 1,
+                                interpret=True, q_rest=q[..., 128:160])
