@@ -58,8 +58,8 @@ import urllib.request
 REPO = os.path.dirname(os.path.abspath(__file__))
 T0 = time.monotonic()
 
-# modelbench.run_model_tier's llm-1.26b, every width as it is there; the
-# seed picks the random weights (jaxserver reads config["seed"])
+# the 1.26B flagship (head_dim 128, 2:1 grouped queries); the seed picks
+# the random weights (jaxserver reads config["seed"])
 FLAGSHIP = {
     "vocab_size": 32000, "d_model": 2048, "n_layers": 24,
     "n_heads": 16, "n_kv_heads": 8, "d_ff": 5632,
@@ -275,7 +275,7 @@ def write_model_and_spec(out: str, config: dict, mesh_shape: str = "") -> str:
     from seldon_core_tpu.graph.spec import (
         PredictorSpec, default_predictor, validate_predictor,
     )
-    from seldon_core_tpu.modelbench import write_model_dir
+    from seldon_core_tpu.testing import write_model_dir
 
     model_dir = write_model_dir(out, "llm", config)
     parameters = [
@@ -422,7 +422,7 @@ def run_serve_leg(name: str, out: str, config: dict, rehearse: bool,
                   mesh_shape: str = "") -> dict:
     """One engine_main child that owns the chip(s): load, warm, answer every
     request, stop. Returns the leg's observations. Raises LegFailed."""
-    from seldon_core_tpu.modelbench import free_port
+    from seldon_core_tpu.testing import free_port
 
     leg_dir = os.path.join(out, name)
     os.makedirs(leg_dir)

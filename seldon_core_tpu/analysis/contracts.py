@@ -6,8 +6,8 @@ tables in ``graph/engine_metrics.py`` (``_STEP_PHASES``,
 ``_KV_TRANSFER``, ``_RECOVERY``, ``_RECOVERY_GAUGES``, ``_SLO_TIMERS``,
 ``_FUSED``, ``_DEVICE``, ``_DEVICE_GAUGES``, ``_SLO_BURN``),
 the servers that emit the ``gen_*`` keys those tables consume, the
-tools that parse the published series (``flight_report``,
-``gen_arch_numbers``), and the operator docs. The rule re-derives the
+tools that parse the published series (``flight_report``), and the
+operator docs. The rule re-derives the
 table from source and cross-checks all four:
 
 * every mapped ``gen_*`` input key is actually emitted somewhere,

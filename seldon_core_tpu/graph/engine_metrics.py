@@ -202,9 +202,8 @@ class MetricsRegistry:
     # device-time ledger (serving/profiler.py): per-executable dispatch
     # attribution — seconds/dispatches/bytes with (kind, variant[,
     # tenant]) labels. rate(seldon_engine_device_time_seconds) by kind
-    # is the live answer to "which executable burns the accelerator",
-    # the question the offline modelbench roofline could only answer
-    # per-capture. gen_device_time_ms ships as ms (CounterDeltas keeps
+    # is the live answer to "which executable burns the accelerator".
+    # gen_device_time_ms ships as ms (CounterDeltas keeps
     # integers honest) and lands in seconds here, matching every other
     # *_seconds series.
     _DEVICE = {

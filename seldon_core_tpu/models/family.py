@@ -420,7 +420,7 @@ class DecoderFamily(ServedModel):
                 lax.slice_in_dim(cv, 0, attn_len, axis=2))
 
     # -- the device-time ledger's price list (serving/profiler.py, off by
-    # default; modelbench's offline MBU shares it). On the interface until
+    # default, and its only reader in the program). On the interface until
     # ROADMAP D11 retires the ledger; a family prices its own ``n_params``,
     # ``flops_per_token`` and decode kinds -----------------------------------
 
@@ -451,8 +451,7 @@ class DecoderFamily(ServedModel):
     ) -> float:
         """Modeled HBM bytes READ by ONE warmed-executable dispatch of the
         given kind — the static cost model the serving-time device-time
-        ledger attributes MBU with (``serving/profiler.py``), shared with
-        modelbench's offline MBU so live and bench numbers use one basis.
+        ledger attributes MBU with (``serving/profiler.py``).
 
         ``param_bytes``/``kv_row_bytes`` default to the unsharded bf16
         closed forms; the batcher passes its live (shard-aware) values.

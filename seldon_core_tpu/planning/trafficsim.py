@@ -23,8 +23,8 @@ deadlines — from ONE integer seed and nothing else:
 
 Everything derives from ``random.Random(seed)`` — the same seed yields
 the byte-identical trace on every run (asserted by
-tests/test_planning.py), which is what lets modelbench's
-``llm_1b_storm`` gate planner convergence instead of anecdotes.
+tests/test_planning.py), which is what lets ``tools/planner_smoke.py``
+gate planner convergence on a replayed storm instead of anecdotes.
 """
 
 from __future__ import annotations
@@ -192,7 +192,7 @@ class TrafficSim:
         return out
 
     def summary(self, trace: Optional[List[TrafficEvent]] = None) -> Dict[str, Any]:
-        """Aggregate shape of a trace (modelbench scenario text)."""
+        """Aggregate shape of a trace."""
         trace = self.trace() if trace is None else trace
         if not trace:
             return {"events": 0}

@@ -405,8 +405,8 @@ class GenerateServer(SeldonComponent):
         # the ledger is a shared no-op then, and the identity/overhead
         # gates in tests/test_profiler.py hold it to byte-identical
         # output. The MBU / dispatch-floor denominators are knobs so
-        # the live gauges use MEASURED numbers (modelbench publishes
-        # them) — 0 omits the gauge rather than publishing a guess.
+        # the live gauges use numbers MEASURED on the serving chip — 0
+        # omits the gauge rather than publishing a guess.
         from ..serving.profiler import DeviceTimeLedger
 
         self.profiler = DeviceTimeLedger(

@@ -653,8 +653,8 @@ class ContinuousBatcher:
         # that held a token of a prompt: their ratio is what padding costs
         # burst_reads/burst_read_bytes: modeled HBM read traffic of
         # dispatched decode bursts — params once per step plus each
-        # lane-row's bucketed KV read (spec rounds are excluded: their
-        # draft/verify byte model lives in modelbench's round-true MBU).
+        # lane-row's bucketed KV read (spec rounds are excluded: a round
+        # reads the draft's blocks gamma times beside one verify pass).
         # lane_steps = sum over dispatched bursts of k x rows (steps x
         # slots) — the occupancy denominator.
         self.stats = {
@@ -736,9 +736,9 @@ class ContinuousBatcher:
         # COMPLETED requests. ``slo_pending`` is the drain queue the
         # serving component ships as Meta.metrics TIMERs (drop-oldest
         # under pressure — telemetry must never grow unbounded);
-        # ``slo_recent`` is a reservoir benches/diagnostics read for
-        # percentiles. The count and the TTFT sum ride in ``stats`` so a
-        # window-diffed bench snapshot has the mean (modelbench reads it).
+        # ``slo_recent`` is a reservoir diagnostics read for percentiles.
+        # The count and the TTFT sum ride in ``stats`` so a window-diffed
+        # snapshot has the mean.
         self.slo_pending: "collections.deque" = collections.deque(maxlen=4096)
         self.slo_recent: "collections.deque" = collections.deque(maxlen=2048)
         self.stats.update({"slo_samples": 0, "ttft_s_sum": 0.0})
@@ -3619,9 +3619,9 @@ class ContinuousBatcher:
 
         ``k_max``: the caller's snapshot of ``self._fused_k`` — the loop
         passes the same value that decided ``use_fused`` this poll, so a
-        concurrent toggle (the modelbench fused probe flips the knob on
-        a live server) can never tear between the mode decision and the
-        plan and yield an unwarmed K."""
+        concurrent toggle (``retune`` flips the knob on a live server)
+        can never tear between the mode decision and the plan and yield
+        an unwarmed K."""
         if k_max is None:
             k_max = self._fused_k
         k, reason = k_max, None

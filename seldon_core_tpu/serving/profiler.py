@@ -3,13 +3,11 @@
 Every warmed-executable dispatch the continuous batcher makes — prefill,
 chunked prefill slice, decode burst, fused burst, spec burst, prefix
 splice/insert/extract, swap cast — is timed and attributed per
-``(kind, variant, tenant)``. The ledger turns the
-offline modelbench numbers into live gauges: bytes-read per variant are
-known statically (the same cost model ``modelbench.bench_generate``
-prices MBU with — see ``DecoderLM.dispatch_read_bytes``), so live MBU
-is a divide over a sliding window, not a profile run, and the
-dispatch-floor percentage is the observed dispatch rate priced at the
-measured per-dispatch floor.
+``(kind, variant, tenant)``. The ledger turns that into live gauges:
+bytes-read per variant are known statically (the family's cost model —
+see ``DecoderLM.dispatch_read_bytes``), so live MBU is a divide over a
+sliding window, not a profile run, and the dispatch-floor percentage is
+the observed dispatch rate priced at the measured per-dispatch floor.
 
 What a "measurement" means under JAX async dispatch, honestly:
 
@@ -287,8 +285,7 @@ class DeviceTimeLedger:
         if self.dispatch_floor_us > 0:
             # fraction of wall time the measured per-dispatch floor alone
             # would consume at the observed dispatch rate: near 100 means
-            # the workload is dispatch-bound (the modelbench roofline,
-            # live)
+            # the workload is dispatch-bound
             out["dispatch_floor_pct"] = round(
                 min(100.0, 100.0 * disp_s * self.dispatch_floor_us * 1e-6),
                 2,

@@ -1,21 +1,14 @@
 """Socket helpers shared by the socket-level tests.
 
 A plain module (not conftest) so it stays importable under
-``--import-mode=importlib``; bench.py keeps its own free_port copy so it
-runs standalone.
+``--import-mode=importlib``.
 """
 
 import json
 import socket
 import threading
 
-
-def free_port() -> int:
-    s = socket.socket()
-    s.bind(("127.0.0.1", 0))
-    port = s.getsockname()[1]
-    s.close()
-    return port
+from seldon_core_tpu.testing import free_port
 
 
 class FixedResponseServer:
@@ -78,6 +71,34 @@ class FixedResponseServer:
     def __exit__(self, *exc):
         self._stop.set()
         self._srv.close()
+
+
+def post_predictions(port: int, body: bytes,
+                     content_type: str = "application/json"):
+    """POST ``body`` to an engine's predictions route -> (status, bytes)."""
+    import http.client
+
+    conn = http.client.HTTPConnection("127.0.0.1", port, timeout=120)
+    try:
+        conn.request("POST", "/api/v0.1/predictions", body,
+                     {"Content-Type": content_type})
+        resp = conn.getresponse()
+        return resp.status, resp.read()
+    finally:
+        conn.close()
+
+
+def grpc_predict(port: int, request: bytes) -> bytes:
+    """One ``Seldon/Predict`` call, serialized SeldonMessage in and out."""
+    import grpc
+
+    from seldon_core_tpu.proto.services import method_path
+
+    with grpc.insecure_channel(f"127.0.0.1:{port}") as ch:
+        rpc = ch.unary_unary(method_path("Seldon", "Predict"),
+                             request_serializer=lambda b: b,
+                             response_deserializer=lambda b: b)
+        return rpc(request, timeout=120.0)
 
 
 def wait_port(port: int, timeout: float = 5.0) -> None:

@@ -1,17 +1,12 @@
 """Anchors (components/anchors.py): the reference's default explainer
 family (alibi anchors, seldondeployment_explainers.go:32-187) rebuilt
-black-box — rule + precision + coverage for non-differentiable models.
+black-box — rule + precision + coverage for non-differentiable models."""
 
-Also home to repo ANCHOR tests: assertions that load-bearing artifacts
-(bench scenarios the driver's acceptance gates read) cannot silently
-disappear from the tree."""
-
-import os
 
 import numpy as np
 import pytest
 
-from _net import free_port, serve_on_thread, wait_port
+from _net import free_port, serve_on_thread
 
 from seldon_core_tpu.components.anchors import AnchorTabular, AnchorText
 from seldon_core_tpu.components.explainer import Explainer
@@ -121,231 +116,3 @@ def test_sklearn_iris_anchor_behind_explain_route(tmp_path, rest_client):
     assert any("petal" in rule for rule in out["anchors"][0]["anchor"])
     assert out["prediction"] == int(clf.predict(iris.data[:1])[0])
 
-
-def test_bench_shared_prefix_scenario_anchor():
-    """The ``llm_1b_shared_prefix`` bench scenario is an acceptance
-    artifact (prefix-cache speedup + greedy-identity are read from the
-    bench output): it must stay wired through the model tier, and the
-    numbers-table generator must know its key — this anchor fails if
-    either silently drops it."""
-    import seldon_core_tpu.modelbench as modelbench
-
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    mb_src = open(modelbench.__file__).read()
-    assert 'results["llm_1b_shared_prefix"]' in mb_src
-    assert hasattr(modelbench, "bench_generate_shared_prefix")
-    gen_src = open(os.path.join(root, "tools", "gen_arch_numbers.py")).read()
-    assert "llm_1b_shared_prefix" in gen_src
-    # bench.py's final stdout line must stay the compact parseable
-    # summary (the harness parses the tail's last line)
-    bench_src = open(os.path.join(root, "bench.py")).read()
-    assert "compact_summary" in bench_src
-
-
-def test_bench_disagg_scenario_anchor():
-    """The ``llm_1b_disagg`` bench scenario is an acceptance artifact
-    (greedy byte-identity of the KV-slab handoff across loopback + TCP,
-    the decode-pool TTFT/TPOT p99 isolation ratios under long-prompt
-    injection, and the ``kv_transfer_bytes_saved`` dedup proof are read
-    from its entry): it must stay wired through BOTH model tiers, and
-    the numbers-table generator must know its key."""
-    import seldon_core_tpu.modelbench as modelbench
-
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    mb_src = open(modelbench.__file__).read()
-    assert mb_src.count('results["llm_1b_disagg"]') >= 2  # tiny + chip
-    assert hasattr(modelbench, "bench_disagg")
-    # the entry asserts the greedy-identity bit like prior scenarios
-    assert '"greedy_identical": identical' in mb_src
-    gen_src = open(os.path.join(root, "tools", "gen_arch_numbers.py")).read()
-    assert "llm_1b_disagg" in gen_src
-
-
-def test_bench_rollout_scenario_anchor():
-    """The ``llm_1b_rollout`` bench scenario is an acceptance artifact
-    (per-step greedy byte-identity of an identical-weights canary, the
-    one-interval auto-rollback proof, and the shadow-mirror overhead are
-    read from its entry): it must stay wired through BOTH model tiers,
-    and the numbers-table generator must know its key."""
-    import seldon_core_tpu.modelbench as modelbench
-
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    mb_src = open(modelbench.__file__).read()
-    assert mb_src.count('results["llm_1b_rollout"]') >= 2  # tiny + chip
-    assert hasattr(modelbench, "bench_rollout")
-    gen_src = open(os.path.join(root, "tools", "gen_arch_numbers.py")).read()
-    assert "llm_1b_rollout" in gen_src
-
-
-def test_bench_chaos_scenario_anchor():
-    """The ``llm_1b_chaos`` bench scenario is an acceptance artifact
-    (greedy byte-identity of every completed request under seeded
-    KV-transport faults + one induced scheduler death, the no-hang
-    bound, and the exercised recovery counters are read from its
-    entry): it must stay wired through BOTH model tiers, and the
-    numbers-table generator must know its key."""
-    import seldon_core_tpu.modelbench as modelbench
-
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    mb_src = open(modelbench.__file__).read()
-    assert mb_src.count('results["llm_1b_chaos"]') >= 2  # tiny + chip
-    assert hasattr(modelbench, "bench_chaos")
-    # the entry asserts the acceptance bits like prior scenarios
-    assert '"greedy_identical": identical' in mb_src
-    assert '"no_hang"' in mb_src
-    gen_src = open(os.path.join(root, "tools", "gen_arch_numbers.py")).read()
-    assert "llm_1b_chaos" in gen_src
-
-
-def test_bench_migration_scenario_anchor():
-    """The ``llm_1b_migration`` bench scenario is an acceptance artifact
-    (byte-identity of a mixed greedy+seeded batch across a mid-decode
-    graceful drain — unary and streaming, zero client failures, no
-    stream span re-sent, counters matching the flight-recorder records
-    — plus the member-kill resume-token proof are read from its entry):
-    it must stay wired through BOTH model tiers, and the numbers-table
-    generator must know its key."""
-    import seldon_core_tpu.modelbench as modelbench
-
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    mb_src = open(modelbench.__file__).read()
-    assert mb_src.count('results["llm_1b_migration"]') >= 2  # tiny + chip
-    assert hasattr(modelbench, "bench_migration")
-    # the entry asserts the acceptance bits like prior scenarios
-    assert '"stream_no_resend": stream_ok' in mb_src
-    assert '"kill_resume_identical": kill_identical' in mb_src
-    assert '"counters_match_flight": counters_match' in mb_src
-    gen_src = open(os.path.join(root, "tools", "gen_arch_numbers.py")).read()
-    assert "llm_1b_migration" in gen_src
-
-
-def test_bench_sharded_scenario_anchor():
-    """The ``llm_1b_sharded`` bench scenario is an acceptance artifact
-    (one checkpoint served 1-device vs mesh-sharded with params + KV
-    resident at 1/N per chip: greedy AND seeded byte-identity probes,
-    sharded vs plain tokens/s and p50 side-by-side with the no-slower
-    verdict, and the per-shard HBM ledger bytes — all read from its
-    entry): it must stay wired through BOTH model tiers, and the
-    numbers-table generator must know its key."""
-    import seldon_core_tpu.modelbench as modelbench
-
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    mb_src = open(modelbench.__file__).read()
-    assert mb_src.count('results["llm_1b_sharded"]') >= 2  # tiny + chip
-    assert hasattr(modelbench, "bench_sharded")
-    # the entry asserts the acceptance bits like prior scenarios
-    assert '"greedy_identical": greedy_identical' in mb_src
-    assert '"sampled_identical": sampled_identical' in mb_src
-    assert '"p50_no_slower"' in mb_src
-    assert '"param_shard_bytes": param_shard_bytes' in mb_src
-    gen_src = open(os.path.join(root, "tools", "gen_arch_numbers.py")).read()
-    assert "llm_1b_sharded" in gen_src
-
-
-def test_bench_kvtier_scenario_anchor():
-    """The ``llm_1b_kvtier`` bench scenario is an acceptance artifact
-    (the spill-vs-destroy proof: tier-off resumes replay tokens, tier-on
-    resumes ride host-tier copy-back with the replay-fallback counter
-    quiet, greedy byte-identity both modes — all read from its entry):
-    it must stay wired through BOTH model tiers, and the numbers-table
-    generator must know its key."""
-    import seldon_core_tpu.modelbench as modelbench
-
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    mb_src = open(modelbench.__file__).read()
-    assert mb_src.count('results["llm_1b_kvtier"]') >= 2  # tiny + chip
-    assert hasattr(modelbench, "bench_kvtier")
-    # the entry asserts the acceptance bits like prior scenarios
-    assert '"greedy_identical": identical' in mb_src
-    assert '"copyback_exercised"' in mb_src
-    gen_src = open(os.path.join(root, "tools", "gen_arch_numbers.py")).read()
-    assert "llm_1b_kvtier" in gen_src
-
-
-def test_bench_pressure_scenario_anchor():
-    """The ``llm_1b_pressure`` bench scenario is an acceptance artifact
-    (byte-identity of greedy AND seeded-sampling outputs across a
-    mid-run HBM-ledger shrink — preemption + recompute-resume — plus
-    the no-hang bound and the preemption-exercised bit are read from
-    its entry): it must stay wired through BOTH model tiers, and the
-    numbers-table generator must know its key."""
-    import seldon_core_tpu.modelbench as modelbench
-
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    mb_src = open(modelbench.__file__).read()
-    assert mb_src.count('results["llm_1b_pressure"]') >= 2  # tiny + chip
-    assert hasattr(modelbench, "bench_pressure")
-    # the entry asserts the acceptance bits like prior scenarios
-    assert '"sampled_identical": sampled_identical' in mb_src
-    assert '"no_hang"' in mb_src
-    gen_src = open(os.path.join(root, "tools", "gen_arch_numbers.py")).read()
-    assert "llm_1b_pressure" in gen_src
-
-
-def test_bench_rag_scenario_anchor():
-    """The ``llm_rag`` bench scenario is an acceptance artifact (fused
-    vs hop-by-hop greedy byte-identity with the generate tail, the
-    fused-no-slower bit, the 3-stages-to-1-dispatch span proof, and the
-    chaos leg's counted fallback are read from its entry): it must stay
-    wired through BOTH model tiers, and the numbers-table generator
-    must know its key."""
-    import seldon_core_tpu.modelbench as modelbench
-
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    mb_src = open(modelbench.__file__).read()
-    assert mb_src.count('results["llm_rag"]') >= 2  # tiny + chip
-    assert hasattr(modelbench, "bench_rag")
-    # the entry asserts the acceptance bits like prior scenarios
-    assert '"greedy_identical": identical' in mb_src
-    assert '"fused_no_slower"' in mb_src
-    assert '"single_dispatch_per_segment": single_dispatch' in mb_src
-    gen_src = open(os.path.join(root, "tools", "gen_arch_numbers.py")).read()
-    assert "llm_rag" in gen_src
-
-
-def test_bench_multitenant_scenario_anchor():
-    """The ``llm_1b_multitenant`` bench scenario is an acceptance
-    artifact (three tenants with distinct checkpoints and SLO classes
-    consolidated onto ONE paged server vs a dedicated server each:
-    per-tenant greedy AND seeded byte-identity probes across
-    demote→promote cycles, Zipf-mix paged-vs-dedicated tokens/s, the
-    per-tenant TTFT p99 split, and the pager/switch counters are read
-    from its entry): it must stay wired through BOTH model tiers, and
-    the numbers-table generator must know its key."""
-    import seldon_core_tpu.modelbench as modelbench
-
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    mb_src = open(modelbench.__file__).read()
-    assert mb_src.count('results["llm_1b_multitenant"]') >= 2  # tiny + chip
-    assert hasattr(modelbench, "bench_multitenant")
-    # the entry asserts the acceptance bits like prior scenarios
-    assert '"greedy_identical": greedy_identical' in mb_src
-    assert '"sampled_identical": sampled_identical' in mb_src
-    assert '"ttft_p99_ms_by_tenant": ttft_p99' in mb_src
-    assert '"page_ins": pager["page_ins"]' in mb_src
-    gen_src = open(os.path.join(root, "tools", "gen_arch_numbers.py")).read()
-    assert "llm_1b_multitenant" in gen_src
-
-
-def test_bench_storm_scenario_anchor():
-    """The ``llm_1b_storm`` bench scenario is an acceptance artifact
-    (one seeded diurnal+burst trafficsim storm replayed against a
-    hand-tuned static config and a mistuned boot the autonomic planner
-    must converge mid-storm through the safe poll-boundary retune
-    path: convergence, greedy byte-identity across the retune, the
-    no-hang bound, and the post-retune TTFT p99 objective are read
-    from its entry): it must stay wired through BOTH model tiers, and
-    the numbers-table generator must know its key."""
-    import seldon_core_tpu.modelbench as modelbench
-
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    mb_src = open(modelbench.__file__).read()
-    assert mb_src.count('results["llm_1b_storm"]') >= 2  # tiny + chip
-    assert hasattr(modelbench, "bench_storm")
-    # the entry asserts the acceptance bits like prior scenarios
-    assert '"greedy_identical": greedy_identical' in mb_src
-    assert '"planner_converged": converged' in mb_src
-    assert '"slo_held": slo_held' in mb_src
-    assert '"retunes_applied"' in mb_src
-    gen_src = open(os.path.join(root, "tools", "gen_arch_numbers.py")).read()
-    assert "llm_1b_storm" in gen_src

@@ -116,7 +116,7 @@ def test_generated_command_actually_serves(pysrc, tmp_path):
     import time
     import urllib.request
 
-    from seldon_core_tpu.modelbench import free_port
+    from seldon_core_tpu.testing import free_port
 
     out = tmp_path / "ctx"
     write_build_context(str(pysrc), str(out), "MyModel")

@@ -31,7 +31,7 @@ def test_parent_side_never_imports_jax(tmp_path):
         "import sys; sys.path.insert(0, {repo!r}); import chip_smoke as s; "
         "s.write_model_and_spec({out!r}, s.FLAGSHIP, s.MESH_SHAPE); "
         "s.bucket_of(24); s.make_prompts(256); s.child_env(False); "
-        "import grpc, seldon_core_tpu.proto, seldon_core_tpu.modelbench; "
+        "import grpc, seldon_core_tpu.proto, seldon_core_tpu.testing; "
         "bad = [m for m in sys.modules if m == 'jax' or m.startswith('jax.')]; "
         "assert not bad, bad"
     ).format(repo=REPO, out=str(tmp_path))
@@ -72,7 +72,6 @@ def test_result_line_has_the_contract_keys_and_no_others():
 
 
 def test_spec_and_model_dir_are_the_flagship(tmp_path):
-    from seldon_core_tpu import modelbench
     from seldon_core_tpu.graph.spec import (
         PredictorSpec, default_predictor, validate_predictor,
     )
@@ -98,11 +97,6 @@ def test_spec_and_model_dir_are_the_flagship(tmp_path):
         "n_heads": 16, "n_kv_heads": 8, "d_ff": 5632,
         "max_seq": 1024, "residual_scale": 0.05,
     }
-    # ... which is modelbench's llm-1.26b, literally
-    src = open(modelbench.__file__).read()
-    assert ('"vocab_size": 32000, "d_model": 2048, "n_layers": 24,\n'
-            '                "n_heads": 16, "n_kv_heads": 8, "d_ff": 5632,\n'
-            '                "max_seq": 1024, "residual_scale": 0.05,') in src
     lm = DecoderLM(**cfg)
     assert lm.cfg.head_dim == 128
     assert 1.2e9 < lm.n_params() < 1.3e9

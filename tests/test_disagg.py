@@ -356,7 +356,7 @@ def test_remote_admit_flight_records_and_stats(model_and_params):
 
 @pytest.fixture(scope="module")
 def model_dir(tmp_path_factory):
-    from seldon_core_tpu.modelbench import write_model_dir
+    from seldon_core_tpu.testing import write_model_dir
 
     root = tmp_path_factory.mktemp("disagg-model")
     return write_model_dir(str(root), "llm", {

@@ -459,7 +459,7 @@ def test_health_status_flips_readiness(model_and_params):
 
 @pytest.fixture(scope="module")
 def model_dir(tmp_path_factory):
-    from seldon_core_tpu.modelbench import write_model_dir
+    from seldon_core_tpu.testing import write_model_dir
 
     root = tmp_path_factory.mktemp("ft-model")
     return write_model_dir(str(root), "llm", {
@@ -502,6 +502,61 @@ def test_decode_degrades_to_local_prefill_byte_identical(model_dir):
     finally:
         for s in (uni, dec):
             s.close()
+
+
+@pytest.fixture(scope="module")
+def two_peer_pool(model_dir):
+    """A decode server in front of two prefill peers over TCP, and the
+    unified server's answers to the same prompts."""
+    from seldon_core_tpu.servers.generateserver import GenerateServer
+    from seldon_core_tpu.serving.disagg import PrefillTransportServer
+
+    body = {"prompt_tokens": [[5, 6, 7, 8], [9, 10, 11], [3, 1, 4, 1, 5]],
+            "max_new_tokens": 6, "temperature": 0.0}
+    uni = GenerateServer(model_uri=model_dir, slots=2, steps_per_poll=4)
+    uni.load()
+    refs = uni.predict(dict(body), [])["tokens"]
+    uni.close()
+    prefills = [GenerateServer(model_uri=model_dir, role="prefill")
+                for _ in range(2)]
+    for pf in prefills:
+        pf.load()
+    listeners = [PrefillTransportServer(pf, port=0) for pf in prefills]
+    addrs = [f"127.0.0.1:{l.port}" for l in listeners]
+    dec = GenerateServer(model_uri=model_dir, slots=2, steps_per_poll=4,
+                         role="decode", peer=",".join(addrs),
+                         peer_eject_backoff_s=30.0)
+    dec.load()
+    yield dec, addrs, body, refs
+    dec.close()
+    for l in listeners:
+        l.close()
+    for pf in prefills:
+        pf.close()
+
+
+@pytest.mark.parametrize("fault,ejects", [
+    (FaultRule(kv_connect_refused_rate=1.0), True),
+    (FaultRule(kv_corrupt_rate=1.0), True),
+    (FaultRule(kv_truncate_rate=1.0), True),
+    (FaultRule(kv_drop_rate=1.0), True),
+    (FaultRule(kv_stall_rate=1.0, kv_stall_ms=50.0), False),
+], ids=["connect_refused", "corrupt", "truncate", "frame_drop", "stall"])
+def test_second_peer_absorbs_a_faulted_first(two_peer_pool, fault, ejects):
+    """Every transfer from the first peer faulted one way: each request
+    still completes byte-identical to the unified server's, through the
+    clean second peer (never by local prefill), and the faulted peer is
+    ejected where the fault breaks the transfer (a stall only delays)."""
+    dec, addrs, body, refs = two_peer_pool
+    dec._kv_client.close()
+    dec.set_peer(",".join(addrs))  # a fresh client: no peer ejected yet
+    first = next(p for p in dec._kv_client.peers if p.addr == addrs[0])
+    first.transport._fault = KVFaults([fault], seed=7, addr=first.addr)
+    before = dict(dec.batcher.stats)
+    assert dec.predict(dict(body), [])["tokens"] == refs
+    after = dec.batcher.stats
+    assert (after["peer_ejections"] > before["peer_ejections"]) is ejects
+    assert after["degraded_local_prefill"] == before["degraded_local_prefill"]
 
 
 def test_stream_midstream_batcher_death_surfaces_typed_no_hang(model_dir):

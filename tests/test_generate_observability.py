@@ -401,23 +401,3 @@ def test_engine_prometheus_end_to_end(shared_server):
     assert "seldon_engine_generate_queue_wait_seconds_bucket" in text
     assert 'unit="gen"' in text
 
-
-def test_modelbench_recorder_probe_and_slo(tmp_path):
-    """bench_generate publishes the SLO phase breakdown and the
-    recorder-on-vs-off probe (overhead field + greedy byte-identity)."""
-    from seldon_core_tpu.modelbench import bench_generate
-
-    out = bench_generate(
-        str(tmp_path), seconds=1.5, concurrency=2, prompt_len=4,
-        max_new_tokens=6, slots=2, steps_per_poll=4,
-        config=dict(CFG), recorder_probe=True,
-    )
-    slo = out["slo"]
-    assert slo["samples"] > 0
-    for phase in ("queue_wait_ms", "ttft_ms", "tpot_ms"):
-        assert {"p50_ms", "p99_ms", "mean_ms"} <= set(slo[phase])
-    probe = out["flight_recorder_probe"]
-    assert probe["greedy_identical"] is True
-    assert "overhead_pct" in probe
-    assert probe["recorder_on_tokens_per_s"] > 0
-    assert probe["recorder_off_tokens_per_s"] > 0

@@ -905,7 +905,7 @@ def test_engine_grpc_generate_e2e(tmp_path):
     gRPC external API shape carrying the TPU-native generate payload."""
     import grpc
 
-    from seldon_core_tpu.modelbench import EngineHarness
+    from seldon_core_tpu.testing import EngineHarness
     from seldon_core_tpu.proto import prediction_pb2 as pb
     from seldon_core_tpu.proto.services import method_path
     from seldon_core_tpu.servers.generateserver import GenerateServer
@@ -943,7 +943,7 @@ def test_streaming_generate_over_sse(tmp_path):
     event before done) and an exact final payload."""
     import http.client
 
-    from seldon_core_tpu.modelbench import EngineHarness
+    from seldon_core_tpu.testing import EngineHarness
     from seldon_core_tpu.servers.generateserver import GenerateServer
 
     d = tmp_path / "llm"
@@ -989,7 +989,7 @@ def test_streaming_rejects_batch_and_multinode(tmp_path):
     truncated stream), and a non-generate graph 501s."""
     import http.client
 
-    from seldon_core_tpu.modelbench import EngineHarness
+    from seldon_core_tpu.testing import EngineHarness
     from seldon_core_tpu.servers.generateserver import GenerateServer
     from seldon_core_tpu.user_model import SeldonComponent
 
@@ -1040,7 +1040,7 @@ def test_streaming_disconnect_cancels_request(tmp_path):
     import http.client
     import time
 
-    from seldon_core_tpu.modelbench import EngineHarness
+    from seldon_core_tpu.testing import EngineHarness
     from seldon_core_tpu.servers.generateserver import GenerateServer
 
     d = tmp_path / "llm"
@@ -1080,7 +1080,7 @@ def test_streaming_generate_over_grpc(tmp_path):
     responses concatenate to the unary result."""
     import grpc
 
-    from seldon_core_tpu.modelbench import EngineHarness
+    from seldon_core_tpu.testing import EngineHarness
     from seldon_core_tpu.payload import proto_to_json
     from seldon_core_tpu.proto import prediction_pb2 as pb
     from seldon_core_tpu.proto.services import method_path

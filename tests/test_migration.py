@@ -489,7 +489,7 @@ def test_bad_swap_resume_policy_rejected(model_and_params):
 
 @pytest.fixture(scope="module")
 def model_dir(tmp_path_factory):
-    from seldon_core_tpu.modelbench import write_model_dir
+    from seldon_core_tpu.testing import write_model_dir
 
     root = tmp_path_factory.mktemp("mig-model")
     return write_model_dir(str(root), "llm", {
@@ -671,7 +671,7 @@ def test_engine_drain_route_tcp(model_dir):
     import http.client
     import json as _json
 
-    from seldon_core_tpu.modelbench import EngineHarness
+    from seldon_core_tpu.testing import EngineHarness
 
     prompt = [1, 3, 5, 7]
     kw = dict(max_new_tokens=24, temperature=0.8, eos_id=None, seed=6)

@@ -137,3 +137,34 @@ def test_vit_serves_through_jaxserver(tmp_path):
     out = np.asarray(s.predict(img, []))
     assert out.shape == (2, 3)
     assert np.isfinite(out).all()
+
+
+def test_flops_analytics_sane():
+    from seldon_core_tpu.models.bert import BertClassifier
+    from seldon_core_tpu.models.llm import DecoderLM
+    from seldon_core_tpu.models.resnet import ResNet50
+
+    # ResNet-50 @224 is ~8.2 GFLOP under the 2xMAC convention
+    assert 7.5e9 < ResNet50().flops_per_row() < 9.0e9
+    # BERT-base @128 tokens ~22 GFLOP
+    assert 18e9 < BertClassifier().flops_per_row(128) < 26e9
+    lm = DecoderLM()
+    assert lm.flops_per_token(64) > 0
+    assert lm.flops_per_row(64) > lm.flops_per_token(64)
+
+
+def test_n_params_matches_pytree():
+    import jax
+
+    from seldon_core_tpu.models.llm import DecoderLM
+
+    for cfg in (
+        dict(vocab_size=128, d_model=32, n_layers=2, n_heads=2, n_kv_heads=2, d_ff=64),
+        dict(vocab_size=64, d_model=16, n_layers=2, n_heads=2, n_kv_heads=1,
+             d_ff=32, n_experts=2),
+    ):
+        m = DecoderLM(**cfg)
+        counted = sum(
+            np.prod(a.shape) for a in jax.tree_util.tree_leaves(m.init_params(0))
+        )
+        assert m.n_params() == counted, cfg

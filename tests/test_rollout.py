@@ -676,6 +676,51 @@ def test_mirror_without_event_loop_drops_safely():
     assert "recent_divergences" in m.summary()
 
 
+# -- the gates read the engines' own counters -----------------------------------
+
+
+@pytest.mark.parametrize("canary_fails,verdict,restored", [
+    (False, "promote", {"baseline": 0, "canary": 100}),
+    (True, "rollback", {"baseline": 100, "canary": 0}),
+], ids=["healthy", "broken"])
+def test_gates_read_what_the_engines_counted(canary_fails, verdict, restored):
+    """No hand-fed registry: two engines on sockets, named as the
+    predictors, count their own requests and errors under the label the
+    controller reads. A healthy window promotes; a canary that answers
+    500 is rolled back in the tick that sees it."""
+    from _net import post_predictions
+
+    from seldon_core_tpu.testing import EngineHarness
+    from seldon_core_tpu.user_model import SeldonComponent
+
+    class Unit(SeldonComponent):
+        def __init__(self, fails):
+            self.fails = fails
+
+        def predict(self, X, names, meta=None):
+            if self.fails:
+                raise RuntimeError("canary is broken")
+            return X
+
+    def post(port):
+        return post_predictions(port, b'{"data": {"ndarray": [[1.0]]}}')[0]
+
+    ctl, store, clock, reg = make_ctl(rollout_dep(steps="50,100", interval="5"))
+    baseline = EngineHarness(Unit(False), name="baseline", metrics=reg).start()
+    canary = EngineHarness(Unit(canary_fails), name="canary", metrics=reg).start()
+    try:
+        assert ctl.tick_all() == {"default/dep": "start"}
+        for _ in range(4):
+            assert post(baseline.http_port) == 200
+            assert (post(canary.http_port) >= 500) is canary_fails
+        clock.t += 5.0
+        assert ctl.tick_all() == {"default/dep": verdict}
+        assert weights(store) == restored
+    finally:
+        baseline.stop()
+        canary.stop()
+
+
 # -- control-plane integration ----------------------------------------------
 
 
@@ -1042,7 +1087,7 @@ def test_prefix_index_set_version_purges_and_rekeys():
 
 
 def _tiny_model_dir(root):
-    from seldon_core_tpu.modelbench import write_model_dir
+    from seldon_core_tpu.testing import write_model_dir
 
     return write_model_dir(str(root), "llm", {
         "vocab_size": 256, "d_model": 32, "n_layers": 2, "n_heads": 2,
@@ -1054,7 +1099,7 @@ def test_generateserver_hot_swap_rejects_then_swaps(tmp_path):
     """One served component, both hot_swap outcomes: a different-arch
     checkpoint is rejected without touching serving, then the same
     checkpoint swaps in byte-identically."""
-    from seldon_core_tpu.modelbench import write_model_dir
+    from seldon_core_tpu.testing import write_model_dir
     from seldon_core_tpu.servers.generateserver import GenerateServer
 
     model_dir = _tiny_model_dir(tmp_path)
@@ -1088,7 +1133,7 @@ def test_generateserver_hot_swap_rejects_then_swaps(tmp_path):
 def test_engine_weights_swap_route(tmp_path):
     import http.client
 
-    from seldon_core_tpu.modelbench import EngineHarness
+    from seldon_core_tpu.testing import EngineHarness
     from seldon_core_tpu.servers.generateserver import GenerateServer
 
     model_dir = _tiny_model_dir(tmp_path)
@@ -1140,7 +1185,7 @@ def test_engine_weights_swap_route(tmp_path):
 def test_engine_weights_swap_route_501_without_support():
     import http.client
 
-    from seldon_core_tpu.modelbench import EngineHarness
+    from seldon_core_tpu.testing import EngineHarness
     from seldon_core_tpu.user_model import SeldonComponent
 
     class Plain(SeldonComponent):

@@ -15,10 +15,10 @@ import numpy as np
 import pytest
 import urllib.request
 
-from seldon_core_tpu.modelbench import EngineHarness
 from seldon_core_tpu.payload import json_to_proto, proto_to_json
 from seldon_core_tpu.proto import prediction_pb2 as pb
 from seldon_core_tpu.proto.services import method_path
+from seldon_core_tpu.testing import EngineHarness, free_port
 from seldon_core_tpu.user_model import SeldonComponent
 
 
@@ -185,14 +185,6 @@ def grpc_feedback(harness, fb):
     return proto_to_json(out)
 
 
-def _free_port():
-    s = socket.socket()
-    s.bind(("127.0.0.1", 0))
-    port = s.getsockname()[1]
-    s.close()
-    return port
-
-
 def _wait_port(port, timeout=5.0):
     t0 = time.time()
     while time.time() - t0 < timeout:
@@ -283,7 +275,7 @@ def test_native_and_python_engines_agree(tmp_path):
         return data["ndarray"]
 
     for spec_dict, body in [(s_, b_) for s_ in specs for b_ in bodies]:
-        port = _free_port()
+        port = free_port()
         with NativeEngine(spec_dict, port=port):
             _wait_port(port)
             status, native = _post(port, "/api/v0.1/predictions", body)

@@ -39,9 +39,9 @@ def main() -> int:
     import http.client
 
     from seldon_core_tpu.graph.engine_metrics import REGISTRY
-    from seldon_core_tpu.modelbench import EngineHarness, write_model_dir
-    from seldon_core_tpu.serving.disagg import PrefillTransportServer
     from seldon_core_tpu.servers.generateserver import GenerateServer
+    from seldon_core_tpu.serving.disagg import PrefillTransportServer
+    from seldon_core_tpu.testing import EngineHarness, write_model_dir
 
     failures = []
 

@@ -36,8 +36,14 @@ def _scrub(payload: dict) -> dict:
     meta = payload.get("meta") or {}
     meta.pop("puid", None)
     if "metrics" in meta:
+        from seldon_core_tpu.servers.generateserver import COMPILE_TELEMETRY_KEYS
+
+        # wall time is not data, nor is what XLA compiled when: a shape's
+        # first call compiles, whichever engine makes it
         meta["metrics"] = [
-            m for m in meta["metrics"] if m.get("type") != "TIMER"
+            m for m in meta["metrics"]
+            if m.get("type") != "TIMER"
+            and m.get("key") not in COMPILE_TELEMETRY_KEYS
         ]
     return payload
 
@@ -50,10 +56,10 @@ def main() -> int:
     import numpy as np
 
     from seldon_core_tpu.graph.units import RagPromptBuilder
-    from seldon_core_tpu.modelbench import EngineHarness, write_model_dir
     from seldon_core_tpu.resilience.faults import FaultInjector
     from seldon_core_tpu.servers.generateserver import GenerateServer
     from seldon_core_tpu.servers.jaxserver import JAXServer
+    from seldon_core_tpu.testing import EngineHarness, write_model_dir
 
     failures = []
 

@@ -37,8 +37,8 @@ def main() -> int:
     sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
     import http.client
 
-    from seldon_core_tpu.modelbench import EngineHarness, write_model_dir
     from seldon_core_tpu.servers.generateserver import GenerateServer
+    from seldon_core_tpu.testing import EngineHarness, write_model_dir
     from seldon_core_tpu.tracing import get_tracer, init_tracer
 
     init_tracer("obs-smoke", enabled=True)

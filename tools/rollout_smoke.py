@@ -37,9 +37,9 @@ def main() -> int:
 
     from seldon_core_tpu.controlplane import ResourceStore, SeldonDeployment
     from seldon_core_tpu.graph.engine_metrics import REGISTRY
-    from seldon_core_tpu.modelbench import EngineHarness, write_model_dir
     from seldon_core_tpu.rollout import RolloutController, ShadowMirror
     from seldon_core_tpu.servers.generateserver import GenerateServer
+    from seldon_core_tpu.testing import EngineHarness, write_model_dir
 
     failures = []
 
