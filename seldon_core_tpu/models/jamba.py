@@ -558,8 +558,10 @@ class JambaLM(DecoderFamily):
         """``kv_rows_read`` of one step, as the lfm2_moe block counts it:
         what ``decode_attention`` streams of K and V over the attention
         layers, by the lowering that runs: the kernel walks each lane's own
-        length in whole blocks (``walk_block`` asked), the dots read the
-        static bound of EVERY lane."""
+        length in whole blocks (``walk_block`` asked: 1,024 keys at this
+        block's ONE KV head of 128 in bfloat16, whose K and V are the 512 KiB
+        that cover an iteration's chain), the dots read the static bound of
+        EVERY lane."""
         import jax.numpy as jnp
         from jax import lax
 

@@ -25,7 +25,8 @@ The earlier kernel that lost here (23.7 ms a step against the dots' 6.0,
 ``[block_k, Dh]`` chunks per head: program overhead x (layers x lanes x
 chunks). This one is ONE program per layer: the walk over lanes and
 over a lane's blocks are loops inside it, a block is ``block`` positions
-x all KV heads (512 KB a K and V pair or more: ``walk_block``),
+x all KV heads (``walk_block``: 128 keys doubled until a K and V pair
+copies 512 KiB, 1,024 keys at most),
 double-buffered across lane boundaries, so the next lane's first block
 is in flight while this lane's last one is computed.
 
@@ -98,31 +99,44 @@ GROUP = 8
 #   at Mistral's lengths 0.245-0.251 / 0.246-0.256 / 0.269-0.274 (at
 #   InternLM's 28 lanes of 65-1024 alone 0.116-0.119 / 0.101-0.103 /
 #   0.098-0.100, which PR 30's burst above did not see: PERF.md section 7).
-# So the rule is on the BYTES of a 128-key block: under ``COVERS`` the walk
-# takes ``WIDE_BLOCK`` keys.
+# - PR 55 (call 4), ONE KV head of 128 (128 keys: 64 KiB, 0.08 us), one
+#   position a lane, alone at jamba's 192 lanes of 90-2,281 of a cache of 8192,
+#   ms a call at 128 / 256 / 512 / 1,024: 0.862 / 0.585 / 0.461 / 0.406: the
+#   chain, not the copy, sets the pace until the block copies ``COVERS``.
+# - PR 61 (call 1), the same kernel IN the cell (jamba2-3b.thinking: two calls
+#   a step, ~189 live lanes at a mean of ~1,050 keys; two traced runs each on
+#   one machine), us a call at 256 / 512 / 1,024: 443.0, 439.1 / 284.3, 277.4 /
+#   241.9, 235.1, streaming 1.12 / 1.24 / 1.49 of the lanes' live rows: 512 is
+#   18% slower than 1,024 (PR 50's bar was 3%), so the ceiling is 1,024, where
+#   one KV head's copy is ``COVERS`` and the kernel reads 75-81% of the rate.
+# So the rule is on the BYTES a block copies: the block doubles from ``BLOCK``
+# while its own copy is under ``COVERS``, to ``CEILING`` keys at most (no cell
+# holds rows smaller than one KV head's: past 1,024 keys nothing was measured).
 COVERS = 512 * 1024
-WIDE_BLOCK = 256
+CEILING = 1024
 
 
 def walk_block(kv_heads: int, head_dim: int, dtype, t: int,
                v_dim: int = None) -> int:
     """Positions a block of the kernel's walk holds, for a cache [B,
-    ``kv_heads``, ``t``, ``head_dim``] of ``dtype``: ``WIDE_BLOCK`` where
-    ``BLOCK`` keys of K and V are under ``COVERS`` bytes (their copy would
-    not cover the iteration's chain) and ``WIDE_BLOCK`` divides the
-    cache's length, else ``BLOCK``. A rule on the call's shapes alone.
+    ``kv_heads``, ``t``, ``head_dim``] of ``dtype``: from ``BLOCK`` the block
+    doubles while its own K and V are under ``COVERS`` bytes (their copy
+    would not cover the iteration's chain) and the doubled block divides
+    the cache's length, up to ``CEILING``. A rule on the call's shapes alone.
     Whoever states what the kernel streams (a counter, a roofline's
     bytes, the scheduler's ``kv_positions_read``) asks here. ``v_dim``: a
     value row's width where it is not the key row's. ``copied`` counts what
-    a block's two copies MOVE: ``head_dim`` is what a KV head's key
+    a block's two copies MOVE a position: ``head_dim`` is what a KV head's key
     occupies of the K array, the row's width where a key is padded to one
     and the key's own where its rest is packed (``packed_key_rows``: a
     head of 192 in a row of 128 and half a row is 192 wide here)."""
     copied = (kv_heads * (head_dim + (v_dim or head_dim))
-              * jnp.dtype(dtype).itemsize * BLOCK)
-    if copied < COVERS and t % WIDE_BLOCK == 0:
-        return WIDE_BLOCK
-    return BLOCK
+              * jnp.dtype(dtype).itemsize)
+    block = BLOCK
+    while (block < CEILING and copied * block < COVERS
+           and t % (2 * block) == 0):
+        block *= 2
+    return block
 
 
 def packed_key_rows(head_dim: int, kv_heads: int) -> int:

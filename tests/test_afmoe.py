@@ -154,7 +154,7 @@ def _kernel_case(seed, lens, window, lanes=5, heads=8, kv=1, t=1024):
     return q, k, v, k_new, v_new, lens, wp, jnp.maximum(0, lens - window)
 
 
-@pytest.mark.parametrize("block", [None, 128], ids=["rule", "128"])
+@pytest.mark.parametrize("block", [None, 256, 128], ids=["rule", "256", "128"])
 @pytest.mark.parametrize("seed, lens, window", [
     (0, [700, 0, 1024, 130, 257], 256),     # starts inside, at and off a block
     (1, [256, 255, 1, 0, 640], 256),        # window not yet full; one key
@@ -163,12 +163,13 @@ def _kernel_case(seed, lens, window, lanes=5, heads=8, kv=1, t=1024):
 def test_the_ragged_kernel_with_starts_against_the_masked_dots(
         seed, lens, window, block):
     """Interpreted, as PR 30's tests run the kernel, under the block the
-    rule gives the call (256: one KV head of 128) and under 128: the caches
+    rule gives the call (1,024, this whole cache: one KV head of 128 in
+    bfloat16), under the cell's 256 and under 128: the caches
     bit for bit the scatter's, the read the masked dots' to bfloat16
     rounding, and a lane's blocks left of its window never asked for."""
     q, k, v, k_new, v_new, lens, wp, starts = _kernel_case(seed, lens, window)
     walked = block or walk_block(k.shape[1], k.shape[3], k.dtype, k.shape[2])
-    assert walked == (block or 256)
+    assert walked == (block or 1024)
     o, k2, v2 = ragged_decode_attention(
         q, k, v, lens, k_new, v_new, wp, interpret=True, starts=starts,
         block=block)

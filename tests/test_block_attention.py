@@ -31,18 +31,21 @@ F32, BF16 = jnp.float32, jnp.bfloat16
 # (positions a lane, heads a KV head, cache length, dtype, block: None is
 # the rule's own, ``walk_block``, KV heads of 128); the block the rule gives
 WALKS = [
-    # 2 KV heads of 128: 128 keys of K and V are 128 KiB in bfloat16 and
-    # 256 in float32, so the rule's block is the wide one, whatever the
+    # 2 KV heads of 128: 128 keys of K and V are 256 KiB in float32, so the
+    # rule's block is 256, and 128 KiB in bfloat16, where it doubles once
+    # more (512 keys copy 512 KiB) in a cache that 512 divides; whatever the
     # query rows a KV head brings (6 to 24 here); under 128 too
-    *[((w, REP, T, dtype, block, KV), 256) for w in (2, 4, 8)
+    *[((w, REP, T, dtype, block, KV), 256 if dtype is F32 else 512)
+      for w in (2, 4, 8)
       for dtype in (F32, BF16) for block in (None, BLOCK)],
-    # the block pass's 32 rows (8 heads x 4 positions, 4 x 8); 512, which
-    # the chip read no faster, walks too
-    *[((4, 8, 1024, dtype, block, KV), 256) for dtype in (F32, BF16)
-      for block in (None, 512, BLOCK)],
-    ((8, 4, 1024, BF16, None, KV), 256),
+    # the block pass's 32 rows (8 heads x 4 positions, 4 x 8); under 512
+    # (which at the sdar cell's 4 KV heads the chip read no faster) and 128
+    *[((4, 8, 1024, dtype, block, KV), 256 if dtype is F32 else 512)
+      for dtype in (F32, BF16) for block in (None, 512, BLOCK)],
+    ((8, 4, 1024, BF16, None, KV), 512),
+    # a cache that 256 divides and 512 does not
     ((4, 8, 768, BF16, None, KV), 256),
-    # a cache the wide block does not divide
+    # a cache that only 128 divides
     ((4, 8, 640, BF16, None, KV), BLOCK),
     # 128 keys of 512 KiB (8 KV heads in bfloat16, 4 in float32): their
     # copy covers the chain, the rule's block is 128
@@ -104,13 +107,15 @@ def test_the_block_kernel_is_the_scatter_and_the_dots(walk, ruled):
     # the block pass of the sdar cell: 4 KV heads of 128 in bfloat16 (128
     # keys of K and V: 256 KiB), whatever the cache that 256 divides
     (4, 128, BF16, 4096, 256), (4, 128, BF16, 2048, 256), (4, 128, BF16, 768, 256),
-    # fewer bytes still
-    (2, 128, BF16, 4096, 256), (1, 128, BF16, 256, 256), (2, 256, BF16, 4096, 256),
+    # fewer bytes still: the block doubles until its copy is 512 KiB, in a
+    # cache the doubled block divides
+    (2, 128, BF16, 4096, 512), (1, 128, BF16, 256, 256), (2, 256, BF16, 4096, 256),
     (7, 128, BF16, 1024, 256), (2, 128, F32, 1024, 256),
+    (1, 128, BF16, 4096, 1024), (2, 128, BF16, 768, 256),
     # 128 keys of 512 KiB or more: the copy covers the chain
     (8, 128, BF16, 4096, BLOCK), (4, 256, BF16, 4096, BLOCK),
     (4, 128, F32, 4096, BLOCK), (16, 128, BF16, 2048, BLOCK),
-    # a cache the wide block does not divide
+    # a cache 256 does not divide
     (4, 128, BF16, 640, BLOCK), (4, 128, BF16, 128, BLOCK), (4, 128, BF16, 1152, BLOCK),
 ])
 def test_the_walks_block_follows_the_bytes_a_block_copies(kv, dh, dtype, t, block):

@@ -17,7 +17,8 @@ import pytest
 from seldon_core_tpu.models.llm import DecoderLM
 from seldon_core_tpu.ops.decode_attention import (
     BLOCK,
-    WIDE_BLOCK,
+    CEILING,
+    COVERS,
     cache_attention,
     cache_write,
     decode_attention,
@@ -144,32 +145,58 @@ def test_kernel_block_sizes_agree(block, write):
     assert float(err.max()) <= 2 ** -6
 
 
+# the cells' block at 4 KV heads of 128 and at 2 of 256 in bfloat16: twice 128
+WIDE = 2 * BLOCK
+
+
 @pytest.mark.parametrize("why,kv,dh,dtype,t,block", [
-    # the decode shapes of the benchmark's seven configurations
+    # the decode shapes of the benchmark's configurations
     # (benchmark/configs/*.json: KV heads, head size, the cache's dtype,
     # server.max_seq): 128 keys of K and V over 8 KV heads of 128 are 512
     # KiB, a copy that covers the walk's chain
     ("internlm2-1.8b", 8, 128, "bfloat16", 2048, BLOCK),
     ("mistral-7b-v0.3", 8, 128, "bfloat16", 2048, BLOCK),
-    # 256 KiB: the wide block
-    ("trinity-mini", 4, 128, "bfloat16", 4096, WIDE_BLOCK),
-    ("qwen3-next-80b-a3b", 2, 256, "bfloat16", 4096, WIDE_BLOCK),
-    ("sdar-30b-a3b", 4, 128, "bfloat16", 4096, WIDE_BLOCK),
+    # 256 KiB: the block doubles once, and its copy is 512 KiB
+    ("trinity-mini", 4, 128, "bfloat16", 4096, WIDE),
+    ("qwen3-next-80b-a3b", 2, 256, "bfloat16", 4096, WIDE),
+    ("sdar-30b-a3b", 4, 128, "bfloat16", 4096, WIDE),
     # kernels of their own; the batcher's count asks for them all the same
     ("joyai-llm-flash", 32, 64, "bfloat16", 6144, BLOCK),
     ("evabyte", 32, 128, "bfloat16", 16384, BLOCK),
     ("a cache length 256 does not divide", 4, 128, "bfloat16", 4096 - 128, BLOCK),
     ("a float32 cache of 4 KV heads of 128", 4, 128, "float32", 4096, BLOCK),
-    ("a float32 cache of 2 KV heads of 128", 2, 128, "float32", 4096, WIDE_BLOCK),
-    ("an 8-bit cache of 8 KV heads of 128", 8, 128, "int8", 4096, WIDE_BLOCK),
-    ("7 KV heads of 128: 448 KiB", 7, 128, jnp.bfloat16, 2048, WIDE_BLOCK),
+    ("a float32 cache of 2 KV heads of 128", 2, 128, "float32", 4096, WIDE),
+    ("an 8-bit cache of 8 KV heads of 128", 8, 128, "int8", 4096, WIDE),
+    ("7 KV heads of 128: 448 KiB", 7, 128, jnp.bfloat16, 2048, WIDE),
+    # ONE KV head of 128 in bfloat16 (jamba2-3b): 64 KiB a 128 keys, the block
+    # doubles three times, to the ceiling, whose copy is ``COVERS`` itself
+    ("jamba2-3b", 1, 128, "bfloat16", 8192, CEILING),
+    ("one KV head, a cache that 256 divides and 512 does not", 1, 128,
+     "bfloat16", 2304, WIDE),
+    ("one KV head, a cache that 512 divides and 1,024 does not", 1, 128,
+     "bfloat16", 1536, 512),
+    ("a float32 cache of one KV head: half the keys", 1, 128, "float32", 8192,
+     CEILING // 2),
+    ("2 KV heads of 128: a copy exactly at COVERS stops", 2, 128, "bfloat16",
+     8192, 512),
+    ("3 KV heads of 128: 512 keys copy 768 KiB, 256 under COVERS", 3, 128,
+     "bfloat16", 8192, 512),
+    ("rows far under COVERS: the ceiling, no further", 1, 128, "int8", 8192,
+     CEILING),
+    ("a tiny model's rows: the ceiling where it divides", 2, 8, "float32", 4096,
+     CEILING),
 ])
 def test_the_walks_block_is_set_by_the_bytes_a_block_copies(
         why, kv, dh, dtype, t, block):
     """``walk_block``: ONE rule on the call's shapes, for both of the
-    kernel's entries, the scheduler's count and the sdar family's."""
+    kernel's entries, the scheduler's count and the sdar family's: the
+    block doubles from 128 while its own copy of K and V is under
+    ``COVERS`` and the doubled block divides the cache, to ``CEILING``."""
     assert walk_block(kv, dh, dtype, t) == block, why
-    assert t % block == 0
+    assert t % block == 0 and BLOCK <= block <= CEILING
+    copied = 2 * kv * dh * jnp.dtype(dtype).itemsize
+    # no block the rule doubled was already a copy that covers the chain
+    assert block == BLOCK or copied * (block // 2) < COVERS, why
 
 
 def test_the_rule_answers_for_the_configurations_as_the_benchmark_holds_them():
@@ -187,46 +214,49 @@ def test_the_rule_answers_for_the_configurations_as_the_benchmark_holds_them():
             c["num_key_value_heads"], dh, c["torch_dtype"], c["server"]["max_seq"])
     assert got == {
         "internlm2-1.8b": BLOCK, "mistral-7b-v0.3": BLOCK, "evabyte": BLOCK,
-        "joyai-llm-flash": BLOCK, "trinity-mini": WIDE_BLOCK,
-        "qwen3-next-80b-a3b": WIDE_BLOCK, "sdar-30b-a3b": WIDE_BLOCK,
+        "joyai-llm-flash": BLOCK, "trinity-mini": WIDE,
+        "qwen3-next-80b-a3b": WIDE, "sdar-30b-a3b": WIDE,
         # 8 KV heads of 64 are 4 rows of 128: 256 KiB a 128 keys, as the
         # cache lies (two heads a row) and as the file states it
-        "lfm2-24b-a2b": WIDE_BLOCK,
-        # ONE KV head of 128: 64 KiB a 128 keys, the rule's wide block (a
-        # wider one still is faster there: PERF.md section 5, ROADMAP S19 b)
-        "jamba2-3b": WIDE_BLOCK,
+        "lfm2-24b-a2b": WIDE,
+        # ONE KV head of 128: 64 KiB a 128 keys, 512 KiB (``COVERS``) a 1,024:
+        # the ceiling (PR 61; PERF.md section 5)
+        "jamba2-3b": CEILING,
         # the FULL layers' 4 KV heads, keys of 192 as the file states them:
         # 384 KiB a 128 keys; as the cache holds them (key rows of 256
         # beside values of 128) the same bytes and the same answer
-        "mimo-v2.5": WIDE_BLOCK}
-    assert walk_block(4, 256, "bfloat16", 12288, 128) == WIDE_BLOCK
+        "mimo-v2.5": WIDE}
+    assert CEILING * 1 * (128 + 128) * 2 == COVERS      # jamba's block's copy
+    assert walk_block(4, 256, "bfloat16", 12288, 128) == WIDE
     # a ring of 128 rows is one block of the walk, whatever its bytes
     assert walk_block(8, 256, "bfloat16", 128, 128) == BLOCK
 
 
-# lanes of the one-position call at the rule's 256 keys a block: (length,
-# write position). Under 256, on its edges, no multiple of it, the whole
-# cache; a write in the walk's last block (a step's: ``len - 1``), in a
-# block the read does not hold, parked; an idle lane
-_WIDE_T = 4 * WIDE_BLOCK
+# lanes of the one-position call at the rule's block for 2 KV heads of 128 in
+# a cache of 1,024 (256 keys in float32, 512 in bfloat16): (length, write
+# position). Under 256, on its and on 512's edges, no multiple of either, the
+# whole cache; a write in the walk's last block (a step's: ``len - 1``), in
+# a block the read does not hold, parked; an idle lane
+_WIDE_T = 4 * WIDE
 _WIDE_LANES = [
     (0, 70), (1, 0), (72, 71), (255, 254), (256, 255), (257, 256),
-    (300, 299), (511, 510), (513, 512), (700, 699), (_WIDE_T, _WIDE_T - 1),
-    (640, 900), (72, 600), (530, 3), (333, _WIDE_T),
+    (300, 299), (511, 510), (512, 511), (513, 512), (700, 699),
+    (_WIDE_T, _WIDE_T - 1), (640, 900), (72, 600), (530, 3), (333, _WIDE_T),
 ]
 
 
 @pytest.mark.parametrize("window", [None, 200, 256, 300, 600])
-@pytest.mark.parametrize("dtype,tol", [("float32", 1e-5), ("bfloat16", 2 ** -6)])
-def test_the_one_position_kernel_at_the_rules_wide_block(dtype, tol, window):
-    """No ``block=``: the call takes ``walk_block``'s 256 for its 2 KV heads
-    of 128. With and without ``starts`` (a window whose start lies inside a
+@pytest.mark.parametrize("dtype,tol,ruled", [
+    ("float32", 1e-5, WIDE), ("bfloat16", 2 ** -6, 2 * WIDE)])
+def test_the_one_position_kernel_at_the_rules_wide_block(dtype, tol, ruled, window):
+    """No ``block=``: the call takes ``walk_block``'s answer for its 2 KV
+    heads of 128. With and without ``starts`` (a window whose start lies inside a
     block, on its edge, and before the cache's first position): the output
     is the masked dots' over the cache the scatter made, both caches are
     that cache bit for bit, an idle lane gives zeros and writes nothing."""
     dtype = jnp.dtype(dtype)
     q, k, v = _inputs(4, dtype, t=_WIDE_T, lanes=len(_WIDE_LANES), seed=7)
-    assert walk_block(k.shape[1], k.shape[3], k.dtype, _WIDE_T) == WIDE_BLOCK
+    assert walk_block(k.shape[1], k.shape[3], k.dtype, _WIDE_T) == ruled
     lens, wp = (jnp.asarray(a, jnp.int32) for a in zip(*_WIDE_LANES))
     starts = None if window is None else jnp.maximum(0, lens - window)
     k_new, v_new = _rows(k)
@@ -246,6 +276,50 @@ def test_the_one_position_kernel_at_the_rules_wide_block(dtype, tol, window):
         block=BLOCK)
     assert np.array_equal(_f32(nk), _f32(gk)) and np.array_equal(_f32(nv), _f32(gv))
     assert float(np.abs(_f32(narrow) - _f32(got)).max()) <= tol
+
+
+def _one_kv_head_lanes(block):
+    """(length, write position) a lane, for a cache of ``2 x CEILING``: an
+    idle lane, one key, both sides of 1,024 and of the block, the whole
+    cache; every step's write in the last block read (``land``), then a
+    write in a block read earlier, in one not read (``land_unread``), and
+    a parked one."""
+    t = 2 * CEILING
+    steps = [1, block - 1, block, block + 1, 1023, 1024, 1025, 2 * block, t]
+    return [(0, 70), *((n, n - 1) for n in steps),
+            (block + 30, 3), (40, block + 500), (72, t - 1), (333, t)]
+
+
+@pytest.mark.parametrize("block", [512, CEILING])
+def test_one_kv_head_of_128_walks_blocks_of_512_and_of_1024(block):
+    """Jamba's call (ONE KV head of 128, 20 query heads, bfloat16) at the
+    two blocks the chip chose between, interpreted against the scatter and
+    the dots: outputs within one bfloat16 step of the lane's largest,
+    caches bit for bit, the idle lane zeros and unwritten; and the rule's
+    own answer for this cache is the ceiling."""
+    t = 2 * CEILING
+    lanes = _one_kv_head_lanes(block)
+    q, k, v = _inputs(20, jnp.bfloat16, t=t, lanes=len(lanes), kv=1, seed=61)
+    assert walk_block(1, 128, k.dtype, t) == CEILING
+    lens, wp = (jnp.asarray(a, jnp.int32) for a in zip(*lanes))
+    k_new, v_new = _rows(k)
+    got, gk, gv = ragged_decode_attention(
+        q, k, v, lens, k_new, v_new, wp, interpret=True,
+        block=None if block == CEILING else block)     # None: the rule's own
+    live = np.asarray(lens) > 0
+    at = jnp.where(lens > 0, wp, t)[:, None]
+    sk, sv = cache_write(k, k_new, at), cache_write(v, v_new, at)
+    assert np.array_equal(_f32(gk), _f32(sk)) and np.array_equal(_f32(gv), _f32(sv))
+    # the unread write landed, the parked one and the idle lane's did not
+    assert not np.array_equal(_f32(gk[-3]), _f32(k[-3]))
+    assert np.array_equal(_f32(gk[-1]), _f32(k[-1]))
+    assert np.array_equal(_f32(gk[0]), _f32(k[0]))
+    want = _f32(cache_attention(q, sk, sv, lens - 1, q.dtype))
+    err = np.abs(_f32(got) - want).max(axis=(1, 2, 3))
+    largest = np.abs(want).max(axis=(1, 2, 3))
+    step = 2.0 ** (np.floor(np.log2(largest)) - 7)     # bfloat16: 8 bits
+    assert (err[live] <= step[live]).all(), (err, step)
+    assert not _f32(got)[~live].any()
 
 
 @pytest.mark.parametrize("rep", [2, 4])
@@ -595,13 +669,17 @@ def test_model_step_through_the_kernel(monkeypatch):
     (BLOCK - 2, 4, 4 * BLOCK, BLOCK, 2 * BLOCK + 2 * 2 * BLOCK),  # crossing inside
     # a lane's length is clamped to the bucket
     (3 * BLOCK, 8, 2 * BLOCK, BLOCK, 8 * 2 * BLOCK),
-    # the wide block: whole blocks, past a bucket that 256 does not divide
-    (0, 1, 128, WIDE_BLOCK, 256),
-    (255, 1, 640, WIDE_BLOCK, 256),
-    (256, 1, 640, WIDE_BLOCK, 512),
-    (254, 4, 640, WIDE_BLOCK, 2 * 256 + 2 * 512),
-    (600, 4, 640, WIDE_BLOCK, 4 * 768),
-    (3 * BLOCK, 8, 2 * BLOCK, WIDE_BLOCK, 8 * 2 * BLOCK),
+    # a wider block: whole blocks, past a bucket that 256 does not divide
+    (0, 1, 128, WIDE, 256),
+    (255, 1, 640, WIDE, 256),
+    (256, 1, 640, WIDE, 512),
+    (254, 4, 640, WIDE, 2 * 256 + 2 * 512),
+    (600, 4, 640, WIDE, 4 * 768),
+    (3 * BLOCK, 8, 2 * BLOCK, WIDE, 8 * 2 * BLOCK),
+    # the ceiling: one key is a block of 1,024, as 1,023 are
+    (0, 1, 128, CEILING, 1024),
+    (1022, 4, 2304, CEILING, 2 * 1024 + 2 * 2048),
+    (2300, 2, 2304, CEILING, 2 * 3072),
 ])
 def test_positions_streamed_rounds_each_step_to_the_block(pos, k, bucket, block, want):
     from seldon_core_tpu.serving.continuous import _positions_streamed
@@ -610,8 +688,11 @@ def test_positions_streamed_rounds_each_step_to_the_block(pos, k, bucket, block,
 
 
 @pytest.mark.parametrize("max_seq,block", [
-    (3 * BLOCK, BLOCK),       # a cache the wide block does not divide
-    (4 * BLOCK, WIDE_BLOCK),  # 2 KV heads of 8 in float32: the wide block
+    # 2 KV heads of 8 in float32, rows far under ``COVERS``: the largest
+    # block up to the ceiling that divides the cache
+    (3 * BLOCK, BLOCK),
+    (2 * BLOCK, WIDE),
+    (4 * BLOCK, 2 * WIDE),
 ])
 def test_batcher_counts_what_the_read_streams_and_what_the_bucket_held(
         max_seq, block):
@@ -646,7 +727,8 @@ def test_batcher_counts_what_the_read_streams_and_what_the_bucket_held(
         2 * 4 * g["attn_len"] for g in groups)
     assert stats["kv_positions_read"] == len(groups) * _positions_streamed(5, 2, 128, block)
     assert stats["kv_positions_read"] == 2 * block * len(groups)
-    assert stats["kv_positions_read"] * 2 <= stats["kv_positions_bucket"]
+    if block <= WIDE:   # (rows this tiny walk blocks wider than the bucket)
+        assert stats["kv_positions_read"] * 2 <= stats["kv_positions_bucket"]
     counters = b.capture_counters()["counters"]
     assert "kv_positions_read" in counters
     # the write: one lane x 2 steps x 2 layers x (K, V) a burst, by the
@@ -673,8 +755,8 @@ def _wide(seed, lanes, kv, rep, t, dk, dv, dtype):
 
 @pytest.mark.parametrize("why,kv,rep,t,lens,wp,sink", [
     # 16 queries a KV head over a long cache: the full layers' call
-    ("full: 16 a head", 2, 16, 2 * WIDE_BLOCK,
-     [0, 1, 255, 256, 257, 2 * WIDE_BLOCK], [9, 0, 254, 255, 256, 511], False),
+    ("full: 16 a head", 2, 16, 2 * WIDE,
+     [0, 1, 255, 256, 257, 2 * WIDE], [9, 0, 254, 255, 256, 511], False),
     # a ring of one block: lanes not yet once round (the bound of the slots
     # written), a full ring written anywhere in it, an idle lane, a parked one
     ("ring: a sink", 2, 4, BLOCK,
@@ -690,6 +772,9 @@ def test_keys_wider_than_values_a_sink_and_a_ring(why, kv, rep, t, lens, wp,
     bit, a lane of length 0 zeros and unwritten; the sink is one more logit
     a head with no value row, so a row's weights sum to less than one."""
     q, k, v, kn, vn, logits = _wide(7, len(lens), kv, rep, t, 256, 128, dtype)
+    if t == 2 * WIDE:   # (what the call walks with no ``block=``)
+        assert walk_block(kv, 256, dtype, t, 128) == (
+            WIDE if dtype == "float32" else 2 * WIDE)
     lens, wp = jnp.asarray(lens, jnp.int32), jnp.asarray(wp, jnp.int32)
     s = logits if sink else None
     o, k2, v2 = ragged_decode_attention(
@@ -758,7 +843,7 @@ def test_one_entry_takes_a_window_at_two_widths(dtype, tol):
     for every caller, so ``starts`` meets keys wider than values there: the
     dots under the band are the kernel's windowed walk (interpreted), caches
     bit for bit; a sink beside ``starts`` is refused before either."""
-    t = 2 * WIDE_BLOCK
+    t = 2 * WIDE
     q, k, v, kn, vn, logits = _wide(11, 4, 2, 4, t, 256, 128, dtype)
     lens = jnp.asarray([1, 200, 300, t], jnp.int32)
     starts = jnp.maximum(lens - 128, 0)
@@ -796,9 +881,9 @@ def _packed_case(seed, lanes, kv, rep, t, dk, dtype, w=1):
 @pytest.mark.parametrize("why,kv,rep,t,dk,lens,wp,sink", [
     # lanes on both sides of a block's edge, one of length 0, a parked
     # write, the write landing in the last block of the read and in none
-    ("full: two heads' rests a row", 4, 4, 2 * WIDE_BLOCK, 192,
-     [0, 1, 255, 256, 257, 2 * WIDE_BLOCK, 300, 40],
-     [9, 0, 254, 255, 256, 511, 2 * WIDE_BLOCK, 400], False),
+    ("full: two heads' rests a row", 4, 4, 2 * WIDE, 192,
+     [0, 1, 255, 256, 257, 2 * WIDE, 300, 40],
+     [9, 0, 254, 255, 256, 511, 2 * WIDE, 400], False),
     # a ring of one block under its own name: lanes not yet once round, a
     # full ring written anywhere in it, an idle lane, a parked one
     ("ring: a sink", 4, 2, BLOCK, 192,
@@ -849,7 +934,7 @@ def test_a_block_of_positions_over_packed_key_rows():
     lens = jnp.asarray([4, 260, 0, 512], jnp.int32)
     wp = jnp.asarray([0, 256, 8, 508], jnp.int32)
     q, k, v, kn, vn, _s, packed, rows, new = _packed_case(
-        17, 4, 2, 2, 2 * WIDE_BLOCK, 192, "float32", w=4)
+        17, 4, 2, 2, 2 * WIDE, 192, "float32", w=4)
     o, k2, v2 = ragged_decode_attention(
         q[..., :128], rows, v, lens, new, vn, wp, interpret=True,
         q_rest=q[..., 128:])

@@ -26,7 +26,10 @@ SMALL = dict(
     norm_eps=1e-5, dtype="float32", layer_types=KINDS, n_dense_layers=1,
     n_routed_experts=16, experts_per_tok=4, expert_width=64,
     experts_held=(4, 4), conv_kernel=3, residual_scale=0.5)
-BLOCK = 256     # the kernel's block at these rows: lengths lie on both sides
+BLOCK = 256     # the kernel's block at the cell's rows, and at 2 rows of 128 in
+#                 float32: lengths lie on both sides
+WALKED = 512    # its block at SMALL's ONE row of 128 in float32 (128 keys of
+#                 K and V: 128 KiB; 512 copy 512 KiB): the whole of this cache
 
 
 @pytest.fixture(scope="module")
@@ -55,7 +58,8 @@ def test_the_block_is_built_through_decoderlm_and_counts_its_parameters(served):
     # two heads of 64 a row of 128
     assert cache["k"][0].shape == (4, 1, 512, 128)
     assert cache["conv"][0].shape == (4, 2, 128)
-    assert walk_block(1, 128, cache["k"][0].dtype, 512) == BLOCK
+    assert walk_block(1, 128, cache["k"][0].dtype, 512) == WALKED
+    assert walk_block(2, 128, cache["k"][0].dtype, 512) == BLOCK
     assert model.kv_bytes_per_token() == 2 * 2 * 2 * 64 * 2
     lane_bytes = model.lane_cache_bytes(cache)
     per_position = model.cache_position_bytes(cache)
@@ -191,7 +195,7 @@ def test_an_idle_lanes_tail_and_rows_stay_and_the_step_counts_its_lanes(stepped)
         # what the kernel's lowering counts: rounded as it walks
         walked = stepped["model"]._rows_walked(
             stepped["cache"]["k"][0], jnp.asarray(lens, jnp.int32))
-        assert int(walked) == (-(-lens // BLOCK) * BLOCK).sum()
+        assert int(walked) == (-(-lens // WALKED) * WALKED).sum()
 
 
 @pytest.mark.parametrize("lens", [

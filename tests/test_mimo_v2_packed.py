@@ -263,8 +263,9 @@ def test_the_walks_block_is_asked_with_the_bytes_the_call_copies():
         4, 192, jnp.bfloat16, 12288, 128)
     assert model.read_block(12288 + 128) == 128      # 256 does not divide it
     assert walk_block(8, 192, jnp.bfloat16, 128, 128) == 128
+    # a tiny size's 2 x (128 + 16) x 4 x 128 = 147,456 B double twice
     tiny = DecoderLM(**dict(PACKED, head_dim=24, v_head_width=16, rotary_dim=8))
-    assert tiny.read_block(1024) == 256 == walk_block(
+    assert tiny.read_block(1024) == 512 == walk_block(
         2, 128, jnp.float32, 1024, 16)
 
 
@@ -472,7 +473,8 @@ def test_the_benchmarks_comparison_is_answered_by_the_family_over_packed_rows():
     finally:
         batcher.close()
     assert out["ok"], out
-    assert out["read_block"] == 256 and out["lanes_wrapped"] > 0
+    # 2 KV heads of 192 + 128 in bfloat16: 512 keys copy 655,360 B
+    assert out["read_block"] == 512 and out["lanes_wrapped"] > 0
     assert out["rows_ratio"] <= arch.ROWS_TOLERANCE
     assert out["rings_ratio"] <= arch.RINGS_TOLERANCE
     assert out["idle_untouched"] and out["inserted"]

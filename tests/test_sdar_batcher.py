@@ -17,7 +17,7 @@ from benchmark.reference import sdar_moe as reference
 from seldon_core_tpu import tracing
 from seldon_core_tpu.http_server import Request
 from seldon_core_tpu.models.llm import DecoderLM
-from seldon_core_tpu.ops.decode_attention import WIDE_BLOCK, walk_block
+from seldon_core_tpu.ops.decode_attention import walk_block
 from seldon_core_tpu.serving.continuous import ContinuousBatcher
 
 MASK = 96
@@ -95,10 +95,11 @@ def test_lanes_in_different_phases_share_a_burst(served):
     # of each block above, over the 2 layers; what the kernel streams of
     # them rounds each length up to the block of its walk, the rule's own
     # for this model's 2 KV heads of 16 in float32 and this cache of 256
+    # (the rows are far under ``COVERS``: the largest block that divides it)
     passes = [(3, 28), (3, 32), (3, 36), (2, 8), (3, 12), (3, 16), (2, 16),
               (3, 20)]
     block = walk_block(model.cfg.n_kv_heads, model.cfg.head_dim, "float32", 256)
-    assert block == WIDE_BLOCK == batcher._kv_read_block
+    assert block == 256 == batcher._kv_read_block
     assert diff["block_rows_live"] == 2 * sum(n * at for n, at in passes)
     assert diff["block_rows_read"] == 2 * sum(
         n * -(-at // block) * block for n, at in passes)

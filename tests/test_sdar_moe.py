@@ -168,12 +168,14 @@ def test_lanes_of_their_own_lengths_with_idle_ones_among_them(tiny):
 
 
 @pytest.mark.parametrize("shape,cache_len,block", [
-    # 2 KV heads of 16 in float32: 128 keys of K and V are 32 KiB, the wide
-    # block, at 8 query rows a KV head as at 32
-    (dict(n_heads=4), 1024, 256),
-    (dict(n_heads=16), 1024, 256),
+    # 2 KV heads of 16 in float32: 128 keys of K and V are 32 KiB, far
+    # under what covers the chain, so the block is the largest up to 1,024
+    # that divides the cache, at 8 query rows a KV head as at 32
+    (dict(n_heads=4), 1024, 1024),
+    (dict(n_heads=16), 1024, 1024),
+    (dict(n_heads=16), 1536, 512),
     (dict(n_heads=16), 768, 256),
-    (dict(n_heads=16), 640, 128),     # which does not divide this cache
+    (dict(n_heads=16), 640, 128),     # which 256 does not divide
     # 4 KV heads of 128 in float32: 512 KiB, a copy that covers the chain
     (dict(n_heads=4, n_kv_heads=4, head_dim=128), 768, 128),
 ])
